@@ -11,8 +11,8 @@
 # Build:  make image                      (dynamo-tpu/runtime:latest)
 #         make image RELEASE_VERSION=0.5.0 JAX_EXTRA=tpu
 # The default build installs jax[tpu] (libtpu wheel). JAX_EXTRA= (empty)
-# builds a CPU-only image for CI and operator-only clusters — every worker
-# path degrades cleanly off-chip.
+# builds a CPU-only image for CI and operator-only clusters; a worker in it
+# starts only with JAX_PLATFORMS=cpu set on purpose (no CPU fallback).
 
 ARG BASE_IMAGE=python:3.12-slim
 FROM ${BASE_IMAGE}

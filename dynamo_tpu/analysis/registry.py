@@ -64,7 +64,7 @@ KNOWN_ENV: Dict[str, str] = {
         "rotation",
     "DYNAMO_TPU_BUILD_DIR":
         "native runtime: build/cache directory (default "
-        "~/.cache/dynamo_tpu/native)",
+        "<checkout>/.dynamo_cache/native)",
     "DYNAMO_TPU_CHIP":
         "TPU chip generation override (v4/v5e/v5p/v6e) for utilization "
         "denominators in engine metrics",

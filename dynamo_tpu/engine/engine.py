@@ -22,10 +22,11 @@ from __future__ import annotations
 
 import collections
 import functools
+import inspect
 import logging
 import threading
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +76,14 @@ def _pack_logit_bias(req: GenRequest):
             ids[i] = int(tok)
             vals[i] = float(b)
     return ids, vals
+
+
+def _argnums(fn, *names: str) -> Tuple[int, ...]:
+    """Positions of the parameters called `names` in `fn`'s signature (a
+    `*varargs` name gives the position of its first operand). Raises
+    ValueError for a name the signature does not have."""
+    params = list(inspect.signature(fn).parameters)
+    return tuple(params.index(n) for n in names)
 
 
 def _next_bucket(n: int, page_size: int, max_len: int) -> int:
@@ -594,11 +603,10 @@ class Engine:
         # phase arms a hang deadline; the health state machine drives
         # shedding, in-place resurrection, and permanent quarantine.
         # Sentinel tier resolved once at construction (env is a boot knob).
-        # Derived deadlines arm only on real accelerators: the CPU
-        # fallback recompiles mid-seam (no AOT warmup guarantee), which
-        # would read as a hang; env/CI overrides still trip there.
-        self.watchdog = EngineWatchdog(
-            self, derive_deadline=(backend != "cpu"))
+        # The derived deadline arms only once warmup() has completed on a
+        # real accelerator: until then (and always on the CPU) a seam may
+        # hold a compilation. Env/CI overrides trip everywhere.
+        self.watchdog = EngineWatchdog(self, derive_deadline=False)
         self.timeline.watch = self.watchdog
         self.integrity = integrity_mode()
         self._page_nbytes = (self.kv_spec.bytes_per_token()
@@ -731,8 +739,6 @@ class Engine:
         # The *aslot splat keeps the lora-off signatures byte-identical to
         # before — no recompiles, no donation-index churn, zero cost.
         lora_on = self.lora is not None
-        # jax.P / jax.NamedSharding top-level aliases only exist on newer
-        # jax releases; the jax.sharding forms work on every version in use
         rep_sharding = jax.sharding.NamedSharding(
             self.mesh, jax.sharding.PartitionSpec())
 
@@ -1117,37 +1123,34 @@ class Engine:
 
             self._build_guided_window = _build_guided_window_eager
         else:
-            # donate KV pools + carried decode state: XLA updates in place
-            # (active mask, block tables, sampling params and slot keys are
-            # reused across windows). tokens/pos/ctx/counts/k/v donated —
-            # positions 1, 2, 3, 15, 16, 17 of window_fn. (A previous tuple
-            # mistakenly donated the REUSED bias_ids/bias_vals/slot_keys at
-            # 12-14; on TPU at B=64/window=32 XLA aliased bias_ids onto the
-            # int32[32, 64] token output and deleted it, crashing the next
-            # dispatch with 'Array has been deleted' — the battery's
-            # multistep_32/int8kv_pallas failures.)
-            window_donate = (1, 2, 3, 15, 16, 17)
-            jp = jax.jit(prefill_fn, donate_argnums=(3, 4))
-            jpb = jax.jit(prefill_batch_fn, donate_argnums=(3, 4))
+            # donate the KV pools + the carried decode state, which XLA
+            # then updates in place. Everything else (active mask, block
+            # tables, sampling params, bias arrays, slot keys, the
+            # per-call chunk operands) is REUSED by the next dispatch and
+            # must never be donated: a TPU deletes a donated buffer
+            # ('Array has been deleted' on the next use) where the CPU
+            # only warns, so no CPU test can see a wrong tuple. Hence
+            # donation is declared by parameter NAME and resolved against
+            # each function's own signature (_argnums).
+            kv = ("k_pages", "v_pages")
+            carry = ("tokens", "positions", "context_lens", "counts") + kv
+            jp = jax.jit(prefill_fn, donate_argnums=_argnums(prefill_fn, *kv))
+            jpb = jax.jit(prefill_batch_fn,
+                          donate_argnums=_argnums(prefill_batch_fn, *kv))
             jsb = jax.jit(sample_first_batch)
-            jc = jax.jit(chunk_fn, donate_argnums=(4, 5))
-            jw = {k: jax.jit(f, donate_argnums=window_donate)
+            jc = jax.jit(chunk_fn, donate_argnums=_argnums(chunk_fn, *kv))
+            jw = {k: jax.jit(f, donate_argnums=_argnums(f, *carry))
                   for k, f in window_fns.items()}
-            # the mixed step's leading operands are the window's, so the
-            # same donation tuple applies; the trailing chunk operands are
-            # per-call uploads and stay undonated
-            jm = {k: jax.jit(f, donate_argnums=window_donate)
+            jm = {k: jax.jit(f, donate_argnums=_argnums(f, *carry))
                   for k, f in mixed_fns.items()}
-            # same intent as window_donate: tokens/pos/ctx/counts/k/v (the
-            # reused bias/key arrays at 13-15 must NOT be donated)
-            jspec = jax.jit(spec_fn, donate_argnums=(1, 3, 4, 16, 18, 19))
-            # the mixed-spec leading operands are spec_fn's, so the same
-            # donation tuple applies; chunk operands trail undonated
+            jspec = jax.jit(spec_fn,
+                            donate_argnums=_argnums(spec_fn, *carry))
             jms = jax.jit(mixed_spec_fn,
-                          donate_argnums=(1, 3, 4, 16, 18, 19))
+                          donate_argnums=_argnums(mixed_spec_fn, *carry))
             js = jax.jit(sample_first)
-            jr = jax.jit(reset_count_fn, donate_argnums=(0,))
-            ji = jax.jit(import_fn, donate_argnums=(0, 1))
+            jr = jax.jit(reset_count_fn,
+                         donate_argnums=_argnums(reset_count_fn, "counts"))
+            ji = jax.jit(import_fn, donate_argnums=_argnums(import_fn, *kv))
             self._prefill = ctx(jp)
             self._prefill_batch = ctx(jpb)
             self._prefill_chunk = ctx(jc)
@@ -1164,15 +1167,16 @@ class Engine:
                 """Guided decode-window variant, built lazily on first use
                 (warmup()'s __warm_guided/__warm_guided_lp requests trigger
                 all four variants before /ready). The carried grammar state
-                (gmode/gdepth/gbits at 18-20, shifted by one when the lora
-                adapter-slot operand precedes it) is donated like the other
-                carry; gactive (the next position) is reused."""
+                (gmode/gdepth/gbits: the first three *extra operands, after
+                the lora adapter-slot operand when there is one) is donated
+                like the other carry; gactive (the next position) is
+                reused."""
                 fn = make_decode_window(n_multi if multi else 1, lp,
                                         guide_tables=self._guide_dev)
-                g0 = 19 if lora_on else 18
+                g0 = _argnums(fn, "extra")[0] + (1 if lora_on else 0)
                 j = jax.jit(fn,
-                            donate_argnums=window_donate + (g0, g0 + 1,
-                                                            g0 + 2))
+                            donate_argnums=_argnums(fn, *carry)
+                            + (g0, g0 + 1, g0 + 2))
                 self._jit_handles[f"window_guided_{multi}_{lp}"] = j
                 return ctx(j)
 
@@ -1234,6 +1238,9 @@ class Engine:
             return {"programs": 0, "seconds": 0}
         if self.has_work:
             raise RuntimeError("warmup() requires an idle engine")
+        # every first call below compiles inside its dispatch seam: not a
+        # hang, and not a sample of steady-state seam time
+        self.watchdog.derive_deadline = False
         cfg = self.cfg
         t0 = time.monotonic()
         k = max(1, cfg.num_scheduler_steps)
@@ -1374,6 +1381,8 @@ class Engine:
         # (dynamo_engine_warmup_seconds / _jit_programs, the bridge in
         # observability/engine_metrics.py) reads it at scrape time
         self.warmup_info = dict(out)
+        # compile-complete: from here a slow seam is a hang
+        self.watchdog.derive_deadline = jax.default_backend() != "cpu"
         log.info("warmup complete: %s", out)
         return out
 

@@ -39,32 +39,32 @@ def main(argv=None) -> None:
                    default=float(os.environ.get("SCRAPE_INTERVAL", "10")))
     args = p.parse_args(argv)
 
-    from dynamo_tpu.utils.platform import init_backend_with_fallback
-    backend = init_backend_with_fallback()
-    logging.info("tpu exporter on %s:%d (backend=%s)", args.host, args.port,
-                 backend)
+    # A chip belongs to one process. On a node where an engine worker runs,
+    # the worker holds the chips and exports these series in-process on its
+    # own /metrics (serving/worker.py); this standalone process then cannot
+    # initialise the TPU and must say so and exit — never come up on the
+    # CPU and publish zero-valued tpu_* series as if they were a chip's.
+    from dynamo_tpu.utils.platform import init_backend
+
+    try:
+        backend = init_backend()
+    except SystemExit as e:
+        raise SystemExit(
+            f"{e}\ndynamo_tpu.exporter: if an engine worker runs on this "
+            f"node it owns the chips and already serves the tpu_* series "
+            f"on its own /metrics; the standalone exporter is only for "
+            f"nodes whose chips no worker holds.") from e
+    if backend != "tpu":
+        raise SystemExit(
+            "dynamo_tpu.exporter: refusing to export tpu_* hardware series "
+            f"from a {backend} backend (JAX_PLATFORMS=cpu is set)")
+    logging.info("tpu exporter on %s:%d", args.host, args.port)
 
     stop = threading.Event()
-    # On TPU nodes the chips are held by the worker process, which exports
-    # in-process (serving/worker.py). A standalone pod falling back to CPU
-    # would export zero-valued tpu_* series that pollute the dashboard
-    # alongside the real ones — so keep the registry empty unless forced.
-    if backend == "cpu" and not os.environ.get("DYNAMO_EXPORTER_FORCE"):
-        logging.warning(
-            "cpu backend and DYNAMO_EXPORTER_FORCE unset: serving /health "
-            "and an empty /metrics, no tpu_* series"
-        )
-        from dynamo_tpu.serving.metrics import Registry
-
-        class _Empty:
-            registry = Registry()
-
-        exp = _Empty()
-    else:
-        exp = TpuMetricsExporter()
-        t = threading.Thread(target=exp.run_forever, args=(args.interval, stop),
-                             daemon=True)
-        t.start()
+    exp = TpuMetricsExporter()
+    t = threading.Thread(target=exp.run_forever, args=(args.interval, stop),
+                         daemon=True)
+    t.start()
     srv = make_http_server(_Handler, {"exporter": exp}, args.host, args.port)
     try:
         srv.serve_forever()

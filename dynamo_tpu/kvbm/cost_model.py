@@ -95,26 +95,15 @@ class OnboardGate:
 
 
 def _detect_chip_flops() -> float:
-    """Peak bf16 FLOPs of the chip actually serving, for the recompute
-    side of the gate; the v5e planning number when detection fails (CPU
-    tests, unknown chips)."""
-    try:
-        import jax
+    """Peak bf16 FLOP/s of the chip actually serving, for the recompute
+    side of the gate, from the one chip table (profiler/systems.py). An
+    unknown TPU is an error; a CPU process (tests, local development) has
+    no peak of its own and plans for the v5e the repo is measured on."""
+    import jax
 
-        from dynamo_tpu.profiler.systems import CHIPS
+    from dynamo_tpu.profiler import systems
 
-        kind = (getattr(jax.devices()[0], "device_kind", "") or "").lower()
-        import re
-
-        for pat, name in [(r"v5 ?lite|v5e", "v5e"), (r"v5p|v5 ?pod", "v5p"),
-                          (r"v6e|v6 ?lite|trillium", "v6e"), (r"v4", "v4")]:
-            if re.search(pat, kind):
-                return CHIPS[name].bf16_flops
-    except Exception:
-        pass
-    try:
-        from dynamo_tpu.profiler.systems import CHIPS
-
-        return CHIPS["v5e"].bf16_flops
-    except Exception:
-        return 2e14
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return systems.CHIPS["v5e"].bf16_flops
+    return systems.require_chip(dev.device_kind).bf16_flops

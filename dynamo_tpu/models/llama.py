@@ -615,8 +615,8 @@ def prefill_batch(
     """Prefill N same-bucket prompts in ONE dispatch.
 
     Admission batching: under bursty load the per-dispatch host round trip
-    (large on tunneled TPUs) dominates short-prompt TTFT; grouping
-    same-bucket admissions amortizes it N-fold. Attention is the per-seq
+    weighs on short-prompt TTFT; grouping same-bucket admissions amortizes
+    it N-fold. Attention is the per-seq
     prefill kernel vmapped over the group; KV writes share one flat
     scatter (lane i's pages are disjoint by construction). Dummy padding
     lanes carry all-trash page rows, so their writes land in the reserved

@@ -67,10 +67,24 @@ def load_or_init_params(
         # model). Small models keep init+quantize so int8 stays
         # token-parity-testable against the fp engine.
         return random_quantized_params(cfg, seed, mode=quantization)
-    cpu = jax.devices("cpu")[0]
-    with jax.default_device(cpu):
+    with jax.default_device(_host_device()):
         params = _load()
         return quant.quantize_params(params, mode=quantization)
+
+
+def _host_device():
+    """JAX's CPU device, for staging full-precision weights on the host
+    before they are quantized. It exists beside the TPU when JAX_PLATFORMS
+    is unset or lists `cpu` (utils/platform.init_backend adds it when an
+    entry point was told `tpu` alone); a library caller that pinned
+    JAX_PLATFORMS=tpu itself gets told what to change."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "quantizing a checkpoint on the host needs JAX's CPU backend "
+            "beside the accelerator: leave JAX_PLATFORMS unset or use "
+            "JAX_PLATFORMS=tpu,cpu") from e
 
 
 def random_quantized_params(cfg: ModelConfig, seed: int = 0,
@@ -80,38 +94,38 @@ def random_quantized_params(cfg: ModelConfig, seed: int = 0,
     Statistically equivalent to init + quantize (int8 values uniform over the
     byte range with per-channel scales sized so dequantized weights match
     each spec's sigma at amax ~= 4.5 sigma) at a tiny fraction of the cost:
-    raw RNG bytes instead of N billion f32 normals + a second f32 pass."""
+    raw RNG bytes instead of N billion f32 normals + a second f32 pass.
+
+    The leaves are host (numpy) arrays, so no JAX backend is touched here
+    at all: the tree crosses to the accelerator once, shard by shard, in
+    the engine's shard_params."""
     from dynamo_tpu.models import quant
 
     dt = jnp.dtype(cfg.dtype)
     cls = quant.qtensor_class(mode)
     rng = np.random.Generator(np.random.PCG64(seed))
-    p: Dict[str, jax.Array] = {}
-    # pin to host like the quantize path: the int8 tree crosses to the
-    # accelerator once, via the engine's shard_params
-    with jax.default_device(jax.devices("cpu")[0]):
-        for name, (shape, kind, sigma) in llama.param_specs(cfg).items():
-            if kind == "ones":
-                p[name] = jnp.ones(shape, dt)
-            elif kind == "zeros":
-                p[name] = jnp.zeros(shape, dt)
-            elif name in quant.QUANT_AXES:
-                n = int(np.prod(shape))
-                # 16 MiB of entropy tiled to size: weight VALUES are
-                # irrelevant here (no checkpoint to reproduce; serving
-                # timing is value-independent) — only shape/dtype/scale
-                # matter, and multi-GiB PCG64 streams cost minutes
-                ent = np.frombuffer(rng.bytes(min(n, 1 << 24)), dtype=np.int8)
-                q = np.tile(ent, -(-n // ent.size))[:n].reshape(shape)
-                sshape = tuple(1 if i in quant.QUANT_AXES[name] else s
-                               for i, s in enumerate(shape))
-                scale = np.full(sshape, sigma * 4.5 / 127.0, dtype=np.float32)
-                p[name] = cls(jnp.asarray(q), jnp.asarray(scale))
-            else:
-                # unquantized weight (router etc.): small enough for normals
-                p[name] = jnp.asarray(
-                    rng.standard_normal(shape, dtype=np.float32) * sigma
-                ).astype(dt)
+    p: Dict[str, np.ndarray] = {}
+    for name, (shape, kind, sigma) in llama.param_specs(cfg).items():
+        if kind == "ones":
+            p[name] = np.ones(shape, dt)
+        elif kind == "zeros":
+            p[name] = np.zeros(shape, dt)
+        elif name in quant.QUANT_AXES:
+            n = int(np.prod(shape))
+            # 16 MiB of entropy tiled to size: weight VALUES are
+            # irrelevant here (no checkpoint to reproduce; serving
+            # timing is value-independent) — only shape/dtype/scale
+            # matter, and multi-GiB PCG64 streams cost minutes
+            ent = np.frombuffer(rng.bytes(min(n, 1 << 24)), dtype=np.int8)
+            q = np.tile(ent, -(-n // ent.size))[:n].reshape(shape)
+            sshape = tuple(1 if i in quant.QUANT_AXES[name] else s
+                           for i, s in enumerate(shape))
+            scale = np.full(sshape, sigma * 4.5 / 127.0, dtype=np.float32)
+            p[name] = cls(q, scale)
+        else:
+            # unquantized weight (router etc.): small enough for normals
+            p[name] = (rng.standard_normal(shape, dtype=np.float32)
+                       * sigma).astype(dt)
     return p
 
 

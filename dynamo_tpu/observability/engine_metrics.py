@@ -214,6 +214,43 @@ def resolve_chip():
     return systems.chip_for_device_kind(kind)
 
 
+def device_report(engine) -> dict:
+    """What this worker runs on and what it compiled, as JAX reports it —
+    the `/worker/stats` fields every benchmark row and `chip_smoke.py`
+    need to say which device a number came from: platform, device kind
+    and count, library versions, the compiled-program count and warmup
+    time, and which implementation each attention op was traced with (the
+    counted Pallas->XLA demotions are `dynamo_pallas_fallback_total` on
+    `/metrics`). Per-device memory is already in the `memory.devices`
+    block (observability/memory.py)."""
+    import importlib.metadata
+
+    import jax
+
+    from dynamo_tpu.ops import attention as att
+
+    def version(dist: str) -> Optional[str]:
+        try:
+            return importlib.metadata.version(dist)
+        except importlib.metadata.PackageNotFoundError:
+            return None
+
+    devices = jax.devices()
+    traced: dict = {}
+    for (op, impl), n in sorted(att.attention_impl_counts().items()):
+        traced.setdefault(op, {})[impl] = n
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "versions": {"jax": jax.__version__, "jaxlib": version("jaxlib"),
+                     "libtpu": version("libtpu")},
+        "compiled_programs": engine.compiled_program_count(),
+        "warmup": getattr(engine, "warmup_info", None),
+        "attention": {"traced": traced},
+    }
+
+
 class EngineMetricsBridge:
     """Registers the dynamo_engine_* series against a worker registry and
     refreshes the MFU/MBU gauges at scrape time."""

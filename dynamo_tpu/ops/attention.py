@@ -33,16 +33,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-try:  # top-level alias exists on newer jax only
-    _shard_map = jax.shard_map
-except AttributeError:  # pre-0.6 spelling (and check_vma was check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def _shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_impl(f, **kw)
 from jax.sharding import Mesh, PartitionSpec as P
 
 # (backend, mesh, kv_lane_blocks) bound by the engine around each jit call
@@ -58,6 +48,7 @@ _BACKEND: Optional[str] = None  # process-wide override (tests, ad-hoc use)
 _MESH: Optional[Mesh] = None
 
 _VALID_BACKENDS = ("auto", "xla", "pallas", "pallas_interpret")
+_KERNEL_BACKENDS = ("pallas", "pallas_interpret")
 
 
 @contextlib.contextmanager
@@ -96,14 +87,6 @@ def _resolve_backend() -> str:
     if b == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "xla"
     return b
-
-
-def _explicit_backend() -> Optional[str]:
-    """The backend the USER pinned (context/global/env), or None for auto —
-    fallback warnings fire only when an explicit choice is overridden."""
-    ctx_backend = _ATTN_CTX.get()[0]
-    b = ctx_backend or _BACKEND or os.environ.get("DYNAMO_TPU_ATTN_BACKEND")
-    return None if b in (None, "auto") else b
 
 
 def _scoped_mesh() -> Optional[Mesh]:
@@ -421,36 +404,32 @@ def chunk_attention(
       on-chip parity case passes (CHUNK_KERNEL_INT8_HW_VALIDATED).
     """
     # Selection: the DYNAMO_TPU_CHUNK_ATTENTION env var wins when set;
-    # otherwise, once the kernel is hardware-validated
-    # (pallas_attention.CHUNK_KERNEL_HW_VALIDATED — flipped by the battery's
-    # chunk_kernel_parity case), selection follows _resolve_backend() like
-    # the decode/prefill ops.
+    # otherwise it follows _resolve_backend() like the decode/prefill ops.
+    # Every route from a resolved kernel to the XLA path goes through
+    # _demote, so it is counted and logged.
+    op = "chunk attention"
     backend = os.environ.get("DYNAMO_TPU_CHUNK_ATTENTION")
     if not backend:
         from dynamo_tpu.ops import pallas_attention as _pa
 
-        backend = (_resolve_backend() if _pa.CHUNK_KERNEL_HW_VALIDATED
-                   else "xla")
+        backend = _resolve_backend()
+        if not _pa.CHUNK_KERNEL_HW_VALIDATED:
+            backend = _demote(backend, op, "not_validated",
+                              "CHUNK_KERNEL_HW_VALIDATED is False")
         # the on-chip parity case that flipped the flag ran bf16 pages;
-        # int8 dequant-in-chunk has its own gate (battery case
-        # chunk_kernel_int8_parity)
-        if backend in ("pallas", "pallas_interpret") \
-                and k_pages.dtype == jnp.int8 \
+        # int8 dequant-in-chunk has its own gate
+        if k_pages.dtype == jnp.int8 \
                 and not _pa.CHUNK_KERNEL_INT8_HW_VALIDATED:
-            _note_fallback(
-                "chunk attention", "int8_not_validated",
-                "int8 dequant-in-chunk awaits its on-chip parity case; "
+            backend = _demote(
+                backend, op, "int8_not_validated",
+                "int8 dequant-in-chunk awaits its on-chip parity verdict; "
                 "set DYNAMO_TPU_CHUNK_ATTENTION=pallas to force")
-            backend = "xla"
     if window is not None or logit_cap:
-        backend = "xla"  # sliding window / softcap: kernel doesn't model them
-    if backend in ("pallas", "pallas_interpret") \
-            and _seq_parallel_mesh() is not None:
+        backend = _demote(backend, op, "window_softcap")
+    if _seq_parallel_mesh() is not None:
         # see the decode dispatch's seq-mesh note
-        _note_fallback("chunk attention", "seq_mesh",
-                       "sequence-parallel mesh shards the pool under GSPMD")
-        backend = "xla"
-    if backend in ("pallas", "pallas_interpret"):
+        backend = _demote(backend, op, "seq_mesh")
+    if backend in _KERNEL_BACKENDS:
         quantized = k_pages.dtype == jnp.int8
         n_kv = _pool_kv_heads(k_pages, q.shape[2], num_kv_heads)
         lb = _kv_lane_blocks() if quantized else 1
@@ -472,6 +451,7 @@ def chunk_attention(
 
             interp = backend == "pallas_interpret"
             n_kv_call = n_kv // max(tp, 1)
+            _note_impl(op, backend)
 
             def call(q, kp, vp, pg, st):
                 return pa.chunk_prefill_attention(
@@ -483,7 +463,7 @@ def chunk_attention(
             st = jnp.asarray(start, jnp.int32)
             if mesh is None:
                 return call(q, k_pages, v_pages, pages, st)
-            return _shard_map(
+            return jax.shard_map(
                 call,
                 mesh=mesh,
                 in_specs=(P(None, "model", None), P(None, None, "model"),
@@ -491,6 +471,7 @@ def chunk_attention(
                 out_specs=P(None, "model", None),
                 check_vma=False,
             )(q, k_pages, v_pages, pages, st)
+    _note_impl(op, "xla")
     return chunk_attention_xla(
         q, k_pages, v_pages, pages, start, page_size=page_size,
         num_kv_heads=num_kv_heads, window=window, logit_cap=logit_cap)
@@ -563,23 +544,11 @@ def ragged_mixed_attention(
     head/lane gates guard the kernel, with demotions counted via
     _note_fallback.
     """
-    backend = os.environ.get("DYNAMO_TPU_RAGGED_ATTENTION")
-    if not backend:
-        from dynamo_tpu.ops import ragged_attention as _ra
-
-        backend = (_resolve_backend() if _ra.RAGGED_KERNEL_HW_VALIDATED
-                   else "xla")
-    if window is not None or logit_cap:
-        backend = "xla"  # sliding window / softcap: kernel doesn't model them
-    if backend in ("pallas", "pallas_interpret") \
-            and _seq_parallel_mesh() is not None:
-        _note_fallback("ragged attention", "seq_mesh",
-                       "sequence-parallel mesh shards the pool under GSPMD")
-        backend = "xla"
+    backend = _ragged_backend(window, logit_cap)
     n_kv = _pool_kv_heads(k_pages, q.shape[2], num_kv_heads)
     b = num_decode
     c = q.shape[0] - b
-    if backend in ("pallas", "pallas_interpret"):
+    if backend in _KERNEL_BACKENDS:
         quantized = k_pages.dtype == jnp.int8
         lb = _kv_lane_blocks() if quantized else 1
         mesh = _mesh_for_shard_map()
@@ -600,6 +569,7 @@ def ragged_mixed_attention(
 
             interp = backend == "pallas_interpret"
             n_kv_call = n_kv // max(tp, 1)
+            _note_impl("ragged attention", backend)
             # unified descriptor set: one page-table row per decode slot
             # plus a final row for the chunk, all zero-(trash-)padded to a
             # common width
@@ -624,7 +594,7 @@ def ragged_mixed_attention(
 
             if mesh is None:
                 return call(q, k_pages, v_pages, tabs, kv_lens, q_starts)
-            return _shard_map(
+            return jax.shard_map(
                 call,
                 mesh=mesh,
                 in_specs=(P(None, "model", None), P(None, None, "model"),
@@ -636,6 +606,7 @@ def ragged_mixed_attention(
     # XLA composition: the decode gather and chunk gather reference paths,
     # concatenated — token-identical to the separate-program paths by
     # construction, which is what the mixed-step parity tests pin.
+    _note_impl("ragged attention", "xla")
     dec = paged_attention_decode_xla(
         q[:b], k_pages, v_pages, block_tables, context_lens,
         page_size=page_size, num_kv_heads=n_kv,
@@ -674,23 +645,11 @@ def ragged_verify_attention(
     flips, and until then the XLA composition — verify gather + chunk gather
     — serves every backend. Inactive windows carry zero tables + position 0
     (trash-page rows, outputs discarded by the engine)."""
-    backend = os.environ.get("DYNAMO_TPU_RAGGED_ATTENTION")
-    if not backend:
-        from dynamo_tpu.ops import ragged_attention as _ra
-
-        backend = (_resolve_backend() if _ra.RAGGED_KERNEL_HW_VALIDATED
-                   else "xla")
-    if window is not None or logit_cap:
-        backend = "xla"  # sliding window / softcap: kernel doesn't model them
-    if backend in ("pallas", "pallas_interpret") \
-            and _seq_parallel_mesh() is not None:
-        _note_fallback("ragged attention", "seq_mesh",
-                       "sequence-parallel mesh shards the pool under GSPMD")
-        backend = "xla"
+    backend = _ragged_backend(window, logit_cap)
     n_kv = _pool_kv_heads(k_pages, q.shape[2], num_kv_heads)
     b, k1 = num_verify, verify_width
     c = q.shape[0] - b * k1
-    if backend in ("pallas", "pallas_interpret"):
+    if backend in _KERNEL_BACKENDS:
         quantized = k_pages.dtype == jnp.int8
         lb = _kv_lane_blocks() if quantized else 1
         mesh = _mesh_for_shard_map()
@@ -711,6 +670,7 @@ def ragged_verify_attention(
 
             interp = backend == "pallas_interpret"
             n_kv_call = n_kv // max(tp, 1)
+            _note_impl("ragged attention", backend)
             # unified descriptors: window rows span [pos, pos + K1) so the
             # horizon includes every draft written this step
             pmax = block_tables.shape[1]
@@ -733,7 +693,7 @@ def ragged_verify_attention(
 
             if mesh is None:
                 return call(q, k_pages, v_pages, tabs, kv_lens, q_starts)
-            return _shard_map(
+            return jax.shard_map(
                 call,
                 mesh=mesh,
                 in_specs=(P(None, "model", None), P(None, None, "model"),
@@ -745,6 +705,7 @@ def ragged_verify_attention(
     # XLA composition: the verify gather and chunk gather reference paths,
     # concatenated — token-identical to the separate-program paths by
     # construction (what the mixed-spec parity tests pin).
+    _note_impl("ragged attention", "xla")
     ver = verify_attention(
         q[:b * k1].reshape(b, k1, q.shape[1], q.shape[2]),
         k_pages, v_pages, block_tables, positions,
@@ -836,6 +797,60 @@ def _note_fallback(op: str, reason: str, detail: str = "") -> None:
             f": {detail}" if detail else "")
 
 
+# what the log line says for the reasons every op shares
+_DEMOTION_DETAIL = {
+    "window_softcap": "the kernel models neither sliding windows nor "
+                      "score capping",
+    "seq_mesh": "sequence-parallel mesh shards the pool under GSPMD",
+}
+
+
+def _demote(backend: str, op: str, reason: str, detail: str = "") -> str:
+    """Send `op` to the XLA path. When it had resolved to a kernel
+    (`auto` on a TPU, or an explicit pallas*), that is a demotion and is
+    counted; when it was XLA already, nothing happened."""
+    if backend in _KERNEL_BACKENDS:
+        _note_fallback(op, reason,
+                       detail or _DEMOTION_DETAIL.get(reason, ""))
+    return "xla"
+
+
+def _ragged_backend(window, logit_cap) -> str:
+    """Backend for the two ragged ops: DYNAMO_TPU_RAGGED_ATTENTION wins
+    when set; otherwise the scoped backend, demoted (visibly) to the XLA
+    composition until RAGGED_KERNEL_HW_VALIDATED flips."""
+    op = "ragged attention"
+    backend = os.environ.get("DYNAMO_TPU_RAGGED_ATTENTION")
+    if not backend:
+        from dynamo_tpu.ops import ragged_attention as _ra
+
+        backend = _resolve_backend()
+        if not _ra.RAGGED_KERNEL_HW_VALIDATED:
+            backend = _demote(backend, op, "not_validated",
+                              "RAGGED_KERNEL_HW_VALIDATED is False; set "
+                              "DYNAMO_TPU_RAGGED_ATTENTION=pallas to force")
+    if window is not None or logit_cap:
+        backend = _demote(backend, op, "window_softcap")
+    if _seq_parallel_mesh() is not None:
+        backend = _demote(backend, op, "seq_mesh")
+    return backend
+
+
+# Which implementation each op was TRACED with: {(op, impl): traces}, impl
+# in {pallas, pallas_interpret, xla}. Like the fallback counts this is
+# per compiled program, not per step. /worker/stats carries it so a smoke
+# or a benchmark row can say what actually ran.
+_IMPL_COUNTS: dict = {}
+
+
+def _note_impl(op: str, impl: str) -> None:
+    _IMPL_COUNTS[(op, impl)] = _IMPL_COUNTS.get((op, impl), 0) + 1
+
+
+def attention_impl_counts() -> dict:
+    return dict(_IMPL_COUNTS)
+
+
 def pallas_fallback_counts() -> dict:
     """{(op, reason): trace-time demotion count}; exported by
     observability/engine_metrics.attach_engine_metrics."""
@@ -881,14 +896,12 @@ def paged_attention_decode(
     backend = _resolve_backend()
     windowed = window is not None or bool(logit_cap)
     if windowed:
-        backend = "xla"  # sliding window / softcap: kernel doesn't model them
-    if backend != "xla" and _seq_parallel_mesh() is not None:
+        backend = _demote(backend, "decode", "window_softcap")
+    if _seq_parallel_mesh() is not None:
         # long-context (seq) mesh: the pool is GSPMD-sharded on `model`,
         # and an unannotated pallas_call would force an all-gather of the
         # whole pool per step — the XLA gather path partitions cleanly
-        _note_fallback("decode", "seq_mesh",
-                       "sequence-parallel mesh shards the pool under GSPMD")
-        backend = "xla"
+        backend = _demote(backend, "decode", "seq_mesh")
     mesh = _mesh_for_shard_map()
     if windowed:
         # the traced per-layer `window` scalar can't be closed over by an
@@ -914,7 +927,7 @@ def paged_attention_decode(
         # 128-aligned by construction and would always pass.
         span = n_kv * q.shape[2] if quantized else k_pages.shape[2]
         if not _pallas_lane_gate(span, _mesh_tp(mesh), "decode"):
-            backend = "xla"
+            backend = "xla"  # counted by the gate
     if quantized and backend != "xla" and lb != max(_mesh_tp(mesh), 1):
         # the Pallas kernel reads SINGLE-block rows: the shard_map split
         # count must equal the layout blocking (each shard then sees its own
@@ -927,6 +940,7 @@ def paged_attention_decode(
     tp_eff = _mesh_tp(mesh)
     n_kv_call = n_kv // tp_eff  # per-shard KV heads seen by the inner call
     lb_call = lb // tp_eff if quantized else 1
+    _note_impl("decode", backend)
     if backend == "xla":
         def call(q, kp, vp, bt, cl):
             return paged_attention_decode_xla(
@@ -951,7 +965,7 @@ def paged_attention_decode(
         return call(q, k_pages, v_pages, block_table, context_lens)
     # Heads (the fused KV*D lane axis) shard on `model`, batch on `data`:
     # attention is embarrassingly parallel over both — no collectives inside.
-    return _shard_map(
+    return jax.shard_map(
         call,
         mesh=mesh,
         in_specs=(
@@ -983,6 +997,8 @@ def prefill_attention(
             "sequence-parallel prefill does not support sliding-window/"
             "softcap models")
     if window is not None or logit_cap:
+        _demote(_resolve_backend(), "prefill", "window_softcap")
+        _note_impl("prefill", "xla")
         return prefill_attention_xla(q, k, v, seq_len, window=window,
                                      logit_cap=logit_cap)
     if sp_mesh is not None:
@@ -1023,19 +1039,19 @@ def prefill_attention(
             q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
             k = jnp.pad(k, ((0, pad), (0, 0), (0, 0)))
             v = jnp.pad(v, ((0, pad), (0, 0), (0, 0)))
+        # ring/Ulysses are jnp collectives, not the flash kernel
+        _demote(_resolve_backend(), "prefill", "seq_mesh",
+                "sequence-parallel prefill runs ring/Ulysses attention")
+        _note_impl("prefill", strategy)
         out = fn(q, k, v, seq_len, sp_mesh)
         return out[:s] if pad else out
     backend = _resolve_backend()
-    if backend != "xla" and q.shape[2] % 128 != 0 and q.shape[2] not in (32, 64):
+    if q.shape[2] % 128 != 0 and q.shape[2] not in (32, 64):
         # e.g. MLA's latent width (kv_lora_rank + rope = 576): no Mosaic
         # tiling for off-size trailing dims — serve via XLA
-        if _explicit_backend() is not None:
-            import logging
-
-            logging.getLogger("dynamo_tpu.ops").warning(
-                "pallas prefill needs a tileable head dim (got %d); using "
-                "the XLA path", q.shape[2])
-        backend = "xla"
+        backend = _demote(backend, "prefill", "head_dim",
+                          f"no Mosaic tiling for head dim {q.shape[2]}")
+    _note_impl("prefill", backend)
     if backend == "xla":
         return prefill_attention_xla(q, k, v, seq_len)
     from dynamo_tpu.ops import pallas_attention as pa
@@ -1047,12 +1063,12 @@ def prefill_attention(
 
     mesh = _mesh_for_shard_map()
     tp = _mesh_tp(mesh)
-    if tp > 1 and (q.shape[1] % tp != 0 or k.shape[1] % tp != 0):
+    if not _pallas_head_gate(q.shape[1], k.shape[1], tp, "prefill"):
         mesh = None  # heads not divisible: GSPMD auto-shards instead
     if mesh is None:
         return call(q, k, v, jnp.asarray(seq_len, jnp.int32))
     # Prefill is single-sequence: replicated over `data`, heads on `model`.
-    return _shard_map(
+    return jax.shard_map(
         call,
         mesh=mesh,
         in_specs=(

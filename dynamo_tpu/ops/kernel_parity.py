@@ -1,0 +1,251 @@
+"""On-chip compile + parity check of every Pallas attention kernel.
+
+Interpret mode proves a kernel's arithmetic; only Mosaic on a real chip
+proves it lowers. This module compiles each kernel at a serving model's
+head shapes and compares it with its XLA twin from `ops/attention.py`:
+
+    python -m dynamo_tpu.ops.kernel_parity            # on the chip
+    python -m dynamo_tpu.ops.kernel_parity --interpret  # CPU rehearsal
+
+One JSON line per case: `compiled` (with max_abs_err), `parity_error`
+(compiled, but disagrees with the twin or is non-finite) or `refused`
+(lowering/compile raised; Mosaic's message is kept). The last line is the
+summary, also written to `chiprun_out/kernel_parity.json`. Exit code 1 if
+any case is not `compiled`.
+
+Run it once per PR that touches a kernel, outside any timed window.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import json
+import os
+import sys
+from typing import Callable, Dict, List, Tuple
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from dynamo_tpu.ops import attention as att
+from dynamo_tpu.ops import pallas_attention as pa
+
+# bf16 pools/queries with unit-normal values: outputs are convex averages
+# of |v| <~ 4, the XLA twins round scores and probabilities to bf16
+# (eps 2^-8) where the kernels accumulate in f32. 0.05 absolute is ~3x the
+# worst disagreement that rounding alone explains and far below what a
+# wrong mask, page or head mapping produces (O(1)).
+TOLERANCE = 0.05
+
+PAGE_SIZE = 16
+HEAD_DIM = 128
+NUM_POOL_PAGES = 96
+
+OUT_PATH = os.path.join("chiprun_out", "kernel_parity.json")
+
+# (label, query heads, KV heads): qwen2.5-7b whole, and one tp=4 shard
+SHAPES: Tuple[Tuple[str, int, int], ...] = (
+    ("28q4kv", 28, 4),
+    ("7q1kv", 7, 1),
+)
+
+
+def _pools(rng, n_kv: int, quantized: bool):
+    n = NUM_POOL_PAGES * PAGE_SIZE
+    kf = rng.normal(size=(n, n_kv, HEAD_DIM)).astype(np.float32)
+    vf = rng.normal(size=(n, n_kv, HEAD_DIM)).astype(np.float32)
+    if quantized:
+        w = att.kv_lane_width(n_kv, HEAD_DIM, True)
+        return (att.pack_kv_rows(jnp.asarray(kf), w).reshape(
+                    NUM_POOL_PAGES, PAGE_SIZE, w),
+                att.pack_kv_rows(jnp.asarray(vf), w).reshape(
+                    NUM_POOL_PAGES, PAGE_SIZE, w))
+    shape = (NUM_POOL_PAGES, PAGE_SIZE, n_kv * HEAD_DIM)
+    return (jnp.asarray(kf.reshape(shape), jnp.bfloat16),
+            jnp.asarray(vf.reshape(shape), jnp.bfloat16))
+
+
+def _decode_tables(b: int = 8, pmax: int = 12):
+    """Disjoint page tables with contexts that hit 1 token, mid-page,
+    page-exact and table-full rows."""
+    tables = np.zeros((b, pmax), np.int32)
+    ctx = [1, 21, 96, 40, 7, 64, 33, pmax * PAGE_SIZE][:b]
+    nxt = 1
+    for i, c in enumerate(ctx):
+        n = -(-c // PAGE_SIZE)
+        tables[i, :n] = np.arange(nxt, nxt + n) % (NUM_POOL_PAGES - 1) + 1
+        nxt += n
+    return jnp.asarray(tables), jnp.asarray(ctx, jnp.int32)
+
+
+def _case_decode(h: int, n_kv: int, quantized: bool, interpret: bool):
+    rng = np.random.default_rng(9)
+    kp, vp = _pools(rng, n_kv, quantized)
+    bt, cl = _decode_tables()
+    q = jnp.asarray(rng.normal(size=(bt.shape[0], h, HEAD_DIM)), jnp.bfloat16)
+    ref = jax.jit(lambda *a: att.paged_attention_decode_xla(
+        *a, page_size=PAGE_SIZE, num_kv_heads=n_kv, lane_blocks=1))
+    ker = jax.jit(lambda *a: pa.paged_attention_decode(
+        *a, page_size=PAGE_SIZE, num_kv_heads=n_kv, interpret=interpret))
+    args = (q, kp, vp, bt, cl)
+    return ker, ref, args
+
+
+def _case_prefill(h: int, n_kv: int, s: int, interpret: bool):
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.normal(size=(s, h, HEAD_DIM)), jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(s, n_kv, HEAD_DIM)), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(s, n_kv, HEAD_DIM)), jnp.bfloat16)
+    sl = jnp.asarray(max(1, s - 5), jnp.int32)  # padded tail is masked
+    ref = jax.jit(lambda q, k, v, sl: att.prefill_attention_xla(q, k, v, sl))
+    ker = jax.jit(lambda q, k, v, sl: pa.prefill_attention(
+        q, k, v, sl, interpret=interpret))
+    return ker, ref, (q, k, v, sl)
+
+
+def _chunk_pages(width: int = 32, used: int = 24):
+    return jnp.asarray(list(range(40, 40 + used)) + [0] * (width - used),
+                       jnp.int32)
+
+
+def _case_chunk(h: int, n_kv: int, quantized: bool, interpret: bool):
+    """A 256-token chunk starting mid-prompt at token 128 (page 8)."""
+    rng = np.random.default_rng(5)
+    kp, vp = _pools(rng, n_kv, quantized)
+    q = jnp.asarray(rng.normal(size=(256, h, HEAD_DIM)), jnp.bfloat16)
+    pages, start = _chunk_pages(), jnp.asarray(128, jnp.int32)
+    ref = jax.jit(lambda *a: att.chunk_attention_xla(
+        *a, page_size=PAGE_SIZE, num_kv_heads=n_kv))
+    ker = jax.jit(lambda *a: pa.chunk_prefill_attention(
+        *a, page_size=PAGE_SIZE, num_kv_heads=n_kv, interpret=interpret))
+    return ker, ref, (q, kp, vp, pages, start)
+
+
+def _case_ragged(h: int, n_kv: int, quantized: bool, decode_q: int,
+                 interpret: bool):
+    """8 leading rows (decode_q=1: decode slots; >1: speculative verify
+    windows) plus one 256-token chunk, through the SAME descriptor
+    construction the dispatcher uses (`attention.ragged_*_attention` with
+    the kernel forced by env) against the XLA composition."""
+    rng = np.random.default_rng(17)
+    kp, vp = _pools(rng, n_kv, quantized)
+    bt, cl = _decode_tables()
+    b = bt.shape[0]
+    q = jnp.asarray(rng.normal(size=(b * decode_q + 256, h, HEAD_DIM)),
+                    jnp.bfloat16)
+    pages, start = _chunk_pages(), jnp.asarray(128, jnp.int32)
+    if decode_q == 1:
+        def op(*a):
+            return att.ragged_mixed_attention(
+                *a, page_size=PAGE_SIZE, num_kv_heads=n_kv, num_decode=b)
+        args = (q, kp, vp, bt, cl, pages, start)
+    else:
+        def op(*a):
+            return att.ragged_verify_attention(
+                *a, page_size=PAGE_SIZE, num_kv_heads=n_kv, num_verify=b,
+                verify_width=decode_q)
+        # window b's first query sits at position ctx-1; its K1 queries
+        # need ctx-1+K1 tokens inside the table, so cap the full row
+        pos = jnp.minimum(cl - 1, bt.shape[1] * PAGE_SIZE - decode_q)
+        args = (q, kp, vp, bt, pos, pages, start)
+
+    def forced(backend: str) -> Callable:
+        # a fresh function object per backend: jit's trace cache is keyed
+        # on the function, and the env var is read at trace time
+        fn = jax.jit(lambda *a: op(*a))
+
+        def run(*a):
+            with mock.patch.dict(
+                    os.environ, {"DYNAMO_TPU_RAGGED_ATTENTION": backend}):
+                return fn(*a)
+        return run
+
+    return (forced("pallas_interpret" if interpret else "pallas"),
+            forced("xla"), args)
+
+
+def cases(interpret: bool) -> List[Tuple[str, Callable]]:
+    out: List[Tuple[str, Callable]] = []
+    for label, h, n_kv in SHAPES:
+        def add(name, fn, *a):
+            out.append((f"{name}/{label}",
+                        functools.partial(fn, *a, interpret)))
+        add("decode_bf16", _case_decode, h, n_kv, False)
+        add("decode_int8kv", _case_decode, h, n_kv, True)
+        add("prefill_s16", _case_prefill, h, n_kv, 16)
+        add("prefill_s256", _case_prefill, h, n_kv, 256)
+        add("chunk_bf16", _case_chunk, h, n_kv, False)
+        add("chunk_int8kv", _case_chunk, h, n_kv, True)
+        add("ragged_mixed_bf16", _case_ragged, h, n_kv, False, 1)
+        add("ragged_mixed_int8kv", _case_ragged, h, n_kv, True, 1)
+        add("ragged_verify_q5_bf16", _case_ragged, h, n_kv, False, 5)
+    return out
+
+
+def run_case(name: str, build: Callable) -> Dict[str, object]:
+    row: Dict[str, object] = {"case": name}
+    try:
+        ker, ref, args = build()
+        want = np.asarray(ref(*args).astype(jnp.float32))
+    except Exception as e:  # the XLA twin itself failed: not a kernel verdict
+        row.update(outcome="reference_failed", error=_short(e))
+        return row
+    try:
+        got = np.asarray(ker(*args).astype(jnp.float32))
+    except Exception as e:
+        row.update(outcome="refused", error=_short(e))
+        return row
+    err = float(np.max(np.abs(got - want)))
+    finite = bool(np.all(np.isfinite(got)))
+    row.update(max_abs_err=round(err, 5), tolerance=TOLERANCE,
+               outcome="compiled" if finite and err < TOLERANCE
+               else "parity_error")
+    return row
+
+
+def _short(e: BaseException, limit: int = 600) -> str:
+    text = f"{type(e).__name__}: {e}"
+    return text if len(text) <= limit else text[:limit] + " …"
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser(prog="dynamo_tpu.ops.kernel_parity")
+    p.add_argument("--interpret", action="store_true",
+                   help="CPU rehearsal through the Pallas interpreter "
+                        "(proves arithmetic, not Mosaic lowering)")
+    args = p.parse_args(argv)
+
+    from dynamo_tpu.utils.platform import init_backend
+
+    platform = init_backend()
+    if args.interpret != (platform == "cpu"):
+        print(f"kernel_parity: --interpret is for the CPU and only the CPU "
+              f"(platform {platform!r})", file=sys.stderr)
+        return 2
+    rows = []
+    for name, build in cases(args.interpret):
+        row = run_case(name, build)
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    dev = jax.devices()[0]
+    summary = {
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "interpret": args.interpret,
+        "cases": len(rows),
+        "compiled": sum(r["outcome"] == "compiled" for r in rows),
+        "not_compiled": [r["case"] for r in rows
+                         if r["outcome"] != "compiled"],
+    }
+    os.makedirs(os.path.dirname(OUT_PATH), exist_ok=True)
+    with open(OUT_PATH, "w") as f:
+        json.dump({"summary": summary, "rows": rows}, f, indent=1)
+    print(json.dumps(summary), flush=True)
+    return 0 if not summary["not_compiled"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -48,19 +48,14 @@ attends over its local KV-head lane span.
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed upstream (TPUCompilerParams -> CompilerParams); accept both
-_CompilerParams = getattr(pltpu, 'CompilerParams', None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = float("-inf")
-
-import os
 
 
 def _env_int(name: str, default: int, lo: int) -> int:
@@ -80,17 +75,18 @@ def _env_int(name: str, default: int, lo: int) -> int:
 
 # Chunk-prefill kernel hardware-validation flag: while False, chunked
 # prefill defaults to the XLA gather path unless DYNAMO_TPU_CHUNK_ATTENTION
-# explicitly selects the kernel. Flipped True after the round-5 battery's
-# chunk_kernel_parity case passed on a real chip (interpret mode cannot
-# validate Mosaic lowering): bench_results/tpu_battery_r05.jsonl,
-# 2026-07-31T03:48:20Z, max_abs_err 0.0098 (bf16 tolerance) vs the XLA
-# gather path. Selection now follows the engine's attention backend like
-# the decode/prefill ops.
+# explicitly selects the kernel (interpret mode cannot validate Mosaic
+# lowering). True: the kernel compiles under jaxlib 0.9.0's Mosaic on a v5e
+# and agrees with the XLA gather path within bf16 tolerance at 28/4 and 7/1
+# heads (ops/kernel_parity.py; outcomes in PERF.md). Selection follows the
+# engine's attention backend like the decode/prefill ops.
 CHUNK_KERNEL_HW_VALIDATED = True
 
-# The chunk kernel's int8-KV dequant path was NOT covered by that bf16
-# parity case; it stays env-opt-in (DYNAMO_TPU_CHUNK_ATTENTION=pallas)
-# until the battery's chunk_kernel_int8_parity case passes on chip.
+# The int8-KV dequant-in-chunk path compiles and passes the same on-chip
+# parity check, but its default does not flip on a parity run: a changed
+# default path is judged on a benchmark cell (ROADMAP S3/S4). Until then it
+# is env-opt-in (DYNAMO_TPU_CHUNK_ATTENTION=pallas) and the demotion is
+# counted in dynamo_pallas_fallback_total.
 CHUNK_KERNEL_INT8_HW_VALIDATED = False
 
 # pages per decode superblock (tokens per block = this * page_size);
@@ -404,7 +400,7 @@ def paged_attention_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, n_heads, head_dim), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # sequential on purpose: the DMA pipeline carries state across
             # grid steps (see module docstring)
             dimension_semantics=("arbitrary", "arbitrary"),
@@ -539,7 +535,7 @@ def prefill_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_kv, group, s_pad, head_dim), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -772,7 +768,7 @@ def chunk_prefill_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nq, block_q, n_heads, head_dim),
                                        q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
         interpret=interpret,

@@ -38,9 +38,11 @@ running max is finite from the first `_flash_update` on.
 
 Hardware-validation gating follows the CHUNK_KERNEL convention: while
 `RAGGED_KERNEL_HW_VALIDATED` is False the dispatch in `attention.py` keeps
-the XLA composition as default and the kernel is env-opt-in
-(`DYNAMO_TPU_RAGGED_ATTENTION=pallas`); interpret mode cannot validate the
-Mosaic lowering, only an on-chip parity battery can flip the flag.
+the XLA composition as default (counted in dynamo_pallas_fallback_total) and
+the kernel is env-opt-in (`DYNAMO_TPU_RAGGED_ATTENTION=pallas`). The kernel
+compiles on a v5e and passes parity there, mixed and verify (PERF.md); what
+is still missing is a benchmark cell that judges it as the default step
+(ROADMAP S4).
 """
 
 from __future__ import annotations
@@ -56,17 +58,17 @@ from dynamo_tpu.ops.pallas_attention import (
     DEFAULT_BLOCK_PAGES,
     DEFAULT_NUM_BUFS,
     NEG_INF,
-    _CompilerParams,
     _dequant_rows,
     _flash_normalize,
     _flash_reset,
     _flash_update,
 )
 
-# Flipped True once the TPU battery's ragged_kernel_parity case (mixed
-# decode+chunk batch vs the XLA composition, bf16 and int8) passes on a real
-# chip. Until then `ragged_mixed_attention` defaults to the XLA path on every
-# backend and DYNAMO_TPU_RAGGED_ATTENTION=pallas opts in for the battery run.
+# Flipped by ROADMAP S4, on a cell: the on-chip parity check
+# (ops/kernel_parity.py) already passes for mixed decode+chunk batches, bf16
+# and int8-KV, and for decode_q verify windows. Until then the ragged ops
+# default to the XLA composition on every backend and
+# DYNAMO_TPU_RAGGED_ATTENTION=pallas opts in.
 RAGGED_KERNEL_HW_VALIDATED = False
 
 
@@ -332,7 +334,7 @@ def ragged_paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nbq, block_q, n_heads, head_dim),
                                        q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # sequential on purpose: the DMA pipeline carries state across
             # grid steps (see module docstring)
             dimension_semantics=("arbitrary", "arbitrary"),

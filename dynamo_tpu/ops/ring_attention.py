@@ -30,16 +30,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-try:  # top-level alias exists on newer jax only
-    _shard_map = jax.shard_map
-except AttributeError:  # pre-0.6 spelling (and check_vma was check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def _shard_map(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_impl(f, **kw)
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -143,7 +133,7 @@ def ring_prefill_attention(
     fn = functools.partial(
         _ring_attention_local, axis_name=seq_axis, causal=causal
     )
-    return _shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(
@@ -221,7 +211,7 @@ def ulysses_prefill_attention(
     """
     ha = _head_axis(mesh, head_axis)
     fn = functools.partial(_ulysses_local, axis_name=seq_axis, causal=causal)
-    return _shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(

@@ -13,11 +13,14 @@ the mesh axis ordering matches the physical device order
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
+
+log = logging.getLogger("dynamo_tpu.mesh")
 
 AXES = ("data", "expert", "model")
 
@@ -42,7 +45,8 @@ def build_mesh(
     The `model` axis is innermost so tensor-parallel collectives ride the
     fastest ICI links (nearest-neighbour on the torus).
     """
-    devices = list(devices if devices is not None else jax.devices())
+    explicit = devices is not None
+    devices = list(devices if explicit else jax.devices())
     n = cfg.num_devices
     if n > len(devices):
         raise ValueError(
@@ -51,13 +55,28 @@ def build_mesh(
             f"only {len(devices)} available"
         )
     shape = (cfg.data_parallel, cfg.expert_parallel, cfg.tensor_parallel)
-    try:
-        from jax.experimental import mesh_utils
+    return Mesh(_device_grid(shape, devices[:n], explicit), AXES)
 
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices[:n])
-    except Exception:
-        dev_array = np.array(devices[:n]).reshape(shape)
-    return Mesh(dev_array, AXES)
+
+def _device_grid(shape, devices, explicit: bool) -> np.ndarray:
+    """Devices arranged as `shape` in the order that puts the innermost
+    mesh axis on the fastest ICI links. A topology error is an error —
+    except in the one documented case: the caller handed over its OWN
+    device list (colocated disagg roles on disjoint sub-meshes of one
+    slice) and that subset is not a contiguous cuboid of the torus, so no
+    topology-aware order exists for it. Then the caller's order is used,
+    and said so."""
+    from jax.experimental import mesh_utils
+
+    try:
+        return mesh_utils.create_device_mesh(shape, devices=devices)
+    except (AssertionError, NotImplementedError) as e:
+        if not explicit:
+            raise
+        log.warning(
+            "no topology-aware order for the explicit device subset %s "
+            "(%s); using the order given", [d.id for d in devices], e)
+        return np.array(devices).reshape(shape)
 
 
 def single_device_mesh() -> Mesh:
@@ -79,7 +98,8 @@ def build_long_context_mesh(
     long-context prefill path (dynamo_tpu.ops.ring_attention), which the
     reference has no analogue for (SURVEY.md §5).
     """
-    devices = list(devices if devices is not None else jax.devices())
+    explicit = devices is not None
+    devices = list(devices if explicit else jax.devices())
     n = sequence_parallel * tensor_parallel
     if n > len(devices):
         raise ValueError(
@@ -87,10 +107,4 @@ def build_long_context_mesh(
             f"tp={tensor_parallel}), only {len(devices)} available"
         )
     shape = (sequence_parallel, tensor_parallel)
-    try:
-        from jax.experimental import mesh_utils
-
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices[:n])
-    except Exception:
-        dev_array = np.array(devices[:n]).reshape(shape)
-    return Mesh(dev_array, LONG_CONTEXT_AXES)
+    return Mesh(_device_grid(shape, devices[:n], explicit), LONG_CONTEXT_AXES)

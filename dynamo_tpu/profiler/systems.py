@@ -25,6 +25,9 @@ class ChipSpec:
     ici_link_bw: float         # one-direction ICI bandwidth per link, bytes/s
     ici_links: int             # ICI links per chip (torus degree)
     chips_per_host: int = 4    # chips attached to one host VM (pod slices)
+    # board power used ONLY by the exporter's modeled tpu_power_usage_watts
+    # series (label source="modeled"); an estimate, not a datasheet peak
+    tdp_w: float = 0.0
 
     @property
     def ici_bisection_bw(self) -> float:
@@ -32,12 +35,16 @@ class ChipSpec:
         return self.ici_link_bw * self.ici_links
 
 
-# Public datasheet numbers (cloud.google.com/tpu/docs/system-architecture).
+# Source: Google Cloud TPU documentation, one page per generation
+# (cloud.google.com/tpu/docs/v4, /v5e, /v5p, /v6e): peak bf16 FLOP/s, HBM
+# capacity and HBM bandwidth per chip as published there (v5e: 197
+# TFLOP/s, 16 GB, 819 GB/s). ICI numbers are per-link planning values from
+# the same pages' interconnect totals.
 CHIPS: Dict[str, ChipSpec] = {
-    "v4": ChipSpec("v4", 275e12, 32 * GiB, 1.2e12, 4.5e10, 6, 4),
-    "v5e": ChipSpec("v5e", 197e12, 16 * GiB, 8.19e11, 4.5e10, 4, 8),
-    "v5p": ChipSpec("v5p", 459e12, 95 * GiB, 2.765e12, 9.0e10, 6, 4),
-    "v6e": ChipSpec("v6e", 918e12, 32 * GiB, 1.64e12, 9.0e10, 4, 8),
+    "v4": ChipSpec("v4", 275e12, 32 * GiB, 1.2e12, 4.5e10, 6, 4, 170.0),
+    "v5e": ChipSpec("v5e", 197e12, 16 * GiB, 8.19e11, 4.5e10, 4, 8, 170.0),
+    "v5p": ChipSpec("v5p", 459e12, 95 * GiB, 2.765e12, 9.0e10, 6, 4, 350.0),
+    "v6e": ChipSpec("v6e", 918e12, 32 * GiB, 1.64e12, 9.0e10, 4, 8, 200.0),
 }
 
 
@@ -78,9 +85,11 @@ SYSTEMS: Dict[str, SystemSpec] = {
 _SYSTEM_RE = re.compile(r"^(v\d+[ep]?)-(\d+)$")
 
 
-# device_kind regexes (jax `device.device_kind` strings) -> chip catalog
-# names; shared by bench.py and the live MFU/MBU exposition
-# (observability/engine_metrics.py) so both map hardware the same way
+# jax `device.device_kind` strings -> chip catalog names. THE table: the
+# benchmark, the live MFU/MBU exposition (observability/engine_metrics.py),
+# the KVBM cost gate (kvbm/cost_model.py), the hardware exporter and
+# chip_smoke.py all map hardware through it. A v5e reports itself as
+# "TPU v5 lite"; the short forms cover `DYNAMO_TPU_CHIP`-style names.
 _DEVICE_KIND_PATTERNS = (
     (r"v5 ?lite|v5e", "v5e"), (r"v5p|v5 ?pod", "v5p"),
     (r"v6e|v6 ?lite|trillium", "v6e"), (r"v4", "v4"),
@@ -88,13 +97,26 @@ _DEVICE_KIND_PATTERNS = (
 
 
 def chip_for_device_kind(kind: str) -> "ChipSpec | None":
-    """Map a jax `device_kind` string onto the chip catalog (None if
-    unknown — e.g. the CPU fallback backend)."""
+    """Map a jax `device_kind` string onto the chip catalog; None when it
+    names no chip in the table (a CPU, or a TPU nobody has entered yet).
+    Callers that need a peak use `require_chip`."""
     kind = (kind or "").lower()
     for pat, name in _DEVICE_KIND_PATTERNS:
         if re.search(pat, kind):
             return CHIPS[name]
     return None
+
+
+def require_chip(kind: str) -> ChipSpec:
+    """`chip_for_device_kind` for callers that need a peak number: a device
+    that is not in the table is an error, never a default."""
+    chip = chip_for_device_kind(kind)
+    if chip is None:
+        raise KeyError(
+            f"device kind {kind!r} is not in the chip table "
+            f"(profiler/systems.py CHIPS: {sorted(CHIPS)}); add its "
+            f"datasheet peaks there before using them")
+    return chip
 
 
 def get_system(name: str) -> SystemSpec:

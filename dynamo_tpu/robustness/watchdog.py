@@ -56,11 +56,16 @@ Env knobs (registered in dynalint KNOWN_ENV):
 
 - ``DYNAMO_TPU_STEP_DEADLINE_S`` — hard per-seam deadline override;
   unset derives ``max(floor, ewma * margin)`` from observed seam times.
-  The derived deadline only arms on real accelerators
-  (``derive_deadline``): on the CPU fallback a mid-seam XLA recompile
-  routinely dwarfs any measured EWMA (there is no AOT warmup guarantee
-  off-TPU), so without an explicit override the monitor observes but
-  never trips there — CI drills set the override;
+  The derived deadline rests on one guarantee: a warmed engine never
+  compiles inside a seam.  A first ``jit`` call compiles inside its
+  ``dispatch`` seam — tens of seconds per program at 7B widths, which
+  would read as two hangs and a quarantine before ``/ready`` — so the
+  engine arms it (``derive_deadline``) only once an ``Engine.warmup()``
+  has completed, and only on a real accelerator: an engine that was
+  never warmed (``--no-warmup``, ``bench.py``'s step-to-warm loops) and
+  CPU runs (tests, local development) compile mid-seam at will.  While
+  it is off, seams neither trip it nor feed the EWMA.  An explicit
+  override trips everywhere, warmup included — CI drills set it;
 - ``DYNAMO_TPU_QUARANTINE_WINDOW_S`` (default 300) — two trips inside
   this window quarantine the worker permanently;
 - ``DYNAMO_TPU_INTEGRITY`` (default ``logits``) — sentinel tier.
@@ -164,8 +169,10 @@ class EngineWatchdog:
                  clock: Callable[[], float] = time.monotonic):
         self.engine = engine
         self._clock = clock
-        # False = only an explicit override (env/ctor/test) ever trips
-        # the monitor; the EWMA still accumulates for observability
+        # False = seams may hold a compilation: only an explicit override
+        # (env/ctor/test) trips the monitor and no seam feeds the EWMA.
+        # The engine flips it on when a warmup() completes on a real
+        # accelerator (Engine.warmup)
         self.derive_deadline = derive_deadline
         self._deadline_override = (deadline_s if deadline_s is not None
                                    else _env_deadline())
@@ -244,9 +251,11 @@ class EngineWatchdog:
         with self._lock:
             armed = self._armed
             self._armed = None
-            if armed is None or armed[2]:
-                # nothing armed, or this seam already tripped — a late
-                # return from a tripped seam must not poison the EWMA
+            if armed is None or armed[2] or not self.derive_deadline:
+                # nothing armed, this seam already tripped (a late return
+                # from a tripped seam must not poison the EWMA), or the
+                # seam may have held a compilation (minutes, not the
+                # milliseconds the deadline is derived from)
                 return
             dt = max(0.0, now - armed[1])
             if self._ewma_s is None:
@@ -280,9 +289,8 @@ class EngineWatchdog:
         idle_since: Optional[float] = None
         while not self._stop.is_set():
             deadline = self.deadline_s()
-            # derived deadlines only arm on real accelerators: a CPU
-            # fallback recompiles mid-seam at will, so without an
-            # explicit override the monitor observes but never trips
+            # the derived deadline arms only on a warmed engine on a real
+            # accelerator; an explicit override always arms
             armable = (self._deadline_override is not None
                        or self.derive_deadline)
             tripped_seam = None

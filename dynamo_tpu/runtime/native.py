@@ -1,9 +1,12 @@
 """ctypes binding + on-demand build of the native transport library.
 
-The shared object is compiled once into a per-user cache dir (g++ is in the
-image; pybind11 is not, hence the plain C ABI). A build failure degrades to
-`lib = None`; the transfer layer then uses its pure-Python socket fallback
-with identical wire format, so functionality never depends on a compiler.
+The shared object is compiled once, keyed by the source's content hash, into
+the program's build home (`utils/platform.build_home()`: inside the checkout,
+git-ignored; `DYNAMO_TPU_BUILD_DIR` places it elsewhere, e.g. an image
+layer). g++ is in the image; pybind11 is not, hence the plain C ABI. A build
+failure degrades to `lib = None`; the transfer layer then uses its
+pure-Python socket fallback with identical wire format, so functionality
+never depends on a compiler.
 """
 
 from __future__ import annotations
@@ -28,10 +31,10 @@ _router_tried = False
 
 
 def _build_dir() -> str:
-    d = os.environ.get(
-        "DYNAMO_TPU_BUILD_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "dynamo_tpu", "native"),
-    )
+    from dynamo_tpu.utils.platform import build_home
+
+    d = os.environ.get("DYNAMO_TPU_BUILD_DIR",
+                       os.path.join(build_home(), "native"))
     os.makedirs(d, exist_ok=True)
     return d
 

@@ -22,6 +22,7 @@ from dynamo_tpu.engine.kv_cache import OutOfPages
 from dynamo_tpu.engine.request import GenRequest
 from dynamo_tpu.engine.tokenizer import get_tokenizer
 from dynamo_tpu.observability import context as obs_context
+from dynamo_tpu.observability.engine_metrics import device_report
 from dynamo_tpu.observability import slo as obs_slo
 from dynamo_tpu.observability import tracing as obs_tracing
 from dynamo_tpu.robustness import faults
@@ -1198,6 +1199,10 @@ class _Handler(JsonHTTPHandler):
                 "total_pages": eng.cfg.num_pages,
                 "max_num_seqs": eng.cfg.max_num_seqs,
                 "disaggregation_mode": eng.cfg.disaggregation_mode,
+                # platform / device_kind / device_count as JAX reports
+                # them, versions, compiled programs and the attention
+                # implementations traced
+                **device_report(eng),
                 # watchdog health state machine + trip/sentinel counters
                 # (the same summary the heartbeat carries to frontends)
                 "health": eng.watchdog.summary(),

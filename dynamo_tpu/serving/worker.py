@@ -184,24 +184,12 @@ def main(argv=None, backend_name: str = "jetstream") -> None:
 
     if backend_name == "trtllm_tpu":
         # the compiled-engine contract: refuse to serve without an explicit
-        # engine-build config, and persist compiled programs so a restart
-        # "loads the engine" instead of rebuilding it
+        # engine-build config (every profile persists compiled programs
+        # through enable_compile_cache below, so a restart "loads the
+        # engine" instead of rebuilding it)
         if not getattr(args, "engine_config", None):
             p.error("--engine-config FILE is required for the trtllm_tpu "
                     "backend (the TRT engine-build config analogue)")
-        # jax is already imported by this point, so the env var would be a
-        # no-op — set the config knob directly (env var still wins if the
-        # operator configured one)
-        import jax
-
-        if not (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-                or jax.config.jax_compilation_cache_dir):
-            jax.config.update(
-                "jax_compilation_cache_dir",
-                os.path.join(os.path.expanduser("~"), ".cache", "dynamo_tpu",
-                             "engine-cache"),
-            )
-
     cfg = EngineConfig.from_cli_args(args)
     if backend_name == "trtllm_tpu" and not cfg.warmup:
         # the profile's defining contract (docs/backends.md): /ready never
@@ -215,14 +203,17 @@ def main(argv=None, backend_name: str = "jetstream") -> None:
     dist_cfg = dist.resolve(args.coordinator, args.num_processes,
                             args.process_id)
     dist.initialize(dist_cfg)  # must precede the first backend touch
-    from dynamo_tpu.utils.platform import init_backend_with_fallback
+    from dynamo_tpu.utils.platform import enable_compile_cache, init_backend
 
-    backend = init_backend_with_fallback()
+    # this process owns the chip from here on: one in-process init, no
+    # probe child, no CPU fallback (utils/platform.py)
+    backend = init_backend()
+    cache_dir = enable_compile_cache()
     log.info("starting %s worker: model=%s mode=%s tp=%d backend=%s "
-             "process=%d/%d",
+             "process=%d/%d compile_cache=%s",
              backend_name, cfg.model, cfg.disaggregation_mode,
              cfg.tensor_parallel, backend, dist_cfg.process_id,
-             dist_cfg.num_processes)
+             dist_cfg.num_processes, cache_dir)
     engine = Engine(cfg)
     if cfg.warmup:
         # compile-complete before the socket opens: /ready can never observe

@@ -154,7 +154,7 @@ def test_chunk_kernel_int8_pools_stay_gated_until_validated(monkeypatch):
     """The bf16 on-chip parity pass flipped CHUNK_KERNEL_HW_VALIDATED, but
     the int8 dequant-in-chunk path has its own gate: int8 pools keep the
     XLA path under default selection until CHUNK_KERNEL_INT8_HW_VALIDATED
-    flips (battery case chunk_kernel_int8_parity)."""
+    flips (ROADMAP S3/S4: judged on a cell)."""
     import numpy as np
     import jax.numpy as jnp
 

@@ -78,3 +78,21 @@ def test_engine_config_cli_integration(tmp_path):
     assert cfg.max_num_seqs == 3
     assert cfg.quantization == "int8"
     assert cfg.warmup is True  # worker CLI default
+
+
+def test_donation_is_resolved_by_parameter_name():
+    """A TPU deletes a donated buffer where the CPU only warns, so no CPU
+    test can see a wrong donate_argnums tuple; the engine therefore names
+    what it donates and resolves positions from each signature."""
+    from dynamo_tpu.engine.engine import _argnums
+
+    def window_fn(params, tokens, positions, context_lens, active,
+                  bias_ids, counts, k_pages, v_pages, *extra):
+        pass
+
+    assert _argnums(window_fn, "tokens", "positions", "context_lens",
+                    "counts", "k_pages", "v_pages") == (1, 2, 3, 6, 7, 8)
+    # the first *extra operand (guided grammar carry / lora slot) follows
+    assert _argnums(window_fn, "extra") == (9,)
+    with pytest.raises(ValueError):
+        _argnums(window_fn, "slot_keys")  # not in this signature: loud

@@ -649,3 +649,78 @@ def test_hung_dispatch_handoff_resume_and_resurrection(watchdog_stack):
         for u in watchdog_stack["urls"]:
             post(watchdog_stack["frontend"], "/internal/deregister",
                  {"url": u})
+
+
+# ---------------------------------------------------------------------------
+# compilation is not a hang: the derived deadline arms only once a warmup()
+# has completed (derive_deadline), explicit overrides always
+# ---------------------------------------------------------------------------
+def _hold_seam(wd, seconds):
+    wd.device_enter("dispatch")
+    time.sleep(seconds)
+    wd.device_exit("dispatch")
+
+
+def test_derived_deadline_is_off_until_armed():
+    """A first jit call compiles inside its dispatch seam for far longer
+    than the floor; with derive_deadline off (an engine not yet warmed)
+    that neither trips the derived deadline nor teaches the EWMA. Once it
+    is on, the same seam trips."""
+    wd = EngineWatchdog(floor_s=0.05, derive_deadline=False,
+                        quarantine_window_s=0.0)
+    try:
+        _hold_seam(wd, 0.4)  # 8x the floor
+        _hold_seam(wd, 0.4)
+        s = wd.summary()
+        assert s["state"] == "healthy" and s["trips_total"] == {}
+        assert s["ewma_s"] is None  # compile time is not seam time
+        wd.derive_deadline = True  # what the end of warmup() does
+        wd.device_enter("dispatch")
+        deadline = time.monotonic() + 5.0
+        while time.monotonic() < deadline and wd.health == "healthy":
+            time.sleep(0.01)
+        assert wd.summary()["trips_total"] == {"hung_dispatch": 1}
+        wd.device_exit("dispatch")
+    finally:
+        wd.stop()
+
+
+def test_explicit_deadline_trips_while_derived_is_off():
+    """A hang drill (env/ctor override) during warmup is still a hang."""
+    wd = EngineWatchdog(deadline_s=0.05, derive_deadline=False)
+    try:
+        wd.device_enter("dispatch")
+        deadline = time.monotonic() + 5.0
+        while time.monotonic() < deadline and wd.health == "healthy":
+            time.sleep(0.01)
+        wd.device_exit("dispatch")
+        assert wd.summary()["trips_total"] == {"hung_dispatch": 1}
+    finally:
+        wd.stop()
+
+
+def test_only_a_completed_warmup_arms_the_derived_deadline(monkeypatch):
+    """An engine that never warmed (--no-warmup, bench.py's step-to-warm
+    loops) compiles in its first seams, so it never arms; warmup() disarms
+    at its start and arms at its end, on an accelerator only. (Admission
+    is stubbed, so nothing compiles: a real CPU warmup costs 20 s and is
+    covered by tests/test_warmup.py.)"""
+    import jax
+
+    eng = _engine(num_pages=64, max_num_seqs=2, max_seq_len=32)
+    wd = eng.watchdog
+    during = []
+    monkeypatch.setattr(
+        eng, "add_request", lambda req: during.append(wd.derive_deadline))
+    try:
+        assert wd.derive_deadline is False  # fresh engine: not warmed
+        wd.derive_deadline = True  # as a previous warmup left it
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        eng.warmup()
+        assert during and not any(during)
+        assert wd.derive_deadline is True
+        monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+        eng.warmup()
+        assert wd.derive_deadline is False
+    finally:
+        wd.stop()

@@ -292,13 +292,12 @@ class WeightManager:
             self._previous = None
             self._armed = None
         live = eng.params
-        fresh: Dict[str, Any] = {}
-        for k in live:
-            # np.asarray pulls a host copy first; device_put onto the
-            # leaf's own sharding keeps the jit signatures byte-identical
-            fresh[k] = jax.device_put(np.asarray(live[k]),
-                                      live[k].sharding)
-        eng.params = fresh
+        # np.asarray pulls a host copy first; device_put onto the leaf's
+        # own sharding keeps the jit signatures byte-identical. Leaf by
+        # leaf: a quantized weight is a (values, scales) pair, not an array
+        eng.params = jax.tree_util.tree_map(
+            lambda leaf: jax.device_put(np.asarray(leaf), leaf.sharding),
+            live)
         dt = time.monotonic() - t0
         eng.flight.note("restage_live", version=self.version,
                         seconds=round(dt, 3))

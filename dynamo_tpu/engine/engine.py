@@ -215,6 +215,25 @@ class EngineMetrics:
         self.mixed_prefill_tokens = 0
         self.phases: Dict[str, PhaseTimer] = {p: PhaseTimer()
                                               for p in self._PHASES}
+        # a first token by stage, cumulative seconds over `count` requests
+        # (serving/api.py GenerationHandle observes, from handler threads):
+        # received -> submitted -> prefill start -> first TokenEvent ->
+        # first frame written; the four stages sum to ttft_s
+        self.first_token: Dict[str, float] = {
+            "count": 0, "submit_s": 0.0, "queue_s": 0.0, "prefill_s": 0.0,
+            "emit_s": 0.0, "ttft_s": 0.0}
+        self._first_token_lock = threading.Lock()
+
+    def observe_first_token(self, submit_s: float, queue_s: float,
+                            prefill_s: float, emit_s: float) -> None:
+        with self._first_token_lock:
+            ft = self.first_token
+            ft["count"] += 1
+            ft["submit_s"] += submit_s
+            ft["queue_s"] += queue_s
+            ft["prefill_s"] += prefill_s
+            ft["emit_s"] += emit_s
+            ft["ttft_s"] += submit_s + queue_s + prefill_s + emit_s
 
     def observe_phase(self, phase: str, seconds: float,
                       weight: int = 1) -> None:
@@ -293,7 +312,10 @@ class EngineMetrics:
                if k not in ("phases", "occupancy_buckets", "mixed_buckets",
                             "spec_accept_buckets", "spec_draft_by",
                             "spec_accepted_by", "spec_hist_by",
-                            "spec_sum_by", "spec_count_by")}
+                            "spec_sum_by", "spec_count_by",
+                            "first_token", "_first_token_lock")}
+        with self._first_token_lock:
+            out["first_token"] = dict(self.first_token)
         out["phases"] = {p: t.snapshot() for p, t in self.phases.items()}
         out["spec_accept_mean"] = (
             round(self.spec_accept_sum / self.spec_accept_count, 4)
@@ -2297,16 +2319,25 @@ class Engine:
         finished, reason = self._check_stop(seq, first)
         ev = TokenEvent(req.request_id, first, 0, finished, reason)
         if t_prefill_start is not None:
-            now = time.monotonic()
-            ev.phase = {
-                "queue_s": max(0.0, t_prefill_start - req.arrival_time),
-                "prefill_s": max(0.0, now - t_prefill_start),
-            }
+            ev.phase = self._first_token_phase(req, t_prefill_start)
         if req.logprobs is not None:
             self._decorate_lp(ev, seq, lp[0], lp[1], lp[2])
         if finished:
             self._finish_slot(slot, reason)
         return ev
+
+    @staticmethod
+    def _first_token_phase(req: GenRequest, t_prefill_start: float) -> dict:
+        """The first TokenEvent's `phase`: how long the request queued and
+        how long its prompt computed (all chunks and mixed steps), plus
+        the monotonic stamp of this moment, `t_first`, from which the
+        serving layer times its own emit stage (serving/api.py)."""
+        now = time.monotonic()
+        return {
+            "queue_s": max(0.0, t_prefill_start - req.arrival_time),
+            "prefill_s": max(0.0, now - t_prefill_start),
+            "t_first": now,
+        }
 
     def _request_key(self, req: GenRequest):
         """Per-request PRNG chain root: deterministic when seeded; a
@@ -2710,13 +2741,9 @@ class Engine:
         finished, reason = self._check_stop(seq, first)
         # "prefill" records admission-to-first-token for BOTH paths (the
         # TTFT phase); per-chunk timings live in "prefill_chunk"
-        now = time.monotonic()
-        self.metrics.observe_phase("prefill", now - inf.t_start)
         ev = TokenEvent(req.request_id, first, 0, finished, reason)
-        ev.phase = {
-            "queue_s": max(0.0, inf.t_start - req.arrival_time),
-            "prefill_s": max(0.0, now - inf.t_start),
-        }
+        ev.phase = self._first_token_phase(req, inf.t_start)
+        self.metrics.observe_phase("prefill", ev.phase["prefill_s"])
         if req.logprobs is not None:
             self._decorate_lp(ev, seq, lp[0], lp[1], lp[2])
         if finished:
@@ -2846,13 +2873,9 @@ class Engine:
         seq = self._install_slot(req, inf.slot, inf.pages, inf.prompt_len,
                                  first, req_key)
         finished, reason = self._check_stop(seq, first)
-        now = time.monotonic()
-        self.metrics.observe_phase("prefill", now - inf.t_start)
         ev = TokenEvent(req.request_id, first, 0, finished, reason)
-        ev.phase = {
-            "queue_s": max(0.0, inf.t_start - req.arrival_time),
-            "prefill_s": max(0.0, now - inf.t_start),
-        }
+        ev.phase = self._first_token_phase(req, inf.t_start)
+        self.metrics.observe_phase("prefill", ev.phase["prefill_s"])
         if req.logprobs is not None:
             self._decorate_lp(ev, seq, lp[0], lp[1], lp[2])
         if finished:
@@ -2969,13 +2992,9 @@ class Engine:
         seq = self._install_slot(req, inf.slot, inf.pages, inf.prompt_len,
                                  first, req_key)
         finished, reason = self._check_stop(seq, first)
-        now = time.monotonic()
-        self.metrics.observe_phase("prefill", now - inf.t_start)
         ev = TokenEvent(req.request_id, first, 0, finished, reason)
-        ev.phase = {
-            "queue_s": max(0.0, inf.t_start - req.arrival_time),
-            "prefill_s": max(0.0, now - inf.t_start),
-        }
+        ev.phase = self._first_token_phase(req, inf.t_start)
+        self.metrics.observe_phase("prefill", ev.phase["prefill_s"])
         if req.logprobs is not None:
             self._decorate_lp(ev, seq, lp[0], lp[1], lp[2])
         if finished:
@@ -3497,7 +3516,8 @@ class Engine:
         # so interleaved work (chunk prefills, scheduling) between dispatch
         # and readback isn't double-counted into decode_window.
         self._pending_win = (window, ys, want_lp,
-                             time.monotonic() - t0, list(self.seqs))
+                             time.monotonic() - t0, list(self.seqs),
+                             self.timeline.dispatch_seq)
 
     def _materialize_pending(self) -> List[TokenEvent]:
         if self._pending_win is None:
@@ -3507,10 +3527,12 @@ class Engine:
     def _materialize_window(self, pw) -> List[TokenEvent]:
         if self._pending_win is pw:
             self._pending_win = None
-        window, ys, want_lp, dispatch_s, slots = pw
+        window, ys, want_lp, dispatch_s, slots, ticket = pw
         events: List[TokenEvent] = []
         t_wait = time.monotonic()
-        with self.timeline.phase("device_wait"):
+        # the stepline's drained account needs to know WHICH program this
+        # waits for: under async scheduling a newer window is in flight
+        with self.timeline.phase("device_wait", upto=ticket):
             # chaos: slow-but-alive readback — must NOT trip the watchdog
             # when the delay stays under the deadline
             faults.sleep_point("engine.device_slow")

@@ -34,6 +34,30 @@ number the zero-bubble PR must drive to ~0; it exports as
 existing ``dynamo_engine_phase_seconds{phase}`` histogram as additional
 label values (observability/engine_metrics.py).
 
+The record also spans the engine thread's time OUTSIDE ``step()`` when a
+loop driver (serving/engine_service.py ``_run``) declares it with
+``loop_state()``: ``between_steps`` (event fan-out, lock hand-off, the
+GIL, with work pending) and ``no_work`` (``has_work`` false, waiting to
+be woken).  ``loop_wall_s`` = step wall + both, conserved the same way.
+
+**Drained time.**  The device is *drained* from the exit of a
+``device_wait`` that leaves no dispatched program unfinished to the exit
+of the next ``dispatch``.  Programs run in dispatch order, so a wait on
+program k proves every program <= k done; ``phase("device_wait",
+upto=ticket)`` names k (``dispatch_seq`` read after the dispatch), and
+a wait without a ticket is on the newest program.  Every segment of the
+thread's time that lies in a drained interval (phases, ``untracked``,
+``between_steps``, ``no_work``; a ``device_wait`` there is an implicit
+program such as first-token sampling and is left out) is summed into
+``drained.by``: a measurement of what the host did while the device had
+nothing to do, which ``bubble`` reports as shares of ``loop_wall_s``.
+
+**One clock with the device.**  While a profiler capture is open
+(``start_annotations``, serving/api.py ``capture_trace``) every segment
+is also a ``jax.profiler.TraceAnnotation`` named ``stepline/<segment>``
+and each step a ``StepTraceAnnotation``, so the trace holds the host's
+phases on the profiler's clock beside the device's operations.
+
 Record keeping follows the flight recorder's single-writer draft
 pattern: `Engine.step()` runs under `_exec_lock` on one scheduler
 thread, so the draft and phase stack are touched lock-free; the only
@@ -76,9 +100,17 @@ ENABLE_ENV = "DYNAMO_TPU_TIMELINE"
 
 # instrumented phase names, in pipeline order
 PHASES = ("admit", "page_alloc", "dispatch", "device_wait", "detok", "bank")
-# phases during which the DEVICE is (or may be) busy on our behalf; the
-# rest are pure host work — the candidates that "eat" the dispatch gap
+# phases during which the DEVICE is (or may be) busy on our behalf
 DEVICE_PHASES = frozenset(("dispatch", "device_wait"))
+# the engine thread's time that no phase claims: inside step() ...
+UNTRACKED = "untracked"
+# ... and outside it, as the loop driver declares it (loop_state)
+LOOP_STATES = ("between_steps", "no_work")
+# what a drained interval is split over (device_wait is never drained
+# time: the device works for whoever waits on it)
+DRAINED_KEYS = ("admit", "page_alloc", "dispatch", "detok", "bank",
+                UNTRACKED) + LOOP_STATES
+ANNOTATION_PREFIX = "stepline/"
 
 
 def _env_capacity() -> int:
@@ -146,11 +178,13 @@ class _Phase:
     """Reusable-shape context manager for one instrumented phase; kept
     allocation-light because several open per engine step."""
 
-    __slots__ = ("_tl", "_name", "_watched")
+    __slots__ = ("_tl", "_name", "_upto", "_watched")
 
-    def __init__(self, tl: "StepTimeline", name: str):
+    def __init__(self, tl: "StepTimeline", name: str,
+                 upto: Optional[int] = None):
         self._tl = tl
         self._name = name
+        self._upto = upto
         self._watched = False
 
     def __enter__(self) -> "_Phase":
@@ -161,7 +195,7 @@ class _Phase:
         if watch is not None and self._name in DEVICE_PHASES:
             self._watched = watch
             watch.device_enter(self._name)
-        self._tl._enter(self._name)
+        self._tl._enter(self._name, self._upto)
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -202,6 +236,29 @@ class StepTimeline:
         self._draft: Optional[Dict[str, Any]] = None
         self._stack: List[List[Any]] = []  # [name, segment_open_monotonic]
         self._last_return: Optional[float] = None  # device ctrl-return mark
+        # the thread's time outside step(), by loop state; None until a
+        # loop driver calls loop_state() (a library caller of step() has
+        # no loop, and its own time between steps is not the engine's)
+        self.loop_totals: Dict[str, float] = {s: 0.0 for s in LOOP_STATES}
+        self._loop_state: Optional[str] = None
+        self._loop_t = 0.0  # start of the open segment outside step()
+        # drained-time attribution: the open segment [_cur_t, now) of the
+        # thread is `_cur_name`; dispatched/finished program counters say
+        # whether the device has anything left to run
+        self.drained_by: Dict[str, float] = {k: 0.0 for k in DRAINED_KEYS}
+        self.drained_total_s = 0.0
+        self.drained_count = 0
+        self.dispatch_seq = 0  # programs dispatched (ticket of the newest)
+        self._done_seq = 0  # newest program a device_wait proved finished
+        self._drained = False
+        self._cur_name: Optional[str] = None
+        self._cur_t = 0.0
+        # profiler annotations, only while a capture is open
+        self._tracing = False  # the one test a segment pays outside one
+        self._annotate: Optional[Any] = None
+        self._annotate_step: Optional[Any] = None
+        self._ann: Optional[Any] = None
+        self._ann_step: Optional[Any] = None
         # optional EngineWatchdog: device-phase enter/exit mirror — hang
         # detection coverage tracks stepline instrumentation exactly
         self.watch: Optional[Any] = None
@@ -224,6 +281,75 @@ class StepTimeline:
         self._draft = None
         self._stack = []
         self._last_return = None
+        self.loop_totals = {s: 0.0 for s in LOOP_STATES}
+        self._loop_t = self._cur_t = time.monotonic()
+        self.drained_by = {k: 0.0 for k in DRAINED_KEYS}
+        self.drained_total_s = 0.0
+        self.drained_count = 0
+        self._cur_name = self._loop_state
+
+    def loop_state(self, name: str) -> None:
+        """The loop driver's declaration, on the engine thread, of what it
+        is about to do outside step(): `between_steps` (work pending) or
+        `no_work` (about to wait).  Closes the open outside segment into
+        its state's total; repeated calls with one state just fold it, so
+        a scrape never misses more than one idle tick."""
+        if not self.enabled or self._draft is not None:
+            return
+        now = time.monotonic()
+        if self._loop_state is not None:
+            self.loop_totals[self._loop_state] += now - self._loop_t
+        self._loop_state = name
+        self._loop_t = now
+        self._mark(now, name)
+
+    def start_annotations(self, annotate, annotate_step=None) -> None:
+        """From now on every segment is also `annotate("stepline/<name>")`
+        and every step `annotate_step("stepline/step", step_num=n)`:
+        context managers, entered and left on the engine thread
+        (jax.profiler.TraceAnnotation / StepTraceAnnotation while a
+        capture is open).  Any thread may call this."""
+        self._annotate = annotate
+        self._annotate_step = annotate_step
+        self._tracing = True
+
+    def stop_annotations(self) -> None:
+        """The engine thread closes what it has open at its next segment
+        boundary and stops annotating."""
+        self._annotate = None
+        self._annotate_step = None
+
+    def _mark(self, now: float, name: Optional[str]) -> None:
+        """Segment boundary: [_cur_t, now) was `_cur_name`, `name` opens.
+        Whether the closed segment was drained is `_drained` as it stands
+        here; the flag only flips at a boundary, after its _mark."""
+        cur = self._cur_name
+        if cur is not None and self._drained and cur != "device_wait":
+            dur = now - self._cur_t
+            if dur > 0:
+                self.drained_by[cur] += dur
+                self.drained_total_s += dur
+        self._cur_name = name
+        self._cur_t = now
+        if self._tracing:
+            self._reannotate(name)
+
+    def _reannotate(self, name: Optional[str]) -> None:
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        make = self._annotate
+        if make is None:
+            self._close_step_annotation()
+            self._tracing = False
+        elif name is not None:
+            self._ann = make(ANNOTATION_PREFIX + name)
+            self._ann.__enter__()
+
+    def _close_step_annotation(self) -> None:
+        ann, self._ann_step = self._ann_step, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
     def begin_step(self) -> None:
         """Open the draft for one `Engine.step()`.  A draft still open
@@ -233,17 +359,29 @@ class StepTimeline:
             return
         if self._draft is not None:
             self._finalize(aborted=True)
-        self._draft = {"t0": time.monotonic(), "t0_unix_ns": time.time_ns(),
+        now = time.monotonic()
+        self._draft = {"t0": now, "t0_unix_ns": time.time_ns(),
                        "segs": [], "gaps": []}
         self._stack = []
+        if self._tracing:
+            self._mark(now, None)  # the step's annotation holds its segments
+            make = self._annotate_step
+            if make is not None:
+                self._ann_step = make(ANNOTATION_PREFIX + "step",
+                                      step_num=self.steps_total)
+                self._ann_step.__enter__()
+        self._mark(now, UNTRACKED)
 
-    def phase(self, name: str) -> _Phase:
+    def phase(self, name: str, upto: Optional[int] = None) -> _Phase:
         """Context manager for one instrumented phase of the open step.
         No-op outside an open draft (disabled timeline, or engine paths
-        like the disagg prefill role that run outside step())."""
-        return _Phase(self, name)
+        like the disagg prefill role that run outside step()).  `upto`,
+        on a `device_wait`, is the ticket (`dispatch_seq` after its
+        dispatch) of the program waited for; without it the wait is on
+        the newest program."""
+        return _Phase(self, name, upto)
 
-    def _enter(self, name: str) -> None:
+    def _enter(self, name: str, upto: Optional[int] = None) -> None:
         d = self._draft
         if d is None:
             return
@@ -262,7 +400,8 @@ class StepTimeline:
             # at _last_return; program N+1 launches now. Clamped — async
             # scheduling dispatches N+1 before materializing N.
             d["gaps"].append(max(0.0, now - self._last_return))
-        stack.append([name, now])
+        stack.append([name, now, upto])
+        self._mark(now, name)
 
     def _exit(self) -> None:
         d = self._draft
@@ -273,10 +412,22 @@ class StepTimeline:
         top = stack.pop()
         if now > top[1]:
             d["segs"].append((top[0], top[1] - d["t0"], now - d["t0"]))
-        if top[0] in DEVICE_PHASES:
-            self._last_return = now
         if stack:
             stack[-1][1] = now  # resume the paused outer phase
+        self._mark(now, stack[-1][0] if stack else UNTRACKED)
+        if top[0] in DEVICE_PHASES:
+            self._last_return = now
+            if top[0] == "dispatch":
+                # the device has work again: a drained interval ends here
+                self.dispatch_seq += 1
+                if self._drained:
+                    self._drained = False
+                    self.drained_count += 1
+            else:
+                done = self.dispatch_seq if top[2] is None else top[2]
+                if done > self._done_seq:
+                    self._done_seq = done
+                self._drained = self._done_seq >= self.dispatch_seq
 
     def commit_step(self, **fields: Any) -> None:
         """Finalize the open step record.  Steps that measured nothing
@@ -299,8 +450,15 @@ class StepTimeline:
                 d["segs"].append((top[0], top[1] - d["t0"], now - d["t0"]))
             if self._stack:
                 self._stack[-1][1] = now
+        if self._tracing:
+            self._mark(now, None)
+            self._close_step_annotation()
+        self._mark(now, self._loop_state)
         if not d["segs"]:
-            return
+            return  # its time stays in the open segment outside step()
+        if self._loop_state is not None:
+            self.loop_totals[self._loop_state] += d["t0"] - self._loop_t
+            self._loop_t = now
         wall = now - d["t0"]
         sums: Dict[str, float] = {}
         for name, s0, s1 in d["segs"]:
@@ -386,29 +544,35 @@ class StepTimeline:
                 if wall else 0.0,
             },
             "untracked_s": round(max(0.0, wall - tracked), 6),
+            # the thread's whole time: step wall + the loop's two states
+            "loop_wall_s": round(wall + sum(self.loop_totals.values()), 6),
+            "loop": {s: round(t, 6) for s, t in self.loop_totals.items()},
+            "drained": {
+                "total_s": round(self.drained_total_s, 6),
+                "count": self.drained_count,
+                "by": {k: round(t, 6) for k, t in self.drained_by.items()},
+            },
         }
-        bubble = _bubble_attribution(
-            {n: self.phase_totals[n] for n in PHASES},
-            max(0.0, wall - tracked), wall)
+        bubble = _bubble_attribution(out["drained"]["by"],
+                                     out["loop_wall_s"])
         if bubble is not None:
             out["bubble"] = bubble
         return out
 
 
-def _bubble_attribution(phase_totals: Dict[str, float], untracked: float,
-                        wall: float) -> Optional[Dict[str, Any]]:
-    """Which HOST phase eats the inter-dispatch gap: rank the non-device
-    phases (plus the untracked residue) by their share of step wall."""
-    eaters = {n: t for n, t in phase_totals.items()
-              if n not in DEVICE_PHASES and t > 0}
-    if untracked > 0:
-        eaters["untracked"] = untracked
-    if not eaters or wall <= 0:
+def _bubble_attribution(drained_by: Dict[str, float],
+                        loop_wall: float) -> Optional[Dict[str, Any]]:
+    """What the host did while the device was drained, as shares of the
+    engine thread's time, largest first.  The eater is the largest that
+    host work can shrink: `no_work` is the absence of requests."""
+    ranked = sorted(((n, t) for n, t in drained_by.items() if t > 0),
+                    key=lambda kv: -kv[1])
+    eaters = [n for n, _ in ranked if n != "no_work"]
+    if not eaters or loop_wall <= 0:
         return None
-    ranked = sorted(eaters.items(), key=lambda kv: -kv[1])
     return {
-        "gap_eater": ranked[0][0],
-        "host_shares": {n: round(t / wall, 4) for n, t in ranked},
+        "gap_eater": eaters[0],
+        "host_shares": {n: round(t / loop_wall, 4) for n, t in ranked},
     }
 
 
@@ -418,9 +582,10 @@ def merge_summaries(summaries: List[Dict[str, Any]]) -> Dict[str, Any]:
     quantiles don't survive summarization, so the merged view reports
     worst-worker p95 per phase instead."""
     agg: Dict[str, Any] = {
-        "steps": 0, "wall_s": 0.0, "untracked_s": 0.0,
+        "steps": 0, "wall_s": 0.0, "untracked_s": 0.0, "loop_wall_s": 0.0,
         "phases": {},
         "host_gap": {"count": 0, "total_s": 0.0, "p95_ms_max": 0.0},
+        "drained": {"total_s": 0.0, "count": 0, "by": {}},
     }
     for s in summaries:
         if not s:
@@ -428,6 +593,14 @@ def merge_summaries(summaries: List[Dict[str, Any]]) -> Dict[str, Any]:
         agg["steps"] += s.get("steps", 0)
         agg["wall_s"] += s.get("wall_s", 0.0)
         agg["untracked_s"] += s.get("untracked_s", 0.0)
+        # a worker from before the loop account: its steps are its loop
+        agg["loop_wall_s"] += s.get("loop_wall_s", s.get("wall_s", 0.0))
+        dr = s.get("drained") or {}
+        agg["drained"]["total_s"] += dr.get("total_s", 0.0)
+        agg["drained"]["count"] += dr.get("count", 0)
+        for name, t in (dr.get("by") or {}).items():
+            agg["drained"]["by"][name] = (
+                agg["drained"]["by"].get(name, 0.0) + t)
         hg = s.get("host_gap") or {}
         agg["host_gap"]["count"] += hg.get("count", 0)
         agg["host_gap"]["total_s"] += hg.get("total_s", 0.0)
@@ -447,12 +620,14 @@ def merge_summaries(summaries: List[Dict[str, Any]]) -> Dict[str, Any]:
             agg["host_gap"]["total_s"] / wall, 4)
     agg["wall_s"] = round(agg["wall_s"], 6)
     agg["untracked_s"] = round(agg["untracked_s"], 6)
+    agg["loop_wall_s"] = round(agg["loop_wall_s"], 6)
+    agg["drained"]["total_s"] = round(agg["drained"]["total_s"], 6)
+    agg["drained"]["by"] = {n: round(t, 6)
+                            for n, t in agg["drained"]["by"].items()}
     agg["host_gap"]["total_s"] = round(agg["host_gap"]["total_s"], 6)
     for ph in agg["phases"].values():
         ph["total_s"] = round(ph["total_s"], 6)
-    bubble = _bubble_attribution(
-        {n: p["total_s"] for n, p in agg["phases"].items()},
-        agg["untracked_s"], wall)
+    bubble = _bubble_attribution(agg["drained"]["by"], agg["loop_wall_s"])
     if bubble is not None:
         agg["bubble"] = bubble
     return agg

@@ -132,7 +132,8 @@ class GenerationHandle:
 
     def __init__(self, ctx: "ServingContext", rid: str, prompt_ids: List[int],
                  params: dict, index: int = 0, trace_span=None,
-                 deadline: Optional[Deadline] = None):
+                 deadline: Optional[Deadline] = None,
+                 received_at: Optional[float] = None):
         self.ctx = ctx
         self.rid = rid
         self.index = index
@@ -189,6 +190,11 @@ class GenerationHandle:
             # scheduler (and across preemption/recovery continuations)
             tenant=params.get("tenant"),
         )
+        # when the handler received the request (monotonic): the first of
+        # the first token's stamps. A library caller has no handler, and
+        # its submit stage is empty.
+        self.received_at = (received_at if received_at is not None
+                            else self.req.arrival_time)
         self.tenant = self.req.tenant or "default"
         ctx.metrics.tenant_requests.inc(tenant=self.tenant)
         if self.req.adapter and ctx.lora_requests_total is not None:
@@ -217,16 +223,10 @@ class GenerationHandle:
             [(tok.decode([tid]), lp) for tid, lp in (ev.top_logprobs or [])],
         )
 
-    def _first_token_spans(self, ev, ttft_s: float):
-        """Bridge the engine's per-request phase timings (TokenEvent.phase,
-        recorded by the same prefill paths that feed the PhaseTimer
-        histograms) into back-dated worker.queue / worker.prefill child
-        spans, then open the worker.decode span. Engine-wide PhaseTimer
-        quantiles ride as attributes so a single slow trace carries the
-        fleet context it should be judged against."""
+    def _decode_span(self, ttft_s: float):
+        """Open the worker.decode span at the first TokenEvent."""
         if not self.span.recording:
             return None
-        tracer = self.ctx.tracer
         eng = self.ctx.engine
         if self.req.adapter and eng.lora is not None:
             # the device slot is known once admission resolved it
@@ -234,28 +234,50 @@ class GenerationHandle:
                 "lora.adapter": self.req.adapter,
                 "lora.slot": eng.lora.slot_of(self.req.adapter) or 0,
             })
-        eng_ph = eng.metrics.phases
-        t_first_ns = time.time_ns()
-        phase = ev.phase or {}
-        queue_ns = int(phase.get("queue_s", 0.0) * 1e9)
-        prefill_ns = int(phase.get("prefill_s", 0.0) * 1e9)
-        pf_start_ns = t_first_ns - prefill_ns
-        if queue_ns or prefill_ns:
-            tracer.start_span(
-                "worker.queue", parent=self.span,
-                start_ns=pf_start_ns - queue_ns).end(end_ns=pf_start_ns)
-            tracer.start_span(
-                "worker.prefill", parent=self.span, start_ns=pf_start_ns,
-                attributes={
-                    "prompt_tokens": len(self.prompt_ids),
-                    "engine.prefill.p50_ms":
-                        round(eng_ph["prefill"].quantile_ms(0.5), 3),
-                    "engine.prefill.p95_ms":
-                        round(eng_ph["prefill"].quantile_ms(0.95), 3),
-                }).end(end_ns=t_first_ns)
-        return tracer.start_span(
-            "worker.decode", parent=self.span, start_ns=t_first_ns,
+        return self.ctx.tracer.start_span(
+            "worker.decode", parent=self.span,
             attributes={"ttft_s": round(ttft_s, 6)})
+
+    def _first_token_written(self, phase: Optional[dict]) -> None:
+        """The first frame with content is on the wire: put the first
+        token's time down to its four stages, from five monotonic stamps
+        (received_at, the request's arrival_time, and the engine's
+        prefill start and `t_first` on TokenEvent.phase, now). The same
+        stamps feed the cumulative counters the benchmark reads
+        (/worker/stats metrics.first_token) and four back-dated spans,
+        children of the request's span: worker.submit (parse, template,
+        tokenise), worker.queue, worker.prefill, worker.emit (event
+        queue, detokenise, frame). A first event without stamps (a
+        disaggregated prefill elsewhere) gives neither."""
+        if not phase or "t_first" not in phase:
+            return
+        t_written = time.monotonic()
+        t_first = phase["t_first"]
+        t_prefill = t_first - phase["prefill_s"]
+        stamps = (self.received_at, self.req.arrival_time, t_prefill,
+                  t_first, t_written)
+        eng = self.ctx.engine
+        eng.metrics.observe_first_token(
+            *(b - a for a, b in zip(stamps, stamps[1:])))
+        if not self.span.recording:
+            return
+        # spans live on the unix clock: one offset carries every stamp over
+        to_ns = time.time_ns() - int(t_written * 1e9)
+        prefill = eng.metrics.phases["prefill"]
+        prefill_attributes = {
+            "prompt_tokens": len(self.prompt_ids),
+            # engine-wide quantiles ride along, so that one slow trace
+            # carries the fleet context it should be judged against
+            "engine.prefill.p50_ms": round(prefill.quantile_ms(0.5), 3),
+            "engine.prefill.p95_ms": round(prefill.quantile_ms(0.95), 3)}
+        names = ("worker.submit", "worker.queue", "worker.prefill",
+                 "worker.emit")
+        for name, a, b in zip(names, stamps, stamps[1:]):
+            self.ctx.tracer.start_span(
+                name, parent=self.span, start_ns=to_ns + int(a * 1e9),
+                attributes=(prefill_attributes if name == "worker.prefill"
+                            else None),
+            ).end(end_ns=to_ns + int(max(a, b) * 1e9))
 
     def run(self, emit) -> tuple:
         """Drive the stream; emit(delta, finish|None, lp_entry|None) -> bool
@@ -268,6 +290,7 @@ class GenerationHandle:
         t0 = time.monotonic()
         t_prev: Optional[float] = None
         decode_span = None
+        first_phase: Optional[dict] = None  # until the first frame is out
         detok = IncrementalDetokenizer(ctx.tokenizer)
         matcher = StopStringMatcher(self.stops) if self.stops else None
         text_parts: List[str] = []
@@ -343,7 +366,8 @@ class GenerationHandle:
             if t_prev is None:
                 m.ttft.observe(now - t0, exemplar=ex, model=model)
                 m.tenant_ttft.observe(now - t0, tenant=self.tenant)
-                decode_span = self._first_token_spans(ev, now - t0)
+                decode_span = self._decode_span(now - t0)
+                first_phase = ev.phase
             else:
                 m.itl.observe(now - t_prev, exemplar=ex, model=model)
                 m.tenant_itl.observe(now - t_prev, tenant=self.tenant)
@@ -400,7 +424,11 @@ class GenerationHandle:
             # emit on no-delta events too when they carry a logprob entry
             # (UTF-8 holdback): streaming logprobs are one entry per token
             if delta or ev.finished or lp_entry is not None:
-                if not emit(delta, fr, lp_entry) and not ev.finished:
+                ok = emit(delta, fr, lp_entry)
+                if first_phase is not None:
+                    self._first_token_written(first_phase)
+                    first_phase = None
+                if not ok and not ev.finished:
                     log.info("client disconnected; aborting %s", self.rid)
                     ctx.service.abort(self.rid)
                     finish = "abort"
@@ -763,11 +791,22 @@ class ServingContext:
             d = tempfile.mkdtemp(prefix="dynamo-trace-")
             try:
                 jax.profiler.start_trace(d)
-                # the capture window IS the critical section: _trace_lock
-                # serializes profiler runs and the acquire above is
-                # non-blocking (concurrent callers 409 instead of parking)
-                time.sleep(min(max(duration_s, 0.05), 30.0))  # dynalint: off blocking-under-lock
-                jax.profiler.stop_trace()
+                # for the length of the capture, and only then, the
+                # stepline's segments are profiler annotations: the
+                # host's phases on the device's clock, in the same file
+                timeline = self.engine.timeline
+                timeline.start_annotations(
+                    jax.profiler.TraceAnnotation,
+                    jax.profiler.StepTraceAnnotation)
+                try:
+                    # the capture window IS the critical section:
+                    # _trace_lock serializes profiler runs and the acquire
+                    # above is non-blocking (concurrent callers 409
+                    # instead of parking)
+                    time.sleep(min(max(duration_s, 0.05), 30.0))  # dynalint: off blocking-under-lock
+                finally:
+                    timeline.stop_annotations()
+                    jax.profiler.stop_trace()
                 buf = io.BytesIO()
                 with zipfile.ZipFile(buf, "w", zipfile.ZIP_DEFLATED) as z:
                     for root, _, files in os.walk(d):
@@ -988,13 +1027,15 @@ class ServingContext:
         self.service.close()
 
     def start_generation(self, rid, prompt_ids, params, index: int = 0,
-                         trace_span=None, deadline=None) -> "GenerationHandle":
+                         trace_span=None, deadline=None,
+                         received_at=None) -> "GenerationHandle":
         return GenerationHandle(self, rid, prompt_ids, params, index=index,
-                                trace_span=trace_span, deadline=deadline)
+                                trace_span=trace_span, deadline=deadline,
+                                received_at=received_at)
 
     def start_choices(self, rid, prompt_ids, params,
-                      trace_span=None,
-                      deadline=None) -> List["GenerationHandle"]:
+                      trace_span=None, deadline=None,
+                      received_at=None) -> List["GenerationHandle"]:
         """Submit all n choices of a request (choice i streams under
         request_id '<rid>-i'). Submission is all-or-nothing: a rejection on
         choice k aborts choices 0..k-1 before re-raising."""
@@ -1005,7 +1046,7 @@ class ServingContext:
                 handles.append(GenerationHandle(
                     self, f"{rid}-{i}" if n > 1 else rid,
                     prompt_ids, params, index=i, trace_span=trace_span,
-                    deadline=deadline,
+                    deadline=deadline, received_at=received_at,
                 ))
         except Exception:
             for h in handles:
@@ -1299,6 +1340,7 @@ class _Handler(JsonHTTPHandler):
             self._error(404, f"no route {path}")
 
     def do_POST(self):
+        self._received_at = time.monotonic()
         path = self.path.split("?")[0]
         if (self.ctx.draining.is_set()
                 and path.startswith(("/v1/", "/disagg/prefill"))):
@@ -1723,7 +1765,7 @@ class _Handler(JsonHTTPHandler):
         self._span.set_attribute("request.id", rid)
         handles = self.ctx.start_choices(  # may raise -> 400
             rid, prompt_ids, p, trace_span=self._span,
-            deadline=self._deadline)
+            deadline=self._deadline, received_at=self._received_at)
 
         if p["stream"]:
             with_null = p.get("include_usage", False)
@@ -1850,7 +1892,8 @@ class _Handler(JsonHTTPHandler):
         self._span.set_attribute("request.id", rid)
         handles = self.ctx.start_choices(rid, prompt_ids, p,
                                          trace_span=self._span,
-                                         deadline=self._deadline)
+                                         deadline=self._deadline,
+                                         received_at=self._received_at)
 
         def lp_block(h):
             if not h.want_logprobs:

@@ -159,8 +159,15 @@ class EngineService:
     # ------------------------------------------------------------ scheduler
     def _run(self):
         idle_tick = getattr(self.engine, "idle_tick", None)
+        # the stepline accounts for this thread's whole time: what lies
+        # outside step() is `no_work` or `between_steps` (event fan-out,
+        # lock hand-off, the GIL), declared here as each begins
+        timeline = getattr(self.engine, "timeline", None)
+        loop_state = (timeline.loop_state if timeline is not None
+                      else lambda name: None)
         while not self._stop:
             if not self.engine.has_work:
+                loop_state("no_work")
                 if idle_tick is not None:
                     # multi-host leader: heartbeat the replication plane so
                     # idle followers' pending collective never times out
@@ -168,6 +175,7 @@ class EngineService:
                 self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 continue
+            loop_state("between_steps")
             try:
                 events = self.engine.step()
             except Exception as e:

@@ -848,10 +848,17 @@ class _FrontendHandler(JsonHTTPHandler):
             if worker is not None:
                 pick_span.set_attribute("worker.url", worker.url)
         if worker is None:
-            span.set_status("ERROR", f"no live worker for {model!r}")
+            # say WHY the router had no candidate: none registered (or all
+            # past their heartbeat TTL), or some skipped for an open
+            # circuit breaker or for the health they advertise
+            reason = ", ".join(
+                f"{k}={explain.get(k, 0)}"
+                for k in ("candidates", "breaker_skipped", "health_skipped"))
+            msg = f"no live worker for model {model!r} ({reason})"
+            span.set_status("ERROR", msg)
+            span.set_attribute("router.no_worker_reason", reason)
             ctx.metrics.errors_total.inc(model=model, code="503")
-            self._error(503, f"no live worker for model {model!r}",
-                        "service_unavailable")
+            self._error(503, msg, "service_unavailable")
             return
 
         m = ctx.metrics

@@ -95,6 +95,7 @@ def heartbeat_loop(ctx: ServingContext, frontend_url: str, self_url: str,
         if not first and stop.wait(interval):
             return
         first = False
+        t_beat = time.monotonic()
         eng = ctx.engine
         body = json.dumps({
             "url": self_url,
@@ -149,6 +150,14 @@ def heartbeat_loop(ctx: ServingContext, frontend_url: str, self_url: str,
             except Exception as e:
                 # one dead replica must not starve the others of beats
                 log.warning("heartbeat to %s failed: %s", payload_url, e)
+        took = time.monotonic() - t_beat
+        if took > interval:
+            # a frontend purges a worker whose beats stop for its TTL: a
+            # beat slower than its interval is the first sign of that
+            log.warning("heartbeat took %.2fs, longer than its %.1fs "
+                        "interval (payload of %d bytes built and posted "
+                        "to %d frontend(s))", took, interval, len(body),
+                        len(payload_urls))
 
 
 def build_parser(backend_name: str) -> argparse.ArgumentParser:

@@ -247,3 +247,56 @@ def test_metrics_label_escaping_adversarial():
         if line.startswith("#"):
             continue
         assert re.match(r'^[a-zA-Z_:][a-zA-Z0-9_:]*(\{.*\})? \S+$', line), line
+
+
+# ------------------------------------------- the frontend's 503 says why --
+
+@pytest.mark.parametrize("case,want", [
+    ("nobody_registered",
+     "candidates=0, breaker_skipped=0, health_skipped=0"),
+    ("breaker_open", "candidates=1, breaker_skipped=1, health_skipped=0"),
+    ("unhealthy", "candidates=1, breaker_skipped=0, health_skipped=1"),
+])
+def test_no_live_worker_503_names_its_reason(case, want):
+    """`no live worker` carries why the router had no candidate, in the
+    message (the benchmark logs a refused probe's message) and on the
+    request's span."""
+    import urllib.error
+    import urllib.request
+
+    from dynamo_tpu.serving.api import serve_forever_in_thread
+    from dynamo_tpu.serving.frontend import (
+        FrontendContext, make_frontend_server,
+    )
+
+    fctx = FrontendContext()
+    url = "http://127.0.0.1:9"   # nothing listens there; never dialled
+    if case != "nobody_registered":
+        health = {"state": "suspect"} if case == "unhealthy" else None
+        fctx.router.register(url, "m", "agg", stats={
+            "max_num_seqs": 4, "free_pages": 9, "total_pages": 9,
+            **({"health": health} if health else {})})
+    if case == "breaker_open":
+        for _ in range(fctx.router.breakers.threshold):
+            fctx.router.breakers.record_failure(url)
+    srv = make_frontend_server(fctx, "127.0.0.1", 0)
+    serve_forever_in_thread(srv)
+    try:
+        with pytest.raises(urllib.error.HTTPError) as ei:
+            urllib.request.urlopen(urllib.request.Request(
+                f"http://127.0.0.1:{srv.server_address[1]}"
+                "/v1/chat/completions",
+                data=json.dumps({"model": "m", "messages": [
+                    {"role": "user", "content": "x"}]}).encode(),
+                headers={"Content-Type": "application/json"}), timeout=30)
+        assert ei.value.code == 503
+        message = json.loads(ei.value.read())["error"]["message"]
+        assert message == f"no live worker for model 'm' ({want})"
+        spans = [sp for sp in fctx.tracer.collector.snapshot()
+                 if sp.attributes.get("router.no_worker_reason")]
+        assert spans and spans[-1].attributes[
+            "router.no_worker_reason"] == want
+        assert spans[-1].status_code == "ERROR"
+        assert want in spans[-1].status_message
+    finally:
+        srv.shutdown()

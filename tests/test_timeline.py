@@ -15,6 +15,12 @@ Covers observability/timeline.py and its engine + HTTP wiring:
 - /debug/timeline payload formats (json / summary / perfetto / steps=);
 - fleet rollup: merge_summaries totals, worst-worker p95, bubble
   attribution;
+- the loop account: step wall + `between_steps` + `no_work` =
+  `loop_wall_s` exactly, `no_work` only while the engine has no work;
+- drained time: what a `device_wait` leaves in flight decides whether the
+  device is drained, every drained second is put down to one segment,
+  and `bubble` follows `drained.by`;
+- profiler annotations exist only between start_ and stop_annotations;
 - disabled mode + ring bounds + overhead budget of the on path.
 """
 
@@ -22,7 +28,9 @@ import json
 
 import pytest
 
+from dynamo_tpu.observability import timeline as timeline_mod
 from dynamo_tpu.observability.timeline import (
+    DRAINED_KEYS,
     PHASES,
     PhaseDigest,
     StepTimeline,
@@ -342,8 +350,14 @@ def test_merge_summaries_totals_and_bubble():
                          "p95_ms": p95, "share": gap_s / wall},
         }
 
-    merged = merge_summaries([mk(1.0, 0.2, 0.05, 4.0),
-                              mk(2.0, 0.4, 0.10, 9.0), {}])
+    a, b = mk(1.0, 0.2, 0.05, 4.0), mk(2.0, 0.4, 0.10, 9.0)
+    # `a` is a worker from before the loop account: its steps are its loop
+    b["loop_wall_s"] = 3.0
+    a["drained"] = {"total_s": 0.3, "count": 3,
+                    "by": {"admit": 0.2, "dispatch": 0.1}}
+    b["drained"] = {"total_s": 1.4, "count": 4,
+                    "by": {"admit": 0.3, "no_work": 1.1}}
+    merged = merge_summaries([a, b, {}])
     assert merged["steps"] == 20
     assert abs(merged["wall_s"] - 3.0) < 1e-9
     adm = merged["phases"]["admit"]
@@ -354,8 +368,295 @@ def test_merge_summaries_totals_and_bubble():
     hg = merged["host_gap"]
     assert hg["count"] == 10 and hg["p95_ms_max"] == 9.0
     assert abs(hg["total_s"] - 0.15) < 1e-9
-    # bubble attribution over the merged host phases
+    assert abs(merged["loop_wall_s"] - 4.0) < 1e-9
+    dr = merged["drained"]
+    assert dr["count"] == 7 and abs(dr["total_s"] - 1.7) < 1e-9
+    assert dr["by"] == {"admit": 0.5, "dispatch": 0.1, "no_work": 1.1}
+    # bubble follows the merged drained.by, as shares of the loop's wall;
+    # the absence of requests is listed but is never the eater
     assert merged["bubble"]["gap_eater"] == "admit"
+    assert merged["bubble"]["host_shares"] == {
+        "no_work": 0.275, "admit": 0.125, "dispatch": 0.025}
+    # no drained account at all (old workers only): no bubble, as before
+    assert "bubble" not in merge_summaries([mk(1.0, 0.2, 0.05, 4.0)])
+
+
+# ---------------------------------------------------------------------------
+# the loop account and drained time, on a clock the test moves
+# ---------------------------------------------------------------------------
+class _Clock:
+    """Stands in for the `time` module inside observability/timeline.py."""
+
+    def __init__(self):
+        self.t = 100.0
+
+    def monotonic(self):
+        return self.t
+
+    def time_ns(self):
+        return int(self.t * 1e9)
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    c = _Clock()
+    monkeypatch.setattr(timeline_mod, "time", c)
+    return c
+
+
+def _play(tl, clock, script):
+    """Run a script of (op, arg, seconds) on the timeline: `loop` declares
+    a loop state, `begin`/`commit` bracket a step, `enter`/`exit` a phase
+    (`enter` takes a name or (name, upto)), `idle` is time inside a step
+    that no phase claims. The clock moves by `seconds` AFTER each op, so
+    that is how long the segment the op opened lasts."""
+    for op, arg, seconds in script:
+        if op == "loop":
+            tl.loop_state(arg)
+        elif op == "begin":
+            tl.begin_step()
+        elif op == "commit":
+            tl.commit_step()
+        elif op == "enter":
+            name, upto = arg if isinstance(arg, tuple) else (arg, None)
+            tl._enter(name, upto)
+        elif op == "exit":
+            tl._exit()
+        clock.t += seconds
+
+
+def _sync_step(wait_s=0.0):
+    """dispatch one program, wait for it: the device ends drained."""
+    return [("begin", None, 0.0), ("enter", "dispatch", 0.002),
+            ("exit", None, 0.0), ("enter", "device_wait", wait_s),
+            ("exit", None, 0.0)]
+
+
+# each case: (script, expected drained.by without the zeros, intervals)
+_DRAINED_CASES = {
+    # a drained device through the rest of the step, the loop's fan-out,
+    # an idle wait, and the next step up to its dispatch's exit
+    "sync_then_everything": (
+        [("loop", "between_steps", 0.0)] + _sync_step(0.010) + [
+            ("idle", None, 0.001),
+            ("enter", "detok", 0.003), ("exit", None, 0.0),
+            ("enter", "bank", 0.0005), ("exit", None, 0.0),
+            ("commit", None, 0.0007),          # fan-out: between_steps
+            ("loop", "no_work", 0.050),
+            ("loop", "between_steps", 0.0002),
+            ("begin", None, 0.0),
+            ("enter", "admit", 0.004),
+            ("enter", "page_alloc", 0.0015), ("exit", None, 0.0005),
+            ("exit", None, 0.0),
+            ("enter", "dispatch", 0.002), ("exit", None, 0.0),
+            ("enter", "device_wait", 0.010), ("exit", None, 0.0),
+            ("commit", None, 0.0)],
+        {"untracked": 0.001, "detok": 0.003, "bank": 0.0005,
+         "between_steps": 0.0009, "no_work": 0.050, "admit": 0.0045,
+         "page_alloc": 0.0015, "dispatch": 0.002}, 1),
+    # async scheduling: window k+1 is dispatched, THEN window k is waited
+    # for. A program is still in flight: nothing here is drained time
+    "async_wait_on_the_older_program": (
+        [("begin", None, 0.0), ("enter", "dispatch", 0.002),
+         ("exit", None, 0.0), ("commit", None, 0.0),
+         ("begin", None, 0.0), ("enter", "dispatch", 0.002),
+         ("exit", None, 0.0),
+         ("enter", ("device_wait", 1), 0.010), ("exit", None, 0.0),
+         ("enter", "detok", 0.003), ("exit", None, 0.0),
+         ("commit", None, 0.0)],
+        {}, 0),
+    # a chunk dispatched after the window and waited for: programs run in
+    # order, so the older window is done too, whatever its own wait says
+    "wait_on_the_newest_covers_the_older": (
+        [("begin", None, 0.0), ("enter", "dispatch", 0.002),
+         ("exit", None, 0.0), ("enter", "dispatch", 0.002),
+         ("exit", None, 0.0),
+         ("enter", "device_wait", 0.010), ("exit", None, 0.0),
+         ("enter", "detok", 0.003), ("exit", None, 0.0),
+         ("enter", ("device_wait", 1), 0.0001), ("exit", None, 0.0),
+         ("enter", "detok", 0.002), ("exit", None, 0.0),
+         ("commit", None, 0.0)],
+        {"detok": 0.005}, 0),
+    # a device_wait inside a drained interval (first-token sampling is an
+    # implicit program) is not drained time; the interval goes on after it
+    "a_wait_while_drained_is_left_out": (
+        _sync_step(0.010) + [
+            ("enter", "detok", 0.001), ("exit", None, 0.0),
+            ("enter", "device_wait", 0.004), ("exit", None, 0.0),
+            ("enter", "detok", 0.002), ("exit", None, 0.0),
+            ("commit", None, 0.0)],
+        {"detok": 0.003}, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DRAINED_CASES))
+def test_drained_time_is_put_down_to_segments(clock, case):
+    script, want, intervals = _DRAINED_CASES[case]
+    tl = StepTimeline(capacity=8, enabled=True)
+    t_start = clock.t
+    _play(tl, clock, script)
+    summ = tl.summary()
+    dr = summ["drained"]
+    assert set(dr["by"]) == set(DRAINED_KEYS)
+    got = {k: v for k, v in dr["by"].items() if v}
+    assert got == pytest.approx(want, abs=1e-9)
+    assert dr["count"] == intervals  # closed by a dispatch's exit
+    # every drained second is in exactly one segment, and all of them are
+    # the thread's time
+    assert sum(dr["by"].values()) == pytest.approx(dr["total_s"], abs=1e-6)
+    assert dr["total_s"] <= summ["loop_wall_s"] + 1e-9
+    assert summ["loop_wall_s"] <= clock.t - t_start + 1e-9
+    # bubble keeps its keys and follows drained.by
+    host = {k: v for k, v in got.items() if k != "no_work"}
+    if host:
+        bub = summ["bubble"]
+        assert set(bub) == {"gap_eater", "host_shares"}
+        assert bub["gap_eater"] == max(host, key=host.get)
+        assert bub["host_shares"] == pytest.approx(
+            {k: round(v / summ["loop_wall_s"], 4) for k, v in got.items()})
+    else:
+        assert "bubble" not in summ
+
+
+def test_loop_conservation(clock):
+    """Σ phases + untracked + between_steps + no_work = loop_wall_s =
+    the clock's own elapsed time, from the first declaration on."""
+    tl = StepTimeline(capacity=8, enabled=True)
+    clock.t += 5.0          # before any loop driver: nobody's time
+    t_start = clock.t
+    script, _, _ = _DRAINED_CASES["sync_then_everything"]
+    _play(tl, clock, script + [("loop", "no_work", 0.25),
+                               ("loop", "no_work", 0.05),
+                               ("loop", "between_steps", 0.0)])
+    summ = tl.summary()
+    parts = (sum(p["total_s"] for p in summ["phases"].values())
+             + summ["untracked_s"] + sum(summ["loop"].values()))
+    assert parts == pytest.approx(summ["loop_wall_s"], abs=1e-6)
+    assert summ["loop_wall_s"] == pytest.approx(clock.t - t_start, abs=1e-6)
+    assert summ["loop"] == pytest.approx(
+        {"between_steps": 0.0009, "no_work": 0.35}, abs=1e-6)
+    # the step's own account is what it was: two shipped metrics read it
+    assert summ["steps"] == 2
+    assert summ["wall_s"] == pytest.approx(0.0345, abs=1e-6)
+    assert summ["untracked_s"] == pytest.approx(0.001, abs=1e-6)
+    # a library caller of step() declares no loop: its time between steps
+    # is not the engine's, and loop_wall_s is the steps' wall
+    lib = StepTimeline(capacity=8, enabled=True)
+    _play(lib, clock, _sync_step(0.01) + [("commit", None, 3.0)]
+          + _sync_step(0.01) + [("commit", None, 0.0)])
+    ls = lib.summary()
+    assert ls["loop_wall_s"] == pytest.approx(ls["wall_s"]) \
+        == pytest.approx(0.024)
+    assert ls["drained"]["by"]["between_steps"] == 0.0
+    # reset() starts the account over and keeps the declared state
+    tl.reset()
+    clock.t += 0.5
+    tl.loop_state("no_work")
+    rs = tl.summary()
+    assert rs["loop_wall_s"] == pytest.approx(0.5) and rs["steps"] == 0
+    assert rs["loop"]["between_steps"] == pytest.approx(0.5)
+
+
+class _StubEngine:
+    """What EngineService._run needs of an engine: has_work, step(), and
+    a timeline. Each step is one dispatch and its wait."""
+
+    def __init__(self, steps):
+        self.timeline = StepTimeline(capacity=8, enabled=True)
+        self.remaining = steps
+        self.no_work_seen_while_busy = []
+        self.on_abort_all = None
+
+    @property
+    def has_work(self):
+        return self.remaining > 0
+
+    def step(self):
+        import time
+
+        tl = self.timeline
+        self.no_work_seen_while_busy.append(tl.loop_totals["no_work"])
+        tl.begin_step()
+        with tl.phase("dispatch"):
+            time.sleep(0.002)
+        with tl.phase("device_wait"):
+            pass
+        tl.commit_step()
+        self.remaining -= 1
+        return []
+
+
+def test_engine_loop_declares_no_work_only_without_work():
+    import time
+
+    from dynamo_tpu.serving.engine_service import EngineService
+
+    eng = _StubEngine(steps=20)
+    svc = EngineService(eng)
+    try:
+        deadline = time.monotonic() + 10.0
+        while eng.has_work and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert not eng.has_work
+        time.sleep(0.2)     # a few idle ticks of 50 ms
+    finally:
+        svc.close()
+    tl = eng.timeline
+    # while the engine had work the loop never declared `no_work` ...
+    assert eng.no_work_seen_while_busy == [0.0] * 20
+    summ = tl.summary()
+    assert summ["steps"] == 20
+    # ... and once it had none, the waiting is there, drained: every
+    # step's wait left nothing in flight
+    assert summ["loop"]["no_work"] >= 0.1
+    assert summ["loop"]["between_steps"] > 0.0
+    assert summ["drained"]["by"]["no_work"] == pytest.approx(
+        summ["loop"]["no_work"], abs=0.06)
+    assert summ["drained"]["count"] == 19
+    parts = (sum(p["total_s"] for p in summ["phases"].values())
+             + summ["untracked_s"] + sum(summ["loop"].values()))
+    assert parts == pytest.approx(summ["loop_wall_s"], abs=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# profiler annotations
+# ---------------------------------------------------------------------------
+def test_annotations_are_made_only_during_a_capture(clock):
+    log = []
+
+    class _Ann:
+        def __init__(self, name, **kw):
+            self.name, self.kw = name, kw
+
+        def __enter__(self):
+            log.append(("enter", self.name, self.kw))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    tl = StepTimeline(capacity=8, enabled=True)
+    step = _sync_step(0.01) + [("commit", None, 0.001)]
+    _play(tl, clock, [("loop", "between_steps", 0.0)] + step)
+    assert log == [], "no capture is open: no annotation may be made"
+    tl.start_annotations(_Ann, _Ann)
+    _play(tl, clock, [("loop", "between_steps", 0.0)] + step)
+    names = [e[1] for e in log if e[0] == "enter"]
+    assert names == ["stepline/between_steps", "stepline/step",
+                     "stepline/untracked", "stepline/dispatch",
+                     "stepline/untracked", "stepline/device_wait",
+                     "stepline/untracked", "stepline/between_steps"]
+    assert ("enter", "stepline/step", {"step_num": 1}) in log
+    # segments nest inside the step's annotation and never overlap
+    depth = 0
+    for e in log:
+        depth += 1 if e[0] == "enter" else -1
+        assert 0 <= depth <= 2
+    tl.stop_annotations()
+    n = len(log)
+    _play(tl, clock, [("loop", "between_steps", 0.0)] + step)
+    # the engine thread closes what it had open, and makes no more
+    assert log[n:] == [("exit", "stepline/between_steps")]
+    assert tl._tracing is False
 
 
 # ---------------------------------------------------------------------------

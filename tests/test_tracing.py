@@ -11,6 +11,7 @@ import pytest
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import Engine, PhaseTimer
 from dynamo_tpu.engine.request import GenRequest
+from dynamo_tpu.observability.tracing import new_trace_id
 from dynamo_tpu.serving.api import ServingContext, make_server
 
 
@@ -63,3 +64,218 @@ def test_worker_stats_include_phase_histograms(server):
     assert phases["prefill"]["count"] >= 1
     assert phases["decode_window"]["count"] >= 1
     assert phases["decode_step"]["p50_ms"] > 0
+
+
+# ---------------------------------------------------------------------------
+# a first token by stage, and the keys the benchmark's new metrics read
+# ---------------------------------------------------------------------------
+def _run_handle(ctx, rid, prompt, max_tokens, on_first=None):
+    """One request through the serving layer's own GenerationHandle, as an
+    HTTP handler drives it; returns the number of tokens emitted."""
+    import time
+
+    params = {"max_tokens": max_tokens, "temperature": 0.0, "top_p": 1.0,
+              "top_k": 0, "ignore_eos": True}
+    h = ctx.start_generation(rid, prompt, params,
+                             received_at=time.monotonic() - 0.004)
+    seen = []
+
+    def emit(delta, finish, lp_entry):
+        seen.append(delta)
+        if len(seen) == 1 and on_first is not None:
+            on_first()
+        return True
+
+    h.run(emit)
+    return len(seen)
+
+
+# plain: one whole-prompt prefill; chunked: the prompt in 8-token chunks
+# with decode windows between them; mixed: its chunks ride the decode step
+# of a request that is already streaming
+_ADMISSIONS = {
+    "plain": {},
+    "chunked": {"prefill_chunk_tokens": 8},
+    "mixed": {"prefill_chunk_tokens": 8, "mixed_batch_tokens": 8},
+}
+
+
+@pytest.mark.parametrize("admission", sorted(_ADMISSIONS))
+def test_first_token_stages_sum_to_ttft(admission):
+    cfg = EngineConfig(model="tiny-debug", page_size=4, num_pages=64,
+                       max_num_seqs=2, max_seq_len=64,
+                       **_ADMISSIONS[admission])
+    ctx = ServingContext(Engine(cfg), served_model="tiny-debug")
+    try:
+        prompt = list(range(1, 30))
+        if admission == "mixed":
+            # the long prompt arrives while another request decodes
+            second = threading.Thread(
+                target=_run_handle, args=(ctx, "ft-b", prompt, 4))
+            _run_handle(ctx, "ft-a", [3, 1, 4], 40, on_first=second.start)
+            second.join()
+            assert ctx.engine.metrics.mixed_count > 0, \
+                "the prompt's chunks did not ride a decode step"
+            want = 2
+        else:
+            _run_handle(ctx, "ft-a", prompt, 4)
+            want = 1
+        ft = ctx.engine.metrics.snapshot()["first_token"]
+    finally:
+        ctx.close()
+    assert ft["count"] == want
+    stages = [ft[k] for k in ("submit_s", "queue_s", "prefill_s", "emit_s")]
+    assert sum(stages) == pytest.approx(ft["ttft_s"], abs=1e-9)
+    # `received_at` was put 4 ms before the submit: that is the first stage
+    assert ft["submit_s"] >= 0.004 * want
+    assert all(s >= 0.0 for s in stages) and ft["prefill_s"] > 0.0
+    # a sum and a count: the benchmark reads their deltas over a window
+    assert ft["ttft_s"] / ft["count"] < 120.0
+
+
+def test_worker_stats_carry_what_the_layer_metrics_read(server):
+    """/worker/stats holds every counter that a benchmarks/chip/
+    layer_metrics file of this program's stepline and first-token account
+    names, and every key it had: three shipped metrics read those."""
+    import glob
+    import os
+
+    ctx, base = server
+    body = json.dumps({"model": "tiny-debug", "prompt": "abc",
+                       "max_tokens": 4, "stream": True,
+                       "ignore_eos": True}).encode()
+    urllib.request.urlopen(urllib.request.Request(
+        f"{base}/v1/completions", data=body,
+        headers={"Content-Type": "application/json"}), timeout=120).read()
+    stats = json.load(urllib.request.urlopen(f"{base}/worker/stats",
+                                             timeout=30))
+
+    def has(path):
+        node = stats
+        for key in path.split("."):
+            if not isinstance(node, dict) or key not in node:
+                return False
+            node = node[key]
+        return isinstance(node, (int, float))
+
+    chip = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip", "layer_metrics")
+    named = set()
+    for path in glob.glob(os.path.join(chip, "*.json")):
+        with open(path) as f:
+            args = json.load(f).get("args", {})
+        for value in args.values():
+            for item in (value if isinstance(value, list) else [value]):
+                if isinstance(item, str) and item.startswith(
+                        ("timeline.", "metrics.")):
+                    named.add(item)
+    assert {"timeline.loop_wall_s", "timeline.drained.by.no_work",
+            "metrics.first_token.ttft_s"} <= named
+    # a phase that never ran has no entry under timeline.phases: the
+    # shipped readers read an absent path as 0 (readers/stats_delta.py)
+    missing = sorted(p for p in named if not has(p)
+                     and not p.startswith("timeline.phases."))
+    assert not missing, f"/worker/stats lacks {missing}"
+    tl = stats["timeline"]
+    assert {"wall_s", "steps", "phases", "untracked_s", "host_gap", "bubble",
+            "loop_wall_s", "loop", "drained"} <= set(tl)
+    assert set(tl["bubble"]) == {"gap_eater", "host_shares"}
+    dr = tl["drained"]
+    assert sum(dr["by"].values()) == pytest.approx(dr["total_s"], abs=1e-4)
+    assert dr["total_s"] <= tl["loop_wall_s"] + 1e-6
+    # the request came through an HTTP handler: all four stages are there
+    ft = stats["metrics"]["first_token"]
+    assert ft["count"] >= 1 and ft["submit_s"] > 0.0 and ft["emit_s"] > 0.0
+
+
+def test_capture_annotates_the_stepline_and_then_stops(server):
+    """During /debug/trace the engine thread's segments are profiler
+    annotations; after it, none is open and none is made."""
+    import time
+
+    ctx, base = server
+    tl = ctx.engine.timeline
+    assert tl._tracing is False and tl._annotate is None
+    seen = []
+    real_start = tl.start_annotations
+
+    def spy(annotate, annotate_step=None):
+        def make(name, **kw):
+            seen.append(name)
+            return annotate(name, **kw)
+        real_start(make, annotate_step)
+
+    tl.start_annotations = spy
+    try:
+        worker = threading.Thread(target=_run_handle,
+                                  args=(ctx, "ann", [1, 2, 3], 6))
+        worker.start()
+        urllib.request.urlopen(f"{base}/debug/trace?duration_s=0.5",
+                               timeout=120).read()
+        worker.join()
+    finally:
+        del tl.start_annotations
+    assert "stepline/no_work" in seen
+    assert {n for n in seen} <= {
+        "stepline/" + k for k in ("admit", "page_alloc", "dispatch",
+                                  "device_wait", "detok", "bank",
+                                  "untracked", "between_steps", "no_work")}
+    deadline = time.monotonic() + 5.0
+    while tl._tracing and time.monotonic() < deadline:
+        time.sleep(0.02)    # the thread closes its last one at its next tick
+    assert tl._tracing is False and tl._ann is None and tl._ann_step is None
+    n = len(seen)
+    _run_handle(ctx, "ann2", [1, 2, 3], 3)
+    assert len(seen) == n, "an annotation was made outside a capture"
+
+
+def test_first_token_spans_share_the_request_trace_and_parent(server):
+    """worker.submit / .queue / .prefill / .emit: one trace id (the
+    request's), one parent (worker.request), end to end without a gap,
+    and they are the stages the counters summed."""
+    ctx, base = server
+    before = ctx.engine.metrics.snapshot()["first_token"]
+    rid = "ab" * 16
+    body = json.dumps({"model": "tiny-debug", "prompt": "hello there",
+                       "max_tokens": 4, "stream": True,
+                       "ignore_eos": True}).encode()
+    urllib.request.urlopen(urllib.request.Request(
+        f"{base}/v1/completions", data=body,
+        headers={"Content-Type": "application/json",
+                 "x-request-id": rid}), timeout=120).read()
+    after = ctx.engine.metrics.snapshot()["first_token"]
+    import time
+
+    request = []
+    deadline = time.monotonic() + 5.0
+    while not request and time.monotonic() < deadline:
+        # the request's own span ends a moment after the body is read
+        request = [sp for sp in ctx.tracer.collector.snapshot()
+                   if sp.name == "worker.request" and sp.end_ns
+                   and sp.trace_id == new_trace_id(rid)]
+        time.sleep(0.02)
+    request = request[-1]
+    stages = [sp for sp in ctx.tracer.collector.snapshot(
+                  trace_id=request.trace_id)
+              if sp.name in ("worker.submit", "worker.queue",
+                             "worker.prefill", "worker.emit")]
+    assert [sp.name for sp in sorted(stages, key=lambda sp: sp.start_ns)] \
+        == ["worker.submit", "worker.queue", "worker.prefill", "worker.emit"]
+    decode = [sp for sp in ctx.tracer.collector.snapshot(
+                  trace_id=request.trace_id) if sp.name == "worker.decode"]
+    for sp in stages + decode:
+        assert sp.trace_id == request.trace_id
+        assert sp.parent_span_id == request.span_id
+    ordered = sorted(stages, key=lambda sp: sp.start_ns)
+    for a, b in zip(ordered, ordered[1:]):
+        assert abs(a.end_ns - b.start_ns) <= 1  # one stamp ends a, starts b
+    assert ordered[0].start_ns >= request.start_ns - 1_000_000
+    # one source: the spans' lengths are what the counters grew by
+    grew = {k: after[k] - before[k] for k in after}
+    assert grew["count"] == 1
+    for sp, key in zip(ordered, ("submit_s", "queue_s", "prefill_s",
+                                 "emit_s")):
+        assert (sp.end_ns - sp.start_ns) / 1e9 == pytest.approx(
+            grew[key], abs=2e-6)
+    assert sum((sp.end_ns - sp.start_ns) for sp in ordered) / 1e9 \
+        == pytest.approx(grew["ttft_s"], abs=1e-5)

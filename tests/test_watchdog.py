@@ -340,9 +340,13 @@ def _greedy(eng, rid, max_tokens=10):
                                    ignore_eos=True))
 
 
-def test_fatal_step_inline_resurrection_then_quarantine():
+@pytest.mark.parametrize("quantization", [None, "w8a8"])
+def test_fatal_step_inline_resurrection_then_quarantine(quantization):
+    """w8a8 too: a quantized weight is a (values, scales) pair, and a
+    resurrection that cannot restage it quarantines the worker for good
+    (seen on the chip, PERF.md PR 25: every request after it got 503)."""
     faults.reset_plane()
-    eng = _engine()
+    eng = _engine(**({"quantization": quantization} if quantization else {}))
     ref = _greedy(eng, "r0")
 
     # first fatal step: trip -> inline resurrection -> healthy, and the

@@ -27,10 +27,10 @@ reports platform "tpu" and a device kind found in the one chip table
 (dynamo_tpu/profiler/systems.py), the watchdog is healthy with no trips and
 no integrity faults and its hang deadline armed once warmup completed, no
 program was compiled after warmup, the only Pallas->XLA demotions are the
-ones listed in EXPECTED_FALLBACKS, decode / prefill / chunk attention were
-traced as `pallas`, and both children exit 0 after SIGTERM. The line before
-last is the full summary (set-up reported apart from serving; no throughput
-figure is derived: this is a smoke, not a benchmark).
+ones listed in EXPECTED_FALLBACKS (none), decode / prefill / chunk / ragged
+attention were traced as `pallas`, and both children exit 0 after SIGTERM.
+The line before last is the full summary (set-up reported apart from
+serving; no throughput figure is derived: this is a smoke, not a benchmark).
 """
 
 from __future__ import annotations
@@ -64,18 +64,13 @@ MAX_NUM_SEQS = 8
 
 # Pallas->XLA demotions the smoke expects, by (op, reason). Anything else in
 # dynamo_pallas_fallback_total fails the run.
-EXPECTED_FALLBACKS = {
-    # the unified mixed prefill+decode step runs its XLA composition until
-    # ROADMAP S4 judges the ragged kernel on a cell and flips
-    # RAGGED_KERNEL_HW_VALIDATED; the kernel itself compiles and passes
-    # parity on the chip (PERF.md, kernel table)
-    ("ragged attention", "not_validated"),
-}
+EXPECTED_FALLBACKS: set = set()
 # the rehearsal forces interpret-mode kernels onto tiny-debug, whose fused
-# KV*D lane span (2 x 32) is below the 128-lane DMA tile: decode and chunk
-# attention demote at the lane gate there (and only there)
+# KV*D lane span (2 x 32) is below the 128-lane DMA tile: decode, chunk and
+# ragged attention demote at the lane gate there (and only there)
 REHEARSAL_FALLBACKS = EXPECTED_FALLBACKS | {
     ("decode", "lane_gate"), ("chunk attention", "lane_gate"),
+    ("ragged attention", "lane_gate"),
 }
 
 # a cold /ready (46 programs at 7B widths, empty compile cache) took
@@ -566,7 +561,8 @@ def run(args) -> dict:
                             f"(all: {fallbacks})")
         traced = after["attention"]["traced"]
         if not rehearsal:
-            for op in ("decode", "prefill", "chunk attention"):
+            for op in ("decode", "prefill", "chunk attention",
+                       "ragged attention"):
                 if set(traced.get(op, {})) != {"pallas"}:
                     failures.append(
                         f"{op} attention traced as {traced.get(op)}, "

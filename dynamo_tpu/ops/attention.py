@@ -538,11 +538,10 @@ def ragged_mixed_attention(
     existing inactive-slot contract).
 
     Dispatch mirrors chunk_attention: DYNAMO_TPU_RAGGED_ATTENTION wins when
-    set; otherwise the Pallas kernel is selected by the scoped backend once
-    RAGGED_KERNEL_HW_VALIDATED flips (until then the XLA composition —
-    decode gather + chunk gather — serves every backend). The same
-    head/lane gates guard the kernel, with demotions counted via
-    _note_fallback.
+    set; otherwise the scoped backend selects (`auto` on a TPU: the Pallas
+    kernel, whose work follows the live KV; elsewhere the XLA composition —
+    decode gather + chunk gather over the whole table). The same head/lane
+    gates guard the kernel, with demotions counted via _note_fallback.
     """
     backend = _ragged_backend(window, logit_cap)
     n_kv = _pool_kv_heads(k_pages, q.shape[2], num_kv_heads)
@@ -640,11 +639,11 @@ def ragged_verify_attention(
     window's pages (drafts' K/V already written, like verify_attention).
 
     Dispatch mirrors ragged_mixed_attention: DYNAMO_TPU_RAGGED_ATTENTION
-    wins when set; otherwise the Pallas kernel (each window = one padded
-    query block, via decode_q=K1) is selected once RAGGED_KERNEL_HW_VALIDATED
-    flips, and until then the XLA composition — verify gather + chunk gather
-    — serves every backend. Inactive windows carry zero tables + position 0
-    (trash-page rows, outputs discarded by the engine)."""
+    wins when set; otherwise the scoped backend selects the Pallas kernel
+    (each window = one padded query block, via decode_q=K1) or the XLA
+    composition — verify gather + chunk gather. Inactive windows carry zero
+    tables + position 0 (trash-page rows, outputs discarded by the
+    engine)."""
     backend = _ragged_backend(window, logit_cap)
     n_kv = _pool_kv_heads(k_pages, q.shape[2], num_kv_heads)
     b, k1 = num_verify, verify_width
@@ -817,8 +816,9 @@ def _demote(backend: str, op: str, reason: str, detail: str = "") -> str:
 
 def _ragged_backend(window, logit_cap) -> str:
     """Backend for the two ragged ops: DYNAMO_TPU_RAGGED_ATTENTION wins
-    when set; otherwise the scoped backend, demoted (visibly) to the XLA
-    composition until RAGGED_KERNEL_HW_VALIDATED flips."""
+    when set; otherwise the scoped backend (RAGGED_KERNEL_HW_VALIDATED is
+    True since PR 26; pulled to False it demotes, visibly, to the XLA
+    composition). Windows, score caps and seq meshes demote either way."""
     op = "ragged attention"
     backend = os.environ.get("DYNAMO_TPU_RAGGED_ATTENTION")
     if not backend:

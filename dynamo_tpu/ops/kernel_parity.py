@@ -2,7 +2,10 @@
 
 Interpret mode proves a kernel's arithmetic; only Mosaic on a real chip
 proves it lowers. This module compiles each kernel at a serving model's
-head shapes and compares it with its XLA twin from `ops/attention.py`:
+head shapes — and the ragged kernel at the benchmark cells' own mixed step
+(`ragged_cell_*`: 32 slots mostly inactive, tables 128 wide, a 256-token
+chunk at positions 0 and 1536, 28/4 and 32/8 heads) — and compares it with
+its XLA twin from `ops/attention.py`:
 
     python -m dynamo_tpu.ops.kernel_parity            # on the chip
     python -m dynamo_tpu.ops.kernel_parity --interpret  # CPU rehearsal
@@ -53,17 +56,17 @@ SHAPES: Tuple[Tuple[str, int, int], ...] = (
 )
 
 
-def _pools(rng, n_kv: int, quantized: bool):
-    n = NUM_POOL_PAGES * PAGE_SIZE
+def _pools(rng, n_kv: int, quantized: bool, pages: int = NUM_POOL_PAGES):
+    n = pages * PAGE_SIZE
     kf = rng.normal(size=(n, n_kv, HEAD_DIM)).astype(np.float32)
     vf = rng.normal(size=(n, n_kv, HEAD_DIM)).astype(np.float32)
     if quantized:
         w = att.kv_lane_width(n_kv, HEAD_DIM, True)
         return (att.pack_kv_rows(jnp.asarray(kf), w).reshape(
-                    NUM_POOL_PAGES, PAGE_SIZE, w),
+                    pages, PAGE_SIZE, w),
                 att.pack_kv_rows(jnp.asarray(vf), w).reshape(
-                    NUM_POOL_PAGES, PAGE_SIZE, w))
-    shape = (NUM_POOL_PAGES, PAGE_SIZE, n_kv * HEAD_DIM)
+                    pages, PAGE_SIZE, w))
+    shape = (pages, PAGE_SIZE, n_kv * HEAD_DIM)
     return (jnp.asarray(kf.reshape(shape), jnp.bfloat16),
             jnp.asarray(vf.reshape(shape), jnp.bfloat16))
 
@@ -152,19 +155,75 @@ def _case_ragged(h: int, n_kv: int, quantized: bool, decode_q: int,
         pos = jnp.minimum(cl - 1, bt.shape[1] * PAGE_SIZE - decode_q)
         args = (q, kp, vp, bt, pos, pages, start)
 
-    def forced(backend: str) -> Callable:
-        # a fresh function object per backend: jit's trace cache is keyed
-        # on the function, and the env var is read at trace time
-        fn = jax.jit(lambda *a: op(*a))
+    return (_forced(op, "pallas_interpret" if interpret else "pallas"),
+            _forced(op, "xla"), args)
 
-        def run(*a):
-            with mock.patch.dict(
-                    os.environ, {"DYNAMO_TPU_RAGGED_ATTENTION": backend}):
-                return fn(*a)
-        return run
 
-    return (forced("pallas_interpret" if interpret else "pallas"),
-            forced("xla"), args)
+def _forced(op: Callable, backend: str) -> Callable:
+    """`op` jitted with the ragged dispatch forced to `backend`. A fresh
+    function object per backend: jit's trace cache is keyed on the
+    function, and the env var is read at trace time."""
+    fn = jax.jit(lambda *a: op(*a))
+
+    def run(*a):
+        with mock.patch.dict(
+                os.environ, {"DYNAMO_TPU_RAGGED_ATTENTION": backend}):
+            return fn(*a)
+    return run
+
+
+# The benchmark cells' mixed step (benchmarks/chip/configs/*.json: 32 slots,
+# --max-seq-len 2048, --mixed-batch-tokens 256, page 16): heads as served,
+# whole on one chip
+CELL_SHAPES: Tuple[Tuple[str, int, int], ...] = (
+    ("28q4kv", 28, 4),   # qwen2.5-7b-w8a8
+    ("32q8kv", 32, 8),   # mixtral-8x7b-w8a8-1chip
+)
+CELL_SLOTS = 32
+CELL_TABLE_WIDTH = 128
+CELL_CHUNK = 256
+CELL_POOL_PAGES = 640
+# live slots (slot -> context length); the other 26 carry the engine's
+# inactive-slot contract: a zero table and context_lens 1
+CELL_LIVE = {0: 517, 3: 300, 4: 16, 9: 129, 17: 1999, 31: 800}
+
+
+# (chunk start, width of the chunk's page table): a prompt's first chunk in
+# the 256 bucket and the seventh in the 2048 bucket; the width is the
+# bucket's pages plus chunk_pages - 1 trash slots
+# (KVCacheSpec.page_table_width)
+CELL_CHUNKS = ((0, 16 + 15), (1536, 128 + 15))
+
+
+def _case_ragged_cell(h: int, n_kv: int, p_start: int, width: int,
+                      interpret: bool):
+    """The cells' own mixed step: 32 decode rows of which 6 live, tables
+    128 wide, one 256-token chunk at `p_start`, bf16 pool."""
+    rng = np.random.default_rng(26 + p_start)
+    kp, vp = _pools(rng, n_kv, False, pages=CELL_POOL_PAGES)
+    tables = np.zeros((CELL_SLOTS, CELL_TABLE_WIDTH), np.int32)
+    ctx = np.ones((CELL_SLOTS,), np.int32)
+    nxt = 1
+    for slot, c in CELL_LIVE.items():
+        n = -(-c // PAGE_SIZE)
+        tables[slot, :n] = np.arange(nxt, nxt + n)
+        ctx[slot] = c
+        nxt += n
+    used = (p_start + CELL_CHUNK) // PAGE_SIZE
+    assert nxt + used <= CELL_POOL_PAGES
+    pages = np.zeros((width,), np.int32)
+    pages[:used] = np.arange(nxt, nxt + used)
+    q = jnp.asarray(
+        rng.normal(size=(CELL_SLOTS + CELL_CHUNK, h, HEAD_DIM)), jnp.bfloat16)
+
+    def op(*a):
+        return att.ragged_mixed_attention(
+            *a, page_size=PAGE_SIZE, num_kv_heads=n_kv,
+            num_decode=CELL_SLOTS)
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(ctx),
+            jnp.asarray(pages), jnp.asarray(p_start, jnp.int32))
+    return (_forced(op, "pallas_interpret" if interpret else "pallas"),
+            _forced(op, "xla"), args)
 
 
 def cases(interpret: bool) -> List[Tuple[str, Callable]]:
@@ -182,6 +241,11 @@ def cases(interpret: bool) -> List[Tuple[str, Callable]]:
         add("ragged_mixed_bf16", _case_ragged, h, n_kv, False, 1)
         add("ragged_mixed_int8kv", _case_ragged, h, n_kv, True, 1)
         add("ragged_verify_q5_bf16", _case_ragged, h, n_kv, False, 5)
+    for label, h, n_kv in CELL_SHAPES:
+        for p_start, width in CELL_CHUNKS:
+            out.append((f"ragged_cell_p{p_start}_bf16/{label}",
+                        functools.partial(_case_ragged_cell, h, n_kv,
+                                          p_start, width, interpret)))
     return out
 
 

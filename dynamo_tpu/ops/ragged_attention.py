@@ -9,10 +9,15 @@ tokens ride the same launch as decode slots instead of preempting them — the
 scheduling shape that collapses the engine's fused-window zoo (see
 `dynamo_tpu.engine` mixed step).
 
-Kernel anatomy is deliberately identical to `_chunk_kernel` /`_decode_kernel`
-in `pallas_attention.py` (page-major fused-head KV, multi-page superblock DMA
+Kernel anatomy follows `_chunk_kernel` / `_decode_kernel` in
+`pallas_attention.py` (page-major fused-head KV, multi-page superblock DMA
 ring pipelined across a sequential grid via a persistent SMEM cursor,
-block-diagonal GQA matmuls, int8 packed-scale rows dequantized in-VMEM):
+block-diagonal GQA matmuls, int8 packed-scale rows dequantized in-VMEM).
+Where it departs, it is to be small: the kernel is traced and lowered for
+every mixed program (16 at warmup, ~1 s each on a serving host before
+PR 26), so the page copies are a loop, `//` on traced integers is one
+`lax.div`, and the wrapper is jitted so that programs that share shapes
+share one trace:
 
 - Grid is `(num_q_blocks, nk_max)` where the first `num_decode` query blocks
   are the decode slots (one real row each, padded to `block_q`) and the rest
@@ -36,13 +41,20 @@ NaN-safety mirrors the house kernels: token 0 is unmasked for every row of
 every sequence at its first KV block (`q_start >= 0`, `kv_len >= 1`), so the
 running max is finite from the first `_flash_update` on.
 
-Hardware-validation gating follows the CHUNK_KERNEL convention: while
-`RAGGED_KERNEL_HW_VALIDATED` is False the dispatch in `attention.py` keeps
-the XLA composition as default (counted in dynamo_pallas_fallback_total) and
-the kernel is env-opt-in (`DYNAMO_TPU_RAGGED_ATTENTION=pallas`). The kernel
-compiles on a v5e and passes parity there, mixed and verify (PERF.md); what
-is still missing is a benchmark cell that judges it as the default step
-(ROADMAP S4).
+Live KV only: a block's page copies never follow its table past the block's
+horizon (a superblock's tail re-reads the last live page), so the work, the
+bytes and the result depend on nothing a sequence does not own below its
+kv_len — the property the mixed step pays for (the XLA composition it
+replaced gathers max_num_seqs x max_seq_len whatever is live).
+
+Hardware-validation gating follows the CHUNK_KERNEL convention:
+`RAGGED_KERNEL_HW_VALIDATED` is True, so the dispatch in `attention.py`
+follows the scoped backend (`auto` -> this kernel on a TPU). It was flipped
+by PR 26 after on-chip parity at the benchmark cells' own shapes and after
+both cells judged it as the default step (PERF.md section 6). With the flag
+False the XLA composition serves every backend (counted in
+dynamo_pallas_fallback_total); `DYNAMO_TPU_RAGGED_ATTENTION` overrides
+either way.
 """
 
 from __future__ import annotations
@@ -64,12 +76,19 @@ from dynamo_tpu.ops.pallas_attention import (
     _flash_update,
 )
 
-# Flipped by ROADMAP S4, on a cell: the on-chip parity check
-# (ops/kernel_parity.py) already passes for mixed decode+chunk batches, bf16
-# and int8-KV, and for decode_q verify windows. Until then the ragged ops
-# default to the XLA composition on every backend and
-# DYNAMO_TPU_RAGGED_ATTENTION=pallas opts in.
-RAGGED_KERNEL_HW_VALIDATED = False
+# True since PR 26: on-chip parity at the benchmark cells' own shapes
+# (ops/kernel_parity.py, `ragged_cell_*`) and both cells judged the kernel
+# as the default mixed step (PERF.md section 6). Kept, with the
+# `not_validated` route and DYNAMO_TPU_RAGGED_ATTENTION, for the parity
+# tool and the tests until ROADMAP D1 removes the duplicate kernels.
+RAGGED_KERNEL_HW_VALIDATED = True
+
+
+def _div(x, n: int):
+    """x // n for x >= 0, as ONE operation: `//` on a traced integer is
+    Python's floor division, seven operations that truncation does not
+    need here, and the kernel's size is paid at every trace and lowering."""
+    return jax.lax.div(x, jnp.int32(n))
 
 
 def _ragged_kernel(
@@ -122,38 +141,48 @@ def _ragged_kernel(
         # the block's token offset within its sequence's query span
         return jnp.maximum(qq - num_decode, 0) * block_q
 
-    def block_copies(qq, kk, slot):
-        r = seq_row(qq)
-        out = []
-        for j in range(block_pages):
-            pg = tables_ref[
-                r, jnp.minimum(kk * block_pages + j, table_width - 1)]
-            out.append(pltpu.make_async_copy(
-                k_hbm.at[pg], kbuf.at[slot, j], sem.at[slot, 0, j]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[pg], vbuf.at[slot, j], sem.at[slot, 1, j]))
-        return out
-
-    def n_blocks(qq):
+    def horizon(qq):
         # causal horizon of block qq clamped to its sequence's kv length
         # (a decode block stops at its context; a chunk block never reads
-        # past the chunk end). Clamped >= 1 so every block owns at least
-        # one pipeline step — breaking issue/consume pairing would corrupt
-        # the DMA slot parity.
+        # past the chunk end), and >= 1: see n_blocks
         r = seq_row(qq)
-        horizon = jnp.minimum(qstart_ref[r] + q_off(qq) + block_q,
-                              kvlen_ref[r])
-        horizon = jnp.maximum(horizon, 1)
-        return (horizon + tokens_per_block - 1) // tokens_per_block
+        hz = jnp.minimum(qstart_ref[r] + q_off(qq) + block_q, kvlen_ref[r])
+        return jnp.maximum(hz, 1)
+
+    def block_dma(qq, kk, slot, wait):
+        """Start (or wait for) the 2 * block_pages page copies of KV block
+        kk of query block qq into ring slot `slot`. A loop, not an unrolled
+        list: the kernel is traced and lowered once per mixed program (16
+        at warmup), and its size is set-up time."""
+        r = seq_row(qq)
+        # live KV only: a superblock's tail past the horizon re-reads the
+        # last live page instead of following the table, so neither a
+        # table entry past the horizon nor the page it names is ever read
+        last = jnp.minimum(_div(horizon(qq) - 1, page_size), table_width - 1)
+
+        def page(j, carry):
+            # a wait needs the copy's shape and semaphore, not its source
+            pg = 0 if wait else tables_ref[
+                r, jnp.minimum(kk * block_pages + j, last)]
+            for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                c = pltpu.make_async_copy(
+                    hbm.at[pg], buf.at[slot, j], sem.at[slot, which, j])
+                c.wait() if wait else c.start()
+            return carry
+
+        jax.lax.fori_loop(0, block_pages, page, 0)
+
+    def n_blocks(qq):
+        # >= 1 (horizon is): every block owns at least one pipeline step —
+        # breaking issue/consume pairing would corrupt the DMA slot parity
+        return _div(horizon(qq) + tokens_per_block - 1, tokens_per_block)
 
     def issue_one():
         iq, ik = ptr_ref[1], ptr_ref[2]
 
         @pl.when(iq < nq)
         def _():
-            slot = jax.lax.rem(ptr_ref[3], num_bufs)
-            for c in block_copies(iq, ik, slot):
-                c.start()
+            block_dma(iq, ik, jax.lax.rem(ptr_ref[3], num_bufs), wait=False)
             ptr_ref[3] = ptr_ref[3] + 1
             nxt = ik + 1
             done = nxt >= n_blocks(iq)
@@ -176,20 +205,20 @@ def _ragged_kernel(
         cnt = ptr_ref[0]
         cur = jax.lax.rem(cnt, num_bufs)
         issue_one()
-        for c in block_copies(qb, kb, cur):
-            c.wait()
+        block_dma(qb, kb, cur, wait=True)
         ptr_ref[0] = cnt + 1
 
-        row_kv = (jax.lax.broadcasted_iota(jnp.int32, (rows, kvd), 0)
-                  % h) // group
-        lane_kv = jax.lax.broadcasted_iota(jnp.int32, (rows, kvd), 1) // d
-        bd_mask = row_kv == lane_kv
+        def bd_mask():
+            # only the first and the last KV block of a query block need it
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, kvd), 0)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (rows, kvd), 1)
+            return _div(jax.lax.rem(row, h), group) == _div(lane, d)
 
         @pl.when(kb == 0)
         def _reset():
             _flash_reset(m_ref, l_ref, acc_ref)
             q = q_ref[0].astype(jnp.float32).reshape(rows, d) * scale
-            qbd_ref[...] = jnp.where(bd_mask, jnp.tile(q, (1, n_kv)), 0.0)
+            qbd_ref[...] = jnp.where(bd_mask(), jnp.tile(q, (1, n_kv)), 0.0)
 
         if quantized:
             k = _dequant_rows(kbuf[cur].reshape(tokens_per_block, lane_width),
@@ -207,22 +236,26 @@ def _ragged_kernel(
             jnp.int32, s.shape, 1
         )
         r = seq_row(qb)
-        qpos = qstart_ref[r] + q_off(qb) + (
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // h
-        )
+        qpos = qstart_ref[r] + q_off(qb) + _div(
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, 0), h)
         s = jnp.where((tok <= qpos) & (tok < kvlen_ref[r]), s, NEG_INF)
         _flash_update(m_ref, l_ref, acc_ref, s, v)
 
         @pl.when(kb == nb_q - 1)
         def _finalize():
             out = _flash_normalize(l_ref, acc_ref)  # [rows, KVD]
-            out = jnp.where(bd_mask, out, 0.0)
+            out = jnp.where(bd_mask(), out, 0.0)
             folded = out[:, 0:d]
             for kv in range(1, n_kv):
                 folded = folded + out[:, kv * d:(kv + 1) * d]
             o_ref[0] = folded.reshape(block_q, h, d).astype(o_ref.dtype)
 
 
+# jitted so that the kernel body is traced once per shape, not once per
+# enclosing program: 14 of the engine's 16 mixed programs share one shape
+@functools.partial(jax.jit, static_argnames=(
+    "page_size", "num_kv_heads", "num_decode", "decode_q", "block_q",
+    "block_pages", "num_bufs", "interpret"))
 def ragged_paged_attention(
     q: jax.Array,  # [num_decode * decode_q + C, H, D] — leading rows, chunk
     k_pages: jax.Array,  # [P, ps, KV*D] (or int8 packed single-block rows)

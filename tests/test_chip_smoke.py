@@ -135,9 +135,11 @@ def test_every_auto_to_xla_route_is_counted(monkeypatch, route):
                 "DYNAMO_TPU_RAGGED_ATTENTION"):
         monkeypatch.delenv(var, raising=False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if route == ("ragged attention", "window_softcap"):
-        # past its own gate, the window demotion must still be counted
-        monkeypatch.setattr(ra, "RAGGED_KERNEL_HW_VALIDATED", True)
+    # the ragged kernel is the default since PR 26: its gate is a route
+    # only when the flag is pulled, and every other route lies past it
+    assert ra.RAGGED_KERNEL_HW_VALIDATED is True
+    if route == ("ragged attention", "not_validated"):
+        monkeypatch.setattr(ra, "RAGGED_KERNEL_HW_VALIDATED", False)
     before = att.pallas_fallback_counts().get(route, 0)
     out = _route_calls()[route]()
     assert np.all(np.isfinite(np.asarray(out)))

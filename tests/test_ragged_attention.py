@@ -117,9 +117,10 @@ def test_ragged_kernel_matches_xla_composition(monkeypatch, quantized):
 
 
 def test_ragged_kernel_gated_until_hw_validated(monkeypatch):
-    """With no env override the ragged dispatch stays on the XLA
-    composition until RAGGED_KERNEL_HW_VALIDATED flips; once flipped it
-    follows the engine's scoped attention backend (CHUNK_KERNEL idiom)."""
+    """With no env override the ragged dispatch follows the engine's
+    scoped attention backend (the default since PR 26's cells judged the
+    kernel); with RAGGED_KERNEL_HW_VALIDATED pulled back to False it stays
+    on the XLA composition (CHUNK_KERNEL idiom)."""
     from dynamo_tpu.ops import attention as att
     from dynamo_tpu.ops import ragged_attention as ra
 
@@ -135,17 +136,89 @@ def test_ragged_kernel_gated_until_hw_validated(monkeypatch):
         return real(*a, **k)
 
     monkeypatch.setattr(ra, "ragged_paged_attention", spy)
+    assert ra.RAGGED_KERNEL_HW_VALIDATED is True  # the shipped default
     with att.attention_context("pallas_interpret", None):
+        att.ragged_mixed_attention(q, kp, vp, tabs, ctx, pp, start,
+                                   page_size=16, num_kv_heads=2,
+                                   num_decode=3)
+        assert calls  # validated: follows the engine backend
+        del calls[:]
         monkeypatch.setattr(ra, "RAGGED_KERNEL_HW_VALIDATED", False)
         att.ragged_mixed_attention(q, kp, vp, tabs, ctx, pp, start,
                                    page_size=16, num_kv_heads=2,
                                    num_decode=3)
         assert not calls  # not validated: XLA path even under pallas ctx
-        monkeypatch.setattr(ra, "RAGGED_KERNEL_HW_VALIDATED", True)
-        att.ragged_mixed_attention(q, kp, vp, tabs, ctx, pp, start,
-                                   page_size=16, num_kv_heads=2,
-                                   num_decode=3)
-        assert calls  # validated: follows the engine backend
+
+
+def _poisoned_cell(rng, start, k1=1):
+    """The cells' mixed step in small: 32 slots of which 5 live, 32/8
+    heads, one 16-token chunk at `start`. Returns the clean operands and a
+    poisoned twin: NaN in every pool page that no row reaches below its
+    kv_len, and every table entry past a row's live pages naming one."""
+    import jax.numpy as jnp
+
+    ps, n_pool, b, h, n_kv, d, width, c = 4, 96, 32, 32, 8, 16, 16, 16
+    kf = rng.normal(size=(n_pool, ps, n_kv * d)).astype(np.float32)
+    vf = rng.normal(size=(n_pool, ps, n_kv * d)).astype(np.float32)
+    live = {1: 5, 7: 33, 12: ps * width - k1 + 1, 20: 17, 31: 48}
+    tables = np.zeros((b, width), np.int32)
+    ctx = np.ones((b,), np.int32)  # the inactive-slot contract
+    n_live_pages = np.ones((b,), np.int32)
+    nxt = 1
+    for slot, n_tok in live.items():
+        # a verify window's horizon is its last draft: k1 - 1 tokens more
+        n = -(-(n_tok + k1 - 1) // ps)
+        tables[slot, :n] = np.arange(nxt, nxt + n)
+        ctx[slot], n_live_pages[slot] = n_tok, n
+        nxt += n
+    wp = (start + c) // ps + 3
+    p_pages = np.zeros((wp,), np.int32)
+    p_pages[:(start + c) // ps] = np.arange(nxt, nxt + (start + c) // ps)
+    nxt += (start + c) // ps
+    dead = n_pool - 1
+    assert nxt < dead
+    bad_k, bad_v = kf.copy(), vf.copy()
+    bad_k[nxt:], bad_v[nxt:] = np.nan, np.nan
+    bad_tables, bad_pages = tables.copy(), p_pages.copy()
+    for slot in range(b):
+        bad_tables[slot, n_live_pages[slot]:] = dead
+    bad_pages[(start + c) // ps:] = dead
+    q = jnp.asarray(rng.normal(size=(b * k1 + c, h, d)), jnp.float32)
+    clean = (q, jnp.asarray(kf), jnp.asarray(vf), jnp.asarray(tables),
+             jnp.asarray(ctx), jnp.asarray(p_pages), start)
+    bad = (q, jnp.asarray(bad_k), jnp.asarray(bad_v),
+           jnp.asarray(bad_tables), jnp.asarray(ctx),
+           jnp.asarray(bad_pages), start)
+    return clean, bad, dict(page_size=ps, num_kv_heads=n_kv)
+
+
+@pytest.mark.parametrize("start,k1", [(0, 1), (32, 1), (32, 3)],
+                         ids=["first_chunk", "mid_prompt", "verify_k3"])
+def test_ragged_kernel_attends_live_kv_only(monkeypatch, start, k1):
+    """What PR 26 made the default does work in proportion to the live KV:
+    with every dead page and every table entry past a row's live pages
+    poisoned, the kernel's output is finite and equal to the composition's
+    on the clean pool, while the composition itself, which gathers the
+    whole table, turns NaN on the poisoned one."""
+    from dynamo_tpu.ops import attention as att
+
+    clean, bad, kw = _poisoned_cell(np.random.default_rng(26), start, k1)
+
+    def run(backend, args):
+        monkeypatch.setenv("DYNAMO_TPU_RAGGED_ATTENTION", backend)
+        if k1 == 1:
+            return np.asarray(att.ragged_mixed_attention(
+                *args, num_decode=32, **kw))
+        q, kp, vp, tabs, ctx, pp, st = args
+        return np.asarray(att.ragged_verify_attention(
+            q, kp, vp, tabs, ctx - 1, pp, st, num_verify=32,
+            verify_width=k1, **kw))
+
+    ref = run("xla", clean)
+    out = run("pallas_interpret", bad)
+    assert np.all(np.isfinite(out))
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    assert np.isnan(run("xla", bad)).any()  # the poison is in reach of a gather
 
 
 def test_ragged_gate_demotion_is_counted(monkeypatch):

@@ -47,6 +47,7 @@ from dynamo_tpu.lora.registry import NoFreeAdapterSlot
 from dynamo_tpu.models import llama
 from dynamo_tpu.ops import attention as att_ops
 from dynamo_tpu.ops import json_guide
+from dynamo_tpu.ops.moe import MOE_STATS
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.parallel.mesh import MeshConfig, build_mesh
 from dynamo_tpu.parallel import sharding as shd
@@ -223,6 +224,69 @@ class EngineMetrics:
             "count": 0, "submit_s": 0.0, "queue_s": 0.0, "prefill_s": 0.0,
             "emit_s": 0.0, "ttft_s": 0.0}
         self._first_token_lock = threading.Lock()
+        # what the grouped expert layers counted (ops/moe.MOE_STATS), summed
+        # over layers and steps. Each step program returns its counts as a
+        # small device array; they wait in _moe_pending and are folded in
+        # when read, so the step loop never waits on one
+        # what the paged attention kernels of an MLA model were asked for
+        # (all zero for any other model), counted on the host at dispatch,
+        # a layer's worth (the benchmark's roofline
+        # shares multiply by the layers): query rows, and KV rows read —
+        # a decode row reads its whole context once; a chunk's query block
+        # of 8 reads its causal horizon (chunk_block_kv_rows) and each of
+        # its tokens scores its own (chunk_kv_pairs)
+        self.attn: Dict[str, int] = {
+            "decode_q_rows": 0, "decode_kv_rows": 0,
+            "mixed_decode_q_rows": 0, "mixed_decode_kv_rows": 0,
+            "mixed_chunk_q_rows": 0, "mixed_chunk_block_kv_rows": 0,
+            "mixed_chunk_kv_pairs": 0}
+        # all zero for a model whose expert layers do not count
+        self.moe: Dict[str, int] = dict.fromkeys(MOE_STATS, 0)
+        self._moe_pending: list = []
+        self._moe_lock = threading.Lock()
+
+    def observe_decode_attention(self, contexts, steps: int) -> None:
+        """A fused window of `steps` decode steps over sequences whose
+        contexts (tokens in the cache, the one being decoded included)
+        are `contexts` at its first step."""
+        a = self.attn
+        a["decode_q_rows"] += len(contexts) * steps
+        a["decode_kv_rows"] += (sum(contexts) * steps
+                                + len(contexts) * steps * (steps - 1) // 2)
+
+    def observe_mixed_attention(self, contexts, start: int, take: int,
+                                block_q: int = 8) -> None:
+        """One ragged step: decode rows as above, and `take` tokens of a
+        prompt from position `start`."""
+        a = self.attn
+        a["mixed_decode_q_rows"] += len(contexts)
+        a["mixed_decode_kv_rows"] += sum(contexts)
+        a["mixed_chunk_q_rows"] += take
+        a["mixed_chunk_kv_pairs"] += take * start + take * (take + 1) // 2
+        blocks = -(-take // block_q)
+        a["mixed_chunk_block_kv_rows"] += (
+            blocks * start + block_q * blocks * (blocks + 1) // 2)
+
+    def observe_moe(self, stats) -> None:
+        """One program's expert-layer counts (a device array, maybe still
+        being computed)."""
+        with self._moe_lock:
+            self._moe_pending.append(stats)
+            if len(self._moe_pending) < 256:
+                return
+            # the oldest finished long ago: reading them cannot wait
+            done = self._moe_pending[:-8]
+            del self._moe_pending[:-8]
+        self._fold_moe(done)
+
+    def _fold_moe(self, done: list) -> None:
+        """Read the counts back (outside the lock: the newest may still be
+        computed, and the step loop must not queue behind a reader)."""
+        sums = np.sum([np.asarray(st) for st in done], axis=0,
+                      dtype=np.int64) if done else ()
+        with self._moe_lock:
+            for key, v in zip(self.moe, sums):
+                self.moe[key] += int(v)
 
     def observe_first_token(self, submit_s: float, queue_s: float,
                             prefill_s: float, emit_s: float) -> None:
@@ -313,7 +377,13 @@ class EngineMetrics:
                             "spec_accept_buckets", "spec_draft_by",
                             "spec_accepted_by", "spec_hist_by",
                             "spec_sum_by", "spec_count_by",
-                            "first_token", "_first_token_lock")}
+                            "first_token", "_first_token_lock", "moe", "attn",
+                            "_moe_pending", "_moe_lock")}
+        out["attn"] = dict(self.attn)
+        with self._moe_lock:
+            done, self._moe_pending = self._moe_pending, []
+        self._fold_moe(done)
+        out["moe"] = dict(self.moe)
         with self._first_token_lock:
             out["first_token"] = dict(self.first_token)
         out["phases"] = {p: t.snapshot() for p, t in self.phases.items()}
@@ -773,6 +843,14 @@ class Engine:
                 lambda a: jax.lax.with_sharding_constraint(a, rep_sharding), x
             )
 
+        # a model whose expert layers count (llama MixedOut.moe_stats etc.)
+        # has every step program return the counts last; `ctx` takes them
+        # off again, so call sites see the same tuples for every model
+        counts_moe = mcfg.moe_grouped
+
+        def moe_tail(stats):
+            return (rep(stats),) if counts_moe else ()
+
         def prefill_fn(params, tokens, seq_len, k_pages, v_pages, pages,
                        *aslot):
             out = llama.prefill(
@@ -780,7 +858,8 @@ class Engine:
                 page_size=page_size,
                 adapter_slots=aslot[0] if aslot else None,
             )
-            return rep(out.last_logits), out.k_pages, out.v_pages
+            return (rep(out.last_logits), out.k_pages,
+                    out.v_pages) + moe_tail(out.moe_stats)
 
         def prefill_batch_fn(params, tokens, seq_lens, k_pages, v_pages,
                              pages, *aslot):
@@ -789,7 +868,8 @@ class Engine:
                 page_size=page_size,
                 adapter_slots=aslot[0] if aslot else None,
             )
-            return rep(out.last_logits), out.k_pages, out.v_pages
+            return (rep(out.last_logits), out.k_pages,
+                    out.v_pages) + moe_tail(out.moe_stats)
 
         def sample_first_batch(logits, temperature, top_p, top_k, min_p,
                                bias_ids, bias_vals, keys, positions):
@@ -808,7 +888,8 @@ class Engine:
                 pages, page_size=page_size,
                 adapter_slots=aslot[0] if aslot else None,
             )
-            return rep(out.last_logits), out.k_pages, out.v_pages
+            return (rep(out.last_logits), out.k_pages,
+                    out.v_pages) + moe_tail(out.moe_stats)
 
         def make_decode_window(n_steps: int, with_logprobs: bool,
                                guide_tables=None):
@@ -898,23 +979,25 @@ class Engine:
                         # 1 so their trash-page work never grows
                         new_carry = (nxt, pos + step, ctx_lens + step, cnts,
                                      out.k_pages, out.v_pages)
-                    return new_carry, y
+                    return new_carry, (y, out.moe_stats)
 
                 init = ((tokens, positions, context_lens, counts,
                          gmode0, gdepth0, gbits0, k_pages, v_pages)
                         if guided else
                         (tokens, positions, context_lens, counts,
                          k_pages, v_pages))
-                carry, ys = jax.lax.scan(body, init, None, length=n_steps)
+                carry, (ys, st) = jax.lax.scan(body, init, None,
+                                               length=n_steps)
+                tail = moe_tail(st.sum(axis=0) if counts_moe else None)
                 if guided:
                     (tokens, positions, context_lens, counts,
                      gm, gd, gb, k_pages, v_pages) = carry
                     # ys: (toks [n_steps, B], [logprob extras...])
                     return (rep(ys), tokens, positions, context_lens, counts,
-                            gm, gd, gb, k_pages, v_pages)
+                            gm, gd, gb, k_pages, v_pages) + tail
                 tokens, positions, context_lens, counts, k_pages, v_pages = carry
                 return (rep(ys), tokens, positions, context_lens, counts,
-                        k_pages, v_pages)
+                        k_pages, v_pages) + tail
 
             return window_fn
 
@@ -981,7 +1064,7 @@ class Engine:
                 # token only on the FINAL chunk (same tail as chunk_fn)
                 return (rep(y), rep(out.chunk_logits), nxt,
                         positions + step, context_lens + step, counts,
-                        out.k_pages, out.v_pages)
+                        out.k_pages, out.v_pages) + moe_tail(out.moe_stats)
 
             return mixed_fn
 
@@ -1042,7 +1125,8 @@ class Engine:
                                     context_lens, active, state, slot_keys,
                                     counts, room)
             return (rep((emitted, n_acc)), tokens_new, pos_new, ctx_new,
-                    counts, out.k_pages, out.v_pages)
+                    counts, out.k_pages, out.v_pages) + moe_tail(
+                        out.moe_stats)
 
         def mixed_spec_fn(params, tokens, drafts, positions, context_lens,
                           active, block_tables, temperature, top_p, top_k,
@@ -1081,7 +1165,7 @@ class Engine:
             # only on the FINAL chunk (same tail as mixed_fn)
             return (rep((emitted, n_acc)), rep(out.chunk_logits),
                     tokens_new, pos_new, ctx_new, counts,
-                    out.k_pages, out.v_pages)
+                    out.k_pages, out.v_pages) + moe_tail(out.moe_stats)
 
         def sample_first(logits, temperature, top_p, top_k, min_p,
                          bias_ids, bias_vals, req_key, pos):
@@ -1102,6 +1186,9 @@ class Engine:
 
         def import_fn(k_pages, v_pages, idx, k_new, v_new):
             # disagg KV install: in-place page scatter (pools donated)
+            if v_pages.shape[-1] == 0:
+                # MLA: the latent row lives once, in the K pool
+                return k_pages.at[:, idx].set(k_new), v_pages
             return (
                 k_pages.at[:, idx].set(k_new),
                 v_pages.at[:, idx].set(v_new),
@@ -1116,21 +1203,28 @@ class Engine:
         mesh = self.mesh
         lane_blocks = self.kv_spec.lane_blocks
 
-        def ctx(fn):
+        def ctx(fn, steps=False):
+            """Bind the attention scope around `fn`. steps=True marks a
+            program that runs the model's layers: where the expert layers
+            count, its last output is their counts, banked here."""
             def wrapped(*args):
                 with _att.attention_context(backend, mesh, lane_blocks):
-                    return fn(*args)
+                    out = fn(*args)
+                if steps and counts_moe:
+                    self.metrics.observe_moe(out[-1])
+                    out = out[:-1]
+                return out
 
             return wrapped
 
         if cfg.enforce_eager:
-            self._prefill = ctx(prefill_fn)
-            self._prefill_batch = ctx(prefill_batch_fn)
-            self._prefill_chunk = ctx(chunk_fn)
-            self._windows = {k: ctx(f) for k, f in window_fns.items()}
-            self._mixed = {k: ctx(f) for k, f in mixed_fns.items()}
-            self._spec = ctx(spec_fn)
-            self._mixed_spec = ctx(mixed_spec_fn)
+            self._prefill = ctx(prefill_fn, True)
+            self._prefill_batch = ctx(prefill_batch_fn, True)
+            self._prefill_chunk = ctx(chunk_fn, True)
+            self._windows = {k: ctx(f, True) for k, f in window_fns.items()}
+            self._mixed = {k: ctx(f, True) for k, f in mixed_fns.items()}
+            self._spec = ctx(spec_fn, True)
+            self._mixed_spec = ctx(mixed_spec_fn, True)
             self._sample_first = ctx(sample_first)
             self._sample_first_batch = ctx(sample_first_batch)
             self._reset_count = ctx(reset_count_fn)
@@ -1141,7 +1235,7 @@ class Engine:
             def _build_guided_window_eager(multi: bool, lp: bool):
                 return ctx(make_decode_window(
                     n_multi if multi else 1, lp,
-                    guide_tables=self._guide_dev))
+                    guide_tables=self._guide_dev), True)
 
             self._build_guided_window = _build_guided_window_eager
         else:
@@ -1173,13 +1267,13 @@ class Engine:
             jr = jax.jit(reset_count_fn,
                          donate_argnums=_argnums(reset_count_fn, "counts"))
             ji = jax.jit(import_fn, donate_argnums=_argnums(import_fn, *kv))
-            self._prefill = ctx(jp)
-            self._prefill_batch = ctx(jpb)
-            self._prefill_chunk = ctx(jc)
-            self._windows = {k: ctx(f) for k, f in jw.items()}
-            self._mixed = {k: ctx(f) for k, f in jm.items()}
-            self._spec = ctx(jspec)
-            self._mixed_spec = ctx(jms)
+            self._prefill = ctx(jp, True)
+            self._prefill_batch = ctx(jpb, True)
+            self._prefill_chunk = ctx(jc, True)
+            self._windows = {k: ctx(f, True) for k, f in jw.items()}
+            self._mixed = {k: ctx(f, True) for k, f in jm.items()}
+            self._spec = ctx(jspec, True)
+            self._mixed_spec = ctx(jms, True)
             self._sample_first = ctx(js)
             self._sample_first_batch = ctx(jsb)
             self._reset_count = ctx(jr)
@@ -1200,7 +1294,7 @@ class Engine:
                             donate_argnums=_argnums(fn, *carry)
                             + (g0, g0 + 1, g0 + 2))
                 self._jit_handles[f"window_guided_{multi}_{lp}"] = j
-                return ctx(j)
+                return ctx(j, True)
 
             self._build_guided_window = _build_guided_window
             # jitted upload whose outputs share the sharding provenance of
@@ -2835,6 +2929,9 @@ class Engine:
         self.metrics.observe_phase("decode_step", dt)
         self.metrics.observe_occupancy(len(slots), cfg.max_num_seqs)
         self.metrics.observe_mixed(take, len(slots))
+        if self.model_cfg.is_mla:  # read by the latent kernels' rooflines
+            self.metrics.observe_mixed_attention(
+                [s.num_tokens for s in self.seqs.values()], start, take)
         self._step_obs("mixed", dt, take=take)
         with self.timeline.phase("detok"):
             for slot in slots:
@@ -3559,6 +3656,10 @@ class Engine:
         self.metrics.observe_phase("decode_window", dt)
         self.metrics.observe_phase("decode_step", dt / window, weight=window)
         self.metrics.observe_occupancy(len(slots), self.cfg.max_num_seqs)
+        if self.model_cfg.is_mla:
+            self.metrics.observe_decode_attention(
+                [self.seqs[s].num_tokens for s in slots if s in self.seqs],
+                window)
         self._step_obs("decode", dt)
 
         with self.timeline.phase("detok"):

@@ -8,6 +8,14 @@ with a single DMA. The fused axis is sharded over the `model` mesh axis
 each tensor-parallel shard owns its local heads' lanes of every page, and the
 decode loop never crosses ICI for cache reads.
 
+An MLA model keeps ONE pool: its cache row is the shared latent
+[c_kv | k_rope], which is both what queries score against and (its first
+kv_lora_rank lanes) what they average, so the row lives once, in the K pool,
+and the V pool is allocated with no lanes (`KVCacheSpec.v_from_k`; the
+attention ops and kernels read V from the K rows they already hold). The
+second array keeps the engine's (k_pages, v_pages) plumbing — donation,
+transfer, tiering — one shape for every model.
+
 Page 0 is a reserved "trash" page: inactive batch slots point at it so the
 full-batch decode step stays shape-static without masking scatter writes.
 
@@ -44,6 +52,9 @@ class KVCacheSpec:
     # lane split over the `model` mesh axis hands each shard its own heads'
     # values AND scales (see dynamo_tpu.ops.attention, int8 KV section)
     lane_blocks: int = 1
+    # MLA: the latent row is stored once, in the K pool; the V pool has
+    # no lanes and V is read from the K rows (see the module docstring)
+    v_from_k: bool = False
 
     @staticmethod
     def from_model(
@@ -76,6 +87,7 @@ class KVCacheSpec:
             head_dim=head_dim,
             dtype=cfg.dtype if kv_dtype in ("auto", "") else kv_dtype,
             lane_blocks=blocks if quantized else 1,
+            v_from_k=cfg.is_mla,
         )
 
     @property
@@ -98,9 +110,14 @@ class KVCacheSpec:
             self.lane_width,
         )
 
+    @property
+    def v_shape(self):
+        return self.shape[:3] + (0 if self.v_from_k else self.lane_width,)
+
     def bytes_per_token(self) -> int:
         itemsize = jnp.dtype(self.dtype).itemsize
-        return 2 * self.num_layers * self.lane_width * itemsize
+        pools = 1 if self.v_from_k else 2
+        return pools * self.num_layers * self.lane_width * itemsize
 
     def page_table_width(self, bucket_tokens: int,
                          chunk_tokens: int) -> int:
@@ -122,7 +139,7 @@ class KVCacheSpec:
 def alloc_kv_pages(spec: KVCacheSpec, sharding=None):
     """Allocate zeroed K/V page pools (optionally with a NamedSharding)."""
     k = jnp.zeros(spec.shape, dtype=jnp.dtype(spec.dtype))
-    v = jnp.zeros(spec.shape, dtype=jnp.dtype(spec.dtype))
+    v = jnp.zeros(spec.v_shape, dtype=jnp.dtype(spec.dtype))
     if sharding is not None:
         k = jax.device_put(k, sharding)
         v = jax.device_put(v, sharding)

@@ -191,6 +191,30 @@ class ModelConfig:
     # global-softmax probabilities, scaled by routed_scaling_factor
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # router scores: "softmax" (Mixtral / DeepSeek-V2) or "sigmoid"
+    # (DeepSeek-V3 / Kimi-K2: s = sigmoid(logits), the k largest of
+    # s + bias are picked, weights are s_i / sum_sel s, times
+    # routed_scaling_factor). router_bias adds the per-expert selection
+    # bias (HF e_score_correction_bias, topk_method "noaux_tc"): it moves
+    # the PICK only, never the weights.
+    moe_scoring: str = "softmax"
+    router_bias: bool = False
+    # leading dense layers (HF first_k_dense_replace): the first
+    # `first_k_dense` layers run one SwiGLU of dense_intermediate_size
+    # instead of the expert layer; they keep their own parameter stack
+    # ("dense." prefix) and run unrolled ahead of the layer scan.
+    first_k_dense: int = 0
+    dense_intermediate_size: int = 0
+    # this chip's share of an expert-parallel deployment: the router keeps
+    # num_experts outputs and num_experts_per_tok picks; the layer holds
+    # experts [local_expert_offset, local_expert_offset + num_local_experts)
+    # and computes only their part of the result (plus what every chip
+    # computes alike: the shared expert). 0 = every expert is held.
+    num_local_experts: int = 0
+    local_expert_offset: int = 0
+    # first vocabulary row held here when the vocabulary is sliced
+    # (vocab_size is then the slice; only the checkpoint loader needs it)
+    vocab_offset: int = 0
     # MLA (DeepSeek-V2-family multi-head latent attention). kv_lora_rank > 0
     # switches attention to the latent form: the paged cache stores ONE
     # shared [c_kv | k_rope] row per token (kv_lora_rank + qk_rope_head_dim
@@ -198,6 +222,10 @@ class ModelConfig:
     # decode runs in the ABSORBED form (q_nope folded through W_UK so
     # queries attend directly over the latent rows).
     kv_lora_rank: int = 0
+    # query low-rank path (DeepSeek-V2 236B / V3 / Kimi-K2):
+    # x -> q_lora_rank -> RMSNorm -> H x (nope + rope); 0 = one full
+    # query projection (DeepSeek-V2-Lite)
+    q_lora_rank: int = 0
     qk_nope_head_dim: int = 0   # per-head no-rope query/key dim
     qk_rope_head_dim: int = 0   # shared rope dim appended to the latent row
     v_head_dim: int = 0         # per-head value dim out of W_UV
@@ -217,10 +245,55 @@ class ModelConfig:
             raise ValueError(
                 f"MoE models are SwiGLU-only (hidden_act={self.hidden_act!r}"
                 " requested); ops/moe.py would need the activation plumbed")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_scoring {self.moe_scoring!r}")
+        if self.first_k_dense and not (
+                self.is_moe and 0 < self.first_k_dense < self.num_layers
+                and self.dense_intermediate_size > 0):
+            raise ValueError(
+                "first_k_dense needs an MoE model with at least one expert "
+                "layer after the dense ones and a dense_intermediate_size")
+        if self.first_k_dense and (self.attention_bias or self.qk_norm
+                                   or self.post_norms):
+            # the dense stack (llama.param_specs) carries the attention
+            # and FFN matrices and the two pre-norms, nothing else yet
+            raise ValueError(
+                "first_k_dense with attention_bias / qk_norm / post_norms "
+                "is not implemented: the dense stack has no such leaves")
+        held = self.num_local_experts
+        if held and not (
+                self.is_moe and 0 <= self.local_expert_offset
+                and self.local_expert_offset + held <= self.num_experts):
+            raise ValueError(
+                f"held experts [{self.local_expert_offset}, "
+                f"{self.local_expert_offset + held}) lie outside the "
+                f"router's {self.num_experts}")
 
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights live here (the whole router's unless the
+        config states a share)."""
+        return self.num_local_experts or self.num_experts
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense if self.is_moe else 0
+
+    @property
+    def moe_grouped(self) -> bool:
+        """Which expert layer serves this shape (ops/moe.py): grouped
+        matmuls over the tokens each expert was picked by, or every expert
+        on every token. Dense wins while a token picks a large part of the
+        experts (Mixtral: 2 of 8, weights streamed once either way, no
+        sort/gather); at k/X <= 1/8 it would do >= 8x the model's
+        arithmetic. A share of a wider router always groups."""
+        return self.is_moe and (
+            self.held_experts != self.num_experts
+            or 8 * self.num_experts_per_tok <= self.num_experts)
 
     @property
     def is_mla(self) -> bool:
@@ -280,17 +353,44 @@ class ModelConfig:
         eos = cfg.get("eos_token_id", 2)
         if isinstance(eos, list):
             eos = eos[0]
-        if cfg.get("first_k_dense_replace"):
-            # DeepSeek's dense-first-k layout breaks the uniform layer scan
+        # keys whose mechanism is not implemented refuse loudly: serving
+        # a checkpoint while ignoring one of them serves another model
+        if (cfg.get("n_group") or 1) > 1 or (cfg.get("topk_group") or 1) > 1:
             raise ValueError(
-                "first_k_dense_replace (dense first layers in an MoE "
-                "model) is not supported yet — all layers must share one "
-                "structure for the lax.scan layer stack")
+                f"group-limited routing (n_group={cfg.get('n_group')}, "
+                f"topk_group={cfg.get('topk_group')}) is not implemented; "
+                "only n_group = topk_group = 1 is served")
+        if (cfg.get("num_nextn_predict_layers") or 0) > 0:
+            raise ValueError(
+                "multi-token-prediction layers (num_nextn_predict_layers="
+                f"{cfg['num_nextn_predict_layers']}) are not implemented")
+        if (cfg.get("moe_layer_freq") or 1) != 1:
+            raise ValueError(
+                f"moe_layer_freq={cfg['moe_layer_freq']} (dense layers "
+                "interleaved after the leading ones) is not implemented")
+        scoring = cfg.get("scoring_func", "softmax")
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring_func {scoring!r} is not implemented")
+        topk_method = cfg.get("topk_method") or "greedy"
+        if topk_method not in ("greedy", "noaux_tc", "group_limited_greedy"):
+            # group_limited_greedy with n_group 1 (checked above) is greedy
+            raise ValueError(f"topk_method {topk_method!r} is not implemented")
         # expert count: Mixtral uses num_local_experts, DeepSeek
         # n_routed_experts, Qwen3-MoE plain num_experts
         n_experts = (cfg.get("num_local_experts")
                      or cfg.get("n_routed_experts")
                      or cfg.get("num_experts") or 0)
+        # this chip's share of a wider deployment (benchmark cuts, see the
+        # model-configs guide, section 4): the counting keys give what is
+        # HELD here and `deployment_share` gives the router's and the
+        # vocabulary's whole extent and where the held part starts
+        share = cfg.get("deployment_share") or {}
+        held = 0
+        if share.get("n_routed_experts_total"):
+            held, n_experts = n_experts, int(share["n_routed_experts_total"])
+        first_dense = int(cfg.get("first_k_dense_replace") or 0)
+        if not n_experts:
+            first_dense = 0
         if n_experts:
             # MoE configs carry BOTH intermediate_size (dense-equivalent,
             # unused) and moe_intermediate_size (per-expert, the real one)
@@ -349,7 +449,16 @@ class ModelConfig:
             norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
             routed_scaling_factor=float(
                 cfg.get("routed_scaling_factor", 1.0)),
+            moe_scoring=scoring if n_experts else "softmax",
+            router_bias=bool(n_experts) and topk_method == "noaux_tc",
+            first_k_dense=first_dense,
+            dense_intermediate_size=(
+                int(cfg.get("intermediate_size") or 0) if first_dense else 0),
+            num_local_experts=held,
+            local_expert_offset=int(share.get("first_routed_expert", 0)),
+            vocab_offset=int(share.get("first_vocab_row", 0)),
             kv_lora_rank=cfg.get("kv_lora_rank", 0) or 0,
+            q_lora_rank=cfg.get("q_lora_rank", 0) or 0,
             qk_nope_head_dim=cfg.get("qk_nope_head_dim", 0) or 0,
             qk_rope_head_dim=cfg.get("qk_rope_head_dim", 0) or 0,
             v_head_dim=cfg.get("v_head_dim", 0) or 0,
@@ -397,6 +506,22 @@ PRESETS = {
         name="tiny-mla-debug",
         kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
         v_head_dim=16,
+    ),
+    # Kimi-K2 / DeepSeek-V3 structure at a toy size: one leading dense
+    # layer, then expert layers with sigmoid routing, a selection bias, a
+    # shared expert, MLA with the query low-rank path, YaRN. No share:
+    # tests cut shares from it with dataclasses.replace.
+    "tiny-kimi-debug": ModelConfig(
+        name="tiny-kimi-debug",
+        intermediate_size=64, num_layers=3,
+        num_experts=16, num_experts_per_tok=4, num_shared_experts=1,
+        norm_topk_prob=True, routed_scaling_factor=2.5,
+        moe_scoring="sigmoid", router_bias=True,
+        first_k_dense=1, dense_intermediate_size=256,
+        kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16,
+        rope_theta=50000.0, rms_norm_eps=1e-6, tie_word_embeddings=False,
+        rope_yarn_scaling=(32.0, 1.0, 1.0, 64, 1.0, 1.0, -1.0),
     ),
     "llama-3.2-1b-instruct": ModelConfig(
         name="llama-3.2-1b-instruct",
@@ -777,3 +902,8 @@ PRESETS["meta-llama/Llama-3.2-1B-Instruct".lower().split("/")[-1]] = PRESETS[
 ]
 PRESETS["qwen/qwen3-0.6b".split("/")[-1]] = PRESETS["qwen3-0.6b"]
 PRESETS["deepseek-v2-lite-chat"] = PRESETS["deepseek-v2-lite"]
+# one chip's share of tiny-kimi-debug: experts 4-7 of 16 held (the chip
+# benchmark's CPU rehearsal of the Kimi-K2 cell serves this)
+PRESETS["tiny-kimi-ep4-debug"] = dataclasses.replace(
+    PRESETS["tiny-kimi-debug"], name="tiny-kimi-ep4-debug",
+    num_local_experts=4, local_expert_offset=4)

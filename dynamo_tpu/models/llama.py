@@ -147,20 +147,26 @@ def _post(cfg: ModelConfig, lp: Params, name: str, y: jax.Array) -> jax.Array:
     return rms_norm(y, lp[name], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
 
 
+# leaf-name prefix of the leading dense layers' own parameter stack
+DENSE_PREFIX = "dense."
+
+
 def param_specs(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], str, float]]:
     """Shape/init spec for every parameter: name -> (shape, kind, sigma).
 
     kind: "normal" (random weight with stddev sigma), "ones", "zeros".
     Single source of truth for param shapes — `init_params` and the loader's
     fast random-int8 path both build from it, so they cannot drift."""
-    e, h, kv, d, f, l = (
+    e, h, kv, d, f = (
         cfg.hidden_size,
         cfg.num_heads,
         cfg.num_kv_heads,
         cfg.head_dim,
         cfg.intermediate_size,
-        cfg.num_layers,
     )
+    # the scanned stack: every layer, or the expert layers that follow
+    # the leading dense ones (those get their own stack, below)
+    l = cfg.num_layers - cfg.first_k_dense
 
     def w(shape, sigma=None):
         return (shape, "normal",
@@ -176,23 +182,35 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], str, float
         "final_norm": ((e,), nk, 0.0),
         "attn_norm": ((l, e), nk, 0.0),
     }
-    if cfg.is_mla:
-        # multi-head latent attention (DeepSeek-V2 family): queries project
-        # per-head to [nope | rope]; keys/values come from ONE shared
-        # latent row per token via the up-projections W_UK / W_UV
-        nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-        lora, vd = cfg.kv_lora_rank, cfg.v_head_dim
-        p["wq_mla"] = w((l, e, h, nope + rope))
-        p["w_kv_a"] = w((l, e, lora + rope))
-        p["kv_a_norm"] = ((l, lora), "ones", 0.0)
-        p["w_uk"] = w((l, h, nope, lora))
-        p["w_uv"] = w((l, h, lora, vd))
-        p["wo"] = w((l, h, vd, e))
-    else:
-        p["wq"] = w((l, e, h, d))
-        p["wk"] = w((l, e, kv, d))
-        p["wv"] = w((l, e, kv, d))
-        p["wo"] = w((l, h, d, e))
+    def attention(p, l, pre=""):
+        if cfg.is_mla:
+            # multi-head latent attention (DeepSeek-V2 family): queries
+            # project per-head to [nope | rope] (through a low-rank latent
+            # with its own norm when q_lora_rank > 0); keys/values come
+            # from ONE shared latent row per token via the up-projections
+            # W_UK / W_UV
+            nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+            lora, vd = cfg.kv_lora_rank, cfg.v_head_dim
+            if cfg.q_lora_rank > 0:
+                qr = cfg.q_lora_rank
+                p[pre + "wq_a"] = w((l, e, qr))
+                p[pre + "q_a_norm"] = ((l, qr), "ones", 0.0)
+                # HF's view: one [qr, H*(nope+rope)] matrix, fan-in qr
+                p[pre + "wq_b"] = w((l, qr, h, nope + rope), 1.0 / qr ** 0.5)
+            else:
+                p[pre + "wq_mla"] = w((l, e, h, nope + rope))
+            p[pre + "w_kv_a"] = w((l, e, lora + rope))
+            p[pre + "kv_a_norm"] = ((l, lora), "ones", 0.0)
+            p[pre + "w_uk"] = w((l, h, nope, lora))
+            p[pre + "w_uv"] = w((l, h, lora, vd))
+            p[pre + "wo"] = w((l, h, vd, e))
+        else:
+            p[pre + "wq"] = w((l, e, h, d))
+            p[pre + "wk"] = w((l, e, kv, d))
+            p[pre + "wv"] = w((l, e, kv, d))
+            p[pre + "wo"] = w((l, h, d, e))
+
+    attention(p, l)
     p["mlp_norm"] = ((l, e), nk, 0.0)
     if cfg.post_norms:  # gemma-2 sandwich norms on branch outputs
         p["post_attn_norm"] = ((l, e), nk, 0.0)
@@ -207,8 +225,13 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], str, float
         p["q_norm"] = ((l, d), nk, 0.0)
         p["k_norm"] = ((l, d), nk, 0.0)
     if cfg.is_moe:
-        x = cfg.num_experts
-        p["router"] = w((l, e, x), 0.02)
+        # the router keeps its whole width; the expert weights are those
+        # HELD here (all of them unless the config states a share)
+        x = cfg.held_experts
+        p["router"] = w((l, e, cfg.num_experts), 0.02)
+        if cfg.router_bias:
+            # HF e_score_correction_bias: float32, selection only
+            p["router_bias"] = ((l, cfg.num_experts), "zeros", 0.0)
         p["moe_w_gate"] = w((l, x, e, f))
         p["moe_w_up"] = w((l, x, e, f))
         p["moe_w_down"] = w((l, x, f, e))
@@ -224,6 +247,18 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], str, float
         p["w_gate"] = w((l, e, f))
         p["w_up"] = w((l, e, f))
         p["w_down"] = w((l, f, e))
+    if cfg.first_k_dense:
+        # leading dense layers: the same attention, one SwiGLU of the
+        # dense width; own stack under the "dense." prefix (run unrolled
+        # ahead of the scan, see _scan_layers_paged)
+        ld, fd = cfg.first_k_dense, cfg.dense_intermediate_size
+        pre = DENSE_PREFIX
+        p[pre + "attn_norm"] = ((ld, e), nk, 0.0)
+        attention(p, ld, pre)
+        p[pre + "mlp_norm"] = ((ld, e), nk, 0.0)
+        p[pre + "w_gate"] = w((ld, e, fd))
+        p[pre + "w_up"] = w((ld, e, fd))
+        p[pre + "w_down"] = w((ld, fd, e))
     return p
 
 
@@ -234,7 +269,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     ks = jax.random.split(key, len(specs))
     p: Params = {}
     for k, (name, (shape, kind, sigma)) in zip(ks, specs.items()):
-        if kind == "ones":
+        if name == "router_bias":
+            p[name] = jnp.zeros(shape, jnp.float32)
+        elif kind == "ones":
             p[name] = jnp.ones(shape, dt)
         elif kind == "zeros":
             p[name] = jnp.zeros(shape, dt)
@@ -245,43 +282,95 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     return p
 
 
+def _live_rows(cfg: ModelConfig, block_tables: jax.Array):
+    """[B] bool: decode slots that hold a sequence (an inactive slot's
+    table is all trash page 0, a live one's first page never is). Only the
+    grouped expert layer asks: it computes and counts no row of an empty
+    slot. None elsewhere, so the other models' programs do not change."""
+    return block_tables[:, 0] > 0 if cfg.moe_grouped else None
+
+
 def _layer_params(p: Params) -> Params:
     """The subtree that carries a leading layer axis (scanned)."""
     return {
         k: v
         for k, v in p.items()
         if k not in ("embed", "lm_head", "final_norm")
+        and not k.startswith(DENSE_PREFIX)
     }
 
 
-def _scan_layers_paged(params: Params, body, x, k_pages, v_pages,
-                       num_layers: int):
-    """lax.scan over (layer params, layer index) with the KV pools carried
-    FLAT through the scan: [L, P, ps, KV*D] is viewed as [L*P, ps, KV*D]
-    (a bitcast), layer l's page p lives at flat id l*P + p, and `body`
-    receives (x, flat_k, flat_v, lp, layer_page_offset) and returns the
-    updated (x, flat_k, flat_v).
+def _dense_layer_params(p: Params, i: int) -> Params:
+    """Leading dense layer i's parameters under their plain names."""
+    return {
+        k[len(DENSE_PREFIX):]: jax.tree.map(lambda a: a[i], v)
+        for k, v in p.items() if k.startswith(DENSE_PREFIX)
+    }
 
-    Why: offsetting page ids instead of slicing a [P, ps, KV*D] layer out
-    of the pool means each iteration touches only the written rows and the
-    gathered pages. Before pools moved into the carry with flat
+
+_EXPERT_STACKS = ("moe_w_gate", "moe_w_up", "moe_w_down")
+
+
+def _scan_layers_paged(cfg: ModelConfig, params: Params, body, x,
+                       k_pages, v_pages):
+    """Run every layer with the KV pools carried FLAT: [L, P, ps, KV*D] is
+    viewed as [L*P, ps, KV*D] (a bitcast), layer l's page p lives at flat
+    id l*P + p, and `body` receives (x, flat_k, flat_v, lp,
+    layer_page_offset) and returns the updated (x, flat_k, flat_v) and
+    what its expert layer counted (_mlp's second result: None for most).
+
+    The leading dense layers of a DeepSeek-V3-style model (cfg.first_k_dense,
+    own parameter stack) run unrolled first; the rest is one lax.scan over
+    (layer params, layer index) — one compiled layer body whatever the
+    depth. Page offsets count all layers. Returns (x, k_pages, v_pages,
+    moe_stats): moe_stats is the grouped expert layers' counts summed over
+    layers (int32 [5]), or None where no layer counts.
+
+    Why flat: offsetting page ids instead of slicing a [P, ps, KV*D] layer
+    out of the pool means each iteration touches only the written rows and
+    the gathered pages. Before pools moved into the carry with flat
     addressing, the per-layer slice/stack/copy traffic cost ~10ms of a
     25ms decode step on the 8B model (XProf hlo_stats: 'data formatting'
-    copies + dynamic-slice fusions at full-pool size)."""
+    copies + dynamic-slice fusions at full-pool size).
+
+    An MLA model's V pool has no lanes (engine/kv_cache.py): the latent row
+    lives once, in the K pool, and the attention ops read V from it."""
     l, p = k_pages.shape[:2]
-    flat = (l * p,) + k_pages.shape[2:]
-    kpf, vpf = k_pages.reshape(flat), v_pages.reshape(flat)
+    kpf = k_pages.reshape((l * p,) + k_pages.shape[2:])
+    vpf = v_pages.reshape((l * p,) + v_pages.shape[2:])
+    # the grouped expert layers' counts ride the carry only where a layer
+    # counts: every other model's programs stay as they were
+    extra = ((jnp.zeros((len(moe_ops.MOE_STATS),), jnp.int32),)
+             if cfg.moe_grouped else ())
 
-    def wrapped(carry, scanned):
-        x, kp, vp = carry
-        lp, layer = scanned
-        return body(x, kp, vp, lp, layer * p), None
+    def layer(carry, lp, page_off):
+        x, kp, vp, counts = body(*carry[:3], lp, page_off)
+        # a leading dense layer of a counting model counts nothing
+        return (x, kp, vp) + tuple(
+            acc if counts is None else acc + counts for acc in carry[3:])
 
-    (x, kpf, vpf), _ = jax.lax.scan(
-        wrapped, (x, kpf, vpf), (_layer_params(params),
-                                 jnp.arange(num_layers))
-    )
-    return x, kpf.reshape(k_pages.shape), vpf.reshape(v_pages.shape)
+    carry = (x, kpf, vpf) + extra
+    for i in range(cfg.first_k_dense):
+        carry = layer(carry, _dense_layer_params(params, i), i * p)
+
+    scanned = _layer_params(params)
+    # the grouped expert matmul takes the experts' WHOLE stack and the
+    # layer's index (ops/moe.moe_mlp_grouped says why): those leaves ride
+    # the scan's closure, not its sliced operands
+    whole = ({k: scanned.pop(k) for k in _EXPERT_STACKS}
+             if cfg.moe_grouped else {})
+
+    def wrapped(carry, xs):
+        lp, idx = xs
+        if whole:
+            lp = dict(lp, **whole, moe_layer=idx - cfg.first_k_dense)
+        return layer(carry, lp, idx * p), None
+
+    carry, _ = jax.lax.scan(
+        wrapped, carry, (scanned, jnp.arange(cfg.first_k_dense, l)))
+    x, kpf, vpf = carry[:3]
+    return (x, kpf.reshape(k_pages.shape), vpf.reshape(v_pages.shape),
+            carry[3] if extra else None)
 
 
 def _qkv(cfg: ModelConfig, lp: Params, x: jax.Array, positions: jax.Array,
@@ -353,24 +442,35 @@ def _qkv_mla(cfg: ModelConfig, lp: Params, x: jax.Array,
     (q_eff = [q_nope @ W_UK | q_rope]), so the generic paged ops score
     queries directly against the latent rows. Their internal
     1/sqrt(latent_width) scale is corrected to MLA's 1/sqrt(nope+rope)
-    here. The V pool stores the same row; the attention output's first
-    kv_lora_rank lanes are probs @ c_kv, which _attn_out expands through
-    W_UV (the k_rope lanes are sliced away there).
+    here. The row is stored ONCE: an MLA model's V pool has no lanes, and
+    the attention ops read V from the K rows they already hold. The
+    attention output's first kv_lora_rank lanes are probs @ c_kv, which
+    _attn_out expands through W_UV (the k_rope lanes are sliced away there).
     """
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     lora = cfg.kv_lora_rank
-    q = qeinsum("te,ehd->thd", x, lp["wq_mla"])  # [T, H, nope+rope]
+    if cfg.q_lora_rank > 0:
+        # query low-rank path: x -> q_lora_rank -> RMSNorm -> heads
+        with jax.named_scope("mla_q_lora"):
+            c_q = rms_norm(qeinsum("te,er->tr", x, lp["wq_a"]),
+                           lp["q_a_norm"], cfg.rms_norm_eps,
+                           cfg.rms_norm_unit_offset)
+            q = qeinsum("tr,rhd->thd", c_q, lp["wq_b"])
+    else:
+        q = qeinsum("te,ehd->thd", x, lp["wq_mla"])  # [T, H, nope+rope]
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta,
                         llama3_scaling=cfg.rope_llama3_scaling,
                         yarn_scaling=cfg.rope_yarn_scaling)
-    kv = qeinsum("te,er->tr", x, lp["w_kv_a"])  # [T, lora+rope]
-    c_kv = rms_norm(kv[:, :lora], lp["kv_a_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-    k_rope = apply_rope(kv[:, None, lora:], positions, cfg.rope_theta,
-                        llama3_scaling=cfg.rope_llama3_scaling,
-                        yarn_scaling=cfg.rope_yarn_scaling)[:, 0]
-    q_lat = jnp.einsum("thn,hnr->thr", q_nope.astype(jnp.float32),
-                       lp["w_uk"].astype(jnp.float32)).astype(q.dtype)
+    with jax.named_scope("mla_qkv"):
+        kv = qeinsum("te,er->tr", x, lp["w_kv_a"])  # [T, lora+rope]
+        c_kv = rms_norm(kv[:, :lora], lp["kv_a_norm"], cfg.rms_norm_eps,
+                        cfg.rms_norm_unit_offset)
+        k_rope = apply_rope(kv[:, None, lora:], positions, cfg.rope_theta,
+                            llama3_scaling=cfg.rope_llama3_scaling,
+                            yarn_scaling=cfg.rope_yarn_scaling)[:, 0]
+        q_lat = jnp.einsum("thn,hnr->thr", q_nope.astype(jnp.float32),
+                           lp["w_uk"].astype(jnp.float32)).astype(q.dtype)
     # generic ops scale scores by 1/sqrt(q.shape[-1]) — the PADDED cache
     # width (cache_head_dim rounds real latent rows up to a 128-lane
     # multiple for Pallas DMA tiling; zero lanes add nothing to scores);
@@ -399,9 +499,10 @@ def _attn_out(cfg: ModelConfig, lp: Params, o: jax.Array,
     h = o.shape[-2]
     o2 = o.reshape((-1, h, o.shape[-1]))
     if cfg.is_mla:
-        o2 = jnp.einsum("thr,hrv->thv",
-                        o2[..., :cfg.kv_lora_rank].astype(jnp.float32),
-                        lp["w_uv"].astype(jnp.float32)).astype(o.dtype)
+        with jax.named_scope("mla_out"):
+            o2 = jnp.einsum("thr,hrv->thv",
+                            o2[..., :cfg.kv_lora_rank].astype(jnp.float32),
+                            lp["w_uv"].astype(jnp.float32)).astype(o.dtype)
     out = qeinsum("thd,hde->te", o2, lp["wo"])
     if lora_slots is not None and "lora_oa" in lp:
         from dynamo_tpu.lora import apply as _lora
@@ -413,53 +514,80 @@ def _attn_out(cfg: ModelConfig, lp: Params, o: jax.Array,
 
 def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
          token_mask: jax.Array | None = None,
-         allow_capacity: bool = False) -> jax.Array:
+         allow_capacity: bool = False):
     """SwiGLU MLP or MoE block. x: [T, E]; token_mask: [T] bool, False for
     padding rows (prefill pads to a page multiple). The capacity-gather MoE
     path is prefill-only (allow_capacity): decode batches contain inactive
     slots with no mask to exclude them, and are small enough that dense
-    dispatch wins anyway."""
+    dispatch wins anyway. Returns (y [T, E], counts): what the grouped
+    expert layer counted (moe_ops.MOE_STATS, int32), None on every other
+    path."""
     def dense(x):
         g = qeinsum("te,ef->tf", x, lp["w_gate"])
         u = qeinsum("te,ef->tf", x, lp["w_up"])
         return qeinsum("tf,fe->te", _act(cfg, g) * u, lp["w_down"])
 
-    if not cfg.is_moe:
-        return dense(x)
-    shared = dense(x) if cfg.num_shared_experts > 0 else 0.0
-    # MoE: top-k routing into a dense [T, X] combine matrix, then one of two
-    # dispatch paths (dynamo_tpu.ops.moe): exact dense-masked by default;
-    # capacity-based gather (T*k*cf expert-MLP rows instead of T*X) when the
-    # deployment opts in via moe_capacity_factor > 0. Both partition over the
-    # `expert` mesh axis via the sharding rules on moe_w_*.
-    logits = jnp.einsum("te,ex->tx", x, lp["router"]).astype(jnp.float32)
-    combine = moe_ops.topk_combine(
-        logits, cfg.num_experts_per_tok, x.dtype,
-        renormalize=cfg.norm_topk_prob,
-        scaling_factor=cfg.routed_scaling_factor)
-    if token_mask is not None:
-        # padding rows must not claim expert capacity (nor compute)
-        combine = combine * token_mask.astype(combine.dtype)[:, None]
+    if not cfg.is_moe or "router" not in lp:
+        # dense model, or a leading dense layer of an MoE model (its own
+        # parameter stack carries no router)
+        return dense(x), None
+    if cfg.num_shared_experts > 0:
+        with jax.named_scope("moe_shared_expert"):
+            shared = dense(x)
+    else:
+        shared = 0.0
+    # MoE: top-k routing, then one of three dispatch paths
+    # (dynamo_tpu.ops.moe). Grouped matmuls over each expert's own tokens
+    # where a token picks few of many experts, or this chip holds a share
+    # of a wider router (cfg.moe_grouped, decided from shapes); else
+    # exact dense-masked dispatch through a [T, X] combine matrix, or the
+    # capacity-based gather (T*k*cf expert-MLP rows instead of T*X) when
+    # the deployment opts in via moe_capacity_factor > 0. All partition
+    # over the `expert` mesh axis via the sharding rules on moe_w_*.
+    with jax.named_scope("moe_router"):
+        logits = jnp.einsum("te,ex->tx", x, lp["router"],
+                            preferred_element_type=jnp.float32)
+        topi, weights = moe_ops.route_topk(
+            logits, cfg.num_experts_per_tok,
+            renormalize=cfg.norm_topk_prob,
+            scaling_factor=cfg.routed_scaling_factor,
+            scoring=cfg.moe_scoring,
+            select_bias=lp.get("router_bias"))
     t = x.shape[0]
+    capacity = 0
     if allow_capacity and cfg.moe_capacity_factor > 0:
-        cap = moe_ops.expert_capacity(
+        capacity = moe_ops.expert_capacity(
             t, cfg.num_experts, cfg.num_experts_per_tok,
             cfg.moe_capacity_factor,
         )
-        if cap < t:  # gather only pays off when capacity actually cuts rows
-            return shared + moe_ops.moe_mlp_dropping(
-                x, combine, lp["moe_w_gate"], lp["moe_w_up"],
-                lp["moe_w_down"], capacity=cap,
-            )
+        if capacity >= t or cfg.held_experts != cfg.num_experts:
+            capacity = 0  # gather only pays off when capacity cuts rows
+    if cfg.moe_grouped and not capacity:
+        y, stats = moe_ops.moe_mlp_grouped(
+            x, topi, weights, lp["moe_w_gate"], lp["moe_w_up"],
+            lp["moe_w_down"], expert_offset=cfg.local_expert_offset,
+            token_mask=token_mask, layer=lp.get("moe_layer"))
+        return shared + y, stats
+    combine = moe_ops.scatter_combine(topi, weights, cfg.num_experts,
+                                      x.dtype)
+    if token_mask is not None:
+        # padding rows must not claim expert capacity (nor compute)
+        combine = combine * token_mask.astype(combine.dtype)[:, None]
+    if capacity:
+        return shared + moe_ops.moe_mlp_dropping(
+            x, combine, lp["moe_w_gate"], lp["moe_w_up"],
+            lp["moe_w_down"], capacity=capacity,
+        ), None
     return shared + moe_ops.moe_mlp_dense(
         x, combine, lp["moe_w_gate"], lp["moe_w_up"], lp["moe_w_down"]
-    )
+    ), None
 
 
 class PrefillOut(NamedTuple):
     last_logits: jax.Array  # [V] logits at the final real token
     k_pages: jax.Array
     v_pages: jax.Array
+    moe_stats: Any = None  # grouped expert layers' counts, or None
 
 
 def _logits(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
@@ -513,17 +641,16 @@ def prefill(
             kp, vp, k, v, pages + page_off, page_size=page_size
         )
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-        x = x + _post(cfg, lp, "post_mlp_norm",
-                  _mlp(cfg, lp, h, token_mask=token_mask,
-                       allow_capacity=True))
-        return x, kp, vp
+        y, counts = _mlp(cfg, lp, h,
+                         token_mask=token_mask, allow_capacity=True)
+        x = x + _post(cfg, lp, "post_mlp_norm", y)
+        return x, kp, vp, counts
 
-    x, k_pages, v_pages = _scan_layers_paged(
-        params, body, x, k_pages, v_pages, cfg.num_layers
-    )
+    x, k_pages, v_pages, moe_stats = _scan_layers_paged(
+        cfg, params, body, x, k_pages, v_pages)
     last = jnp.take(x, seq_len - 1, axis=0)[None]  # [1, E]
     logits = _logits(cfg, params, last)[0]
-    return PrefillOut(logits, k_pages, v_pages)
+    return PrefillOut(logits, k_pages, v_pages, moe_stats)
 
 
 def prefill_chunk(
@@ -580,23 +707,23 @@ def prefill_chunk(
         x = x + _post(cfg, lp, "post_attn_norm",
                       _attn_out(cfg, lp, o, lora_slots=slots))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-        x = x + _post(cfg, lp, "post_mlp_norm",
-                  _mlp(cfg, lp, h, token_mask=token_mask,
-                       allow_capacity=True))
-        return x, kp, vp
+        y, counts = _mlp(cfg, lp, h,
+                         token_mask=token_mask, allow_capacity=True)
+        x = x + _post(cfg, lp, "post_mlp_norm", y)
+        return x, kp, vp, counts
 
-    x, k_pages, v_pages = _scan_layers_paged(
-        params, body, x, k_pages, v_pages, cfg.num_layers
-    )
+    x, k_pages, v_pages, moe_stats = _scan_layers_paged(
+        cfg, params, body, x, k_pages, v_pages)
     last = jnp.take(x, chunk_len - 1, axis=0)[None]  # [1, E]
     logits = _logits(cfg, params, last)[0]
-    return PrefillOut(logits, k_pages, v_pages)
+    return PrefillOut(logits, k_pages, v_pages, moe_stats)
 
 
 class PrefillBatchOut(NamedTuple):
     last_logits: jax.Array  # [N, V] logits at each sequence's final token
     k_pages: jax.Array
     v_pages: jax.Array
+    moe_stats: Any = None  # grouped expert layers' counts, or None
 
 
 def prefill_batch(
@@ -651,31 +778,32 @@ def prefill_batch(
             kp, vp, k, v, pages.reshape(-1) + page_off, page_size=page_size
         )
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-        x = x + _post(cfg, lp, "post_mlp_norm",
-                  _mlp(cfg, lp, h, token_mask=token_mask,
-                       allow_capacity=True))
-        return x, kp, vp
+        y, counts = _mlp(cfg, lp, h,
+                         token_mask=token_mask, allow_capacity=True)
+        x = x + _post(cfg, lp, "post_mlp_norm", y)
+        return x, kp, vp, counts
 
-    x, k_pages, v_pages = _scan_layers_paged(
-        params, body, x, k_pages, v_pages, cfg.num_layers
-    )
+    x, k_pages, v_pages, moe_stats = _scan_layers_paged(
+        cfg, params, body, x, k_pages, v_pages)
     last = jnp.take_along_axis(
         x.reshape(n, s, -1), (seq_lens - 1)[:, None, None], axis=1
     )[:, 0]  # [N, E]
     logits = _logits(cfg, params, last)
-    return PrefillBatchOut(logits, k_pages, v_pages)
+    return PrefillBatchOut(logits, k_pages, v_pages, moe_stats)
 
 
 class DecodeOut(NamedTuple):
     logits: jax.Array  # [B, V]
     k_pages: jax.Array
     v_pages: jax.Array
+    moe_stats: Any = None  # grouped expert layers' counts, or None
 
 
 class VerifyOut(NamedTuple):
     logits: jax.Array  # [B, K1, V] — logits at every query position
     k_pages: jax.Array
     v_pages: jax.Array
+    moe_stats: Any = None  # grouped expert layers' counts, or None
 
 
 def decode_verify(
@@ -721,6 +849,9 @@ def decode_verify(
     slots = (None if adapter_slots is None
              else jnp.repeat(adapter_slots.astype(jnp.int32), k1))
     x = _embed_rows(cfg, params, tokens.reshape(b * k1))
+    live = _live_rows(cfg, block_tables)
+    if live is not None:
+        live = jnp.repeat(live, k1)
 
     def body(x, kp, vp, lp, page_off):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
@@ -742,14 +873,14 @@ def decode_verify(
                   _attn_out(cfg, lp, o.reshape(b * k1, *o.shape[2:]),
                             lora_slots=slots))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-        x = x + _post(cfg, lp, "post_mlp_norm", _mlp(cfg, lp, h))
-        return x, kp, vp
+        y, counts = _mlp(cfg, lp, h, token_mask=live)
+        x = x + _post(cfg, lp, "post_mlp_norm", y)
+        return x, kp, vp, counts
 
-    x, k_pages, v_pages = _scan_layers_paged(
-        params, body, x, k_pages, v_pages, cfg.num_layers
-    )
+    x, k_pages, v_pages, moe_stats = _scan_layers_paged(
+        cfg, params, body, x, k_pages, v_pages)
     logits = _logits(cfg, params, x).reshape(b, k1, -1)
-    return VerifyOut(logits, k_pages, v_pages)
+    return VerifyOut(logits, k_pages, v_pages, moe_stats)
 
 
 def decode_step(
@@ -769,6 +900,7 @@ def decode_step(
     x = _embed_rows(cfg, params, tokens)  # [B, E]
     slots = (None if adapter_slots is None
              else adapter_slots.astype(jnp.int32))
+    live = _live_rows(cfg, block_tables)
 
     def body(x, kp, vp, lp, page_off):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
@@ -788,14 +920,14 @@ def decode_step(
         x = x + _post(cfg, lp, "post_attn_norm",
                       _attn_out(cfg, lp, o, lora_slots=slots))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-        x = x + _post(cfg, lp, "post_mlp_norm", _mlp(cfg, lp, h))
-        return x, kp, vp
+        y, counts = _mlp(cfg, lp, h, token_mask=live)
+        x = x + _post(cfg, lp, "post_mlp_norm", y)
+        return x, kp, vp, counts
 
-    x, k_pages, v_pages = _scan_layers_paged(
-        params, body, x, k_pages, v_pages, cfg.num_layers
-    )
+    x, k_pages, v_pages, moe_stats = _scan_layers_paged(
+        cfg, params, body, x, k_pages, v_pages)
     logits = _logits(cfg, params, x)
-    return DecodeOut(logits, k_pages, v_pages)
+    return DecodeOut(logits, k_pages, v_pages, moe_stats)
 
 
 class MixedOut(NamedTuple):
@@ -803,6 +935,7 @@ class MixedOut(NamedTuple):
     chunk_logits: jax.Array  # [V] logits at the chunk's last valid token
     k_pages: jax.Array
     v_pages: jax.Array
+    moe_stats: Any = None  # grouped expert layers' counts, or None
 
 
 def mixed_step(
@@ -846,8 +979,10 @@ def mixed_step(
     b = tokens.shape[0]
     c = chunk_tokens.shape[0]
     all_pos = jnp.concatenate([positions, chunk_start + jnp.arange(c)])
+    live = _live_rows(cfg, block_tables)
     token_mask = jnp.concatenate(
-        [jnp.ones((b,), bool), jnp.arange(c) < chunk_len])
+        [jnp.ones((b,), bool) if live is None else live,
+         jnp.arange(c) < chunk_len])
     write_pages = jax.lax.dynamic_slice(
         chunk_pages, (chunk_start // page_size,), (c // page_size,)
     )
@@ -883,17 +1018,16 @@ def mixed_step(
         x = x + _post(cfg, lp, "post_attn_norm",
                       _attn_out(cfg, lp, o, lora_slots=slots))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-        x = x + _post(cfg, lp, "post_mlp_norm",
-                      _mlp(cfg, lp, h, token_mask=token_mask))
-        return x, kp, vp
+        y, counts = _mlp(cfg, lp, h, token_mask=token_mask)
+        x = x + _post(cfg, lp, "post_mlp_norm", y)
+        return x, kp, vp, counts
 
-    x, k_pages, v_pages = _scan_layers_paged(
-        params, body, x, k_pages, v_pages, cfg.num_layers
-    )
+    x, k_pages, v_pages, moe_stats = _scan_layers_paged(
+        cfg, params, body, x, k_pages, v_pages)
     last = jnp.take(x[b:], chunk_len - 1, axis=0)[None]  # [1, E]
     rows = jnp.concatenate([x[:b], last])
     logits = _logits(cfg, params, rows)
-    return MixedOut(logits[:b], logits[b], k_pages, v_pages)
+    return MixedOut(logits[:b], logits[b], k_pages, v_pages, moe_stats)
 
 
 class MixedVerifyOut(NamedTuple):
@@ -901,6 +1035,7 @@ class MixedVerifyOut(NamedTuple):
     chunk_logits: jax.Array  # [V] logits at the chunk's last valid token
     k_pages: jax.Array
     v_pages: jax.Array
+    moe_stats: Any = None  # grouped expert layers' counts, or None
 
 
 def mixed_verify_step(
@@ -947,8 +1082,10 @@ def mixed_verify_step(
     flat_pos = jnp.where(valid, flat_pos, 0)
     flat_tables = jnp.where(valid[:, None], flat_tables, 0)
     all_pos = jnp.concatenate([flat_pos, chunk_start + jnp.arange(c)])
+    live = _live_rows(cfg, block_tables)
     token_mask = jnp.concatenate(
-        [jnp.ones((n,), bool), jnp.arange(c) < chunk_len])
+        [jnp.ones((n,), bool) if live is None else jnp.repeat(live, k1),
+         jnp.arange(c) < chunk_len])
     write_pages = jax.lax.dynamic_slice(
         chunk_pages, (chunk_start // page_size,), (c // page_size,)
     )
@@ -985,15 +1122,14 @@ def mixed_verify_step(
         x = x + _post(cfg, lp, "post_attn_norm",
                       _attn_out(cfg, lp, o, lora_slots=slots))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-        x = x + _post(cfg, lp, "post_mlp_norm",
-                      _mlp(cfg, lp, h, token_mask=token_mask))
-        return x, kp, vp
+        y, counts = _mlp(cfg, lp, h, token_mask=token_mask)
+        x = x + _post(cfg, lp, "post_mlp_norm", y)
+        return x, kp, vp, counts
 
-    x, k_pages, v_pages = _scan_layers_paged(
-        params, body, x, k_pages, v_pages, cfg.num_layers
-    )
+    x, k_pages, v_pages, moe_stats = _scan_layers_paged(
+        cfg, params, body, x, k_pages, v_pages)
     last = jnp.take(x[n:], chunk_len - 1, axis=0)[None]  # [1, E]
     rows = jnp.concatenate([x[:n], last])
     logits = _logits(cfg, params, rows)
     return MixedVerifyOut(logits[:n].reshape(b, k1, -1), logits[n],
-                          k_pages, v_pages)
+                          k_pages, v_pages, moe_stats)

@@ -106,11 +106,14 @@ def random_quantized_params(cfg: ModelConfig, seed: int = 0,
     rng = np.random.Generator(np.random.PCG64(seed))
     p: Dict[str, np.ndarray] = {}
     for name, (shape, kind, sigma) in llama.param_specs(cfg).items():
-        if kind == "ones":
+        axes = quant.quant_axes(name)
+        if name == "router_bias":
+            p[name] = np.zeros(shape, np.float32)
+        elif kind == "ones":
             p[name] = np.ones(shape, dt)
         elif kind == "zeros":
             p[name] = np.zeros(shape, dt)
-        elif name in quant.QUANT_AXES:
+        elif axes:
             n = int(np.prod(shape))
             # 16 MiB of entropy tiled to size: weight VALUES are
             # irrelevant here (no checkpoint to reproduce; serving
@@ -118,7 +121,7 @@ def random_quantized_params(cfg: ModelConfig, seed: int = 0,
             # matter, and multi-GiB PCG64 streams cost minutes
             ent = np.frombuffer(rng.bytes(min(n, 1 << 24)), dtype=np.int8)
             q = np.tile(ent, -(-n // ent.size))[:n].reshape(shape)
-            sshape = tuple(1 if i in quant.QUANT_AXES[name] else s
+            sshape = tuple(1 if i in axes else s
                            for i, s in enumerate(shape))
             scale = np.full(sshape, sigma * 4.5 / 127.0, dtype=np.float32)
             p[name] = cls(q, scale)
@@ -134,13 +137,12 @@ def load_hf_safetensors(cfg: ModelConfig, files) -> Dict[str, jax.Array]:
     from safetensors import safe_open
 
     dt = jnp.dtype(cfg.dtype)
-    e, h, kv, d, f, l = (
+    e, h, kv, d, f = (
         cfg.hidden_size,
         cfg.num_heads,
         cfg.num_kv_heads,
         cfg.head_dim,
         cfg.intermediate_size,
-        cfg.num_layers,
     )
 
     raw: Dict[str, jax.Array] = {}
@@ -164,12 +166,37 @@ def load_hf_safetensors(cfg: ModelConfig, files) -> Dict[str, jax.Array]:
     def to_dt(x) -> jax.Array:
         return jnp.asarray(x).astype(dt)
 
-    def stack(fmt: str, transform) -> jax.Array:
-        return jnp.stack([transform(g(fmt.format(i=i))) for i in range(l)])
-
     p: Dict[str, jax.Array] = {}
-    p["embed"] = to_dt(g("model.embed_tokens.weight"))
+    # a sliced vocabulary (this chip's share): rows [vocab_offset,
+    # vocab_offset + vocab_size) of the checkpoint's table and head
+    v0, v1 = cfg.vocab_offset, cfg.vocab_offset + cfg.vocab_size
+    p["embed"] = to_dt(g("model.embed_tokens.weight"))[v0:v1]
     p["final_norm"] = to_dt(g("model.norm.weight"))
+    if not cfg.tie_word_embeddings:
+        p["lm_head"] = to_dt(g("lm_head.weight"))[v0:v1].T
+    first = cfg.first_k_dense
+    # leading dense layers into their own stack, the rest into the scanned
+    # one; checkpoint layer numbers run through both
+    _load_layers(cfg, p, g, has, to_dt, range(first, cfg.num_layers), "",
+                 is_moe=cfg.is_moe, f=f)
+    if first:
+        _load_layers(cfg, p, g, has, to_dt, range(first), llama.DENSE_PREFIX,
+                     is_moe=False, f=cfg.dense_intermediate_size)
+    return p
+
+
+def _load_layers(cfg: ModelConfig, out: Dict[str, jax.Array], g, has, to_dt,
+                 layers, pre: str, *, is_moe: bool, f: int) -> None:
+    """Stack checkpoint layers `layers` into out[pre + name], leading axis
+    in that order. `f` is the stack's dense / shared-expert FFN width."""
+    e, h, kv, d = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
+                   cfg.head_dim)
+    l0 = layers[0]
+    p: Dict[str, jax.Array] = {}
+
+    def stack(fmt: str, transform) -> jax.Array:
+        return jnp.stack([transform(g(fmt.format(i=i))) for i in layers])
+
     p["attn_norm"] = stack(
         "model.layers.{i}.input_layernorm.weight", lambda w: to_dt(w)
     )
@@ -215,8 +242,27 @@ def load_hf_safetensors(cfg: ModelConfig, files) -> Dict[str, jax.Array]:
             return jnp.concatenate(
                 [w[..., :lora], w[..., lora + deint]], axis=-1)
 
-        p["wq_mla"] = stack(
-            "model.layers.{i}.self_attn.q_proj.weight", fix_q)
+        if cfg.q_lora_rank > 0:
+            # query low-rank path: q_a_proj [qr, E], its norm, and
+            # q_b_proj [H*(nope+rope), qr] with the same per-head rope
+            # de-interleave as the one-matrix form
+            qr = cfg.q_lora_rank
+            p["wq_a"] = stack(
+                "model.layers.{i}.self_attn.q_a_proj.weight",
+                lambda w: to_dt(w).T)
+            p["q_a_norm"] = stack(
+                "model.layers.{i}.self_attn.q_a_layernorm.weight", to_dt)
+
+            def fix_q_b(w):
+                w = to_dt(w).T.reshape(qr, h, nope + rope)
+                return jnp.concatenate(
+                    [w[..., :nope], w[..., nope + deint]], axis=-1)
+
+            p["wq_b"] = stack(
+                "model.layers.{i}.self_attn.q_b_proj.weight", fix_q_b)
+        else:
+            p["wq_mla"] = stack(
+                "model.layers.{i}.self_attn.q_proj.weight", fix_q)
         p["w_kv_a"] = stack(
             "model.layers.{i}.self_attn.kv_a_proj_with_mqa.weight",
             fix_kv_a,
@@ -230,19 +276,19 @@ def load_hf_safetensors(cfg: ModelConfig, files) -> Dict[str, jax.Array]:
             return b[:, :nope, :], jnp.swapaxes(b[:, nope:, :], 1, 2)
 
         kv_b = [split_kv_b(g(f"model.layers.{i}.self_attn.kv_b_proj.weight"))
-                for i in range(l)]
+                for i in layers]
         p["w_uk"] = jnp.stack([b[0] for b in kv_b])
         p["w_uv"] = jnp.stack([b[1] for b in kv_b])
         p["wo"] = stack(
             "model.layers.{i}.self_attn.o_proj.weight",
             lambda w: to_dt(w).T.reshape(h, vd, e),
         )
-    elif has("model.layers.0.self_attn.qkv_proj.weight"):
+    elif has(f"model.layers.{l0}.self_attn.qkv_proj.weight"):
         # Phi-3 fuses q/k/v rows into one projection: [(H+2KV)*D, E] with
         # q first, then k, then v (same split in HF's Phi3Attention);
         # each fused tensor is read ONCE per layer (stack() consumes)
         qkv = [to_dt(g(f"model.layers.{i}.self_attn.qkv_proj.weight"))
-               for i in range(l)]
+               for i in layers]
         p["wq"] = jnp.stack([w[: h * d].T.reshape(e, h, d) for w in qkv])
         p["wk"] = jnp.stack(
             [w[h * d: (h + kv) * d].T.reshape(e, kv, d) for w in qkv])
@@ -282,21 +328,20 @@ def load_hf_safetensors(cfg: ModelConfig, files) -> Dict[str, jax.Array]:
     if cfg.qk_norm:
         p["q_norm"] = stack("model.layers.{i}.self_attn.q_norm.weight", to_dt)
         p["k_norm"] = stack("model.layers.{i}.self_attn.k_norm.weight", to_dt)
-    if cfg.is_moe:
-        x = cfg.num_experts
-        if (has("model.layers.0.mlp.gate_proj.weight")
-                and not has("model.layers.0.mlp.gate.weight")):
-            # DeepSeek's first_k_dense_replace layout: layer 0 is a plain
-            # dense FFN while later layers are MoE — the uniform layer scan
-            # cannot represent it, so fail with the real reason instead of
-            # a KeyError deep in the expert stacking
+    if is_moe:
+        if (has(f"model.layers.{l0}.mlp.gate_proj.weight")
+                and not has(f"model.layers.{l0}.mlp.gate.weight")):
+            # a dense layer where the config promised an expert layer: fail
+            # with the real reason instead of a KeyError deep in the
+            # expert stacking
             raise ValueError(
-                "checkpoint has a dense first layer "
-                "(first_k_dense_replace); heterogeneous layer stacks are "
-                "not supported yet")
+                f"checkpoint layer {l0} is a dense FFN but the config "
+                f"(first_k_dense_replace={cfg.first_k_dense}) makes it an "
+                "expert layer")
         # two upstream MoE naming schemes: Mixtral's block_sparse_moe with
-        # w1/w3/w2, Qwen3-MoE's mlp.experts with gate/up/down_proj
-        if has("model.layers.0.block_sparse_moe.gate.weight"):
+        # w1/w3/w2, Qwen3-MoE's / DeepSeek's mlp.experts with
+        # gate/up/down_proj
+        if has(f"model.layers.{l0}.block_sparse_moe.gate.weight"):
             moe_base = "block_sparse_moe"
             names = {"gate": "w1", "up": "w3", "down": "w2"}
         else:
@@ -307,18 +352,27 @@ def load_hf_safetensors(cfg: ModelConfig, files) -> Dict[str, jax.Array]:
             f"model.layers.{{i}}.{moe_base}.gate.weight",
             lambda w: to_dt(w).T
         )
+        if cfg.router_bias:
+            # selection bias: float32 in the checkpoint, and kept so
+            p["router_bias"] = stack(
+                f"model.layers.{{i}}.{moe_base}.gate.e_score_correction_bias",
+                lambda w: jnp.asarray(w).astype(jnp.float32))
+        # the experts held here: the share's slice of the checkpoint's
+        # (the router above keeps its whole width)
+        x0 = cfg.local_expert_offset
+        held = range(x0, x0 + cfg.held_experts)
 
         def experts(i: int, which: str) -> jnp.ndarray:
             ws = [
                 to_dt(g(f"model.layers.{i}.{moe_base}.experts.{j}"
                         f".{names[which]}.weight")).T
-                for j in range(x)
+                for j in held
             ]
-            return jnp.stack(ws)  # [X, in, out]
+            return jnp.stack(ws)  # [X held, in, out]
 
-        p["moe_w_gate"] = jnp.stack([experts(i, "gate") for i in range(l)])
-        p["moe_w_up"] = jnp.stack([experts(i, "up") for i in range(l)])
-        p["moe_w_down"] = jnp.stack([experts(i, "down") for i in range(l)])
+        p["moe_w_gate"] = jnp.stack([experts(i, "gate") for i in layers])
+        p["moe_w_up"] = jnp.stack([experts(i, "up") for i in layers])
+        p["moe_w_down"] = jnp.stack([experts(i, "down") for i in layers])
         if cfg.num_shared_experts > 0:
             # DeepSeek shared experts load into the dense-MLP param slots
             p["w_gate"] = stack(
@@ -330,10 +384,10 @@ def load_hf_safetensors(cfg: ModelConfig, files) -> Dict[str, jax.Array]:
             p["w_down"] = stack(
                 f"model.layers.{{i}}.{moe_base}.shared_experts"
                 ".down_proj.weight", lambda w: to_dt(w).T)
-    elif has("model.layers.0.mlp.gate_up_proj.weight"):
+    elif has(f"model.layers.{l0}.mlp.gate_up_proj.weight"):
         # Phi-3 fuses gate/up rows: [2F, E], gate first (read once/layer)
         gu = [to_dt(g(f"model.layers.{i}.mlp.gate_up_proj.weight"))
-              for i in range(l)]
+              for i in layers]
         p["w_gate"] = jnp.stack([w[:f].T for w in gu])
         p["w_up"] = jnp.stack([w[f:].T for w in gu])
         p["w_down"] = stack(
@@ -347,6 +401,4 @@ def load_hf_safetensors(cfg: ModelConfig, files) -> Dict[str, jax.Array]:
         p["w_down"] = stack(
             "model.layers.{i}.mlp.down_proj.weight", lambda w: to_dt(w).T
         )
-    if not cfg.tie_word_embeddings:
-        p["lm_head"] = to_dt(g("lm_head.weight")).T
-    return p
+    out.update({pre + k: v for k, v in p.items()})

@@ -69,6 +69,8 @@ QUANT_AXES: Dict[str, Tuple[int, ...]] = {
     # MLA projections (qeinsum-served; W_UK/W_UV stay unquantized — they
     # run in f32 inside the absorbed-query path)
     "wq_mla": (1,),   # [L, E, H, nope+rope]
+    "wq_a": (1,),     # [L, E, q_lora] query low-rank path (Kimi-K2 / V3)
+    "wq_b": (1,),     # [L, q_lora, H, nope+rope]
     "w_kv_a": (1,),   # [L, E, lora+rope]
     "w_gate": (1,),  # [L, E, F]
     "w_up": (1,),
@@ -95,12 +97,20 @@ def qtensor_class(mode: str):
 
 def quantize_params(params: Dict[str, jax.Array], mode: str = "int8"
                     ) -> Dict[str, jax.Array]:
-    """Quantize every weight named in QUANT_AXES; pass the rest through."""
+    """Quantize every weight named in QUANT_AXES; pass the rest through.
+    A leading dense layer's leaf ("dense." prefix) follows its plain
+    name's entry: the stacks share their axes."""
     cls = qtensor_class(mode)
     return {
-        k: quantize(v, QUANT_AXES[k], cls) if k in QUANT_AXES else v
+        k: quantize(v, quant_axes(k), cls) if quant_axes(k) else v
         for k, v in params.items()
     }
+
+
+def quant_axes(name: str):
+    """Contraction axes of the stacked leaf `name`, or None if it stays
+    in the model dtype."""
+    return QUANT_AXES.get(name.rsplit(".", 1)[-1])
 
 
 def is_quantized(params: Dict) -> bool:

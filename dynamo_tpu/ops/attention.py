@@ -231,6 +231,16 @@ def _gather_kv(pages_pool: jax.Array, idx: jax.Array, n_kv: int,
     return rows.reshape(*rows.shape[:-1], n_kv, head_dim)
 
 
+def shared_kv(v_pages) -> bool:
+    """True when V is read from the K rows: an MLA model keeps its latent
+    [c_kv | k_rope] row once (engine/kv_cache.py), so its V pool has no
+    lanes and nothing is written to it. The compositions here gather K
+    alone; the paged kernels copy K alone and use the block they hold for
+    both products (their unused V operand is the K pool again: an HBM
+    reference, nothing is moved)."""
+    return v_pages is None or v_pages.shape[-1] == 0
+
+
 def write_kv_token(
     k_pages: jax.Array,
     v_pages: jax.Array,
@@ -251,17 +261,17 @@ def write_kv_token(
         block_table, (positions // page_size)[:, None], axis=1
     ).squeeze(1)  # [B]
     slot_idx = positions % page_size  # [B]
-    if k_pages.dtype == jnp.int8:
-        w = k_pages.shape[-1]
-        lb = _kv_lane_blocks()
-        k_rows = pack_kv_rows(k_new, w, lane_blocks=lb)
-        v_rows = pack_kv_rows(v_new, w, lane_blocks=lb)
-    else:
-        k_rows = k_new.reshape(b, kv * d)
-        v_rows = v_new.reshape(b, kv * d)
+    def rows(new):
+        if k_pages.dtype == jnp.int8:
+            return pack_kv_rows(new, k_pages.shape[-1],
+                                lane_blocks=_kv_lane_blocks())
+        return new.reshape(b, kv * d)
+
     # advanced indexing over (page, slot) pairs -> rows of [lane_width]
-    k_pages = k_pages.at[page_idx, slot_idx, :].set(k_rows, mode="drop")
-    v_pages = v_pages.at[page_idx, slot_idx, :].set(v_rows, mode="drop")
+    k_pages = k_pages.at[page_idx, slot_idx, :].set(rows(k_new), mode="drop")
+    if not shared_kv(v_pages):
+        v_pages = v_pages.at[page_idx, slot_idx, :].set(rows(v_new),
+                                                        mode="drop")
     return k_pages, v_pages
 
 
@@ -277,18 +287,16 @@ def write_kv_prefill(
     """Scatter a full (padded) prompt's K/V into its pages."""
     s, kv, d = k_new.shape
     n_pages = s // page_size
-    if k_pages.dtype == jnp.int8:
-        w = k_pages.shape[-1]
-        lb = _kv_lane_blocks()
-        k_r = pack_kv_rows(k_new, w, lane_blocks=lb).reshape(
-            n_pages, page_size, w)
-        v_r = pack_kv_rows(v_new, w, lane_blocks=lb).reshape(
-            n_pages, page_size, w)
-    else:
-        k_r = k_new.reshape(n_pages, page_size, kv * d)
-        v_r = v_new.reshape(n_pages, page_size, kv * d)
-    k_pages = k_pages.at[pages].set(k_r, mode="drop")
-    v_pages = v_pages.at[pages].set(v_r, mode="drop")
+    def rows(new):
+        if k_pages.dtype == jnp.int8:
+            w = k_pages.shape[-1]
+            return pack_kv_rows(new, w, lane_blocks=_kv_lane_blocks()
+                                ).reshape(n_pages, page_size, w)
+        return new.reshape(n_pages, page_size, kv * d)
+
+    k_pages = k_pages.at[pages].set(rows(k_new), mode="drop")
+    if not shared_kv(v_pages):
+        v_pages = v_pages.at[pages].set(rows(v_new), mode="drop")
     return k_pages, v_pages
 
 
@@ -326,8 +334,8 @@ def paged_attention_decode_xla(
                    lane_blocks).reshape(
         bsz, pmax * page_size, n_kv, head_dim
     ).transpose(0, 2, 1, 3)
-    v = _gather_kv(v_pages, block_table, n_kv, head_dim, q.dtype,
-                   lane_blocks).reshape(
+    v = k if shared_kv(v_pages) else _gather_kv(
+        v_pages, block_table, n_kv, head_dim, q.dtype, lane_blocks).reshape(
         bsz, pmax * page_size, n_kv, head_dim
     ).transpose(0, 2, 1, 3)
     k = repeat_kv(k, n_heads // n_kv, axis=1)
@@ -497,7 +505,8 @@ def chunk_attention_xla(
     s_ctx = pages.shape[0] * page_size
     k = _gather_kv(k_pages, pages, n_kv, head_dim, q.dtype).reshape(
         s_ctx, n_kv, head_dim)
-    v = _gather_kv(v_pages, pages, n_kv, head_dim, q.dtype).reshape(
+    v = k if shared_kv(v_pages) else _gather_kv(
+        v_pages, pages, n_kv, head_dim, q.dtype).reshape(
         s_ctx, n_kv, head_dim)
     k = repeat_kv(k, n_heads // n_kv, axis=1)
     v = repeat_kv(v, n_heads // n_kv, axis=1)
@@ -747,7 +756,8 @@ def verify_attention(
     s_ctx = w * page_size
     k = _gather_kv(k_pages, block_table, n_kv, head_dim, q.dtype).reshape(
         b, s_ctx, n_kv, head_dim)
-    v = _gather_kv(v_pages, block_table, n_kv, head_dim, q.dtype).reshape(
+    v = k if shared_kv(v_pages) else _gather_kv(
+        v_pages, block_table, n_kv, head_dim, q.dtype).reshape(
         b, s_ctx, n_kv, head_dim)
     k = repeat_kv(k, n_heads // n_kv, axis=2)
     v = repeat_kv(v, n_heads // n_kv, axis=2)

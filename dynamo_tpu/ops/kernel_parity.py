@@ -4,8 +4,12 @@ Interpret mode proves a kernel's arithmetic; only Mosaic on a real chip
 proves it lowers. This module compiles each kernel at a serving model's
 head shapes — and the ragged kernel at the benchmark cells' own mixed step
 (`ragged_cell_*`: 32 slots mostly inactive, tables 128 wide, a 256-token
-chunk at positions 0 and 1536, 28/4 and 32/8 heads) — and compares it with
-its XLA twin from `ops/attention.py`:
+chunk at positions 0 and 1536, 28/4 and 32/8 heads), and every paged kernel
+at MLA's latent geometry as the Kimi-K2 cell runs it (`mla_*`: 64 heads on
+one 640-lane row stored once, 64 slots, contexts of 8-10k, a chunk behind
+an 8,192-token cached prefix) — and compares it with its XLA twin from
+`ops/attention.py` (for `mla_*` the same sums against the one shared row,
+written here: the twin's 64-fold repeat of the row does not fit):
 
     python -m dynamo_tpu.ops.kernel_parity            # on the chip
     python -m dynamo_tpu.ops.kernel_parity --interpret  # CPU rehearsal
@@ -226,6 +230,147 @@ def _case_ragged_cell(h: int, n_kv: int, p_start: int, width: int,
             _forced(op, "xla"), args)
 
 
+# ---- MLA's latent geometry (Kimi-K2 / DeepSeek-V3 cell, benchmarks/chip/
+# configs/kimi-k2-w8a8-ep16-1chip.json): 64 query heads on ONE shared row of
+# 640 lanes (512 + 64, padded), bf16, the row stored once — the V pool has no
+# lanes and the kernels read V from the K rows. 64 slots, --max-seq-len
+# 10240, 256-token chunks, an 8,192-token cached prefix: ISSUE 27's first
+# sizes. The cell now runs the issue's fallback (4,096-token prefix, 6144
+# positions: a table of 384 pages), which lies inside these shapes.
+MLA_HEADS, MLA_LANES = 64, 640
+MLA_SLOTS = 64
+MLA_TABLE_WIDTH = 640
+MLA_POOL_PAGES = 4096
+# live slots (slot -> context); the rest are inactive (zero table, ctx 1)
+MLA_LIVE = {0: 8600, 5: 8225, 6: 9984, 21: 8193, 40: 9000, 63: 16}
+# (chunk start, chunk table width): a first chunk in the 256 bucket, and a
+# tail's first chunk behind the cached prefix in the 10240 bucket
+MLA_CHUNKS = ((0, 16 + 15), (8192, 640 + 15))
+
+
+def _mla_decode_twin(q, kp, bt, cl):
+    """Absorbed MLA decode over the gathered rows, every head against the
+    one shared row (what attention.paged_attention_decode_xla computes,
+    without repeating the row 64 times: at this size that does not fit)."""
+    b, pmax = bt.shape
+    rows = kp[bt].reshape(b, pmax * PAGE_SIZE, MLA_LANES)
+    scale = 1.0 / jnp.sqrt(jnp.float32(MLA_LANES)).astype(q.dtype)
+    s = jnp.einsum("bhd,bsd->bhs", q * scale, rows)
+    mask = jnp.arange(pmax * PAGE_SIZE)[None, None, :] < cl[:, None, None]
+    s = jnp.where(mask, s, jnp.finfo(s.dtype).min)
+    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhs,bsd->bhd", p, rows)
+
+
+def _mla_chunk_twin(q, kp, pages, start):
+    c = q.shape[0]
+    rows = kp[pages].reshape(pages.shape[0] * PAGE_SIZE, MLA_LANES)
+    scale = 1.0 / jnp.sqrt(jnp.float32(MLA_LANES)).astype(q.dtype)
+    s = jnp.einsum("chd,sd->hcs", q * scale, rows)
+    mask = (jnp.arange(rows.shape[0])[None, None, :]
+            <= start + jnp.arange(c)[None, :, None])
+    s = jnp.where(mask, s, jnp.finfo(s.dtype).min)
+    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("hcs,sd->chd", p, rows)
+
+
+def _mla_pool(rng, pages: int):
+    kp = jnp.asarray(rng.normal(size=(pages, PAGE_SIZE, MLA_LANES)),
+                     jnp.bfloat16)
+    return kp, jnp.zeros((pages, PAGE_SIZE, 0), jnp.bfloat16)
+
+
+def _mla_batch(shrink: int):
+    """(tables, contexts, first free page) of the cell's decode batch; an
+    interpret-mode rehearsal divides the contexts by `shrink`."""
+    tables = np.zeros((MLA_SLOTS, MLA_TABLE_WIDTH // shrink), np.int32)
+    ctx = np.ones((MLA_SLOTS,), np.int32)
+    nxt = 1
+    for slot, c in MLA_LIVE.items():
+        c = max(1, c // shrink)
+        n = -(-c // PAGE_SIZE)
+        tables[slot, :n] = np.arange(nxt, nxt + n)
+        ctx[slot] = c
+        nxt += n
+    return tables, ctx, nxt
+
+
+def _case_mla_decode(interpret: bool):
+    """The decode window's kernel over the cell's batch."""
+    shrink = 16 if interpret else 1
+    rng = np.random.default_rng(27)
+    tables, ctx, _ = _mla_batch(shrink)
+    kp, vp = _mla_pool(rng, MLA_POOL_PAGES // shrink)
+    q = jnp.asarray(rng.normal(size=(MLA_SLOTS, MLA_HEADS, MLA_LANES)),
+                    jnp.bfloat16)
+    ref = jax.jit(lambda q, kp, vp, bt, cl: _mla_decode_twin(q, kp, bt, cl))
+    ker = jax.jit(lambda *a: pa.paged_attention_decode(
+        *a, page_size=PAGE_SIZE, num_kv_heads=1, interpret=interpret))
+    return ker, ref, (q, kp, vp, jnp.asarray(tables), jnp.asarray(ctx))
+
+
+def _mla_chunk_pages(first: int, p_start: int, width: int, shrink: int):
+    p_start //= shrink
+    used = (p_start + CELL_CHUNK) // PAGE_SIZE
+    pages = np.zeros((max(width // shrink, used),), np.int32)
+    pages[:used] = np.arange(first, first + used)
+    return pages, p_start
+
+
+def _case_mla_chunk(p_start: int, width: int, interpret: bool):
+    """The chunk kernel: 256 tokens behind `p_start` cached ones."""
+    shrink = 16 if interpret else 1
+    rng = np.random.default_rng(28 + p_start)
+    kp, vp = _mla_pool(rng, MLA_POOL_PAGES // shrink)
+    pages, start = _mla_chunk_pages(1, p_start, width, shrink)
+    q = jnp.asarray(rng.normal(size=(CELL_CHUNK, MLA_HEADS, MLA_LANES)),
+                    jnp.bfloat16)
+    ref = jax.jit(lambda q, kp, vp, pg, st: _mla_chunk_twin(q, kp, pg, st))
+    ker = jax.jit(lambda *a: pa.chunk_prefill_attention(
+        *a, page_size=PAGE_SIZE, num_kv_heads=1, interpret=interpret))
+    return ker, ref, (q, kp, vp, jnp.asarray(pages),
+                      jnp.asarray(start, jnp.int32))
+
+
+def _case_mla_ragged(p_start: int, width: int, interpret: bool):
+    """The cell's mixed step through the dispatcher: 64 decode rows of
+    which 6 live plus one 256-token chunk at `p_start`."""
+    shrink = 16 if interpret else 1
+    rng = np.random.default_rng(29 + p_start)
+    tables, ctx, nxt = _mla_batch(shrink)
+    kp, vp = _mla_pool(rng, MLA_POOL_PAGES // shrink + 32)
+    pages, start = _mla_chunk_pages(nxt, p_start, width, shrink)
+    q = jnp.asarray(
+        rng.normal(size=(MLA_SLOTS + CELL_CHUNK, MLA_HEADS, MLA_LANES)),
+        jnp.bfloat16)
+
+    def op(*a):
+        return att.ragged_mixed_attention(
+            *a, page_size=PAGE_SIZE, num_kv_heads=1, num_decode=MLA_SLOTS)
+
+    def twin(q, kp, vp, bt, cl, pg, st):
+        return jnp.concatenate([
+            _mla_decode_twin(q[:MLA_SLOTS], kp, bt, cl),
+            _mla_chunk_twin(q[MLA_SLOTS:], kp, pg, st)], axis=0)
+    args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(ctx),
+            jnp.asarray(pages), jnp.asarray(start, jnp.int32))
+    return (_forced(op, "pallas_interpret" if interpret else "pallas"),
+            jax.jit(twin), args)
+
+
+def _case_mla_prefill(s: int, interpret: bool):
+    """Full prefill of a short prompt in the absorbed form: 64 heads
+    against one 640-lane row (prompts up to the chunk size take it)."""
+    rng = np.random.default_rng(31)
+    q = jnp.asarray(rng.normal(size=(s, MLA_HEADS, MLA_LANES)), jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(s, 1, MLA_LANES)), jnp.bfloat16)
+    sl = jnp.asarray(max(1, s - 5), jnp.int32)
+    ref = jax.jit(lambda q, k, v, sl: att.prefill_attention_xla(q, k, v, sl))
+    ker = jax.jit(lambda q, k, v, sl: pa.prefill_attention(
+        q, k, v, sl, interpret=interpret))
+    return ker, ref, (q, k, k, sl)
+
+
 def cases(interpret: bool) -> List[Tuple[str, Callable]]:
     out: List[Tuple[str, Callable]] = []
     for label, h, n_kv in SHAPES:
@@ -246,6 +391,19 @@ def cases(interpret: bool) -> List[Tuple[str, Callable]]:
             out.append((f"ragged_cell_p{p_start}_bf16/{label}",
                         functools.partial(_case_ragged_cell, h, n_kv,
                                           p_start, width, interpret)))
+    label = f"{MLA_HEADS}q1kv{MLA_LANES}"
+    out.append((f"mla_decode_cell_bf16/{label}",
+                functools.partial(_case_mla_decode, interpret)))
+    for p_start, width in MLA_CHUNKS:
+        out.append((f"mla_chunk_p{p_start}_bf16/{label}",
+                    functools.partial(_case_mla_chunk, p_start, width,
+                                      interpret)))
+        out.append((f"mla_ragged_cell_p{p_start}_bf16/{label}",
+                    functools.partial(_case_mla_ragged, p_start, width,
+                                      interpret)))
+    for s_len in (16, 256):
+        out.append((f"mla_prefill_s{s_len}/{label}",
+                    functools.partial(_case_mla_prefill, s_len, interpret)))
     return out
 
 

@@ -17,8 +17,20 @@ dynamo_tpu.parallel.sharding map moe_w_* onto P('expert', ...)):
   device touches only its local experts. Tokens past an expert's capacity are
   dropped (standard capacity-factor semantics); cf defaults to 1.25.
 
-The dense combine matrix [T, X] is the single interface between routing and
-dispatch, so both paths share the router code in models/llama.py.
+- `moe_mlp_grouped`: each token is computed only in the experts it picked.
+  The T*k assignments are sorted by expert and the three projections run as
+  grouped matmuls (`jax.lax.ragged_dot`; int8 x int8 -> int32 for W8A8
+  weights) over the groups; nothing is dropped at any imbalance, an expert
+  no token picked is not read. It is told which experts it holds
+  (`expert_offset`, the weights' leading axis): the router keeps its full
+  width, assignments to experts held elsewhere are left out — that part
+  of the sum is another chip's — and no code stands in for their exchange.
+  The choice between it and `moe_mlp_dense` is made from the model's
+  shapes (ModelConfig.moe_grouped).
+
+`route_topk` is the single router: (expert ids [T, k], weights [T, k]);
+`topk_combine` scatters them into the dense combine matrix [T, X] the first
+two paths contract with.
 """
 
 from __future__ import annotations
@@ -26,31 +38,63 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from dynamo_tpu.models import quant
 from dynamo_tpu.models.quant import einsum as qeinsum
+
+
+def route_topk(logits: jax.Array, k: int,
+               renormalize: bool = True,
+               scaling_factor: float = 1.0,
+               scoring: str = "softmax",
+               select_bias: jax.Array | None = None):
+    """Router logits [T, X] (float32) -> (expert ids [T, k], weights
+    [T, k] float32).
+
+    scoring="softmax", renormalize=True (Mixtral/Qwen3): softmax over the
+    selected top-k logits, weights sum to 1. renormalize=False (DeepSeek-V2
+    norm_topk_prob=false): the GLOBAL softmax probabilities of the selected
+    experts, sum < 1. scoring="sigmoid" (DeepSeek-V3 / Kimi-K2, HF
+    topk_method noaux_tc at n_group 1): s = sigmoid(logits); the k largest
+    of s + select_bias are picked — the bias moves the PICK only — and the
+    weights are the picked s, divided by their sum + 1e-20 when
+    renormalize. Either way times scaling_factor."""
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choose = scores if select_bias is None else (
+            scores + select_bias.astype(scores.dtype))
+        _, topi = jax.lax.top_k(choose, k)
+        weights = jnp.take_along_axis(scores, topi, axis=-1)
+        if renormalize:
+            weights = weights / (
+                jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    else:
+        topv, topi = jax.lax.top_k(logits, k)
+        if renormalize:
+            weights = jax.nn.softmax(topv, axis=-1)
+        else:
+            weights = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1),
+                                          topi, axis=-1)
+    if scaling_factor != 1.0:
+        weights = weights * scaling_factor
+    return topi, weights
 
 
 def topk_combine(logits: jax.Array, k: int, dtype,
                  renormalize: bool = True,
-                 scaling_factor: float = 1.0) -> jax.Array:
-    """Router logits [T, X] -> dense combine matrix [T, X]: top-k gate
-    weights scattered back, zeros elsewhere.
+                 scaling_factor: float = 1.0, **route) -> jax.Array:
+    """Router logits [T, X] -> dense combine matrix [T, X]: route_topk's
+    gate weights scattered back, zeros elsewhere."""
+    topi, weights = route_topk(logits, k, renormalize, scaling_factor,
+                               **route)
+    return scatter_combine(topi, weights, logits.shape[-1], dtype)
 
-    renormalize=True (Mixtral/Qwen3 convention): softmax over the selected
-    top-k logits, weights sum to 1. renormalize=False (DeepSeek-V2
-    norm_topk_prob=false): the GLOBAL softmax probabilities of the selected
-    experts, sum < 1, optionally scaled by routed_scaling_factor."""
-    topv, topi = jax.lax.top_k(logits, k)
-    if renormalize:
-        weights = jax.nn.softmax(topv, axis=-1)
-    else:
-        weights = jnp.take_along_axis(jax.nn.softmax(logits, axis=-1),
-                                      topi, axis=-1)
-    if scaling_factor != 1.0:
-        weights = weights * scaling_factor
+
+def scatter_combine(topi: jax.Array, weights: jax.Array, num_experts: int,
+                    dtype) -> jax.Array:
     weights = weights.astype(dtype)  # [T, K]
-    t = logits.shape[0]
+    t = topi.shape[0]
     return (
-        jnp.zeros(logits.shape, dtype)
+        jnp.zeros((t, num_experts), dtype)
         .at[jnp.arange(t)[:, None], topi]
         .add(weights)
     )
@@ -107,3 +151,111 @@ def moe_mlp_dropping(
     out = jnp.zeros((t, e), y.dtype)
     out = out.at[sel_i.reshape(-1)].add(y.reshape(-1, e))
     return out
+
+
+# what moe_mlp_grouped counts for a layer (int32 [5]); summed over layers
+# and steps by the model and the engine, read at /worker/stats
+MOE_STATS = ("assignments", "assignments_held", "busiest_held_sum",
+             "experts_touched", "layer_steps")
+
+
+def _flat_groups(w: jax.Array) -> jax.Array:
+    """[L, X, K, N] (a whole layer stack) -> [L * X, K, N]: a view."""
+    return w.reshape((-1,) + w.shape[-2:])
+
+
+def _grouped_dot(x: jax.Array, w, group_sizes: jax.Array,
+                 row_expert: jax.Array) -> jax.Array:
+    """rows [A, K] (sorted by group) x w [G, K, N] -> [A, N]: row a meets
+    the matrix of its own group only (row_expert[a]; group_sizes [G]).
+    QTensorA8 weights contract int8 x int8 -> int32 on the MXU with
+    per-row activation scales, like models.quant.einsum; weight-only int8
+    converts the weights. `w` may be a whole layer stack [L, X, K, N]: its
+    groups are then all layers' experts (see moe_mlp_grouped)."""
+    if not isinstance(w, quant.QTensor):
+        return jax.lax.ragged_dot(x, _flat_groups(w), group_sizes)
+    w = type(w)(_flat_groups(w.q), _flat_groups(w.scale))
+    # scale [G, 1, N] -> each row's own expert's output-channel scales
+    w_scale = jnp.take(w.scale[:, 0, :], row_expert, axis=0)  # [A, N]
+    if isinstance(w, quant.QTensorA8):
+        x32 = x.astype(jnp.float32)
+        amax = jnp.max(jnp.abs(x32), axis=-1, keepdims=True)
+        xs = jnp.where(amax > 0, amax / 127.0, 1.0)
+        xq = jnp.clip(jnp.round(x32 / xs), -127, 127).astype(jnp.int8)
+        acc = jax.lax.ragged_dot(xq, w.q, group_sizes,
+                                 preferred_element_type=jnp.int32)
+        return (acc.astype(jnp.float32) * xs * w_scale).astype(x.dtype)
+    y = jax.lax.ragged_dot(x, w.q.astype(x.dtype), group_sizes)
+    return y * w_scale.astype(y.dtype)
+
+
+def moe_mlp_grouped(
+    x: jax.Array,        # [T, E]
+    topi: jax.Array,     # [T, K] expert ids over the router's whole width
+    weights: jax.Array,  # [T, K] gate weights
+    w_gate,              # [Xh, E, F] the experts HELD here
+    w_up,
+    w_down,              # [Xh, F, E]
+    *,
+    expert_offset: int = 0,
+    token_mask: jax.Array | None = None,
+    layer=None,
+):
+    """Each token is computed only in the experts it picked, and only in
+    those held here: experts [expert_offset, expert_offset + Xh) of the
+    router's width. Returns (y [T, E], stats int32 [5] as MOE_STATS).
+
+    The T*K assignments are sorted by held expert (assignments to experts
+    held elsewhere, and those of masked rows, sort behind the last group
+    and belong to no group), the token rows are gathered in that order and
+    the projections run as grouped matmuls; each result row is weighted and
+    added back to its token. The buffers are sized for the worst case —
+    every assignment held here — so no token is ever dropped; the grouped
+    matmul visits only the rows its groups cover and reads only the
+    experts some row picked.
+
+    `layer` (a traced index) with weights [L, Xh, ...]: the WHOLE layer
+    stack is handed to the grouped matmul, whose groups are then all
+    layers' experts with every size zero but this layer's. Slicing a
+    layer's experts out of the stack inside the layer scan would copy
+    them (the grouped matmul is a custom call, a slice cannot fuse into
+    it): 1.06 GB a layer at Kimi-K2's widths, read or not (seen on the
+    chip, PR 27: 70% of a decode step's device time)."""
+    t, k = topi.shape
+    stack = (w_gate.q if isinstance(w_gate, quant.QTensor) else w_gate).shape
+    xh = stack[-3]
+    local = topi.astype(jnp.int32) - expert_offset
+    held = (local >= 0) & (local < xh)
+    if token_mask is not None:
+        held &= token_mask[:, None]
+    key = jnp.where(held, local, xh).reshape(t * k)
+    order = jnp.argsort(key)  # stable: ties keep token order
+    row_expert = jnp.minimum(key[order], xh - 1)
+    tok = order // k
+    group_sizes = jnp.bincount(key, length=xh + 1)[:xh].astype(jnp.int32)
+    n_held = jnp.sum(group_sizes)
+    live = (jnp.arange(t * k) < n_held)[:, None]
+    layer_sizes = group_sizes
+    if layer is not None:
+        first = layer * xh
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((stack[0] * xh,), jnp.int32), group_sizes, (first,))
+        row_expert = row_expert + first
+    with jax.named_scope("moe_experts"):
+        xs = jnp.take(x, tok, axis=0)  # [A, E]
+        g = _grouped_dot(xs, w_gate, group_sizes, row_expert)
+        u = _grouped_dot(xs, w_up, group_sizes, row_expert)
+        h = jnp.where(live, jax.nn.silu(g) * u, 0)
+        y = _grouped_dot(h, w_down, group_sizes, row_expert)
+        # rows behind the last group were never written by the grouped
+        # matmul: select, do not multiply (they may hold anything)
+        wr = weights.reshape(t * k)[order].astype(jnp.float32)
+        y = jnp.where(live, y.astype(jnp.float32) * wr[:, None], 0)
+        out = jnp.zeros(x.shape, jnp.float32).at[tok].add(y).astype(x.dtype)
+    n_all = (jnp.sum(token_mask) * k if token_mask is not None
+             else jnp.int32(t * k))
+    stats = jnp.stack([
+        n_all.astype(jnp.int32), n_held.astype(jnp.int32),
+        jnp.max(layer_sizes), jnp.sum(layer_sizes > 0).astype(jnp.int32),
+        jnp.int32(1)])
+    return out, stats

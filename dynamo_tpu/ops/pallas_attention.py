@@ -55,6 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dynamo_tpu.ops.attention import shared_kv
+
 NEG_INF = float("-inf")
 
 
@@ -95,6 +97,34 @@ DEFAULT_BLOCK_PAGES = _env_int("DYNAMO_TPU_DECODE_BLOCK_PAGES", 8, 1)
 # KV block buffers in the DMA ring: num_bufs - 1 blocks are in flight ahead
 # of the one being consumed (pipeline depth)
 DEFAULT_NUM_BUFS = _env_int("DYNAMO_TPU_DECODE_NUM_BUFS", 4, 2)
+
+
+def _kv_block(kbuf, vbuf, cur, tokens: int, n_kv: int, d: int,
+              lane_width: int, quantized: bool, shared: bool):
+    """(k, v) [tokens, KV*D] of ring slot `cur`, as the products take them.
+
+    Per-head K/V: float32 (int8 rows dequantized), as measured best at 28/4
+    and 32/8 heads, where the MXU is not the limit (PR 26). A shared latent
+    row (MLA: 64 heads on one 640-lane row, V read from K) stays in the
+    pool's bf16 and meets bf16 queries and probabilities with float32
+    accumulation: there every row is multiplied by all the heads, the MXU IS
+    the limit, and float32 operands cost 3-6 passes of it."""
+    if quantized:
+        k = _dequant_rows(kbuf[cur].reshape(tokens, lane_width), n_kv, d,
+                          lane_width)
+        v = k if shared else _dequant_rows(
+            vbuf[cur].reshape(tokens, lane_width), n_kv, d, lane_width)
+        return k, v
+    k = kbuf[cur].reshape(tokens, n_kv * d)
+    if shared:
+        return k, k
+    return (k.astype(jnp.float32),
+            vbuf[cur].reshape(tokens, n_kv * d).astype(jnp.float32))
+
+
+def _v_ring(shared: bool, shape, dtype):
+    """The V block ring's scratch; a token one when V is read from K."""
+    return pltpu.VMEM((1, 1, 8, 128) if shared else shape, dtype)
 
 
 # -------------------------------------------------------------- int8 dequant --
@@ -160,8 +190,10 @@ def _flash_update(m_ref, l_ref, acc_ref, s, v):
         alpha * l_prev + jnp.sum(p, axis=1, keepdims=True), l_ref.shape
     )
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    # p meets v in v's dtype: float32 as stored-then-cast rows are, or the
+    # pool's own bf16 where a kernel keeps them so (_kv_block)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-        p, v, preferred_element_type=jnp.float32
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32
     )
 
 
@@ -201,6 +233,7 @@ def _decode_kernel(
     scale: float,
     lane_width: int,
     quantized: bool,
+    shared: bool = False,
 ):
     b = pl.program_id(0)
     i = pl.program_id(1)
@@ -219,11 +252,12 @@ def _decode_kernel(
                     k_hbm.at[pg], kbuf.at[slot, j], sem.at[slot, 0, j]
                 )
             )
-            out.append(
-                pltpu.make_async_copy(
-                    v_hbm.at[pg], vbuf.at[slot, j], sem.at[slot, 1, j]
+            if not shared:
+                out.append(
+                    pltpu.make_async_copy(
+                        v_hbm.at[pg], vbuf.at[slot, j], sem.at[slot, 1, j]
+                    )
                 )
-            )
         return out
 
     def n_blocks(bb):
@@ -300,20 +334,10 @@ def _decode_kernel(
         def _compute():
             q = q_ref[0].astype(jnp.float32) * scale  # [H, D]
             q_bd = jnp.where(bd_mask, jnp.tile(q, (1, n_kv)), 0.0)  # [H, KVD]
-            if quantized:
-                k = _dequant_rows(
-                    kbuf[cur].reshape(tokens_per_block, lane_width),
-                    n_kv, d, lane_width)
-                v = _dequant_rows(
-                    vbuf[cur].reshape(tokens_per_block, lane_width),
-                    n_kv, d, lane_width)
-            else:
-                k = kbuf[cur].reshape(tokens_per_block, kvd).astype(
-                    jnp.float32)
-                v = vbuf[cur].reshape(tokens_per_block, kvd).astype(
-                    jnp.float32)
+            k, v = _kv_block(kbuf, vbuf, cur, tokens_per_block, n_kv, d,
+                             lane_width, quantized, shared)
             s = jax.lax.dot_general(
-                q_bd, k, (((1,), (1,)), ((), ())),
+                q_bd.astype(k.dtype), k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [H, T] — block-diagonal q => per-head scores, no cross-talk
             tok = i * tokens_per_block + jax.lax.broadcasted_iota(
@@ -351,6 +375,9 @@ def paged_attention_decode(
     bsz, n_heads, head_dim = q.shape
     lane_width = k_pages.shape[2]
     quantized = k_pages.dtype == jnp.int8
+    shared = shared_kv(v_pages)
+    if shared:
+        v_pages = k_pages
     kvd = num_kv_heads * head_dim
     if quantized:
         assert lane_width >= kvd + 2 * num_kv_heads, (lane_width, kvd)
@@ -376,8 +403,8 @@ def paged_attention_decode(
         scratch_shapes=[
             pltpu.VMEM((num_bufs, block_pages, page_size, lane_width),
                        k_pages.dtype),
-            pltpu.VMEM((num_bufs, block_pages, page_size, lane_width),
-                       v_pages.dtype),
+            _v_ring(shared, (num_bufs, block_pages, page_size, lane_width),
+                    v_pages.dtype),
             pltpu.VMEM((n_heads, 128), jnp.float32),
             pltpu.VMEM((n_heads, 128), jnp.float32),
             pltpu.VMEM((n_heads, kvd), jnp.float32),
@@ -395,6 +422,7 @@ def paged_attention_decode(
         scale=scale,
         lane_width=lane_width,
         quantized=quantized,
+        shared=shared,
     )
     out = pl.pallas_call(
         kernel,
@@ -486,6 +514,11 @@ def prefill_attention(
     group = n_heads // n_kv
     scale = 1.0 / (head_dim**0.5)
 
+    # the kernel holds all `group` query heads of a KV head per block:
+    # keep group * block_q rows near 1024 so the f32 accumulator fits the
+    # scoped VMEM at MLA's geometry (64 heads on one 640-lane row); at
+    # group <= 8 this is the requested block
+    block_q = min(block_q, max(8, 1024 // group))
     block_q = min(block_q, max(s, 8))
     block_k = min(block_k, max(s, 8))
     s_pad = -(-s // max(block_q, block_k)) * max(block_q, block_k)
@@ -575,6 +608,7 @@ def _chunk_kernel(
     scale: float,
     lane_width: int,
     quantized: bool,
+    shared: bool = False,
 ):
     """Chunked-prefill flash attention over the paged KV cache.
 
@@ -603,8 +637,9 @@ def _chunk_kernel(
             pg = pages_ref[jnp.minimum(kk * block_pages + j, table_width - 1)]
             out.append(pltpu.make_async_copy(
                 k_hbm.at[pg], kbuf.at[slot, j], sem.at[slot, 0, j]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[pg], vbuf.at[slot, j], sem.at[slot, 1, j]))
+            if not shared:
+                out.append(pltpu.make_async_copy(
+                    v_hbm.at[pg], vbuf.at[slot, j], sem.at[slot, 1, j]))
         return out
 
     def n_blocks(qq):
@@ -657,16 +692,10 @@ def _chunk_kernel(
             q = q_ref[0].astype(jnp.float32).reshape(rows, d) * scale
             qbd_ref[...] = jnp.where(bd_mask, jnp.tile(q, (1, n_kv)), 0.0)
 
-        if quantized:
-            k = _dequant_rows(kbuf[cur].reshape(tokens_per_block, lane_width),
-                              n_kv, d, lane_width)
-            v = _dequant_rows(vbuf[cur].reshape(tokens_per_block, lane_width),
-                              n_kv, d, lane_width)
-        else:
-            k = kbuf[cur].reshape(tokens_per_block, kvd).astype(jnp.float32)
-            v = vbuf[cur].reshape(tokens_per_block, kvd).astype(jnp.float32)
+        k, v = _kv_block(kbuf, vbuf, cur, tokens_per_block, n_kv, d,
+                         lane_width, quantized, shared)
         s = jax.lax.dot_general(
-            qbd_ref[...], k, (((1,), (1,)), ((), ())),
+            qbd_ref[...].astype(k.dtype), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [rows, T]
         tok = kb * tokens_per_block + jax.lax.broadcasted_iota(
@@ -705,6 +734,9 @@ def chunk_prefill_attention(
     c, n_heads, head_dim = q.shape
     lane_width = k_pages.shape[2]
     quantized = k_pages.dtype == jnp.int8
+    shared = shared_kv(v_pages)
+    if shared:
+        v_pages = k_pages
     kvd = num_kv_heads * head_dim
     if quantized:
         assert lane_width >= kvd + 2 * num_kv_heads, (lane_width, kvd)
@@ -740,8 +772,8 @@ def chunk_prefill_attention(
         scratch_shapes=[
             pltpu.VMEM((num_bufs, block_pages, page_size, lane_width),
                        k_pages.dtype),
-            pltpu.VMEM((num_bufs, block_pages, page_size, lane_width),
-                       v_pages.dtype),
+            _v_ring(shared, (num_bufs, block_pages, page_size, lane_width),
+                    v_pages.dtype),
             pltpu.VMEM((rows, kvd), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
@@ -761,6 +793,7 @@ def chunk_prefill_attention(
         scale=scale,
         lane_width=lane_width,
         quantized=quantized,
+        shared=shared,
     )
     q4 = q.reshape(nq, block_q, n_heads, head_dim)
     out = pl.pallas_call(

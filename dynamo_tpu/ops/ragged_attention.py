@@ -70,10 +70,12 @@ from dynamo_tpu.ops.pallas_attention import (
     DEFAULT_BLOCK_PAGES,
     DEFAULT_NUM_BUFS,
     NEG_INF,
-    _dequant_rows,
     _flash_normalize,
     _flash_reset,
     _flash_update,
+    _kv_block,
+    _v_ring,
+    shared_kv,
 )
 
 # True since PR 26: on-chip parity at the benchmark cells' own shapes
@@ -121,6 +123,7 @@ def _ragged_kernel(
     scale: float,
     lane_width: int,
     quantized: bool,
+    shared: bool = False,
 ):
     qb = pl.program_id(0)
     kb = pl.program_id(1)
@@ -164,7 +167,9 @@ def _ragged_kernel(
             # a wait needs the copy's shape and semaphore, not its source
             pg = 0 if wait else tables_ref[
                 r, jnp.minimum(kk * block_pages + j, last)]
-            for hbm, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+            # MLA keeps its latent row once: K alone is copied then
+            for hbm, buf, which in (((k_hbm, kbuf, 0),) if shared else (
+                    (k_hbm, kbuf, 0), (v_hbm, vbuf, 1))):
                 c = pltpu.make_async_copy(
                     hbm.at[pg], buf.at[slot, j], sem.at[slot, which, j])
                 c.wait() if wait else c.start()
@@ -220,16 +225,10 @@ def _ragged_kernel(
             q = q_ref[0].astype(jnp.float32).reshape(rows, d) * scale
             qbd_ref[...] = jnp.where(bd_mask(), jnp.tile(q, (1, n_kv)), 0.0)
 
-        if quantized:
-            k = _dequant_rows(kbuf[cur].reshape(tokens_per_block, lane_width),
-                              n_kv, d, lane_width)
-            v = _dequant_rows(vbuf[cur].reshape(tokens_per_block, lane_width),
-                              n_kv, d, lane_width)
-        else:
-            k = kbuf[cur].reshape(tokens_per_block, kvd).astype(jnp.float32)
-            v = vbuf[cur].reshape(tokens_per_block, kvd).astype(jnp.float32)
+        k, v = _kv_block(kbuf, vbuf, cur, tokens_per_block, n_kv, d,
+                         lane_width, quantized, shared)
         s = jax.lax.dot_general(
-            qbd_ref[...], k, (((1,), (1,)), ((), ())),
+            qbd_ref[...].astype(k.dtype), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [rows, T]
         tok = kb * tokens_per_block + jax.lax.broadcasted_iota(
@@ -286,6 +285,9 @@ def ragged_paged_attention(
     assert c >= 1, "ragged batch needs a prefill chunk (use decode kernel)"
     lane_width = k_pages.shape[2]
     quantized = k_pages.dtype == jnp.int8
+    shared = shared_kv(v_pages)
+    if shared:
+        v_pages = k_pages
     kvd = num_kv_heads * head_dim
     if quantized:
         assert lane_width >= kvd + 2 * num_kv_heads, (lane_width, kvd)
@@ -339,8 +341,8 @@ def ragged_paged_attention(
         scratch_shapes=[
             pltpu.VMEM((num_bufs, block_pages, page_size, lane_width),
                        k_pages.dtype),
-            pltpu.VMEM((num_bufs, block_pages, page_size, lane_width),
-                       v_pages.dtype),
+            _v_ring(shared, (num_bufs, block_pages, page_size, lane_width),
+                    v_pages.dtype),
             pltpu.VMEM((rows, kvd), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
@@ -361,6 +363,7 @@ def ragged_paged_attention(
         scale=scale,
         lane_width=lane_width,
         quantized=quantized,
+        shared=shared,
     )
     out = pl.pallas_call(
         kernel,

@@ -36,6 +36,11 @@ PARAM_RULES: Dict[str, P] = {
     # the shared latent down-projection and norm replicate (every shard
     # scores its local heads against the full latent row)
     "wq_mla": P(None, None, "model", None),   # [L, E, H, nope+rope]
+    # query low-rank path: the shared down-projection and its norm
+    # replicate like w_kv_a, the per-head up-projection shards its heads
+    "wq_a": P(None, None, None),              # [L, E, q_lora]
+    "q_a_norm": P(None, None),
+    "wq_b": P(None, None, "model", None),     # [L, q_lora, H, nope+rope]
     "w_kv_a": P(None, None, None),            # [L, E, lora+rope] shared
     "kv_a_norm": P(None, None),
     "w_uk": P(None, "model", None, None),     # [L, H, nope, lora]
@@ -46,7 +51,11 @@ PARAM_RULES: Dict[str, P] = {
     "w_up": P(None, None, "model"),
     "w_down": P(None, "model", None),  # [L, F, E] row-parallel
     # MoE: experts shard on `expert`, features on `model`
+    # the router and its selection bias keep the model's whole width on
+    # every chip; the expert weights' X axis is the experts HELD: under an
+    # ep > 1 mesh each shard runs the same layer over its own slice
     "router": P(None, None, None),  # [L, E, num_experts]
+    "router_bias": P(None, None),   # [L, num_experts]
     "moe_w_gate": P(None, "expert", None, "model"),  # [L, X, E, F]
     "moe_w_up": P(None, "expert", None, "model"),
     "moe_w_down": P(None, "expert", "model", None),  # [L, X, F, E]
@@ -70,6 +79,9 @@ def param_specs(params: Dict[str, Any]) -> Dict[str, Any]:
     from dynamo_tpu.models.quant import QTensor
 
     def spec_for(name: str, x):
+        # a leading dense layer's leaf ("dense." prefix) follows its plain
+        # name's rule: both stacks carry the same leading layer axis
+        name = name.rsplit(".", 1)[-1]
         if isinstance(x, QTensor):
             rule = PARAM_RULES.get(name, P(*([None] * x.q.ndim)))
             scale_rule = P(*(

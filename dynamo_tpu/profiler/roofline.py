@@ -36,14 +36,18 @@ DISPATCH_OVERHEAD_S = 0.004  # per-step host dispatch + scheduling
 
 
 def param_count(cfg: ModelConfig) -> float:
-    """Total parameter count (all experts for MoE)."""
+    """Parameters held here: every expert of an MoE model, or the experts
+    of this chip's share (cfg.held_experts; the router keeps its width)."""
     h, hd = cfg.hidden_size, cfg.head_dim
     if cfg.is_mla:
         nh, nope, rope = (cfg.num_heads, cfg.qk_nope_head_dim,
                           cfg.qk_rope_head_dim)
         lora, vd = cfg.kv_lora_rank, cfg.v_head_dim
-        attn = (h * nh * (nope + rope)      # q projection
-                + h * (lora + rope)         # latent down-projection
+        qr = cfg.q_lora_rank
+        q_proj = (h * qr + qr + qr * nh * (nope + rope) if qr
+                  else h * nh * (nope + rope))  # low-rank path, or one matrix
+        attn = (q_proj
+                + h * (lora + rope) + lora  # latent down-projection, norm
                 + nh * nope * lora          # W_UK
                 + nh * lora * vd            # W_UV
                 + nh * vd * h)              # output projection
@@ -51,13 +55,16 @@ def param_count(cfg: ModelConfig) -> float:
         attn = (h * cfg.num_heads * hd + 2 * h * cfg.num_kv_heads * hd
                 + cfg.num_heads * hd * h)
     mlp_one = 3 * h * cfg.intermediate_size
-    mlp = mlp_one * max(cfg.num_experts, 1)
+    mlp = mlp_one * max(cfg.held_experts, 1)
     if cfg.is_moe and cfg.num_shared_experts:
         mlp += mlp_one * cfg.num_shared_experts
-    router = h * cfg.num_experts if cfg.is_moe else 0
+    router = (h + cfg.router_bias) * cfg.num_experts if cfg.is_moe else 0
     per_layer = attn + mlp + router + 2 * h  # + rmsnorm scales
+    # leading dense layers: the same attention, one SwiGLU of their width
+    dense_layer = attn + 3 * h * cfg.dense_intermediate_size + 2 * h
     embed = cfg.vocab_size * h * (1 if cfg.tie_word_embeddings else 2)
-    return cfg.num_layers * per_layer + embed + h
+    return ((cfg.num_layers - cfg.first_k_dense) * per_layer
+            + cfg.first_k_dense * dense_layer + embed + h)
 
 
 def active_param_count(cfg: ModelConfig) -> float:
@@ -66,8 +73,52 @@ def active_param_count(cfg: ModelConfig) -> float:
         return param_count(cfg)
     h = cfg.hidden_size
     mlp_one = 3 * h * cfg.intermediate_size
-    inactive = (cfg.num_experts - cfg.num_experts_per_tok) * mlp_one
-    return param_count(cfg) - cfg.num_layers * inactive
+    # of a token's picks, the part that lands on experts held here
+    picks_here = (cfg.num_experts_per_tok * cfg.held_experts
+                  / cfg.num_experts)
+    inactive = (cfg.held_experts - picks_here) * mlp_one
+    return param_count(cfg) - cfg.num_moe_layers * inactive
+
+
+def moe_expert_cost(cfg: ModelConfig, rows: float,
+                    experts_touched: float) -> dict:
+    """Operations and bytes of the grouped expert matmuls (ops/moe.py
+    moe_mlp_grouped) for `rows` assignments to held experts of which
+    `experts_touched` had at least one, int8 weights and rows, int32 out:
+    each row meets its own expert's gate, up and down matrices only, an
+    expert no row picked is not read. The chip benchmark's
+    kernel_costs/grouped_expert_matmul.py counts the same."""
+    e, f = cfg.hidden_size, cfg.intermediate_size
+    return {"ops": rows * 3 * 2 * e * f,
+            "bytes": (experts_touched * 3 * e * f + rows * (2 * e + f)
+                      + rows * 4 * (2 * f + e))}
+
+
+def mla_attention_cost(cfg: ModelConfig, kv_rows_read: float,
+                       qk_pairs: float) -> dict:
+    """Operations and bytes of absorbed-form MLA attention over the paged
+    cache, the form decode AND prefill chunks use here: every head scores
+    against one shared [c_kv | k_rope] bf16 row a token (read once: the
+    cache holds it once) and averages its first kv_lora_rank lanes. The
+    chip benchmark's kernel_costs/mla_paged_attention.py counts the same."""
+    lanes = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    return {"ops": qk_pairs * cfg.num_heads * 2 * (lanes + cfg.kv_lora_rank),
+            "bytes": kv_rows_read * lanes * BYTES}
+
+
+def attention_flops_per_pair(cfg: ModelConfig) -> float:
+    """Operations one (query token, key token) pair costs over all heads:
+    scores and the weighted average. MLA in the absorbed form (the one the
+    program runs, for chunks too) pays the latent width per head."""
+    if cfg.is_mla:
+        return mla_attention_cost(cfg, 0, 1)["ops"]
+    return 4.0 * cfg.num_heads * cfg.head_dim
+
+
+def router_flops_per_token(cfg: ModelConfig) -> float:
+    """The router's matmul over its whole width (sigmoid or softmax and
+    the top-k are negligible beside it), per expert layer."""
+    return 2.0 * cfg.hidden_size * cfg.num_experts if cfg.is_moe else 0.0
 
 
 def kv_bytes_per_token(cfg: ModelConfig, kv_dtype: str = "auto",
@@ -79,6 +130,9 @@ def kv_bytes_per_token(cfg: ModelConfig, kv_dtype: str = "auto",
     if cfg.is_mla:
         tp = 1
     lanes = kv_heads * head_dim
+    # MLA holds its latent row ONCE (engine/kv_cache.py: the V pool has no
+    # lanes); every other model keeps a K row and a V row
+    pools = 1.0 if cfg.is_mla else 2.0
     if kv_dtype == "int8":
         # packed-scale int8 rows, lane-BLOCKED per TP shard and padded to a
         # 128 multiple PER BLOCK (dynamo_tpu.ops.attention.kv_lane_width) —
@@ -87,8 +141,8 @@ def kv_bytes_per_token(cfg: ModelConfig, kv_dtype: str = "auto",
         # the roofline must model the real layout, not lanes/2
         kv_l = max(kv_heads // max(tp, 1), 1)
         block = -(-(kv_l * head_dim + 2 * kv_l) // 128) * 128
-        return 2.0 * cfg.num_layers * max(tp, 1) * block
-    return 2.0 * cfg.num_layers * lanes * BYTES
+        return pools * cfg.num_layers * max(tp, 1) * block
+    return pools * cfg.num_layers * lanes * BYTES
 
 
 # Serving quantization tiers the engine implements (`--quantization`,
@@ -206,7 +260,8 @@ def estimate(
 
     # --- prefill (one request of isl tokens on one tp group).
     l, nh, hd = cfg.num_layers, cfg.num_heads, cfg.head_dim
-    flops_prefill = 2.0 * p_active * isl + 4.0 * l * nh * hd * isl * isl
+    flops_prefill = (2.0 * p_active * isl
+                     + l * attention_flops_per_pair(cfg) * isl * isl)
     t_compute = flops_prefill / (tp * chip.bf16_flops * MFU_PREFILL)
     # 2 all-reduces per layer of the activations (attn out + mlp out)
     act_bytes = isl * cfg.hidden_size * BYTES

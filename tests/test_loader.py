@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.llama import param_specs as llama_param_specs
 from dynamo_tpu.models.loader import load_hf_safetensors
 
 
@@ -215,32 +216,69 @@ def test_from_hf_config_deepseek_mla_keys():
     assert cfg.cache_head_dim == 640 and cfg.cache_kv_heads == 1  # padded for Pallas
 
 
-def test_from_hf_config_rejects_dense_first_layers():
-    with pytest.raises(ValueError, match="first_k_dense_replace"):
-        ModelConfig.from_hf_config({
-            "vocab_size": 100, "hidden_size": 64, "num_hidden_layers": 2,
-            "num_attention_heads": 4, "first_k_dense_replace": 1,
-            "n_routed_experts": 4,
-        }, name="dsv2-dense-first")
+def test_from_hf_config_takes_dense_first_layers():
+    """first_k_dense_replace: leading dense layers of their own width, then
+    the expert layers (it used to be refused)."""
+    cfg = ModelConfig.from_hf_config({
+        "vocab_size": 100, "hidden_size": 64, "num_hidden_layers": 3,
+        "num_attention_heads": 4, "first_k_dense_replace": 1,
+        "n_routed_experts": 4, "intermediate_size": 256,
+        "moe_intermediate_size": 32,
+    }, name="dsv2-dense-first")
+    assert cfg.first_k_dense == 1 and cfg.num_moe_layers == 2
+    assert cfg.dense_intermediate_size == 256
+    assert cfg.intermediate_size == 32  # the experts' width
+    specs = llama_param_specs(cfg)
+    assert specs["dense.w_gate"][0] == (1, 64, 256)
+    assert specs["moe_w_gate"][0] == (2, 4, 64, 32)
+    assert specs["attn_norm"][0] == (2, 64)
+    assert specs["dense.attn_norm"][0] == (1, 64)
 
 
-def test_loader_rejects_dense_first_layer_checkpoint(tmp_path):
+def test_loader_loads_dense_first_layer_checkpoint(tmp_path):
+    """A checkpoint whose layer 0 is a dense FFN loads into the dense
+    stack and the later layers into the scanned one (it used to be
+    refused); the same checkpoint under a config that promises an expert
+    layer there still fails with the reason."""
     from safetensors.numpy import save_file
 
-    cfg = dataclasses.replace(
-        ModelConfig.from_model_name("tiny-moe-debug", dtype="float32"))
-    t = _hf_tensors(cfg, "qwen3moe")
+    moe = dataclasses.replace(
+        ModelConfig.from_model_name("tiny-moe-debug", dtype="float32"),
+        num_layers=3)
+    t = _hf_tensors(moe, "qwen3moe")
     # turn layer 0 into a dense FFN (DeepSeek first_k_dense_replace=1)
     for k in [k for k in t if k.startswith("model.layers.0.mlp.")]:
         del t[k]
-    e, f = cfg.hidden_size, cfg.intermediate_size
+    e, fd = moe.hidden_size, 96
     rng = np.random.default_rng(2)
-    t["model.layers.0.mlp.gate_proj.weight"] = \
-        rng.standard_normal((f, e)).astype(np.float32)
+    for name, shape in (("gate_proj", (fd, e)), ("up_proj", (fd, e)),
+                        ("down_proj", (e, fd))):
+        t[f"model.layers.0.mlp.{name}.weight"] = \
+            rng.standard_normal(shape).astype(np.float32)
     path = tmp_path / "m.safetensors"
     save_file(t, str(path))
     with pytest.raises(ValueError, match="first_k_dense_replace"):
-        load_hf_safetensors(cfg, [str(path)])
+        load_hf_safetensors(moe, [str(path)])
+    cfg = dataclasses.replace(moe, first_k_dense=1,
+                              dense_intermediate_size=fd)
+    p = load_hf_safetensors(cfg, [str(path)])
+    assert p["dense.w_gate"].shape == (1, e, fd)
+    np.testing.assert_array_equal(
+        np.asarray(p["dense.w_gate"][0]),
+        t["model.layers.0.mlp.gate_proj.weight"].T)
+    assert p["moe_w_gate"].shape[:2] == (2, moe.num_experts)
+    # checkpoint layer 1 is the scanned stack's first
+    np.testing.assert_array_equal(
+        np.asarray(p["router"][0]),
+        t["model.layers.1.mlp.gate.weight"].T)
+    np.testing.assert_array_equal(
+        np.asarray(p["attn_norm"][1]),
+        t["model.layers.2.input_layernorm.weight"])
+    np.testing.assert_array_equal(
+        np.asarray(p["dense.attn_norm"][0]),
+        t["model.layers.0.input_layernorm.weight"])
+    assert {k: v.shape for k, v in p.items()} == {
+        k: shape for k, (shape, _, _) in llama_param_specs(cfg).items()}
 
 
 def test_rope_deinterleave_matches_hf_reference():

@@ -111,12 +111,14 @@ def test_model_mlp_moe_paths_agree():
     t = 64
     xs = jax.random.normal(jax.random.PRNGKey(1), (t, cfg.hidden_size),
                            dtype=jnp.float32) * 0.1
-    big = llama._mlp(cfg, lp, xs, allow_capacity=True)  # cf=4 -> cap==t -> dense
+    # cf=4 -> cap==t -> dense
+    big, _ = llama._mlp(cfg, lp, xs, allow_capacity=True)
     cfg_drop = dataclasses.replace(cfg, moe_capacity_factor=1.25)
-    small = llama._mlp(cfg_drop, lp, xs, allow_capacity=True)  # gather path
+    small, _ = llama._mlp(cfg_drop, lp, xs,
+                          allow_capacity=True)  # gather path
     err = np.linalg.norm(np.asarray(big - small)) / np.linalg.norm(np.asarray(big))
     assert err < 0.15, err
     # decode path (allow_capacity=False) must ignore the capacity factor
-    dec = llama._mlp(cfg_drop, lp, xs)
+    dec, _ = llama._mlp(cfg_drop, lp, xs)
     np.testing.assert_allclose(np.asarray(big), np.asarray(dec), rtol=1e-4,
                                atol=1e-5)

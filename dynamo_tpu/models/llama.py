@@ -282,12 +282,17 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     return p
 
 
-def _live_rows(cfg: ModelConfig, block_tables: jax.Array):
+def _live_slots(block_tables: jax.Array) -> jax.Array:
     """[B] bool: decode slots that hold a sequence (an inactive slot's
-    table is all trash page 0, a live one's first page never is). Only the
-    grouped expert layer asks: it computes and counts no row of an empty
-    slot. None elsewhere, so the other models' programs do not change."""
-    return block_tables[:, 0] > 0 if cfg.moe_grouped else None
+    table is all trash page 0, a live one's first page never is)."""
+    return block_tables[:, 0] > 0
+
+
+def _live_rows(cfg: ModelConfig, block_tables: jax.Array):
+    """`_live_slots` as an expert layer's token mask. Only the grouped
+    expert layer asks: it computes and counts no row of an empty slot.
+    None elsewhere, so the other models' MLPs do not change."""
+    return _live_slots(block_tables) if cfg.moe_grouped else None
 
 
 def _layer_params(p: Params) -> Params:
@@ -901,6 +906,10 @@ def decode_step(
     slots = (None if adapter_slots is None
              else adapter_slots.astype(jnp.int32))
     live = _live_rows(cfg, block_tables)
+    # the engine pins an empty slot at context 1 on the trash page; the
+    # Pallas kernel is handed context 0 there and does nothing for the
+    # slot. Once, outside the layer scan.
+    kernel_lens = jnp.where(_live_slots(block_tables), context_lens, 0)
 
     def body(x, kp, vp, lp, page_off):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
@@ -914,7 +923,7 @@ def decode_step(
         )
         o = att.paged_attention_decode(
             q, kp, vp, tables, context_lens, page_size=page_size,
-            num_kv_heads=cfg.cache_kv_heads,
+            num_kv_heads=cfg.cache_kv_heads, kernel_lens=kernel_lens,
             **_attn_kwargs(cfg, page_off, k_pages.shape[1]),
         )
         x = x + _post(cfg, lp, "post_attn_norm",

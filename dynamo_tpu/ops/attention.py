@@ -902,7 +902,12 @@ def paged_attention_decode(
     num_kv_heads=None,
     window=None,
     logit_cap: float = 0.0,
+    kernel_lens=None,  # [B] context_lens with 0 for a slot that holds nothing
 ) -> jax.Array:
+    """`kernel_lens` is what the Pallas kernel is handed in place of
+    `context_lens`: it does nothing for a slot at context 0 (no page copy,
+    zeros out). The XLA twin keeps `context_lens`, where the engine pins an
+    empty slot at context 1 so that no row is masked whole."""
     backend = _resolve_backend()
     windowed = window is not None or bool(logit_cap)
     if windowed:
@@ -970,6 +975,9 @@ def paged_attention_decode(
                 num_kv_heads=n_kv_call,
                 interpret=interpret,
             )
+
+        if kernel_lens is not None:
+            context_lens = kernel_lens
 
     if mesh is None:
         return call(q, k_pages, v_pages, block_table, context_lens)

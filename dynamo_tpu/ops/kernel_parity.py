@@ -2,10 +2,12 @@
 
 Interpret mode proves a kernel's arithmetic; only Mosaic on a real chip
 proves it lowers. This module compiles each kernel at a serving model's
-head shapes — and the ragged kernel at the benchmark cells' own mixed step
-(`ragged_cell_*`: 32 slots mostly inactive, tables 128 wide, a 256-token
-chunk at positions 0 and 1536, 28/4 and 32/8 heads), and every paged kernel
-at MLA's latent geometry as the Kimi-K2 cell runs it (`mla_*`: 64 heads on
+head shapes — and the decode and ragged kernels at the benchmark cells' own
+steps (`decode_cell_*`, `ragged_cell_*`: 32 slots of which 6 live, tables 128
+wide, a 256-token chunk at positions 0 and 1536, 28/4 and 32/8 heads; the
+decode kernel is handed context 0 for an empty slot, as `llama.decode_step`
+hands it), and every paged kernel at MLA's latent geometry as the Kimi-K2
+cell runs it (`mla_*`: 64 heads on
 one 640-lane row stored once, 64 slots, contexts of 8-10k, a chunk behind
 an 8,192-token cached prefix) — and compares it with its XLA twin from
 `ops/attention.py` (for `mla_*` the same sums against the one shared row,
@@ -199,12 +201,9 @@ CELL_LIVE = {0: 517, 3: 300, 4: 16, 9: 129, 17: 1999, 31: 800}
 CELL_CHUNKS = ((0, 16 + 15), (1536, 128 + 15))
 
 
-def _case_ragged_cell(h: int, n_kv: int, p_start: int, width: int,
-                      interpret: bool):
-    """The cells' own mixed step: 32 decode rows of which 6 live, tables
-    128 wide, one 256-token chunk at `p_start`, bf16 pool."""
-    rng = np.random.default_rng(26 + p_start)
-    kp, vp = _pools(rng, n_kv, False, pages=CELL_POOL_PAGES)
+def _cell_batch():
+    """(tables, contexts, first free page) of the chat cells' decode batch:
+    32 slots of which 6 live, tables 128 wide."""
     tables = np.zeros((CELL_SLOTS, CELL_TABLE_WIDTH), np.int32)
     ctx = np.ones((CELL_SLOTS,), np.int32)
     nxt = 1
@@ -213,6 +212,41 @@ def _case_ragged_cell(h: int, n_kv: int, p_start: int, width: int,
         tables[slot, :n] = np.arange(nxt, nxt + n)
         ctx[slot] = c
         nxt += n
+    return tables, ctx, nxt
+
+
+def _as_decode_step(ker, ref, args):
+    """A decode case as `llama.decode_step` hands it over: the kernel gets
+    context 0 for a slot whose table is all trash and writes zeros there;
+    the twin keeps the engine's pin (context 1), and its rows for those
+    slots are zeroed to match."""
+    live = args[3][:, 0] > 0
+    return (lambda q, kp, vp, bt, cl: ker(q, kp, vp, bt,
+                                          jnp.where(live, cl, 0)),
+            lambda *a: jnp.where(live[:, None, None], ref(*a), 0), args)
+
+
+def _case_decode_cell(h: int, n_kv: int, interpret: bool):
+    """The decode window's kernel over the chat cells' batch, bf16 pool."""
+    rng = np.random.default_rng(28)
+    kp, vp = _pools(rng, n_kv, False, pages=CELL_POOL_PAGES)
+    tables, ctx, _ = _cell_batch()
+    q = jnp.asarray(rng.normal(size=(CELL_SLOTS, h, HEAD_DIM)), jnp.bfloat16)
+    ref = jax.jit(lambda *a: att.paged_attention_decode_xla(
+        *a, page_size=PAGE_SIZE, num_kv_heads=n_kv, lane_blocks=1))
+    ker = jax.jit(lambda *a: pa.paged_attention_decode(
+        *a, page_size=PAGE_SIZE, num_kv_heads=n_kv, interpret=interpret))
+    return _as_decode_step(
+        ker, ref, (q, kp, vp, jnp.asarray(tables), jnp.asarray(ctx)))
+
+
+def _case_ragged_cell(h: int, n_kv: int, p_start: int, width: int,
+                      interpret: bool):
+    """The cells' own mixed step: 32 decode rows of which 6 live, tables
+    128 wide, one 256-token chunk at `p_start`, bf16 pool."""
+    rng = np.random.default_rng(26 + p_start)
+    kp, vp = _pools(rng, n_kv, False, pages=CELL_POOL_PAGES)
+    tables, ctx, nxt = _cell_batch()
     used = (p_start + CELL_CHUNK) // PAGE_SIZE
     assert nxt + used <= CELL_POOL_PAGES
     pages = np.zeros((width,), np.int32)
@@ -306,7 +340,8 @@ def _case_mla_decode(interpret: bool):
     ref = jax.jit(lambda q, kp, vp, bt, cl: _mla_decode_twin(q, kp, bt, cl))
     ker = jax.jit(lambda *a: pa.paged_attention_decode(
         *a, page_size=PAGE_SIZE, num_kv_heads=1, interpret=interpret))
-    return ker, ref, (q, kp, vp, jnp.asarray(tables), jnp.asarray(ctx))
+    return _as_decode_step(
+        ker, ref, (q, kp, vp, jnp.asarray(tables), jnp.asarray(ctx)))
 
 
 def _mla_chunk_pages(first: int, p_start: int, width: int, shrink: int):
@@ -387,6 +422,9 @@ def cases(interpret: bool) -> List[Tuple[str, Callable]]:
         add("ragged_mixed_int8kv", _case_ragged, h, n_kv, True, 1)
         add("ragged_verify_q5_bf16", _case_ragged, h, n_kv, False, 5)
     for label, h, n_kv in CELL_SHAPES:
+        out.append((f"decode_cell_bf16/{label}",
+                    functools.partial(_case_decode_cell, h, n_kv,
+                                      interpret)))
         for p_start, width in CELL_CHUNKS:
             out.append((f"ragged_cell_p{p_start}_bf16/{label}",
                         functools.partial(_case_ragged_cell, h, n_kv,

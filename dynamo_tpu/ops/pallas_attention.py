@@ -12,23 +12,32 @@ loop, and decode attention is HBM-bandwidth-bound by definition):
   so each page moves HBM->VMEM in ONE big DMA instead of one tiny DMA per
   KV head. TPU DMA requires the trailing dim be a multiple of 128 lanes;
   KV*D satisfies that for every model this repo serves (8*64, 8*128, ...).
-- **Multi-page superblocks**: each grid step consumes `block_pages` pages
-  (default 8 => 128 tokens) fetched by parallel async copies.
-- **Cross-grid-step double buffering**: the copies for block i+1 (or for the
-  next sequence's first block) are issued before computing on block i, with
-  the pipeline threaded through a persistent SMEM block counter — so in
-  steady state the kernel is never waiting on HBM latency, only throughput.
-  Grid dims are `arbitrary` (sequential) on purpose: the software pipeline
-  carries state across steps.
+- **Multi-page superblocks**: a block is `block_pages` pages (default 8 =>
+  128 tokens) fetched by parallel async copies.
+- **Live KV only**: the grid is one step a batch slot; inside it a loop with
+  a dynamic trip count runs over that slot's OWN superblocks,
+  `ceil(context / tokens_per_block)` of them, read off the scalar-prefetched
+  context lens. A slot at context 0 (an empty slot: `llama.decode_step`
+  hands the kernel 0 where the table is all trash) owns no block: no page
+  copy, no product, zeros written. A block's copies never follow the table
+  past the context (the tail re-reads the last live page), so the work, the
+  bytes and the result depend on nothing a sequence does not own below its
+  context.
+- **A DMA ring across slots**: the copies for the next `num_bufs - 1` blocks
+  (this slot's, or the next slots that own any) are in flight while block i
+  is computed, threaded through a persistent SMEM cursor that steps over the
+  slots that own no block — so in steady state the kernel is never waiting
+  on HBM latency, only throughput. The grid is `arbitrary` (sequential) on
+  purpose: the software pipeline carries state across grid steps.
 - **Block-diagonal GQA matmuls**: all H query heads are packed into one
   `[H, KV*D]` block-diagonal matrix (row r nonzero only in its KV head's
   D-lane span), so scores for every head come from ONE `[H,KV*D]x[KV*D,T]`
   MXU op with zero cross-head score waste in the VPU, and the PV product
   accumulates `[H, KV*D]` whose off-head lanes are sliced away once at
   finalize. No reshapes or transposes of KV data anywhere.
-- Pages whose tokens lie past the context length are masked in-compute;
-  blocks wholly past it are never fetched (the per-sequence block count is a
-  dynamic `fori_loop` bound derived from the scalar-prefetched context lens).
+- Tokens past the context length inside a slot's last block are masked
+  in-compute; the tiled query and its block-diagonal mask are built once a
+  slot, not once a block.
 - **int8 KV pools** (packed-scale rows, see dynamo_tpu.ops.attention) are
   read natively: the superblock DMA moves the int8 rows (half the HBM
   bytes), and `_dequant_rows` rebuilds values in-VMEM with iota-selector
@@ -206,18 +215,28 @@ def _flash_normalize(l_ref, acc_ref):
 # ------------------------------------------------------------------ decode --
 
 
+def _div(x, n: int):
+    """x // n for x >= 0, as ONE operation: `//` on a traced integer is
+    Python's floor division, seven operations that truncation does not
+    need here, and a kernel's size is paid at every trace and lowering.
+    (`ragged_attention` keeps its own copy until ROADMAP D1 folds the
+    decode kernel into it.)"""
+    return jax.lax.div(x, jnp.int32(n))
+
+
 def _decode_kernel(
     # scalar prefetch
     bt_ref,  # [B, Pmax] int32 block table
-    cl_ref,  # [B] int32 context lens (incl. current token)
+    cl_ref,  # [B] int32 context lens (incl. current token); 0 = empty slot
     # inputs
-    q_ref,  # [1, H, D] VMEM block (this sequence's query)
+    q_ref,  # [1, H, D] VMEM block (this slot's query)
     k_hbm,  # [P, ps, KVD] in ANY/HBM — manually DMA'd
     v_hbm,  # [P, ps, KVD]
     o_ref,  # [1, H, D]
     # scratch (persistent across the sequential grid)
     kbuf,  # [NBUF, SB, ps, KVD] KV-dtype ring of block buffers
     vbuf,  # [NBUF, SB, ps, KVD]
+    qbd_ref,  # [H, KVD] block-diagonal queries as the product takes them
     m_ref,  # [H, 128] f32 running max
     l_ref,  # [H, 128] f32 running denominator
     acc_ref,  # [H, KVD] f32 running numerator (off-head lanes carry garbage
@@ -235,128 +254,141 @@ def _decode_kernel(
     quantized: bool,
     shared: bool = False,
 ):
+    """One grid step a slot; inside it a loop over that slot's OWN
+    superblocks, `ceil(ctx / tokens_per_block)` of them. A slot with context
+    0 owns none: no page copy, no product, zeros written. The DMA ring runs
+    on across slots (issue order == consume order), its issue cursor
+    stepping over the slots that own no block."""
     b = pl.program_id(0)
-    i = pl.program_id(1)
     bsz = pl.num_programs(0)
     tokens_per_block = block_pages * page_size
     h, d = q_ref.shape[1], q_ref.shape[2]
     group = h // n_kv
-
-    def block_copies(bb, ii, slot):
-        """The 2*SB async page copies that fetch block ii of sequence bb."""
-        out = []
-        for j in range(block_pages):
-            pg = bt_ref[bb, jnp.minimum(ii * block_pages + j, pages_per_seq - 1)]
-            out.append(
-                pltpu.make_async_copy(
-                    k_hbm.at[pg], kbuf.at[slot, j], sem.at[slot, 0, j]
-                )
-            )
-            if not shared:
-                out.append(
-                    pltpu.make_async_copy(
-                        v_hbm.at[pg], vbuf.at[slot, j], sem.at[slot, 1, j]
-                    )
-                )
-        return out
+    kvd = n_kv * d
 
     def n_blocks(bb):
-        # clamp to >= 1 so every sequence owns at least one pipeline block
-        # (ctx 0 rows emit zeros via the all-masked normalize path; breaking
-        # the issue/consume pairing would corrupt the DMA slot parity)
-        ctx_b = jnp.maximum(cl_ref[bb], 1)
-        return (ctx_b + tokens_per_block - 1) // tokens_per_block
+        return _div(cl_ref[bb] + tokens_per_block - 1, tokens_per_block)
+
+    def block_dma(bb, ii, slot, wait):
+        """Start (or wait for) the page copies of block ii of slot bb into
+        ring slot `slot`. Unrolled: as a loop the copies cost 0.14 us a
+        block, 13-15% of the kernel where every slot is live (PR 28, v5e)."""
+        if not wait:
+            # live KV only: a superblock's tail past the context re-reads
+            # the last live page instead of following the table, so neither
+            # a table entry past the context nor the page it names is read
+            last = jnp.minimum(_div(cl_ref[bb] - 1, page_size),
+                               pages_per_seq - 1)
+        for j in range(block_pages):
+            # a wait needs the copy's shape and semaphore, not its source
+            pg = 0 if wait else bt_ref[
+                bb, jnp.minimum(ii * block_pages + j, last)]
+            # MLA keeps its latent row once: K alone is copied then
+            for hbm, buf, which in (((k_hbm, kbuf, 0),) if shared else (
+                    (k_hbm, kbuf, 0), (v_hbm, vbuf, 1))):
+                c = pltpu.make_async_copy(
+                    hbm.at[pg], buf.at[slot, j], sem.at[slot, which, j])
+                c.wait() if wait else c.start()
+
+    def next_owner(bb):
+        """The first slot at or after bb that owns a block; bsz if none."""
+        return jax.lax.while_loop(
+            lambda x: (x < bsz) & (cl_ref[jnp.minimum(x, bsz - 1)] <= 0),
+            lambda x: x + 1, bb)
 
     def issue_one():
         """Issue the block at the issue cursor (if any remain) into ring
-        slot `issued % num_bufs`, then advance the cursor one active block
-        (every sequence has >= 1 active block, so advancing never skips).
-        The consume side reproduces the slot as `consumed % num_bufs` —
-        issue order == consume order, so the ring stays in lockstep."""
+        slot `issued % num_bufs`, then advance the cursor to the next block
+        anyone owns. The consume side reproduces the slot as
+        `consumed % num_bufs` — issue order == consume order, so the ring
+        stays in lockstep."""
         ib, ii = ptr_ref[1], ptr_ref[2]
 
         @pl.when(ib < bsz)
         def _():
-            slot = jax.lax.rem(ptr_ref[3], num_bufs)
-            for c in block_copies(ib, ii, slot):
-                c.start()
+            block_dma(ib, ii, jax.lax.rem(ptr_ref[3], num_bufs), wait=False)
             ptr_ref[3] = ptr_ref[3] + 1
-            nxt = ii + 1
-            done = nxt >= n_blocks(ib)
-            ptr_ref[1] = jnp.where(done, ib + 1, ib)
-            ptr_ref[2] = jnp.where(done, 0, nxt)
+            more = ii + 1 < n_blocks(ib)
+            ptr_ref[2] = jnp.where(more, ii + 1, 0)
 
-    nb_b = n_blocks(b)
+            @pl.when(jnp.logical_not(more))
+            def _():
+                ptr_ref[1] = next_owner(ib + 1)
 
-    # Pipeline warm-up: the very first grid step primes `num_bufs - 1`
-    # blocks (the full ring minus the slot consumed+reissued each step).
-    @pl.when((b == 0) & (i == 0))
+    # Pipeline warm-up: the first grid step primes `num_bufs - 1` blocks
+    # (the full ring minus the slot consumed+reissued each block).
+    @pl.when(b == 0)
     def _init():
         ptr_ref[0] = 0  # consumed-block count
-        ptr_ref[1] = 0  # issue cursor: sequence
-        ptr_ref[2] = 0  # issue cursor: block within sequence
+        ptr_ref[1] = next_owner(0)  # issue cursor: slot
+        ptr_ref[2] = 0  # issue cursor: block within the slot
         ptr_ref[3] = 0  # issued-block count
-        for _ in range(num_bufs - 1):
+
+        def prime(_, carry):
             issue_one()
+            return carry
 
-    @pl.when(i < nb_b)
-    def _active():
-        cnt = ptr_ref[0]
-        cur = jax.lax.rem(cnt, num_bufs)
+        # a loop: it runs once a call, and the kernel is lowered in every
+        # decode-window program
+        jax.lax.fori_loop(0, num_bufs - 1, prime, 0)
 
-        # keep the ring full: issue one block `num_bufs - 1` ahead of the
-        # one being consumed (slot `cur` frees after this step's wait — the
-        # new issue targets the slot consumed `num_bufs - 1` steps ago,
-        # which is complete and idle)
-        issue_one()
+    ctx = cl_ref[b]
 
-        for c in block_copies(b, i, cur):
-            c.wait()
-        ptr_ref[0] = cnt + 1
+    @pl.when(ctx <= 0)
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
-        @pl.when(i == 0)
-        def _reset():
-            _flash_reset(m_ref, l_ref, acc_ref)
-
+    @pl.when(ctx > 0)
+    def _live():
         # Block-diagonal lane mask over the fused KV*D axis: row r's own KV
         # head (r // group) occupies lanes [(r//group)*D, (r//group+1)*D).
         # Built with iota + lane tiling — no lane-splitting reshapes, which
-        # Mosaic cannot lower.
-        kvd = n_kv * d
-        row_kv = jax.lax.broadcasted_iota(jnp.int32, (h, kvd), 0) // group
-        lane_kv = jax.lax.broadcasted_iota(jnp.int32, (h, kvd), 1) // d
-        bd_mask = row_kv == lane_kv  # [H, KVD]
-        ctx = cl_ref[b]
+        # Mosaic cannot lower. Once a slot, with the tiled query.
+        def bd_mask():  # [H, KVD]; needed before the loop and after it
+            row = jax.lax.broadcasted_iota(jnp.int32, (h, kvd), 0)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (h, kvd), 1)
+            return _div(row, group) == _div(lane, d)
 
-        # Skip compute for a fully-masked block (only possible at ctx == 0,
-        # the inactive-slot case — an all -inf row would NaN the online max).
-        @pl.when(i * tokens_per_block < ctx)
-        def _compute():
-            q = q_ref[0].astype(jnp.float32) * scale  # [H, D]
-            q_bd = jnp.where(bd_mask, jnp.tile(q, (1, n_kv)), 0.0)  # [H, KVD]
+        q = q_ref[0].astype(jnp.float32) * scale  # [H, D]
+        qbd_ref[...] = jnp.where(
+            bd_mask(), jnp.tile(q, (1, n_kv)), 0.0).astype(qbd_ref.dtype)
+        _flash_reset(m_ref, l_ref, acc_ref)
+
+        def block(i, carry):
+            cnt = ptr_ref[0]
+            cur = jax.lax.rem(cnt, num_bufs)
+            # keep the ring full: issue one block `num_bufs - 1` ahead of
+            # the one being consumed (the new issue targets the slot
+            # consumed `num_bufs - 1` blocks ago, which is complete and idle)
+            issue_one()
+            block_dma(b, i, cur, wait=True)
+            ptr_ref[0] = cnt + 1
             k, v = _kv_block(kbuf, vbuf, cur, tokens_per_block, n_kv, d,
                              lane_width, quantized, shared)
             s = jax.lax.dot_general(
-                q_bd.astype(k.dtype), k, (((1,), (1,)), ((), ())),
+                qbd_ref[...], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # [H, T] — block-diagonal q => per-head scores, no cross-talk
             tok = i * tokens_per_block + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1
             )
+            # every block of the loop holds token i * T < ctx, so no row is
+            # all -inf
             s = jnp.where(tok < ctx, s, NEG_INF)
             _flash_update(m_ref, l_ref, acc_ref, s, v)
+            return carry
 
-        @pl.when(i == nb_b - 1)
-        def _finalize():
-            out = _flash_normalize(l_ref, acc_ref)  # [H, KVD]
-            # keep each row's own KV-head lane span (off-head lanes carry
-            # accumulated garbage), then fold the KV spans down to [H, D]
-            # with static lane slices — again avoiding lane-split reshapes.
-            out = jnp.where(bd_mask, out, 0.0)
-            folded = out[:, 0:d]
-            for kv in range(1, n_kv):
-                folded = folded + out[:, kv * d:(kv + 1) * d]
-            o_ref[0] = folded.astype(o_ref.dtype)
+        jax.lax.fori_loop(0, n_blocks(b), block, 0)
+
+        out = _flash_normalize(l_ref, acc_ref)  # [H, KVD]
+        # keep each row's own KV-head lane span (off-head lanes carry
+        # accumulated garbage), then fold the KV spans down to [H, D]
+        # with static lane slices — again avoiding lane-split reshapes.
+        out = jnp.where(bd_mask(), out, 0.0)
+        folded = out[:, 0:d]
+        for kv in range(1, n_kv):
+            folded = folded + out[:, kv * d:(kv + 1) * d]
+        o_ref[0] = folded.astype(o_ref.dtype)
 
 
 def paged_attention_decode(
@@ -386,25 +418,27 @@ def paged_attention_decode(
     pmax = block_table.shape[1]
     block_pages = max(1, min(block_pages, pmax))
     num_bufs = max(2, num_bufs)
-    nb_max = -(-pmax // block_pages)
     scale = 1.0 / (head_dim**0.5)
+    # the queries meet K in the dtype _kv_block hands it over in
+    q_dtype = k_pages.dtype if shared and not quantized else jnp.float32
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(bsz, nb_max),
+        grid=(bsz,),
         in_specs=[
-            pl.BlockSpec((1, n_heads, head_dim), lambda b, i, bt, cl: (b, 0, 0)),
+            pl.BlockSpec((1, n_heads, head_dim), lambda b, bt, cl: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
-            (1, n_heads, head_dim), lambda b, i, bt, cl: (b, 0, 0)
+            (1, n_heads, head_dim), lambda b, bt, cl: (b, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((num_bufs, block_pages, page_size, lane_width),
                        k_pages.dtype),
             _v_ring(shared, (num_bufs, block_pages, page_size, lane_width),
                     v_pages.dtype),
+            pltpu.VMEM((n_heads, kvd), q_dtype),
             pltpu.VMEM((n_heads, 128), jnp.float32),
             pltpu.VMEM((n_heads, 128), jnp.float32),
             pltpu.VMEM((n_heads, kvd), jnp.float32),
@@ -431,7 +465,7 @@ def paged_attention_decode(
         compiler_params=pltpu.CompilerParams(
             # sequential on purpose: the DMA pipeline carries state across
             # grid steps (see module docstring)
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
     )(block_table.astype(jnp.int32), context_lens.astype(jnp.int32),

@@ -87,9 +87,15 @@ QUARANTINE_WINDOW_ENV = "DYNAMO_TPU_QUARANTINE_WINDOW_S"
 INTEGRITY_ENV = "DYNAMO_TPU_INTEGRITY"
 
 DEFAULT_QUARANTINE_WINDOW_S = 300.0
-# without an EWMA yet (pre-warmup) or an env override, never trip a seam
-# faster than this — cold dispatches legitimately include compilation
-DEFAULT_DEADLINE_FLOOR_S = 2.0
+# never trip a seam faster than this. The EWMA is one mean over all seams
+# and millisecond dispatch seams hold it near 10 ms, so on a warmed engine
+# the floor IS the deadline — and the longest seam of a healthy engine is
+# the wait for a drained 16-step decode window, 0.2-0.4 s on a v5e. At 2 s
+# one slow episode of the device (a 0.2 s window that took 2.0-2.5 s and
+# came back; ROADMAP S1) read as a hang, aborted every stream and ended in
+# quarantine. 8 s is that seam times the 20x margin the EWMA was meant to
+# give it.
+DEFAULT_DEADLINE_FLOOR_S = 8.0
 # EWMA multiplier: decode seams are milliseconds, so even 20x stays far
 # below human-visible; a genuine hang overshoots by orders of magnitude
 DEFAULT_DEADLINE_MARGIN = 20.0

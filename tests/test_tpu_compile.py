@@ -1,10 +1,11 @@
-"""The ragged kernel at the benchmark cells' mixed-step shapes, compiled by
-the TPU's own compiler for a v5e that is described, not attached.
+"""The ragged kernel at the benchmark cells' mixed-step shapes, and the
+decode kernel at their decode batch, compiled by the TPU's own compiler for
+a v5e that is described, not attached.
 
 Interpret mode proves a kernel's arithmetic on the CPU; Mosaic refuses what
 it cannot tile or fit in VMEM only when it compiles. The compiler is
 installed in the sandbox, so this guards the default mixed step of both
-cells (PR 26) at no chip time. Nothing runs: no result, no timing.
+chat cells (PR 26) and every cell's decode window (PR 28) at no chip time. Nothing runs: no result, no timing.
 
 The topology is described inside a fixture and in this file only (one
 process may load libtpu; see the on-chip-measurement guide, section 2)."""
@@ -61,6 +62,44 @@ def test_ragged_kernel_compiles_for_v5e_at_cell_shapes(one_chip, heads,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize(
+    "slots,heads,kv_heads,head_dim,width,pool_dtype,v_lanes",
+    [(32, 28, 4, 128, 128, "bfloat16", None),
+     (32, 32, 8, 128, 128, "bfloat16", None),
+     (64, 64, 1, 640, 384, "bfloat16", 0),
+     (32, 28, 4, 128, 128, "int8", None)],
+    ids=["qwen_28q4kv", "mixtral_32q8kv", "kimi_64q_one_640_lane_row",
+         "qwen_int8kv"])
+def test_decode_kernel_compiles_for_v5e_at_cell_shapes(
+        one_chip, slots, heads, kv_heads, head_dim, width, pool_dtype,
+        v_lanes):
+    """The decode window's kernel (one grid step a slot, a loop with a
+    dynamic trip count over the slot's own superblocks, PR 28) at the three
+    cells' batch and table, and over an int8-KV pool."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops import attention as att
+    from dynamo_tpu.ops import pallas_attention as pa
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    dtype = jnp.dtype(pool_dtype)
+    lanes = att.kv_lane_width(kv_heads, head_dim, dtype == jnp.int8)
+    fn = jax.jit(functools.partial(
+        pa.paged_attention_decode, page_size=PAGE, num_kv_heads=kv_heads))
+    compiled = fn.lower(
+        arg((slots, heads, head_dim), jnp.bfloat16),
+        arg((POOL_PAGES, PAGE, lanes), dtype),
+        arg((POOL_PAGES, PAGE, lanes if v_lanes is None else v_lanes), dtype),
+        arg((slots, width), jnp.int32), arg((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    # the benchmark's trace readers match the kernel by this output
+    assert f"bf16[{slots},{heads},{head_dim}]" in text
+
+
 def test_ragged_dispatch_compiles_head_parallel_on_four_chips(topo):
     """`chip_smoke.py --chips 4`'s mixed step: the dispatcher's shard_map
     over a (data=1, model=4) mesh hands each chip 7 query / 1 KV head."""
@@ -91,6 +130,41 @@ def test_ragged_dispatch_compiles_head_parallel_on_four_chips(topo):
             P(None, "model", None)), pool, pool,
         arg((SLOTS, 128), jnp.int32, P()), arg((SLOTS,), jnp.int32, P()),
         arg((143,), jnp.int32, P()), arg((), jnp.int32, P())).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" not in text and "all-reduce" not in text
+
+
+def test_decode_dispatch_compiles_head_parallel_on_four_chips(topo):
+    """`chip_smoke.py --chips 4`'s decode window: the dispatcher's shard_map
+    hands each chip 7 query / 1 KV head and the kernel's lens (context 0
+    for an empty slot), with no collective."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from dynamo_tpu.ops import attention as att
+
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+
+    def arg(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    pool = arg((POOL_PAGES, PAGE, 4 * HEAD_DIM), jnp.bfloat16,
+               P(None, None, "model"))
+
+    def step(q, kp, vp, tables, ctx):
+        with att.attention_context("pallas", mesh):
+            return att.paged_attention_decode(
+                q, kp, vp, tables, ctx, page_size=PAGE, num_kv_heads=4,
+                kernel_lens=jnp.where(tables[:, 0] > 0, ctx, 0))
+
+    compiled = jax.jit(step).lower(
+        arg((SLOTS, 28, HEAD_DIM), jnp.bfloat16, P(None, "model", None)),
+        pool, pool, arg((SLOTS, 128), jnp.int32, P()),
+        arg((SLOTS,), jnp.int32, P())).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" not in text and "all-reduce" not in text

@@ -100,6 +100,25 @@ def test_deadline_floor_then_ewma_then_override():
     wd2.stop()
 
 
+def test_slow_window_that_comes_back_is_not_a_hang():
+    """ROADMAP S1: decode seams of milliseconds hold the EWMA down, so the
+    floor is the deadline; a drained 16-step window of 0.2 s that took 2.5 s
+    once (a slow episode of the device, seen on the chip) must stay under
+    it, with the 20x margin to spare over a healthy one of 0.4 s."""
+    clk = FakeClock()
+    wd = EngineWatchdog(clock=clk)
+    try:
+        for _ in range(50):  # the EWMA a serving engine has: ~10 ms seams
+            wd.device_enter("dispatch")
+            clk.t += 0.010
+            wd.device_exit("dispatch")
+        assert wd.deadline_s() == wd.floor_s
+        assert wd.floor_s >= wd.margin * 0.4
+        assert wd.floor_s > 2.5
+    finally:
+        wd.stop()
+
+
 def test_env_knobs_configure_deadline_and_window(monkeypatch):
     monkeypatch.setenv(DEADLINE_ENV, "3.5")
     monkeypatch.setenv(QUARANTINE_WINDOW_ENV, "42")

@@ -20,8 +20,9 @@ This is the TPU-native replacement for the reference's consumed engine workers
 
 from __future__ import annotations
 
+import bisect
 import collections
-import functools
+import dataclasses
 import inspect
 import logging
 import threading
@@ -121,15 +122,9 @@ class PhaseTimer:
         self.sum_s += seconds * weight
         if seconds > self.max_s:
             self.max_s = seconds
-        ms = seconds * 1e3
-        lo, hi = 0, len(self._EDGES_MS)
-        while lo < hi:  # first edge >= ms (binary search; 61 edges)
-            mid = (lo + hi) // 2
-            if ms <= self._EDGES_MS[mid]:
-                hi = mid
-            else:
-                lo = mid + 1
-        self.buckets[lo] += weight
+        # first edge >= ms (the last bucket takes what passes every edge)
+        self.buckets[bisect.bisect_left(self._EDGES_MS,
+                                        seconds * 1e3)] += weight
 
     def quantile_ms(self, q: float) -> float:
         """Geometric-midpoint estimate of the q-quantile from the buckets."""
@@ -306,12 +301,7 @@ class EngineMetrics:
     def observe_occupancy(self, active: int, capacity: int) -> None:
         """One decode window's batch occupancy fraction."""
         frac = active / max(capacity, 1)
-        for i, edge in enumerate(self._OCC_EDGES):
-            if frac <= edge:
-                self.occupancy_buckets[i] += 1
-                break
-        else:
-            self.occupancy_buckets[-1] += 1
+        self._bucketize(self._OCC_EDGES, self.occupancy_buckets, frac)
         self.occupancy_sum += frac
         self.occupancy_count += 1
 
@@ -320,25 +310,23 @@ class EngineMetrics:
         """One speculating slot's accepted-draft count for one verify step
         (same cumulative-bucket scheme as occupancy). `drafter` also files
         the observation under that proposer's labeled series."""
-        self._bucketize(self.spec_accept_buckets, n_acc)
+        self._bucketize(self._SPEC_EDGES, self.spec_accept_buckets, n_acc)
         self.spec_accept_sum += n_acc
         self.spec_accept_count += 1
         if drafter is not None:
             hist = self.spec_hist_by.setdefault(
                 drafter, [0] * (len(self._SPEC_EDGES) + 1))
-            self._bucketize(hist, n_acc)
+            self._bucketize(self._SPEC_EDGES, hist, n_acc)
             self.spec_sum_by[drafter] = (
                 self.spec_sum_by.get(drafter, 0) + n_acc)
             self.spec_count_by[drafter] = (
                 self.spec_count_by.get(drafter, 0) + 1)
 
-    def _bucketize(self, buckets: List[int], n: int) -> None:
-        for i, edge in enumerate(self._SPEC_EDGES):
-            if n <= edge:
-                buckets[i] += 1
-                break
-        else:
-            buckets[-1] += 1
+    @staticmethod
+    def _bucketize(edges, buckets: List[int], x) -> None:
+        """Count `x` in the first bucket whose edge holds it (the last
+        bucket takes what passes every edge)."""
+        buckets[bisect.bisect_left(edges, x)] += 1
 
     def add_spec_tokens(self, drafted: int, accepted: int,
                         drafter: Optional[str] = None) -> None:
@@ -356,12 +344,7 @@ class EngineMetrics:
         of the window's total rows (same cumulative-bucket scheme as
         occupancy; the exposition bridge serves both as histograms)."""
         frac = prefill_tokens / max(prefill_tokens + decode_rows, 1)
-        for i, edge in enumerate(self._OCC_EDGES):
-            if frac <= edge:
-                self.mixed_buckets[i] += 1
-                break
-        else:
-            self.mixed_buckets[-1] += 1
+        self._bucketize(self._OCC_EDGES, self.mixed_buckets, frac)
         self.mixed_sum += frac
         self.mixed_count += 1
         self.mixed_prefill_tokens += prefill_tokens
@@ -486,9 +469,7 @@ class Engine:
                 cfg.model_path or cfg.model, dtype=cfg.dtype or default_dtype
             )
         if cfg.moe_capacity_factor > 0:
-            import dataclasses as _dc
-
-            model_cfg = _dc.replace(
+            model_cfg = dataclasses.replace(
                 model_cfg, moe_capacity_factor=cfg.moe_capacity_factor
             )
         self.model_cfg = model_cfg
@@ -585,27 +566,23 @@ class Engine:
             # prefill (the packed tokens ARE chunks): an unset chunk size
             # inherits the mixed budget so both paths agree on chunk
             # geometry and the A/B bench compares scheduling, not shapes.
-            import dataclasses as _dc
-
             mixed = -(-cfg.mixed_batch_tokens
                       // cfg.page_size) * cfg.page_size
             chunk = cfg.prefill_chunk_tokens or mixed
             if (mixed != cfg.mixed_batch_tokens
                     or chunk != cfg.prefill_chunk_tokens):
-                cfg = _dc.replace(cfg, mixed_batch_tokens=mixed,
+                cfg = dataclasses.replace(cfg, mixed_batch_tokens=mixed,
                                   prefill_chunk_tokens=chunk)
                 self.cfg = cfg
         if cfg.sequence_parallel > 1 and cfg.prefill_chunk_tokens > 0:
             # chunked prefill routes through the paged chunk op, which the
             # ring/Ulysses path does not serve — a long-context sp worker
             # exists precisely for whole-prompt ring prefills
-            import dataclasses as _dc
-
             log.warning(
                 "sequence_parallel=%d disables chunked prefill (ring "
                 "attention serves whole-prompt prefills)",
                 cfg.sequence_parallel)
-            cfg = _dc.replace(cfg, prefill_chunk_tokens=0,
+            cfg = dataclasses.replace(cfg, prefill_chunk_tokens=0,
                               mixed_batch_tokens=0)
             self.cfg = cfg
         # prefix caching historically required chunked prefill (cache hits
@@ -757,12 +734,10 @@ class Engine:
         if cfg.prefill_chunk_tokens > 0:
             # chunks must be page-aligned (chunk KV scatters whole pages);
             # replace rather than mutate the caller's config object
-            import dataclasses as _dc
-
             rounded = -(-cfg.prefill_chunk_tokens
                         // cfg.page_size) * cfg.page_size
             if rounded != cfg.prefill_chunk_tokens:
-                cfg = _dc.replace(cfg, prefill_chunk_tokens=rounded)
+                cfg = dataclasses.replace(cfg, prefill_chunk_tokens=rounded)
                 self.cfg = cfg
         self._aborted: set = set()  # guarded_by: _lock
         # abort_all teardown hook: the serving layer flushes its stream
@@ -844,8 +819,9 @@ class Engine:
             )
 
         # a model whose expert layers count (llama MixedOut.moe_stats etc.)
-        # has every step program return the counts last; `ctx` takes them
-        # off again, so call sites see the same tuples for every model
+        # has every step program return the counts last; `program` takes
+        # them off again at each call, so call sites see the same tuples
+        # for every model
         counts_moe = mcfg.moe_grouped
 
         def moe_tail(stats):
@@ -1002,12 +978,9 @@ class Engine:
             return window_fn
 
         n_multi = max(1, cfg.num_scheduler_steps)
-        window_fns = {
-            (False, False): make_decode_window(1, False),
-            (True, False): make_decode_window(n_multi, False),
-            (False, True): make_decode_window(1, True),
-            (True, True): make_decode_window(n_multi, True),
-        }
+        window_fns = {(multi, lp): make_decode_window(
+            n_multi if multi else 1, lp)
+            for multi in (False, True) for lp in (False, True)}
 
         def make_mixed_step(with_logprobs: bool):
             """One unified ragged step (RPA, PAPERS.md arxiv 2604.15464):
@@ -1015,9 +988,8 @@ class Engine:
             mixed_batch_tokens of the inflight prefill chunk ride the SAME
             program — llama.mixed_step routes both row kinds through
             ragged_mixed_attention, so a long admission stops preempting
-            decode ITL. The leading 18 operands match window_fn exactly
-            (the donation tuple carries over unchanged); the chunk
-            operands trail and are fresh uploads each call."""
+            decode ITL. The leading 18 operands match window_fn exactly;
+            the chunk operands trail and are fresh uploads each call."""
 
             def mixed_fn(
                 params, tokens, positions, context_lens, active, block_tables,
@@ -1136,8 +1108,8 @@ class Engine:
             verify window AND the inflight prefill chunk rides the same
             program — spec_fn x mixed_fn (llama.mixed_verify_step routes
             both row kinds through ragged_verify_attention). The leading
-            operands match spec_fn exactly so its donation tuple carries
-            over; the chunk operands trail and are fresh uploads each
+            operands match spec_fn exactly (_ragged_step builds one list
+            for both); the chunk operands trail and are fresh uploads each
             call, like mixed_fn's."""
             # extra layout: [adapter_slots]? + (p_tokens, p_start, p_len,
             # p_pages) + [p_adapter_slot]? — like mixed_fn
@@ -1197,106 +1169,87 @@ class Engine:
         # Bind this engine's attention backend + mesh around every call
         # (traces happen inside the first call, so the kernel selection and
         # shard_map mesh are baked per-engine — not via process globals).
-        from dynamo_tpu.ops import attention as _att
-
         backend = None if cfg.attention_backend == "auto" else cfg.attention_backend
         mesh = self.mesh
         lane_blocks = self.kv_spec.lane_blocks
 
-        def ctx(fn, steps=False):
-            """Bind the attention scope around `fn`. steps=True marks a
-            program that runs the model's layers: where the expert layers
-            count, its last output is their counts, banked here."""
-            def wrapped(*args):
-                with _att.attention_context(backend, mesh, lane_blocks):
+        # raw jitted fns, for warmup verification (compile-cache sizes)
+        self._jit_handles = {}
+
+        def program(name, fn, donated=(), steps=False, donated_at=()):
+            """One row of the table below -> what the engine calls: `fn`
+            with the parameters NAMED in `donated` (and the positions
+            `donated_at`) donated, jitted unless enforce_eager, inside this
+            engine's attention scope. steps=True marks a program that runs
+            the model's layers: where the expert layers count, its last
+            output is their counts, banked at each call."""
+            if not cfg.enforce_eager:
+                fn = jax.jit(fn, donate_argnums=_argnums(fn, *donated)
+                             + tuple(donated_at))
+                if name:
+                    self._jit_handles[name] = fn
+
+            def call(*args):
+                with att_ops.attention_context(backend, mesh, lane_blocks):
                     out = fn(*args)
                 if steps and counts_moe:
                     self.metrics.observe_moe(out[-1])
                     out = out[:-1]
                 return out
 
-            return wrapped
+            return call
 
-        if cfg.enforce_eager:
-            self._prefill = ctx(prefill_fn, True)
-            self._prefill_batch = ctx(prefill_batch_fn, True)
-            self._prefill_chunk = ctx(chunk_fn, True)
-            self._windows = {k: ctx(f, True) for k, f in window_fns.items()}
-            self._mixed = {k: ctx(f, True) for k, f in mixed_fns.items()}
-            self._spec = ctx(spec_fn, True)
-            self._mixed_spec = ctx(mixed_spec_fn, True)
-            self._sample_first = ctx(sample_first)
-            self._sample_first_batch = ctx(sample_first_batch)
-            self._reset_count = ctx(reset_count_fn)
-            self._import = ctx(import_fn)
-            self._upload = lambda *xs: tuple(jnp.asarray(x) for x in xs)
-            self._jit_handles = {}
+        # Donated: the KV pools + the carried decode state, which XLA then
+        # updates in place. Everything else (active mask, block tables,
+        # sampling params, bias arrays, slot keys, the per-call chunk
+        # operands) is REUSED by the next dispatch and must never be
+        # donated: a TPU deletes a donated buffer ('Array has been deleted'
+        # on the next use) where the CPU only warns, so no CPU test can see
+        # a wrong tuple. Hence donation is declared by parameter NAME and
+        # resolved against each function's own signature (_argnums).
+        kv = ("k_pages", "v_pages")
+        carry = ("tokens", "positions", "context_lens", "counts") + kv
+        self._prefill = program("prefill", prefill_fn, kv, True)
+        self._prefill_batch = program("prefill_batch", prefill_batch_fn, kv,
+                                      True)
+        self._prefill_chunk = program("prefill_chunk", chunk_fn, kv, True)
+        self._windows = {
+            (m, lp): program(f"window_{m}_{lp}", f, carry, True)
+            for (m, lp), f in window_fns.items()}
+        self._mixed = {lp: program(f"mixed_{lp}", f, carry, True)
+                       for lp, f in mixed_fns.items()}
+        self._spec = program("spec", spec_fn, carry, True)
+        self._mixed_spec = program("mixed_spec", mixed_spec_fn, carry, True)
+        self._sample_first = program("sample_first", sample_first)
+        # no handle: the warm-up's count of programs (`compiled_programs`,
+        # which the benchmark holds constant) never had this one
+        self._sample_first_batch = program(None, sample_first_batch)
+        self._reset_count = program("reset_count", reset_count_fn,
+                                    ("counts",))
+        self._import = program("import", import_fn, kv)
 
-            def _build_guided_window_eager(multi: bool, lp: bool):
-                return ctx(make_decode_window(
-                    n_multi if multi else 1, lp,
-                    guide_tables=self._guide_dev), True)
-
-            self._build_guided_window = _build_guided_window_eager
-        else:
-            # donate the KV pools + the carried decode state, which XLA
-            # then updates in place. Everything else (active mask, block
-            # tables, sampling params, bias arrays, slot keys, the
-            # per-call chunk operands) is REUSED by the next dispatch and
-            # must never be donated: a TPU deletes a donated buffer
-            # ('Array has been deleted' on the next use) where the CPU
-            # only warns, so no CPU test can see a wrong tuple. Hence
-            # donation is declared by parameter NAME and resolved against
-            # each function's own signature (_argnums).
-            kv = ("k_pages", "v_pages")
-            carry = ("tokens", "positions", "context_lens", "counts") + kv
-            jp = jax.jit(prefill_fn, donate_argnums=_argnums(prefill_fn, *kv))
-            jpb = jax.jit(prefill_batch_fn,
-                          donate_argnums=_argnums(prefill_batch_fn, *kv))
-            jsb = jax.jit(sample_first_batch)
-            jc = jax.jit(chunk_fn, donate_argnums=_argnums(chunk_fn, *kv))
-            jw = {k: jax.jit(f, donate_argnums=_argnums(f, *carry))
-                  for k, f in window_fns.items()}
-            jm = {k: jax.jit(f, donate_argnums=_argnums(f, *carry))
-                  for k, f in mixed_fns.items()}
-            jspec = jax.jit(spec_fn,
-                            donate_argnums=_argnums(spec_fn, *carry))
-            jms = jax.jit(mixed_spec_fn,
-                          donate_argnums=_argnums(mixed_spec_fn, *carry))
-            js = jax.jit(sample_first)
-            jr = jax.jit(reset_count_fn,
-                         donate_argnums=_argnums(reset_count_fn, "counts"))
-            ji = jax.jit(import_fn, donate_argnums=_argnums(import_fn, *kv))
-            self._prefill = ctx(jp, True)
-            self._prefill_batch = ctx(jpb, True)
-            self._prefill_chunk = ctx(jc, True)
-            self._windows = {k: ctx(f, True) for k, f in jw.items()}
-            self._mixed = {k: ctx(f, True) for k, f in jm.items()}
-            self._spec = ctx(jspec, True)
-            self._mixed_spec = ctx(jms, True)
-            self._sample_first = ctx(js)
-            self._sample_first_batch = ctx(jsb)
-            self._reset_count = ctx(jr)
-            self._import = ctx(ji)
-
-            def _build_guided_window(multi: bool, lp: bool):
-                """Guided decode-window variant, built lazily on first use
-                (warmup()'s __warm_guided/__warm_guided_lp requests trigger
-                all four variants before /ready). The carried grammar state
-                (gmode/gdepth/gbits: the first three *extra operands, after
-                the lora adapter-slot operand when there is one) is donated
-                like the other carry; gactive (the next position) is
-                reused."""
+        def guided_window(multi: bool, lp: bool):
+            """Guided decode-window variant, built on first use
+            (warmup()'s __warm_guided/__warm_guided_lp requests trigger
+            all four variants before /ready). The carried grammar state
+            (gmode/gdepth/gbits: the first three *extra operands, after
+            the lora adapter-slot operand when there is one) is donated
+            like the other carry; gactive (the next position) is
+            reused."""
+            if (multi, lp) not in self._guided_windows:
+                self._ensure_guide_table()
                 fn = make_decode_window(n_multi if multi else 1, lp,
                                         guide_tables=self._guide_dev)
                 g0 = _argnums(fn, "extra")[0] + (1 if lora_on else 0)
-                j = jax.jit(fn,
-                            donate_argnums=_argnums(fn, *carry)
-                            + (g0, g0 + 1, g0 + 2))
-                self._jit_handles[f"window_guided_{multi}_{lp}"] = j
-                return ctx(j, True)
+                self._guided_windows[multi, lp] = program(
+                    f"window_guided_{multi}_{lp}", fn, carry, True,
+                    donated_at=(g0, g0 + 1, g0 + 2))
+            return self._guided_windows[multi, lp]
 
-            self._build_guided_window = _build_guided_window
+        self._get_guided_window = guided_window
+        if cfg.enforce_eager:
+            self._upload = lambda *xs: tuple(jnp.asarray(x) for x in xs)
+        else:
             # jitted upload whose outputs share the sharding provenance of
             # other jit outputs over the engine mesh (see _decode_once).
             # optimization_barrier defeats jit's pass-through fast path for
@@ -1305,20 +1258,6 @@ class Engine:
             self._upload = jax.jit(
                 lambda *xs: jax.lax.optimization_barrier(xs),
                 out_shardings=rep_sharding)
-            # raw jitted fns, for warmup verification (compile-cache sizes)
-            self._jit_handles = {"prefill": jp, "prefill_chunk": jc,
-                                 "prefill_batch": jpb,
-                                 "sample_first": js,
-                                 "reset_count": jr, "import": ji,
-                                 **{f"window_{m}_{l}": f
-                                    for (m, l), f in jw.items()}}
-            if cfg.mixed_batch_tokens > 0:
-                for l, f in jm.items():
-                    self._jit_handles[f"mixed_{l}"] = f
-            if cfg.speculative_mode != "off":
-                self._jit_handles["spec"] = jspec
-                if cfg.mixed_batch_tokens > 0:
-                    self._jit_handles["mixed_spec"] = jms
 
     def set_kv_event_sink(self, sink) -> None:
         """Attach the cluster KV event plane: `sink(kind, [hash bytes],
@@ -1373,25 +1312,21 @@ class Engine:
             buckets.add(b)
             b *= 2
         buckets.add(cap)
-        for bucket in sorted(buckets):
-            p = min(bucket, cfg.max_seq_len - 1)
-            # distinct tokens per bucket: identical prompts would hit the
-            # prefix cache and skip the full-prefill compilation this
-            # request exists to trigger
-            toks = [(bucket * 7 + j) % 97 + 1 for j in range(p)]
-            reqs.append(GenRequest(f"__warm_b{bucket}", toks, max_tokens=1,
-                                   temperature=0.0, ignore_eos=True))
-        if (self.prefix_cache is not None
-                and cfg.disaggregation_mode != "prefill"):
-            # second pass: now-cached prefixes route through the
-            # chunked-suffix path, compiling its per-bucket page-table
-            # widths too (the prefill role serves via prefill_only, which
-            # never consults the cache — a second pass there would just
-            # re-run every bucket and delay /ready)
+        # second pass ("c"): now-cached prefixes route through the
+        # chunked-suffix path, compiling its per-bucket page-table widths
+        # too (the prefill role serves via prefill_only, which never
+        # consults the cache — a second pass there would just re-run every
+        # bucket and delay /ready)
+        passes = "bc" if (self.prefix_cache is not None
+                          and cfg.disaggregation_mode != "prefill") else "b"
+        for tag in passes:
             for bucket in sorted(buckets):
                 p = min(bucket, cfg.max_seq_len - 1)
+                # distinct tokens per bucket: identical prompts would hit
+                # the prefix cache and skip the full-prefill compilation
+                # the first pass exists to trigger
                 toks = [(bucket * 7 + j) % 97 + 1 for j in range(p)]
-                reqs.append(GenRequest(f"__warm_c{bucket}", toks,
+                reqs.append(GenRequest(f"__warm_{tag}{bucket}", toks,
                                        max_tokens=1, temperature=0.0,
                                        ignore_eos=True))
         # decode windows: max_tokens = 2k+2 runs two consecutive fused-k
@@ -1456,10 +1391,9 @@ class Engine:
                 # slots live while one prompt per bucket streams in, so
                 # the mixed program compiles at every page-table width
                 # (plus the logprobs twin) before /ready flips. With
-                # speculation on, the lp=None pass routes through
-                # _mixed_spec_step and compiles the mixed-verify program
-                # instead; the lp pass still compiles mixed[True] (the
-                # logprobs demotion path)
+                # speculation on, the lp=None pass compiles the
+                # mixed-verify program instead; the lp pass still compiles
+                # mixed[True] (the logprobs demotion path)
                 for lp in (None, 1):
                     tag = "lp" if lp else "t"
                     self.add_request(GenRequest(
@@ -1923,36 +1857,31 @@ class Engine:
             # a logprobs request demotes the step to plain mixed
             # (per-position logprob extraction isn't wired through
             # verify — counted like the other spec demotions).
-            if self.cfg.speculative_mode != "off":
-                if any(s.logprobs is not None
-                       for s in self.seqs.values()):
-                    att_ops._note_fallback(
-                        "spec", "logprobs",
-                        "logprobs request in the batch: mixed step "
-                        "runs without verify windows")
-                    self.flight.note("spec_demote", reason="logprobs")
-                    events.extend(self._mixed_step())
-                else:
-                    events.extend(self._mixed_spec_step())
-            else:
-                events.extend(self._mixed_step())
-            with self.timeline.phase("bank"):
-                self._qos_account(events)
-            return events
-        if self._inflight is not None:
-            # one chunk per step: decode windows run between chunks, so
-            # a long admission never monopolizes the chip
-            events.extend(self._advance_chunk())
+            spec = self.cfg.speculative_mode != "off"
+            if spec and any(s.logprobs is not None
+                            for s in self.seqs.values()):
+                att_ops._note_fallback(
+                    "spec", "logprobs",
+                    "logprobs request in the batch: mixed step "
+                    "runs without verify windows")
+                self.flight.note("spec_demote", reason="logprobs")
+                spec = False
+            events.extend(self._mixed_step(spec))
         else:
-            with self.timeline.phase("admit"):
-                events.extend(self._admit())
-        if self.seqs:
-            if self.cfg.speculative_mode != "off":
-                events.extend(self._decode_spec())
-            elif self.cfg.async_scheduling:
-                events.extend(self._decode_async())
+            if self._inflight is not None:
+                # one chunk per step: decode windows run between chunks,
+                # so a long admission never monopolizes the chip
+                events.extend(self._advance_chunk())
             else:
-                events.extend(self._decode_once())
+                with self.timeline.phase("admit"):
+                    events.extend(self._admit())
+            if self.seqs:
+                if self.cfg.speculative_mode != "off":
+                    events.extend(self._decode_spec())
+                elif self.cfg.async_scheduling:
+                    events.extend(self._decode_async())
+                else:
+                    events.extend(self._decode_once())
         # per-tenant QoS: bank this step's decoded tokens into the
         # weighted-fair budgets (no-op without configured tenants)
         with self.timeline.phase("bank"):
@@ -2186,8 +2115,9 @@ class Engine:
                     break
                 events.extend(got)
                 continue
+            t0 = time.monotonic()
             try:
-                ev = self._prefill_request(req)
+                got = self._run_prefill(req, events)
             except OutOfPages:
                 self.metrics.kv_oom += 1
                 events.append(
@@ -2196,13 +2126,8 @@ class Engine:
                 self.flight.note("kv_oom", rid=req.request_id,
                                  tenant=self._tenant_of(req), where="prefill")
                 continue
-            except IntegrityFault:
-                # sentinel tripped on this request's logits: abort ONLY
-                # this stream (pages already freed by _run_prefill)
-                events.append(TokenEvent(req.request_id, -1, 0, True,
-                                         "integrity_fault"))
-                continue
-            events.append(ev)
+            if got is not None:
+                events.append(self._finalize_admission(req, *got, t0))
         return events
 
     def _widen_group(self, req: GenRequest, chunk: int) -> List[GenRequest]:
@@ -2381,57 +2306,96 @@ class Engine:
             if not finite_np[i]:
                 # poisoned lane: this stream aborts, its pages go back,
                 # the co-batched lanes below admit untouched
-                self.allocator.free(page_lists[i])
-                self.watchdog.record_integrity_fault(
-                    "logits", [r.request_id], where="prefill_group")
-                events.append(TokenEvent(r.request_id, -1, 0, True,
-                                         "integrity_fault"))
+                self._abort_poisoned(events, r, page_lists[i],
+                                     "prefill_group")
                 continue
             self.metrics.prompt_tokens += int(seq_lens[i])
             events.append(self._finalize_admission(
                 r, page_lists[i], int(seq_lens[i]), int(toks_np[i]), keys[i],
-                (float(chosen_np[i]), tids_np[i], tvals_np[i]),
-                t_prefill_start=t0,
-            ))
+                (float(chosen_np[i]), tids_np[i], tvals_np[i]), t0))
         return events
 
+    def _first_token_or_abort(self, events: List[TokenEvent],
+                              req: GenRequest, pages, prompt_len: int,
+                              last_logits, where: str,
+                              slot: Optional[int] = None):
+        """One prompt's first token from its last logits, for every path
+        that samples a prompt on its own (full prefill, last chunk, a mixed
+        step's ragged tail): (first, req_key, lp). Logits that are not
+        finite (the integrity sentinel) end THIS stream and nothing else:
+        its pages go back, so does a slot reserved for it, the fault is
+        counted under `where`, the event that ends the stream joins
+        `events`, and None is returned — the engine keeps serving."""
+        try:
+            with self.timeline.phase("device_wait"):
+                return self._first_token(req, last_logits, prompt_len)
+        except IntegrityFault:
+            self._abort_poisoned(events, req, pages, where, slot)
+            return None
+
+    def _abort_poisoned(self, events: List[TokenEvent], req: GenRequest,
+                        pages, where: str, slot: Optional[int] = None):
+        """End the one stream whose prompt gave logits that are not finite
+        (see _first_token_or_abort; the grouped prefill checks each of its
+        lanes and calls this for a poisoned one)."""
+        self.allocator.free(pages)
+        if slot is not None:
+            self._free_slots.append(slot)
+        self.watchdog.record_integrity_fault(
+            "logits", [req.request_id], where=where)
+        events.append(TokenEvent(req.request_id, -1, 0, True,
+                                 "integrity_fault"))
+
     def _finalize_admission(self, req: GenRequest, pages, prompt_len: int,
-                            first: int, req_key, lp,
-                            t_prefill_start: Optional[float] = None
-                            ) -> TokenEvent:
-        """Shared post-prefill bookkeeping for the single and grouped
-        admission paths: publish the prefix, install the slot, stop-check
-        the first token, decorate logprobs. `t_prefill_start` (monotonic)
-        splits admission-to-first-token into queue vs prefill on the event's
-        `phase` dict — the per-request bridge the serving layer turns into
-        trace spans."""
+                            first: int, req_key, lp, t_prefill_start: float,
+                            slot: Optional[int] = None) -> TokenEvent:
+        """What turns a finished prompt into a sequence, on every path:
+        publish the prefix, install the slot (`slot` where _start_inflight
+        reserved one for a chunked prompt, a free one otherwise),
+        stop-check the first token, decorate logprobs. The event's `phase`
+        is the per-request bridge the serving layer turns into trace
+        spans: how long the request queued before `t_prefill_start`, how
+        long its prompt computed (all chunks and mixed steps), and the
+        stamp of this moment, from which serving/api.py times its emit."""
         if self.prefix_cache is not None:
             self.prefix_cache.insert(req.prompt_token_ids, pages,
                                      namespace=self._kv_namespace(req.adapter))
-        slot = self._free_slots.pop()
+        chunked = slot is not None
+        if not chunked:
+            slot = self._free_slots.pop()
         seq = self._install_slot(req, slot, pages, prompt_len, first, req_key)
         finished, reason = self._check_stop(seq, first)
         ev = TokenEvent(req.request_id, first, 0, finished, reason)
-        if t_prefill_start is not None:
-            ev.phase = self._first_token_phase(req, t_prefill_start)
+        now = time.monotonic()
+        ev.phase = {"queue_s": max(0.0, t_prefill_start - req.arrival_time),
+                    "prefill_s": max(0.0, now - t_prefill_start),
+                    "t_first": now}
+        if chunked:
+            # "prefill" records admission-to-first-token for BOTH paths
+            # (the TTFT phase): a full prefill observed it at its dispatch,
+            # per-chunk timings live in "prefill_chunk"
+            self.metrics.observe_phase("prefill", ev.phase["prefill_s"])
         if req.logprobs is not None:
             self._decorate_lp(ev, seq, lp[0], lp[1], lp[2])
         if finished:
             self._finish_slot(slot, reason)
         return ev
 
-    @staticmethod
-    def _first_token_phase(req: GenRequest, t_prefill_start: float) -> dict:
-        """The first TokenEvent's `phase`: how long the request queued and
-        how long its prompt computed (all chunks and mixed steps), plus
-        the monotonic stamp of this moment, `t_first`, from which the
-        serving layer times its own emit stage (serving/api.py)."""
-        now = time.monotonic()
-        return {
-            "queue_s": max(0.0, t_prefill_start - req.arrival_time),
-            "prefill_s": max(0.0, now - t_prefill_start),
-            "t_first": now,
-        }
+    def _finish_inflight(self, last_logits, where: str,
+                         events: List[TokenEvent]) -> None:
+        """The inflight prompt's last chunk has run (alone, or in a mixed
+        step's ragged tail): sample its first token from the chunk's last
+        logits and install it in the slot reserved at _start_inflight."""
+        inf = self._inflight
+        self._inflight = None
+        self.metrics.prompt_tokens += inf.prompt_len
+        got = self._first_token_or_abort(
+            events, inf.req, inf.pages, inf.prompt_len, last_logits, where,
+            slot=inf.slot)
+        if got is not None:
+            events.append(self._finalize_admission(
+                inf.req, inf.pages, inf.prompt_len, *got, inf.t_start,
+                slot=inf.slot))
 
     def _request_key(self, req: GenRequest):
         """Per-request PRNG chain root: deterministic when seeded; a
@@ -2459,13 +2423,15 @@ class Engine:
                 }
         return None
 
-    def _run_prefill(self, req: GenRequest):
+    def _run_prefill(self, req: GenRequest, events: List[TokenEvent]):
         """Shared prefill: bucket, allocate pages, run the jitted prefill, and
         sample the first token. Used by both the aggregated admission path and
         the disagg prefill role.
 
-        Returns (first_token, pages, prompt_len, req_key, lp) where lp =
-        (chosen_logprob, top_ids, top_logprobs) numpy for the first token."""
+        Returns (pages, prompt_len, first_token, req_key, lp) where lp =
+        (chosen_logprob, top_ids, top_logprobs) numpy for the first token,
+        or None where the integrity sentinel ended the stream (its last
+        event is in `events` then)."""
         cfg = self.cfg
         t0 = time.monotonic()
         prompt = req.prompt_token_ids
@@ -2492,24 +2458,18 @@ class Engine:
                 jnp.asarray(pages_arr),
                 *lx,
             )
-        try:
-            with self.timeline.phase("device_wait"):
-                first, req_key, lp = self._first_token(req, last_logits,
-                                                       prompt_len)
-        except IntegrityFault:
-            # poisoned stream: give its pages back and let the caller
-            # abort exactly this request — the engine keeps serving
-            self.allocator.free(pages)
-            self.watchdog.record_integrity_fault(
-                "logits", [req.request_id], where="prefill")
-            raise
+        got = self._first_token_or_abort(events, req, pages, prompt_len,
+                                         last_logits, "prefill")
+        if got is None:
+            return None
+        first, req_key, lp = got
         dt = time.monotonic() - t0
         self.metrics.prefill_time_s += dt
         self.metrics.observe_phase("prefill", dt)
         self.metrics.prompt_tokens += prompt_len
         self._step_obs("prefill", dt,
                        shares={self._tenant_of(req): float(prompt_len)})
-        return first, pages, prompt_len, req_key, lp
+        return pages, prompt_len, first, req_key, lp
 
     # ------------------------------------------------------- JSON guide --
 
@@ -2573,13 +2533,6 @@ class Engine:
             if len(self._guide_row_cache) < 64:
                 self._guide_row_cache[state] = row
         return row
-
-    def _get_guided_window(self, multi: bool, lp: bool):
-        key = (multi, lp)
-        if key not in self._guided_windows:
-            self._ensure_guide_table()
-            self._guided_windows[key] = self._build_guided_window(multi, lp)
-        return self._guided_windows[key]
 
     def _ensure_dev_guide(self) -> None:
         """(Re)build the device grammar-state arrays from the seq.guide
@@ -2729,12 +2682,6 @@ class Engine:
         n = min(int(seq.logprobs or 0), len(tids))
         ev.top_logprobs = [(int(tids[i]), float(tvals[i])) for i in range(n)]
 
-    def _prefill_request(self, req: GenRequest) -> TokenEvent:
-        t0 = time.monotonic()
-        first, pages, prompt_len, req_key, lp = self._run_prefill(req)
-        return self._finalize_admission(req, pages, prompt_len, first,
-                                        req_key, lp, t_prefill_start=t0)
-
     def _ensure_pages(self, n: int) -> bool:
         """can_alloc with prefix-cache eviction as the pressure valve."""
         if self.allocator.can_alloc(n):
@@ -2778,9 +2725,8 @@ class Engine:
         the first token and install the sequence into a decode slot."""
         inf = self._inflight
         assert inf is not None
-        cfg = self.cfg
         t0 = time.monotonic()
-        c = cfg.prefill_chunk_tokens
+        c = self.cfg.prefill_chunk_tokens
         start = inf.done
         take = min(c, inf.prompt_len - start)
         tokens = np.zeros((c,), dtype=np.int32)
@@ -2808,41 +2754,10 @@ class Engine:
         if inf.done < inf.prompt_len:
             return []
 
-        # final chunk: first token + slot installation (same tail as the
-        # full-prefill path); drain any in-flight async window first
+        # final chunk: drain any in-flight async window, then the same
+        # tail as every other path
         events = self._materialize_pending()
-        self._inflight = None
-        self.metrics.prompt_tokens += inf.prompt_len
-        req = inf.req
-        if self.prefix_cache is not None:
-            self.prefix_cache.insert(req.prompt_token_ids, inf.pages,
-                                     namespace=self._kv_namespace(req.adapter))
-        try:
-            with self.timeline.phase("device_wait"):
-                first, req_key, lp = self._first_token(req, last_logits,
-                                                       inf.prompt_len)
-        except IntegrityFault:
-            self.allocator.free(inf.pages)
-            self._free_slots.append(inf.slot)
-            self.watchdog.record_integrity_fault(
-                "logits", [req.request_id], where="prefill_chunk")
-            events.append(TokenEvent(req.request_id, -1, 0, True,
-                                     "integrity_fault"))
-            return events
-        slot = inf.slot  # reserved at _start_inflight
-        seq = self._install_slot(req, slot, inf.pages, inf.prompt_len, first,
-                                 req_key)
-        finished, reason = self._check_stop(seq, first)
-        # "prefill" records admission-to-first-token for BOTH paths (the
-        # TTFT phase); per-chunk timings live in "prefill_chunk"
-        ev = TokenEvent(req.request_id, first, 0, finished, reason)
-        ev.phase = self._first_token_phase(req, inf.t_start)
-        self.metrics.observe_phase("prefill", ev.phase["prefill_s"])
-        if req.logprobs is not None:
-            self._decorate_lp(ev, seq, lp[0], lp[1], lp[2])
-        if finished:
-            self._finish_slot(slot, reason)
-        events.append(ev)
+        self._finish_inflight(last_logits, "prefill_chunk", events)
         return events
 
     def _mixed_eligible(self) -> bool:
@@ -2850,7 +2765,7 @@ class Engine:
         prefill is inflight AND decode slots are live — otherwise the
         classic paths are strictly better (full/batched prefill when
         idle, plain fused windows when nothing is admitting). Speculation
-        composes: step() routes to _mixed_spec_step, whose program carries
+        composes: step() asks _mixed_step for the program that carries
         the draft operands as ragged verify rows. Guided decode keeps the
         classic alternation — neither mixed program carries grammar
         operands (the inflight request's OWN guide still applies: its
@@ -2862,242 +2777,110 @@ class Engine:
                 and not any(s.guide is not None
                             for s in self.seqs.values()))
 
-    def _mixed_step(self) -> List[TokenEvent]:
+    def _mixed_step(self, spec: bool) -> List[TokenEvent]:
         """One unified ragged step: a single dispatch advances every
-        decode slot by one token AND pushes the inflight prefill forward
-        by up to mixed_batch_tokens (the RPA continuous-batching shape,
-        PAPERS.md arxiv 2604.15464). Decode ITL stops paying for whole
-        prefill chunks between windows — the chunk tokens fill the same
-        program's ragged tail, and on the final chunk the first token
-        installs from the fused program's own last-row logits."""
+        decode slot AND pushes the inflight prefill forward by up to
+        mixed_batch_tokens (the RPA continuous-batching shape, PAPERS.md
+        arxiv 2604.15464). Decode ITL stops paying for whole prefill
+        chunks between windows — the chunk tokens fill the same program's
+        ragged tail, and on the final chunk the first token installs from
+        the fused program's own last-row logits. A slot advances one
+        token, or with `spec` runs a K+1-token verify window and emits
+        1..K+1 (dispatched even when no slot drafted this step: n_acc = 0
+        everywhere reduces it to plain mixed semantics, and the
+        compiled-program set stays bounded and warm)."""
         inf = self._inflight
-        cfg = self.cfg
-        events: List[TokenEvent] = []
         # the mixed program extends the decode carry like a 1-step
         # window: drain any in-flight async window first, then provision
-        # decode pages for the one token this step writes
-        if self._pending_win is not None:
-            events.extend(self._materialize_pending())
+        # decode pages for the tokens this step may write
+        events = self._materialize_pending()
+        ahead = self.cfg.num_speculative_tokens + 1 if spec else 1
         with self.timeline.phase("page_alloc"):
-            self._grow_pages(1, events)
+            got = self._grow_pages(ahead, events)
         if not self.seqs:
             # page pressure killed the whole batch: the chunk still has
             # its reserved pages — advance it on the classic path
             events.extend(self._advance_chunk())
             return events
-        c = cfg.mixed_batch_tokens
-        start = inf.done
-        take = min(c, inf.prompt_len - start)
-        p_tokens = np.zeros((c,), dtype=np.int32)
-        p_tokens[:take] = inf.req.prompt_token_ids[start:start + take]
-
-        t0 = time.monotonic()
-        self._ensure_dev_state()
-        want_lp = any(s.logprobs is not None for s in self.seqs.values())
-        cur, pos, ctx_lens, active_dev = self._dev_state
-        (temp, top_p, top_k, pres, freq, min_p, bias_ids, bias_vals,
-         keys) = self._dev_sampling
-        lx = (self._dev_adapters,) if self.lora is not None else ()
-        px = (jnp.int32(inf.aslot),) if self.lora is not None else ()
-        with self.timeline.phase("dispatch"):
-            (ys, chunk_logits, cur, pos, ctx_lens, self.token_counts,
-             self.k_pages, self.v_pages) = self._mixed[want_lp](
-                self.params, cur, pos, ctx_lens, active_dev,
-                self._dev_tables, temp, top_p, top_k, pres, freq, min_p,
-                bias_ids, bias_vals, keys, self.token_counts,
-                self.k_pages, self.v_pages, *lx,
-                jnp.asarray(p_tokens), jnp.int32(start), jnp.int32(take),
-                jnp.asarray(inf.pages_arr), *px,
-            )
-        self._dev_state = (cur, pos, ctx_lens, active_dev)
-        slots = list(self.seqs)
-        with self.timeline.phase("device_wait"):
-            next_np = np.asarray(ys[0])  # [1, B]
-            if want_lp:
-                chosen_np = np.asarray(ys[1])
-                tids_np = np.asarray(ys[2])
-                tvals_np = np.asarray(ys[3])
-        dt = time.monotonic() - t0
-        inf.done += take
-        # the mixed dispatch IS this iteration's decode step — it feeds
-        # the same ITL histograms (that is exactly what the A/B measures)
-        # plus its own phase and the ragged-composition histogram
-        self.metrics.decode_steps += 1
-        self.metrics.decode_time_s += dt
-        self.metrics.observe_phase("mixed_step", dt)
-        self.metrics.observe_phase("decode_window", dt)
-        self.metrics.observe_phase("decode_step", dt)
-        self.metrics.observe_occupancy(len(slots), cfg.max_num_seqs)
-        self.metrics.observe_mixed(take, len(slots))
-        if self.model_cfg.is_mla:  # read by the latent kernels' rooflines
-            self.metrics.observe_mixed_attention(
-                [s.num_tokens for s in self.seqs.values()], start, take)
-        self._step_obs("mixed", dt, take=take)
-        with self.timeline.phase("detok"):
-            for slot in slots:
-                seq = self.seqs.get(slot)
-                if seq is None:
-                    continue
-                tok = int(next_np[0, slot])
-                seq.num_tokens += 1
-                seq.output_tokens.append(tok)
-                self.cur_tokens[slot] = tok
-                self.metrics.output_tokens += 1
-                finished, reason = self._check_stop(seq, tok)
-                ev = TokenEvent(seq.request_id, tok,
-                                len(seq.output_tokens) - 1, finished,
-                                reason)
-                if want_lp and seq.logprobs is not None:
-                    self._decorate_lp(ev, seq, chosen_np[0, slot],
-                                      tids_np[0, slot], tvals_np[0, slot])
-                events.append(ev)
-                if finished:
-                    self._finish_slot(slot, reason)
-        if inf.done < inf.prompt_len:
-            return events
-
-        # final chunk rode this window: same installation tail as
-        # _advance_chunk, with the ragged program's last-token logits
-        self._inflight = None
-        self.metrics.prompt_tokens += inf.prompt_len
-        req = inf.req
-        if self.prefix_cache is not None:
-            self.prefix_cache.insert(req.prompt_token_ids, inf.pages,
-                                     namespace=self._kv_namespace(req.adapter))
-        with self.timeline.phase("device_wait"):
-            first, req_key, lp = self._first_token(req, chunk_logits,
-                                                   inf.prompt_len)
-        seq = self._install_slot(req, inf.slot, inf.pages, inf.prompt_len,
-                                 first, req_key)
-        finished, reason = self._check_stop(seq, first)
-        ev = TokenEvent(req.request_id, first, 0, finished, reason)
-        ev.phase = self._first_token_phase(req, inf.t_start)
-        self.metrics.observe_phase("prefill", ev.phase["prefill_s"])
-        if req.logprobs is not None:
-            self._decorate_lp(ev, seq, lp[0], lp[1], lp[2])
-        if finished:
-            self._finish_slot(inf.slot, reason)
-        events.append(ev)
+        chunk_logits = self._ragged_step(
+            events, inf, self._spec_drafts(got) if spec else None)
+        if inf.done >= inf.prompt_len:
+            self._finish_inflight(chunk_logits,
+                                  "mixed_spec" if spec else "mixed", events)
         return events
 
-    def _mixed_spec_step(self) -> List[TokenEvent]:
-        """One unified ragged step WITH speculation: every decode slot runs
-        a K+1-token verify window, the inflight prefill chunk rides the
-        same dispatch, and each speculating slot emits 1..K+1 tokens — the
-        composition the roadmap called the biggest gap (the fastest
-        scheduler and the fastest decoder were mutually exclusive). The
-        spec program is dispatched even when no slot drafted this step
-        (n_acc = 0 everywhere reduces it to plain mixed semantics) so the
-        compiled-program set stays bounded and warm."""
-        inf = self._inflight
-        cfg = self.cfg
-        events: List[TokenEvent] = []
-        if self._pending_win is not None:
-            events.extend(self._materialize_pending())
-        k = cfg.num_speculative_tokens
-        k1 = k + 1
-        with self.timeline.phase("page_alloc"):
-            got = self._grow_pages(k1, events)
-        if not self.seqs:
-            # page pressure killed the whole batch: the chunk still has
-            # its reserved pages — advance it on the classic path
-            events.extend(self._advance_chunk())
-            return events
-        drafts, room, nreal = self._spec_drafts(got)
-        c = cfg.mixed_batch_tokens
-        start = inf.done
-        take = min(c, inf.prompt_len - start)
-        p_tokens = np.zeros((c,), dtype=np.int32)
-        p_tokens[:take] = inf.req.prompt_token_ids[start:start + take]
+    def _ragged_step(self, events: List[TokenEvent],
+                     inf: Optional[InflightPrefill], drafted):
+        """Dispatch ONE program over the decode batch, read it back,
+        account for it and emit its tokens. `drafted` = _spec_drafts'
+        (drafts, room, nreal) runs every slot's verify window, None
+        advances each slot one token; `inf` rides the same program with
+        its next chunk (a plain decode without a chunk is a window:
+        _dispatch_window). The verify and the mixed-verify programs share
+        their leading operands, the chunk's trail. Returns the chunk's
+        last-row logits (None without a chunk)."""
+        px, chunk = (), None
+        if inf is not None:
+            c = self.cfg.mixed_batch_tokens
+            start = inf.done
+            take = min(c, inf.prompt_len - start)
+            chunk = (start, take)
+            p_tokens = np.zeros((c,), dtype=np.int32)
+            p_tokens[:take] = inf.req.prompt_token_ids[start:start + take]
 
         t0 = time.monotonic()
         self._ensure_dev_state()
         cur, pos, ctx_lens, active_dev = self._dev_state
-        (temp, top_p, top_k, pres, freq, min_p, bias_ids, bias_vals,
-         keys) = self._dev_sampling
-        d_drafts, d_room = self._upload(drafts, room)
         lx = (self._dev_adapters,) if self.lora is not None else ()
-        px = (jnp.int32(inf.aslot),) if self.lora is not None else ()
+        if drafted is not None:
+            drafts, room, nreal = drafted
+            d_drafts, d_room = self._upload(drafts, room)
+            fn = self._spec if inf is None else self._mixed_spec
+            kind = "decode_spec" if inf is None else "mixed_spec"
+            args = (self.params, cur, d_drafts, pos, ctx_lens, active_dev,
+                    self._dev_tables, *self._dev_sampling,
+                    self.token_counts, d_room)
+        else:
+            want_lp = any(s.logprobs is not None
+                          for s in self.seqs.values())
+            fn, kind = self._mixed[want_lp], "mixed"
+            args = (self.params, cur, pos, ctx_lens, active_dev,
+                    self._dev_tables, *self._dev_sampling,
+                    self.token_counts)
         with self.timeline.phase("dispatch"):
-            (ys, chunk_logits, cur, pos, ctx_lens, self.token_counts,
-             self.k_pages, self.v_pages) = self._mixed_spec(
-                self.params, cur, d_drafts, pos, ctx_lens, active_dev,
-                self._dev_tables, temp, top_p, top_k, pres, freq, min_p,
-                bias_ids, bias_vals, keys, self.token_counts, d_room,
-                self.k_pages, self.v_pages, *lx,
-                jnp.asarray(p_tokens), jnp.int32(start), jnp.int32(take),
-                jnp.asarray(inf.pages_arr), *px,
-            )
+            if inf is not None:  # fresh uploads each call, never donated
+                px = (jnp.asarray(p_tokens), jnp.int32(start),
+                      jnp.int32(take), jnp.asarray(inf.pages_arr))
+                if self.lora is not None:
+                    px += (jnp.int32(inf.aslot),)
+            ys, *out = fn(*args, self.k_pages, self.v_pages, *lx, *px)
+            chunk_logits = out.pop(0) if inf is not None else None
+            (cur, pos, ctx_lens, self.token_counts, self.k_pages,
+             self.v_pages) = out
+            del args  # the donated arrays die inside this span, as in
+            # _dispatch_window
         self._dev_state = (cur, pos, ctx_lens, active_dev)
         slots = list(self.seqs)
+        given = lps = None
         with self.timeline.phase("device_wait"):
-            emitted_np = np.asarray(ys[0])  # [B, K1]
-            nacc_np = np.asarray(ys[1])  # [B]
+            if drafted is not None:
+                toks = np.asarray(ys[0]).T  # [K+1, B]
+                nacc_np = np.asarray(ys[1])  # [B]
+                given = nacc_np + 1
+            else:
+                toks = np.asarray(ys[0])  # [1, B]
+                if want_lp:
+                    lps = tuple(np.asarray(y) for y in ys[1:])
         dt = time.monotonic() - t0
-        inf.done += take
-        total = sum(int(nacc_np[s]) + 1 for s in slots)
-        self.metrics.decode_steps += 1
-        self.metrics.decode_time_s += dt
-        self._spec_feedback(slots, room, nreal, nacc_np)
-        self.metrics.observe_phase("mixed_step", dt)
-        self.metrics.observe_phase("decode_window", dt)
-        self.metrics.observe_occupancy(len(slots), cfg.max_num_seqs)
-        self.metrics.observe_mixed(take, len(slots))
-        # weight = effective steps this verify advanced (same vote scheme
-        # as _decode_spec, so spec and plain windows share the histogram)
-        eff_steps = max(1, -(-total // len(slots)))
-        self.metrics.observe_phase("decode_step", dt / eff_steps,
-                                   weight=eff_steps)
-        self._step_obs("mixed_spec", dt, take=take)
-        with self.timeline.phase("detok"):
-            for slot in slots:
-                seq = self.seqs.get(slot)
-                if seq is None:
-                    continue
-                for j in range(int(nacc_np[slot]) + 1):
-                    tok = int(emitted_np[slot, j])
-                    seq.num_tokens += 1
-                    seq.output_tokens.append(tok)
-                    self.cur_tokens[slot] = tok
-                    self.metrics.output_tokens += 1
-                    finished, reason = self._check_stop(seq, tok)
-                    events.append(TokenEvent(
-                        seq.request_id, tok, len(seq.output_tokens) - 1,
-                        finished, reason,
-                    ))
-                    if finished:
-                        # mid-chain stop: later accepted tokens are
-                        # discarded; _finish_slot invalidates device state,
-                        # so the stale advanced position is rebuilt from
-                        # mirrors next step
-                        self._finish_slot(slot, reason)
-                        break
-        if inf.done < inf.prompt_len:
-            return events
-
-        # final chunk rode this window: same installation tail as
-        # _mixed_step, with the ragged program's last-token logits
-        self._inflight = None
-        self.metrics.prompt_tokens += inf.prompt_len
-        req = inf.req
-        if self.prefix_cache is not None:
-            self.prefix_cache.insert(req.prompt_token_ids, inf.pages,
-                                     namespace=self._kv_namespace(req.adapter))
-        with self.timeline.phase("device_wait"):
-            first, req_key, lp = self._first_token(req, chunk_logits,
-                                                   inf.prompt_len)
-        seq = self._install_slot(req, inf.slot, inf.pages, inf.prompt_len,
-                                 first, req_key)
-        finished, reason = self._check_stop(seq, first)
-        ev = TokenEvent(req.request_id, first, 0, finished, reason)
-        ev.phase = self._first_token_phase(req, inf.t_start)
-        self.metrics.observe_phase("prefill", ev.phase["prefill_s"])
-        if req.logprobs is not None:
-            self._decorate_lp(ev, seq, lp[0], lp[1], lp[2])
-        if finished:
-            self._finish_slot(inf.slot, reason)
-        events.append(ev)
-        return events
+        if inf is not None:
+            inf.done += take
+        if drafted is not None:
+            self._spec_feedback(slots, room, nreal, nacc_np)
+        # the dispatch IS this iteration's decode step — it feeds the same
+        # ITL histograms (that is exactly what the mixed A/B measures)
+        self._account_step(kind, dt, 1, slots, chunk=chunk, given=given)
+        self._emit_tokens(events, slots, toks, given=given, lps=lps)
+        return chunk_logits
 
     def _window_steps(self, extra: int = 0) -> int:
         """How many decode steps the next dispatch may fuse (1 = classic).
@@ -3237,13 +3020,11 @@ class Engine:
     def _preempt_slot(self, slot: int) -> None:
         """Preempt ONE sequence by recompute: free its pages, requeue the
         continuation at the front of its priority level."""
-        import dataclasses as _dc
-
         seq = self.seqs.get(slot)
         if seq is None:
             return
         old = seq.req
-        cont = _dc.replace(
+        cont = dataclasses.replace(
             old,
             prompt_token_ids=list(seq.prompt_ids)
             + list(seq.output_tokens),
@@ -3368,8 +3149,8 @@ class Engine:
         return drafts, room, nreal
 
     def _spec_feedback(self, slots, room, nreal, nacc_np) -> None:
-        """Post-verify bookkeeping shared by _decode_spec and
-        _mixed_spec_step: drafter-labeled draft/accept accounting,
+        """Post-verify bookkeeping of a verify step, with or without a
+        chunk: drafter-labeled draft/accept accounting,
         per-slot acceptance-length observations, adaptive-K controller
         feedback, and the per-window flight record. Acceptances are
         clamped to each slot's REAL draft count — padded row positions
@@ -3405,79 +3186,20 @@ class Engine:
         if self._spec_demoted():
             return self._decode_once()
         events: List[TokenEvent] = []
-        cfg = self.cfg
-        k = cfg.num_speculative_tokens
-        k1 = k + 1
         with self.timeline.phase("page_alloc"):
-            got = self._grow_pages(k1, events)
+            got = self._grow_pages(self.cfg.num_speculative_tokens + 1,
+                                   events)
         if not self.seqs:
             return events
-        drafts, room, nreal = self._spec_drafts(got)
-
-        if not room.any():
+        drafted = self._spec_drafts(got)
+        if drafted[1].any():
+            self._ragged_step(events, None, drafted)
+        else:
             # nothing drafted (all-penalized batch, page shortfall): the
             # verify forward would cost (K+1)x a decode step to emit the
             # same one token per slot — use the plain window path instead
             # (the per-slot demotions were counted by _spec_drafts)
             events.extend(self._decode_once())
-            return events
-
-        t0 = time.monotonic()
-        self._ensure_dev_state()
-        cur, pos, ctx_lens, active_dev = self._dev_state
-        (temp, top_p, top_k, pres, freq, min_p, bias_ids, bias_vals,
-         keys) = self._dev_sampling
-        d_drafts, d_room = self._upload(drafts, room)
-        lx = (self._dev_adapters,) if self.lora is not None else ()
-        with self.timeline.phase("dispatch"):
-            (ys, cur, pos, ctx_lens, self.token_counts, self.k_pages,
-             self.v_pages) = self._spec(
-                self.params, cur, d_drafts, pos, ctx_lens, active_dev,
-                self._dev_tables, temp, top_p, top_k, pres, freq, min_p,
-                bias_ids, bias_vals, keys, self.token_counts, d_room,
-                self.k_pages, self.v_pages, *lx,
-            )
-        self._dev_state = (cur, pos, ctx_lens, active_dev)
-        slots = list(self.seqs)
-        with self.timeline.phase("device_wait"):
-            emitted_np = np.asarray(ys[0])  # [B, K1]
-            nacc_np = np.asarray(ys[1])  # [B]
-        dt = time.monotonic() - t0
-        total = sum(int(nacc_np[s]) + 1 for s in slots)
-        self.metrics.decode_steps += 1
-        self.metrics.decode_time_s += dt
-        self._spec_feedback(slots, room, nreal, nacc_np)
-        self.metrics.observe_phase("decode_window", dt)
-        self.metrics.observe_occupancy(len(slots), self.cfg.max_num_seqs)
-        # weight = effective steps this verify advanced, so spec verifies
-        # and fused windows carry proportional votes in the shared histogram
-        eff_steps = max(1, -(-total // len(slots)))
-        self.metrics.observe_phase("decode_step", dt / eff_steps,
-                                   weight=eff_steps)
-        self._step_obs("decode_spec", dt)
-        with self.timeline.phase("detok"):
-            for slot in slots:
-                seq = self.seqs.get(slot)
-                if seq is None:
-                    continue
-                for j in range(int(nacc_np[slot]) + 1):
-                    tok = int(emitted_np[slot, j])
-                    seq.num_tokens += 1
-                    seq.output_tokens.append(tok)
-                    self.cur_tokens[slot] = tok
-                    self.metrics.output_tokens += 1
-                    finished, reason = self._check_stop(seq, tok)
-                    events.append(TokenEvent(
-                        seq.request_id, tok, len(seq.output_tokens) - 1,
-                        finished, reason,
-                    ))
-                    if finished:
-                        # mid-chain stop: later accepted tokens are
-                        # discarded; _finish_slot invalidates device state,
-                        # so the stale advanced position is rebuilt from
-                        # mirrors next step
-                        self._finish_slot(slot, reason)
-                        break
         return events
 
     def _decode_once(self) -> List[TokenEvent]:
@@ -3510,8 +3232,7 @@ class Engine:
                 window = self._grow_pages(window, events, offset=lag,
                                           allow_kill=prev is None)
         if not self.seqs:
-            if self._pending_win is not None:
-                events.extend(self._materialize_pending())
+            events.extend(self._materialize_pending())
             return events
         if window <= 0:
             # not enough headroom/pages to run ahead of the in-flight
@@ -3580,33 +3301,27 @@ class Engine:
             want_lp = any(s.logprobs is not None
                           for s in self.seqs.values())
             cur, pos, ctx_lens, active_dev = self._dev_state
-            (temp, top_p, top_k, pres, freq, min_p, bias_ids, bias_vals,
-             keys) = self._dev_sampling
             # lora mode: the per-slot adapter indices ride every window
             # (slot 0 keeps base sequences on the zero delta)
             lx = (self._dev_adapters,) if self.lora is not None else ()
+            args = (self.params, cur, pos, ctx_lens, active_dev,
+                    self._dev_tables, *self._dev_sampling, self.token_counts,
+                    self.k_pages, self.v_pages, *lx)
             if any(s.guide is not None for s in self.seqs.values()):
                 self._ensure_dev_guide()
-                gm, gd, gb, ga = self._dev_guide
                 fn = self._get_guided_window(window > 1, want_lp)
-                (ys, cur, pos, ctx_lens, self.token_counts, gm, gd, gb,
-                 self.k_pages, self.v_pages) = fn(
-                    self.params, cur, pos, ctx_lens, active_dev,
-                    self._dev_tables, temp, top_p, top_k, pres, freq,
-                    min_p, bias_ids, bias_vals, keys, self.token_counts,
-                    self.k_pages, self.v_pages, *lx, gm, gd, gb, ga,
-                )
-                self._dev_guide = (gm, gd, gb, ga)
+                (ys, cur, pos, ctx_lens, self.token_counts, *grammar,
+                 self.k_pages, self.v_pages) = fn(*args, *self._dev_guide)
+                self._dev_guide = (*grammar, self._dev_guide[3])
             else:
                 fn = self._windows[(window > 1, want_lp)]
                 (ys, cur, pos, ctx_lens, self.token_counts, self.k_pages,
-                 self.v_pages) = fn(
-                    self.params, cur, pos, ctx_lens, active_dev,
-                    self._dev_tables, temp, top_p, top_k, pres, freq,
-                    min_p, bias_ids, bias_vals, keys, self.token_counts,
-                    self.k_pages, self.v_pages, *lx,
-                )
+                 self.v_pages) = fn(*args)
             self._dev_state = (cur, pos, ctx_lens, active_dev)
+            # the last references to the donated arrays die INSIDE this
+            # span: on a TPU releasing them takes ~0.5 ms a window, which
+            # `host_share_pct` would otherwise read as the host's
+            del args
         # capture membership AT DISPATCH: a slot installed later (disagg
         # import) must not consume this window's rows. The stored duration
         # is the HOST dispatch cost; the materialize side adds its own wait
@@ -3633,36 +3348,69 @@ class Engine:
             # chaos: slow-but-alive readback — must NOT trip the watchdog
             # when the delay stays under the deadline
             faults.sleep_point("engine.device_slow")
-            next_np = np.asarray(ys[0])  # [window, B]
-            if want_lp:
-                chosen_np = np.asarray(ys[1])  # [window, B]
-                tids_np = np.asarray(ys[2])  # [window, B, K]
-                tvals_np = np.asarray(ys[3])
-        bad_slots = ()
-        if self.integrity != "off":
-            # host-side SDC net for decode windows: the only data that
-            # crosses back per step is the token array — a corrupted id
-            # outside [0, vocab) poisons detok and the KV it indexes.
-            # (Logit-level checks live in the prefill readback; decode
-            # windows donate their programs, so this host check is the
-            # no-recompile-cost equivalent.)
-            oob = ((next_np < 0)
-                   | (next_np >= self.model_cfg.vocab_size)).any(axis=0)
-            if oob.any():
-                bad_slots = tuple(np.flatnonzero(oob))
+            toks = np.asarray(ys[0])  # [window, B]
+            # chosen [window, B], top ids and values [window, B, K]
+            lps = (tuple(np.asarray(y) for y in ys[1:]) if want_lp
+                   else None)
         dt = dispatch_s + (time.monotonic() - t_wait)
-        self.metrics.decode_steps += window
-        self.metrics.decode_time_s += dt
-        self.metrics.observe_phase("decode_window", dt)
-        self.metrics.observe_phase("decode_step", dt / window, weight=window)
-        self.metrics.observe_occupancy(len(slots), self.cfg.max_num_seqs)
-        if self.model_cfg.is_mla:
-            self.metrics.observe_decode_attention(
-                [self.seqs[s].num_tokens for s in slots if s in self.seqs],
-                window)
-        self._step_obs("decode", dt)
+        self._account_step("decode", dt, window, slots)
+        self._emit_tokens(events, slots, toks, lps=lps)
+        return events
 
+    def _account_step(self, kind: str, dt: float, steps: int, slots,
+                      chunk=None, given=None) -> None:
+        """Every observation one dispatch over the decode batch owes: it
+        took `dt`, advanced the device `steps` decode steps over `slots`,
+        carried `chunk` = (start, take) of the inflight prompt if any, and
+        a verify gave slot s `given[s]` tokens. `decode_step` votes once
+        per step advanced, so verifies, fused windows and mixed steps
+        carry proportional votes in the shared histogram: a verify counts
+        the steps it advanced its slots on average."""
+        m = self.metrics
+        m.decode_steps += steps
+        m.decode_time_s += dt
+        m.observe_phase("decode_window", dt)
+        votes = (steps if given is None
+                 else max(1, -(-int(given[slots].sum()) // len(slots))))
+        m.observe_phase("decode_step", dt / votes, weight=votes)
+        m.observe_occupancy(len(slots), self.cfg.max_num_seqs)
+        take = 0
+        if chunk is not None:
+            start, take = chunk
+            m.observe_phase("mixed_step", dt)
+            m.observe_mixed(take, len(slots))
+        if self.model_cfg.is_mla:  # read by the latent kernels' rooflines
+            contexts = [self.seqs[s].num_tokens for s in slots
+                        if s in self.seqs]
+            if chunk is not None:
+                m.observe_mixed_attention(contexts, start, take)
+            elif given is None:  # a verify does not run the decode kernel
+                m.observe_decode_attention(contexts, steps)
+        self._step_obs(kind, dt, take=take)
+
+    def _emit_tokens(self, events: List[TokenEvent], slots, toks,
+                     given=None, lps=None) -> None:
+        """Turn one readback into TokenEvents. `toks[j, slot]` is the j-th
+        token the dispatch gave `slot`: a window's column, a mixed step's
+        one token, and of a verify window the first `given[slot]` = n_acc
+        + 1 (every row where `given` is None). `lps` = (chosen, top ids,
+        top values) indexed alike, where the batch asked for logprobs."""
+        rows = toks.shape[0]
         with self.timeline.phase("detok"):
+            bad_slots = ()
+            if self.integrity != "off":
+                # host-side SDC net: the only data that crosses back per
+                # step is the token array — a corrupted id outside
+                # [0, vocab) poisons detok and the KV it indexes.
+                # (Logit-level checks live in the prefill readback; the
+                # step programs donate their carry, so this host check is
+                # the no-recompile-cost equivalent.)
+                oob = (toks < 0) | (toks >= self.model_cfg.vocab_size)
+                if given is not None:
+                    oob &= np.arange(rows)[:, None] < given[None, :]
+                oob = oob.any(axis=0)
+                if oob.any():
+                    bad_slots = tuple(np.flatnonzero(oob))
             for slot in slots:
                 seq = self.seqs.get(slot)
                 if seq is None:  # finished/aborted since dispatch
@@ -3675,8 +3423,8 @@ class Engine:
                                              "integrity_fault"))
                     self._finish_slot(slot, "integrity_fault")
                     continue
-                for k in range(window):
-                    tok = int(next_np[k, slot])
+                for j in range(rows if given is None else int(given[slot])):
+                    tok = int(toks[j, slot])
                     seq.num_tokens += 1  # the attended token is now cached
                     seq.output_tokens.append(tok)
                     self.cur_tokens[slot] = tok
@@ -3692,18 +3440,18 @@ class Engine:
                         seq.request_id, tok, len(seq.output_tokens) - 1,
                         finished, reason,
                     )
-                    if want_lp and seq.logprobs is not None:
-                        self._decorate_lp(ev, seq, chosen_np[k, slot],
-                                          tids_np[k, slot],
-                                          tvals_np[k, slot])
+                    if lps is not None and seq.logprobs is not None:
+                        self._decorate_lp(ev, seq, lps[0][j, slot],
+                                          lps[1][j, slot], lps[2][j, slot])
                     events.append(ev)
                     if finished:
-                        # mid-window stop: later window tokens for this
+                        # mid-chain stop: the later tokens given to this
                         # slot are discarded (their KV lives in pages
-                        # freed right here)
+                        # freed right here); _finish_slot invalidates
+                        # device state, so a stale advanced position is
+                        # rebuilt from mirrors next step
                         self._finish_slot(slot, reason)
                         break
-        return events
 
     def _check_stop(self, seq: SeqState, token: int):
         if token in seq.stop_token_ids:
@@ -3780,7 +3528,11 @@ class Engine:
                 f"{self.cfg.num_pages - 1}"
             )
         with self._exec_lock:
-            first, pages, prompt_len, _, lp = self._run_prefill(req)
+            got = self._run_prefill(req, [])
+        if got is None:  # pages freed, fault counted: the caller's error
+            raise IntegrityFault("logits", [req.request_id],
+                                 "non-finite prefill logits")
+        pages, prompt_len, first, _, lp = got
         with self._lock:
             stale = self._parked.pop(req.request_id, None)
             self._parked[req.request_id] = (pages, prompt_len, time.monotonic())
@@ -3804,13 +3556,8 @@ class Engine:
         TPU-native replacement for the NIXL KV pull: a single XLA gather per
         pool (device->host once), shipped over ICI/DCN by the transfer layer.
         """
-        with self._lock:
-            pages, n_tokens, _ = self._parked[request_id]
-        with self._exec_lock:
-            idx = jnp.asarray(pages, jnp.int32)
-            k = np.asarray(jnp.take(self.k_pages, idx, axis=1))
-            v = np.asarray(jnp.take(self.v_pages, idx, axis=1))
-        return k, v, n_tokens
+        k, v, n_tokens = self.export_kv_device(request_id)
+        return np.asarray(k), np.asarray(v), n_tokens
 
     def export_kv_device(self, request_id: str):
         """Device-resident twin of export_kv: the gathered pages stay

@@ -5,6 +5,7 @@ label-escaping regression (ISSUE 1 satellites)."""
 import gc
 import json
 import sys
+import time
 import tracemalloc
 
 import pytest
@@ -292,8 +293,18 @@ def test_no_live_worker_503_names_its_reason(case, want):
         assert ei.value.code == 503
         message = json.loads(ei.value.read())["error"]["message"]
         assert message == f"no live worker for model 'm' ({want})"
-        spans = [sp for sp in fctx.tracer.collector.snapshot()
-                 if sp.attributes.get("router.no_worker_reason")]
+        # the handler ends its span after the response is written: the
+        # client can be here first (and then sees an earlier case's span
+        # last), so give the collector a moment
+        deadline = time.monotonic() + 5.0
+        while True:
+            spans = [sp for sp in fctx.tracer.collector.snapshot()
+                     if sp.attributes.get("router.no_worker_reason")]
+            if ((spans and spans[-1].attributes[
+                    "router.no_worker_reason"] == want)
+                    or time.monotonic() > deadline):
+                break
+            time.sleep(0.01)
         assert spans and spans[-1].attributes[
             "router.no_worker_reason"] == want
         assert spans[-1].status_code == "ERROR"

@@ -605,6 +605,105 @@ def test_nan_sentinel_aborts_exactly_the_poisoned_stream(watchdog_stack):
              {"url": url_a})
 
 
+def _drain(eng, out):
+    """Step the engine dry, filing each request's events under its id."""
+    while eng.has_work:
+        for ev in eng.step():
+            out.setdefault(ev.request_id, []).append(ev)
+    return out
+
+
+@pytest.mark.parametrize("case", ["mixed_nan", "mixed_spec_nan", "mixed_oob"])
+def test_fault_on_a_mixed_step_ends_exactly_one_stream(case, monkeypatch):
+    """Where a prompt arrives while decodes are live, its chunks and its
+    first token ride mixed steps (every cell of the benchmark): the
+    sentinels owe those steps what they owe the idle paths. Non-finite
+    logits under the prompt's last chunk (with and without verify windows
+    in the same program) end that request and nothing else; an id outside
+    the vocabulary in a mixed step's readback ends that slot and nothing
+    else. Pages, slots and health come back as an idle engine's."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import Engine
+    from dynamo_tpu.engine.request import GenRequest
+
+    monkeypatch.setenv(INTEGRITY_ENV, "logits")
+    spec = (dict(speculative_mode="ngram", num_speculative_tokens=2)
+            if case == "mixed_spec_nan" else {})
+    eng = Engine(EngineConfig(**KW, mixed_batch_tokens=8,
+                              enable_prefix_caching=False, **spec))
+    vocab = eng.model_cfg.vocab_size
+
+    def live(rid):
+        return GenRequest(rid, PROMPT, max_tokens=24, temperature=0.0,
+                          ignore_eos=True)
+
+    def late(rid):  # 12 tokens: two chunks of 8, the last rides a mixed step
+        return GenRequest(rid, [7, 3, 9, 2, 8, 4, 6, 1, 5, 3, 2, 7],
+                          max_tokens=6, temperature=0.0, ignore_eos=True)
+
+    recorded = []
+    real_record = eng.watchdog.record_integrity_fault
+    monkeypatch.setattr(
+        eng.watchdog, "record_integrity_fault",
+        lambda sentinel, rids, **kw: (recorded.append((sentinel, rids, kw)),
+                                      real_record(sentinel, rids, **kw)))
+    try:
+        ref_live = eng.generate(live("ref-live"))
+        ref_late = eng.generate(late("ref-late"))
+        idle = (eng.allocator.free_pages, sorted(eng._free_slots))
+        mixed_before = eng.metrics.mixed_count
+
+        eng.add_request(live("live"))
+        out = {}
+        while not eng.seqs:
+            for ev in eng.step():
+                out.setdefault(ev.request_id, []).append(ev)
+        (slot_live,) = eng.seqs
+        eng.add_request(late("late"))
+        if case == "mixed_oob":
+            real = eng._mixed[False]
+            armed = [True]
+
+            def corrupt(*args):
+                ys, *rest = real(*args)
+                if armed:  # the first mixed step only
+                    armed.clear()
+                    ys = (ys[0].at[0, slot_live].set(vocab),) + tuple(ys[1:])
+                return (ys, *rest)
+
+            eng._mixed[False] = corrupt
+            victim, survivor, ref = "live", "late", ref_late
+            sentinel = "decode_tokens"
+        else:
+            faults.get_plane().configure({"engine.device_nan": {"times": 1}})
+            victim, survivor, ref = "late", "live", ref_live
+            sentinel = "logits"
+        _drain(eng, out)
+
+        assert eng.metrics.mixed_count > mixed_before, "no mixed step ran"
+        last = out[victim][-1]
+        assert (last.token_id, last.finished, last.finish_reason) == (
+            -1, True, "integrity_fault")
+        assert [e.finish_reason for e in out[victim] if e.finished] == [
+            "integrity_fault"]
+        assert [e.token_id for e in out[survivor]] == ref, \
+            "the other stream must finish with its usual tokens"
+        assert out[survivor][-1].finish_reason == "length"
+        assert (eng.allocator.free_pages, sorted(eng._free_slots)) == idle
+        assert not eng.has_work and eng._inflight is None
+        wd = eng.watchdog.summary()
+        assert wd["integrity_faults_total"] == {sentinel: 1}
+        assert not any(wd["trips_total"].values())
+        assert eng.watchdog.health == "healthy"
+        assert [r[0] for r in recorded] == [sentinel]
+        if sentinel == "logits":
+            assert recorded[0][2]["where"] == (
+                "mixed_spec" if spec else "mixed")
+    finally:
+        faults.get_plane().clear()
+        eng.watchdog.stop()
+
+
 def test_hung_dispatch_handoff_resume_and_resurrection(watchdog_stack):
     """The headline drill: a device hang on worker A blows the step
     deadline — the monitor trips (suspect, shedding), the in-flight

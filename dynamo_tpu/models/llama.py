@@ -329,7 +329,7 @@ def _scan_layers_paged(cfg: ModelConfig, params: Params, body, x,
     (layer params, layer index) — one compiled layer body whatever the
     depth. Page offsets count all layers. Returns (x, k_pages, v_pages,
     moe_stats): moe_stats is the grouped expert layers' counts summed over
-    layers (int32 [5]), or None where no layer counts.
+    layers (int32 [6]), or None where no layer counts.
 
     Why flat: offsetting page ids instead of slicing a [P, ps, KV*D] layer
     out of the pool means each iteration touches only the written rows and
@@ -571,7 +571,8 @@ def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
         y, stats = moe_ops.moe_mlp_grouped(
             x, topi, weights, lp["moe_w_gate"], lp["moe_w_up"],
             lp["moe_w_down"], expert_offset=cfg.local_expert_offset,
-            token_mask=token_mask, layer=lp.get("moe_layer"))
+            num_experts=cfg.num_experts, token_mask=token_mask,
+            layer=lp.get("moe_layer"))
         return shared + y, stats
     combine = moe_ops.scatter_combine(topi, weights, cfg.num_experts,
                                       x.dtype)

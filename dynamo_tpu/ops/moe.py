@@ -25,6 +25,9 @@ dynamo_tpu.parallel.sharding map moe_w_* onto P('expert', ...)):
   (`expert_offset`, the weights' leading axis): the router keeps its full
   width, assignments to experts held elsewhere are left out — that part
   of the sum is another chip's — and no code stands in for their exchange.
+  The matmuls run over the smallest of a few static row counts that
+  holds the rows the held experts really received (`row_rungs`), and not
+  at all where no row picked a held expert.
   The choice between it and `moe_mlp_dense` is made from the model's
   shapes (ModelConfig.moe_grouped).
 
@@ -34,6 +37,8 @@ two paths contract with.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -153,10 +158,37 @@ def moe_mlp_dropping(
     return out
 
 
-# what moe_mlp_grouped counts for a layer (int32 [5]); summed over layers
+# what moe_mlp_grouped counts for a layer (int32 [6]); summed over layers
 # and steps by the model and the engine, read at /worker/stats
 MOE_STATS = ("assignments", "assignments_held", "busiest_held_sum",
-             "experts_touched", "layer_steps")
+             "experts_touched", "layer_steps", "rows_computed")
+
+# the rows of XLA's int8 grouped-matmul tile: no rung lies under the smallest
+# (32), and past the largest (512) fewer rows no longer shorten the matmul
+_MIN_TILE_ROWS, _MAX_TILE_ROWS = 32, 512
+
+
+def row_rungs(assignments: int, share: float) -> tuple[int, ...]:
+    """The static row counts the grouped matmuls are compiled for, from the
+    shapes alone: A = T*k assignments of which the share `share` (held
+    experts over the router's width) is expected here. 0 | R1 | 4*R1 | A
+    with R1 the power of two at or above A*share; 4*R1 only while it is a
+    smaller tile than A's, and a rung that is not under A is left out: a
+    layer that holds every expert, or a tiny one, keeps 0 | A.
+
+    Why these (measured on the chip, PR 31, benchmarks/chip/records/
+    pr31-ragged-dot-rows.json): XLA's ragged_dot pays one row tile of
+    min(rows handed, 512) for every group it touches, whatever the group
+    holds (30 us a touched expert at 32 rows, 43 at 256, 58 at 512 and
+    above, at Kimi-K2's widths), and the gather, quantisation and
+    scatter-add around it pay 0.5 us a row handed. Every rung is one more
+    copy of the layer's body in every program's compile."""
+    r1 = _MIN_TILE_ROWS
+    while r1 < assignments * share:
+        r1 *= 2
+    tail = (4 * r1,) if 4 * r1 <= _MAX_TILE_ROWS else ()
+    return (0,) + tuple(
+        r for r in (r1,) + tail if r < assignments) + (assignments,)
 
 
 def _flat_groups(w: jax.Array) -> jax.Array:
@@ -189,6 +221,28 @@ def _grouped_dot(x: jax.Array, w, group_sizes: jax.Array,
     return y * w_scale.astype(y.dtype)
 
 
+def _expert_rows(rows: int, x, tok, row_expert, wr, n_held, group_sizes,
+                 w_gate, w_up, w_down) -> jax.Array:
+    """The expert layer over the first `rows` sorted assignments (those
+    that belong to a group come first: n_held <= rows): gather the token
+    rows, gate / up / down as grouped matmuls, weight each result row and
+    add it back to its token -> [T, E]."""
+    if not rows:
+        return jnp.zeros_like(x)
+    tok, row_expert, wr = tok[:rows], row_expert[:rows], wr[:rows]
+    live = (jnp.arange(rows) < n_held)[:, None]
+    with jax.named_scope("moe_experts"):
+        xs = jnp.take(x, tok, axis=0)  # [R, E]
+        g = _grouped_dot(xs, w_gate, group_sizes, row_expert)
+        u = _grouped_dot(xs, w_up, group_sizes, row_expert)
+        h = jnp.where(live, jax.nn.silu(g) * u, 0)
+        y = _grouped_dot(h, w_down, group_sizes, row_expert)
+        # rows behind the last group were never written by the grouped
+        # matmul: select, do not multiply (they may hold anything)
+        y = jnp.where(live, y.astype(jnp.float32) * wr[:, None], 0)
+        return jnp.zeros(x.shape, jnp.float32).at[tok].add(y).astype(x.dtype)
+
+
 def moe_mlp_grouped(
     x: jax.Array,        # [T, E]
     topi: jax.Array,     # [T, K] expert ids over the router's whole width
@@ -198,21 +252,30 @@ def moe_mlp_grouped(
     w_down,              # [Xh, F, E]
     *,
     expert_offset: int = 0,
+    num_experts: int | None = None,
     token_mask: jax.Array | None = None,
     layer=None,
 ):
     """Each token is computed only in the experts it picked, and only in
     those held here: experts [expert_offset, expert_offset + Xh) of the
-    router's width. Returns (y [T, E], stats int32 [5] as MOE_STATS).
+    router's `num_experts` (None: Xh, every expert is held). Returns
+    (y [T, E], stats int32 [6] as MOE_STATS).
 
     The T*K assignments are sorted by held expert (assignments to experts
     held elsewhere, and those of masked rows, sort behind the last group
     and belong to no group), the token rows are gathered in that order and
     the projections run as grouped matmuls; each result row is weighted and
-    added back to its token. The buffers are sized for the worst case —
-    every assignment held here — so no token is ever dropped; the grouped
-    matmul visits only the rows its groups cover and reads only the
-    experts some row picked.
+    added back to its token.
+
+    XLA's grouped matmul pays, for every group it touches, a row tile
+    sized by the rows it is HANDED, whatever the groups cover, and a share
+    of a wide router receives few of them (row_rungs has the numbers): so
+    the gather, the matmuls and the scatter run over the first R sorted
+    rows, R the smallest rung of `row_rungs` that holds this layer's own
+    count (chosen on the device; every rung is compiled). The last rung is
+    every assignment — all of them held here — so no token is ever
+    dropped at any imbalance, and the first is none: a layer no row of
+    which picked a held expert runs no matmul.
 
     `layer` (a traced index) with weights [L, Xh, ...]: the WHOLE layer
     stack is handed to the grouped matmul, whose groups are then all
@@ -234,28 +297,23 @@ def moe_mlp_grouped(
     tok = order // k
     group_sizes = jnp.bincount(key, length=xh + 1)[:xh].astype(jnp.int32)
     n_held = jnp.sum(group_sizes)
-    live = (jnp.arange(t * k) < n_held)[:, None]
     layer_sizes = group_sizes
     if layer is not None:
         first = layer * xh
         group_sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((stack[0] * xh,), jnp.int32), group_sizes, (first,))
         row_expert = row_expert + first
-    with jax.named_scope("moe_experts"):
-        xs = jnp.take(x, tok, axis=0)  # [A, E]
-        g = _grouped_dot(xs, w_gate, group_sizes, row_expert)
-        u = _grouped_dot(xs, w_up, group_sizes, row_expert)
-        h = jnp.where(live, jax.nn.silu(g) * u, 0)
-        y = _grouped_dot(h, w_down, group_sizes, row_expert)
-        # rows behind the last group were never written by the grouped
-        # matmul: select, do not multiply (they may hold anything)
-        wr = weights.reshape(t * k)[order].astype(jnp.float32)
-        y = jnp.where(live, y.astype(jnp.float32) * wr[:, None], 0)
-        out = jnp.zeros(x.shape, jnp.float32).at[tok].add(y).astype(x.dtype)
+    wr = weights.reshape(t * k)[order].astype(jnp.float32)
+    rungs = row_rungs(t * k, xh / (num_experts or xh))
+    ladder = jnp.asarray(rungs, jnp.int32)
+    rung = jnp.sum(ladder[:-1] < n_held)  # the first that holds n_held
+    out = jax.lax.switch(
+        rung, [functools.partial(_expert_rows, r) for r in rungs],
+        x, tok, row_expert, wr, n_held, group_sizes, w_gate, w_up, w_down)
     n_all = (jnp.sum(token_mask) * k if token_mask is not None
              else jnp.int32(t * k))
     stats = jnp.stack([
         n_all.astype(jnp.int32), n_held.astype(jnp.int32),
         jnp.max(layer_sizes), jnp.sum(layer_sizes > 0).astype(jnp.int32),
-        jnp.int32(1)])
+        jnp.int32(1), ladder[rung]])
     return out, stats

@@ -235,7 +235,7 @@ def test_grouped_equals_dense_at_every_imbalance(kind, mode):
     assert st == {"assignments": (t - 1) * k, "assignments_held": (t - 1) * k,
                   "busiest_held_sum": int(counts.max()),
                   "experts_touched": int((counts > 0).sum()),
-                  "layer_steps": 1}
+                  "layer_steps": 1, "rows_computed": t * k}
     assert not np.allclose(np.asarray(got)[4], 0)  # no token dropped
     np.testing.assert_array_equal(np.asarray(got)[3], 0)  # the padding row
 
@@ -265,7 +265,7 @@ def test_no_pick_held_here_gives_zero():
     got, stats = moe_ops.moe_mlp_grouped(x, topi, jnp.ones((3, 2)), wg, wu,
                                          wd, expert_offset=0)
     np.testing.assert_array_equal(got, 0)
-    assert np.asarray(stats).tolist() == [6, 0, 0, 0, 1]
+    assert np.asarray(stats).tolist() == [6, 0, 0, 0, 1, 0]
 
 
 # ------------------------------------------------ program against reference --

@@ -100,6 +100,61 @@ def test_decode_kernel_compiles_for_v5e_at_cell_shapes(
     assert f"bf16[{slots},{heads},{head_dim}]" in text
 
 
+@pytest.mark.parametrize("tokens,rungs", [(64, (32, 128, 512)),
+                                          (64 + CHUNK, (256, 2560))],
+                         ids=["decode_64_slots", "mixed_64_slots_256_chunk"])
+def test_kimi_expert_layer_compiles_with_a_ragged_dot_a_rung(one_chip, tokens,
+                                                             rungs):
+    """The Kimi share's expert layers (8 x 24 of 384 experts, 7168 x 2048,
+    w8a8, top-8; the layer scan hands the whole stack and the layer's
+    index) at the cell's decode and mixed steps: every rung of the row
+    ladder (PR 31) keeps XLA's own grouped matmul, three instructions NAMED
+    `%ragged-dot...` with the rung's row count, inside one conditional, and
+    none copies the stack. The trace names an operation by that text, and
+    benchmarks/chip/layer_metrics/moe_*.agent.json find the expert matmuls
+    by `^%ragged-dot`: a kernel of our own in their place would read as
+    nothing there (PERF.md section 7)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.models import quant
+    from dynamo_tpu.ops import moe
+
+    layers, held, experts, hidden, width, k = 8, 24, 384, 7168, 2048, 8
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def stack(a, b):
+        return quant.QTensorA8(arg((layers, held, a, b), jnp.int8),
+                               arg((layers, held, 1, b), jnp.float32))
+
+    def expert_layers(x, topi, w, wg, wu, wd):
+        def body(carry, idx):
+            x, counts = carry
+            y, c = moe.moe_mlp_grouped(
+                x, topi, w, wg, wu, wd, expert_offset=2 * held,
+                num_experts=experts, layer=idx)
+            return (x + y, counts + c), None
+        zero = jnp.zeros((len(moe.MOE_STATS),), jnp.int32)
+        return jax.lax.scan(body, (x, zero), jnp.arange(layers))[0]
+
+    text = jax.jit(expert_layers).lower(
+        arg((tokens, hidden), jnp.bfloat16), arg((tokens, k), jnp.int32),
+        arg((tokens, k), jnp.float32), stack(hidden, width),
+        stack(hidden, width), stack(width, hidden)).compile().as_text()
+    assert moe.row_rungs(tokens * k, held / experts) == (0,) + rungs
+    named = re.findall(r"^\s*(?:ROOT )?%ragged-dot[\w.-]* = s32\[(\d+),(\d+)\]",
+                       text, re.M)
+    want = sorted((r, n) for r in rungs for n in (width, width, hidden))
+    assert sorted((int(r), int(n)) for r, n in named) == want
+    # the branches read the int8 stacks where they lie
+    assert not re.search(r"s8\[(192|8,24),\d+,\d+\]\S* copy\(", text)
+    assert len(re.findall(r" conditional\(", text)) == 1
+
+
 def test_ragged_dispatch_compiles_head_parallel_on_four_chips(topo):
     """`chip_smoke.py --chips 4`'s mixed step: the dispatcher's shard_map
     over a (data=1, model=4) mesh hands each chip 7 query / 1 KV head."""

@@ -235,6 +235,19 @@ class EngineMetrics:
             "mixed_decode_q_rows": 0, "mixed_decode_kv_rows": 0,
             "mixed_chunk_q_rows": 0, "mixed_chunk_block_kv_rows": 0,
             "mixed_chunk_kv_pairs": 0}
+        # what the sparse-attention indexer of a DeepSeek-V3.2-style model
+        # was asked for (all zero for any other model), counted like
+        # `attn`: on the host at dispatch, a layer's worth. A query in a
+        # program that runs the selection (models/llama._selects: its page
+        # table addresses more than index_topk tokens) scores every token
+        # of its context and attends over min(index_topk, context) selected
+        # rows; one whose context is at most index_topk (every token is
+        # selected, or the program keeps today's kernels and scores
+        # nothing) also counts in queries_unselected
+        self.dsa: Dict[str, int] = {
+            "decode_queries": 0, "decode_keys_scored": 0,
+            "chunk_queries": 0, "chunk_keys_scored": 0,
+            "rows_selected": 0, "queries_unselected": 0}
         # all zero for a model whose expert layers do not count
         self.moe: Dict[str, int] = dict.fromkeys(MOE_STATS, 0)
         self._moe_pending: list = []
@@ -261,6 +274,31 @@ class EngineMetrics:
         blocks = -(-take // block_q)
         a["mixed_chunk_block_kv_rows"] += (
             blocks * start + block_q * blocks * (blocks + 1) // 2)
+
+    def observe_dsa(self, topk: int, selects: bool, contexts=(),
+                    steps: int = 1, chunk=None) -> None:
+        """One dispatch of an indexed model: decode rows whose contexts
+        (the token being decoded included) are `contexts` at the first of
+        `steps` steps, and `chunk` = (start, take) prompt tokens. `selects`:
+        whether that program runs the selection."""
+        d = self.dsa
+        ctx = (np.asarray(list(contexts), np.int64)[:, None]
+               + np.arange(steps, dtype=np.int64)[None, :]).reshape(-1)
+        d["decode_queries"] += int(ctx.size)
+        both = [ctx]
+        if chunk is not None:
+            start, take = chunk
+            pctx = start + 1 + np.arange(take, dtype=np.int64)
+            d["chunk_queries"] += int(take)
+            both.append(pctx)
+            if selects:
+                d["chunk_keys_scored"] += int(pctx.sum())
+        if selects:
+            d["decode_keys_scored"] += int(ctx.sum())
+        for c in both:
+            d["queries_unselected"] += int((c <= topk).sum())
+            if selects:
+                d["rows_selected"] += int(np.minimum(c, topk).sum())
 
     def observe_moe(self, stats) -> None:
         """One program's expert-layer counts (a device array, maybe still
@@ -361,8 +399,9 @@ class EngineMetrics:
                             "spec_accepted_by", "spec_hist_by",
                             "spec_sum_by", "spec_count_by",
                             "first_token", "_first_token_lock", "moe", "attn",
-                            "_moe_pending", "_moe_lock")}
+                            "dsa", "_moe_pending", "_moe_lock")}
         out["attn"] = dict(self.attn)
+        out["dsa"] = dict(self.dsa)
         with self._moe_lock:
             done, self._moe_pending = self._moe_pending, []
         self._fold_moe(done)
@@ -473,6 +512,14 @@ class Engine:
                 model_cfg, moe_capacity_factor=cfg.moe_capacity_factor
             )
         self.model_cfg = model_cfg
+        if model_cfg.is_dsa and (cfg.speculative_mode != "off"
+                                 or cfg.sequence_parallel > 1):
+            raise ValueError(
+                "a model under the learned sparse selection (index_topk="
+                f"{model_cfg.index_topk}) is served without speculation and "
+                "without sequence parallelism: verify windows and the "
+                "ring / Ulysses prefill have no per-query selection "
+                "(models/llama.py, ops/attention.dsa_*)")
         if cfg.sequence_parallel > 1:
             # long-context serving: prefill shards the sequence over the
             # `seq` axis (ring/Ulysses over ICI); params/KV shard on
@@ -1420,7 +1467,9 @@ class Engine:
                     self.k_pages.dtype,
                 )
                 self.k_pages, self.v_pages = self._import(
-                    self.k_pages, self.v_pages, idx, one, one
+                    self.k_pages, self.v_pages, idx, one,
+                    one[..., :self.kv_spec.v_lane_width]
+                    if self.kv_spec.index_lanes else one
                 )
         self.reset_metrics()  # don't surface warm traffic as load
         out = {
@@ -2299,6 +2348,8 @@ class Engine:
         for i, r in enumerate(reqs):
             t = self._tenant_of(r)
             shares[t] = shares.get(t, 0.0) + float(seq_lens[i])
+            self._observe_dsa_prompt(0, int(seq_lens[i]),
+                                     bucket // cfg.page_size)
         self._step_obs("prefill", dt, shares=shares)
 
         events: List[TokenEvent] = []
@@ -2467,6 +2518,7 @@ class Engine:
         self.metrics.prefill_time_s += dt
         self.metrics.observe_phase("prefill", dt)
         self.metrics.prompt_tokens += prompt_len
+        self._observe_dsa_prompt(0, prompt_len, n_bucket_pages)
         self._step_obs("prefill", dt,
                        shares={self._tenant_of(req): float(prompt_len)})
         return pages, prompt_len, first, req_key, lp
@@ -2699,7 +2751,8 @@ class Engine:
         cfg = self.cfg
         chunk = cfg.prefill_chunk_tokens
         prompt_len = len(req.prompt_token_ids)
-        bucket = _next_bucket(prompt_len, cfg.page_size, cfg.max_seq_len)
+        bucket = self._table_bucket(
+            _next_bucket(prompt_len, cfg.page_size, cfg.max_seq_len))
         total = max(1, -(-prompt_len // cfg.page_size))
         pages = list(cached_pages or [])
         pages += self.allocator.alloc(total - len(pages))
@@ -2719,6 +2772,27 @@ class Engine:
                          tenant=self._tenant_of(req),
                          adapter=req.adapter or "", prompt_len=prompt_len,
                          cached_tokens=n_cached)
+
+    def _table_bucket(self, bucket: int) -> int:
+        """The prompt bucket a chunked prompt's page table is sized for:
+        its own, so that the chunk programs' work follows the prompt.
+        A model under a learned sparse selection keeps TWO widths instead
+        of one a bucket: the bucket that holds index_topk tokens for every
+        prompt up to it (little to select from), and the longest for all
+        others. Each width costs a chunk program and two mixed programs,
+        and with the selection's sort and gathers in them (and the decode
+        rows' in every mixed one) the 12 widths of a 32k context compiled
+        for over 1,600 s on a v5e, past what a worker is given to become
+        ready (PERF.md section 6, PR 32). The price: a prompt just past
+        index_topk scores and sorts over the longest table."""
+        topk = self.model_cfg.index_topk
+        if not topk:
+            return bucket
+        cfg = self.cfg
+        small = _next_bucket(min(topk, cfg.max_seq_len), cfg.page_size,
+                             cfg.max_seq_len)
+        return small if bucket <= small else _next_bucket(
+            cfg.max_seq_len, cfg.page_size, cfg.max_seq_len)
 
     def _advance_chunk(self) -> List[TokenEvent]:
         """Run ONE chunk of the inflight prefill; on the last chunk, sample
@@ -2748,6 +2822,7 @@ class Engine:
         dt = time.monotonic() - t0
         self.metrics.prefill_time_s += dt
         self.metrics.observe_phase("prefill_chunk", dt)
+        self._observe_dsa_prompt(start, take, len(inf.pages_arr))
         # this dispatch ran the chunk alone — its tenant owns the segment
         self._step_obs("prefill_chunk", dt, take=take,
                        shares={self._tenant_of(inf.req): float(take)})
@@ -3386,7 +3461,27 @@ class Engine:
                 m.observe_mixed_attention(contexts, start, take)
             elif given is None:  # a verify does not run the decode kernel
                 m.observe_decode_attention(contexts, steps)
+            if self.model_cfg.is_dsa:
+                m.observe_dsa(self.model_cfg.index_topk,
+                              self._dsa_selects(self.cfg.max_pages_per_seq),
+                              contexts, steps, chunk)
         self._step_obs(kind, dt, take=take)
+
+    def _dsa_selects(self, table_pages: int) -> bool:
+        """Whether a program over a page table of `table_pages` pages runs
+        the sparse selection (models/llama._selects, from the same
+        shapes)."""
+        return llama._selects(self.model_cfg,
+                              table_pages * self.cfg.page_size)
+
+    def _observe_dsa_prompt(self, start: int, take: int,
+                            table_pages: int) -> None:
+        """A prompt's tokens prefilled outside a step over the decode
+        batch: a chunk alone, or a whole bucket-sized prefill."""
+        if self.model_cfg.is_dsa:
+            self.metrics.observe_dsa(
+                self.model_cfg.index_topk, self._dsa_selects(table_pages),
+                chunk=(start, take))
 
     def _emit_tokens(self, events: List[TokenEvent], slots, toks,
                      given=None, lps=None) -> None:

@@ -16,6 +16,14 @@ attention ops and kernels read V from the K rows they already hold). The
 second array keeps the engine's (k_pages, v_pages) plumbing — donation,
 transfer, tiering — one shape for every model.
 
+An MLA model under a learned sparse selection (DeepSeek-V3.2's indexer,
+`ModelConfig.index_topk`) keeps a SECOND kind of per-token row: the
+indexer's key, `index_head_dim` lanes. It lives in the V pool
+(`KVCacheSpec.index_lanes`), under the same page ids as the latent row, so
+the allocator, prefix sharing, demotion, export / import and preemption
+carry both rows of a token together. Attention still reads V from the K
+rows: that is `v_from_k`, the spec's word, not the V pool's lane count.
+
 Page 0 is a reserved "trash" page: inactive batch slots point at it so the
 full-batch decode step stays shape-static without masking scatter writes.
 
@@ -55,6 +63,9 @@ class KVCacheSpec:
     # MLA: the latent row is stored once, in the K pool; the V pool has
     # no lanes and V is read from the K rows (see the module docstring)
     v_from_k: bool = False
+    # lanes of the V pool of a v_from_k model: 0, or the width of the
+    # sparse-attention indexer's key row (the second row kind a page holds)
+    index_lanes: int = 0
 
     @staticmethod
     def from_model(
@@ -67,6 +78,11 @@ class KVCacheSpec:
             raise ValueError(
                 f"kv_cache_dtype must be 'auto' or 'int8', got {kv_dtype!r}")
         quantized = kv_dtype == "int8"
+        if quantized and cfg.cache_index_dim:
+            raise ValueError(
+                "kv_cache_dtype=int8 with a sparse-attention indexer is not "
+                "implemented: the indexer's key rows have no packed-scale "
+                "layout")
         # cache geometry comes from the cache_* properties: MLA stores ONE
         # shared [c_kv | k_rope] latent row per token, classic attention
         # per-head K/V. MLA pools REPLICATE across the model axis (no lane
@@ -88,6 +104,7 @@ class KVCacheSpec:
             dtype=cfg.dtype if kv_dtype in ("auto", "") else kv_dtype,
             lane_blocks=blocks if quantized else 1,
             v_from_k=cfg.is_mla,
+            index_lanes=cfg.cache_index_dim,
         )
 
     @property
@@ -112,12 +129,16 @@ class KVCacheSpec:
 
     @property
     def v_shape(self):
-        return self.shape[:3] + (0 if self.v_from_k else self.lane_width,)
+        return self.shape[:3] + (self.v_lane_width,)
+
+    @property
+    def v_lane_width(self) -> int:
+        return self.index_lanes if self.v_from_k else self.lane_width
 
     def bytes_per_token(self) -> int:
         itemsize = jnp.dtype(self.dtype).itemsize
-        pools = 1 if self.v_from_k else 2
-        return pools * self.num_layers * self.lane_width * itemsize
+        return (self.num_layers * (self.lane_width + self.v_lane_width)
+                * itemsize)
 
     def page_table_width(self, bucket_tokens: int,
                          chunk_tokens: int) -> int:

@@ -81,7 +81,7 @@ class DiskBlockTier:
                 self.stored += 1
         return dropped
 
-    def get(self, block_hash: bytes, shape, dtype
+    def get(self, block_hash: bytes, shape, dtype, v_shape=None
             ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
         with self._lock:
             path = self._lru.get(block_hash)
@@ -94,9 +94,11 @@ class DiskBlockTier:
             with self._lock:
                 self._lru.pop(block_hash, None)
             return None
-        half = len(raw) // 2
+        # K's bytes, then V's (its own shape where the pools differ)
+        half = int(np.prod(shape)) * np.dtype(dtype).itemsize
         k = np.frombuffer(raw[:half], dtype=np.uint8).view(dtype).reshape(shape)
-        v = np.frombuffer(raw[half:], dtype=np.uint8).view(dtype).reshape(shape)
+        v = np.frombuffer(raw[half:], dtype=np.uint8).view(dtype).reshape(
+            v_shape or shape)
         self.hits += 1
         return k.copy(), v.copy()
 
@@ -113,16 +115,21 @@ class HostBlockPool:
     """Preallocated host-RAM KV block arena with LRU eviction and pinning."""
 
     def __init__(self, capacity_blocks: int, block_shape, dtype,
-                 disk: Optional[DiskBlockTier] = None):
+                 disk: Optional[DiskBlockTier] = None, v_block_shape=None):
         if capacity_blocks <= 0:
             raise ValueError("capacity_blocks must be > 0")
         self.capacity = capacity_blocks
         self.block_shape = tuple(block_shape)
+        # the V pool's block: the K block's shape unless the model's V rows
+        # have their own width (MLA: none at all, or the indexer's keys)
+        self.v_block_shape = tuple(v_block_shape or block_shape)
         self.dtype = np.dtype(dtype)
-        # [capacity, 2(K/V)] + block_shape — one contiguous slab, allocated
-        # once; a block's K is arena[slot, 0], V is arena[slot, 1]
-        self._arena = np.empty((capacity_blocks, 2) + self.block_shape,
+        # one slab a pool, allocated once: a block's K is _arena[slot],
+        # its V is _arena_v[slot]
+        self._arena = np.empty((capacity_blocks,) + self.block_shape,
                                self.dtype)
+        self._arena_v = np.empty((capacity_blocks,) + self.v_block_shape,
+                                 self.dtype)
         self._free: List[int] = list(range(capacity_blocks - 1, -1, -1))  # guarded_by: _lock
         self._entries: Dict[bytes, int] = {}  # guarded_by: _lock — hash -> slot, dict order = LRU
         self._pins: Dict[bytes, int] = {}  # guarded_by: _lock
@@ -137,7 +144,8 @@ class HostBlockPool:
 
     @property
     def block_nbytes(self) -> int:
-        return 2 * int(np.prod(self.block_shape)) * self.dtype.itemsize
+        return (int(np.prod(self.block_shape))
+                + int(np.prod(self.v_block_shape))) * self.dtype.itemsize
 
     def __len__(self) -> int:
         with self._lock:
@@ -159,8 +167,8 @@ class HostBlockPool:
             if slot is None:
                 self.rejected_full += 1
                 return False, removed
-            np.copyto(self._arena[slot, 0], k, casting="no")
-            np.copyto(self._arena[slot, 1], v, casting="no")
+            np.copyto(self._arena[slot], k, casting="no")
+            np.copyto(self._arena_v[slot], v, casting="no")
             self._entries[block_hash] = slot
             self.stored += 1
         return True, removed
@@ -177,7 +185,7 @@ class HostBlockPool:
             self.evicted_lru += 1
             if self.disk is not None:
                 removed.extend(self.disk.put(
-                    old, self._arena[slot, 0], self._arena[slot, 1]))
+                    old, self._arena[slot], self._arena_v[slot]))
             else:
                 removed.append(old)
             return slot
@@ -195,9 +203,10 @@ class HostBlockPool:
             if slot is not None:
                 self._entries[block_hash] = self._entries.pop(block_hash)
                 self.hits += 1
-                return self._arena[slot, 0].copy(), self._arena[slot, 1].copy()
+                return self._arena[slot].copy(), self._arena_v[slot].copy()
         if self.disk is not None:
-            got = self.disk.get(block_hash, self.block_shape, self.dtype)
+            got = self.disk.get(block_hash, self.block_shape, self.dtype,
+                                self.v_block_shape)
             if got is not None:
                 self.hits += 1
                 _, dropped = self.put(block_hash, got[0], got[1])  # re-promote

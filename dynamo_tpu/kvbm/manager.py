@@ -55,13 +55,17 @@ class KVBM:
         import jax.numpy as jnp
 
         self.block_shape = (spec.num_layers, spec.page_size, spec.lane_width)
+        # the V pool's rows have their own width for an MLA model: none, or
+        # the sparse-attention indexer's keys, which follow their pages
+        self.v_block_shape = self.block_shape[:2] + (spec.v_lane_width,)
         self._np_dtype = np.dtype(jnp.dtype(spec.dtype))
         disk = None
         if getattr(cfg, "kvbm_disk_dir", None):
             disk = DiskBlockTier(cfg.kvbm_disk_dir,
                                  capacity_blocks=cfg.kvbm_disk_blocks)
         self.pool = HostBlockPool(cfg.kvbm_host_blocks, self.block_shape,
-                                  self._np_dtype, disk=disk)
+                                  self._np_dtype, disk=disk,
+                                  v_block_shape=self.v_block_shape)
         self.gate = OnboardGate(
             mode=getattr(cfg, "kvbm_gate", "auto"),
             model_cfg=engine.model_cfg,
@@ -280,7 +284,8 @@ class KVBM:
             idx[:len(pages)] = pages
             k_new = np.zeros((self.block_shape[0], width) + self.block_shape[1:],
                              self._np_dtype)
-            v_new = np.zeros_like(k_new)
+            v_new = np.zeros((self.v_block_shape[0], width)
+                             + self.v_block_shape[1:], self._np_dtype)
             for i, (_, kb, vb) in enumerate(blocks):
                 k_new[:, i] = kb
                 v_new[:, i] = vb
@@ -318,7 +323,8 @@ class KVBM:
             return []
         out = []
         for h, (kb, vb) in zip(hashes, got):
-            if kb.shape != self.block_shape or kb.dtype != self._np_dtype:
+            if (kb.shape != self.block_shape or kb.dtype != self._np_dtype
+                    or vb.shape != self.v_block_shape):
                 log.warning("kvbm peer block layout mismatch "
                             "(%s/%s vs %s/%s); recomputing",
                             kb.shape, kb.dtype, self.block_shape,

@@ -199,6 +199,12 @@ class ModelConfig:
     # the PICK only, never the weights.
     moe_scoring: str = "softmax"
     router_bias: bool = False
+    # group-limited selection (HF n_group / topk_group, DeepSeek-V3's
+    # noaux_tc): the router's outputs form n_group groups of consecutive
+    # experts, a group scores the sum of its 2 largest s + bias, and the
+    # picks come from the topk_group best groups only. 1 / 1 = no groups.
+    n_group: int = 1
+    topk_group: int = 1
     # leading dense layers (HF first_k_dense_replace): the first
     # `first_k_dense` layers run one SwiGLU of dense_intermediate_size
     # instead of the expert layer; they keep their own parameter stack
@@ -229,6 +235,15 @@ class ModelConfig:
     qk_nope_head_dim: int = 0   # per-head no-rope query/key dim
     qk_rope_head_dim: int = 0   # shared rope dim appended to the latent row
     v_head_dim: int = 0         # per-head value dim out of W_UV
+    # learned sparse selection in front of MLA (DeepSeek-V3.2's lightning
+    # indexer): index_n_heads query heads of index_head_dim lanes score
+    # every cached token against ONE index key a token (a second kind of
+    # per-token state, kept in the V pool an MLA model leaves empty), and
+    # a query attends over its index_topk best-scoring tokens only.
+    # index_topk == 0: no indexer.
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # dtype for params/compute (bfloat16 on TPU; float32 for CPU tests)
     dtype: str = "bfloat16"
     eos_token_id: int = 2
@@ -260,6 +275,33 @@ class ModelConfig:
             raise ValueError(
                 "first_k_dense with attention_bias / qk_norm / post_norms "
                 "is not implemented: the dense stack has no such leaves")
+        if self.n_group > 1 or self.topk_group > 1:
+            if not (self.is_moe and self.moe_scoring == "sigmoid"
+                    and self.num_experts % self.n_group == 0
+                    and 1 <= self.topk_group <= self.n_group
+                    and self.num_experts // self.n_group >= 2
+                    and self.num_experts_per_tok
+                    <= self.topk_group * (self.num_experts // self.n_group)):
+                raise ValueError(
+                    f"group-limited routing (n_group={self.n_group}, "
+                    f"topk_group={self.topk_group}) needs a sigmoid-scored "
+                    f"router whose {self.num_experts} experts divide into "
+                    "the groups (>= 2 a group), topk_group <= n_group, and "
+                    "the kept groups must hold num_experts_per_tok experts")
+        if self.index_topk:
+            if not (self.is_mla and self.q_lora_rank > 0
+                    and self.index_n_heads > 0
+                    and self.index_head_dim >= self.qk_rope_head_dim
+                    and self.index_head_dim % 2 == 0):
+                raise ValueError(
+                    "the sparse-attention indexer (index_topk="
+                    f"{self.index_topk}) needs MLA with a query low-rank "
+                    "path (its queries come from the q-LoRA latent), "
+                    "index_n_heads > 0 and index_head_dim >= "
+                    "qk_rope_head_dim (its leading lanes take the rotary)")
+        elif self.index_n_heads or self.index_head_dim:
+            raise ValueError("index_n_heads / index_head_dim without "
+                             "index_topk: no indexer is built")
         held = self.num_local_experts
         if held and not (
                 self.is_moe and 0 <= self.local_expert_offset
@@ -299,6 +341,17 @@ class ModelConfig:
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
 
+    @property
+    def is_dsa(self) -> bool:
+        """MLA under the learned sparse selection (index_topk > 0)."""
+        return self.index_topk > 0
+
+    @property
+    def cache_index_dim(self) -> int:
+        """Lanes of the second per-token row an indexed model caches (the
+        indexer's key); 0 for every other model."""
+        return self.index_head_dim if self.is_dsa else 0
+
     # --- KV-cache geometry (what the paged pools actually store): MLA keeps
     # one shared latent row per token; classic attention keeps per-head K/V.
     @property
@@ -322,8 +375,15 @@ class ModelConfig:
     def from_hf_config(cfg: dict, name: str = "hf-model", dtype: str = "bfloat16") -> "ModelConfig":
         """Map a HuggingFace config.json dict onto ModelConfig.
 
-        Covers LlamaForCausalLM / Qwen2ForCausalLM / Qwen3ForCausalLM /
-        MixtralForCausalLM config keys.
+        Maps the config keys of: Llama 3.x, Mistral, Qwen2 / Qwen2.5,
+        Qwen3 and Qwen3-MoE, Mixtral, Phi-3, Gemma 1 / 2 / 3 (text),
+        DeepSeek-V2 (MLA, softmax-scored experts), DeepSeek-V3 / Kimi-K2
+        (q-LoRA MLA, sigmoid scores with a selection bias, n_group /
+        topk_group, leading dense layers, `deployment_share`) and
+        DeepSeek-V3.2 (`deepseek_v32`: the same block under the lightning
+        indexer's index_n_heads / index_head_dim / index_topk). Keys whose
+        mechanism is not implemented refuse loudly (multi-token-prediction
+        layers, interleaved dense layers, other scoring functions).
         """
         arch = (cfg.get("architectures") or [""])[0]
         if arch.startswith("Gemma3n"):
@@ -355,15 +415,13 @@ class ModelConfig:
             eos = eos[0]
         # keys whose mechanism is not implemented refuse loudly: serving
         # a checkpoint while ignoring one of them serves another model
-        if (cfg.get("n_group") or 1) > 1 or (cfg.get("topk_group") or 1) > 1:
-            raise ValueError(
-                f"group-limited routing (n_group={cfg.get('n_group')}, "
-                f"topk_group={cfg.get('topk_group')}) is not implemented; "
-                "only n_group = topk_group = 1 is served")
         if (cfg.get("num_nextn_predict_layers") or 0) > 0:
             raise ValueError(
                 "multi-token-prediction layers (num_nextn_predict_layers="
-                f"{cfg['num_nextn_predict_layers']}) are not implemented")
+                f"{cfg['num_nextn_predict_layers']}) are not implemented: "
+                "the module is a layer past num_hidden_layers that drafts "
+                "the next token; set the key to 0 to serve the model "
+                "without it")
         if (cfg.get("moe_layer_freq") or 1) != 1:
             raise ValueError(
                 f"moe_layer_freq={cfg['moe_layer_freq']} (dense layers "
@@ -373,8 +431,20 @@ class ModelConfig:
             raise ValueError(f"scoring_func {scoring!r} is not implemented")
         topk_method = cfg.get("topk_method") or "greedy"
         if topk_method not in ("greedy", "noaux_tc", "group_limited_greedy"):
-            # group_limited_greedy with n_group 1 (checked above) is greedy
             raise ValueError(f"topk_method {topk_method!r} is not implemented")
+        n_group = int(cfg.get("n_group") or 1)
+        topk_group = int(cfg.get("topk_group") or 1)
+        if topk_method == "greedy":
+            n_group = topk_group = 1  # HF ignores the groups there
+        elif (n_group > 1 or topk_group > 1) and topk_method != "noaux_tc":
+            # DeepSeek-V2's group_limited_greedy scores a group by its
+            # LARGEST softmax probability; only noaux_tc's rule (sum of the
+            # 2 largest sigmoid scores + bias) is written down in
+            # ops/moe.route_topk
+            raise ValueError(
+                f"topk_method {topk_method!r} with n_group={n_group} is not "
+                "implemented (groups are served for noaux_tc only)")
+        index_topk = int(cfg.get("index_topk") or 0)
         # expert count: Mixtral uses num_local_experts, DeepSeek
         # n_routed_experts, Qwen3-MoE plain num_experts
         n_experts = (cfg.get("num_local_experts")
@@ -451,6 +521,8 @@ class ModelConfig:
                 cfg.get("routed_scaling_factor", 1.0)),
             moe_scoring=scoring if n_experts else "softmax",
             router_bias=bool(n_experts) and topk_method == "noaux_tc",
+            n_group=n_group if n_experts else 1,
+            topk_group=topk_group if n_experts else 1,
             first_k_dense=first_dense,
             dense_intermediate_size=(
                 int(cfg.get("intermediate_size") or 0) if first_dense else 0),
@@ -462,6 +534,11 @@ class ModelConfig:
             qk_nope_head_dim=cfg.get("qk_nope_head_dim", 0) or 0,
             qk_rope_head_dim=cfg.get("qk_rope_head_dim", 0) or 0,
             v_head_dim=cfg.get("v_head_dim", 0) or 0,
+            index_n_heads=int(cfg.get("index_n_heads") or 0) if index_topk
+            else 0,
+            index_head_dim=int(cfg.get("index_head_dim") or 0) if index_topk
+            else 0,
+            index_topk=index_topk,
             dtype=dtype,
             eos_token_id=eos,
             bos_token_id=cfg.get("bos_token_id", 1),
@@ -902,6 +979,19 @@ PRESETS["meta-llama/Llama-3.2-1B-Instruct".lower().split("/")[-1]] = PRESETS[
 ]
 PRESETS["qwen/qwen3-0.6b".split("/")[-1]] = PRESETS["qwen3-0.6b"]
 PRESETS["deepseek-v2-lite-chat"] = PRESETS["deepseek-v2-lite"]
+# DeepSeek-V3.2 structure at a toy size: tiny-kimi-debug's block with the
+# experts in 4 groups of which 2 are kept, under an indexer of 4 heads x 32
+# lanes that keeps 16 rows a query (so a 17-token context already selects)
+PRESETS["tiny-dsv32-debug"] = dataclasses.replace(
+    PRESETS["tiny-kimi-debug"], name="tiny-dsv32-debug",
+    n_group=4, topk_group=2, rope_theta=10000.0,
+    rope_yarn_scaling=(40.0, 32.0, 1.0, 64, 1.0, 1.0, -1.0),
+    index_n_heads=4, index_head_dim=32, index_topk=16)
+# one chip's share of it: experts 0-3 of 16 held (group 0), as the chip
+# benchmark's cell holds experts 0-15 of 256
+PRESETS["tiny-dsv32-ep4-debug"] = dataclasses.replace(
+    PRESETS["tiny-dsv32-debug"], name="tiny-dsv32-ep4-debug",
+    num_local_experts=4, local_expert_offset=0)
 # one chip's share of tiny-kimi-debug: experts 4-7 of 16 held (the chip
 # benchmark's CPU rehearsal of the Kimi-K2 cell serves this)
 PRESETS["tiny-kimi-ep4-debug"] = dataclasses.replace(

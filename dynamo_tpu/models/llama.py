@@ -204,6 +204,16 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], str, float
             p[pre + "w_uk"] = w((l, h, nope, lora))
             p[pre + "w_uv"] = w((l, h, lora, vd))
             p[pre + "wo"] = w((l, h, vd, e))
+            if cfg.is_dsa:
+                # the lightning indexer (DeepSeek-V3.2), a layer's own:
+                # queries from the q-LoRA latent, one key a token through
+                # a LayerNorm (weight and bias), a weight an index head
+                hi, di = cfg.index_n_heads, cfg.index_head_dim
+                p[pre + "idx_wq_b"] = w((l, cfg.q_lora_rank, hi, di))
+                p[pre + "idx_wk"] = w((l, e, di))
+                p[pre + "idx_k_norm"] = ((l, di), "ones", 0.0)
+                p[pre + "idx_k_bias"] = ((l, di), "zeros", 0.0)
+                p[pre + "idx_w"] = w((l, e, hi))
         else:
             p[pre + "wq"] = w((l, e, h, d))
             p[pre + "wk"] = w((l, e, kv, d))
@@ -396,7 +406,7 @@ def _qkv(cfg: ModelConfig, lp: Params, x: jax.Array, positions: jax.Array,
     the absorbed query over the latent space — the generic paged-attention
     ops then serve MLA unchanged."""
     if cfg.is_mla:
-        return _qkv_mla(cfg, lp, x, positions)
+        return _qkv_mla(cfg, lp, x, positions)  # is_dsa: see _dsa_index
     q = qeinsum("te,ehd->thd", x, lp["wq"])
     k = qeinsum("te,ekd->tkd", x, lp["wk"])
     v = qeinsum("te,ekd->tkd", x, lp["wv"])
@@ -490,7 +500,138 @@ def _qkv_mla(cfg: ModelConfig, lp: Params, x: jax.Array,
     if pad:
         q_eff = jnp.pad(q_eff, ((0, 0), (0, 0), (0, pad)))
         row = jnp.pad(row, ((0, 0), (0, 0), (0, pad)))
+    if cfg.is_dsa:
+        q_idx, w_idx, k_idx = _dsa_index(cfg, lp, x, c_q, positions)
+        return DsaQuery(q_eff, q_idx, w_idx), row, k_idx
     return q_eff, row, row
+
+
+class DsaQuery(NamedTuple):
+    """What an indexed model's attention is handed as `q`: the absorbed
+    MLA query and the indexer's side of the selection. `_qkv` returns it
+    beside the latent row (`k`) and the indexer's key row (`v`: the row
+    the V pool caches, engine/kv_cache.py)."""
+    q: jax.Array  # [T, H, W] absorbed query over the latent row
+    q_idx: jax.Array  # [T, Hi, Di] indexer queries
+    w_idx: jax.Array  # [T, Hi] float32 index-head weights
+
+
+_INDEX_LN_EPS = 1e-6  # the indexer's LayerNorm (weight and bias)
+
+
+def _dsa_index(cfg: ModelConfig, lp: Params, x: jax.Array, c_q: jax.Array,
+               positions: jax.Array):
+    """The lightning indexer's projections (DeepSeek-V3.2): per token,
+    index_n_heads queries from the SAME normalised q-LoRA latent the
+    attention's queries come from, one key through a LayerNorm, and a
+    float32 weight an index head (scaled Hi^-1/2 * Di^-1/2). The rotary
+    turns the first qk_rope_head_dim lanes of queries and key. The scores,
+    the selection and the sparse attention are ops/attention.dsa_*; the key
+    is cached beside the latent row. DEPARTURE from the published code: no
+    Hadamard rotation and no FP8 (bf16 here): the rotation is orthogonal,
+    so it changes no product."""
+    rope, hi, di = cfg.qk_rope_head_dim, cfg.index_n_heads, cfg.index_head_dim
+    with jax.named_scope("dsa_indexer"):
+        q_idx = qeinsum("tr,rhd->thd", c_q, lp["idx_wq_b"])
+        k = qeinsum("te,ed->td", x, lp["idx_wk"]).astype(jnp.float32)
+        mu = jnp.mean(k, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(k - mu), axis=-1, keepdims=True)
+        k = ((k - mu) * jax.lax.rsqrt(var + _INDEX_LN_EPS)
+             * lp["idx_k_norm"].astype(jnp.float32)
+             + lp["idx_k_bias"].astype(jnp.float32)).astype(x.dtype)
+        k_idx = k[:, None, :]  # [T, 1, Di]
+
+        def turn(a):
+            r = apply_rope(a[..., :rope], positions, cfg.rope_theta,
+                           llama3_scaling=cfg.rope_llama3_scaling,
+                           yarn_scaling=cfg.rope_yarn_scaling)
+            return jnp.concatenate([r, a[..., rope:]], axis=-1)
+
+        q_idx, k_idx = turn(q_idx), turn(k_idx)
+        w_idx = jnp.einsum("te,eh->th", x, lp["idx_w"],
+                           preferred_element_type=jnp.float32
+                           ) * (hi ** -0.5 * di ** -0.5)
+    return q_idx, w_idx, k_idx
+
+
+def _selects(cfg: ModelConfig, extent: int) -> bool:
+    """Whether a program whose page table (or prompt bucket) addresses
+    `extent` tokens runs the selection. At most index_topk tokens: every
+    token would be selected, so it takes today's kernels with the V pool
+    (the indexer's keys) kept away from them."""
+    return cfg.is_dsa and extent > cfg.index_topk
+
+
+def _dsa_rows(q: DsaQuery, rows) -> DsaQuery:
+    """The query's three parts at `rows` (a slice, or an index array)."""
+    return DsaQuery(*(x[rows] for x in q))
+
+
+def _dense_qv(cfg: ModelConfig, q, vp):
+    """(query, V pool) as today's attention ops take them: an indexed
+    model's absorbed query alone, and None for V (its V pool holds the
+    indexer's keys; attention reads V from the K rows)."""
+    return (q.q, None) if cfg.is_dsa else (q, vp)
+
+
+# decode slots the selection runs over: the smallest of these row counts
+# that holds the live slots, else the whole batch. Measured alone on a v5e
+# (PERF.md section 6, PR 32): a layer's dsa_decode_attention takes 7.7 ms
+# over 64 slots and 0.74 ms over 8, whatever is live, because the index
+# keys of EVERY slot's whole page table are gathered and scored.
+_LIVE_RUNGS = (8, 32)
+
+
+def _dsa_live_plan(block_tables: jax.Array):
+    """Once a step, outside the layer scan: (rung index, slot order with
+    the live slots first) for `_dsa_decode_rows`, or None where the batch
+    is no larger than the smallest rung."""
+    b = block_tables.shape[0]
+    rungs = [r for r in _LIVE_RUNGS if r < b]
+    if not rungs:
+        return None
+    live = _live_slots(block_tables)
+    n_live = jnp.sum(live.astype(jnp.int32))
+    order = jnp.argsort(~live, stable=True).astype(jnp.int32)
+    which = sum((n_live > r).astype(jnp.int32) for r in rungs)
+    return which, order
+
+
+def _dsa_decode_rows(cfg: ModelConfig, q: DsaQuery, kp, vp, tables,
+                     context_lens, plan, page_size: int) -> jax.Array:
+    """Decode rows' selection and sparse attention over the live slots
+    only: `jax.lax.switch` on the device to the smallest rung that holds
+    them (the rows gathered, their outputs scattered back, zeros for the
+    slots left out: they hold nothing and the engine discards their
+    logits); the last rung is the whole batch, the program without the
+    ladder. The pools are read where they lie."""
+    def whole(q, tables, ctx):
+        return att.dsa_decode_attention(
+            *q, kp, vp, tables, ctx, page_size=page_size,
+            topk=cfg.index_topk)
+
+    if plan is None:
+        return whole(q, tables, context_lens)
+    which, order = plan
+    b = tables.shape[0]
+
+    def rung(r):
+        def run(q, tables, ctx):
+            rows = order[:r]
+            o = whole(_dsa_rows(q, rows), tables[rows], ctx[rows])
+            return jnp.zeros((b,) + o.shape[1:], o.dtype).at[rows].set(o)
+        return run
+
+    branches = [rung(r) for r in _LIVE_RUNGS if r < b] + [whole]
+    return jax.lax.switch(which, branches, q, tables, context_lens)
+
+
+def _no_speculation_under_selection(cfg: ModelConfig) -> None:
+    if cfg.is_dsa:
+        raise NotImplementedError(
+            "speculative verify windows under the sparse selection are not "
+            "implemented: each draft position needs its own selected rows "
+            "(ops/attention.dsa_*); serve this model without speculation")
 
 
 def _attn_out(cfg: ModelConfig, lp: Params, o: jax.Array,
@@ -557,7 +698,8 @@ def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
             renormalize=cfg.norm_topk_prob,
             scaling_factor=cfg.routed_scaling_factor,
             scoring=cfg.moe_scoring,
-            select_bias=lp.get("router_bias"))
+            select_bias=lp.get("router_bias"),
+            n_group=cfg.n_group, topk_group=cfg.topk_group)
     t = x.shape[0]
     capacity = 0
     if allow_capacity and cfg.moe_capacity_factor > 0:
@@ -638,14 +780,25 @@ def prefill(
                        rope=_layer_rope(cfg, page_off,
                                         k_pages.shape[1]),
                        lora_slots=slots)
-        o = att.prefill_attention(
-            q, k, v, seq_len,
-            **_attn_kwargs(cfg, page_off, k_pages.shape[1]))
+        if cfg.is_dsa:
+            kp, vp = att.write_kv_prefill(
+                kp, vp, k, v, pages + page_off, page_size=page_size)
+            if _selects(cfg, s):
+                o = att.dsa_chunk_attention(
+                    *q, kp, vp, pages + page_off, 0, page_size=page_size,
+                    topk=cfg.index_topk)
+            else:
+                o = att.prefill_attention(q.q, k, k, seq_len)
+        else:
+            o = att.prefill_attention(
+                q, k, v, seq_len,
+                **_attn_kwargs(cfg, page_off, k_pages.shape[1]))
         x = x + _post(cfg, lp, "post_attn_norm",
                       _attn_out(cfg, lp, o, lora_slots=slots))
-        kp, vp = att.write_kv_prefill(
-            kp, vp, k, v, pages + page_off, page_size=page_size
-        )
+        if not cfg.is_dsa:
+            kp, vp = att.write_kv_prefill(
+                kp, vp, k, v, pages + page_off, page_size=page_size
+            )
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
         y, counts = _mlp(cfg, lp, h,
                          token_mask=token_mask, allow_capacity=True)
@@ -705,11 +858,17 @@ def prefill_chunk(
         kp, vp = att.write_kv_prefill(
             kp, vp, k, v, chunk_pages + page_off, page_size=page_size
         )
-        o = att.chunk_attention(
-            q, kp, vp, pages + page_off, start, page_size=page_size,
-            num_kv_heads=cfg.cache_kv_heads,
-            **_attn_kwargs(cfg, page_off, k_pages.shape[1]),
-        )
+        if _selects(cfg, pages.shape[0] * page_size):
+            o = att.dsa_chunk_attention(
+                *q, kp, vp, pages + page_off, start, page_size=page_size,
+                topk=cfg.index_topk)
+        else:
+            qd, vd = _dense_qv(cfg, q, vp)
+            o = att.chunk_attention(
+                qd, kp, vd, pages + page_off, start, page_size=page_size,
+                num_kv_heads=cfg.cache_kv_heads,
+                **_attn_kwargs(cfg, page_off, k_pages.shape[1]),
+            )
         x = x + _post(cfg, lp, "post_attn_norm",
                       _attn_out(cfg, lp, o, lora_slots=slots))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
@@ -768,21 +927,42 @@ def prefill_batch(
                                         k_pages.shape[1]),
                        lora_slots=slots)  # [N*S,...]
         akw = _attn_kwargs(cfg, page_off, k_pages.shape[1])
-        o = jax.vmap(
-            lambda qq, kk, vv, sl: att.prefill_attention(
-                qq, kk, vv, sl, **akw)
-        )(
-            q.reshape(n, s, *q.shape[1:]),
-            k.reshape(n, s, *k.shape[1:]),
-            v.reshape(n, s, *v.shape[1:]),
-            seq_lens,
-        )
+
+        def lanes(a):
+            return a.reshape(n, s, *a.shape[1:])
+
+        if cfg.is_dsa:
+            kp, vp = att.write_kv_prefill(
+                kp, vp, k, v, pages.reshape(-1) + page_off,
+                page_size=page_size)
+            if _selects(cfg, s):
+                o = jax.vmap(
+                    lambda qq, pg: att.dsa_chunk_attention(
+                        *qq, kp, vp, pg + page_off, 0, page_size=page_size,
+                        topk=cfg.index_topk)
+                )(DsaQuery(*(lanes(a) for a in q)), pages)
+            else:
+                o = jax.vmap(
+                    lambda qq, kk, sl: att.prefill_attention(qq, kk, kk, sl)
+                )(lanes(q.q), lanes(k), seq_lens)
+        else:
+            o = jax.vmap(
+                lambda qq, kk, vv, sl: att.prefill_attention(
+                    qq, kk, vv, sl, **akw)
+            )(
+                q.reshape(n, s, *q.shape[1:]),
+                k.reshape(n, s, *k.shape[1:]),
+                v.reshape(n, s, *v.shape[1:]),
+                seq_lens,
+            )
         x = x + _post(cfg, lp, "post_attn_norm",
                   _attn_out(cfg, lp, o.reshape(n * s, *o.shape[2:]),
                             lora_slots=slots))
-        kp, vp = att.write_kv_prefill(
-            kp, vp, k, v, pages.reshape(-1) + page_off, page_size=page_size
-        )
+        if not cfg.is_dsa:
+            kp, vp = att.write_kv_prefill(
+                kp, vp, k, v, pages.reshape(-1) + page_off,
+                page_size=page_size
+            )
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
         y, counts = _mlp(cfg, lp, h,
                          token_mask=token_mask, allow_capacity=True)
@@ -842,6 +1022,7 @@ def decode_verify(
     page and behave as a plain decode step for position 0; the engine
     forces their acceptance count to zero.
     """
+    _no_speculation_under_selection(cfg)
     b, k1 = tokens.shape
     pos2 = positions[:, None] + jnp.arange(k1)[None, :]  # [B, K1]
     flat_pos = pos2.reshape(b * k1)
@@ -911,6 +1092,7 @@ def decode_step(
     # Pallas kernel is handed context 0 there and does nothing for the
     # slot. Once, outside the layer scan.
     kernel_lens = jnp.where(_live_slots(block_tables), context_lens, 0)
+    dsa_plan = _dsa_live_plan(block_tables) if cfg.is_dsa else None
 
     def body(x, kp, vp, lp, page_off):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
@@ -922,11 +1104,16 @@ def decode_step(
         kp, vp = att.write_kv_token(
             kp, vp, k, v, tables, positions, page_size=page_size
         )
-        o = att.paged_attention_decode(
-            q, kp, vp, tables, context_lens, page_size=page_size,
-            num_kv_heads=cfg.cache_kv_heads, kernel_lens=kernel_lens,
-            **_attn_kwargs(cfg, page_off, k_pages.shape[1]),
-        )
+        if _selects(cfg, block_tables.shape[1] * page_size):
+            o = _dsa_decode_rows(cfg, q, kp, vp, tables, context_lens,
+                                 dsa_plan, page_size)
+        else:
+            qd, vd = _dense_qv(cfg, q, vp)
+            o = att.paged_attention_decode(
+                qd, kp, vd, tables, context_lens, page_size=page_size,
+                num_kv_heads=cfg.cache_kv_heads, kernel_lens=kernel_lens,
+                **_attn_kwargs(cfg, page_off, k_pages.shape[1]),
+            )
         x = x + _post(cfg, lp, "post_attn_norm",
                       _attn_out(cfg, lp, o, lora_slots=slots))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
@@ -1004,6 +1191,7 @@ def mixed_step(
             [adapter_slots.astype(jnp.int32),
              jnp.full((c,), ca, jnp.int32)])
     x = _embed_rows(cfg, params, jnp.concatenate([tokens, chunk_tokens]))
+    dsa_plan = _dsa_live_plan(block_tables) if cfg.is_dsa else None
 
     def body(x, kp, vp, lp, page_off):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
@@ -1019,12 +1207,25 @@ def mixed_step(
             kp, vp, k[b:], v[b:], write_pages + page_off,
             page_size=page_size
         )
-        o = att.ragged_mixed_attention(
-            q, kp, vp, tables, context_lens, chunk_pages + page_off,
-            chunk_start, page_size=page_size,
-            num_kv_heads=cfg.cache_kv_heads, num_decode=b,
-            **_attn_kwargs(cfg, page_off, k_pages.shape[1]),
-        )
+        if _selects(cfg, max(block_tables.shape[1], chunk_pages.shape[0])
+                    * page_size):
+            # decode rows and the chunk's rows each select their own rows
+            o = jnp.concatenate([
+                _dsa_decode_rows(cfg, _dsa_rows(q, slice(0, b)), kp, vp, tables,
+                                 context_lens, dsa_plan, page_size),
+                att.dsa_chunk_attention(
+                    *_dsa_rows(q, slice(b, b + c)), kp, vp,
+                    chunk_pages + page_off,
+                    chunk_start, page_size=page_size, topk=cfg.index_topk),
+            ])
+        else:
+            qd, vd = _dense_qv(cfg, q, vp)
+            o = att.ragged_mixed_attention(
+                qd, kp, vd, tables, context_lens, chunk_pages + page_off,
+                chunk_start, page_size=page_size,
+                num_kv_heads=cfg.cache_kv_heads, num_decode=b,
+                **_attn_kwargs(cfg, page_off, k_pages.shape[1]),
+            )
         x = x + _post(cfg, lp, "post_attn_norm",
                       _attn_out(cfg, lp, o, lora_slots=slots))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
@@ -1082,6 +1283,7 @@ def mixed_verify_step(
     chunk-page scatter. MoE rows use dense dispatch for identity, as in
     mixed_step.
     """
+    _no_speculation_under_selection(cfg)
     b, k1 = tokens.shape
     c = chunk_tokens.shape[0]
     n = b * k1

@@ -283,6 +283,28 @@ def _load_layers(cfg: ModelConfig, out: Dict[str, jax.Array], g, has, to_dt,
             "model.layers.{i}.self_attn.o_proj.weight",
             lambda w: to_dt(w).T.reshape(h, vd, e),
         )
+        if cfg.is_dsa:
+            # the lightning indexer (DeepSeek-V3.2): self_attn.indexer.*.
+            # Its rotary lanes are the FIRST `rope` of each head; ASSUMED
+            # interleaved in the checkpoint like the attention's, so the
+            # same de-interleave is folded into the outputs that turn
+            # (and into the LayerNorm's weight and bias, whose lanes they
+            # are). No checkpoint was at hand to hold this against.
+            hi, di = cfg.index_n_heads, cfg.index_head_dim
+            lanes = np.concatenate([deint, np.arange(rope, di)])
+            base = "model.layers.{i}.self_attn.indexer."
+            p["idx_wq_b"] = stack(
+                base + "wq_b.weight",
+                lambda w: to_dt(w).T.reshape(cfg.q_lora_rank, hi, di)[
+                    ..., lanes])
+            p["idx_wk"] = stack(base + "wk.weight",
+                                lambda w: to_dt(w).T[..., lanes])
+            p["idx_k_norm"] = stack(base + "k_norm.weight",
+                                    lambda w: to_dt(w)[lanes])
+            p["idx_k_bias"] = stack(base + "k_norm.bias",
+                                    lambda w: to_dt(w)[lanes])
+            p["idx_w"] = stack(base + "weights_proj.weight",
+                               lambda w: to_dt(w).T)
     elif has(f"model.layers.{l0}.self_attn.qkv_proj.weight"):
         # Phi-3 fuses q/k/v rows into one projection: [(H+2KV)*D, E] with
         # q first, then k, then v (same split in HF's Phi3Attention);

@@ -72,6 +72,10 @@ QUANT_AXES: Dict[str, Tuple[int, ...]] = {
     "wq_a": (1,),     # [L, E, q_lora] query low-rank path (Kimi-K2 / V3)
     "wq_b": (1,),     # [L, q_lora, H, nope+rope]
     "w_kv_a": (1,),   # [L, E, lora+rope]
+    # the sparse-attention indexer's two larger projections (its head
+    # weights idx_w, its LayerNorm and W_UK / W_UV stay in the model dtype)
+    "idx_wq_b": (1,),  # [L, q_lora, Hi, Di]
+    "idx_wk": (1,),    # [L, E, Di]
     "w_gate": (1,),  # [L, E, F]
     "w_up": (1,),
     "w_down": (1,),  # [L, F, E]

@@ -193,6 +193,16 @@ class MemoryAccountant:
 
         return {
             "page_bytes": pb,
+            "bytes_per_token": eng.kv_spec.bytes_per_token(),
+            # the row kinds a page holds, lanes a token a layer: the K/V or
+            # latent row, and the V pool's (a classic model's V row, an
+            # indexed MLA model's indexer key, nothing for plain MLA)
+            "row_lanes": {"k_pool": eng.kv_spec.lane_width,
+                          "v_pool": eng.kv_spec.v_lane_width,
+                          "v_pool_holds": (
+                              "indexer_key" if eng.kv_spec.index_lanes
+                              else "none" if eng.kv_spec.v_from_k
+                              else "value")},
             "kv_dtype": eng.kv_spec.dtype,
             "pool": {
                 "total_pages": total_pages,

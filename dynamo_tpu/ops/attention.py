@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import os
 from typing import Optional
 
@@ -237,7 +238,10 @@ def shared_kv(v_pages) -> bool:
     lanes and nothing is written to it. The compositions here gather K
     alone; the paged kernels copy K alone and use the block they hold for
     both products (their unused V operand is the K pool again: an HBM
-    reference, nothing is moved)."""
+    reference, nothing is moved). An MLA model whose V pool holds the
+    sparse-attention indexer's key rows (KVCacheSpec.index_lanes) says so
+    by handing the attention ops None for V: models/llama.py decides from
+    the model's config, not from the pool's lane count."""
     return v_pages is None or v_pages.shape[-1] == 0
 
 
@@ -265,7 +269,7 @@ def write_kv_token(
         if k_pages.dtype == jnp.int8:
             return pack_kv_rows(new, k_pages.shape[-1],
                                 lane_blocks=_kv_lane_blocks())
-        return new.reshape(b, kv * d)
+        return new.reshape(b, -1)
 
     # advanced indexing over (page, slot) pairs -> rows of [lane_width]
     k_pages = k_pages.at[page_idx, slot_idx, :].set(rows(k_new), mode="drop")
@@ -292,7 +296,7 @@ def write_kv_prefill(
             w = k_pages.shape[-1]
             return pack_kv_rows(new, w, lane_blocks=_kv_lane_blocks()
                                 ).reshape(n_pages, page_size, w)
-        return new.reshape(n_pages, page_size, kv * d)
+        return new.reshape(n_pages, page_size, -1)
 
     k_pages = k_pages.at[pages].set(rows(k_new), mode="drop")
     if not shared_kv(v_pages):
@@ -772,6 +776,145 @@ def verify_attention(
     scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqs,bshd->bqhd", probs, v)
+
+
+# ------------------------------------------- learned sparse selection --
+# DeepSeek-V3.2's sparse attention over an MLA cache (models/llama.py
+# `_dsa_index` builds the operands): a lightning indexer scores every cached
+# token of a query's sequence against the query,
+#     I[t, s] = sum_j w[t, j] * relu(q_idx[t, j] . k_idx[s]),
+# the `topk` best-scoring tokens s <= t are selected, EXACTLY (jax.lax.top_k:
+# ties go to the lower position), and the query's absorbed-form attention
+# runs over those rows alone, gathered token by token through the page
+# table. The indexer's keys live in the V pool an MLA model leaves empty
+# (`idx_pages`), under the same page ids as the latent rows (`k_pages`).
+# Plain XLA compositions: a kernel of their own is ROADMAP's.
+# A program whose page table cannot address more than `topk` tokens never
+# comes here (every token would be selected): the callers in models/llama.py
+# decide that from shapes and keep today's kernels there.
+
+# tests set this to a callable(kind, qpos, sel, valid): the selection of
+# every traced call is handed to it (jax.debug.callback, in program order).
+# None outside tests: no program carries a callback.
+DSA_TAP = None
+
+
+def _dsa_scores(q_idx: jax.Array, w_idx: jax.Array, keys: jax.Array,
+                spec: str) -> jax.Array:
+    """Index scores, float32. q_idx [.., Hi, Di], w_idx [.., Hi] float32,
+    keys [.., S, Di]; `spec` is the einsum of the q . k products with the
+    index heads kept as axis -2 of the result."""
+    dots = jnp.einsum(spec, q_idx, keys,
+                      preferred_element_type=jnp.float32)
+    return jnp.sum(jax.nn.relu(dots) * w_idx[..., None], axis=-2)
+
+
+def _dsa_select(scores: jax.Array, topk: int, kind: str, qpos):
+    """scores [N, S] float32, -inf where a key may not be seen -> (sel
+    [N, K] positions, valid [N, K]), K = min(topk, S). Exact: the K
+    largest, ties to the lower position; a query with fewer than K visible
+    keys gets them all and the rest flagged invalid."""
+    with jax.named_scope("dsa_select"):
+        vals, sel = jax.lax.top_k(scores, min(topk, scores.shape[-1]))
+        valid = vals > -jnp.inf
+    if DSA_TAP is not None:
+        jax.debug.callback(functools.partial(DSA_TAP, kind), qpos, sel,
+                           valid, ordered=True)
+    return sel, valid
+
+
+def _dsa_attend(q: jax.Array, rows: jax.Array, valid: jax.Array
+                ) -> jax.Array:
+    """q [N, H, D] over each query's own gathered rows [N, K, D] (K and V
+    both: the latent row) -> [N, H, D]. The generic ops' 1/sqrt(D) scale."""
+    scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
+    sc = jnp.einsum("nhd,nkd->nhk", q * scale, rows,
+                    preferred_element_type=jnp.float32)
+    sc = jnp.where(valid[:, None, :], sc, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(sc, axis=-1).astype(q.dtype)
+    return jnp.einsum("nhk,nkd->nhd", probs, rows)
+
+
+def _gather_rows(k_pages: jax.Array, tables: jax.Array, sel: jax.Array,
+                 page_size: int) -> jax.Array:
+    """Token-granular gather: row `sel[n, j]` of sequence n's page table
+    `tables[n]` -> [N, K, D]."""
+    page = jnp.take_along_axis(tables, sel // page_size, axis=1)
+    return k_pages[page, sel % page_size]
+
+
+def dsa_decode_attention(
+    q: jax.Array,  # [B, H, D] absorbed queries
+    q_idx: jax.Array,  # [B, Hi, Di] indexer queries
+    w_idx: jax.Array,  # [B, Hi] float32 head weights
+    k_pages: jax.Array,  # [P, ps, D] latent rows
+    idx_pages: jax.Array,  # [P, ps, Di] indexer keys
+    block_table: jax.Array,  # [B, Pmax]
+    context_lens: jax.Array,  # [B]
+    *,
+    page_size: int,
+    topk: int,
+) -> jax.Array:
+    """One decode token a sequence: score the sequence's cached index keys,
+    select, attend over the selected latent rows. An empty slot (context 1
+    on the trash page) selects that one row, as the dense twin does."""
+    b, pmax = block_table.shape
+    s = pmax * page_size
+    with jax.named_scope("dsa_indexer"):
+        keys = idx_pages[block_table].reshape(b, s, idx_pages.shape[-1])
+        scores = _dsa_scores(q_idx, w_idx, keys, "bhd,bsd->bhs")
+        scores = jnp.where(jnp.arange(s)[None, :] < context_lens[:, None],
+                           scores, -jnp.inf)
+    sel, valid = _dsa_select(scores, topk, "decode", context_lens - 1)
+    with jax.named_scope("dsa_sparse_attn"):
+        rows = _gather_rows(k_pages, block_table, sel, page_size)
+        return _dsa_attend(q, rows, valid)
+
+
+def dsa_chunk_attention(
+    q: jax.Array,  # [C, H, D] one sequence's consecutive queries
+    q_idx: jax.Array,  # [C, Hi, Di]
+    w_idx: jax.Array,  # [C, Hi] float32
+    k_pages: jax.Array,
+    idx_pages: jax.Array,
+    pages: jax.Array,  # [Wp] page ids of the sequence (trash-padded tail)
+    start,  # scalar int32: absolute position of q[0]
+    *,
+    page_size: int,
+    topk: int,
+    block_q: int = 32,
+) -> jax.Array:
+    """A chunk's queries, each with its own selection among the positions
+    at or before its own (the chunk's rows are already written). A block
+    of `block_q` queries at a time: the float32 index products of a whole
+    256-token chunk against 32k keys would be 2 GB."""
+    c = q.shape[0]
+    s = pages.shape[0] * page_size
+    block_q = max(1, min(block_q, c))
+    while c % block_q:
+        block_q //= 2
+    with jax.named_scope("dsa_indexer"):
+        keys = idx_pages[pages].reshape(s, idx_pages.shape[-1])
+    qpos = jnp.asarray(start, jnp.int32) + jnp.arange(c, dtype=jnp.int32)
+    tables = jnp.broadcast_to(pages[None, :], (block_q, pages.shape[0]))
+
+    def block(args):
+        qb, qib, wb, pos = args
+        with jax.named_scope("dsa_indexer"):
+            scores = _dsa_scores(qib, wb, keys, "qhd,sd->qhs")
+            scores = jnp.where(jnp.arange(s)[None, :] <= pos[:, None],
+                               scores, -jnp.inf)
+        sel, valid = _dsa_select(scores, topk, "chunk", pos)
+        with jax.named_scope("dsa_sparse_attn"):
+            rows = _gather_rows(k_pages, tables, sel, page_size)
+            return _dsa_attend(qb, rows, valid)
+
+    def blocks(x):
+        return x.reshape((c // block_q, block_q) + x.shape[1:])
+
+    out = jax.lax.map(block, (blocks(q), blocks(q_idx), blocks(w_idx),
+                              blocks(qpos)))
+    return out.reshape(q.shape)
 
 
 # --------------------------------------------------------------- dispatch --

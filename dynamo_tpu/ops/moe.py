@@ -51,7 +51,8 @@ def route_topk(logits: jax.Array, k: int,
                renormalize: bool = True,
                scaling_factor: float = 1.0,
                scoring: str = "softmax",
-               select_bias: jax.Array | None = None):
+               select_bias: jax.Array | None = None,
+               n_group: int = 1, topk_group: int = 1):
     """Router logits [T, X] (float32) -> (expert ids [T, k], weights
     [T, k] float32).
 
@@ -59,14 +60,35 @@ def route_topk(logits: jax.Array, k: int,
     selected top-k logits, weights sum to 1. renormalize=False (DeepSeek-V2
     norm_topk_prob=false): the GLOBAL softmax probabilities of the selected
     experts, sum < 1. scoring="sigmoid" (DeepSeek-V3 / Kimi-K2, HF
-    topk_method noaux_tc at n_group 1): s = sigmoid(logits); the k largest
+    topk_method noaux_tc): s = sigmoid(logits); the k largest
     of s + select_bias are picked — the bias moves the PICK only — and the
     weights are the picked s, divided by their sum + 1e-20 when
-    renormalize. Either way times scaling_factor."""
+    renormalize. Either way times scaling_factor.
+
+    n_group > 1 (DeepSeek-V3 / V3.2; sigmoid only) limits the pick to
+    groups: the X outputs are n_group groups of X / n_group consecutive
+    experts, a group scores the sum of its 2 largest s + select_bias, the
+    topk_group best groups are kept (ties to the lower group, as top_k
+    has it) and the k picks come from the kept groups alone. n_group = 1
+    traces none of this: the program is the ungrouped one."""
+    if n_group > 1 and scoring != "sigmoid":
+        raise ValueError("group-limited routing is the sigmoid router's")
     if scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         choose = scores if select_bias is None else (
             scores + select_bias.astype(scores.dtype))
+        if n_group > 1:
+            t, x = choose.shape
+            per = choose.reshape(t, n_group, x // n_group)
+            group_score = jnp.sum(jax.lax.top_k(per, 2)[0], axis=-1)
+            _, kept = jax.lax.top_k(group_score, topk_group)  # [T, kept]
+            keep = jnp.zeros((t, n_group), bool).at[
+                jnp.arange(t)[:, None], kept].set(True)
+            # the published code fills the other groups' scores with 0.0;
+            # -inf keeps its picks whatever the bias's sign (the kept
+            # groups hold >= k experts: ModelConfig checks)
+            choose = jnp.where(jnp.repeat(keep, x // n_group, axis=1),
+                               choose, -jnp.inf)
         _, topi = jax.lax.top_k(choose, k)
         weights = jnp.take_along_axis(scores, topi, axis=-1)
         if renormalize:

@@ -45,6 +45,13 @@ PARAM_RULES: Dict[str, P] = {
     "kv_a_norm": P(None, None),
     "w_uk": P(None, "model", None, None),     # [L, H, nope, lora]
     "w_uv": P(None, "model", None, None),     # [L, H, lora, v]
+    # the sparse-attention indexer (DeepSeek-V3.2) replicates: every shard
+    # needs every index head's score to make the same selection
+    "idx_wq_b": P(None, None, None, None),    # [L, q_lora, Hi, Di]
+    "idx_wk": P(None, None, None),            # [L, E, Di]
+    "idx_k_norm": P(None, None),
+    "idx_k_bias": P(None, None),
+    "idx_w": P(None, None, None),             # [L, E, Hi]
     # dense MLP
     "mlp_norm": P(None, None),
     "w_gate": P(None, None, "model"),  # [L, E, F] column-parallel

@@ -51,6 +51,11 @@ def param_count(cfg: ModelConfig) -> float:
                 + nh * nope * lora          # W_UK
                 + nh * lora * vd            # W_UV
                 + nh * vd * h)              # output projection
+        if cfg.is_dsa:
+            # the indexer: queries from the q-LoRA latent, one key a token
+            # (with its LayerNorm's weight and bias), a weight a head
+            hi, di = cfg.index_n_heads, cfg.index_head_dim
+            attn += qr * hi * di + h * di + 2 * di + h * hi
     else:
         attn = (h * cfg.num_heads * hd + 2 * h * cfg.num_kv_heads * hd
                 + cfg.num_heads * hd * h)
@@ -106,6 +111,30 @@ def mla_attention_cost(cfg: ModelConfig, kv_rows_read: float,
             "bytes": kv_rows_read * lanes * BYTES}
 
 
+def dsa_indexer_cost(cfg: ModelConfig, keys_scored: float) -> dict:
+    """Operations and bytes of the sparse-attention indexer's scores
+    (ops/attention.dsa_*) for `keys_scored` (query, key) pairs: every index
+    head's product over index_head_dim lanes, and each query's keys read
+    once as bf16 rows. No sharing between sequences, or between the
+    queries of a chunk, is assumed: a chunk's queries read the same keys,
+    so a kernel that shares them can pass this count's time. The chip
+    benchmark's kernel_costs/dsa_indexer.py counts the same."""
+    return {"ops": keys_scored * 2 * cfg.index_n_heads * cfg.index_head_dim,
+            "bytes": keys_scored * cfg.index_head_dim * BYTES}
+
+
+def dsa_sparse_attention_cost(cfg: ModelConfig, rows_selected: float) -> dict:
+    """Operations and bytes of absorbed-form MLA over the selected rows
+    alone: each selected row is read once as one cached row
+    (cache_head_dim bf16 lanes: the gather moves whole rows) and meets
+    every head's scores and averages. The chip benchmark's
+    kernel_costs/dsa_sparse_attention.py counts the same."""
+    lanes = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+    return {"ops": rows_selected * cfg.num_heads * 2
+            * (lanes + cfg.kv_lora_rank),
+            "bytes": rows_selected * cfg.cache_head_dim * BYTES}
+
+
 def attention_flops_per_pair(cfg: ModelConfig) -> float:
     """Operations one (query token, key token) pair costs over all heads:
     scores and the weighted average. MLA in the absorbed form (the one the
@@ -142,7 +171,8 @@ def kv_bytes_per_token(cfg: ModelConfig, kv_dtype: str = "auto",
         kv_l = max(kv_heads // max(tp, 1), 1)
         block = -(-(kv_l * head_dim + 2 * kv_l) // 128) * 128
         return pools * cfg.num_layers * max(tp, 1) * block
-    return pools * cfg.num_layers * lanes * BYTES
+    # an indexed model's second row kind: the indexer's key, same pages
+    return cfg.num_layers * (pools * lanes + cfg.cache_index_dim) * BYTES
 
 
 # Serving quantization tiers the engine implements (`--quantization`,

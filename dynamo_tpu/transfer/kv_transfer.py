@@ -105,6 +105,9 @@ class KVSource:
                 "n_tokens": n_tokens,
                 "dtype": _dtype_name(k),
                 "shape": list(k.shape),
+                # the V pool's rows have a width of their own for an MLA
+                # model (none, or the sparse-attention indexer's keys)
+                "v_shape": list(v.shape),
             }
             conn.send_msg(json.dumps(header).encode())
             conn.send_msg(_tobytes(k))
@@ -133,7 +136,8 @@ def fetch_kv(host: str, port: int, request_id: str
         if "error" in header:
             raise KeyError(f"prefill side: {header['error']}")
         k = _frombytes(conn.recv_msg(), header["dtype"], header["shape"])
-        v = _frombytes(conn.recv_msg(), header["dtype"], header["shape"])
+        v = _frombytes(conn.recv_msg(), header["dtype"],
+                       header.get("v_shape", header["shape"]))
         conn.send_msg(b"OK")
         return k, v, header["n_tokens"]
     finally:
@@ -202,6 +206,7 @@ class HostTierSource:
             header = {"found": len(blocks)}
             if blocks:
                 header["shape"] = list(blocks[0][0].shape)
+                header["v_shape"] = list(blocks[0][1].shape)
                 header["dtype"] = _dtype_name(blocks[0][0])
             conn.send_msg(json.dumps(header).encode())
             for k, v in blocks:
@@ -224,7 +229,8 @@ def fetch_host_blocks(host: str, port: int, hashes_hex
         out = []
         for _ in range(int(header.get("found", 0))):
             k = _frombytes(conn.recv_msg(), header["dtype"], header["shape"])
-            v = _frombytes(conn.recv_msg(), header["dtype"], header["shape"])
+            v = _frombytes(conn.recv_msg(), header["dtype"],
+                           header.get("v_shape", header["shape"]))
             out.append((k, v))
         return out
     finally:

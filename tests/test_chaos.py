@@ -285,12 +285,18 @@ def test_deadline_header_reaches_worker(stack):
                 headers={"x-deadline": "33.5"}, raw=True)
     resp.read()
     trace_id = resp.headers.get("X-Request-Id")
-    spans = json.loads(urllib.request.urlopen(
-        stack["worker"] + f"/debug/spans?trace_id={trace_id}",
-        timeout=10).read())
-    worker_spans = [sp for rs in spans["resourceSpans"]
-                    for ss in rs["scopeSpans"] for sp in ss["spans"]
-                    if sp["name"] == "worker.request"]
+    # the worker ends its request span after the last frame is written:
+    # under load the client can ask before that, so poll briefly
+    for _ in range(50):
+        spans = json.loads(urllib.request.urlopen(
+            stack["worker"] + f"/debug/spans?trace_id={trace_id}",
+            timeout=10).read())
+        worker_spans = [sp for rs in spans["resourceSpans"]
+                        for ss in rs["scopeSpans"] for sp in ss["spans"]
+                        if sp["name"] == "worker.request"]
+        if worker_spans:
+            break
+        time.sleep(0.1)
     assert worker_spans, "worker.request span missing"
     attrs = {a["key"]: a["value"] for a in worker_spans[-1]["attributes"]}
     got = float(attrs["deadline_s"].get("doubleValue")

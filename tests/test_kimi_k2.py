@@ -122,7 +122,7 @@ def test_share_keys_are_read():
 
 
 @pytest.mark.parametrize("key, value", [
-    ("n_group", 8), ("topk_group", 4), ("num_nextn_predict_layers", 1),
+    ("n_group", 7), ("topk_group", 4), ("num_nextn_predict_layers", 1),
     ("moe_layer_freq", 2), ("scoring_func", "tanh"),
     ("topk_method", "top_p")])
 def test_unimplemented_keys_raise(key, value):

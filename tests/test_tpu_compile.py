@@ -223,3 +223,36 @@ def test_decode_dispatch_compiles_head_parallel_on_four_chips(topo):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" not in text and "all-reduce" not in text
+
+
+@pytest.mark.parametrize("slots", [8, 64])
+def test_sparse_selection_compiles_for_v5e_at_cell_shapes(one_chip, slots):
+    """The DeepSeek-V3.2 cell's decode selection (PR 32; plain XLA:
+    ops/attention.dsa_decode_attention) at a rung of 8 live slots and at
+    the whole 64-slot batch, 128 heads on 640-lane rows, a 2,048-page table,
+    2,048 rows kept: compiles for a described v5e, keeps `jax.lax.top_k` a
+    sort over the [slots, 32768] scores (the trace finds the selection by
+    that shape: layer_metrics/dsa_select_busy_pct.docqa.json), and its
+    scratch stays under a tenth of the chip (the index keys and float32
+    products of every slot's whole table are the intermediates a kernel of
+    its own would not need: ROADMAP, Reach)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops import attention as att
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages, ps, pmax = 8192 * 9, 16, 2048
+    compiled = jax.jit(
+        lambda *a: att.dsa_decode_attention(*a, page_size=ps, topk=2048)
+    ).lower(
+        arg((slots, 128, 640), jnp.bfloat16),
+        arg((slots, 64, 128), jnp.bfloat16), arg((slots, 64), jnp.float32),
+        arg((pages, ps, 640), jnp.bfloat16),
+        arg((pages, ps, 128), jnp.bfloat16),
+        arg((slots, pmax), jnp.int32), arg((slots,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert f"f32[{slots},32768]" in text and "sort(" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9

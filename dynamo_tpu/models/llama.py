@@ -562,6 +562,15 @@ def _selects(cfg: ModelConfig, extent: int) -> bool:
     return cfg.is_dsa and extent > cfg.index_topk
 
 
+def _dsa_kwargs(cfg: ModelConfig, page_off, pages_per_layer: int,
+                page_size: int) -> dict:
+    """What ops/attention.dsa_* take beside the operands: the layer's slice
+    of the flat pool is named so that the selecting sort can carry a page
+    id of `pages_per_layer`'s width, not of the whole pool's."""
+    return dict(page_size=page_size, topk=cfg.index_topk, page_off=page_off,
+                layer_pages=pages_per_layer)
+
+
 def _dsa_rows(q: DsaQuery, rows) -> DsaQuery:
     """The query's three parts at `rows` (a slice, or an index array)."""
     return DsaQuery(*(x[rows] for x in q))
@@ -597,8 +606,8 @@ def _dsa_live_plan(block_tables: jax.Array):
     return which, order
 
 
-def _dsa_decode_rows(cfg: ModelConfig, q: DsaQuery, kp, vp, tables,
-                     context_lens, plan, page_size: int) -> jax.Array:
+def _dsa_decode_rows(q: DsaQuery, kp, vp, tables, context_lens, plan,
+                     **dsa_kw) -> jax.Array:
     """Decode rows' selection and sparse attention over the live slots
     only: `jax.lax.switch` on the device to the smallest rung that holds
     them (the rows gathered, their outputs scattered back, zeros for the
@@ -606,9 +615,7 @@ def _dsa_decode_rows(cfg: ModelConfig, q: DsaQuery, kp, vp, tables,
     logits); the last rung is the whole batch, the program without the
     ladder. The pools are read where they lie."""
     def whole(q, tables, ctx):
-        return att.dsa_decode_attention(
-            *q, kp, vp, tables, ctx, page_size=page_size,
-            topk=cfg.index_topk)
+        return att.dsa_decode_attention(*q, kp, vp, tables, ctx, **dsa_kw)
 
     if plan is None:
         return whole(q, tables, context_lens)
@@ -785,8 +792,9 @@ def prefill(
                 kp, vp, k, v, pages + page_off, page_size=page_size)
             if _selects(cfg, s):
                 o = att.dsa_chunk_attention(
-                    *q, kp, vp, pages + page_off, 0, page_size=page_size,
-                    topk=cfg.index_topk)
+                    *q, kp, vp, pages + page_off, 0,
+                    **_dsa_kwargs(cfg, page_off, k_pages.shape[1],
+                                  page_size))
             else:
                 o = att.prefill_attention(q.q, k, k, seq_len)
         else:
@@ -860,8 +868,8 @@ def prefill_chunk(
         )
         if _selects(cfg, pages.shape[0] * page_size):
             o = att.dsa_chunk_attention(
-                *q, kp, vp, pages + page_off, start, page_size=page_size,
-                topk=cfg.index_topk)
+                *q, kp, vp, pages + page_off, start,
+                **_dsa_kwargs(cfg, page_off, k_pages.shape[1], page_size))
         else:
             qd, vd = _dense_qv(cfg, q, vp)
             o = att.chunk_attention(
@@ -938,8 +946,9 @@ def prefill_batch(
             if _selects(cfg, s):
                 o = jax.vmap(
                     lambda qq, pg: att.dsa_chunk_attention(
-                        *qq, kp, vp, pg + page_off, 0, page_size=page_size,
-                        topk=cfg.index_topk)
+                        *qq, kp, vp, pg + page_off, 0,
+                        **_dsa_kwargs(cfg, page_off, k_pages.shape[1],
+                                      page_size))
                 )(DsaQuery(*(lanes(a) for a in q)), pages)
             else:
                 o = jax.vmap(
@@ -1105,8 +1114,9 @@ def decode_step(
             kp, vp, k, v, tables, positions, page_size=page_size
         )
         if _selects(cfg, block_tables.shape[1] * page_size):
-            o = _dsa_decode_rows(cfg, q, kp, vp, tables, context_lens,
-                                 dsa_plan, page_size)
+            o = _dsa_decode_rows(
+                q, kp, vp, tables, context_lens, dsa_plan,
+                **_dsa_kwargs(cfg, page_off, k_pages.shape[1], page_size))
         else:
             qd, vd = _dense_qv(cfg, q, vp)
             o = att.paged_attention_decode(
@@ -1210,13 +1220,13 @@ def mixed_step(
         if _selects(cfg, max(block_tables.shape[1], chunk_pages.shape[0])
                     * page_size):
             # decode rows and the chunk's rows each select their own rows
+            dsa_kw = _dsa_kwargs(cfg, page_off, k_pages.shape[1], page_size)
             o = jnp.concatenate([
-                _dsa_decode_rows(cfg, _dsa_rows(q, slice(0, b)), kp, vp, tables,
-                                 context_lens, dsa_plan, page_size),
+                _dsa_decode_rows(_dsa_rows(q, slice(0, b)), kp, vp, tables,
+                                 context_lens, dsa_plan, **dsa_kw),
                 att.dsa_chunk_attention(
                     *_dsa_rows(q, slice(b, b + c)), kp, vp,
-                    chunk_pages + page_off,
-                    chunk_start, page_size=page_size, topk=cfg.index_topk),
+                    chunk_pages + page_off, chunk_start, **dsa_kw),
             ])
         else:
             qd, vd = _dense_qv(cfg, q, vp)

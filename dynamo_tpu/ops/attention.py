@@ -783,20 +783,27 @@ def verify_attention(
 # `_dsa_index` builds the operands): a lightning indexer scores every cached
 # token of a query's sequence against the query,
 #     I[t, s] = sum_j w[t, j] * relu(q_idx[t, j] . k_idx[s]),
-# the `topk` best-scoring tokens s <= t are selected, EXACTLY (jax.lax.top_k:
-# ties go to the lower position), and the query's absorbed-form attention
-# runs over those rows alone, gathered token by token through the page
-# table. The indexer's keys live in the V pool an MLA model leaves empty
-# (`idx_pages`), under the same page ids as the latent rows (`k_pages`).
+# the `topk` best-scoring tokens s <= t are selected, EXACTLY (in
+# jax.lax.top_k's order: a total order on the scores, ties to the lower
+# position), and the query's absorbed-form attention runs over those rows
+# alone, gathered token by token from the pool. The sort that selects
+# carries each position's PHYSICAL row with it (`_dsa_row_words`), so the
+# gather needs no page-table lookup (PR 35: that lookup, a scalar gather,
+# cost 7% of the docqa cell's device time). The indexer's keys live in the
+# V pool an MLA model leaves empty (`idx_pages`), under the same page ids as
+# the latent rows (`k_pages`).
 # Plain XLA compositions: a kernel of their own is ROADMAP's.
 # A program whose page table cannot address more than `topk` tokens never
 # comes here (every token would be selected): the callers in models/llama.py
 # decide that from shapes and keep today's kernels there.
 
 # tests set this to a callable(kind, qpos, sel, valid): the selection of
-# every traced call is handed to it (jax.debug.callback, in program order).
-# None outside tests: no program carries a callback.
+# every traced call is handed to it (jax.debug.callback, in program order),
+# `sel` as POSITIONS. None outside tests: no program carries a callback.
 DSA_TAP = None
+
+# bit 30 of the sort's second key: set where the score is -0.0
+_NEG_ZERO = 1 << 30
 
 
 def _dsa_scores(q_idx: jax.Array, w_idx: jax.Array, keys: jax.Array,
@@ -809,18 +816,64 @@ def _dsa_scores(q_idx: jax.Array, w_idx: jax.Array, keys: jax.Array,
     return jnp.sum(jax.nn.relu(dots) * w_idx[..., None], axis=-2)
 
 
-def _dsa_select(scores: jax.Array, topk: int, kind: str, qpos):
-    """scores [N, S] float32, -inf where a key may not be seen -> (sel
-    [N, K] positions, valid [N, K]), K = min(topk, S). Exact: the K
-    largest, ties to the lower position; a query with fewer than K visible
-    keys gets them all and the rest flagged invalid."""
+def _dsa_row_words(pages: jax.Array, page_off, page_size: int,
+                   layer_pages: int):
+    """What the selecting sort carries for every position of a page table:
+    pages [.., Wp] (flat ids inside the layer's slice of the pool, which
+    starts at page `page_off` and holds `layer_pages`) -> (words, unpack).
+    `words` are int32 [.., S]: dense elementwise work, a broadcast of the
+    table over a page's slots and an iota, no gather. The first is the
+    sort's second key and rises with the position, so equal scores keep
+    top_k's order; `unpack(*words)` gives (positions, physical rows of the
+    pool viewed as [P * page_size, D]) of whatever words the sort kept.
+
+    One word where position and page id fit 30 bits together,
+    [position | page - page_off] (the served cell: 15 + 13), so the sort
+    has two operands as top_k's had; else two, [position] and [row]. The
+    TPU compiler gives a STABLE sort whose payload is not an iota a third
+    operand of its own (that iota), which is why stability is not used."""
+    s = pages.shape[-1] * page_size
+    pos = jnp.arange(s, dtype=jnp.int32)
+    page = jnp.broadcast_to(
+        pages[..., None], pages.shape + (page_size,)).reshape(
+            pages.shape[:-1] + (s,))
+    pbits = (layer_pages - 1).bit_length()
+    if (s - 1).bit_length() + pbits <= 30:
+        def unpack(word):
+            sel = (word & (_NEG_ZERO - 1)) >> pbits
+            page = (word & ((1 << pbits) - 1)) + page_off
+            return sel, page * page_size + sel % page_size
+        return ((pos << pbits) | (page - page_off),), unpack
+
+    def unpack(word, rows):
+        return word & (_NEG_ZERO - 1), rows
+    return (jnp.broadcast_to(pos, page.shape),
+            page * page_size + pos % page_size), unpack
+
+
+def _dsa_select(scores: jax.Array, words, unpack, topk: int, kind: str,
+                qpos):
+    """scores [N, S] float32, -inf where a key may not be seen; `words`,
+    `unpack` of `_dsa_row_words` ([S] or [N, S]) -> (rows [N, K] physical
+    rows, valid [N, K]), K = min(topk, S). Exact, and jax.lax.top_k's to
+    the row and its order: the K largest, ties to the lower position; a
+    query with fewer than K visible keys gets them all and the rest (the
+    first masked positions) flagged invalid. One sort of (-score, word):
+    top_k orders +0.0 before -0.0 where jax.lax.sort holds them equal, so
+    a -0.0 score raises a bit above the word's position."""
+    k = min(topk, scores.shape[-1])
     with jax.named_scope("dsa_select"):
-        vals, sel = jax.lax.top_k(scores, min(topk, scores.shape[-1]))
-        valid = vals > -jnp.inf
+        neg_zero = (scores == 0) & jnp.signbit(scores)
+        tie = words[0] | jnp.where(neg_zero, _NEG_ZERO, 0)
+        rest = tuple(jnp.broadcast_to(w, scores.shape) for w in words[1:])
+        key, *kept = jax.lax.sort((-scores, tie) + rest, dimension=1,
+                                  is_stable=False, num_keys=2)
+        valid = key[:, :k] < jnp.inf
+        sel, rows = unpack(*(w[:, :k] for w in kept))
     if DSA_TAP is not None:
         jax.debug.callback(functools.partial(DSA_TAP, kind), qpos, sel,
                            valid, ordered=True)
-    return sel, valid
+    return rows, valid
 
 
 def _dsa_attend(q: jax.Array, rows: jax.Array, valid: jax.Array
@@ -835,12 +888,10 @@ def _dsa_attend(q: jax.Array, rows: jax.Array, valid: jax.Array
     return jnp.einsum("nhk,nkd->nhd", probs, rows)
 
 
-def _gather_rows(k_pages: jax.Array, tables: jax.Array, sel: jax.Array,
-                 page_size: int) -> jax.Array:
-    """Token-granular gather: row `sel[n, j]` of sequence n's page table
-    `tables[n]` -> [N, K, D]."""
-    page = jnp.take_along_axis(tables, sel // page_size, axis=1)
-    return k_pages[page, sel % page_size]
+def _gather_rows(k_pages: jax.Array, rows: jax.Array) -> jax.Array:
+    """Token-granular gather: physical rows [N, K] of the pool viewed as
+    [P * page_size, D] -> [N, K, D]."""
+    return k_pages.reshape((-1, k_pages.shape[-1]))[rows]
 
 
 def dsa_decode_attention(
@@ -854,6 +905,8 @@ def dsa_decode_attention(
     *,
     page_size: int,
     topk: int,
+    page_off=0,  # the table's ids lie in [page_off, page_off + layer_pages)
+    layer_pages=None,  # default: the whole pool
 ) -> jax.Array:
     """One decode token a sequence: score the sequence's cached index keys,
     select, attend over the selected latent rows. An empty slot (context 1
@@ -865,10 +918,12 @@ def dsa_decode_attention(
         scores = _dsa_scores(q_idx, w_idx, keys, "bhd,bsd->bhs")
         scores = jnp.where(jnp.arange(s)[None, :] < context_lens[:, None],
                            scores, -jnp.inf)
-    sel, valid = _dsa_select(scores, topk, "decode", context_lens - 1)
+    rows, valid = _dsa_select(
+        scores, *_dsa_row_words(block_table, page_off, page_size,
+                                layer_pages or k_pages.shape[0]),
+        topk, "decode", context_lens - 1)
     with jax.named_scope("dsa_sparse_attn"):
-        rows = _gather_rows(k_pages, block_table, sel, page_size)
-        return _dsa_attend(q, rows, valid)
+        return _dsa_attend(q, _gather_rows(k_pages, rows), valid)
 
 
 def dsa_chunk_attention(
@@ -883,6 +938,8 @@ def dsa_chunk_attention(
     page_size: int,
     topk: int,
     block_q: int = 32,
+    page_off=0,  # as dsa_decode_attention's
+    layer_pages=None,
 ) -> jax.Array:
     """A chunk's queries, each with its own selection among the positions
     at or before its own (the chunk's rows are already written). A block
@@ -896,7 +953,9 @@ def dsa_chunk_attention(
     with jax.named_scope("dsa_indexer"):
         keys = idx_pages[pages].reshape(s, idx_pages.shape[-1])
     qpos = jnp.asarray(start, jnp.int32) + jnp.arange(c, dtype=jnp.int32)
-    tables = jnp.broadcast_to(pages[None, :], (block_q, pages.shape[0]))
+    # one sequence, one table: its words are built once, outside the map
+    words, unpack = _dsa_row_words(pages, page_off, page_size,
+                                   layer_pages or k_pages.shape[0])
 
     def block(args):
         qb, qib, wb, pos = args
@@ -904,10 +963,9 @@ def dsa_chunk_attention(
             scores = _dsa_scores(qib, wb, keys, "qhd,sd->qhs")
             scores = jnp.where(jnp.arange(s)[None, :] <= pos[:, None],
                                scores, -jnp.inf)
-        sel, valid = _dsa_select(scores, topk, "chunk", pos)
+        rows, valid = _dsa_select(scores, words, unpack, topk, "chunk", pos)
         with jax.named_scope("dsa_sparse_attn"):
-            rows = _gather_rows(k_pages, tables, sel, page_size)
-            return _dsa_attend(qb, rows, valid)
+            return _dsa_attend(qb, _gather_rows(k_pages, rows), valid)
 
     def blocks(x):
         return x.reshape((c // block_q, block_q) + x.shape[1:])

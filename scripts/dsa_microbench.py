@@ -1,10 +1,18 @@
 #!/usr/bin/env python3
-"""Step 1 of PR 32, measured alone on the chip before the cell: what the
-sparse selection's pieces cost at the DeepSeek-V3.2 cell's shapes (one
-layer; 64 decode slots or a block of 32 chunk queries against a 32,768-token
-page table; 2,048 of them kept), and what the other forms would cost.
+"""The sparse selection's pieces, measured alone on the chip before the
+cell, at the DeepSeek-V3.2 cell's shapes (one layer of a 9-layer pool; 8,
+32 or 64 rows of scores against a 32,768-token page table; 2,048 kept).
+
+PR 35: the form PR 32 shipped (`jax.lax.top_k`, each selected position's
+page looked up in the table, a (page, slot) gather: kept here as
+`parent_*`) against the shipped one (`ops/attention._dsa_select`: one sort
+that carries the physical rows), piece by piece and as the whole decode and
+chunk ops; and the sort's two payload layouts (one word where position and
+page id fit 30 bits, two where they do not).
 
     python scripts/dsa_microbench.py   ->  chiprun_out/dsa-microbench.json
+
+DSA_MICROBENCH_SHAPE=kept,keys,pages_a_layer shrinks it for a CPU rehearsal.
 """
 import json
 import os
@@ -19,10 +27,12 @@ import numpy as np
 
 from dynamo_tpu.ops import attention as att
 
-K, S, PS = 2048, 32768, 16
+K, S, LAYER_PAGES = (int(x) for x in os.environ.get(
+    "DSA_MICROBENCH_SHAPE", "2048,32768,8192").split(","))
+PS, LAYERS = 16, 9
 
 
-def timed(fn, *args, n=10):
+def timed(fn, *args, n=20):
     out = fn(*args)
     jax.block_until_ready(out)
     t0 = time.perf_counter()
@@ -32,131 +42,145 @@ def timed(fn, *args, n=10):
     return (time.perf_counter() - t0) / n * 1e6, out
 
 
-def select_bisect(scores, k):
-    """Exact top-k as a SET without a sort: the k-th largest key by 32 steps
-    of bisection on the floats' ordered bit patterns, ties to the lower
-    index, then the chosen positions compacted by a prefix count."""
-    n, s = scores.shape
-    u = jax.lax.bitcast_convert_type(scores, jnp.uint32)
-    key = jnp.where(u >> 31 == 1, ~u, u | jnp.uint32(0x80000000))
+def parent_select_gather(scores, tables, k_pages):
+    """PR 32's form -> (rows [N, K, D], valid)."""
+    vals, sel = jax.lax.top_k(scores, K)
+    page = jnp.take_along_axis(tables, sel // PS, axis=1)
+    return k_pages[page, sel % PS], vals > -jnp.inf
 
-    def body(i, t):
-        cand = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        cnt = jnp.sum(key >= cand[:, None], axis=1)
-        return jnp.where(cnt >= k, cand, t)
 
-    t = jax.lax.fori_loop(0, 32, body, jnp.zeros((n,), jnp.uint32))
-    gt, eq = key > t[:, None], key == t[:, None]
-    need = k - jnp.sum(gt, axis=1)
-    take = gt | (eq & (jnp.cumsum(eq, axis=1) <= need[:, None]))
-    c = jnp.cumsum(take.astype(jnp.int32), axis=1)
-    j = jnp.arange(1, k + 1, dtype=jnp.int32)
-    sel = jax.vmap(lambda row: jnp.searchsorted(row, j, side="left"))(c)
-    sel = jnp.minimum(sel, s - 1).astype(jnp.int32)
-    valid = jnp.take_along_axis(scores, sel, axis=1) > -jnp.inf
-    return sel, valid
+def select_gather(scores, tables, k_pages, off, layer_pages):
+    """The shipped form; tables [N, Wp] or one sequence's [Wp]."""
+    rows, valid = att._dsa_select(
+        scores, *att._dsa_row_words(tables, off, PS, layer_pages), K, "x",
+        None)
+    return att._gather_rows(k_pages, rows), valid
+
+
+def parent_decode(q, qi, wi, kp, ip, tables, ctx):
+    keys = ip[tables].reshape(tables.shape[0], S, ip.shape[-1])
+    sc = att._dsa_scores(qi, wi, keys, "bhd,bsd->bhs")
+    sc = jnp.where(jnp.arange(S)[None] < ctx[:, None], sc, -jnp.inf)
+    rows, valid = parent_select_gather(sc, tables, kp)
+    return att._dsa_attend(q, rows, valid)
+
+
+def parent_chunk(q, qi, wi, kp, ip, pages, start, block_q):
+    c, s = q.shape[0], pages.shape[0] * PS
+    keys = ip[pages].reshape(s, ip.shape[-1])
+    qpos = start + jnp.arange(c, dtype=jnp.int32)
+    tables = jnp.broadcast_to(pages[None, :], (block_q, pages.shape[0]))
+
+    def block(args):
+        qb, qib, wb, pos = args
+        sc = att._dsa_scores(qib, wb, keys, "qhd,sd->qhs")
+        sc = jnp.where(jnp.arange(s)[None] <= pos[:, None], sc, -jnp.inf)
+        rows, valid = parent_select_gather(sc, tables, kp)
+        return att._dsa_attend(qb, rows, valid)
+
+    def blocks(x):
+        return x.reshape((c // block_q, block_q) + x.shape[1:])
+
+    out = jax.lax.map(block, (blocks(q), blocks(qi), blocks(wi),
+                              blocks(qpos)))
+    return out.reshape(q.shape)
 
 
 def main():
     dev = jax.devices()[0]
     rec = {"device": {"platform": dev.platform, "kind": dev.device_kind},
-           "shapes": {"keys": S, "kept": K, "page_size": PS}, "us": {}}
+           "shapes": {"keys": S, "kept": K, "page_size": PS,
+                      "pages_a_layer": LAYER_PAGES, "layers": LAYERS},
+           "us": {}, "same_rows_as_parent": {}}
+    us_of = rec["us"]
     rng = np.random.default_rng(0)
+    h, d, hi, di = 128, 640, 64, 128
+    k1, k2 = jax.random.split(jax.random.PRNGKey(0))
+    kp = 0.1 * jax.random.normal(k1, (LAYERS * LAYER_PAGES, PS, d),
+                                 jnp.bfloat16)
+    ip = jax.random.normal(k2, (LAYERS * LAYER_PAGES, PS, di), jnp.bfloat16)
+    off = jnp.int32(3 * LAYER_PAGES)  # layer 3's slice of the flat pool
+    pmax = S // PS
+
+    def table(*shape):
+        return jnp.asarray(rng.integers(1, LAYER_PAGES, shape), jnp.int32
+                           ) + off
+
+    # the selection and the row gather alone, both forms
     for n in (8, 32, 64):
-        ctx = rng.integers(28000, 31000, n)
+        ctx = rng.integers(S * 7 // 8, S * 15 // 16, n)
         sc = rng.normal(size=(n, S)).astype(np.float32)
+        sc[:, 100:140] = 0.5  # equal scores; zeros of both signs
+        sc[:, 200:220:2], sc[:, 201:220:2] = 0.0, -0.0
         sc[np.arange(S)[None, :] >= ctx[:, None]] = -np.inf
-        sc = jnp.asarray(sc)
+        sc, tables = jnp.asarray(sc), table(n, pmax)
+        us, (want, want_valid) = timed(
+            jax.jit(parent_select_gather), sc, tables, kp)
+        us_of[f"parent: top_k + lookup + gather[{n},{S}]"] = us
         top = jax.jit(lambda x: jax.lax.top_k(x, K))
-        us, (vals, ref_sel) = timed(top, sc)
-        rec["us"][f"top_k[{n},{S}]"] = us
-        us, (sel, valid) = timed(jax.jit(lambda x: select_bisect(x, K)), sc)
-        rec["us"][f"bisect_select[{n},{S}]"] = us
-        same = all(set(np.asarray(a).tolist()) == set(np.asarray(b).tolist())
-                   for a, b in zip(ref_sel, sel))
-        rec[f"bisect_equals_top_k[{n}]"] = bool(same and bool(valid.all()))
-        us, _ = timed(jax.jit(lambda x: jax.lax.approx_max_k(x, K)), sc)
-        rec["us"][f"approx_max_k[{n},{S}] (not exact: never served)"] = us
-        us, _ = timed(jax.jit(lambda x: jnp.argsort(-x, axis=1)[:, :K]), sc)
-        rec["us"][f"argsort[{n},{S}]"] = us
-        print(json.dumps(rec["us"]), flush=True)
+        us, (_, sel) = timed(top, sc)
+        us_of[f"  top_k[{n},{S}]"] = us
+        lookup = jax.jit(
+            lambda t, sel: jnp.take_along_axis(t, sel // PS, axis=1))
+        us, page = timed(lookup, tables, sel)
+        us_of[f"  page lookup (take_along_axis)[{n},{K}]"] = us
+        us, _ = timed(jax.jit(lambda k, p, sel: k[p, sel % PS]), kp, page,
+                      sel)
+        us_of[f"  (page, slot) gather[{n},{K}]"] = us
+        us, _ = timed(jax.jit(att._gather_rows), kp, page * PS + sel % PS)
+        us_of[f"  flat gather[{n},{K}]"] = us
+        for name, lp in (("one word", LAYER_PAGES), ("two words", 1 << 28)):
+            f = jax.jit(lambda s, t, k, lp=lp: select_gather(s, t, k, off, lp))
+            us, (got, valid) = timed(f, sc, tables, kp)
+            us_of[f"shipped: sort ({name}) + flat gather[{n},{S}]"] = us
+            rec["same_rows_as_parent"][f"{name}[{n}]"] = bool(
+                (got == want).all()) and bool((valid == want_valid).all())
+            f = jax.jit(lambda s, t, lp=lp: att._dsa_select(
+                s, *att._dsa_row_words(t, off, PS, lp), K, "x", None))
+            us, _ = timed(f, sc, tables)
+            us_of[f"  words + sort ({name})[{n},{S}]"] = us
+        print(json.dumps(us_of), flush=True)
 
     # the whole ops, one layer, the cell's pools
-    pages, h, d, hi, di = 8192, 128, 640, 64, 128
-    kp = jnp.asarray(rng.normal(size=(pages, PS, d)) * 0.1, jnp.bfloat16)
-    ip = jnp.asarray(rng.normal(size=(pages, PS, di)), jnp.bfloat16)
-    pmax = S // PS
-    for b in (8, 64):
-        tables = jnp.asarray(rng.integers(1, pages, (b, pmax)), jnp.int32)
-        ctx = jnp.asarray(rng.integers(28000, 31000, b), jnp.int32)
+    kw = dict(page_size=PS, topk=K, page_off=off, layer_pages=LAYER_PAGES)
+    for b in (8, 32, 64):
+        tables = table(b, pmax)
+        ctx = jnp.asarray(rng.integers(S * 7 // 8, S * 15 // 16, b),
+                          jnp.int32)
         q = jnp.asarray(rng.normal(size=(b, h, d)), jnp.bfloat16)
         qi = jnp.asarray(rng.normal(size=(b, hi, di)), jnp.bfloat16)
         wi = jnp.asarray(rng.normal(size=(b, hi)), jnp.float32)
-        f = jax.jit(lambda *a: att.dsa_decode_attention(
-            *a, page_size=PS, topk=K))
-        us, _ = timed(f, q, qi, wi, kp, ip, tables, ctx)
-        rec["us"][f"dsa_decode_attention[B={b}]"] = us
-        # its parts
-        def scores_only(qi, wi, ip, tables, ctx):
-            keys = ip[tables].reshape(b, S, di)
-            sc = att._dsa_scores(qi, wi, keys, "bhd,bsd->bhs")
-            return jnp.where(jnp.arange(S)[None] < ctx[:, None], sc, -jnp.inf)
-        us, sc = timed(jax.jit(scores_only), qi, wi, ip, tables, ctx)
-        rec["us"][f"  indexer scores[B={b}]"] = us
-        us, (_, sel) = timed(jax.jit(lambda x: jax.lax.top_k(x, K)), sc)
-        rec["us"][f"  top_k[B={b}]"] = us
-        def attend(q, kp, tables, sel):
-            rows = att._gather_rows(kp, tables, sel, PS)
-            return att._dsa_attend(q, rows, jnp.ones(sel.shape, bool))
-        us, _ = timed(jax.jit(attend), q, kp, tables, sel)
-        rec["us"][f"  gather + attend[B={b}]"] = us
-        # dense MLA decode over the whole context (what the selection saves)
-        from dynamo_tpu.ops import pallas_attention as pa
-        g = jax.jit(lambda q, kp, t, c: pa.paged_attention_decode(
-            q, kp, None, t, c, page_size=PS, num_kv_heads=1))
-        try:
-            us, _ = timed(g, q, kp, tables, ctx)
-            rec["us"][f"dense decode kernel, 128 heads[B={b}]"] = us
-        except Exception as e:  # noqa: BLE001
-            rec["us"][f"dense decode kernel, 128 heads[B={b}]"] = repr(e)[:300]
-        print(json.dumps(rec["us"]), flush=True)
+        args = (q, qi, wi, kp, ip, tables, ctx)
+        us, want = timed(jax.jit(parent_decode), *args)
+        us_of[f"parent: decode layer[B={b}]"] = us
+        us, got = timed(jax.jit(
+            lambda *a: att.dsa_decode_attention(*a, **kw)), *args)
+        us_of[f"shipped: dsa_decode_attention[B={b}]"] = us
+        rec["same_rows_as_parent"][f"decode output[B={b}]"] = bool(
+            (got == want).all())
+        print(json.dumps(us_of), flush=True)
 
-    # a 256-token chunk at a 29k context: gathered form (shipped) ...
-    c, b = 256, 64
-    wp = pmax + c // PS - 1
-    cpages = jnp.asarray(rng.integers(1, pages, (wp,)), jnp.int32)
+    # a 256-token chunk at a 28.7k context, over a table of 2,048 pages and
+    # over the cell's own (engine/kv_cache.page_table_width: 15 trailing
+    # trash slots, so 33,008 keys: the sort works at the next power of two)
+    c, start = 256, jnp.int32(S * 7 // 8)
     q = jnp.asarray(rng.normal(size=(c, h, d)), jnp.bfloat16)
     qi = jnp.asarray(rng.normal(size=(c, hi, di)), jnp.bfloat16)
     wi = jnp.asarray(rng.normal(size=(c, hi)), jnp.float32)
-    for bq in (16, 32, 64):
-        f = jax.jit(lambda *a, bq=bq: att.dsa_chunk_attention(
-            *a, page_size=PS, topk=K, block_q=bq))
-        try:
-            us, _ = timed(f, q, qi, wi, kp, ip, cpages, jnp.int32(28672), n=3)
-            rec["us"][f"dsa_chunk_attention[C=256,start=28672,block_q={bq}]"] = us
-        except Exception as e:  # noqa: BLE001
-            rec["us"][f"dsa_chunk_attention[block_q={bq}]"] = repr(e)[:300]
-    # ... and the mask form's floor: the ragged kernel, dense, same shapes
-    from dynamo_tpu.ops import ragged_attention as ra
-    tables = jnp.asarray(rng.integers(1, pages, (b, pmax)), jnp.int32)
-    ctx = jnp.asarray(rng.integers(28000, 31000, b), jnp.int32)
-    for live in (0, 8):
-        tabs = jnp.zeros((b + 1, wp), jnp.int32)
-        tabs = tabs.at[:live, :pmax].set(tables[:live]).at[b].set(cpages)
-        kl = jnp.concatenate([jnp.where(jnp.arange(b) < live, ctx, 1),
-                              jnp.asarray([28672 + c], jnp.int32)])
-        qs = jnp.concatenate([jnp.maximum(kl[:b] - 1, 0),
-                              jnp.asarray([28672], jnp.int32)])
-        qq = jnp.asarray(rng.normal(size=(b + c, h, d)), jnp.bfloat16)
-        g = jax.jit(lambda qq, kp, tabs, kl, qs: ra.ragged_paged_attention(
-            qq, kp, None, tabs, kl, qs, page_size=PS, num_kv_heads=1,
-            num_decode=b))
-        try:
-            us, _ = timed(g, qq, kp, tabs, kl, qs, n=3)
-            rec["us"][f"dense ragged kernel, 128 heads, chunk 256 @ 28672, "
-                      f"{live} live decode rows"] = us
-        except Exception as e:  # noqa: BLE001
-            rec["us"][f"dense ragged kernel[{live} live]"] = repr(e)[:300]
+    for wp, bq in ((pmax, 32), (pmax + c // PS - 1, 32), (pmax, 64)):
+        cpages = jnp.concatenate([table(pmax), jnp.zeros(
+            (wp - pmax,), jnp.int32) + off])
+        args = (q, qi, wi, kp, ip, cpages, start)
+        label = f"[C=256,start={int(start)},pages={wp},block_q={bq}]"
+        us, want = timed(jax.jit(
+            lambda *a, bq=bq: parent_chunk(*a, block_q=bq)), *args, n=5)
+        us_of[f"parent: chunk layer{label}"] = us
+        us, got = timed(jax.jit(
+            lambda *a, bq=bq: att.dsa_chunk_attention(*a, block_q=bq, **kw)),
+            *args, n=5)
+        us_of[f"shipped: dsa_chunk_attention{label}"] = us
+        rec["same_rows_as_parent"][f"chunk output{label}"] = bool(
+            (got == want).all())
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/dsa-microbench.json", "w") as f:
         json.dump(rec, f, indent=1)

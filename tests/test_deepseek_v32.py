@@ -8,6 +8,7 @@ import dataclasses
 import importlib.util
 import os
 import sys
+import zlib
 
 import jax
 import jax.numpy as jnp
@@ -543,3 +544,136 @@ def test_random_int8_path_and_sharding_know_the_new_names():
         1, 128, 4)
     specs = sharding.param_specs(p)
     assert all(a is None for a in specs["idx_wk"].q)  # replicated
+
+
+# ---- PR 35: the selecting sort carries each position's physical row ----
+
+SEL_PS, SEL_WP, SEL_K, SEL_LP, SEL_D = 4, 16, 16, 32, 8
+SEL_S, SEL_OFF = SEL_PS * SEL_WP, 2 * SEL_LP  # layer 2 of a 3-layer pool
+
+
+def _oracle(scores, tables, pool):
+    """The plain form PR 35 replaced: jax.lax.top_k, each selected
+    position's page looked up in the table, a (page, slot) gather."""
+    vals, sel = jax.lax.top_k(scores, min(SEL_K, scores.shape[-1]))
+    page = jnp.take_along_axis(tables, sel // SEL_PS, axis=1)
+    return sel, vals > -jnp.inf, pool[page, sel % SEL_PS]
+
+
+def _selection_case(case: str, kind: str):
+    """(scores [N, S] masked, tables [N, Wp] flat ids) of one case."""
+    rng = np.random.default_rng(zlib.crc32(f"{case}/{kind}".encode()))
+    n = 6
+    sc = rng.normal(size=(n, SEL_S)).astype(np.float32)
+    if case == "ties":  # runs of equal scores across the threshold
+        sc = np.round(sc * 2) / 2
+    elif case == "zeros":  # the threshold falls among +0.0 and -0.0
+        sc = rng.choice(np.asarray([0.0, -0.0, 0.0, -0.0, 1.0, -1.0],
+                                   np.float32), size=(n, SEL_S))
+    # a sequence's pages: distinct, never the trash page, in no order
+    if kind == "chunk":
+        row = rng.permutation(np.arange(1, SEL_LP))[:SEL_WP]
+        tables = np.broadcast_to(row, (n, SEL_WP)).copy()
+        pos = 41 + np.arange(n)  # consecutive queries of one sequence
+        if case == "short":
+            pos = 3 + np.arange(n)
+        seen = np.arange(SEL_S)[None, :] <= pos[:, None]
+    else:
+        tables = np.stack([rng.permutation(np.arange(1, SEL_LP))[:SEL_WP]
+                           for _ in range(n)])
+        ctx = rng.integers(SEL_K + 1, SEL_S + 1, n)
+        if case == "short":
+            ctx = rng.integers(1, SEL_K, n)
+        if case == "empty":  # slot 2 is empty: context 1 on the trash page
+            tables[2], ctx[2] = 0, 1
+        seen = np.arange(SEL_S)[None, :] < ctx[:, None]
+    if case == "ascending":
+        tables = np.sort(tables, axis=1)
+    sc = np.where(seen, sc, -np.inf).astype(np.float32)
+    return jnp.asarray(sc), jnp.asarray(tables + SEL_OFF, jnp.int32)
+
+
+@pytest.mark.parametrize("layer_pages", [SEL_LP, 1 << 28],
+                         ids=["one_word", "two_words"])
+@pytest.mark.parametrize("case, kind", [
+    (c, k) for c in ("random", "ties", "zeros", "short", "empty", "ascending")
+    for k in ("decode", "chunk") if (c, k) != ("empty", "chunk")])
+def test_the_sort_carries_top_ks_rows_in_top_ks_order(case, kind,
+                                                      layer_pages):
+    """The selection + flat gather returns, row for row and in order, what
+    jax.lax.top_k + the page-table lookup returned: equal scores at the
+    threshold go to the lower position, +0.0 before -0.0, masked keys last
+    (the first of them where a context is shorter than K), an empty slot
+    its trash row (a chunk has no empty slot), page tables in any order;
+    the tap still sees positions.
+    Both layouts of the sort's payload (a pool too large for one word is
+    pretended by `layer_pages` alone)."""
+    scores, tables = _selection_case(case, kind)
+    pool = jnp.arange(3 * SEL_LP * SEL_PS * SEL_D, dtype=jnp.float32
+                      ).reshape(3 * SEL_LP, SEL_PS, SEL_D)
+    want_sel, want_valid, want_rows = _oracle(scores, tables, pool)
+    if case in ("ties", "zeros"):  # the case is what it says it is
+        v = np.asarray(jnp.take_along_axis(scores, want_sel, axis=1))
+        nxt = np.sort(np.asarray(scores), axis=1)[:, -SEL_K - 1]
+        assert (v[:, -1] == nxt).any()
+
+    def run():
+        words, unpack = att._dsa_row_words(
+            tables[0] if kind == "chunk" else tables, SEL_OFF, SEL_PS,
+            layer_pages)
+        assert len(words) == (1 if layer_pages == SEL_LP else 2)
+        rows, valid = jax.jit(
+            lambda s, w: att._dsa_select(s, w, unpack, SEL_K, kind,
+                                         jnp.zeros((), jnp.int32))
+        )(scores, words)
+        return att._gather_rows(pool, rows), valid
+
+    (got_rows, got_valid), calls = tapped(run)
+    np.testing.assert_array_equal(got_valid, want_valid)
+    np.testing.assert_array_equal(got_rows, want_rows)
+    (_, _, sel, valid), = calls
+    np.testing.assert_array_equal(sel, want_sel)
+    np.testing.assert_array_equal(valid, want_valid)
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_the_tap_sees_positions_of_the_rows_gathered(kind):
+    """DSA_TAP's contract is PR 32's, (kind, qpos, sel, valid) with `sel`
+    as positions, and the rows the op attended over are the physical rows
+    of those positions: its output is the attention over them."""
+    rng = np.random.default_rng(5)
+    h, hi, di, n = 2, 2, 8, 4
+    pool = jnp.asarray(rng.normal(size=(3 * SEL_LP, SEL_PS, SEL_D)),
+                       jnp.float32)
+    idx = jnp.asarray(rng.normal(size=(3 * SEL_LP, SEL_PS, di)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(n, h, SEL_D)), jnp.float32)
+    qi = jnp.asarray(rng.normal(size=(n, hi, di)), jnp.float32)
+    wi = jnp.asarray(rng.normal(size=(n, hi)), jnp.float32)
+    tables = jnp.asarray(
+        np.stack([rng.permutation(np.arange(1, SEL_LP))[:SEL_WP]
+                  for _ in range(n)]) + SEL_OFF, jnp.int32)
+    kw = dict(page_size=SEL_PS, topk=SEL_K, page_off=SEL_OFF,
+              layer_pages=SEL_LP)
+    if kind == "decode":
+        ctx = jnp.asarray([9, 30, 64, 17], jnp.int32)
+        out, calls = tapped(lambda: att.dsa_decode_attention(
+            q, qi, wi, pool, idx, tables, ctx, **kw))
+        want_qpos = np.asarray(ctx) - 1
+    else:
+        tables = jnp.broadcast_to(tables[0], tables.shape)
+        out, calls = tapped(lambda: att.dsa_chunk_attention(
+            q, qi, wi, pool, idx, tables[0], 37, block_q=n, **kw))
+        want_qpos = 37 + np.arange(n)
+    (got_kind, qpos, sel, valid), = calls
+    assert got_kind == kind
+    np.testing.assert_array_equal(qpos, want_qpos)
+    assert sel.shape == valid.shape == (n, SEL_K)
+    for r in range(n):  # positions: in sight of the query, each once
+        seen = sel[r][valid[r]]
+        assert len(set(seen.tolist())) == len(seen) == min(
+            SEL_K, want_qpos[r] + 1)
+        assert seen.max() <= want_qpos[r]
+    page = np.take_along_axis(np.asarray(tables), sel // SEL_PS, axis=1)
+    rows = pool[page, sel % SEL_PS]
+    np.testing.assert_array_equal(
+        out, att._dsa_attend(q, rows, jnp.asarray(valid)))

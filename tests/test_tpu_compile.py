@@ -225,17 +225,25 @@ def test_decode_dispatch_compiles_head_parallel_on_four_chips(topo):
     assert "all-gather" not in text and "all-reduce" not in text
 
 
-@pytest.mark.parametrize("slots", [8, 64])
-def test_sparse_selection_compiles_for_v5e_at_cell_shapes(one_chip, slots):
-    """The DeepSeek-V3.2 cell's decode selection (PR 32; plain XLA:
-    ops/attention.dsa_decode_attention) at a rung of 8 live slots and at
-    the whole 64-slot batch, 128 heads on 640-lane rows, a 2,048-page table,
-    2,048 rows kept: compiles for a described v5e, keeps `jax.lax.top_k` a
-    sort over the [slots, 32768] scores (the trace finds the selection by
-    that shape: layer_metrics/dsa_select_busy_pct.docqa.json), and its
-    scratch stays under a tenth of the chip (the index keys and float32
-    products of every slot's whole table are the intermediates a kernel of
-    its own would not need: ROADMAP, Reach)."""
+@pytest.mark.parametrize("kind, rows", [("decode", 8), ("decode", 64),
+                                        ("chunk", 32)])
+def test_sparse_selection_compiles_for_v5e_at_cell_shapes(one_chip, kind,
+                                                          rows):
+    """The DeepSeek-V3.2 cell's selection (plain XLA: ops/attention.dsa_*)
+    in decode, at a rung of 8 live slots and at the whole 64-slot batch,
+    and in a 256-query chunk (blocks of 32), 128 heads on 640-lane rows, a
+    2,048-page table of a 9-layer pool, 2,048 rows kept: compiles for a
+    described v5e; the selection is ONE sort over the [rows, 32768] float32
+    scores with ONE int32 payload (the trace finds it by that shape:
+    layer_metrics/dsa_select_busy_pct.docqa.json), which carries the
+    physical rows, so nothing is looked up in the page table one scalar at
+    a time (PR 35: no gather with an integer result; `take_along_axis`
+    was 7% of the cell's device time); its scratch stays under a tenth of
+    the chip (the index keys and float32 products of every slot's whole
+    table are the intermediates a kernel of its own would not need:
+    ROADMAP, Reach)."""
+    import re
+
     import jax
     import jax.numpy as jnp
 
@@ -244,15 +252,25 @@ def test_sparse_selection_compiles_for_v5e_at_cell_shapes(one_chip, slots):
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pages, ps, pmax = 8192 * 9, 16, 2048
+    layer_pages, ps, pmax = 8192, 16, 2048
+    n = rows if kind == "decode" else 256
+    op, table, last = {
+        "decode": (att.dsa_decode_attention, (n, pmax), (n,)),
+        "chunk": (att.dsa_chunk_attention, (pmax,), ())}[kind]
     compiled = jax.jit(
-        lambda *a: att.dsa_decode_attention(*a, page_size=ps, topk=2048)
+        lambda off, *a: op(*a, page_size=ps, topk=2048, page_off=off,
+                           layer_pages=layer_pages)
     ).lower(
-        arg((slots, 128, 640), jnp.bfloat16),
-        arg((slots, 64, 128), jnp.bfloat16), arg((slots, 64), jnp.float32),
-        arg((pages, ps, 640), jnp.bfloat16),
-        arg((pages, ps, 128), jnp.bfloat16),
-        arg((slots, pmax), jnp.int32), arg((slots,), jnp.int32)).compile()
+        arg((), jnp.int32), arg((n, 128, 640), jnp.bfloat16),
+        arg((n, 64, 128), jnp.bfloat16), arg((n, 64), jnp.float32),
+        arg((9 * layer_pages, ps, 640), jnp.bfloat16),
+        arg((9 * layer_pages, ps, 128), jnp.bfloat16),
+        arg(table, jnp.int32), arg(last, jnp.int32)).compile()
     text = compiled.as_text()
-    assert f"f32[{slots},32768]" in text and "sort(" in text
+    sorts = re.findall(r"^.* sort\(.*$", text, re.M)
+    assert len(sorts) == 1
+    assert re.search(rf"= \(f32\[{rows},32768\]\S*, s32\[{rows},32768\]\S*\) "
+                     r"sort\(", sorts[0]), sorts[0][:300]
+    assert not re.search(r"= s32\[[\d,]*\]\S* gather\(", text)
+    assert "take_along_axis" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9

@@ -2926,7 +2926,9 @@ class Engine:
         rows' in every mixed one) the 12 widths of a 32k context compiled
         for over 1,600 s on a v5e, past what a worker is given to become
         ready (PERF.md section 6, PR 32). The price: a prompt just past
-        index_topk scores and sorts over the longest table."""
+        index_topk scores and sorts over the longest BUCKET's tokens (the
+        table's trash tail, KVCacheSpec.page_table_width, is not part of
+        a chunk's selection: ops/attention.dsa_chunk_attention)."""
         topk = self.model_cfg.index_topk
         cfg = self.cfg
         if self.model_cfg.layer_types:

@@ -210,9 +210,16 @@ class KVCacheSpec:
         max(prefill_chunk_tokens, mixed_batch_tokens): either path may
         advance the same inflight prompt (engine._mixed_step falls back
         to _advance_chunk when the decode batch empties), and both must
-        fit one program's widest window."""
+        fit one program's widest window.
+
+        The tail's arithmetic is ops/attention.chunk_table_tail, and a
+        chunk program takes the tail off again by it: no query of a
+        prompt that fits the bucket can see a key there, so a chunk under
+        a learned sparse selection scores and sorts the bucket's tokens,
+        not the table's (ops/attention.dsa_chunk_attention `key_pages`)."""
+        from dynamo_tpu.ops.attention import chunk_table_tail
         ps = self.page_size
-        return bucket_tokens // ps + (max(chunk_tokens, ps) // ps - 1)
+        return bucket_tokens // ps + chunk_table_tail(chunk_tokens, ps)
 
 
 def window_ring_pages(window: int, ahead_tokens: int, page_size: int) -> int:

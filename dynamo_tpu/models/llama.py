@@ -728,6 +728,18 @@ def _dsa_kwargs(cfg: ModelConfig, page_off, pages_per_layer: int,
                 layer_pages=pages_per_layer)
 
 
+def _chunk_key_pages(pages: jax.Array, chunk_tokens: int,
+                     page_size: int) -> int:
+    """Leading entries of a chunked prompt's page table that can hold a key
+    one of its queries may see: the prompt bucket's pages, that is the
+    table less the trash tail of this program's chunk
+    (KVCacheSpec.page_table_width; the engine sizes the tail for the
+    longer of the classic and the mixed chunk, so where they differ a few
+    trash slots stay, masked as before). The chunk's selection is built
+    over those entries alone."""
+    return pages.shape[0] - att.chunk_table_tail(chunk_tokens, page_size)
+
+
 def _dsa_rows(q: DsaQuery, rows) -> DsaQuery:
     """The query's three parts at `rows` (a slice, or an index array)."""
     return DsaQuery(*(x[rows] for x in q))
@@ -1094,7 +1106,8 @@ def prefill_chunk(
     chunk_len: jax.Array,  # scalar int32: valid tokens in this chunk
     k_pages: jax.Array,  # [L, P, ps, KV*D]
     v_pages: jax.Array,
-    pages: jax.Array,  # [Pbucket] ALL page ids of the sequence (0-padded)
+    pages: jax.Array,  # [Wp] ALL page ids of the sequence, 0-padded to its
+    # bucket's pages + this chunk's trash tail (KVCacheSpec.page_table_width)
     *,
     page_size: int,
     adapter_slots=None,  # scalar int32 LoRA slot for this sequence, or None
@@ -1148,6 +1161,7 @@ def prefill_chunk(
         if _selects(cfg, pages.shape[0] * page_size):
             o = att.dsa_chunk_attention(
                 *q, kp, vp, pages + page_off, start,
+                key_pages=_chunk_key_pages(pages, c, page_size),
                 **_dsa_kwargs(cfg, page_off, k_pages.shape[1], page_size))
         else:
             qd, vd = _dense_qv(cfg, q, vp)
@@ -1452,7 +1466,9 @@ def mixed_step(
     chunk_tokens: jax.Array,  # [C] one prefill chunk, page-multiple padded
     chunk_start: jax.Array,  # scalar int32: absolute position of chunk[0]
     chunk_len: jax.Array,  # scalar int32: valid tokens in this chunk
-    chunk_pages: jax.Array,  # [Wp] ALL page ids of the chunk's sequence
+    chunk_pages: jax.Array,  # [Wp] ALL page ids of the chunk's sequence,
+    # 0-padded to its bucket's pages + the chunk's trash tail, like
+    # prefill_chunk's
     k_pages: jax.Array,  # [L, P, ps, KV*D]
     v_pages: jax.Array,
     *,
@@ -1544,7 +1560,9 @@ def mixed_step(
                                  context_lens, dsa_plan, **dsa_kw),
                 att.dsa_chunk_attention(
                     *_dsa_rows(q, slice(b, b + c)), kp, vp,
-                    chunk_pages + page_off, chunk_start, **dsa_kw),
+                    chunk_pages + page_off, chunk_start,
+                    key_pages=_chunk_key_pages(chunk_pages, c, page_size),
+                    **dsa_kw),
             ])
         else:
             qd, vd = _dense_qv(cfg, q, vp)

@@ -386,6 +386,16 @@ def prefill_attention_xla(
     return jnp.einsum("hqk,khd->qhd", probs, v)
 
 
+def chunk_table_tail(chunk_tokens: int, page_size: int) -> int:
+    """Trailing TRASH slots of a chunked prompt's page table, past its
+    bucket's pages: the padded window of a prompt's last chunk may reach
+    (chunk pages - 1) pages past the bucket, and its page slice must land
+    on the trash page (engine/kv_cache.KVCacheSpec.page_table_width adds
+    them; a program that is handed such a table takes them off again by
+    its own chunk length: models/llama.prefill_chunk, mixed_step)."""
+    return max(chunk_tokens, page_size) // page_size - 1
+
+
 def chunk_attention(
     q: jax.Array,  # [C, H, D] — one prefill chunk's queries
     k_pages: jax.Array,  # [P, ps, KV*D]
@@ -952,12 +962,28 @@ def dsa_chunk_attention(
     block_q: int = 32,
     page_off=0,  # as dsa_decode_attention's
     layer_pages=None,
+    key_pages=None,  # leading entries of `pages` a key may lie in: all
 ) -> jax.Array:
     """A chunk's queries, each with its own selection among the positions
     at or before its own (the chunk's rows are already written). A block
     of `block_q` queries at a time: the float32 index products of a whole
-    256-token chunk against 32k keys would be 2 GB."""
+    256-token chunk against 32k keys would be 2 GB.
+
+    The selection (index keys gathered, scores, mask, the sort's words and
+    so the sort) is built over the first `key_pages` entries of `pages`
+    alone: the prompt bucket's pages, where the table carries a trash tail
+    behind them (`chunk_table_tail`; the cell's 2,063-entry table: 32,768
+    positions scored and sorted, not 33,008, which the TPU sorts as
+    65,536). Exact for every REAL query: its position is under the
+    prompt's length, the prompt fits its bucket, and the mask lets it see
+    positions <= its own, so the tail held nothing it could see and what
+    it selects, in which order, is what the whole table gave. A padded
+    query past the prompt's end may select other rows than it did (past
+    the bucket it sees every key of the extent and none behind it); its
+    own rows lie on the trash page and its output is discarded."""
     c = q.shape[0]
+    if key_pages is not None:
+        pages = pages[:key_pages]
     s = pages.shape[0] * page_size
     block_q = max(1, min(block_q, c))
     while c % block_q:

@@ -3,6 +3,11 @@
 cell, at the DeepSeek-V3.2 cell's shapes (one layer of a 9-layer pool; 8,
 32 or 64 rows of scores against a 32,768-token page table; 2,048 kept).
 
+PR 38: a chunk over the cell's own 2,063-entry page table (the bucket's
+2,048 pages + 15 trailing trash slots), its selection built over the whole
+table (PR 35's form) against over the bucket's pages (shipped), with the
+real queries' outputs held bit for bit.
+
 PR 35: the form PR 32 shipped (`jax.lax.top_k`, each selected position's
 page looked up in the table, a (page, slot) gather: kept here as
 `parent_*`) against the shipped one (`ops/attention._dsa_select`: one sort
@@ -91,7 +96,7 @@ def main():
     rec = {"device": {"platform": dev.platform, "kind": dev.device_kind},
            "shapes": {"keys": S, "kept": K, "page_size": PS,
                       "pages_a_layer": LAYER_PAGES, "layers": LAYERS},
-           "us": {}, "same_rows_as_parent": {}}
+           "us": {}, "same_rows_as_parent": {}, "same_as_whole_table": {}}
     us_of = rec["us"]
     rng = np.random.default_rng(0)
     h, d, hi, di = 128, 640, 64, 128
@@ -162,12 +167,22 @@ def main():
 
     # a 256-token chunk at a 28.7k context, over a table of 2,048 pages and
     # over the cell's own (engine/kv_cache.page_table_width: 15 trailing
-    # trash slots, so 33,008 keys: the sort works at the next power of two)
+    # trash slots, so 33,008 positions, which the sort works at the next
+    # power of two). PR 38: the shipped op is handed the bucket's pages as
+    # the selection's extent, as models/llama hands them; `whole table` is
+    # PR 35's form, the same op over every entry
     c, start = 256, jnp.int32(S * 7 // 8)
+    tail = att.chunk_table_tail(c, PS)
+    fits = min(c, S - int(start))  # queries inside the bucket: all, at 32k
     q = jnp.asarray(rng.normal(size=(c, h, d)), jnp.bfloat16)
     qi = jnp.asarray(rng.normal(size=(c, hi, di)), jnp.bfloat16)
     wi = jnp.asarray(rng.normal(size=(c, hi)), jnp.float32)
-    for wp, bq in ((pmax, 32), (pmax + c // PS - 1, 32), (pmax, 64)):
+
+    def chunk_op(bq, key_pages):
+        return jax.jit(lambda *a: att.dsa_chunk_attention(
+            *a, block_q=bq, key_pages=key_pages, **kw))
+
+    for wp, bq in ((pmax, 32), (pmax + tail, 32), (pmax, 64)):
         cpages = jnp.concatenate([table(pmax), jnp.zeros(
             (wp - pmax,), jnp.int32) + off])
         args = (q, qi, wi, kp, ip, cpages, start)
@@ -175,12 +190,28 @@ def main():
         us, want = timed(jax.jit(
             lambda *a, bq=bq: parent_chunk(*a, block_q=bq)), *args, n=5)
         us_of[f"parent: chunk layer{label}"] = us
-        us, got = timed(jax.jit(
-            lambda *a, bq=bq: att.dsa_chunk_attention(*a, block_q=bq, **kw)),
-            *args, n=5)
+        shipped, whole_table = chunk_op(bq, pmax), chunk_op(bq, None)
+        us, got = timed(shipped, *args, n=5)
         us_of[f"shipped: dsa_chunk_attention{label}"] = us
         rec["same_rows_as_parent"][f"chunk output{label}"] = bool(
-            (got == want).all())
+            (got[:fits] == want[:fits]).all())
+        if wp == pmax:
+            continue
+        us, whole = timed(whole_table, *args, n=5)
+        us_of[f"whole table (PR 35): dsa_chunk_attention{label}"] = us
+        rec["same_as_whole_table"][f"chunk output{label}"] = bool(
+            (got[:fits] == whole[:fits]).all())
+        # a prompt's last chunk: its padded window crosses the bucket's
+        # end, and only its real queries (64 here) owe the parent's bits
+        real = 4 * PS
+        last = (q, qi, wi, kp, ip, cpages, jnp.int32(S - real))
+        got, whole = shipped(*last), whole_table(*last)
+        rec["same_as_whole_table"][
+            f"last chunk's {real} real queries[start={S - real}]"] = bool(
+                (got[:real] == whole[:real]).all())
+        rec["same_as_whole_table"][
+            "  (its padded queries, not owed)"] = bool(
+                (got[real:] == whole[real:]).all())
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/dsa-microbench.json", "w") as f:
         json.dump(rec, f, indent=1)

@@ -7,6 +7,7 @@ group-limited sigmoid routing, as one chip's share and uncut."""
 import dataclasses
 import importlib.util
 import os
+import re
 import sys
 import zlib
 
@@ -212,6 +213,14 @@ def test_one_group_is_todays_router():
 
 # ------------------------------------------------- program vs reference --
 
+def _chunk_table(spec, pages, chunk_tokens):
+    """A chunked prompt's page table as the engine hands it to a chunk
+    program: the bucket's pages, then the chunk's trash tail."""
+    width = spec.page_table_width(len(pages) * spec.page_size, chunk_tokens)
+    return jnp.concatenate(
+        [pages, jnp.zeros((width - len(pages),), pages.dtype)])
+
+
 def _run_program(cfg, p, tokens, n_prefill=24, n_chunk=8):
     """The serving path's forward functions through the paged cache: a
     bucket-sized prefill (24 tokens: past index_topk, so it selects), one
@@ -230,7 +239,8 @@ def _run_program(cfg, p, tokens, n_prefill=24, n_chunk=8):
     end = n_prefill + n_chunk
     out = llama.prefill_chunk(
         cfg, p, toks[n_prefill:end], jnp.int32(n_prefill), jnp.int32(n_chunk),
-        out.k_pages, out.v_pages, pages, page_size=PS)
+        out.k_pages, out.v_pages, _chunk_table(spec, pages, n_chunk),
+        page_size=PS)
     got[end - 1] = out.last_logits
     kp, vp = out.k_pages, out.v_pages
     tables = jnp.stack([pages, jnp.zeros_like(pages)])
@@ -356,8 +366,8 @@ def test_mixed_step_matches_reference(model):
     out = llama.mixed_step(
         cfg, p, jnp.asarray([a[24], 0]), jnp.asarray([24, 0]),
         jnp.stack([pa, jnp.zeros_like(pa)]), jnp.asarray([25, 1]),
-        jnp.asarray(b[24:36]), jnp.int32(24), jnp.int32(12), pb,
-        out.k_pages, out.v_pages, page_size=PS)
+        jnp.asarray(b[24:36]), jnp.int32(24), jnp.int32(12),
+        _chunk_table(spec, pb, 12), out.k_pages, out.v_pages, page_size=PS)
     np.testing.assert_allclose(
         out.logits[0], ref.forward(rc, fp, jnp.asarray(a), rshare)[0][24],
         **TOL)
@@ -677,3 +687,125 @@ def test_the_tap_sees_positions_of_the_rows_gathered(kind):
     rows = pool[page, sel % SEL_PS]
     np.testing.assert_array_equal(
         out, att._dsa_attend(q, rows, jnp.asarray(valid)))
+
+
+# ---- PR 38: a chunk's selection is built over the bucket's pages, not ----
+# ---- over the table's (the bucket's + the chunk's trash tail)         ----
+
+CH_C, CH_LEN = 12, 62  # a 12-token chunk; a 62-token prompt in SEL_S = 64
+CH_TAIL = att.chunk_table_tail(CH_C, SEL_PS)  # 2 trailing trash slots
+
+
+def _whole_table_chunk(q, qi, wi, pool, idx, pages, start):
+    """The form PR 38 replaced, kept plainly: every position of the WHOLE
+    table (the trash tail's too) scored and masked, jax.lax.top_k, each
+    selected position's page looked up in the table, a (page, slot) gather
+    -> (output, selected positions, valid)."""
+    s = pages.shape[0] * SEL_PS
+    keys = idx[pages].reshape(s, idx.shape[-1])
+    pos = start + jnp.arange(q.shape[0])
+    scores = att._dsa_scores(qi, wi, keys, "qhd,sd->qhs")
+    scores = jnp.where(jnp.arange(s)[None, :] <= pos[:, None], scores,
+                       -jnp.inf)
+    sel, valid, rows = _oracle(
+        scores, jnp.broadcast_to(pages, (q.shape[0],) + pages.shape), pool)
+    return att._dsa_attend(q, rows, valid), sel, valid
+
+
+def _chunk_case(seed: int = 7, trash=None):
+    """One sequence's chunk operands: a 62-token prompt on 16 pages of a
+    64-token bucket, a table of those and two trash slots behind them; the
+    trash page's rows (latent and index key) are `trash`, or whatever was
+    written there last (random rows)."""
+    rng = np.random.default_rng(seed)
+    h, hi, di = 2, 2, 8
+    pool = rng.normal(size=(3 * SEL_LP, SEL_PS, SEL_D)).astype(np.float32)
+    idx = rng.normal(size=(3 * SEL_LP, SEL_PS, di)).astype(np.float32)
+    if trash is not None:
+        pool[SEL_OFF], idx[SEL_OFF] = trash, trash
+    pages = np.concatenate([rng.permutation(np.arange(1, SEL_LP))[:SEL_WP],
+                            np.zeros(CH_TAIL, np.int64)]) + SEL_OFF
+    q = rng.normal(size=(CH_C, h, SEL_D)).astype(np.float32)
+    qi = rng.normal(size=(CH_C, hi, di)).astype(np.float32)
+    wi = rng.normal(size=(CH_C, hi)).astype(np.float32)
+    return tuple(jnp.asarray(a) for a in (q, qi, wi, pool, idx)) + (
+        jnp.asarray(pages, jnp.int32),)
+
+
+def _trimmed_chunk(ops, start, layer_pages=SEL_LP):
+    """The shipped op over the bucket's pages -> (output, tap's calls)."""
+    return tapped(lambda: att.dsa_chunk_attention(
+        *ops, start, block_q=CH_C, page_size=SEL_PS, topk=SEL_K,
+        page_off=SEL_OFF, layer_pages=layer_pages, key_pages=SEL_WP))
+
+
+@pytest.mark.parametrize("layer_pages", [SEL_LP, 1 << 28],
+                         ids=["one_word", "two_words"])
+@pytest.mark.parametrize("start", [0, 24, 56],
+                         ids=["first_chunk", "mid_prompt",
+                              "last_chunk_crosses_the_bucket"])
+def test_a_chunk_selects_over_its_bucket_as_over_its_whole_table(
+        start, layer_pages):
+    """The selection built over the bucket's 16 pages hands every REAL
+    query (position < the prompt's 62 tokens) the rows, in the order, and
+    the output bit for bit that the selection over the whole 18-entry
+    table handed it: at the prompt's start (fewer keys in sight than K), in
+    its middle, and in a last chunk whose padded window (56..67) crosses
+    the bucket's end at 64. Excluded by name: the padded queries past the
+    prompt's end (62..67 there), whose rows land on the trash page and
+    whose outputs the engine discards; past the bucket (64..67) they see
+    other keys than they did."""
+    ops = _chunk_case()
+    want, want_sel, want_valid = _whole_table_chunk(*ops, start)
+    got, ((kind, qpos, sel, valid),) = _trimmed_chunk(ops, start,
+                                                      layer_pages)
+    real = np.flatnonzero(start + np.arange(CH_C) < CH_LEN)
+    assert len(real) == (6 if start == 56 else CH_C) and kind == "chunk"
+    np.testing.assert_array_equal(qpos, start + np.arange(CH_C))
+    np.testing.assert_array_equal(valid[real], np.asarray(want_valid)[real])
+    np.testing.assert_array_equal(sel[real], np.asarray(want_sel)[real])
+    np.testing.assert_array_equal(np.asarray(got)[real],
+                                  np.asarray(want)[real])
+    if start == 56:  # the case is what it says: padded queries do differ
+        assert (sel[len(real):] != np.asarray(want_sel)[len(real):]).any()
+
+
+def test_no_real_query_reads_the_trash_page():
+    """With the trash page's index keys and latent rows NaN, the last
+    chunk's real queries give the bits they gave with a clean trash page:
+    nothing of the tail is gathered, scored or sorted, and no real query
+    selects a row there."""
+    clean, _ = _trimmed_chunk(_chunk_case(), 56)
+    dirty, _ = _trimmed_chunk(_chunk_case(trash=np.nan), 56)
+    real = CH_LEN - 56
+    assert not np.isnan(np.asarray(dirty)[:real]).any()
+    np.testing.assert_array_equal(np.asarray(dirty)[:real],
+                                  np.asarray(clean)[:real])
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk", "mixed_step"])
+def test_chunk_programs_select_over_the_bucket(model, program):
+    """Both chunk programs hand the op the bucket's pages by shape (the
+    table's width less the tail of their own chunk length): an 8-token
+    chunk over a 13-entry table (a 48-token bucket + 1 trash slot) lowers
+    to sorts over 48 positions, and nothing 52 wide is left."""
+    cfg, p = model
+    spec = KVCacheSpec.from_model(cfg, num_pages=32, page_size=PS)
+    kp, vp = alloc_kv_pages(spec)
+    table = _chunk_table(spec, jnp.arange(1, 13, dtype=jnp.int32), 8)
+    assert table.shape == (13,)
+    chunk = (jnp.zeros((8,), jnp.int32), jnp.int32(24), jnp.int32(8))
+    if program == "prefill_chunk":
+        text = jax.jit(lambda *a: llama.prefill_chunk(
+            cfg, p, *a, page_size=PS)).lower(
+                *chunk, kp, vp, table).as_text()
+    else:
+        two = jnp.zeros((2,), jnp.int32)
+        text = jax.jit(lambda *a: llama.mixed_step(
+            cfg, p, *a, page_size=PS)).lower(
+                two, two, jnp.zeros((2, 12), jnp.int32), two + 1, *chunk,
+                table, kp, vp).as_text()
+    sorts = re.findall(r'"stablehlo.sort".*?\}\) : \(tensor<\d+x(\d+)xf32>',
+                       text, re.S)
+    assert sorts and set(sorts) == {"48"}, sorts
+    assert "x52x" not in text

@@ -226,7 +226,7 @@ def test_decode_dispatch_compiles_head_parallel_on_four_chips(topo):
 
 
 @pytest.mark.parametrize("kind, rows", [("decode", 8), ("decode", 64),
-                                        ("chunk", 32)])
+                                        ("chunk", 32), ("chunk_tail", 32)])
 def test_sparse_selection_compiles_for_v5e_at_cell_shapes(one_chip, kind,
                                                           rows):
     """The DeepSeek-V3.2 cell's selection (plain XLA: ops/attention.dsa_*)
@@ -241,7 +241,13 @@ def test_sparse_selection_compiles_for_v5e_at_cell_shapes(one_chip, kind,
     was 7% of the cell's device time); its scratch stays under a tenth of
     the chip (the index keys and float32 products of every slot's whole
     table are the intermediates a kernel of its own would not need:
-    ROADMAP, Reach)."""
+    ROADMAP, Reach).
+    `chunk_tail` is the chunk as the cell runs it (PR 38): a table of 2,063
+    entries, the bucket's 2,048 pages and the 15 trailing trash slots of a
+    256-token chunk, handed to the op with the bucket's pages as the
+    selection's extent: its sort is over [32, 32768] all the same (the
+    TPU works a 33,008-wide sort as 65,536), and no instruction makes
+    anything 33,008 or 2,063 wide: only the table's parameter is."""
     import re
 
     import jax
@@ -254,9 +260,13 @@ def test_sparse_selection_compiles_for_v5e_at_cell_shapes(one_chip, kind,
 
     layer_pages, ps, pmax = 8192, 16, 2048
     n = rows if kind == "decode" else 256
+    wide = pmax + att.chunk_table_tail(n, ps)  # 2,063
     op, table, last = {
         "decode": (att.dsa_decode_attention, (n, pmax), (n,)),
-        "chunk": (att.dsa_chunk_attention, (pmax,), ())}[kind]
+        "chunk": (att.dsa_chunk_attention, (pmax,), ()),
+        "chunk_tail": (functools.partial(att.dsa_chunk_attention,
+                                         key_pages=pmax), (wide,), ()),
+    }[kind]
     compiled = jax.jit(
         lambda off, *a: op(*a, page_size=ps, topk=2048, page_off=off,
                            layer_pages=layer_pages)
@@ -274,6 +284,11 @@ def test_sparse_selection_compiles_for_v5e_at_cell_shapes(one_chip, kind,
     assert not re.search(r"= s32\[[\d,]*\]\S* gather\(", text)
     assert "take_along_axis" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
+    if kind == "chunk_tail":
+        assert (wide, wide * ps) == (2063, 33008)
+        made = re.findall(r"= \(?\w+\[(?:\d+,)*(?:2063|33008)[,\]]\S* "
+                          r"(?:\S+ )*?([\w-]+)\(", text)
+        assert set(made) == {"parameter"}, made
 
 
 # Laguna's cut (benchmarks/chip/configs/laguna-s-2.1-w8a8-1chip): layers of

@@ -81,6 +81,13 @@ def _pack_logit_bias(req: GenRequest):
     return ids, vals
 
 
+def _wait_phase(seq: SeqState) -> Optional[Dict[str, float]]:
+    """What the event that ends `seq` carries on TokenEvent.phase: its
+    token time by cause (timeline.TokenWait.phase); None without one."""
+    wait = seq.token_wait
+    return None if wait is None else wait.phase()
+
+
 def _argnums(fn, *names: str) -> Tuple[int, ...]:
     """Positions of the parameters called `names` in `fn`'s signature (a
     `*varargs` name gives the position of its first operand). Raises
@@ -209,7 +216,6 @@ class EngineMetrics:
         self.mixed_buckets = [0] * (len(self._OCC_EDGES) + 1)
         self.mixed_sum = 0.0
         self.mixed_count = 0
-        self.mixed_prefill_tokens = 0
         self.phases: Dict[str, PhaseTimer] = {p: PhaseTimer()
                                               for p in self._PHASES}
         # a first token by stage, cumulative seconds over `count` requests
@@ -430,7 +436,6 @@ class EngineMetrics:
         self._bucketize(self._OCC_EDGES, self.mixed_buckets, frac)
         self.mixed_sum += frac
         self.mixed_count += 1
-        self.mixed_prefill_tokens += prefill_tokens
 
     def reset_phases(self, *names: str) -> None:
         """Re-zero selected phase histograms (bench section boundaries)."""
@@ -480,9 +485,6 @@ class EngineMetrics:
             }
             for d in sorted(set(self.spec_draft_by)
                             | set(self.spec_count_by))}
-        out["occupancy_mean"] = (
-            round(self.occupancy_sum / self.occupancy_count, 4)
-            if self.occupancy_count else 0.0)
         out["mixed_frac_mean"] = (
             round(self.mixed_sum / self.mixed_count, 4)
             if self.mixed_count else 0.0)
@@ -2148,7 +2150,7 @@ class Engine:
             if seq.request_id in aborted:
                 events.append(
                     TokenEvent(seq.request_id, -1, len(seq.output_tokens), True,
-                               "abort")
+                               "abort", phase=_wait_phase(seq))
                 )
                 self._finish_slot(slot, "abort")
         return events
@@ -2391,7 +2393,7 @@ class Engine:
             for i, r in enumerate(reqs):
                 aslots[i] = self._adapter_slot(r)
             lx = (jnp.asarray(aslots),)
-        with self.timeline.phase("dispatch"):
+        with self.timeline.phase("dispatch", kind="prompt"):
             logits, self.k_pages, self.v_pages = self._prefill_batch(
                 self.params, jnp.asarray(tokens), jnp.asarray(seq_lens),
                 self.k_pages, self.v_pages, jnp.asarray(pages_arr), *lx,
@@ -2433,7 +2435,7 @@ class Engine:
         raw_logits = logits
         if pen_rows is not None:
             logits = logits - jnp.asarray(pen_rows)
-        with self.timeline.phase("dispatch"):
+        with self.timeline.phase("dispatch", kind="prompt"):
             toks, chosen, tids, tvals = self._sample_first_batch(
                 logits, jnp.asarray(temp), jnp.asarray(top_p),
                 jnp.asarray(top_k), jnp.asarray(min_p),
@@ -2493,7 +2495,9 @@ class Engine:
         counted under `where`, the event that ends the stream joins
         `events`, and None is returned — the engine keeps serving."""
         try:
-            with self.timeline.phase("device_wait"):
+            # where the device is drained (a mixed step's own readback came
+            # first) the sampling is an implicit program, and a prompt's
+            with self.timeline.phase("device_wait", kind="prompt"):
                 return self._first_token(req, last_logits, prompt_len)
         except IntegrityFault:
             self._abort_poisoned(events, req, pages, where, slot)
@@ -2532,10 +2536,20 @@ class Engine:
         seq = self._install_slot(req, slot, pages, prompt_len, first, req_key)
         finished, reason = self._check_stop(seq, first)
         ev = TokenEvent(req.request_id, first, 0, finished, reason)
-        now = time.monotonic()
+        now = self.timeline.fold()
         ev.phase = {"queue_s": max(0.0, t_prefill_start - req.arrival_time),
                     "prefill_s": max(0.0, now - t_prefill_start),
                     "t_first": now}
+        # token time: the sequence's waits count from here. A preempted
+        # sequence's continuation keeps its account (its client kept
+        # waiting), and this token ends the wait the preemption was in
+        seq.token_wait = req.token_wait
+        if seq.token_wait is None:
+            seq.token_wait = self.timeline.token_start()
+        else:
+            self.timeline.token_gap(seq.token_wait, 1, req.request_id)
+        if finished:
+            ev.phase.update(_wait_phase(seq) or {})
         if chunked:
             # "prefill" records admission-to-first-token for BOTH paths
             # (the TTFT phase): a full prefill observed it at its dispatch,
@@ -2615,7 +2629,7 @@ class Engine:
 
         lx = ((jnp.int32(self._adapter_slot(req)),)
               if self.lora is not None else ())
-        with self.timeline.phase("dispatch"):
+        with self.timeline.phase("dispatch", kind="prompt"):
             last_logits, self.k_pages, self.v_pages = self._prefill(
                 self.params,
                 jnp.asarray(tokens),
@@ -2963,7 +2977,7 @@ class Engine:
         tokens[:take] = inf.req.prompt_token_ids[start:start + take]
 
         lx = (jnp.int32(inf.aslot),) if self.lora is not None else ()
-        with self.timeline.phase("dispatch"):
+        with self.timeline.phase("dispatch", kind="prompt"):
             last_logits, self.k_pages, self.v_pages = self._prefill_chunk(
                 self.params,
                 jnp.asarray(tokens),
@@ -3082,7 +3096,8 @@ class Engine:
             args = (self.params, cur, pos, ctx_lens, active_dev,
                     self._dev_tables, *self._dev_sampling,
                     self.token_counts)
-        with self.timeline.phase("dispatch"):
+        with self.timeline.phase(
+                "dispatch", kind="decode" if inf is None else "prompt"):
             if inf is not None:  # fresh uploads each call, never donated
                 px = (jnp.asarray(p_tokens), jnp.int32(start),
                       jnp.int32(take),
@@ -3210,7 +3225,7 @@ class Engine:
                     events.append(
                         TokenEvent(
                             seq.request_id, -1, len(seq.output_tokens), True,
-                            "kv_oom"
+                            "kv_oom", phase=_wait_phase(seq)
                         )
                     )
                     self.flight.note("kv_oom", rid=seq.request_id, slot=slot,
@@ -3272,6 +3287,7 @@ class Engine:
             max_tokens=seq.max_tokens - len(seq.output_tokens),
             prior_output_token_ids=list(old.prior_output_token_ids)
             + list(seq.output_tokens),
+            token_wait=seq.token_wait,
         )
         log.info(
             "preempting %s under page pressure (%d output tokens "
@@ -3538,7 +3554,7 @@ class Engine:
 
     def _dispatch_window(self, window: int) -> None:
         t0 = time.monotonic()
-        with self.timeline.phase("dispatch"):
+        with self.timeline.phase("dispatch", kind="decode"):
             # chaos: a wedged device program — the sleep runs INSIDE the
             # armed dispatch seam with _exec_lock held, exactly what a
             # real hang looks like to the watchdog monitor thread
@@ -3688,9 +3704,11 @@ class Engine:
                     self.watchdog.record_integrity_fault(
                         "decode_tokens", [seq.request_id], slot=slot)
                     events.append(TokenEvent(seq.request_id, -1, 0, True,
-                                             "integrity_fault"))
+                                             "integrity_fault",
+                                             phase=_wait_phase(seq)))
                     self._finish_slot(slot, "integrity_fault")
                     continue
+                last, n0 = None, len(seq.output_tokens)
                 for j in range(rows if given is None else int(given[slot])):
                     tok = int(toks[j, slot])
                     seq.num_tokens += 1  # the attended token is now cached
@@ -3712,6 +3730,7 @@ class Engine:
                         self._decorate_lp(ev, seq, lps[0][j, slot],
                                           lps[1][j, slot], lps[2][j, slot])
                     events.append(ev)
+                    last = ev
                     if finished:
                         # mid-chain stop: the later tokens given to this
                         # slot are discarded (their KV lives in pages
@@ -3720,6 +3739,14 @@ class Engine:
                         # rebuilt from mirrors next step
                         self._finish_slot(slot, reason)
                         break
+                wait = seq.token_wait
+                if wait is not None and last is not None:
+                    # token time: what this slot waited behind since its
+                    # last emission, ended by the tokens it just received
+                    self.timeline.token_gap(
+                        wait, len(seq.output_tokens) - n0, seq.request_id)
+                    if last.finished:
+                        last.phase = _wait_phase(seq)
 
     def _check_stop(self, seq: SeqState, token: int):
         if token in seq.stop_token_ids:

@@ -537,6 +537,7 @@ class SeqState:
         "req",  # originating GenRequest (preemption rebuilds a continuation)
         "guide",  # (mode, depth, bits) JSON-guide host mirror, or None
         "adapter_slot",  # LoRA device slot (0 = base) — pins the slot
+        "token_wait",  # observability/timeline.TokenWait from the first token
     )
 
     def __init__(
@@ -566,6 +567,7 @@ class SeqState:
         self.logprobs = logprobs
         self.guide = None
         self.adapter_slot = 0
+        self.token_wait = None
         # prompt token ids, retained for the n-gram speculative proposer
         # (engine._propose_ngram fills it at slot installation)
         self.prompt_ids: List[int] = []

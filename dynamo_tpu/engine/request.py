@@ -64,6 +64,10 @@ class GenRequest:
     # ranking; carried across preemption/recovery continuations and the
     # disagg prefill RPC. Scheduling-only: sampling never reads it.
     tenant: Optional[str] = None
+    # preemption-by-recompute continuation (engine-internal): the
+    # sequence's token-time account (observability/timeline.TokenWait),
+    # so that the wait the preemption caused is charged to the request
+    token_wait: Optional[object] = None
 
 
 @dataclasses.dataclass
@@ -76,10 +80,13 @@ class TokenEvent:
     logprob: Optional[float] = None  # chosen-token logprob when requested
     # [(token_id, logprob)] best-first alternatives when requested
     top_logprobs: Optional[List[Tuple[int, float]]] = None
-    # per-request phase timings (seconds), attached ONLY to the first-token
+    # per-request phase timings (seconds), attached to the first-token
     # event by the engine's prefill paths: {"queue_s": admission wait,
-    # "prefill_s": prompt compute}. This is the bridge from the engine's
-    # aggregate PhaseTimer histograms to per-request trace spans — the
-    # serving layer back-dates worker.queue / worker.prefill child spans
-    # from these without the engine knowing about tracing.
+    # "prefill_s": prompt compute}, and to the event that ends a sequence:
+    # its token time by cause, {"decode_s", "prompt_s", "drained_s",
+    # "gap_max_s", "tokens", "t_last"} (timeline.TokenWait.phase). This is
+    # the bridge from the engine's aggregate PhaseTimer histograms to
+    # per-request trace spans — the serving layer back-dates worker.queue /
+    # worker.prefill / worker.decode child spans from these without the
+    # engine knowing about tracing.
     phase: Optional[Dict[str, float]] = None

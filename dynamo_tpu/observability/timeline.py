@@ -58,6 +58,26 @@ is also a ``jax.profiler.TraceAnnotation`` named ``stepline/<segment>``
 and each step a ``StepTraceAnnotation``, so the trace holds the host's
 phases on the profiler's clock beside the device's operations.
 
+**Token time by cause.**  Every second of the thread's time also goes
+to exactly one of three causes (``token_time.cause_s``; their sum is
+``loop_wall_s``): ``drained`` while no dispatched program is unfinished,
+whatever the thread does (a ``device_wait`` there waits on an implicit
+program, first-token sampling, and goes to the kind it declares);
+otherwise the kind of the OLDEST unfinished program, declared at its
+``phase("dispatch", kind=...)``: ``decode`` (decode rows only: a fused
+window, a verify; also what an undeclared dispatch is) or ``prompt`` (it
+carries prompt tokens: a mixed step, a chunk, a prefill).  A ticket keeps
+its kind until a ``device_wait`` proves it finished, so this is the host's
+knowledge: under async scheduling the device may already run window k+1
+when the host learns that k is done; both are ``decode``.  A sequence
+holds a `TokenWait` from its first token on (`token_start`); at every
+emission `token_gap` puts the causes' growth since its last one into
+``token_time.row_s``, counts its tokens in ``token_time.gaps`` and keeps
+the 8 longest single waits (``token_time.worst``; sequences that sat out
+the same wait in one emission share a record).  Σ ``row_s`` is the
+seconds live sequences spent between their first and last tokens;
+over ``gaps`` it is the mean time per output token on this thread's clock.
+
 Record keeping follows the flight recorder's single-writer draft
 pattern: `Engine.step()` runs under `_exec_lock` on one scheduler
 thread, so the draft and phase stack are touched lock-free; the only
@@ -111,6 +131,11 @@ LOOP_STATES = ("between_steps", "no_work")
 DRAINED_KEYS = ("admit", "page_alloc", "dispatch", "detok", "bank",
                 UNTRACKED) + LOOP_STATES
 ANNOTATION_PREFIX = "stepline/"
+# what a second of the thread's time, and of a live sequence's wait, is put
+# down to (token_time): the kind of the oldest unfinished program, or none
+CAUSES = ("decode", "prompt", "drained")
+_DRAINED_CAUSE = CAUSES.index("drained")
+WORST_KEPT = 8  # single waits kept in token_time.worst
 
 
 def _env_capacity() -> int:
@@ -178,13 +203,14 @@ class _Phase:
     """Reusable-shape context manager for one instrumented phase; kept
     allocation-light because several open per engine step."""
 
-    __slots__ = ("_tl", "_name", "_upto", "_watched")
+    __slots__ = ("_tl", "_name", "_upto", "_kind", "_watched")
 
     def __init__(self, tl: "StepTimeline", name: str,
-                 upto: Optional[int] = None):
+                 upto: Optional[int] = None, kind: str = "decode"):
         self._tl = tl
         self._name = name
         self._upto = upto
+        self._kind = kind
         self._watched = False
 
     def __enter__(self) -> "_Phase":
@@ -195,7 +221,7 @@ class _Phase:
         if watch is not None and self._name in DEVICE_PHASES:
             self._watched = watch
             watch.device_enter(self._name)
-        self._tl._enter(self._name, self._upto)
+        self._tl._enter(self._name, self._upto, self._kind)
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -204,6 +230,31 @@ class _Phase:
         if watch:
             watch.device_exit(self._name)
         return False
+
+
+class TokenWait:
+    """One sequence's token-time account, from its first token on: the
+    causes' totals as they stood at its last emission, and what its waits
+    since the first came to.  The engine hands the same object to a
+    preempted sequence's continuation: its client keeps waiting."""
+
+    __slots__ = ("mark", "done_seq", "sums", "gap_max_s", "tokens", "t_last")
+
+    def __init__(self, mark: List[float], done_seq: int, t: float):
+        self.mark = mark
+        self.done_seq = done_seq
+        self.sums = [0.0] * len(CAUSES)
+        self.gap_max_s = 0.0
+        self.tokens = 0  # emitted after the first
+        self.t_last = t  # monotonic stamp of the last emission
+
+    def phase(self) -> Dict[str, float]:
+        """What the event that ends the sequence carries on
+        `TokenEvent.phase` (serving/api.py: span worker.decode)."""
+        out = {c + "_s": v for c, v in zip(CAUSES, self.sums)}
+        out.update(gap_max_s=self.gap_max_s, tokens=self.tokens,
+                   t_last=self.t_last)
+        return out
 
 
 class StepTimeline:
@@ -253,6 +304,19 @@ class StepTimeline:
         self._drained = False
         self._cur_name: Optional[str] = None
         self._cur_t = 0.0
+        # token time by cause: (ticket, cause) of the dispatched programs no
+        # device_wait has proved finished, oldest first; the causes' lifetime
+        # totals (sequences hold marks into them, so reset() moves a base
+        # instead of zeroing them); what live sequences' waits came to
+        self._flying: "collections.deque[tuple]" = collections.deque()
+        self._wait_cause = 0  # of the open device_wait, if drained
+        self._cause = [0.0] * len(CAUSES)
+        self._cause_base = [0.0] * len(CAUSES)
+        self.row_s = [0.0] * len(CAUSES)
+        self.token_gaps = 0
+        self._worst: List[Dict[str, Any]] = []  # longest first
+        self._worst_last: Optional[Dict[str, Any]] = None  # newest record
+        self._worst_floor = 0.0  # a wait must pass it to be kept
         # profiler annotations, only while a capture is open
         self._tracing = False  # the one test a segment pays outside one
         self._annotate: Optional[Any] = None
@@ -287,6 +351,12 @@ class StepTimeline:
         self.drained_total_s = 0.0
         self.drained_count = 0
         self._cur_name = self._loop_state
+        self._cause_base = list(self._cause)
+        self.row_s = [0.0] * len(CAUSES)
+        self.token_gaps = 0
+        self._worst = []
+        self._worst_last = None
+        self._worst_floor = 0.0
 
     def loop_state(self, name: str) -> None:
         """The loop driver's declaration, on the engine thread, of what it
@@ -319,18 +389,29 @@ class StepTimeline:
         self._annotate = None
         self._annotate_step = None
 
-    def _mark(self, now: float, name: Optional[str]) -> None:
-        """Segment boundary: [_cur_t, now) was `_cur_name`, `name` opens.
-        Whether the closed segment was drained is `_drained` as it stands
-        here; the flag only flips at a boundary, after its _mark."""
+    def _fold(self, now: float) -> None:
+        """Put [_cur_t, now) of the open segment down: to its cause, and
+        if drained to its segment.  What is unfinished is `_flying` and
+        `_drained` as they stand here; they only change at a boundary,
+        after its _mark."""
         cur = self._cur_name
-        if cur is not None and self._drained and cur != "device_wait":
-            dur = now - self._cur_t
-            if dur > 0:
+        dur = now - self._cur_t
+        if cur is not None and dur > 0:
+            if self._flying:
+                self._cause[self._flying[0][1]] += dur
+            elif cur == "device_wait":
+                self._cause[self._wait_cause] += dur
+            else:
+                self._cause[_DRAINED_CAUSE] += dur
+            if self._drained and cur != "device_wait":
                 self.drained_by[cur] += dur
                 self.drained_total_s += dur
-        self._cur_name = name
         self._cur_t = now
+
+    def _mark(self, now: float, name: Optional[str]) -> None:
+        """Segment boundary: [_cur_t, now) was `_cur_name`, `name` opens."""
+        self._fold(now)
+        self._cur_name = name
         if self._tracing:
             self._reannotate(name)
 
@@ -372,16 +453,21 @@ class StepTimeline:
                 self._ann_step.__enter__()
         self._mark(now, UNTRACKED)
 
-    def phase(self, name: str, upto: Optional[int] = None) -> _Phase:
+    def phase(self, name: str, upto: Optional[int] = None,
+              kind: str = "decode") -> _Phase:
         """Context manager for one instrumented phase of the open step.
         No-op outside an open draft (disabled timeline, or engine paths
         like the disagg prefill role that run outside step()).  `upto`,
         on a `device_wait`, is the ticket (`dispatch_seq` after its
         dispatch) of the program waited for; without it the wait is on
-        the newest program."""
-        return _Phase(self, name, upto)
+        the newest program.  `kind`, on a `dispatch`, is what the program
+        carries (CAUSES: `prompt` if any prompt token, else `decode`); on
+        a `device_wait`, what the implicit program it waits on belongs to
+        if the device is drained."""
+        return _Phase(self, name, upto, kind)
 
-    def _enter(self, name: str, upto: Optional[int] = None) -> None:
+    def _enter(self, name: str, upto: Optional[int] = None,
+               kind: str = "decode") -> None:
         d = self._draft
         if d is None:
             return
@@ -400,8 +486,10 @@ class StepTimeline:
             # at _last_return; program N+1 launches now. Clamped — async
             # scheduling dispatches N+1 before materializing N.
             d["gaps"].append(max(0.0, now - self._last_return))
-        stack.append([name, now, upto])
+        stack.append([name, now, upto, CAUSES.index(kind)])
         self._mark(now, name)
+        if name == "device_wait":
+            self._wait_cause = stack[-1][3]
 
     def _exit(self) -> None:
         d = self._draft
@@ -420,6 +508,7 @@ class StepTimeline:
             if top[0] == "dispatch":
                 # the device has work again: a drained interval ends here
                 self.dispatch_seq += 1
+                self._flying.append((self.dispatch_seq, top[3]))
                 if self._drained:
                     self._drained = False
                     self.drained_count += 1
@@ -428,6 +517,63 @@ class StepTimeline:
                 if done > self._done_seq:
                     self._done_seq = done
                 self._drained = self._done_seq >= self.dispatch_seq
+                flying = self._flying
+                while flying and flying[0][0] <= self._done_seq:
+                    flying.popleft()
+
+    # ------------------------------------------------ token time by cause --
+    def fold(self) -> float:
+        """Bring the account up to now (the open segment stays open) and
+        return now, on the monotonic clock."""
+        now = time.monotonic()
+        if self.enabled:
+            self._fold(now)
+        return now
+
+    def token_start(self) -> Optional[TokenWait]:
+        """A sequence's first token is out: its waits count from the
+        account as it stands (call `fold` first).  None when disabled."""
+        if not self.enabled:
+            return None
+        return TokenWait(list(self._cause), self._done_seq, self._cur_t)
+
+    def token_gap(self, wait: TokenWait, tokens: int,
+                  request_id: str) -> None:
+        """An emission gave `wait`'s sequence `tokens` tokens: the causes'
+        growth since its last one is one wait of that sequence, ended by
+        these tokens.  Reads the account as the last boundary left it (the
+        `detok` phase every emission opens, or a `fold`)."""
+        cause, mark, sums, row = self._cause, wait.mark, wait.sums, self.row_s
+        parts = [c - m for c, m in zip(cause, mark)]
+        gap = sum(parts)
+        for i, part in enumerate(parts):
+            mark[i] = cause[i]
+            sums[i] += part
+            row[i] += part
+        programs = self._done_seq - wait.done_seq
+        wait.done_seq = self._done_seq
+        wait.tokens += tokens
+        wait.t_last = self._cur_t
+        self.token_gaps += tokens
+        if gap > wait.gap_max_s:
+            wait.gap_max_s = gap
+        last = self._worst_last
+        if last is not None and last["_t"] == self._cur_t \
+                and last["gap_s"] == gap:
+            # the same wait, sat out by another sequence of this emission:
+            # one record, so that the 8 kept are 8 waits and not one stall
+            last["sequences"] += 1
+        elif gap > self._worst_floor:
+            rec = self._worst_last = {
+                "gap_s": gap, "programs": programs, "sequences": 1,
+                "t_unix_ns": time.time_ns(), "request_id": request_id,
+                "_t": self._cur_t}
+            rec.update((c + "_s", p) for c, p in zip(CAUSES, parts))
+            # a new list, swapped in whole: summary() reads from any thread
+            worst = sorted(self._worst + [rec], key=lambda r: -r["gap_s"])
+            self._worst = worst[:WORST_KEPT]
+            if len(worst) >= WORST_KEPT:
+                self._worst_floor = self._worst[-1]["gap_s"]
 
     def commit_step(self, **fields: Any) -> None:
         """Finalize the open step record.  Steps that measured nothing
@@ -509,6 +655,14 @@ class StepTimeline:
         return out
 
     # ----------------------------------------------------------- summary ---
+    def _token_time(self) -> Dict[str, Any]:
+        worst = [{k: round(v, 6) if isinstance(v, float) else v
+                  for k, v in rec.items() if k != "_t"}
+                 for rec in self._worst]
+        return _token_time_of(
+            [c - b for c, b in zip(self._cause, self._cause_base)],
+            self.row_s, self.token_gaps, worst)
+
     def summary(self) -> Dict[str, Any]:
         """Bubble-attribution rollup: per-phase p50/p95 + share of step
         wall time, the inter-dispatch host-gap distribution, and which
@@ -552,12 +706,24 @@ class StepTimeline:
                 "count": self.drained_count,
                 "by": {k: round(t, 6) for k, t in self.drained_by.items()},
             },
+            "token_time": self._token_time(),
         }
         bubble = _bubble_attribution(out["drained"]["by"],
                                      out["loop_wall_s"])
         if bubble is not None:
             out["bubble"] = bubble
         return out
+
+
+def _token_time_of(cause_s, row_s, gaps: int,
+                   worst: List[Dict[str, Any]]) -> Dict[str, Any]:
+    return {
+        "cause_s": {c: round(v, 6) for c, v in zip(CAUSES, cause_s)},
+        "row_s": {c: round(v, 6) for c, v in zip(CAUSES, row_s)},
+        "gaps": gaps,
+        "gap_max_s": worst[0]["gap_s"] if worst else 0.0,
+        "worst": worst,
+    }
 
 
 def _bubble_attribution(drained_by: Dict[str, float],
@@ -587,9 +753,17 @@ def merge_summaries(summaries: List[Dict[str, Any]]) -> Dict[str, Any]:
         "host_gap": {"count": 0, "total_s": 0.0, "p95_ms_max": 0.0},
         "drained": {"total_s": 0.0, "count": 0, "by": {}},
     }
+    cause_s, row_s = [0.0] * len(CAUSES), [0.0] * len(CAUSES)
+    gaps, worst = 0, []
     for s in summaries:
         if not s:
             continue
+        tt = s.get("token_time") or {}  # absent: a worker from before it
+        for i, c in enumerate(CAUSES):
+            cause_s[i] += (tt.get("cause_s") or {}).get(c, 0.0)
+            row_s[i] += (tt.get("row_s") or {}).get(c, 0.0)
+        gaps += tt.get("gaps", 0)
+        worst += tt.get("worst") or []
         agg["steps"] += s.get("steps", 0)
         agg["wall_s"] += s.get("wall_s", 0.0)
         agg["untracked_s"] += s.get("untracked_s", 0.0)
@@ -627,6 +801,9 @@ def merge_summaries(summaries: List[Dict[str, Any]]) -> Dict[str, Any]:
     agg["host_gap"]["total_s"] = round(agg["host_gap"]["total_s"], 6)
     for ph in agg["phases"].values():
         ph["total_s"] = round(ph["total_s"], 6)
+    agg["token_time"] = _token_time_of(
+        cause_s, row_s, gaps,
+        sorted(worst, key=lambda r: -r["gap_s"])[:WORST_KEPT])
     bubble = _bubble_attribution(agg["drained"]["by"], agg["loop_wall_s"])
     if bubble is not None:
         agg["bubble"] = bubble

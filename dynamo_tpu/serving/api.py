@@ -144,6 +144,12 @@ class StopStringMatcher:
         return emit
 
 
+def _unix_ns(t_monotonic: float) -> int:
+    """A time.monotonic() stamp on the unix clock spans live on."""
+    return (time.time_ns() - int(time.monotonic() * 1e9)
+            + int(t_monotonic * 1e9))
+
+
 class GenerationHandle:
     """A submitted request plus its event stream — submission (and its
     validation errors) happens strictly before any response bytes."""
@@ -241,8 +247,11 @@ class GenerationHandle:
             [(tok.decode([tid]), lp) for tid, lp in (ev.top_logprobs or [])],
         )
 
-    def _decode_span(self, ttft_s: float):
-        """Open the worker.decode span at the first TokenEvent."""
+    def _decode_span(self, ttft_s: float, phase: Optional[dict]):
+        """Open the worker.decode span at the first TokenEvent, back-dated
+        to the engine's own stamp of the first token where the event
+        carries one: run() ends it at the engine's stamp of the last, so
+        that its length is the token time the finishing event accounts."""
         if not self.span.recording:
             return None
         eng = self.ctx.engine
@@ -254,6 +263,8 @@ class GenerationHandle:
             })
         return self.ctx.tracer.start_span(
             "worker.decode", parent=self.span,
+            start_ns=(_unix_ns(phase["t_first"])
+                      if phase and "t_first" in phase else None),
             attributes={"ttft_s": round(ttft_s, 6)})
 
     def _first_token_written(self, phase: Optional[dict]) -> None:
@@ -280,7 +291,7 @@ class GenerationHandle:
         if not self.span.recording:
             return
         # spans live on the unix clock: one offset carries every stamp over
-        to_ns = time.time_ns() - int(t_written * 1e9)
+        to_ns = _unix_ns(0.0)
         prefill = eng.metrics.phases["prefill"]
         prefill_attributes = {
             "prompt_tokens": len(self.prompt_ids),
@@ -309,6 +320,7 @@ class GenerationHandle:
         t_prev: Optional[float] = None
         decode_span = None
         first_phase: Optional[dict] = None  # until the first frame is out
+        token_time: Optional[dict] = None  # the finishing event's account
         detok = IncrementalDetokenizer(ctx.tokenizer)
         matcher = StopStringMatcher(self.stops) if self.stops else None
         text_parts: List[str] = []
@@ -384,12 +396,14 @@ class GenerationHandle:
             if t_prev is None:
                 m.ttft.observe(now - t0, exemplar=ex, model=model)
                 m.tenant_ttft.observe(now - t0, tenant=self.tenant)
-                decode_span = self._decode_span(now - t0)
+                decode_span = self._decode_span(now - t0, ev.phase)
                 first_phase = ev.phase
             else:
                 m.itl.observe(now - t_prev, exemplar=ex, model=model)
                 m.tenant_itl.observe(now - t_prev, tenant=self.tenant)
             t_prev = now
+            if ev.finished and ev.phase and "t_last" in ev.phase:
+                token_time = ev.phase
             delta = ""
             lp_entry = None
             if ev.token_id >= 0:
@@ -467,7 +481,16 @@ class GenerationHandle:
                 "engine.decode_step.p95_ms":
                     round(eng_ph["decode_step"].quantile_ms(0.95), 3),
             })
-            decode_span.end()
+            end_ns = None
+            if token_time is not None:
+                # why THIS request's tokens took what they took: its waits
+                # by what the device was running (timeline.TokenWait)
+                decode_span.set_attributes({
+                    "tokens": token_time["tokens"],
+                    **{k: round(token_time[k], 6) for k in (
+                        "decode_s", "prompt_s", "drained_s", "gap_max_s")}})
+                end_ns = _unix_ns(token_time["t_last"])
+            decode_span.end(end_ns=end_ns)
         if (self.span.recording
                 and dur >= obs_tracing.slow_request_threshold_s()):
             log.warning(

@@ -147,6 +147,16 @@ def render(frame: Dict[str, Any], flight_n: int) -> List[str]:
                         phases.items(),
                         key=lambda kv: -kv[1].get("total_s", 0)))
                 out(f"          p50/p95  {parts}")
+            tt = tl.get("token_time") or {}
+            if tt.get("gaps"):
+                # what a live sequence's tokens waited behind, per token
+                per = "  ".join(
+                    f"{c}={1e3 * tt['row_s'].get(c, 0) / tt['gaps']:.2f}ms"
+                    for c in ("decode", "prompt", "drained"))
+                worst = (tt.get("worst") or [{}])[0]
+                out(f"          token    {per}"
+                    f"  longest gap={tt.get('gap_max_s', 0) * 1e3:.0f}ms"
+                    f" ({worst.get('request_id', '-')})")
         fl = w.get("flight")
         if fl and fl.get("records"):
             out(f"   flight ring={fl.get('size')}/{fl.get('capacity')}"

@@ -20,6 +20,10 @@ Covers observability/timeline.py and its engine + HTTP wiring:
 - drained time: what a `device_wait` leaves in flight decides whether the
   device is drained, every drained second is put down to one segment,
   and `bubble` follows `drained.by`;
+- token time by cause: every second of the thread goes to the kind of
+  the oldest unfinished program or to `drained`, the three sum to
+  `loop_wall_s`, a sequence's waits are charged to them at each emission,
+  the 8 longest keep their record, reset() and merge_summaries follow;
 - profiler annotations exist only between start_ and stop_annotations;
 - disabled mode + ring bounds + overhead budget of the on path.
 """
@@ -30,6 +34,7 @@ import pytest
 
 from dynamo_tpu.observability import timeline as timeline_mod
 from dynamo_tpu.observability.timeline import (
+    CAUSES,
     DRAINED_KEYS,
     PHASES,
     PhaseDigest,
@@ -407,9 +412,12 @@ def clock(monkeypatch):
 def _play(tl, clock, script):
     """Run a script of (op, arg, seconds) on the timeline: `loop` declares
     a loop state, `begin`/`commit` bracket a step, `enter`/`exit` a phase
-    (`enter` takes a name or (name, upto)), `idle` is time inside a step
-    that no phase claims. The clock moves by `seconds` AFTER each op, so
-    that is how long the segment the op opened lasts."""
+    (`enter` takes a name, (name, upto) or (name, upto, kind)), `idle` is
+    time inside a step
+    that no phase claims, `first` makes a sequence's TokenWait under the
+    name `arg` in `tl.waits` and `emit` = (name, tokens) charges it an
+    emission. The clock moves by `seconds` AFTER each op, so that is how
+    long the segment the op opened lasts."""
     for op, arg, seconds in script:
         if op == "loop":
             tl.loop_state(arg)
@@ -418,10 +426,15 @@ def _play(tl, clock, script):
         elif op == "commit":
             tl.commit_step()
         elif op == "enter":
-            name, upto = arg if isinstance(arg, tuple) else (arg, None)
-            tl._enter(name, upto)
+            name, upto, *kind = arg if isinstance(arg, tuple) else (arg, None)
+            tl._enter(name, upto, *kind)
         elif op == "exit":
             tl._exit()
+        elif op == "first":
+            tl.fold()
+            tl.__dict__.setdefault("waits", {})[arg] = tl.token_start()
+        elif op == "emit":
+            tl.token_gap(tl.waits[arg[0]], arg[1], arg[0])
         clock.t += seconds
 
 
@@ -616,6 +629,246 @@ def test_engine_loop_declares_no_work_only_without_work():
     parts = (sum(p["total_s"] for p in summ["phases"].values())
              + summ["untracked_s"] + sum(summ["loop"].values()))
     assert parts == pytest.approx(summ["loop_wall_s"], abs=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# token time by cause
+# ---------------------------------------------------------------------------
+def _dispatch(kind, host_s=0.002):
+    return [("enter", ("dispatch", None, kind), host_s), ("exit", None, 0.0)]
+
+
+def _wait(upto, wait_s, kind="decode"):
+    return [("enter", ("device_wait", upto, kind), wait_s),
+            ("exit", None, 0.0)]
+
+
+# each case: (script, expected token_time.cause_s without the zeros)
+_CAUSE_CASES = {
+    # async scheduling: window 2 is dispatched, then window 1 waited for,
+    # then window 3 dispatched and 2 waited for. A window is in flight
+    # throughout, so after the first dispatch nothing is drained; the host
+    # may learn late that 1 is done, but 1 and 2 are both `decode`
+    "async_windows_are_all_decode": (
+        [("loop", "between_steps", 0.001), ("begin", None, 0.0)]
+        + _dispatch("decode") + [("commit", None, 0.0005),
+                                 ("begin", None, 0.0)]
+        + _dispatch("decode") + _wait(1, 0.010)
+        + [("enter", "detok", 0.003), ("exit", None, 0.0),
+           ("commit", None, 0.0005), ("begin", None, 0.0)]
+        + _dispatch("decode") + _wait(2, 0.012)
+        + [("enter", "detok", 0.003), ("exit", None, 0.0),
+           ("commit", None, 0.0)],
+        {"drained": 0.003, "decode": 0.033}),
+    # a mixed step first materializes the pending window (a wait on ticket
+    # 1 with nothing newer: the device drains), then dispatches its own
+    # program, which carries a chunk: the wait on it is `prompt`, and
+    # what follows its readback is drained again
+    "a_mixed_step_after_a_pending_window": (
+        [("loop", "between_steps", 0.0), ("begin", None, 0.0)]
+        + _dispatch("decode") + [("commit", None, 0.0005),
+                                 ("begin", None, 0.0)]
+        + _wait(1, 0.008)
+        + [("enter", "detok", 0.002), ("exit", None, 0.0),
+           ("enter", "page_alloc", 0.001), ("exit", None, 0.0)]
+        + _dispatch("prompt", 0.004) + _wait(None, 0.028)
+        + [("enter", "detok", 0.002), ("exit", None, 0.0),
+           ("commit", None, 0.0)],
+        {"drained": 0.002 + 0.002 + 0.001 + 0.004 + 0.002,
+         "decode": 0.0005 + 0.008, "prompt": 0.028}),
+    # the mixed step's own readback leaves the device drained; the first
+    # token's sampling is then an implicit program, and a prompt's
+    "a_drained_wait_on_first_token_sampling": (
+        [("loop", "between_steps", 0.0), ("begin", None, 0.0)]
+        + _dispatch("prompt") + _wait(None, 0.030)
+        + [("enter", "detok", 0.001), ("exit", None, 0.0)]
+        + _wait(None, 0.0015, "prompt")
+        + [("enter", "detok", 0.0005), ("exit", None, 0.0),
+           ("commit", None, 0.0)],
+        {"drained": 0.002 + 0.001 + 0.0005, "prompt": 0.030 + 0.0015}),
+    # the OLDEST unfinished program decides: a chunk dispatched behind a
+    # window waits as `decode` until the window is proved done, a window
+    # behind a chunk as `prompt`; a wait on the newest ends both
+    "the_oldest_unfinished_program_decides": (
+        [("loop", "between_steps", 0.0), ("begin", None, 0.0)]
+        + _dispatch("decode") + _dispatch("prompt", 0.003)
+        + _wait(1, 0.010) + [("idle", None, 0.001)]
+        + _dispatch("decode", 0.002) + _wait(None, 0.020)
+        + [("commit", None, 0.004), ("loop", "no_work", 0.050),
+           ("loop", "between_steps", 0.0)],
+        {"drained": 0.002 + 0.004 + 0.050, "decode": 0.003 + 0.010,
+         "prompt": 0.001 + 0.002 + 0.020}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CAUSE_CASES))
+def test_every_second_goes_to_one_cause(clock, case):
+    script, want = _CAUSE_CASES[case]
+    tl = StepTimeline(capacity=8, enabled=True)
+    t_start = clock.t
+    _play(tl, clock, script + [("loop", "between_steps", 0.0)])
+    summ = tl.summary()
+    cause = summ["token_time"]["cause_s"]
+    assert tuple(cause) == CAUSES
+    assert {k: v for k, v in cause.items() if v} == pytest.approx(
+        want, abs=1e-9)
+    # exact by construction: one add a segment boundary
+    assert sum(cause.values()) == pytest.approx(summ["loop_wall_s"],
+                                                abs=1e-6)
+    assert summ["loop_wall_s"] == pytest.approx(clock.t - t_start, abs=1e-6)
+    # what the older drained account calls drained is `drained` here too
+    assert cause["drained"] >= summ["drained"]["total_s"] - 1e-9
+
+
+def test_token_gaps_are_charged_to_the_causes(clock):
+    """Two sequences over three emissions: each wait is the causes' growth
+    since the sequence's last emission, its parts sum to it, and the sums
+    are what the sequences' own accounts (TokenEvent.phase) hold."""
+    tl = StepTimeline(capacity=8, enabled=True)
+    _play(tl, clock,
+          [("loop", "between_steps", 0.0), ("begin", None, 0.0)]
+          + _dispatch("prompt") + _wait(None, 0.020, "prompt")
+          + [("first", "a", 0.0), ("commit", None, 0.001),
+             ("begin", None, 0.0)]
+          + _dispatch("decode") + _wait(None, 0.016)
+          + [("enter", "detok", 0.0), ("emit", ("a", 16), 0.001),
+             ("exit", None, 0.0), ("commit", None, 0.0),
+             ("begin", None, 0.0)]
+          # b's prompt rides a mixed step: a waits behind it
+          + _dispatch("prompt", 0.003) + _wait(None, 0.040)
+          + [("enter", "detok", 0.0), ("emit", ("a", 1), 0.0),
+             ("first", "b", 0.001), ("exit", None, 0.0),
+             ("commit", None, 0.0005), ("begin", None, 0.0)]
+          + _dispatch("decode") + _wait(None, 0.017)
+          + [("enter", "detok", 0.0), ("emit", ("a", 16), 0.0),
+             ("emit", ("b", 16), 0.001), ("exit", None, 0.0),
+             ("commit", None, 0.0)])
+    tt = tl.summary()["token_time"]
+    a, b = tl.waits["a"].phase(), tl.waits["b"].phase()
+    assert tt["gaps"] == 49 == a["tokens"] + b["tokens"]
+    assert a["tokens"] == 33 and b["tokens"] == 16
+    for c in CAUSES:
+        assert tt["row_s"][c] == pytest.approx(a[c + "_s"] + b[c + "_s"],
+                                               abs=1e-9)
+    # a: drained 1 ms fan-out + 2 ms dispatch, 16 ms window | 1 ms detok,
+    # 3 ms dispatch drained, 40 ms mixed | 1 ms detok + 0.5 + 2 drained,
+    # 17 ms window; b: from its first token on
+    assert a == pytest.approx(
+        {"decode_s": 0.033, "prompt_s": 0.040, "drained_s": 0.0105,
+         "gap_max_s": 0.044, "tokens": 33,
+         "t_last": tl.waits["a"].t_last}, abs=1e-9)
+    assert b == pytest.approx(
+        {"decode_s": 0.017, "prompt_s": 0.0, "drained_s": 0.0035,
+         "gap_max_s": 0.0205, "tokens": 16,
+         "t_last": tl.waits["b"].t_last}, abs=1e-9)
+    # Sigma row_s = Sigma over sequences of (last - first emitted token)
+    assert sum(tt["row_s"].values()) == pytest.approx(0.0835 + 0.0205,
+                                                      abs=1e-9)
+    assert tt["gap_max_s"] == pytest.approx(0.044, abs=1e-6)
+    # a's last wait and b's only one are the same 20.5 ms of one emission:
+    # one record for the two sequences
+    assert [(w["request_id"], w["sequences"]) for w in tt["worst"]] == [
+        ("a", 1), ("a", 2), ("a", 1)]
+    for w in tt["worst"]:
+        assert w["gap_s"] == pytest.approx(
+            w["decode_s"] + w["prompt_s"] + w["drained_s"], abs=2e-6)
+        assert w["gap_s"] <= tt["gap_max_s"] and w["programs"] == 1
+        assert w["t_unix_ns"] > 0
+    assert tt["worst"][0]["prompt_s"] == pytest.approx(0.040, abs=1e-6)
+
+
+def test_the_eight_longest_waits_are_kept_and_reset_starts_over(clock):
+    tl = StepTimeline(capacity=8, enabled=True)
+    script = [("loop", "between_steps", 0.0), ("begin", None, 0.0),
+              ("first", "s", 0.0)]
+    for i in range(12):
+        script += _dispatch("decode", 0.0) + _wait(None, 0.001 * (i + 1)) \
+            + [("enter", "detok", 0.0), ("emit", ("s", 1), 0.0),
+               ("exit", None, 0.0)]
+    _play(tl, clock, script + [("commit", None, 0.0)])
+    tt = tl.summary()["token_time"]
+    assert [round(w["gap_s"], 6) for w in tt["worst"]] == [
+        round(0.001 * i, 6) for i in range(12, 4, -1)]
+    assert tt["gaps"] == 12 and tt["gap_max_s"] == pytest.approx(0.012)
+    assert tt["row_s"]["decode"] == pytest.approx(0.078)
+    # reset() zeroes the account; a live sequence's next wait is still
+    # whole (its marks are into totals that reset() does not touch)
+    tl.reset()
+    zero = tl.summary()["token_time"]
+    assert zero == {"cause_s": dict.fromkeys(CAUSES, 0.0),
+                    "row_s": dict.fromkeys(CAUSES, 0.0), "gaps": 0,
+                    "gap_max_s": 0.0, "worst": []}
+    _play(tl, clock, [("begin", None, 0.0)] + _dispatch("decode", 0.0)
+          + _wait(None, 0.005) + [("enter", "detok", 0.0),
+                                  ("emit", ("s", 2), 0.0),
+                                  ("exit", None, 0.0), ("commit", None, 0.0)])
+    tt = tl.summary()["token_time"]
+    assert tt["gaps"] == 2 and tt["cause_s"]["decode"] == pytest.approx(0.005)
+    assert tt["row_s"] == pytest.approx(
+        {"decode": 0.005, "prompt": 0.0, "drained": 0.0})
+    # a disabled timeline keeps no account and hands out no TokenWait
+    off = StepTimeline(capacity=8, enabled=False)
+    off.fold()
+    assert off.token_start() is None
+    assert off.summary()["token_time"]["gaps"] == 0
+
+
+def test_merge_summaries_sums_token_time():
+    def worker(scale, rid):
+        return {"steps": 1, "wall_s": 1.0, "token_time": {
+            "cause_s": {"decode": 3.0 * scale, "prompt": 1.0 * scale,
+                        "drained": 0.5 * scale},
+            "row_s": {"decode": 30.0 * scale, "prompt": 8.0 * scale,
+                      "drained": 2.0 * scale},
+            "gaps": 1000 * scale, "gap_max_s": 0.6 * scale,
+            "worst": [{"gap_s": g * scale, "decode_s": 0.0,
+                       "prompt_s": g * scale, "drained_s": 0.0,
+                       "programs": 2, "t_unix_ns": 1, "request_id": rid}
+                      for g in (0.6, 0.5, 0.4, 0.3, 0.25, 0.2, 0.15, 0.1)]}}
+
+    old = {"steps": 1, "wall_s": 1.0}  # a worker from before the account
+    tt = merge_summaries([worker(1, "a"), worker(2, "b"), old, {}])[
+        "token_time"]
+    assert tt["cause_s"] == {"decode": 9.0, "prompt": 3.0, "drained": 1.5}
+    assert tt["row_s"] == {"decode": 90.0, "prompt": 24.0, "drained": 6.0}
+    assert tt["gaps"] == 3000 and tt["gap_max_s"] == 1.2
+    assert [(w["request_id"], w["gap_s"]) for w in tt["worst"]] == [
+        ("b", 1.2), ("b", 1.0), ("b", 0.8), ("a", 0.6), ("b", 0.6),
+        ("a", 0.5), ("b", 0.5), ("a", 0.4)]
+    assert merge_summaries([old])["token_time"]["gaps"] == 0
+
+
+def test_dynamo_top_prints_token_time_by_cause(clock):
+    """scripts/dynamo_top.py: one `token` line a worker, from the summary
+    that rides /worker/stats; none before a token ended a wait."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "dynamo_top", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "scripts", "dynamo_top.py"))
+    top = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(top)
+    tl = StepTimeline(capacity=8, enabled=True)
+    _play(tl, clock, [("begin", None, 0.0)] + _dispatch("prompt")
+          + _wait(None, 0.030) + [("first", "req-7", 0.0),
+                                  ("commit", None, 0.0)])
+
+    def lines():
+        frame = {"ts": "00:00:00", "workers": [{
+            "url": "http://w", "flight": None,
+            "stats": {"model": "m", "timeline": tl.summary()}}]}
+        return [ln for ln in top.render(frame, 0) if " token " in ln]
+
+    assert lines() == []
+    _play(tl, clock, [("begin", None, 0.0)] + _dispatch("decode", 0.004)
+          + _wait(None, 0.196) + [("enter", "detok", 0.0),
+                                  ("emit", ("req-7", 16), 0.0),
+                                  ("exit", None, 0.0), ("commit", None, 0.0)])
+    (line,) = lines()
+    assert "decode=12.25ms" in line and "prompt=0.00ms" in line
+    assert "drained=0.25ms" in line
+    assert "longest gap=200ms (req-7)" in line
 
 
 # ---------------------------------------------------------------------------

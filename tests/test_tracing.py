@@ -171,7 +171,9 @@ def test_worker_stats_carry_what_the_layer_metrics_read(server):
                         ("timeline.", "metrics.")):
                     named.add(item)
     assert {"timeline.loop_wall_s", "timeline.drained.by.no_work",
-            "metrics.first_token.ttft_s"} <= named
+            "metrics.first_token.ttft_s", "timeline.token_time.gaps",
+            "timeline.token_time.row_s.prompt",
+            "timeline.token_time.gap_max_s"} <= named
     # a phase that never ran has no entry under timeline.phases: the
     # shipped readers read an absent path as 0 (readers/stats_delta.py)
     missing = sorted(p for p in named if not has(p)
@@ -179,7 +181,9 @@ def test_worker_stats_carry_what_the_layer_metrics_read(server):
     assert not missing, f"/worker/stats lacks {missing}"
     tl = stats["timeline"]
     assert {"wall_s", "steps", "phases", "untracked_s", "host_gap", "bubble",
-            "loop_wall_s", "loop", "drained"} <= set(tl)
+            "loop_wall_s", "loop", "drained", "token_time"} <= set(tl)
+    assert set(tl["token_time"]) == {"cause_s", "row_s", "gaps",
+                                     "gap_max_s", "worst"}
     assert set(tl["bubble"]) == {"gap_eater", "host_shares"}
     dr = tl["drained"]
     assert sum(dr["by"].values()) == pytest.approx(dr["total_s"], abs=1e-4)
@@ -187,6 +191,94 @@ def test_worker_stats_carry_what_the_layer_metrics_read(server):
     # the request came through an HTTP handler: all four stages are there
     ft = stats["metrics"]["first_token"]
     assert ft["count"] >= 1 and ft["submit_s"] > 0.0 and ft["emit_s"] > 0.0
+
+
+def test_token_time_per_request_is_the_engines_account(server):
+    """Three streamed requests: timeline.token_time grows by what their
+    worker.decode spans carry, cause by cause; each span runs from the
+    engine's stamp of the request's first token to that of its last, which
+    is the sum of its three parts; the longest waits keep their record."""
+    import time
+
+    from dynamo_tpu.observability.timeline import CAUSES
+
+    ctx, base = server
+
+    def stats():
+        return json.load(urllib.request.urlopen(f"{base}/worker/stats",
+                                                timeout=30))
+
+    def post(rid, prompt, max_tokens):
+        body = json.dumps({"model": "tiny-debug", "prompt": prompt,
+                           "max_tokens": max_tokens, "stream": True,
+                           "ignore_eos": True}).encode()
+        urllib.request.urlopen(urllib.request.Request(
+            f"{base}/v1/completions", data=body,
+            headers={"Content-Type": "application/json",
+                     "x-request-id": rid}), timeout=120).read()
+
+    before = stats()
+    rids = ["c1" * 16, "c2" * 16, "c3" * 16]
+    wants = (12, 7, 5)
+    # the second arrives while the first decodes: one waits behind the
+    # other's prompt; the third runs alone
+    pair = [threading.Thread(target=post, args=(rid, "hello there " * k, n))
+            for rid, k, n in zip(rids, (1, 2), wants)]
+    for th in pair:
+        th.start()
+    for th in pair:
+        th.join()
+    post(rids[2], "abc", wants[2])
+    ids = {new_trace_id(rid) for rid in rids}
+    decode, deadline = [], time.monotonic() + 5.0
+    while len(decode) < 3 and time.monotonic() < deadline:
+        decode = [sp for sp in ctx.tracer.collector.snapshot()
+                  if sp.name == "worker.decode" and sp.trace_id in ids]
+        time.sleep(0.02)
+    assert len(decode) == 3
+    after = stats()
+    tt0 = before["timeline"]["token_time"]
+    tt1 = after["timeline"]["token_time"]
+    for c in CAUSES:
+        assert tt1["row_s"][c] - tt0["row_s"][c] == pytest.approx(
+            sum(sp.attributes[c + "_s"] for sp in decode), abs=1e-5)
+    # every token but a sequence's first ends a wait
+    grew = tt1["gaps"] - tt0["gaps"]
+    assert grew == sum(sp.attributes["tokens"] for sp in decode) \
+        == sum(wants) - 3
+    assert grew == (after["metrics"]["output_tokens"]
+                    - before["metrics"]["output_tokens"]) - 3
+    for sp in decode:
+        a = sp.attributes
+        parts = a["decode_s"] + a["prompt_s"] + a["drained_s"]
+        assert (sp.end_ns - sp.start_ns) / 1e9 == pytest.approx(
+            parts, abs=1e-5)
+        assert 0.0 < a["gap_max_s"] <= parts + 1e-6
+        assert a["completion_tokens"] == a["tokens"] + 1
+    assert sum(tt1["row_s"].values()) > sum(tt0["row_s"].values())
+    worst = tt1["worst"]
+    assert 1 <= len(worst) <= 8
+    assert [w["gap_s"] for w in worst] == sorted(
+        (w["gap_s"] for w in worst), reverse=True)
+    for w in worst:
+        assert tt1["gap_max_s"] >= w["gap_s"]
+        assert w["decode_s"] + w["prompt_s"] + w["drained_s"] \
+            == pytest.approx(w["gap_s"], abs=3e-6)
+        assert w["programs"] >= 0 and w["request_id"]
+    # the thread's time by cause is its whole time (read between two of
+    # the idle loop's ticks: the totals are folded one after the other)
+    for _ in range(50):
+        tl = stats()["timeline"]
+        if abs(sum(tl["token_time"]["cause_s"].values())
+               - tl["loop_wall_s"]) < 1e-4:
+            break
+        time.sleep(0.013)
+    assert sum(tl["token_time"]["cause_s"].values()) == pytest.approx(
+        tl["loop_wall_s"], abs=1e-4)
+    # /debug/timeline?format=summary carries the same account
+    summ = json.load(urllib.request.urlopen(
+        f"{base}/debug/timeline?format=summary", timeout=30))
+    assert summ["token_time"]["gaps"] >= tt1["gaps"]
 
 
 def test_capture_annotates_the_stepline_and_then_stops(server):

@@ -251,8 +251,19 @@ class EngineMetrics:
             kind: dict.fromkeys(self.attn, 0) for kind in ("full", "window")}
         # prefix-cache hits a model of kinds turned into misses (a hit is
         # exact only if the sliding layers' rows before it are still held;
-        # a ring is its sequence's own, so none is)
+        # a ring is its sequence's own, so none is), and those of a hybrid
+        # model (a hit at block b needs the state at b, which nothing keeps)
         self.prefix_hits_inexact = 0
+        # what the Mamba-2 layers of a hybrid model were asked for (all zero
+        # for any other model), counted like `attn`: on the host at
+        # dispatch, a layer's worth. decode_rows: live rows x steps, each
+        # one state read and written; chunk_tokens / chunk_calls: prompt
+        # tokens the chunked scan ran over and the programs that ran it;
+        # layer_steps: steps a Mamba-2 layer ran (a fused window counts its
+        # steps)
+        self.ssm: Dict[str, int] = {
+            "decode_rows": 0, "chunk_tokens": 0, "chunk_calls": 0,
+            "layer_steps": 0}
         # what the sparse-attention indexer of a DeepSeek-V3.2-style model
         # was asked for (all zero for any other model), counted like
         # `attn`: on the host at dispatch, a layer's worth. A query in a
@@ -277,12 +288,13 @@ class EngineMetrics:
         contexts (tokens in the cache, the one being decoded included)
         are `contexts` at its first step. `window`: None = a model of one
         kind (`attn`); else both kinds of `attn_kinds`, a sliding layer's
-        query reading min(context, window) rows."""
+        query reading min(context, window) rows (0: full layers alone, a
+        hybrid model's)."""
         if window is not None:
             ctx = (np.asarray(list(contexts), np.int64)[:, None]
                    + np.arange(steps, dtype=np.int64)[None, :])
-            for kind, rows in (("full", ctx),
-                               ("window", np.minimum(ctx, window))):
+            for kind in ("full", "window") if window else ("full",):
+                rows = ctx if kind == "full" else np.minimum(ctx, window)
                 a = self.attn_kinds[kind]
                 a["decode_q_rows"] += int(ctx.size)
                 a["decode_kv_rows"] += int(rows.sum())
@@ -304,7 +316,8 @@ class EngineMetrics:
             pos = start + np.arange(take, dtype=np.int64)
             first = pos[::block_q]  # each block's first token
             last = np.minimum(first + block_q, start + take)  # horizon
-            for kind, w in (("full", None), ("window", window)):
+            for kind in ("full", "window") if window else ("full",):
+                w = None if kind == "full" else window
                 a = self.attn_kinds[kind]
                 a["mixed_decode_q_rows"] += int(ctx.size)
                 a["mixed_decode_kv_rows"] += int(
@@ -350,6 +363,17 @@ class EngineMetrics:
             d["queries_unselected"] += int((c <= topk).sum())
             if selects:
                 d["rows_selected"] += int(np.minimum(c, topk).sum())
+
+    def observe_ssm(self, decode_rows: int, steps: int,
+                    chunk_tokens: int = 0) -> None:
+        """One dispatch of a hybrid model: `decode_rows` live rows over
+        `steps` steps, and `chunk_tokens` of a prompt (0: no chunk)."""
+        s = self.ssm
+        s["decode_rows"] += decode_rows * steps
+        s["layer_steps"] += steps
+        if chunk_tokens:
+            s["chunk_tokens"] += chunk_tokens
+            s["chunk_calls"] += 1
 
     def observe_moe(self, stats) -> None:
         """One program's expert-layer counts (a device array, maybe still
@@ -443,7 +467,7 @@ class EngineMetrics:
             self.phases[n] = PhaseTimer()
 
     def kernel_counters(self) -> Dict[str, dict]:
-        """What the kernels were asked for (attn, attn_kinds, dsa, moe), as
+        """What the kernels were asked for (attn, attn_kinds, dsa, moe, ssm), as
         snapshot() gives them and alone: a profiler capture samples these
         a few times a second (serving/api.py capture_trace)."""
         with self._moe_lock:
@@ -452,7 +476,8 @@ class EngineMetrics:
         return {"attn": dict(self.attn),
                 "attn_kinds": {k: dict(v)
                                for k, v in self.attn_kinds.items()},
-                "dsa": dict(self.dsa), "moe": dict(self.moe)}
+                "dsa": dict(self.dsa), "moe": dict(self.moe),
+                "ssm": dict(self.ssm)}
 
     def snapshot(self) -> Dict[str, float]:
         out = {k: v for k, v in self.__dict__.items()
@@ -461,7 +486,7 @@ class EngineMetrics:
                             "spec_accepted_by", "spec_hist_by",
                             "spec_sum_by", "spec_count_by",
                             "first_token", "_first_token_lock", "moe", "attn",
-                            "attn_kinds", "dsa", "_moe_pending",
+                            "attn_kinds", "dsa", "ssm", "_moe_pending",
                             "_moe_lock")}
         out.update(self.kernel_counters())
         with self._first_token_lock:
@@ -595,6 +620,27 @@ class Engine:
                 raise ValueError(
                     "a model whose layers are of more than one kind "
                     f"(layer_types) is not served with: {'; '.join(unserved)}")
+        if model_cfg.mixer_types:
+            # a hybrid model: a state slot a sequence beside its pages
+            # (engine/kv_cache.py). What would have to carry, split or roll
+            # back a state refuses here, by name
+            unserved = [name for name, on in (
+                ("speculation (a verify window would have to roll a state "
+                 "back)", cfg.speculative_mode != "off"),
+                ("sequence parallelism", cfg.sequence_parallel > 1),
+                ("tensor parallelism (heads and groups of a state split "
+                 "over a model axis)", cfg.tensor_parallel > 1),
+                ("an int8 KV cache", cfg.kv_cache_dtype == "int8"),
+                ("LoRA adapters", cfg.lora_slots > 0),
+                ("the KVBM host tier (a block carries no state)",
+                 cfg.kvbm_host_blocks > 0),
+                ("disaggregated prefill / decode (the KV transfer carries "
+                 "no state)", cfg.disaggregation_mode != "agg"),
+            ) if on]
+            if unserved:
+                raise ValueError(
+                    "a hybrid model (mixer_types: state-space layers) is "
+                    f"not served with: {'; '.join(unserved)}")
         if cfg.sequence_parallel > 1:
             # long-context serving: prefill shards the sequence over the
             # `seq` axis (ring/Ulysses over ICI); params/KV shard on
@@ -677,6 +723,8 @@ class Engine:
                 -(-max(cfg.prefill_chunk_tokens, cfg.mixed_batch_tokens)
                   // ps) * ps,
                 2 * max(1, cfg.num_scheduler_steps) + ps),
+            # a hybrid model: a state slot a decode slot
+            state_slots=cfg.max_num_seqs,
         )
         # MLA pools replicate across the model axis (every TP shard scores
         # its local heads against the FULL shared latent row); classic
@@ -692,6 +740,10 @@ class Engine:
         if self.kv_spec.window_layers:
             self.win_rings = WindowRings(self.kv_spec.window_pages,
                                          self.kv_spec.ring_pages)
+        # a cached prefix is never served, only counted: a ring is its
+        # sequence's own, and nothing keeps a state at a block boundary
+        self._prefix_recomputed = (self.win_rings is not None
+                                   or bool(self.model_cfg.mixer_types))
         self.prefix_cache: Optional[PrefixCache] = None
         if cfg.mixed_batch_tokens > 0:
             # the unified ragged step packs prefill-chunk tokens into the
@@ -1459,7 +1511,7 @@ class Engine:
         # consults the cache — a second pass there would just re-run every
         # bucket and delay /ready)
         passes = "bc" if ((self.prefix_cache is not None
-                           or self.win_rings is not None)
+                           or self._prefix_recomputed)
                           and cfg.disaggregation_mode != "prefill") else "b"
         for tag in passes:
             for bucket in sorted(buckets):
@@ -1501,16 +1553,18 @@ class Engine:
                 self.release_parked(r.request_id)
         else:
             for r in reqs:
-                # pools by kind serve no cached prefix: the second pass is
-                # told to chunk, so that a prompt whose decoders leave
-                # before it is done finds its chunk program compiled
-                self._warm_chunked = (self.win_rings is not None
+                # pools by kind and state slots serve no cached prefix: the
+                # second pass is told to chunk, so that a prompt whose
+                # decoders leave before it is done finds its chunk program
+                # compiled
+                self._warm_chunked = (self._prefix_recomputed
                                       and r.request_id.startswith("__warm_c"))
                 self.add_request(r)
                 while self.has_work:  # one at a time: fused window needs
                     self.step()       # an empty pending queue to engage
             self._warm_chunked = False
-            if cfg.max_prefill_batch > 1 and not self.model_cfg.layer_types:
+            if cfg.max_prefill_batch > 1 and not (
+                    self.model_cfg.layer_types or self.model_cfg.mixer_types):
                 # batched-admission variants: enqueue a full same-bucket
                 # burst per groupable bucket so _prefill_group's padded
                 # program compiles before /ready. A bucket is groupable
@@ -2077,18 +2131,19 @@ class Engine:
         rule. Holdings (KV bytes on device) always come from the live
         holder set, so byte-seconds track actual residency."""
         pb = self._page_nbytes
+        sb = self.kv_spec.bytes_per_slot()  # a hybrid model's state slot
         holdings: Dict[str, float] = {}
         computed: Dict[str, float] = {}
         for seq in list(self.seqs.values()):
             t = self._tenant_of(seq.req) if seq.req is not None else "default"
             computed[t] = computed.get(t, 0.0) + 1.0
-            holdings[t] = holdings.get(t, 0.0) + len(seq.pages) * pb
+            holdings[t] = holdings.get(t, 0.0) + len(seq.pages) * pb + sb
         inf = self._inflight
         if inf is not None:
             t = self._tenant_of(inf.req)
             if take > 0:
                 computed[t] = computed.get(t, 0.0) + float(take)
-            holdings[t] = holdings.get(t, 0.0) + len(inf.pages) * pb
+            holdings[t] = holdings.get(t, 0.0) + len(inf.pages) * pb + sb
         for rid, parked in list(self._parked.items()):
             t = self._rid_tenant.get(rid, "default")
             holdings[t] = holdings.get(t, 0.0) + len(parked[0]) * pb
@@ -2224,11 +2279,12 @@ class Engine:
             # eviction pressure valve evict this very request's cached
             # prefix to satisfy an allocation it never makes
             cached_pages, n_cached = [], 0
-            if self.prefix_cache is not None and self.win_rings is not None:
+            if self.prefix_cache is not None and self._prefix_recomputed:
                 # pools by kind: a hit at block b is exact only if the
                 # sliding layers' rows of [b - window, b) are still held,
-                # and a ring is its sequence's own: served as a miss, and
-                # counted
+                # and a ring is its sequence's own; a hybrid model: the hit
+                # needs the state at b, which nothing keeps. Served as a
+                # miss (the prompt is recomputed), and counted
                 if self.prefix_cache.has_prefix(
                         req.prompt_token_ids,
                         namespace=self._kv_namespace(req.adapter)):
@@ -2298,8 +2354,9 @@ class Engine:
         batched admission (up to max_prefill_batch, bounded by free slots
         and page supply). Requests on the chunked/cached path stay queued
         for the normal loop. A model whose layers are of more than one kind
-        admits one at a time (llama.prefill_batch turns no ring)."""
-        if self.model_cfg.layer_types:
+        admits one at a time (llama.prefill_batch turns no ring), and so
+        does a hybrid model (a lane would need a state slot of its own)."""
+        if self.model_cfg.layer_types or self.model_cfg.mixer_types:
             return [req]
         cfg = self.cfg
         group = [req]
@@ -2636,9 +2693,14 @@ class Engine:
                 jnp.int32(prompt_len),
                 self.k_pages,
                 self.v_pages,
-                self._pages_operand(pages_arr, req.request_id),
+                # the slot _finalize_admission pops for this prompt next
+                self._pages_operand(
+                    pages_arr, req.request_id,
+                    self._free_slots[-1] if self._free_slots else 0),
                 *lx,
             )
+        if self.model_cfg.mixer_types:
+            self.metrics.observe_ssm(0, 1, prompt_len)
         got = self._first_token_or_abort(events, req, pages, prompt_len,
                                          last_logits, "prefill")
         if got is None:
@@ -2881,9 +2943,13 @@ class Engine:
             self.win_rings.grow(
                 request_id, max(1, -(-tokens // self.cfg.page_size)))
 
-    def _pages_operand(self, pages_arr, request_id: str):
-        """A prompt's page table as its program takes it: the array, or
-        with pools by kind the (full table, ring) pair."""
+    def _pages_operand(self, pages_arr, request_id: str, slot: int):
+        """A prompt's page table as its program takes it: the array, with
+        pools by kind the (full table, ring) pair, and for a hybrid model
+        the table and the state slot (the decode slot `slot` the prompt
+        will decode in) its chunks carry their state in."""
+        if self.model_cfg.mixer_types:
+            return llama.SlotPages(jnp.asarray(pages_arr), jnp.int32(slot))
         if self.win_rings is None:
             return jnp.asarray(pages_arr)
         return llama.ByKind(jnp.asarray(pages_arr),
@@ -2945,8 +3011,9 @@ class Engine:
         a chunk's selection: ops/attention.dsa_chunk_attention)."""
         topk = self.model_cfg.index_topk
         cfg = self.cfg
-        if self.model_cfg.layer_types:
-            # layers of more than one kind: a width every factor of four
+        if self.model_cfg.layer_types or self.model_cfg.mixer_types:
+            # layers of more than one kind, or a hybrid model's layers (all
+            # unrolled in every program): a width every factor of four
             # below the longest, down to 2,048 tokens (32,768 | 8,192 |
             # 2,048). Each program holds a period's layers unrolled, so a
             # width costs more to compile than a one-kind model's; the
@@ -2985,7 +3052,8 @@ class Engine:
                 jnp.int32(take),
                 self.k_pages,
                 self.v_pages,
-                self._pages_operand(inf.pages_arr, inf.req.request_id),
+                self._pages_operand(inf.pages_arr, inf.req.request_id,
+                                    inf.slot),
                 *lx,
             )
         inf.done += take
@@ -2993,10 +3061,12 @@ class Engine:
         self.metrics.prefill_time_s += dt
         self.metrics.observe_phase("prefill_chunk", dt)
         self._observe_dsa_prompt(start, take, len(inf.pages_arr))
-        if self.model_cfg.layer_types:
+        if self.model_cfg.layer_types or self.model_cfg.mixer_types:
             # the same kernels' work as a mixed step's chunk, no decode row
             self.metrics.observe_mixed_attention(
                 [], start, take, window=self.model_cfg.sliding_window)
+        if self.model_cfg.mixer_types:
+            self.metrics.observe_ssm(0, 1, take)
         # this dispatch ran the chunk alone — its tenant owns the segment
         self._step_obs("prefill_chunk", dt, take=take,
                        shares={self._tenant_of(inf.req): float(take)})
@@ -3102,7 +3172,7 @@ class Engine:
                 px = (jnp.asarray(p_tokens), jnp.int32(start),
                       jnp.int32(take),
                       self._pages_operand(inf.pages_arr,
-                                          inf.req.request_id))
+                                          inf.req.request_id, inf.slot))
                 if self.lora is not None:
                     px += (jnp.int32(inf.aslot),)
             ys, *out = fn(*args, self.k_pages, self.v_pages, *lx, *px)
@@ -3641,10 +3711,14 @@ class Engine:
             start, take = chunk
             m.observe_phase("mixed_step", dt)
             m.observe_mixed(take, len(slots))
-        kinds = bool(self.model_cfg.layer_types)
+        hybrid = bool(self.model_cfg.mixer_types)
+        kinds = bool(self.model_cfg.layer_types) or hybrid
+        if hybrid:
+            m.observe_ssm(len(slots), steps, take)
         if self.model_cfg.is_mla or kinds:  # read by the kernels' rooflines
             contexts = [self.seqs[s].num_tokens for s in slots
                         if s in self.seqs]
+            # a hybrid model's sliding_window is 0: its full layers alone
             w = self.model_cfg.sliding_window if kinds else None
             if chunk is not None:
                 m.observe_mixed_attention(contexts, start, take, window=w)

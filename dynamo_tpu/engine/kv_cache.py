@@ -36,6 +36,20 @@ out of every query's reach is handed back by being written over, and a
 sequence never holds more than W pages a sliding layer. Both pools ride the
 engine's (k_pages, v_pages) plumbing as one pytree each.
 
+A HYBRID model (`ModelConfig.mixer_types`: state-space, expert and attention
+layers, each layer one mixer) keeps a third thing a sequence owns beside
+pages and rings: a STATE SLOT. Only its attention layers own pages (the pool
+`--num-pages` sizes has `num_layers` = those layers); every Mamba-2 layer
+keeps, for each decode slot, the state S [H, P, N] float32 and the conv's
+last K-1 input rows (`KVCacheSpec.ssm_shape` / `conv_shape`,
+`models/llama.StatePools`). The slot IS the decode slot: the engine
+reserves it at admission before the first chunk (a chunked prompt's state
+rides there between steps), decode row b updates slot b where it lies, and
+the slot goes back at finish, abort and preemption. A state does not grow
+with the context and is overwritten, not appended to: a prefix hit at block
+b would need the state at b, which nothing keeps, so it is served as a miss;
+a slot's first chunk (start 0) begins from zero whatever the slot held.
+
 Page 0 is a reserved "trash" page: inactive batch slots point at it so the
 full-batch decode step stays shape-static without masking scatter writes.
 
@@ -52,7 +66,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dynamo_tpu.models.config import FULL, SLIDING, ModelConfig
+from dynamo_tpu.models.config import (ATTENTION, FULL, MAMBA, SLIDING,
+                                      ModelConfig)
 
 
 class OutOfPages(Exception):
@@ -85,17 +100,27 @@ class KVCacheSpec:
     window_layers: int = 0
     window_pages: int = 0
     ring_pages: int = 0
+    # a hybrid model (module docstring): num_layers above counts its
+    # attention layers; each of its state_layers Mamba-2 layers keeps, a
+    # decode slot, one state of ssm_shape (float32) and conv_shape rows
+    # (the model's dtype). 0: no state.
+    state_layers: int = 0
+    state_slots: int = 0
+    ssm_shape: tuple = ()
+    conv_shape: tuple = ()
 
     @staticmethod
     def from_model(
         cfg: ModelConfig, num_pages: int, page_size: int,
         kv_dtype: str = "auto", tensor_parallel: int = 1,
         window_slots: int = 0, window_ahead: int = 0,
+        state_slots: int = 0,
     ) -> "KVCacheSpec":
         """`window_slots` / `window_ahead` (a model of kinds only): the
         sequences that may hold a ring at once and the tokens a step may
         write ahead of the oldest query in flight; they size the sliding
-        layers' pool."""
+        layers' pool. `state_slots` (a hybrid model only): the decode
+        slots, a state slot each."""
         if kv_dtype not in ("auto", "", "int8"):
             # only exactly "int8" takes the packed-scale quantized path;
             # any other narrow dtype would silently value-cast KV garbage
@@ -126,6 +151,20 @@ class KVCacheSpec:
                 window_layers=cfg.kind_layers(SLIDING), ring_pages=ring,
                 # a ring a slot, and the trash page
                 window_pages=window_slots * ring + 1)
+        if cfg.mixer_types:
+            if quantized or tensor_parallel > 1:
+                raise ValueError(
+                    "a hybrid model (mixer_types) is served with bf16 KV on "
+                    "one chip a replica: int8 rows, and heads and groups "
+                    "of a state split over a model axis, are not "
+                    "implemented")
+            if state_slots <= 0:
+                raise ValueError("a hybrid model needs state_slots")
+            kinds = dict(  # the spec's fields of a hybrid model
+                state_layers=cfg.mixer_layers(MAMBA), state_slots=state_slots,
+                ssm_shape=(cfg.mamba_num_heads, cfg.mamba_head_dim,
+                           cfg.ssm_state_size),
+                conv_shape=(cfg.conv_kernel - 1, cfg.mamba_conv_dim))
         blocks = 1 if cfg.is_mla else tensor_parallel
         if quantized and kv_heads % blocks != 0:
             raise ValueError(
@@ -134,7 +173,9 @@ class KVCacheSpec:
                 f"({kv_heads}) — the packed-scale rows are blocked "
                 f"per TP shard")
         return KVCacheSpec(
-            num_layers=(cfg.kind_layers(FULL) if kinds else cfg.num_layers),
+            num_layers=(cfg.mixer_layers(ATTENTION) if cfg.mixer_types
+                        else cfg.kind_layers(FULL) if kinds
+                        else cfg.num_layers),
             **kinds,
             num_kv_heads=kv_heads,
             num_pages=num_pages,
@@ -198,6 +239,16 @@ class KVCacheSpec:
             out["window"] = self.window_layers * row
         return out
 
+    def bytes_per_slot(self) -> int:
+        """Bytes one state slot costs over the Mamba-2 layers (0 without):
+        what a hybrid model's sequence owns beside its pages, whatever its
+        length."""
+        if not self.state_layers:
+            return 0
+        return self.state_layers * (
+            int(np.prod(self.ssm_shape)) * 4
+            + int(np.prod(self.conv_shape)) * jnp.dtype(self.dtype).itemsize)
+
     def page_table_width(self, bucket_tokens: int,
                          chunk_tokens: int) -> int:
         """Page-table width for a chunked (or unified ragged) prefill at
@@ -234,11 +285,30 @@ def window_ring_pages(window: int, ahead_tokens: int, page_size: int) -> int:
 
 def alloc_kv_pages(spec: KVCacheSpec, sharding=None):
     """Allocate zeroed K/V page pools (optionally with a NamedSharding):
-    two arrays, or with pools by kind two `ByKind` pairs of arrays."""
+    two arrays, with pools by kind two `ByKind` pairs of arrays, and for a
+    hybrid model two `StatePools` (the attention layers' pool and an array
+    a Mamba-2 layer over the state slots)."""
     def put(shape):
         a = jnp.zeros(shape, dtype=jnp.dtype(spec.dtype))
         return a if sharding is None else jax.device_put(a, sharding)
 
+    if spec.state_layers:
+        from dynamo_tpu.models.llama import StatePools
+
+        def states(shape, dtype):
+            # a buffer of its own a layer (each is donated apart),
+            # replicated: one chip a replica (from_model refuses the rest)
+            def one():
+                a = jnp.zeros((spec.state_slots,) + tuple(shape), dtype)
+                return a if sharding is None else jax.device_put(
+                    a, jax.sharding.NamedSharding(
+                        sharding.mesh, jax.sharding.PartitionSpec()))
+            return tuple(one() for _ in range(spec.state_layers))
+
+        return (StatePools(put(spec.shape),
+                           states(spec.ssm_shape, jnp.float32)),
+                StatePools(put(spec.v_shape),
+                           states(spec.conv_shape, jnp.dtype(spec.dtype))))
     if spec.window_layers:
         from dynamo_tpu.models.llama import ByKind
 

@@ -110,10 +110,88 @@ ATTENTION_KINDS = (FULL, SLIDING)
 _MAPPED_MODEL_TYPES = frozenset((
     "llama", "mistral", "qwen2", "qwen3", "qwen3_moe", "mixtral", "phi3",
     "gemma", "gemma2", "gemma3", "gemma3_text", "deepseek_v2", "deepseek_v3",
-    "kimi_k2", "deepseek_v32"))
+    "kimi_k2", "deepseek_v32", "nemotron_h"))
 _MAPPED_ARCH_WORDS = ("Llama", "Mistral", "Qwen", "Mixtral", "Phi3", "Gemma",
                       "Deepseek", "Kimi")
 _KIND_KEYS = ("layer_types", "num_attention_heads_per_layer", "gating")
+
+# the mixer kinds of ModelConfig.mixer_types (a layer is ONE of them), and
+# the letters of `hybrid_override_pattern` that name them
+MAMBA, EXPERTS, ATTENTION = "mamba", "moe", "attention"
+MIXER_LETTERS = {"M": MAMBA, "E": EXPERTS, "*": ATTENTION}
+# two-matrix experts act(u W_up) W_down: the activations written down
+TWO_MATRIX_ACTS = ("relu2", "silu")
+
+
+def _hybrid_from_hf(cfg: dict) -> dict:
+    """The ModelConfig fields of `model_type: nemotron_h` (every layer ONE
+    mixer by `hybrid_override_pattern`: Mamba-2, experts of two matrices
+    beside a shared one, or GQA attention without a rotary); {} for every
+    other model. Refuses, loudly, what it would otherwise serve as another
+    model."""
+    if cfg.get("model_type") != "nemotron_h":
+        return {}
+    n = int(cfg["num_hidden_layers"])
+    pattern = cfg.get("hybrid_override_pattern") or ""
+    unknown = sorted(set(pattern) - set(MIXER_LETTERS))
+    if unknown:
+        raise ValueError(
+            f"hybrid_override_pattern {pattern!r} has letters {unknown}: "
+            f"{sorted(MIXER_LETTERS)} are served ('-', a dense MLP alone, "
+            "is not implemented)")
+    if len(pattern) != n:
+        raise ValueError(
+            f"hybrid_override_pattern has {len(pattern)} letters for "
+            f"num_hidden_layers={n}")
+    mixers = tuple(MIXER_LETTERS[c] for c in pattern)
+    listed = cfg.get("layer_types")
+    if listed is not None and tuple(listed) != mixers:
+        raise ValueError(
+            f"layer_types {list(listed)} disagrees with "
+            f"hybrid_override_pattern {pattern!r}")
+    heads, groups = int(cfg["mamba_num_heads"]), int(cfg["n_groups"])
+    if heads % groups:
+        raise ValueError(
+            f"mamba_num_heads={heads} is no multiple of n_groups={groups}: "
+            "a head reads the B / C rows of ONE group")
+    act = cfg.get("mlp_hidden_act")
+    if act not in TWO_MATRIX_ACTS:
+        raise ValueError(
+            f"mlp_hidden_act={act!r} on a two-matrix expert is not "
+            f"implemented: {TWO_MATRIX_ACTS} are")
+    if int(cfg.get("n_group") or 1) > 1 or int(cfg.get("topk_group") or 1) > 1:
+        raise ValueError(
+            f"n_group={cfg.get('n_group')} / topk_group="
+            f"{cfg.get('topk_group')} under model_type nemotron_h is not "
+            "implemented: its router is served without groups")
+    for key in ("mamba_proj_bias", "mlp_bias", "use_bias", "attention_bias"):
+        if cfg.get(key):
+            raise ValueError(f"{key}=true is not implemented for nemotron_h")
+    if cfg.get("use_conv_bias") is False:
+        raise ValueError("use_conv_bias=false is not implemented")
+    if cfg.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError(f"mamba_hidden_act={cfg['mamba_hidden_act']!r}")
+    shared = int(cfg.get("moe_shared_expert_intermediate_size") or 0)
+    return dict(
+        mixer_types=mixers,
+        mamba_num_heads=heads, mamba_head_dim=int(cfg["mamba_head_dim"]),
+        mamba_n_groups=groups, ssm_state_size=int(cfg["ssm_state_size"]),
+        conv_kernel=int(cfg["conv_kernel"]),
+        ssm_chunk_size=int(cfg.get("chunk_size") or 128),
+        expert_act=act,
+        rms_norm_eps=float(cfg.get("norm_eps")
+                           or cfg.get("layer_norm_epsilon") or 1e-5),
+        # ASSUMED (ISSUE 42 b): DeepSeek-V3's noaux_tc, the family whose
+        # key names these are; the config names no scoring_func
+        moe_scoring="sigmoid", router_bias=True, n_group=1, topk_group=1,
+        num_shared_experts=1 if shared else 0,
+        shared_expert_intermediate_size=shared,
+        first_k_dense=0, dense_intermediate_size=0,
+        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        sliding_window=0,
+        rope_llama3_scaling=None, rope_yarn_scaling=None,
+        rope_longrope_scaling=None,
+    )
 
 
 def _rope_of_kind(kind: str, rp: dict):
@@ -379,6 +457,24 @@ class ModelConfig:
     rope_by_kind: Tuple[tuple, ...] = ()
     attn_gate: str = ""
     shared_expert_intermediate_size: int = 0
+    # a HYBRID model (nemotron_h): one entry a layer, "mamba" | "moe" |
+    # "attention", and every layer is x + mixer(norm(x)) with that ONE
+    # mixer: no attention + FFN pair. Non-empty: the layers run unrolled
+    # over a parameter stack a kind (models/llama.py, "hybrid"), only the
+    # attention layers own KV pages and every Mamba-2 layer owns a state
+    # slot a sequence (engine/kv_cache.py): S [mamba_num_heads,
+    # mamba_head_dim, ssm_state_size] float32 and the conv's last
+    # conv_kernel - 1 input rows. The attention layers take NO rotary.
+    # expert_act: "" = gate / up / down experts (silu(g) * u); "relu2" |
+    # "silu" = two matrices an expert, act(u W_up) W_down, the shared one too.
+    mixer_types: Tuple[str, ...] = ()
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 0
+    ssm_state_size: int = 0
+    conv_kernel: int = 0
+    ssm_chunk_size: int = 128
+    expert_act: str = ""
     # dtype for params/compute (bfloat16 on TPU; float32 for CPU tests)
     dtype: str = "bfloat16"
     eos_token_id: int = 2
@@ -445,6 +541,11 @@ class ModelConfig:
             raise ValueError(
                 "heads_per_layer / rope_by_kind / attn_gate without "
                 "layer_types: no layer would read them")
+        if self.mixer_types:
+            self._check_mixers()
+        elif self.mamba_num_heads or self.expert_act:
+            raise ValueError("mamba_* / expert_act without mixer_types: no "
+                             "layer would read them")
         held = self.num_local_experts
         if held and not (
                 self.is_moe and 0 <= self.local_expert_offset
@@ -506,6 +607,79 @@ class ModelConfig:
             raise ValueError("the leading dense layers are of more than one "
                              "kind: their stack has one shape")
 
+    def _check_mixers(self) -> None:
+        """mixer_types and what hangs on it, as loud as _check_kinds."""
+        kinds = self.mixer_types
+        if len(kinds) != self.num_layers:
+            raise ValueError(f"mixer_types has {len(kinds)} entries for "
+                             f"{self.num_layers} layers")
+        bad = set(kinds) - set(MIXER_LETTERS.values())
+        if bad:
+            raise ValueError(f"unknown mixer_types {sorted(bad)}")
+        if MAMBA in kinds and not (
+                self.mamba_num_heads > 0 and self.mamba_head_dim > 0
+                and self.mamba_n_groups > 0 and self.ssm_state_size > 0
+                and self.conv_kernel > 1 and self.ssm_chunk_size > 0
+                and self.mamba_num_heads % self.mamba_n_groups == 0):
+            raise ValueError(
+                "mamba layers need mamba_num_heads (a multiple of "
+                "mamba_n_groups), mamba_head_dim, ssm_state_size, "
+                "conv_kernel > 1 and ssm_chunk_size")
+        if EXPERTS in kinds and not (
+                self.is_moe and self.expert_act in TWO_MATRIX_ACTS):
+            raise ValueError(
+                "moe mixers need num_experts and a two-matrix expert_act "
+                f"of {TWO_MATRIX_ACTS} (got {self.expert_act!r})")
+        if (self.layer_types or self.is_mla or self.first_k_dense
+                or self.sliding_window or self.attention_bias or self.qk_norm
+                or self.post_norms or self.attn_logit_softcapping
+                or self.num_local_experts or self.n_group > 1
+                or self.rms_norm_unit_offset or self.embed_scale
+                or self.tie_word_embeddings or self.moe_capacity_factor):
+            raise ValueError(
+                "mixer_types is served with plain GQA attention layers "
+                "without a rotary, every expert held, an untied head, and "
+                "none of: layer_types, MLA, leading dense layers, a window, "
+                "biases, q/k or sandwich norms, score capping, router "
+                "groups, a capacity factor")
+
+    def mixer_layers(self, kind: str) -> int:
+        return sum(1 for k in self.mixer_types if k == kind)
+
+    @property
+    def expert_dims_stored(self) -> Tuple[int, int]:
+        """(hidden rows, width lanes) a routed expert's W_up [rows, lanes]
+        and W_down [lanes, rows] are STORED with for a hybrid model: each
+        rounded up to a multiple of 1,024 (of 128 under 1,024; a tiny test
+        config under 128 stays as it is), everything past the model's own
+        extent zero (act(0) = 0 and a zero row meets a zero input lane:
+        they add nothing). Why (PR 42, measured on a v5e,
+        benchmarks/chip/records/pr42-ragged-dot-bench*.json): the grouped
+        matmul is XLA's `ragged_dot`, a custom call whose tiling follows
+        its operands' extents. At Nemotron-H's own 2,688 x 1,856 the TPU
+        lays the int8 stack out transposed (a minor dimension that is no
+        multiple of 128) and the call copied the whole 2.5 GB stack in every
+        layer of every step; at 2,688 x 1,920 (a 128-multiple) it takes
+        12-18 ms for 384 rows over 128 experts; at 3,072 x 2,048 it takes
+        1.9 ms, and W_down at 2,048 x 3,072 likewise (15.5 -> 1.9 ms). The
+        configurations the benchmark had before are 1,024-multiples
+        already. The price: 26% more expert bytes than the model has."""
+        def up(n: int) -> int:
+            if n >= 1024:
+                return -(-n // 1024) * 1024
+            return -(-n // 128) * 128 if n >= 128 else n
+        return up(self.hidden_size), up(self.intermediate_size)
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Lanes the conv runs over: [x | B | C]."""
+        return (self.mamba_d_inner
+                + 2 * self.mamba_n_groups * self.ssm_state_size)
+
     @property
     def kind_period(self) -> int:
         """Layers of the smallest period the kinds behind the leading dense
@@ -544,6 +718,8 @@ class ModelConfig:
 
     @property
     def num_moe_layers(self) -> int:
+        if self.mixer_types:
+            return self.mixer_layers(EXPERTS)
         return self.num_layers - self.first_k_dense if self.is_moe else 0
 
     @property
@@ -768,6 +944,7 @@ class ModelConfig:
             eos_token_id=eos,
             bos_token_id=cfg.get("bos_token_id", 1),
         )
+        kw.update(_hybrid_from_hf(cfg))
         if kinds_kw:
             k_dense = kinds_kw["first_k_dense"] if n_experts else 0
             kinds_kw.update(
@@ -1253,4 +1430,24 @@ PRESETS["tiny-laguna-debug"] = ModelConfig(
                    (16.0, 32.0, 1.0, 16, 1.0, 0.0, 1.25)),
                   (SLIDING, 10000.0, 1.0, None)),
     attn_gate="per-head",
+)
+
+# NVIDIA-Nemotron-3-Nano's structure at a toy size: the first nine letters
+# of its pattern (MEMEM*EME: every layer ONE mixer), Mamba-2 with 4 heads of
+# 8 lanes in 2 groups (more heads than groups, more than one group), state
+# 8, conv 4, a scan chunk of 4 (the tests' prompts are no multiple of it),
+# 4 query heads over 2 KV heads without a rotary, 16 sigmoid-routed
+# two-matrix relu^2 experts top-2 (8 * 2 <= 16 keeps the grouped expert
+# layer, the one the published 128 / top-6 takes) + a shared one of its
+# own width, an untied head
+PRESETS["tiny-nemotron-h-debug"] = ModelConfig(
+    name="tiny-nemotron-h-debug",
+    hidden_size=64, intermediate_size=32, num_layers=9, num_heads=4,
+    num_kv_heads=2, head_dim=16, tie_word_embeddings=False,
+    num_experts=16, num_experts_per_tok=2, num_shared_experts=1,
+    shared_expert_intermediate_size=48, norm_topk_prob=True,
+    routed_scaling_factor=2.5, moe_scoring="sigmoid", router_bias=True,
+    mixer_types=tuple(MIXER_LETTERS[c] for c in "MEMEM*EME"),
+    mamba_num_heads=4, mamba_head_dim=8, mamba_n_groups=2, ssm_state_size=8,
+    conv_kernel=4, ssm_chunk_size=4, expert_act="relu2",
 )

@@ -20,15 +20,18 @@ lives in `dynamo_tpu.engine`.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.models.config import FULL, SLIDING, ModelConfig
+from dynamo_tpu.models.config import (ATTENTION, EXPERTS, FULL, MAMBA,
+                                      SLIDING, ModelConfig)
 from dynamo_tpu.models import quant
 from dynamo_tpu.ops import attention as att
 from dynamo_tpu.ops import moe as moe_ops
+from dynamo_tpu.ops import ssm as ssm_ops
 from dynamo_tpu.ops.rope import apply_rope
 
 qeinsum = quant.einsum  # einsum that understands int8 QTensor weights
@@ -176,6 +179,8 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], str, float
     kind: "normal" (random weight with stddev sigma), "ones", "zeros".
     Single source of truth for param shapes — `init_params` and the loader's
     fast random-int8 path both build from it, so they cannot drift."""
+    if cfg.mixer_types:
+        return _hybrid_param_specs(cfg)
     e, h, kv, d, f = (
         cfg.hidden_size,
         cfg.num_heads,
@@ -315,6 +320,9 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     for k, (name, (shape, kind, sigma)) in zip(ks, specs.items()):
         if name == "router_bias":
             p[name] = jnp.zeros(shape, jnp.float32)
+        elif kind in SSM_INITS:
+            p[name] = jnp.asarray(SSM_INITS[kind](
+                jax.random.uniform(k, shape, dtype=jnp.float32)), jnp.float32)
         elif kind == "ones":
             p[name] = jnp.ones(shape, dt)
         elif kind == "zeros":
@@ -323,6 +331,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             p[name] = (
                 jax.random.normal(k, shape, dtype=jnp.float32) * sigma
             ).astype(dt)
+    for name, cuts in zero_lanes(cfg).items():
+        p[name] = _zero_past(p[name], cuts)
     return p
 
 
@@ -936,6 +946,11 @@ def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
     expert layer counted (moe_ops.MOE_STATS, int32), None on every other
     path."""
     def dense(x):
+        if cfg.expert_act:  # two matrices, no gate (hybrid models)
+            u = qeinsum("te,ef->tf", x, lp["w_up"])
+            return qeinsum("tf,fe->te",
+                           moe_ops.two_matrix_act(cfg.expert_act, u),
+                           lp["w_down"])
         g = qeinsum("te,ef->tf", x, lp["w_gate"])
         u = qeinsum("te,ef->tf", x, lp["w_up"])
         return qeinsum("tf,fe->te", _act(cfg, g) * u, lp["w_down"])
@@ -978,10 +993,10 @@ def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
             capacity = 0  # gather only pays off when capacity cuts rows
     if cfg.moe_grouped and not capacity:
         y, stats = moe_ops.moe_mlp_grouped(
-            x, topi, weights, lp["moe_w_gate"], lp["moe_w_up"],
+            x, topi, weights, lp.get("moe_w_gate"), lp["moe_w_up"],
             lp["moe_w_down"], expert_offset=cfg.local_expert_offset,
             num_experts=cfg.num_experts, token_mask=token_mask,
-            layer=lp.get("moe_layer"))
+            layer=lp.get("moe_layer"), act=cfg.expert_act)
         return shared + y, stats
     combine = moe_ops.scatter_combine(topi, weights, cfg.num_experts,
                                       x.dtype)
@@ -994,8 +1009,342 @@ def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
             lp["moe_w_down"], capacity=capacity,
         ), None
     return shared + moe_ops.moe_mlp_dense(
-        x, combine, lp["moe_w_gate"], lp["moe_w_up"], lp["moe_w_down"]
-    ), None
+        x, combine, lp.get("moe_w_gate"), lp["moe_w_up"], lp["moe_w_down"],
+        act=cfg.expert_act), None
+
+
+
+# ------------------------------------------------------------------ hybrid --
+# A HYBRID model (cfg.mixer_types, nemotron_h): every layer is
+# x + mixer(norm(x)) with ONE mixer of a static kind (Mamba-2 | experts |
+# attention). The layers run unrolled (the published pattern has no period),
+# each kind over its own parameter stack; only the attention layers own KV
+# pages, and every Mamba-2 layer owns a STATE SLOT a decode slot: a
+# sequence's state lives in the slot the engine reserved for it at admission
+# (a chunked prompt's state rides there from chunk to chunk) and decode row
+# b updates slot b where it lies.
+
+
+class StatePools(NamedTuple):
+    """`k_pages` / `v_pages` of a hybrid model, so that the engine's
+    plumbing (donation, the fused windows' carry) keeps one shape: the
+    attention layers' paged pool [attention layers, pages, page_size,
+    KV*D], and one array a Mamba-2 layer over the decode slots: in
+    `k_pages` the state S [slots, H, P, N] float32, in `v_pages` the conv's
+    last K-1 input rows [slots, K-1, C]."""
+    pages: Any
+    state: Any
+
+
+class SlotPages(NamedTuple):
+    """A prompt's `pages` operand of a hybrid model: its page table and the
+    state slot (a scalar int32) its chunks carry their state in."""
+    pages: Any
+    slot: Any
+
+
+# time_step_min / _max / _floor of the family's config: read by the random
+# initialiser alone (a checkpoint brings its own dt_bias)
+_DT_MIN, _DT_MAX, _DT_FLOOR = 1e-3, 1e-1, 1e-4
+
+
+def _init_a_log(u):
+    """A uniform in [1, 16), as Mamba-2 initialises it; u uniform [0, 1)."""
+    return jnp.log(1.0 + 15.0 * u)
+
+
+def _init_dt_bias(u):
+    """softplus^-1 of a step log-uniform in [dt_min, dt_max], floored."""
+    dt = jnp.maximum(jnp.exp(math.log(_DT_MIN) + u * (
+        math.log(_DT_MAX) - math.log(_DT_MIN))), _DT_FLOOR)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+# param_specs kinds of the state-space vectors -> their initialiser over
+# uniform [0, 1) draws (init_params and the loader's random-int8 path)
+SSM_INITS = {"ssm_a_log": _init_a_log, "ssm_dt_bias": _init_dt_bias}
+# the leaves of each mixer kind's parameter stack
+_MIXER_LEAVES = {
+    MAMBA: ("ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias", "ssm_a_log",
+            "ssm_d", "ssm_norm", "ssm_out"),
+    EXPERTS: ("router", "router_bias", "w_up", "w_down"),
+    ATTENTION: ("wq", "wk", "wv", "wo"),
+}
+
+
+def _hybrid_param_specs(cfg: ModelConfig):
+    """param_specs of a hybrid model: one stack a mixer kind on a leading
+    axis of that kind's layers, the norm before every layer's mixer in
+    `mixer_norm` [L, E]."""
+    e, f = cfg.hidden_size, cfg.intermediate_size
+    lm, le, la = (cfg.mixer_layers(k) for k in (MAMBA, EXPERTS, ATTENTION))
+
+    def w(shape, sigma=None):
+        return (shape, "normal",
+                sigma if sigma is not None else 1.0 / shape[-1] ** 0.5)
+
+    p = {
+        "embed": w((cfg.vocab_size, e), 0.02),
+        "final_norm": ((e,), "ones", 0.0),
+        "lm_head": w((e, cfg.vocab_size), 0.02),
+        "mixer_norm": ((cfg.num_layers, e), "ones", 0.0),
+    }
+    if lm:
+        hm, d_in, c = (cfg.mamba_num_heads, cfg.mamba_d_inner,
+                       cfg.mamba_conv_dim)
+        # [z | x B C | dt]
+        p["ssm_in"] = w((lm, e, d_in + c + hm), 1.0 / e ** 0.5)
+        p["ssm_conv_w"] = w((lm, cfg.conv_kernel, c),
+                            1.0 / cfg.conv_kernel ** 0.5)
+        p["ssm_conv_b"] = ((lm, c), "zeros", 0.0)
+        p["ssm_dt_bias"] = ((lm, hm), "ssm_dt_bias", 0.0)
+        p["ssm_a_log"] = ((lm, hm), "ssm_a_log", 0.0)
+        p["ssm_d"] = ((lm, hm), "ones", 0.0)
+        p["ssm_norm"] = ((lm, d_in), "ones", 0.0)
+        p["ssm_out"] = w((lm, d_in, e), 1.0 / d_in ** 0.5)
+    if la:
+        h, kv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        p["wq"] = w((la, e, h, d), 1.0 / e ** 0.5)
+        p["wk"] = w((la, e, kv, d), 1.0 / e ** 0.5)
+        p["wv"] = w((la, e, kv, d), 1.0 / e ** 0.5)
+        p["wo"] = w((la, h, d, e), 1.0 / (h * d) ** 0.5)
+    if le:
+        x, fs = cfg.held_experts, cfg.shared_expert_width
+        # e x f and zero rows / lanes around them (zero_lanes)
+        ep, fp = cfg.expert_dims_stored
+        p["router"] = w((le, e, cfg.num_experts), 0.02)
+        p["router_bias"] = ((le, cfg.num_experts), "zeros", 0.0)
+        p["moe_w_up"] = w((le, x, ep, fp), 1.0 / e ** 0.5)
+        p["moe_w_down"] = w((le, x, fp, ep), 1.0 / f ** 0.5)
+        if fs:
+            p["w_up"] = w((le, e, fs), 1.0 / e ** 0.5)
+            p["w_down"] = w((le, fs, e), 1.0 / fs ** 0.5)
+    return p
+
+
+def zero_lanes(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, int], ...]]:
+    """name -> ((axis, real extent), ...) of the leaves a hybrid model
+    stores larger than the model is (ModelConfig.expert_dims_stored):
+    everything past a real extent along its axis is ZERO, in random weights
+    as in loaded ones. {} where nothing is padded."""
+    if not cfg.mixer_types:
+        return {}
+    e, f = cfg.hidden_size, cfg.intermediate_size
+    if cfg.expert_dims_stored == (e, f):
+        return {}
+    return {"moe_w_up": ((2, e), (3, f)), "moe_w_down": ((2, f), (3, e))}
+
+
+def _zero_past(a, cuts):
+    """`a` with everything past each (axis, extent) of `cuts` zero (a
+    QTensor: its int8 values; the scales are per output channel and stay)."""
+    def cut(v):
+        for axis, extent in cuts:
+            keep = jnp.arange(v.shape[axis]) < extent
+            shape = [1] * v.ndim
+            shape[axis] = -1
+            v = v * keep.reshape(shape).astype(v.dtype)
+        return v
+    if isinstance(a, quant.QTensor):
+        return type(a)(cut(a.q), a.scale)
+    return cut(a)
+
+
+def _mamba_mixer(cfg: ModelConfig, lp: Params, u: jax.Array, ssm, conv,
+                 decode=None, chunk=None):
+    """The Mamba-2 mixer over u [T, E] (normed) -> (y [T, E], ssm, conv).
+
+    The rows are `decode` = (b, live [b] bool): b rows, one token a decode
+    slot, row i updating slot i where live; then `chunk` = (c, slot,
+    n_valid, fresh): c rows of one prompt whose state lies in `slot`, the
+    first n_valid real, starting from zero where `fresh` (a traced bool:
+    the prompt's first chunk). The projections, the gate and the norm run
+    over all rows at once; the conv and the recurrence a part each. Padding
+    rows and empty slots are handed on with dt = 0: they leave a state as
+    it was (ops/ssm.py)."""
+    hm, pm = cfg.mamba_num_heads, cfg.mamba_head_dim
+    g, n = cfg.mamba_n_groups, cfg.ssm_state_size
+    d_in, c_dim = cfg.mamba_d_inner, cfg.mamba_conv_dim
+    with jax.named_scope("ssm_in_proj"):
+        zxbcdt = qeinsum("te,ef->tf", u, lp["ssm_in"])
+    z, xbc = zxbcdt[:, :d_in], zxbcdt[:, d_in:d_in + c_dim]
+    dt = jax.nn.softplus(zxbcdt[:, d_in + c_dim:].astype(jnp.float32)
+                         + lp["ssm_dt_bias"].astype(jnp.float32))
+    a = -jnp.exp(lp["ssm_a_log"].astype(jnp.float32))
+
+    def parts(xo):
+        t = xo.shape[0]
+        return (xo[:, :d_in].reshape(t, hm, pm),
+                xo[:, d_in:d_in + g * n].reshape(t, g, n),
+                xo[:, d_in + g * n:].reshape(t, g, n))
+
+    ys, off = [], 0
+    if decode is not None:
+        b, live = decode
+        with jax.named_scope("ssm_conv"):
+            xo, conv = ssm_ops.conv_step(
+                xbc[:b], conv, lp["ssm_conv_w"], lp["ssm_conv_b"], live)
+        with jax.named_scope("ssm_scan"):
+            x, bm, cm = parts(xo)
+            y, ssm = ssm_ops.step(
+                x, jnp.where(live[:, None], dt[:b], 0.0), a, bm, cm,
+                lp["ssm_d"], ssm)
+        ys.append(y.reshape(b, d_in))
+        off = b
+    if chunk is not None:
+        c, slot, n_valid, fresh = chunk
+        rows = slice(off, off + c)
+        with jax.named_scope("ssm_conv"):
+            prev = jnp.where(fresh, jnp.zeros_like(conv[slot]), conv[slot])
+            xo, kept = ssm_ops.conv_rows(
+                xbc[rows], prev, lp["ssm_conv_w"], lp["ssm_conv_b"], n_valid)
+        with jax.named_scope("ssm_scan"):
+            x, bm, cm = parts(xo)
+            init = jnp.where(fresh, jnp.zeros_like(ssm[slot]), ssm[slot])
+            y, final = ssm_ops.scan_chunked(
+                x, jnp.where((jnp.arange(c) < n_valid)[:, None], dt[rows],
+                             0.0),
+                a, bm, cm, lp["ssm_d"], init, cfg.ssm_chunk_size)
+            ssm = ssm.at[slot].set(final.astype(ssm.dtype))
+            conv = conv.at[slot].set(kept)
+        ys.append(y.reshape(c, d_in))
+    y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
+    with jax.named_scope("ssm_gate_norm"):
+        y = ssm_ops.gate_norm(y, z, lp["ssm_norm"], g,
+                              cfg.rms_norm_eps).astype(u.dtype)
+    with jax.named_scope("ssm_out_proj"):
+        return qeinsum("tf,fe->te", y, lp["ssm_out"]), ssm, conv
+
+
+def _hybrid_layers(cfg: ModelConfig, params: Params, x: jax.Array,
+                   k_pages: StatePools, v_pages: StatePools, attend,
+                   token_mask, decode=None, chunk=None):
+    """Every layer of a hybrid model over x [T, E]: x + mixer(norm(x)).
+    `attend(q, k, v, kp, vp, page_off)` -> (o, kp, vp) writes the rows'
+    K / V into the flat pool and attends; `decode` / `chunk` are
+    _mamba_mixer's. Returns (x, k_pages, v_pages, the expert layers'
+    counts or None)."""
+    pool = k_pages.pages.shape
+    kp = k_pages.pages.reshape((-1,) + pool[2:])
+    vp = v_pages.pages.reshape((-1,) + pool[2:])
+    ssm, conv = list(k_pages.state), list(v_pages.state)
+    counts = (jnp.zeros((len(moe_ops.MOE_STATS),), jnp.int32)
+              if cfg.moe_grouped else None)
+    seen = dict.fromkeys(_MIXER_LEAVES, 0)
+    for i, kind in enumerate(cfg.mixer_types):
+        j = seen[kind]
+        seen[kind] += 1
+        lp = {k: jax.tree.map(lambda a: a[j], params[k])
+              for k in _MIXER_LEAVES[kind] if k in params}
+        h = rms_norm(x, params["mixer_norm"][i], cfg.rms_norm_eps)
+        if kind == MAMBA:
+            y, ssm[j], conv[j] = _mamba_mixer(cfg, lp, h, ssm[j], conv[j],
+                                              decode, chunk)
+        elif kind == ATTENTION:
+            # no rotary: position reaches the layer through the states
+            q = qeinsum("te,ehd->thd", h, lp["wq"])
+            k = qeinsum("te,ekd->tkd", h, lp["wk"])
+            v = qeinsum("te,ekd->tkd", h, lp["wv"])
+            with jax.named_scope("attn_full"):
+                o, kp, vp = attend(q, k, v, kp, vp, j * pool[1])
+            y = _attn_out(cfg, lp, o)
+        else:
+            # the experts' WHOLE stack and the layer's index, as
+            # _scan_layers_paged hands them (ops/moe.moe_mlp_grouped)
+            lp.update(moe_w_up=params["moe_w_up"],
+                      moe_w_down=params["moe_w_down"], moe_layer=j)
+            y, c = _mlp(cfg, lp, h, token_mask=token_mask)
+            if counts is not None:
+                counts = counts + c
+        x = x + y
+    return (x, StatePools(kp.reshape(pool), tuple(ssm)),
+            StatePools(vp.reshape(v_pages.pages.shape), tuple(conv)), counts)
+
+
+def _hybrid_prefill(cfg, params, tokens, n_valid, k_pages, v_pages,
+                    pages: SlotPages, start, page_size: int):
+    """A whole prompt (start None: from position 0, attention over the
+    prompt itself) or one chunk of it from `start` (attention over the
+    cached pages and the chunk): the final state goes to pages.slot."""
+    c = tokens.shape[0]
+    token_mask = jnp.arange(c) < n_valid
+    table = pages.pages
+
+    def attend(q, k, v, kp, vp, off):
+        if start is None:
+            o = att.prefill_attention(q, k, v, n_valid)
+            kp, vp = att.write_kv_prefill(kp, vp, k, v, table + off,
+                                          page_size=page_size)
+            return o, kp, vp
+        write = jax.lax.dynamic_slice(table, (start // page_size,),
+                                      (c // page_size,))
+        kp, vp = att.write_kv_prefill(kp, vp, k, v, write + off,
+                                      page_size=page_size)
+        o = att.chunk_attention(q, kp, vp, table + off, start,
+                                page_size=page_size,
+                                num_kv_heads=cfg.cache_kv_heads)
+        return o, kp, vp
+
+    fresh = jnp.bool_(True) if start is None else start == 0
+    x, k_pages, v_pages, counts = _hybrid_layers(
+        cfg, params, _embed_rows(cfg, params, tokens), k_pages, v_pages,
+        attend, token_mask, chunk=(c, pages.slot, n_valid, fresh))
+    last = jnp.take(x, n_valid - 1, axis=0)[None]
+    return PrefillOut(_logits(cfg, params, last)[0], k_pages, v_pages, counts)
+
+
+def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
+                 k_pages, v_pages, page_size: int, chunk=None):
+    """Every decode slot a token and, with `chunk` = (tokens [C], start,
+    n_valid, pages: SlotPages), one chunk of a prompt in the same forward:
+    rows [B decode | C chunk]. Returns (x [B(+C), E] after the last layer,
+    k_pages, v_pages, counts)."""
+    b = tokens.shape[0]
+    if b != k_pages.state[0].shape[0]:
+        raise ValueError(
+            f"{b} decode rows over {k_pages.state[0].shape[0]} state slots: "
+            "decode row i updates state slot i")
+    live = _live_slots(block_tables)
+    kernel_lens = jnp.where(live, context_lens, 0)
+    tables = block_tables
+    all_tokens, token_mask, mchunk = tokens, live, None
+    if chunk is not None:
+        c_tokens, start, n_valid, pages = chunk
+        c = c_tokens.shape[0]
+        all_tokens = jnp.concatenate([tokens, c_tokens])
+        token_mask = jnp.concatenate([live, jnp.arange(c) < n_valid])
+        mchunk = (c, pages.slot, n_valid, start == 0)
+        write = jax.lax.dynamic_slice(pages.pages, (start // page_size,),
+                                      (c // page_size,))
+
+    def attend(q, k, v, kp, vp, off):
+        kp, vp = att.write_kv_token(kp, vp, k[:b], v[:b], tables + off,
+                                    positions, page_size=page_size)
+        if chunk is None:
+            o = att.paged_attention_decode(
+                q, kp, vp, tables + off, context_lens, page_size=page_size,
+                num_kv_heads=cfg.cache_kv_heads, kernel_lens=kernel_lens)
+            return o, kp, vp
+        kp, vp = att.write_kv_prefill(kp, vp, k[b:], v[b:], write + off,
+                                      page_size=page_size)
+        o = att.ragged_mixed_attention(
+            q, kp, vp, tables + off, context_lens, pages.pages + off, start,
+            page_size=page_size, num_kv_heads=cfg.cache_kv_heads,
+            num_decode=b)
+        return o, kp, vp
+
+    return _hybrid_layers(
+        cfg, params, _embed_rows(cfg, params, all_tokens), k_pages, v_pages,
+        attend, token_mask, decode=(b, live), chunk=mchunk)
+
+
+def _no_hybrid(cfg: ModelConfig, what: str) -> None:
+    if cfg.mixer_types:
+        raise NotImplementedError(
+            f"{what} is not implemented for a hybrid model "
+            "(cfg.mixer_types): a state slot holds ONE sequence's state at "
+            "ONE position, so lanes of one prompt batch and a verify "
+            "window that may roll back have nowhere to keep theirs")
 
 
 class PrefillOut(NamedTuple):
@@ -1034,6 +1383,9 @@ def prefill(
     Mirrors the prefill role of the reference's disaggregated workers
     (/root/reference/examples/deploy/vllm/disagg.yaml:37 `--is-prefill-worker`).
     """
+    if cfg.mixer_types:
+        return _hybrid_prefill(cfg, params, tokens, seq_len, k_pages,
+                               v_pages, pages, None, page_size)
     s = tokens.shape[0]
     positions = jnp.arange(s)
     token_mask = positions < seq_len  # padding rows past the true length
@@ -1126,6 +1478,9 @@ def prefill_chunk(
     chunk). Returns the logits at the chunk's last valid token (only
     meaningful on the final chunk).
     """
+    if cfg.mixer_types:
+        return _hybrid_prefill(cfg, params, tokens, chunk_len, k_pages,
+                               v_pages, pages, start, page_size)
     c = tokens.shape[0]
     positions = start + jnp.arange(c)
     token_mask = jnp.arange(c) < chunk_len
@@ -1215,6 +1570,7 @@ def prefill_batch(
     lanes carry all-trash page rows, so their writes land in the reserved
     page and their logits are discarded by the engine."""
     _no_kinds(cfg, "a batched prefill")
+    _no_hybrid(cfg, "a batched prefill")
     n, s = tokens.shape
     positions = jnp.tile(jnp.arange(s), n)  # [N*S] per-lane positions
     token_mask = (jnp.arange(s)[None, :] < seq_lens[:, None]).reshape(-1)
@@ -1327,6 +1683,7 @@ def decode_verify(
     """
     _no_speculation_under_selection(cfg)
     _no_kinds(cfg, "a speculative verify window")
+    _no_hybrid(cfg, "a speculative verify window")
     b, k1 = tokens.shape
     pos2 = positions[:, None] + jnp.arange(k1)[None, :]  # [B, K1]
     flat_pos = pos2.reshape(b * k1)
@@ -1388,6 +1745,11 @@ def decode_step(
     adapter_slots=None,  # [B] int32 per-slot LoRA slots, or None
 ) -> DecodeOut:
     """One continuous-batching decode step over all batch slots."""
+    if cfg.mixer_types:
+        x, k_pages, v_pages, counts = _hybrid_step(
+            cfg, params, tokens, positions, block_tables, context_lens,
+            k_pages, v_pages, page_size)
+        return DecodeOut(_logits(cfg, params, x), k_pages, v_pages, counts)
     x = _embed_rows(cfg, params, tokens)  # [B, E]
     slots = (None if adapter_slots is None
              else adapter_slots.astype(jnp.int32))
@@ -1498,6 +1860,14 @@ def mixed_step(
     """
     b = tokens.shape[0]
     c = chunk_tokens.shape[0]
+    if cfg.mixer_types:
+        x, k_pages, v_pages, counts = _hybrid_step(
+            cfg, params, tokens, positions, block_tables, context_lens,
+            k_pages, v_pages, page_size,
+            chunk=(chunk_tokens, chunk_start, chunk_len, chunk_pages))
+        last = jnp.take(x[b:], chunk_len - 1, axis=0)[None]
+        logits = _logits(cfg, params, jnp.concatenate([x[:b], last]))
+        return MixedOut(logits[:b], logits[b], k_pages, v_pages, counts)
     all_pos = jnp.concatenate([positions, chunk_start + jnp.arange(c)])
     if cfg.layer_types:
         views = _decode_views(cfg, block_tables, positions, context_lens,
@@ -1631,6 +2001,7 @@ def mixed_verify_step(
     """
     _no_speculation_under_selection(cfg)
     _no_kinds(cfg, "a speculative verify window")
+    _no_hybrid(cfg, "a speculative verify window")
     b, k1 = tokens.shape
     c = chunk_tokens.shape[0]
     n = b * k1

@@ -105,10 +105,20 @@ def random_quantized_params(cfg: ModelConfig, seed: int = 0,
     cls = quant.qtensor_class(mode)
     rng = np.random.Generator(np.random.PCG64(seed))
     p: Dict[str, np.ndarray] = {}
+    # the entropy's period. A hybrid model stores an expert's matrices 3,072
+    # x 2,048 = 3 x 2^21 values (ModelConfig.expert_dims_stored): tiled from
+    # 2^24 values, every eighth expert would be the SAME matrix, and a row
+    # routed to the wrong one of them would show nowhere. An odd period
+    # keeps all of them apart. The other models keep the draw they have
+    # always had (their numbers on the chip are recorded against it).
+    period = (1 << 24) - 57 if cfg.mixer_types else 1 << 24
     for name, (shape, kind, sigma) in llama.param_specs(cfg).items():
         axes = quant.quant_axes(name)
         if name == "router_bias":
             p[name] = np.zeros(shape, np.float32)
+        elif kind in llama.SSM_INITS:
+            p[name] = np.asarray(llama.SSM_INITS[kind](
+                rng.random(shape, dtype=np.float32)), np.float32)
         elif kind == "ones":
             p[name] = np.ones(shape, dt)
         elif kind == "zeros":
@@ -119,7 +129,7 @@ def random_quantized_params(cfg: ModelConfig, seed: int = 0,
             # irrelevant here (no checkpoint to reproduce; serving
             # timing is value-independent) — only shape/dtype/scale
             # matter, and multi-GiB PCG64 streams cost minutes
-            ent = np.frombuffer(rng.bytes(min(n, 1 << 24)), dtype=np.int8)
+            ent = np.frombuffer(rng.bytes(min(n, period)), dtype=np.int8)
             q = np.tile(ent, -(-n // ent.size))[:n].reshape(shape)
             sshape = tuple(1 if i in axes else s
                            for i, s in enumerate(shape))
@@ -129,6 +139,12 @@ def random_quantized_params(cfg: ModelConfig, seed: int = 0,
             # unquantized weight (router etc.): small enough for normals
             p[name] = (rng.standard_normal(shape, dtype=np.float32)
                        * sigma).astype(dt)
+    for name, cuts in llama.zero_lanes(cfg).items():
+        # what a hybrid model stores past the model's own extents: zero
+        q = p[name].q.copy()
+        for axis, extent in cuts:
+            q[(slice(None),) * axis + (slice(extent, None),)] = 0
+        p[name] = cls(q, p[name].scale)
     return p
 
 

@@ -79,6 +79,10 @@ QUANT_AXES: Dict[str, Tuple[int, ...]] = {
     "w_gate": (1,),  # [L, E, F]
     "w_up": (1,),
     "w_down": (1,),  # [L, F, E]
+    # a hybrid model's Mamba-2 projections (the conv and the per-head
+    # vectors stay in their own dtypes)
+    "ssm_in": (1,),   # [L_M, E, z | x B C | dt]
+    "ssm_out": (1,),  # [L_M, d_in, E]
     "moe_w_gate": (2,),  # [L, X, E, F]
     "moe_w_up": (2,),
     "moe_w_down": (2,),  # [L, X, F, E]

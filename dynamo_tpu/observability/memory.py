@@ -215,9 +215,21 @@ class MemoryAccountant:
                     "free_pages": rings.allocator.free_pages,
                     "pages_handed_back": rings.handed_back}}
 
+        # a hybrid model's state slots: what a live sequence owns beside
+        # its pages, whatever its length (a slot each, from its first
+        # chunk to its last token)
+        state_slots = None
+        if eng.kv_spec.state_layers:
+            held = len(eng.seqs) + (eng._inflight is not None)
+            state_slots = {
+                "held": held, "total": eng.kv_spec.state_slots,
+                "bytes": held * eng.kv_spec.bytes_per_slot()}
+
         return {
             "page_bytes": pb,
             "bytes_per_token": eng.kv_spec.bytes_per_token(),
+            "bytes_per_slot": eng.kv_spec.bytes_per_slot(),
+            "state_slots": state_slots,
             "bytes_per_token_by_kind": eng.kv_spec.bytes_per_token_by_kind(),
             "rows_by_kind": rows_by_kind,
             # the row kinds a page holds, lanes a token a layer: the K/V or

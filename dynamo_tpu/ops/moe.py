@@ -127,14 +127,43 @@ def scatter_combine(topi: jax.Array, weights: jax.Array, num_experts: int,
     )
 
 
+def two_matrix_act(act: str, u: jax.Array) -> jax.Array:
+    """The activation of an expert of TWO matrices, act(x W_up) W_down
+    (ModelConfig.expert_act): "relu2" = relu(u)^2, "silu"."""
+    if act == "relu2":
+        return jnp.square(jax.nn.relu(u))
+    if act == "silu":
+        return jax.nn.silu(u)
+    raise ValueError(f"unknown two-matrix expert activation {act!r}")
+
+
+def _rows_of(w) -> int:
+    """Input rows [.., K, N] of a (maybe quantized) expert stack: K."""
+    return (w.q if isinstance(w, quant.QTensor) else w).shape[-2]
+
+
+def _pad_lanes(x: jax.Array, width: int) -> jax.Array:
+    """x [T, E] with zero lanes up to `width` (a two-matrix expert's
+    matrices may be stored with zero rows past the model's hidden size:
+    ModelConfig.expert_dims_stored); x itself where it has them already."""
+    extra = width - x.shape[-1]
+    return jnp.pad(x, ((0, 0), (0, extra))) if extra else x
+
+
 def moe_mlp_dense(
     x: jax.Array,        # [T, E]
     combine: jax.Array,  # [T, X]
-    w_gate: jax.Array,   # [X, E, F]
+    w_gate: jax.Array,   # [X, E, F]; None: an expert of two matrices
     w_up: jax.Array,
     w_down: jax.Array,   # [X, F, E]
+    act: str = "",
 ) -> jax.Array:
     """All experts see all tokens; combine zeroes non-selected outputs."""
+    if w_gate is None:
+        h = two_matrix_act(act, qeinsum(
+            "te,xef->txf", _pad_lanes(x, _rows_of(w_up)), w_up))
+        y = qeinsum("txf,xfe->txe", h, w_down)[..., :x.shape[-1]]
+        return jnp.einsum("txe,tx->te", y, combine)
     g = qeinsum("te,xef->txf", x, w_gate)
     u = qeinsum("te,xef->txf", x, w_up)
     y = qeinsum("txf,xfe->txe", jax.nn.silu(g) * u, w_down)
@@ -243,22 +272,30 @@ def _grouped_dot(x: jax.Array, w, group_sizes: jax.Array,
     return y * w_scale.astype(y.dtype)
 
 
-def _expert_rows(rows: int, x, tok, row_expert, wr, n_held, group_sizes,
-                 w_gate, w_up, w_down) -> jax.Array:
+def _expert_rows(rows: int, act: str, x, tok, row_expert, wr, n_held,
+                 group_sizes, w_gate, w_up, w_down) -> jax.Array:
     """The expert layer over the first `rows` sorted assignments (those
     that belong to a group come first: n_held <= rows): gather the token
-    rows, gate / up / down as grouped matmuls, weight each result row and
-    add it back to its token -> [T, E]."""
+    rows, gate / up / down as grouped matmuls (up / down alone where
+    w_gate is None: an expert of two matrices, `act` its activation),
+    weight each result row and add it back to its token -> [T, E]."""
     if not rows:
         return jnp.zeros_like(x)
     tok, row_expert, wr = tok[:rows], row_expert[:rows], wr[:rows]
     live = (jnp.arange(rows) < n_held)[:, None]
     with jax.named_scope("moe_experts"):
         xs = jnp.take(x, tok, axis=0)  # [R, E]
-        g = _grouped_dot(xs, w_gate, group_sizes, row_expert)
-        u = _grouped_dot(xs, w_up, group_sizes, row_expert)
-        h = jnp.where(live, jax.nn.silu(g) * u, 0)
+        if w_gate is None:
+            u = _grouped_dot(_pad_lanes(xs, _rows_of(w_up)), w_up,
+                             group_sizes, row_expert)
+            h = jnp.where(live, two_matrix_act(act, u), 0)
+        else:
+            g = _grouped_dot(xs, w_gate, group_sizes, row_expert)
+            u = _grouped_dot(xs, w_up, group_sizes, row_expert)
+            h = jnp.where(live, jax.nn.silu(g) * u, 0)
         y = _grouped_dot(h, w_down, group_sizes, row_expert)
+        if w_gate is None:
+            y = y[:, :x.shape[-1]]  # the zero lanes of a stored-wider W_down
         # rows behind the last group were never written by the grouped
         # matmul: select, do not multiply (they may hold anything)
         y = jnp.where(live, y.astype(jnp.float32) * wr[:, None], 0)
@@ -269,14 +306,15 @@ def moe_mlp_grouped(
     x: jax.Array,        # [T, E]
     topi: jax.Array,     # [T, K] expert ids over the router's whole width
     weights: jax.Array,  # [T, K] gate weights
-    w_gate,              # [Xh, E, F] the experts HELD here
-    w_up,
+    w_gate,              # [Xh, E, F] the experts HELD here; None: experts
+    w_up,                # of two matrices, act(x W_up) W_down
     w_down,              # [Xh, F, E]
     *,
     expert_offset: int = 0,
     num_experts: int | None = None,
     token_mask: jax.Array | None = None,
     layer=None,
+    act: str = "",
 ):
     """Each token is computed only in the experts it picked, and only in
     those held here: experts [expert_offset, expert_offset + Xh) of the
@@ -307,7 +345,7 @@ def moe_mlp_grouped(
     it): 1.06 GB a layer at Kimi-K2's widths, read or not (seen on the
     chip, PR 27: 70% of a decode step's device time)."""
     t, k = topi.shape
-    stack = (w_gate.q if isinstance(w_gate, quant.QTensor) else w_gate).shape
+    stack = (w_up.q if isinstance(w_up, quant.QTensor) else w_up).shape
     xh = stack[-3]
     local = topi.astype(jnp.int32) - expert_offset
     held = (local >= 0) & (local < xh)
@@ -330,7 +368,7 @@ def moe_mlp_grouped(
     ladder = jnp.asarray(rungs, jnp.int32)
     rung = jnp.sum(ladder[:-1] < n_held)  # the first that holds n_held
     out = jax.lax.switch(
-        rung, [functools.partial(_expert_rows, r) for r in rungs],
+        rung, [functools.partial(_expert_rows, r, act) for r in rungs],
         x, tok, row_expert, wr, n_held, group_sizes, w_gate, w_up, w_down)
     n_all = (jnp.sum(token_mask) * k if token_mask is not None
              else jnp.int32(t * k))

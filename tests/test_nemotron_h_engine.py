@@ -1,0 +1,222 @@
+"""`tiny-nemotron-h-debug` through `Engine` on the CPU: the served path
+(chunked prompts riding mixed steps, fused decode windows, warm-up) over a
+state slot a sequence beside the ONE attention layer's pages, held to the
+float32 reference's greedy tokens; a live sequence holds exactly one slot
+from its first chunk to its last token; pages and slots conserved across
+finish, abort, preemption and resume; a reused slot starts from zero; a
+prefix hit counted inexact and served by recompute; `metrics.ssm`,
+`metrics.attn_kinds.full` and the memory snapshot; what is refused."""
+
+import dataclasses
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine.config import EngineConfig
+from dynamo_tpu.engine.engine import Engine
+from dynamo_tpu.engine.request import GenRequest
+from dynamo_tpu.models.reference import nemotron_h as ref
+from dynamo_tpu.observability.memory import MemoryAccountant
+
+from nemotron_h_common import hf_dict, tiny
+
+CFG = dict(model="tiny-nemotron-h-debug", page_size=4, num_pages=128,
+           max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
+           mixed_batch_tokens=8, num_scheduler_steps=4, dtype="float32")
+
+
+def prompt(seed: int, n: int):
+    return [int(t) for t in np.random.default_rng(seed).integers(3, 500, n)]
+
+
+def drain(eng: Engine) -> dict:
+    out = {}
+    while eng.has_work:
+        for ev in eng.step():
+            if ev.token_id >= 0:
+                out.setdefault(ev.request_id, []).append(ev.token_id)
+    return out
+
+
+def reference_greedy(eng: Engine, tokens, n_new: int):
+    """The reference's argmax at every generated position, teacher forced
+    on `tokens` (prompt + what the engine gave)."""
+    cfg = dataclasses.replace(eng.model_cfg, dtype="float32")
+    logits = ref.forward(ref.Config.from_hf(hf_dict(cfg)),
+                         ref.dequantize(eng.params), jnp.asarray(tokens))
+    first = len(tokens) - n_new
+    return [int(t) for t in np.argmax(logits[first - 1:-1], axis=-1)]
+
+
+def slots_held(eng: Engine) -> int:
+    return MemoryAccountant(eng).snapshot()["state_slots"]["held"]
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return Engine(EngineConfig(**CFG))
+
+
+def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
+    """A 70-token prompt (nine chunks: its state rides its slot from step
+    to step) beside a 9-token one that arrives while it decodes: the short
+    one's chunks ride mixed steps. Greedy tokens are the reference's; every
+    live sequence holds ONE slot whatever its length; afterwards pages and
+    slots are whole again."""
+    eng = engine
+    free = eng.allocator.free_pages
+    long_p, short_p = prompt(1, 70), prompt(2, 9)
+    eng.add_request(GenRequest("long", long_p, max_tokens=24,
+                               temperature=0.0, ignore_eos=True))
+    got, sent, held = {}, False, set()
+    while eng.has_work:
+        for ev in eng.step():
+            if ev.token_id >= 0:
+                got.setdefault(ev.request_id, []).append(ev.token_id)
+        held.add((len(eng.seqs) + (eng._inflight is not None),
+                  slots_held(eng)))
+        if not sent and len(got.get("long", ())) >= 3:
+            eng.add_request(GenRequest("short", short_p, max_tokens=12,
+                                       temperature=0.0, ignore_eos=True))
+            sent = True
+    assert eng.metrics.mixed_count > 0  # the short prompt rode mixed steps
+    assert all(live == slots for live, slots in held) and (2, 2) in held
+    for name, p in (("long", long_p), ("short", short_p)):
+        toks = got[name]
+        assert toks == reference_greedy(eng, p + toks, len(toks)), name
+    cached = eng.prefix_cache.stats()["entries"]  # full pages it published
+    assert eng.allocator.free_pages + cached == free
+    assert slots_held(eng) == 0 and len(eng._free_slots) == 4
+    ssm = eng.metrics.ssm
+    assert ssm["chunk_tokens"] == 79 and ssm["chunk_calls"] == 9 + 2
+    # a token a live row a step, the prompts' first tokens apart
+    assert ssm["decode_rows"] == (24 - 1) + (12 - 1)
+    assert ssm["layer_steps"] >= ssm["chunk_calls"]
+    kinds = eng.metrics.attn_kinds
+    assert kinds["full"]["decode_q_rows"] > 0
+    assert kinds["full"]["mixed_chunk_q_rows"] == 79
+    assert not any(kinds["window"].values())  # it has no such layer
+    counters = eng.metrics.kernel_counters()
+    assert counters["ssm"] == ssm
+    # four expert layers a step beside four Mamba-2 ones
+    assert counters["moe"]["layer_steps"] > 0
+
+
+def test_a_prefix_hit_is_counted_inexact_and_served_by_recompute(engine):
+    """The same prompt again: its full pages are in the prefix cache, the
+    state at their end is not (nothing keeps one), so the hit is turned
+    into a miss, counted, and the tokens are the first run's."""
+    eng = engine
+    p = prompt(3, 40)
+    runs = []
+    for name in ("first", "again"):
+        eng.add_request(GenRequest(name, p, max_tokens=8, temperature=0.0,
+                                   ignore_eos=True))
+        runs.append(drain(eng)[name])
+    assert runs[0] == runs[1] == reference_greedy(eng, p + runs[0], 8)
+    assert eng.metrics.prefix_hits_inexact == 1
+    assert eng.prefix_cache.stats()["cached_tokens_served"] == 0
+
+
+def test_a_slot_reused_after_a_finish_or_an_abort_starts_from_zero(engine):
+    """Slots are handed out last-freed first, so each request here decodes
+    in the slot its predecessor left its state in: after a finish and
+    after an abort the next tenant's tokens are the reference's."""
+    eng = engine
+    eng.add_request(GenRequest("a", prompt(20, 30), max_tokens=10,
+                               temperature=0.0, ignore_eos=True))
+    drain(eng)
+    slot_a = eng._free_slots[-1]
+    eng.add_request(GenRequest("b", prompt(21, 26), max_tokens=40,
+                               temperature=0.0, ignore_eos=True))
+    for _ in range(8):
+        eng.step()
+    assert list(eng.seqs) == [slot_a]
+    eng.abort_request("b")
+    drain(eng)
+    assert slots_held(eng) == 0
+    p = prompt(22, 19)
+    eng.add_request(GenRequest("c", p, max_tokens=10, temperature=0.0,
+                               ignore_eos=True))
+    eng.step()
+    assert (eng._inflight.slot if eng._inflight else list(eng.seqs)[0]
+            ) == slot_a
+    toks = drain(eng)["c"]
+    assert toks == reference_greedy(eng, p + toks, 10)
+
+
+def test_memory_snapshot_counts_slots_beside_pages(engine):
+    eng = engine
+    eng.add_request(GenRequest("m", prompt(4, 50), max_tokens=30,
+                               temperature=0.0, ignore_eos=True))
+    eng.step()  # admitted: the first chunk has run, the slot is held
+    snap = MemoryAccountant(eng).snapshot()
+    per_slot = 4 * (4 * 8 * 8 * 4 + 3 * 64 * 4)  # S float32 + conv rows
+    assert snap["bytes_per_slot"] == per_slot
+    assert snap["state_slots"] == {"held": 1, "total": 4, "bytes": per_slot}
+    # the page pool is the ONE attention layer's
+    assert snap["bytes_per_token"] == 2 * 2 * 16 * 4
+    for _ in range(12):
+        eng.step()
+    assert slots_held(eng) == 1
+    drain(eng)
+    assert MemoryAccountant(eng).snapshot()["state_slots"]["held"] == 0
+
+
+def test_preemption_and_resume_conserve_pages_and_slots():
+    """A pool too small for three sequences' contexts: the engine preempts
+    by recompute (the state is dropped, the prompt and what was decoded
+    prefilled again from zero) and resumes; every request completes with
+    the tokens it gets alone, and pages and slots end whole."""
+    small = EngineConfig(**{**CFG, "num_pages": 40,
+                            "enable_prefix_caching": False})
+    eng = Engine(small)
+    prompts = {f"r{i}": prompt(10 + i, 30) for i in range(3)}
+    alone = {}
+    for name, p in prompts.items():
+        eng.add_request(GenRequest(name, p, max_tokens=40, temperature=0.0,
+                                   ignore_eos=True))
+        alone[name] = drain(eng)[name]
+    free = eng.allocator.free_pages
+    for name, p in prompts.items():
+        eng.add_request(GenRequest(name, p, max_tokens=40, temperature=0.0,
+                                   ignore_eos=True))
+    together = drain(eng)
+    assert eng.metrics.num_preempted > 0
+    assert together == alone
+    assert eng.allocator.free_pages == free
+    assert slots_held(eng) == 0 and sorted(eng._free_slots) == [0, 1, 2, 3]
+
+
+def test_warmup_compiles_what_the_window_runs(engine):
+    """After warmup() no request compiles a program: not a short prompt
+    whose decoders leave before it is done, nor a long one; the state
+    arrays ride every step program as donated buffers and come back."""
+    eng = engine
+    eng.warmup()
+    before = eng.compiled_program_count()
+    eng.add_request(GenRequest("a", prompt(5, 20), max_tokens=2,
+                               temperature=0.0, ignore_eos=True))
+    eng.step()
+    p = prompt(6, 60)
+    eng.add_request(GenRequest("b", p, max_tokens=6, temperature=0.0,
+                               ignore_eos=True))
+    toks = drain(eng)["b"]
+    assert eng.compiled_program_count() == before
+    assert toks == reference_greedy(eng, p + toks, 6)
+    assert len(eng.k_pages.state) == len(eng.v_pages.state) == 4
+    assert eng.k_pages.state[0].dtype == jnp.float32
+
+
+@pytest.mark.parametrize("change,word", [
+    (dict(speculative_mode="ngram", num_speculative_tokens=2), "speculation"),
+    (dict(lora_slots=2), "LoRA"),
+    (dict(kvbm_host_blocks=8), "KVBM"),
+    (dict(disaggregation_mode="prefill"), "disaggregated"),
+    (dict(kv_cache_dtype="int8"), "int8"),
+    (dict(tensor_parallel=2), "tensor parallelism"),
+], ids=["speculation", "lora", "kvbm", "disagg", "int8_kv", "tp"])
+def test_what_a_state_slot_does_not_serve_is_refused(change, word):
+    with pytest.raises(ValueError, match=word):
+        Engine(EngineConfig(**{**CFG, **change}), model_cfg=tiny())

@@ -422,3 +422,85 @@ def test_prefill_kernel_compiles_for_v5e_at_nine_heads_a_kv_head(one_chip):
             arg((s, 8, HEAD_DIM), jnp.bfloat16),
             arg((), jnp.int32)).compile().as_text()
         assert "tpu_custom_call" in text
+
+
+# NVIDIA-Nemotron-3-Nano's cut (PR 42): 64 slots = 64 state slots, a decode
+# table of 6,144 tokens, the one chunked-prompt table width the engine keeps
+NEMOTRON_SLOTS, NEMOTRON_TABLE, NEMOTRON_CHUNK_TABLE = 64, 384, 399
+NEMOTRON_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
+                   "ssm_out_proj", "attn_full", "moe_router", "moe_experts",
+                   "moe_shared_expert")
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_nemotron_step_compiles_for_v5e_under_its_scope_names(one_chip,
+                                                              program):
+    """The cut model's whole decode step and mixed step at the cell's
+    sizes (w8a8, 64 slots and their states, a 256-token chunk), for a
+    described v5e: no op leaves the kernels (16 query heads over each of 2
+    KV heads, 256 lanes a row, no rotary: no counted fallback), every span
+    the benchmark reads is named in the HLO, no state-sized array is
+    copied, and everything beside the arguments stays under a tenth of a
+    gigabyte (the experts' stack is NOT copied: ModelConfig.
+    expert_dims_stored says what that took)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.engine.kv_cache import KVCacheSpec
+    from dynamo_tpu.models import llama, quant
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.ops import attention as att
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return arg(shape, jnp.int32)
+
+    cfg = ModelConfig.from_model_name(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks/chip/configs/nemotron3-nano-w8a8-1chip"))
+    b = NEMOTRON_SLOTS
+    spec = KVCacheSpec.from_model(cfg, 8192, PAGE, state_slots=b)
+    params = {}
+    for name, (shape, kind, _) in llama.param_specs(cfg).items():
+        axes = quant.quant_axes(name)
+        if axes and kind == "normal":
+            params[name] = quant.QTensorA8(
+                arg(shape, jnp.int8),
+                arg([1 if i in axes else s for i, s in enumerate(shape)],
+                    jnp.float32))
+        else:
+            params[name] = arg(shape, jnp.float32 if (
+                kind in llama.SSM_INITS or name == "router_bias")
+                else jnp.bfloat16)
+    assert params["moe_w_up"].q.shape == (4, 128, 3072, 2048)
+    assert params["moe_w_down"].q.shape == (4, 128, 2048, 3072)
+    kp = llama.StatePools(arg(spec.shape, jnp.bfloat16), tuple(
+        arg((b,) + spec.ssm_shape, jnp.float32) for _ in range(4)))
+    vp = llama.StatePools(arg(spec.v_shape, jnp.bfloat16), tuple(
+        arg((b,) + spec.conv_shape, jnp.bfloat16) for _ in range(4)))
+    before = dict(att.pallas_fallback_counts())
+    with att.attention_context("pallas", None, 1):
+        if program == "decode":
+            compiled = jax.jit(functools.partial(
+                llama.decode_step, cfg, page_size=PAGE),
+                donate_argnums=(5, 6)).lower(
+                params, i32(b), i32(b), i32(b, NEMOTRON_TABLE), i32(b), kp,
+                vp).compile()
+        else:
+            compiled = jax.jit(functools.partial(
+                llama.mixed_step, cfg, page_size=PAGE),
+                donate_argnums=(9, 10)).lower(
+                params, i32(b), i32(b), i32(b, NEMOTRON_TABLE), i32(b),
+                i32(CHUNK), i32(), i32(),
+                llama.SlotPages(i32(NEMOTRON_CHUNK_TABLE), i32()), kp,
+                vp).compile()
+    assert dict(att.pallas_fallback_counts()) == before
+    text = compiled.as_text()
+    for scope in NEMOTRON_SCOPES:
+        assert scope in text, scope
+    assert not re.search(r"f32\[64,64,64,128\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e8

@@ -367,7 +367,8 @@ def test_a_capture_samples_the_kernels_counters(server):
     for got, due in zip(times, (0.0, 0.25, 0.5, 0.6)):
         assert due <= got < due + 0.2
     for s in cap["samples"]:
-        assert set(s["metrics"]) == {"attn", "attn_kinds", "dsa", "moe"}
+        assert set(s["metrics"]) == {"attn", "attn_kinds", "dsa", "moe",
+                                      "ssm"}
         assert s["metrics"]["attn"] == stats["metrics"]["attn"]  # idle
 
 

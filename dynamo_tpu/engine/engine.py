@@ -49,6 +49,7 @@ from dynamo_tpu.lora.registry import NoFreeAdapterSlot
 from dynamo_tpu.models import llama
 from dynamo_tpu.ops import attention as att_ops
 from dynamo_tpu.ops import json_guide
+from dynamo_tpu.ops import ssm as ssm_ops
 from dynamo_tpu.ops.moe import MOE_STATS
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.parallel.mesh import MeshConfig, build_mesh
@@ -260,10 +261,12 @@ class EngineMetrics:
         # one state read and written; chunk_tokens / chunk_calls: prompt
         # tokens the chunked scan ran over and the programs that ran it;
         # layer_steps: steps a Mamba-2 layer ran (a fused window counts its
-        # steps)
+        # steps); slots_touched: state slots the one-token update read and
+        # wrote (the live rows under the kernel, every slot under its XLA
+        # twin: ops/ssm.update; a program of prompt rows alone runs none)
         self.ssm: Dict[str, int] = {
             "decode_rows": 0, "chunk_tokens": 0, "chunk_calls": 0,
-            "layer_steps": 0}
+            "layer_steps": 0, "slots_touched": 0}
         # what the sparse-attention indexer of a DeepSeek-V3.2-style model
         # was asked for (all zero for any other model), counted like
         # `attn`: on the host at dispatch, a layer's worth. A query in a
@@ -365,12 +368,14 @@ class EngineMetrics:
                 d["rows_selected"] += int(np.minimum(c, topk).sum())
 
     def observe_ssm(self, decode_rows: int, steps: int,
-                    chunk_tokens: int = 0) -> None:
+                    chunk_tokens: int = 0, slots_touched: int = 0) -> None:
         """One dispatch of a hybrid model: `decode_rows` live rows over
-        `steps` steps, and `chunk_tokens` of a prompt (0: no chunk)."""
+        `steps` steps, each step's update touching `slots_touched` state
+        slots, and `chunk_tokens` of a prompt (0: no chunk)."""
         s = self.ssm
         s["decode_rows"] += decode_rows * steps
         s["layer_steps"] += steps
+        s["slots_touched"] += slots_touched * steps
         if chunk_tokens:
             s["chunk_tokens"] += chunk_tokens
             s["chunk_calls"] += 1
@@ -1100,6 +1105,8 @@ class Engine:
                 )
                 step = active.astype(positions.dtype)  # inactive slots frozen
                 b = tokens.shape[0]
+                # a hybrid model's live state slots, once for every step
+                state_slots = llama.live_state_slots(mcfg, block_tables)
                 if guided:
                     gmode0, gdepth0, gbits0, gactive = gs
                     gact = gactive & active
@@ -1112,7 +1119,7 @@ class Engine:
                     out = llama.decode_step(
                         mcfg, params, toks, pos, block_tables, ctx_lens,
                         kp, vp, page_size=page_size,
-                        adapter_slots=aslots,
+                        adapter_slots=aslots, state_slots=state_slots,
                     )
                     logits = out.logits
                     if guided:
@@ -1365,6 +1372,13 @@ class Engine:
         backend = None if cfg.attention_backend == "auto" else cfg.attention_backend
         mesh = self.mesh
         lane_blocks = self.kv_spec.lane_blocks
+        # whether a hybrid model's state updates walk the live slots only
+        # (the kernel) or every slot (its XLA twin): metrics.ssm
+        self._ssm_live_only = False
+        if mcfg.mixer_types:
+            with att_ops.attention_context(backend, mesh, lane_blocks):
+                self._ssm_live_only = ssm_ops.update_backend(
+                    self.kv_spec.ssm_shape) != "xla"
 
         # raw jitted fns, for warmup verification (compile-cache sizes)
         self._jit_handles = {}
@@ -3714,7 +3728,9 @@ class Engine:
         hybrid = bool(self.model_cfg.mixer_types)
         kinds = bool(self.model_cfg.layer_types) or hybrid
         if hybrid:
-            m.observe_ssm(len(slots), steps, take)
+            m.observe_ssm(len(slots), steps, take,
+                          len(slots) if self._ssm_live_only
+                          else self.cfg.max_num_seqs)
         if self.model_cfg.is_mla or kinds:  # read by the kernels' rooflines
             contexts = [self.seqs[s].num_tokens for s in slots
                         if s in self.seqs]

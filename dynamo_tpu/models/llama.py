@@ -1154,14 +1154,15 @@ def _mamba_mixer(cfg: ModelConfig, lp: Params, u: jax.Array, ssm, conv,
                  decode=None, chunk=None):
     """The Mamba-2 mixer over u [T, E] (normed) -> (y [T, E], ssm, conv).
 
-    The rows are `decode` = (b, live [b] bool): b rows, one token a decode
-    slot, row i updating slot i where live; then `chunk` = (c, slot,
-    n_valid, fresh): c rows of one prompt whose state lies in `slot`, the
-    first n_valid real, starting from zero where `fresh` (a traced bool:
-    the prompt's first chunk). The projections, the gate and the norm run
-    over all rows at once; the conv and the recurrence a part each. Padding
-    rows and empty slots are handed on with dt = 0: they leave a state as
-    it was (ops/ssm.py)."""
+    The rows are `decode` = (b, live [b] bool, its ssm_ops.LiveSlots): b
+    rows, one token a decode slot, row i updating slot i where live; then
+    `chunk` = (c, slot, n_valid, fresh): c rows of one prompt whose state
+    lies in `slot`, the first n_valid real, starting from zero where
+    `fresh` (a traced bool: the prompt's first chunk). The projections, the
+    gate and the norm run over all rows at once; the conv and the
+    recurrence a part each. An empty slot's state stays as it was (the
+    kernel never visits it; the XLA twin hands it dt = 0), and so does a
+    state under a chunk's padding rows (dt = 0: ops/ssm.py)."""
     hm, pm = cfg.mamba_num_heads, cfg.mamba_head_dim
     g, n = cfg.mamba_n_groups, cfg.ssm_state_size
     d_in, c_dim = cfg.mamba_d_inner, cfg.mamba_conv_dim
@@ -1180,15 +1181,14 @@ def _mamba_mixer(cfg: ModelConfig, lp: Params, u: jax.Array, ssm, conv,
 
     ys, off = [], 0
     if decode is not None:
-        b, live = decode
+        b, live, slots = decode
         with jax.named_scope("ssm_conv"):
             xo, conv = ssm_ops.conv_step(
                 xbc[:b], conv, lp["ssm_conv_w"], lp["ssm_conv_b"], live)
         with jax.named_scope("ssm_scan"):
             x, bm, cm = parts(xo)
-            y, ssm = ssm_ops.step(
-                x, jnp.where(live[:, None], dt[:b], 0.0), a, bm, cm,
-                lp["ssm_d"], ssm)
+            y, ssm = ssm_ops.update(x, dt[:b], a, bm, cm, lp["ssm_d"], ssm,
+                                    live, slots)
         ys.append(y.reshape(b, d_in))
         off = b
     if chunk is not None:
@@ -1293,8 +1293,19 @@ def _hybrid_prefill(cfg, params, tokens, n_valid, k_pages, v_pages,
     return PrefillOut(_logits(cfg, params, last)[0], k_pages, v_pages, counts)
 
 
+def live_state_slots(cfg: ModelConfig, block_tables: jax.Array):
+    """The live decode slots as a hybrid model's state updates walk them
+    (ops/ssm.LiveSlots), None for any other model. A step program builds it
+    once, and a fused window once for all its steps (`decode_step`'s
+    `state_slots`): the table does not change inside a window."""
+    if not cfg.mixer_types:
+        return None
+    return ssm_ops.live_slots(_live_slots(block_tables))
+
+
 def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
-                 k_pages, v_pages, page_size: int, chunk=None):
+                 k_pages, v_pages, page_size: int, chunk=None,
+                 state_slots=None):
     """Every decode slot a token and, with `chunk` = (tokens [C], start,
     n_valid, pages: SlotPages), one chunk of a prompt in the same forward:
     rows [B decode | C chunk]. Returns (x [B(+C), E] after the last layer,
@@ -1305,6 +1316,8 @@ def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
             f"{b} decode rows over {k_pages.state[0].shape[0]} state slots: "
             "decode row i updates state slot i")
     live = _live_slots(block_tables)
+    if state_slots is None:
+        state_slots = ssm_ops.live_slots(live)
     kernel_lens = jnp.where(live, context_lens, 0)
     tables = block_tables
     all_tokens, token_mask, mchunk = tokens, live, None
@@ -1335,7 +1348,7 @@ def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
 
     return _hybrid_layers(
         cfg, params, _embed_rows(cfg, params, all_tokens), k_pages, v_pages,
-        attend, token_mask, decode=(b, live), chunk=mchunk)
+        attend, token_mask, decode=(b, live, state_slots), chunk=mchunk)
 
 
 def _no_hybrid(cfg: ModelConfig, what: str) -> None:
@@ -1743,12 +1756,13 @@ def decode_step(
     *,
     page_size: int,
     adapter_slots=None,  # [B] int32 per-slot LoRA slots, or None
+    state_slots=None,  # live_state_slots(), built by a window for its steps
 ) -> DecodeOut:
     """One continuous-batching decode step over all batch slots."""
     if cfg.mixer_types:
         x, k_pages, v_pages, counts = _hybrid_step(
             cfg, params, tokens, positions, block_tables, context_lens,
-            k_pages, v_pages, page_size)
+            k_pages, v_pages, page_size, state_slots=state_slots)
         return DecodeOut(_logits(cfg, params, x), k_pages, v_pages, counts)
     x = _embed_rows(cfg, params, tokens)  # [B, E]
     slots = (None if adapter_slots is None

@@ -1,4 +1,4 @@
-"""On-chip compile + parity check of every Pallas attention kernel.
+"""On-chip compile + parity check of every Pallas kernel.
 
 Interpret mode proves a kernel's arithmetic; only Mosaic on a real chip
 proves it lowers. This module compiles each kernel at a serving model's
@@ -11,7 +11,10 @@ cell runs it (`mla_*`: 64 heads on
 one 640-lane row stored once, 64 slots, contexts of 8-10k, a chunk behind
 an 8,192-token cached prefix) — and compares it with its XLA twin from
 `ops/attention.py` (for `mla_*` the same sums against the one shared row,
-written here: the twin's 64-fold repeat of the row does not fit):
+written here: the twin's 64-fold repeat of the row does not fit); and the
+Mamba-2 one-token state update over the live slots at the Nemotron cell's
+state (`ssm_update_cell`: 64 slots of [64, 64, 128] float32, 27 live)
+against `ops/ssm.step_every_slot`:
 
     python -m dynamo_tpu.ops.kernel_parity            # on the chip
     python -m dynamo_tpu.ops.kernel_parity --interpret  # CPU rehearsal
@@ -41,6 +44,7 @@ import numpy as np
 
 from dynamo_tpu.ops import attention as att
 from dynamo_tpu.ops import pallas_attention as pa
+from dynamo_tpu.ops import ssm
 
 # bf16 pools/queries with unit-normal values: outputs are convex averages
 # of |v| <~ 4, the XLA twins round scores and probabilities to bf16
@@ -54,6 +58,10 @@ HEAD_DIM = 128
 NUM_POOL_PAGES = 96
 
 OUT_PATH = os.path.join("chiprun_out", "kernel_parity.json")
+
+# Nemotron-3-Nano's Mamba-2 state a slot: heads, head lanes, groups, state
+# lanes (benchmarks/chip/configs/nemotron3-nano-w8a8-1chip)
+SSM_STATE = (64, 64, 8, 128)
 
 # (label, query heads, KV heads): qwen2.5-7b whole, and one tp=4 shard
 SHAPES: Tuple[Tuple[str, int, int], ...] = (
@@ -406,6 +414,37 @@ def _case_mla_prefill(s: int, interpret: bool):
     return ker, ref, (q, k, k, sl)
 
 
+def _case_ssm_update(interpret: bool):
+    """y and the states after one token, side by side: a live row's are
+    the twin's, an empty row's y is 0 and its state what it was."""
+    b, live_n = (8, 3) if interpret else (64, 27)
+    h, p, g, n = SSM_STATE
+    rng = np.random.default_rng(7)
+    live = np.zeros((b,), bool)
+    live[rng.permutation(b)[:live_n]] = True
+    live = jnp.asarray(live)
+    args = (jnp.asarray(rng.normal(size=(b, h, p)), jnp.bfloat16),
+            jnp.asarray(rng.uniform(1e-3, 1e-1, (b, h)), jnp.float32),
+            -jnp.asarray(rng.uniform(1, 16, (h,)), jnp.float32),
+            jnp.asarray(rng.normal(size=(b, g, n)), jnp.bfloat16),
+            jnp.asarray(rng.normal(size=(b, g, n)), jnp.bfloat16),
+            jnp.asarray(rng.normal(size=(h,)), jnp.float32),
+            jnp.asarray(rng.normal(size=(b, h, p, n)), jnp.float32))
+
+    def side_by_side(y, new):
+        return jnp.concatenate([y.reshape(b, -1), new.reshape(b, -1)], axis=1)
+
+    def ker(x, dt, *rest):
+        return side_by_side(*ssm.update_live(
+            x, dt, *rest, live, ssm.live_slots(live), interpret=interpret))
+
+    def ref(*a):
+        y, new = ssm.step_every_slot(*a, live)
+        return side_by_side(jnp.where(live[:, None, None], y, 0.0), new)
+
+    return jax.jit(ker), jax.jit(ref), args
+
+
 def cases(interpret: bool) -> List[Tuple[str, Callable]]:
     out: List[Tuple[str, Callable]] = []
     for label, h, n_kv in SHAPES:
@@ -442,6 +481,9 @@ def cases(interpret: bool) -> List[Tuple[str, Callable]]:
     for s_len in (16, 256):
         out.append((f"mla_prefill_s{s_len}/{label}",
                     functools.partial(_case_mla_prefill, s_len, interpret)))
+    h, p, _, n = SSM_STATE
+    out.append((f"ssm_update_cell/{h}h{p}p{n}n",
+                functools.partial(_case_ssm_update, interpret)))
     return out
 
 

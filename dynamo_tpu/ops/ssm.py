@@ -8,21 +8,32 @@ the conv's last K-1 input rows [K-1, C]. Neither grows with the context.
 
     S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) B_t      y_t = S_t C_t + D x_t
 
-Plain XLA compositions (a kernel of its own is a later step). Every product
-that touches the state runs in float32 at precision HIGHEST: the state is
-what a 2,000-token prompt accumulates into, and a bf16 pass over it is the
-"state kept in bf16" the tests refuse. Their FLOPs are small beside the
+Plain XLA compositions, and ONE kernel: the one-token update of the decode
+slots (`update_live`) reads and writes the LIVE slots' states only, where
+they lie (`step` is its XLA twin over every slot, the reference and the
+path of backend `xla` and of a CPU). Every product that touches the state
+runs in float32 (the matmuls at precision HIGHEST): the state is what a
+2,000-token prompt accumulates into, and a bf16 pass over it is the "state
+kept in bf16" the tests refuse. Their FLOPs are small beside the
 projections' (a 256-token chunk: 0.6 GFLOP a layer).
 
 Rows that are padding (a prompt padded to its bucket, a chunk to 256, an
-empty decode slot) are handed in with dt = 0: exp(0) = 1 and dt x = 0, so
-they leave `S` bit for bit; the conv rows kept are the last K-1 REAL ones.
+empty decode slot) are handed to the XLA compositions with dt = 0: exp(0) =
+1 and dt x = 0, so they leave `S` bit for bit; the conv rows kept are the
+last K-1 REAL ones. The kernel never visits an empty slot.
 """
 
 from __future__ import annotations
 
+import functools
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from dynamo_tpu.ops import attention as att
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -124,6 +135,183 @@ def step(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
            * _by_head(bm.astype(f32), h)[:, :, None, :])
     y = jnp.sum(new * _by_head(cm.astype(f32), h)[:, :, None, :], axis=-1)
     return y + d.astype(f32)[None, :, None] * xf, new
+
+
+def step_every_slot(x, dt, a, bm, cm, d, state, live):
+    """`step` with dt = 0 on the slots that are not `live` [B]: every slot
+    is read and written, an empty one bit for bit as it was."""
+    return step(x, jnp.where(live[:, None], dt, 0.0), a, bm, cm, d, state)
+
+
+class LiveSlots(NamedTuple):
+    """The decode slots that hold a sequence, as `update_live` walks them:
+    `ids` [B] int32, the live slots first and in order (what follows them
+    is never read as a slot), and `count` [1] int32. Built once a program
+    (once a fused window: its slots do not change inside it), not once a
+    layer."""
+    ids: jax.Array
+    count: jax.Array
+
+
+def live_slots(live: jax.Array) -> LiveSlots:
+    """`live` [B] bool -> its LiveSlots."""
+    ids = jnp.nonzero(live, size=live.shape[0], fill_value=0)[0]
+    return LiveSlots(ids.astype(jnp.int32),
+                     jnp.sum(live, dtype=jnp.int32)[None])
+
+
+# a grid step's block of one slot's state: as many heads as fit, the served
+# [64, 64, 128] state whole. Measured alone on a v5e at 27 of 64 slots live
+# (scripts/ssm_microbench.py, PR 43): 0.208 ms a layer in blocks of 64
+# heads, 0.225 of 32, 0.253 of 16 (XLA over every slot: 0.424); a grid step
+# costs 0.35 us, a skipped one 0.13.
+_STATE_BLOCK_BYTES = 2 << 20
+_OP = "ssm state update"
+
+
+def _head_block(heads: int, head_bytes: int) -> int:
+    """The most heads a block (a divisor of `heads`) that fits."""
+    fits = max(1, _STATE_BLOCK_BYTES // head_bytes)
+    return max(h for h in range(1, heads + 1)
+               if heads % h == 0 and h <= fits)
+
+
+def update_backend(state_shape) -> str:
+    """Which implementation `update` takes for states [B, H, P, N] under
+    the scoped attention backend (ops/attention: `auto` is the kernel on a
+    TPU): `pallas` | `pallas_interpret` | `xla`. The engine asks too, to
+    count the slots a dispatch touches."""
+    backend = att._resolve_backend()
+    if backend == "pallas" and (state_shape[-1] % 128 or state_shape[-2] % 8):
+        return "xla"  # a head's state is no multiple of the float32 tile
+    return backend if backend in att._KERNEL_BACKENDS else "xla"
+
+
+def _update_kernel(ids_ref, n_ref, dt_ref, decay_ref, d_ref,  # SMEM
+                   x_ref,  # [1, 1, P, hb]: this block's heads in the lanes
+                   b_ref,  # [1, G, N]
+                   c_ref,  # [1, G, N]
+                   s_ref,  # [1, hb, P, N]
+                   y_ref,  # [1, 1, P, hb]
+                   o_ref,  # [1, hb, P, N], the same memory as s_ref's
+                   *, heads: int, head_block: int, group_heads: int):
+    """Grid step (i, j): heads [j hb, (j + 1) hb) of the i-th live slot. A
+    step past the live count does nothing: its blocks are the last live
+    step's (the index maps clamp), so nothing is copied in for it and the
+    block that leaves at the end is that step's result."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    n = n_ref[0]
+
+    @pl.when(i < n)
+    def _():
+        slot = ids_ref[i]
+        x = x_ref[0, 0]
+        # unrolled: a head's x is then a STATIC lane of x, which costs a
+        # lane broadcast. In a loop over heads the lane is dynamic, and a
+        # masked lane sum or a dynamic roll to find it made the kernel
+        # compute-bound (12 and 22 us a slot against 6.9: PR 43)
+        for h in range(head_block):
+            head = j * head_block + h
+            at = slot * heads + head
+            g = jax.lax.div(head, jnp.int32(group_heads))
+            xc = x[:, h:h + 1]  # [P, 1]
+            new = (decay_ref[at] * s_ref[0, h]
+                   + (xc * dt_ref[at]) * b_ref[0, pl.ds(g, 1), :])
+            o_ref[0, h] = new
+            y_ref[0, 0, :, h:h + 1] = (
+                jnp.sum(new * c_ref[0, pl.ds(g, 1), :], axis=-1,
+                        keepdims=True) + d_ref[head] * xc)
+
+    # no live slot at all: every step names the same block, and a block
+    # that was visited is written back. Hand it back as it came.
+    @pl.when((n == 0) & (i == 0) & (j == 0))
+    def _():
+        o_ref[...] = s_ref[...]
+
+
+# jitted on its own: the kernel's unrolled body takes Python half a second
+# to trace, and a model's layers in each of an engine's 22 step programs
+# would each pay it (+60 s of warm set-up, measured on the chip at PR 43).
+# As a jit of its own it is traced once a process and lowered once a program.
+@functools.partial(jax.jit, static_argnames=("interpret", "head_block"))
+def update_live(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
+                cm: jax.Array, d: jax.Array, state: jax.Array,
+                live: jax.Array, slots: LiveSlots, *,
+                interpret: bool = False,
+                head_block: int | None = None
+                ) -> tuple[jax.Array, jax.Array]:
+    """`step` over the live slots only, in place: x [B, H, P], dt [B, H]
+    float32, bm / cm [B, G, N], state [B, H, P, N] float32 (row i is slot
+    i), live [B] bool and its `slots` -> (y [B, H, P] float32, exactly 0
+    on a row that is not live; the states, a live slot's after the token
+    and any other untouched). The state operand is the result's memory
+    (`input_output_aliases`): a slot the grid never names is neither read
+    nor written. The arithmetic is `step`'s, product for product."""
+    f32 = jnp.float32
+    b, h, p, n = state.shape
+    g = bm.shape[-2]
+    hb = head_block or _head_block(h, p * n * 4)
+    if h % hb or h % g:
+        raise ValueError(f"{h} heads in blocks of {hb}, {g} groups")
+    nj = h // hb
+    dt = dt.astype(f32)
+    decay = jnp.exp(dt * a.astype(f32))  # [B, H]
+    # heads in the lanes, so that a head's x is a column beside its state
+    xt = x.astype(f32).reshape(b, nj, hb, p).transpose(0, 1, 3, 2)
+
+    def block(i, j, ids, count, *_):
+        """(slot, head block) of grid step (i, j); past the live count,
+        the last live step's."""
+        last = jnp.maximum(count[0] - 1, 0)
+        return ids[jnp.minimum(i, last)], jnp.where(i < count[0], j, nj - 1)
+
+    def by_heads(i, j, *refs):
+        return (*block(i, j, *refs), 0, 0)
+
+    def by_slot(i, j, *refs):
+        return block(i, j, *refs)[0], 0, 0
+
+    small = pl.BlockSpec((1, 1, p, hb), by_heads)
+    rows = pl.BlockSpec((1, g, n), by_slot)
+    big = pl.BlockSpec((1, hb, p, n), by_heads)
+    y, new = pl.pallas_call(
+        functools.partial(_update_kernel, heads=h, head_block=hb,
+                          group_heads=h // g),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(b, nj),
+            in_specs=[small, rows, rows, big], out_specs=[small, big]),
+        out_shape=[jax.ShapeDtypeStruct((b, nj, p, hb), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        input_output_aliases={8: 1},
+        compiler_params=pltpu.CompilerParams(
+            # in order: a step past the live count counts on the blocks of
+            # the step before it
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="ssm_update_live",
+    )(slots.ids, slots.count, dt.reshape(-1), decay.reshape(-1),
+      d.astype(f32), xt, bm.astype(f32), cm.astype(f32), state)
+    # a row the grid never wrote holds whatever the memory held
+    y = jnp.where(live[:, None, None],
+                  y.transpose(0, 1, 3, 2).reshape(b, h, p), 0.0)
+    return y, new
+
+
+def update(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
+           cm: jax.Array, d: jax.Array, state: jax.Array, live: jax.Array,
+           slots: LiveSlots) -> tuple[jax.Array, jax.Array]:
+    """The decode rows' one-token update, row i on slot i where `live`:
+    `update_live` where the scoped backend is a kernel's, else `step` with
+    dt = 0 on the empty slots (which then rewrites every slot)."""
+    backend = update_backend(state.shape)
+    att._note_impl(_OP, backend)
+    if backend == "xla":
+        att._demote(att._resolve_backend(), _OP, "state_tiling",
+                    f"a head's state {state.shape[-2:]} is no multiple of "
+                    "the float32 tile (8, 128)")
+        return step_every_slot(x, dt, a, bm, cm, d, state, live)
+    return update_live(x, dt, a, bm, cm, d, state, live, slots,
+                       interpret=backend == "pallas_interpret")
 
 
 def gate_norm(y: jax.Array, z: jax.Array, w: jax.Array, groups: int,

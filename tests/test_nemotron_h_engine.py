@@ -58,6 +58,17 @@ def engine():
     return Engine(EngineConfig(**CFG))
 
 
+# the state update's kernel (interpret mode: ops/ssm.update_live walks the
+# live slots only and leaves a dead slot's state where it lies); attention
+# takes its XLA path at these head sizes, which is counted and no matter here
+KERNEL = dict(CFG, attention_backend="pallas_interpret")
+
+
+@pytest.fixture(scope="module")
+def kernel_engine():
+    return Engine(EngineConfig(**KERNEL))
+
+
 def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
     """A 70-token prompt (nine chunks: its state rides its slot from step
     to step) beside a 9-token one that arrives while it decodes: the short
@@ -119,11 +130,17 @@ def test_a_prefix_hit_is_counted_inexact_and_served_by_recompute(engine):
     assert eng.prefix_cache.stats()["cached_tokens_served"] == 0
 
 
-def test_a_slot_reused_after_a_finish_or_an_abort_starts_from_zero(engine):
+@pytest.mark.parametrize("which", ["engine", "kernel_engine"])
+def test_a_slot_reused_after_a_finish_or_an_abort_starts_from_zero(request,
+                                                                   which):
     """Slots are handed out last-freed first, so each request here decodes
-    in the slot its predecessor left its state in: after a finish and
-    after an abort the next tenant's tokens are the reference's."""
-    eng = engine
+    in the slot its predecessor left its state in (under the kernel a dead
+    slot keeps its last state until a prompt's first chunk zeroes it):
+    after a finish and after an abort the next tenant's tokens are the
+    reference's. `metrics.ssm.slots_touched` says which path ran: the live
+    rows under the kernel, every slot a step under the XLA twin."""
+    eng = request.getfixturevalue(which)
+    before, steps_before = dict(eng.metrics.ssm), eng.metrics.decode_steps
     eng.add_request(GenRequest("a", prompt(20, 30), max_tokens=10,
                                temperature=0.0, ignore_eos=True))
     drain(eng)
@@ -144,6 +161,52 @@ def test_a_slot_reused_after_a_finish_or_an_abort_starts_from_zero(engine):
             ) == slot_a
     toks = drain(eng)["c"]
     assert toks == reference_greedy(eng, p + toks, 10)
+    grew = {k: v - before[k] for k, v in eng.metrics.ssm.items()}
+    assert grew["decode_rows"] > 0
+    if which == "kernel_engine":
+        assert grew["slots_touched"] == grew["decode_rows"]
+    else:  # every step over the decode batch; prompt rows alone: no update
+        assert grew["slots_touched"] == 4 * (
+            eng.metrics.decode_steps - steps_before)
+
+
+@pytest.fixture(scope="module")
+def seeded_streams():
+    """Three sampled requests with seeds of their own, the first done after
+    5 tokens (its slot is dead for the rest of the run, between and inside
+    the others' windows), through an engine on the state update's kernel
+    path: (num_scheduler_steps, async_scheduling) -> {request: tokens},
+    each engine built once."""
+    done = {}
+
+    def run(steps: int, async_sched: bool) -> dict:
+        key = (steps, async_sched)
+        if key not in done:
+            eng = Engine(EngineConfig(**{
+                **KERNEL, "num_scheduler_steps": steps,
+                "async_scheduling": async_sched}))
+            for i, n_new in enumerate((5, 37, 50)):
+                eng.add_request(GenRequest(
+                    f"r{i}", prompt(30 + i, 11 + 6 * i), max_tokens=n_new,
+                    temperature=1.0, seed=100 + i, ignore_eos=True))
+            done[key] = drain(eng)
+            ssm = eng.metrics.ssm
+            assert ssm["slots_touched"] == ssm["decode_rows"] > 0
+        return done[key]
+    return run
+
+
+@pytest.mark.parametrize("steps,async_sched", [(1, True), (16, False),
+                                               (16, True)])
+def test_seeded_streams_are_the_same_bytes_whatever_the_window_and_schedule(
+        seeded_streams, steps, async_sched):
+    """The 16-step window, the 1-step window and the mixed step's decode
+    rows run ONE arithmetic (the same kernel over the same live list), so a
+    seeded stream is byte-identical across window lengths and async
+    scheduling on / off, with a slot going dead mid-run."""
+    want = seeded_streams(1, False)
+    assert [len(want[f"r{i}"]) for i in range(3)] == [5, 37, 50]
+    assert seeded_streams(steps, async_sched) == want
 
 
 def test_memory_snapshot_counts_slots_beside_pages(engine):

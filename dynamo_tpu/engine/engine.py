@@ -267,6 +267,11 @@ class EngineMetrics:
         self.ssm: Dict[str, int] = {
             "decode_rows": 0, "chunk_tokens": 0, "chunk_calls": 0,
             "layer_steps": 0, "slots_touched": 0}
+        # admission passes (Engine._admit) that left the queue's head
+        # waiting, by the store of a hybrid model that lacked room for it:
+        # no free state slot, too few free pages for its prompt, or both
+        # (then both count). All zero for a model without state slots
+        self.admit_blocked: Dict[str, int] = {"state_slots": 0, "pages": 0}
         # what the sparse-attention indexer of a DeepSeek-V3.2-style model
         # was asked for (all zero for any other model), counted like
         # `attn`: on the host at dispatch, a layer's worth. A query in a
@@ -472,9 +477,10 @@ class EngineMetrics:
             self.phases[n] = PhaseTimer()
 
     def kernel_counters(self) -> Dict[str, dict]:
-        """What the kernels were asked for (attn, attn_kinds, dsa, moe, ssm), as
-        snapshot() gives them and alone: a profiler capture samples these
-        a few times a second (serving/api.py capture_trace)."""
+        """What the kernels were asked for (attn, attn_kinds, dsa, moe, ssm)
+        and what admission lacked (admit_blocked), as snapshot() gives them
+        and alone: a profiler capture samples these a few times a second
+        (serving/api.py capture_trace)."""
         with self._moe_lock:
             done, self._moe_pending = self._moe_pending, []
         self._fold_moe(done)
@@ -482,7 +488,8 @@ class EngineMetrics:
                 "attn_kinds": {k: dict(v)
                                for k, v in self.attn_kinds.items()},
                 "dsa": dict(self.dsa), "moe": dict(self.moe),
-                "ssm": dict(self.ssm)}
+                "ssm": dict(self.ssm),
+                "admit_blocked": dict(self.admit_blocked)}
 
     def snapshot(self) -> Dict[str, float]:
         out = {k: v for k, v in self.__dict__.items()
@@ -491,8 +498,8 @@ class EngineMetrics:
                             "spec_accepted_by", "spec_hist_by",
                             "spec_sum_by", "spec_count_by",
                             "first_token", "_first_token_lock", "moe", "attn",
-                            "attn_kinds", "dsa", "ssm", "_moe_pending",
-                            "_moe_lock")}
+                            "attn_kinds", "dsa", "ssm", "admit_blocked",
+                            "_moe_pending", "_moe_lock")}
         out.update(self.kernel_counters())
         with self._first_token_lock:
             out["first_token"] = dict(self.first_token)
@@ -2256,6 +2263,8 @@ class Engine:
         events.extend(self._qos_evict_batch_for_admission())
         events.extend(self._qos_preempt_for_admission())
         chunk = self.cfg.prefill_chunk_tokens
+        if self.kv_spec.state_layers and not self._free_slots:
+            self._count_admit_blocked()
         while self._free_slots:
             with self._lock:
                 if not self.pending:
@@ -2318,6 +2327,8 @@ class Engine:
                                  reason="no_pages",
                                  need_pages=n_pages - len(cached_pages),
                                  free_pages=self.allocator.free_pages)
+                if self.kv_spec.state_layers:
+                    self.metrics.admit_blocked["pages"] += 1
                 break  # wait for running sequences to release pages
             with self._lock:
                 self._pending_remove(req)
@@ -2362,6 +2373,19 @@ class Engine:
             if got is not None:
                 events.append(self._finalize_admission(req, *got, t0))
         return events
+
+    def _count_admit_blocked(self) -> None:
+        """A hybrid model's admission pass with no free state slot: if a
+        request waits, its head lacked a slot, and pages too where the free
+        ones do not cover its prompt (metrics.admit_blocked)."""
+        with self._lock:
+            if not self.pending:
+                return
+            req = self.pending[self._qos_pick_index()]
+        self.metrics.admit_blocked["state_slots"] += 1
+        if not self.allocator.can_alloc(max(
+                1, -(-len(req.prompt_token_ids) // self.cfg.page_size))):
+            self.metrics.admit_blocked["pages"] += 1
 
     def _widen_group(self, req: GenRequest, chunk: int) -> List[GenRequest]:
         """Pull further pending same-bucket full-prefill requests into one

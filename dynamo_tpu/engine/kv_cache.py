@@ -37,12 +37,17 @@ sequence never holds more than W pages a sliding layer. Both pools ride the
 engine's (k_pages, v_pages) plumbing as one pytree each.
 
 A HYBRID model (`ModelConfig.mixer_types`: state-space, expert and attention
-layers, each layer one mixer) keeps a third thing a sequence owns beside
-pages and rings: a STATE SLOT. Only its attention layers own pages (the pool
-`--num-pages` sizes has `num_layers` = those layers); every Mamba-2 layer
-keeps, for each decode slot, the state S [H, P, N] float32 and the conv's
-last K-1 input rows (`KVCacheSpec.ssm_shape` / `conv_shape`,
-`models/llama.StatePools`). The slot IS the decode slot: the engine
+layers, each layer one mixer; or every layer attention AND a state-space
+mixer side by side) keeps a third thing a sequence owns beside pages and
+rings: a STATE SLOT. The layers that attend own pages (the pool `--num-pages`
+sizes has `num_layers` = those layers: a few of the first form's, ALL of the
+second's); every layer with a Mamba-2 mixer keeps, for each decode slot, the
+state S [H, P, N] float32 and the conv's last K-1 input rows
+(`KVCacheSpec.ssm_shape` / `conv_shape`, `models/llama.StatePools`; one array
+a layer, or where the layers run as one scan ONE array over (layer, slot):
+`state_stacked`). In the second form a sequence holds both in every layer, a
+slot flat and pages by the token, and either store can be the one that
+fills. The slot IS the decode slot: the engine
 reserves it at admission before the first chunk (a chunked prompt's state
 rides there between steps), decode row b updates slot b where it lies, and
 the slot goes back at finish, abort and preemption. A state does not grow
@@ -66,8 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from dynamo_tpu.models.config import (ATTENTION, FULL, MAMBA, SLIDING,
-                                      ModelConfig)
+from dynamo_tpu.models.config import FULL, SLIDING, ModelConfig
 
 
 class OutOfPages(Exception):
@@ -100,14 +104,17 @@ class KVCacheSpec:
     window_layers: int = 0
     window_pages: int = 0
     ring_pages: int = 0
-    # a hybrid model (module docstring): num_layers above counts its
-    # attention layers; each of its state_layers Mamba-2 layers keeps, a
-    # decode slot, one state of ssm_shape (float32) and conv_shape rows
-    # (the model's dtype). 0: no state.
+    # a hybrid model (module docstring): num_layers above counts the
+    # layers that attend; each of its state_layers layers with a Mamba-2
+    # mixer keeps, a decode slot, one state of ssm_shape (float32) and
+    # conv_shape rows (the model's dtype). 0: no state. state_stacked: the
+    # states are ONE array [state_layers, state_slots, ...] (the layers run
+    # as one scan), not an array a layer.
     state_layers: int = 0
     state_slots: int = 0
     ssm_shape: tuple = ()
     conv_shape: tuple = ()
+    state_stacked: bool = False
 
     @staticmethod
     def from_model(
@@ -161,7 +168,8 @@ class KVCacheSpec:
             if state_slots <= 0:
                 raise ValueError("a hybrid model needs state_slots")
             kinds = dict(  # the spec's fields of a hybrid model
-                state_layers=cfg.mixer_layers(MAMBA), state_slots=state_slots,
+                state_layers=cfg.state_layers, state_slots=state_slots,
+                state_stacked=cfg.parallel_mixers,
                 ssm_shape=(cfg.mamba_num_heads, cfg.mamba_head_dim,
                            cfg.ssm_state_size),
                 conv_shape=(cfg.conv_kernel - 1, cfg.mamba_conv_dim))
@@ -173,7 +181,7 @@ class KVCacheSpec:
                 f"({kv_heads}) — the packed-scale rows are blocked "
                 f"per TP shard")
         return KVCacheSpec(
-            num_layers=(cfg.mixer_layers(ATTENTION) if cfg.mixer_types
+            num_layers=(cfg.paged_layers if cfg.mixer_types
                         else cfg.kind_layers(FULL) if kinds
                         else cfg.num_layers),
             **kinds,
@@ -240,9 +248,9 @@ class KVCacheSpec:
         return out
 
     def bytes_per_slot(self) -> int:
-        """Bytes one state slot costs over the Mamba-2 layers (0 without):
-        what a hybrid model's sequence owns beside its pages, whatever its
-        length."""
+        """Bytes one state slot costs over the layers with a Mamba-2 mixer
+        (0 without): what a hybrid model's sequence owns beside its pages,
+        whatever its length."""
         if not self.state_layers:
             return 0
         return self.state_layers * (
@@ -286,8 +294,9 @@ def window_ring_pages(window: int, ahead_tokens: int, page_size: int) -> int:
 def alloc_kv_pages(spec: KVCacheSpec, sharding=None):
     """Allocate zeroed K/V page pools (optionally with a NamedSharding):
     two arrays, with pools by kind two `ByKind` pairs of arrays, and for a
-    hybrid model two `StatePools` (the attention layers' pool and an array
-    a Mamba-2 layer over the state slots)."""
+    hybrid model two `StatePools` (the attending layers' pool and an array
+    a Mamba-2 layer over the state slots, or ONE over (layer, slot) where
+    `state_stacked`)."""
     def put(shape):
         a = jnp.zeros(shape, dtype=jnp.dtype(spec.dtype))
         return a if sharding is None else jax.device_put(a, sharding)
@@ -298,12 +307,15 @@ def alloc_kv_pages(spec: KVCacheSpec, sharding=None):
         def states(shape, dtype):
             # a buffer of its own a layer (each is donated apart),
             # replicated: one chip a replica (from_model refuses the rest)
-            def one():
-                a = jnp.zeros((spec.state_slots,) + tuple(shape), dtype)
+            def one(lead):
+                a = jnp.zeros(lead + tuple(shape), dtype)
                 return a if sharding is None else jax.device_put(
                     a, jax.sharding.NamedSharding(
                         sharding.mesh, jax.sharding.PartitionSpec()))
-            return tuple(one() for _ in range(spec.state_layers))
+            if spec.state_stacked:  # one array over (layer, slot)
+                return (one((spec.state_layers, spec.state_slots)),)
+            return tuple(one((spec.state_slots,))
+                         for _ in range(spec.state_layers))
 
         return (StatePools(put(spec.shape),
                            states(spec.ssm_shape, jnp.float32)),

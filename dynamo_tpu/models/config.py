@@ -13,7 +13,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 
 def _llama3_rope_scaling(cfg: dict):
@@ -110,25 +110,118 @@ ATTENTION_KINDS = (FULL, SLIDING)
 _MAPPED_MODEL_TYPES = frozenset((
     "llama", "mistral", "qwen2", "qwen3", "qwen3_moe", "mixtral", "phi3",
     "gemma", "gemma2", "gemma3", "gemma3_text", "deepseek_v2", "deepseek_v3",
-    "kimi_k2", "deepseek_v32", "nemotron_h"))
+    "kimi_k2", "deepseek_v32", "nemotron_h", "falcon_h1"))
 _MAPPED_ARCH_WORDS = ("Llama", "Mistral", "Qwen", "Mixtral", "Phi3", "Gemma",
                       "Deepseek", "Kimi")
 _KIND_KEYS = ("layer_types", "num_attention_heads_per_layer", "gating")
 
-# the mixer kinds of ModelConfig.mixer_types (a layer is ONE of them), and
-# the letters of `hybrid_override_pattern` that name them
+# the mixer kinds of ModelConfig.mixer_types, and the letters of
+# `hybrid_override_pattern` that name the three a nemotron_h layer is ONE of
 MAMBA, EXPERTS, ATTENTION = "mamba", "moe", "attention"
 MIXER_LETTERS = {"M": MAMBA, "E": EXPERTS, "*": ATTENTION}
+# falcon_h1's layer: attention AND a Mamba-2 mixer side by side on one normed
+# input, summed, then a gated MLP. Every layer of such a model is this one.
+PARALLEL = "attention+mamba"
+MIXER_KINDS = (MAMBA, EXPERTS, ATTENTION, PARALLEL)
+# the kinds whose layers page KV, and those that keep a state slot
+PAGED_MIXERS, STATE_MIXERS = (ATTENTION, PARALLEL), (MAMBA, PARALLEL)
 # two-matrix experts act(u W_up) W_down: the activations written down
 TWO_MATRIX_ACTS = ("relu2", "silu")
 
 
+class Multipliers(NamedTuple):
+    """falcon_h1's fixed multipliers, under the published names less
+    `_multiplier(s)`: `ssm` scales the five runs [z | x | B | C | dt] of the
+    Mamba-2 input projection's output, `mlp` the gate's pre-activation and
+    the down projection's output. Where each applies: models/reference/
+    falcon_h1.py."""
+    embedding: float = 1.0
+    lm_head: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    ssm_in: float = 1.0
+    ssm_out: float = 1.0
+    ssm: Tuple[float, ...] = (1.0,) * 5
+    mlp: Tuple[float, ...] = (1.0, 1.0)
+
+
+def _falcon_h1_from_hf(cfg: dict) -> dict:
+    """The ModelConfig fields of `model_type: falcon_h1` (every layer
+    attention AND Mamba-2 on one normed input, summed, then a gated MLP,
+    under fixed multipliers). Refuses, loudly and by key, what is not
+    served."""
+    n = int(cfg["num_hidden_layers"])
+    for key, served in (("mamba_rms_norm", True),
+                        ("mamba_norm_before_gate", False),
+                        ("mamba_use_mlp", True), ("mamba_conv_bias", True),
+                        ("attention_bias", False), ("projectors_bias", False),
+                        ("mamba_proj_bias", False), ("mlp_bias", False)):
+        if bool(cfg.get(key, served)) != served:
+            raise ValueError(
+                f"{key}={str(cfg[key]).lower()} is not implemented for "
+                f"falcon_h1 ({str(served).lower()} is served)")
+    for key in ("attn_layer_indices", "rope_scaling"):
+        if cfg.get(key) is not None:
+            raise ValueError(
+                f"{key}={cfg[key]!r} is not implemented for falcon_h1 "
+                "(null is served: every layer attends, under a plain "
+                "rotary)")
+    if cfg.get("hidden_act", "silu") != "silu":
+        raise ValueError(f"hidden_act={cfg['hidden_act']!r} is not "
+                         "implemented for falcon_h1 (silu is)")
+    listed = cfg.get("layer_types")
+    if listed is not None and tuple(listed) != (PARALLEL,) * n:
+        raise ValueError(
+            f"layer_types {list(listed)}: every falcon_h1 layer is "
+            f"{PARALLEL!r}")
+    heads, groups = int(cfg["mamba_n_heads"]), int(cfg["mamba_n_groups"])
+    if heads % groups:
+        raise ValueError(
+            f"mamba_n_heads={heads} is no multiple of mamba_n_groups="
+            f"{groups}: a head reads the B / C rows of ONE group")
+    if int(cfg["mamba_d_ssm"]) != heads * int(cfg["mamba_d_head"]):
+        raise ValueError(
+            f"mamba_d_ssm={cfg['mamba_d_ssm']} is not mamba_n_heads x "
+            f"mamba_d_head = {heads * int(cfg['mamba_d_head'])}: the "
+            "mixer's width is served as heads x head size (mamba_expand "
+            "is carried and unused)")
+    ssm, mlp = cfg["ssm_multipliers"], cfg["mlp_multipliers"]
+    if len(ssm) != 5 or len(mlp) != 2:
+        raise ValueError("ssm_multipliers has five entries [z | x | B | C | "
+                         "dt] and mlp_multipliers two [gate | down]")
+    return dict(
+        mixer_types=(PARALLEL,) * n,
+        mamba_num_heads=heads, mamba_head_dim=int(cfg["mamba_d_head"]),
+        mamba_n_groups=groups, ssm_state_size=int(cfg["mamba_d_state"]),
+        conv_kernel=int(cfg["mamba_d_conv"]),
+        ssm_chunk_size=int(cfg.get("mamba_chunk_size") or 128),
+        multipliers=Multipliers(
+            embedding=float(cfg["embedding_multiplier"]),
+            lm_head=float(cfg["lm_head_multiplier"]),
+            attention_in=float(cfg["attention_in_multiplier"]),
+            attention_out=float(cfg["attention_out_multiplier"]),
+            key=float(cfg["key_multiplier"]),
+            ssm_in=float(cfg["ssm_in_multiplier"]),
+            ssm_out=float(cfg["ssm_out_multiplier"]),
+            ssm=tuple(float(m) for m in ssm),
+            mlp=tuple(float(m) for m in mlp)),
+        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        rope_theta=float(cfg.get("rope_theta", 10000.0)),
+        attention_bias=False, sliding_window=0,
+        rope_llama3_scaling=None, rope_yarn_scaling=None,
+        rope_longrope_scaling=None,
+    )
+
+
 def _hybrid_from_hf(cfg: dict) -> dict:
-    """The ModelConfig fields of `model_type: nemotron_h` (every layer ONE
-    mixer by `hybrid_override_pattern`: Mamba-2, experts of two matrices
-    beside a shared one, or GQA attention without a rotary); {} for every
-    other model. Refuses, loudly, what it would otherwise serve as another
-    model."""
+    """The ModelConfig fields of a hybrid model: `model_type: nemotron_h`
+    (every layer ONE mixer by `hybrid_override_pattern`: Mamba-2, experts of
+    two matrices beside a shared one, or GQA attention without a rotary) or
+    `falcon_h1` (_falcon_h1_from_hf); {} for every other model. Refuses,
+    loudly, what it would otherwise serve as another model."""
+    if cfg.get("model_type") == "falcon_h1":
+        return _falcon_h1_from_hf(cfg)
     if cfg.get("model_type") != "nemotron_h":
         return {}
     n = int(cfg["num_hidden_layers"])
@@ -457,14 +550,18 @@ class ModelConfig:
     rope_by_kind: Tuple[tuple, ...] = ()
     attn_gate: str = ""
     shared_expert_intermediate_size: int = 0
-    # a HYBRID model (nemotron_h): one entry a layer, "mamba" | "moe" |
+    # a HYBRID model: one entry a layer. nemotron_h: "mamba" | "moe" |
     # "attention", and every layer is x + mixer(norm(x)) with that ONE
-    # mixer: no attention + FFN pair. Non-empty: the layers run unrolled
-    # over a parameter stack a kind (models/llama.py, "hybrid"), only the
-    # attention layers own KV pages and every Mamba-2 layer owns a state
+    # mixer: no attention + FFN pair; the layers run unrolled over a
+    # parameter stack a kind (models/llama.py, "hybrid") and the attention
+    # layers take NO rotary. falcon_h1: every entry "attention+mamba"
+    # (PARALLEL): attention (with a rotary) and a Mamba-2 mixer on one
+    # normed input, summed, then a gated MLP, under the fixed `multipliers`;
+    # the layers are alike and run as ONE scan. Either way a layer that
+    # attends owns KV pages and a layer with a Mamba-2 mixer owns a state
     # slot a sequence (engine/kv_cache.py): S [mamba_num_heads,
     # mamba_head_dim, ssm_state_size] float32 and the conv's last
-    # conv_kernel - 1 input rows. The attention layers take NO rotary.
+    # conv_kernel - 1 input rows.
     # expert_act: "" = gate / up / down experts (silu(g) * u); "relu2" |
     # "silu" = two matrices an expert, act(u W_up) W_down, the shared one too.
     mixer_types: Tuple[str, ...] = ()
@@ -475,6 +572,7 @@ class ModelConfig:
     conv_kernel: int = 0
     ssm_chunk_size: int = 128
     expert_act: str = ""
+    multipliers: Optional[Multipliers] = None
     # dtype for params/compute (bfloat16 on TPU; float32 for CPU tests)
     dtype: str = "bfloat16"
     eos_token_id: int = 2
@@ -543,9 +641,9 @@ class ModelConfig:
                 "layer_types: no layer would read them")
         if self.mixer_types:
             self._check_mixers()
-        elif self.mamba_num_heads or self.expert_act:
-            raise ValueError("mamba_* / expert_act without mixer_types: no "
-                             "layer would read them")
+        elif self.mamba_num_heads or self.expert_act or self.multipliers:
+            raise ValueError("mamba_* / expert_act / multipliers without "
+                             "mixer_types: no layer would read them")
         held = self.num_local_experts
         if held and not (
                 self.is_moe and 0 <= self.local_expert_offset
@@ -613,10 +711,10 @@ class ModelConfig:
         if len(kinds) != self.num_layers:
             raise ValueError(f"mixer_types has {len(kinds)} entries for "
                              f"{self.num_layers} layers")
-        bad = set(kinds) - set(MIXER_LETTERS.values())
+        bad = set(kinds) - set(MIXER_KINDS)
         if bad:
             raise ValueError(f"unknown mixer_types {sorted(bad)}")
-        if MAMBA in kinds and not (
+        if self.state_layers and not (
                 self.mamba_num_heads > 0 and self.mamba_head_dim > 0
                 and self.mamba_n_groups > 0 and self.ssm_state_size > 0
                 and self.conv_kernel > 1 and self.ssm_chunk_size > 0
@@ -630,6 +728,19 @@ class ModelConfig:
             raise ValueError(
                 "moe mixers need num_experts and a two-matrix expert_act "
                 f"of {TWO_MATRIX_ACTS} (got {self.expert_act!r})")
+        if PARALLEL in kinds:
+            if set(kinds) != {PARALLEL}:
+                raise ValueError(
+                    f"a model with a {PARALLEL!r} layer has no layer of "
+                    "another kind: its layers run as one scan")
+            if (self.is_moe or self.expert_act or self.multipliers is None
+                    or self.hidden_act != "silu"):
+                raise ValueError(
+                    f"{PARALLEL!r} layers are served with a dense gated "
+                    "silu MLP and their `multipliers`, without experts")
+        elif self.multipliers is not None:
+            raise ValueError(f"multipliers without {PARALLEL!r} layers: no "
+                             "layer would read them")
         if (self.layer_types or self.is_mla or self.first_k_dense
                 or self.sliding_window or self.attention_bias or self.qk_norm
                 or self.post_norms or self.attn_logit_softcapping
@@ -637,14 +748,31 @@ class ModelConfig:
                 or self.rms_norm_unit_offset or self.embed_scale
                 or self.tie_word_embeddings or self.moe_capacity_factor):
             raise ValueError(
-                "mixer_types is served with plain GQA attention layers "
-                "without a rotary, every expert held, an untied head, and "
-                "none of: layer_types, MLA, leading dense layers, a window, "
-                "biases, q/k or sandwich norms, score capping, router "
-                "groups, a capacity factor")
+                "mixer_types is served in two forms: every layer ONE mixer "
+                "(Mamba-2 | two-matrix experts, all held | plain GQA "
+                "attention without a rotary), or every layer attention "
+                "under a rotary AND Mamba-2 side by side, then a gated MLP; "
+                "both with an untied head and none of: layer_types, MLA, "
+                "leading dense layers, a window, biases, q/k or sandwich "
+                "norms, score capping, router groups, a capacity factor")
 
     def mixer_layers(self, kind: str) -> int:
         return sum(1 for k in self.mixer_types if k == kind)
+
+    @property
+    def parallel_mixers(self) -> bool:
+        """Every layer attention AND Mamba-2 side by side (falcon_h1)."""
+        return PARALLEL in self.mixer_types
+
+    @property
+    def paged_layers(self) -> int:
+        """Layers of a hybrid model that own KV pages."""
+        return sum(1 for k in self.mixer_types if k in PAGED_MIXERS)
+
+    @property
+    def state_layers(self) -> int:
+        """Layers of a hybrid model that own a state slot a sequence."""
+        return sum(1 for k in self.mixer_types if k in STATE_MIXERS)
 
     @property
     def expert_dims_stored(self) -> Tuple[int, int]:
@@ -1450,4 +1578,22 @@ PRESETS["tiny-nemotron-h-debug"] = ModelConfig(
     mixer_types=tuple(MIXER_LETTERS[c] for c in "MEMEM*EME"),
     mamba_num_heads=4, mamba_head_dim=8, mamba_n_groups=2, ssm_state_size=8,
     conv_kernel=4, ssm_chunk_size=4, expert_act="relu2",
+)
+
+# Falcon-H1's structure at a toy size: three layers, each attention (4 query
+# heads over 2 KV heads of 16 lanes, a rotary) AND Mamba-2 (4 heads of 8
+# lanes in 2 groups, state 8, conv 4, a scan chunk of 4) on one normed
+# input, then a gated MLP; every multiplier set, none 1, no two alike
+PRESETS["tiny-falcon-h1-debug"] = ModelConfig(
+    name="tiny-falcon-h1-debug",
+    hidden_size=64, intermediate_size=128, num_layers=3, num_heads=4,
+    num_kv_heads=2, head_dim=16, tie_word_embeddings=False,
+    rope_theta=1e11,
+    mixer_types=(PARALLEL,) * 3,
+    mamba_num_heads=4, mamba_head_dim=8, mamba_n_groups=2, ssm_state_size=8,
+    conv_kernel=4, ssm_chunk_size=4,
+    multipliers=Multipliers(
+        embedding=2.5, lm_head=0.6, attention_in=1.3, attention_out=0.7,
+        key=0.45, ssm_in=0.8, ssm_out=0.9,
+        ssm=(0.55, 1.2, 0.65, 1.4, 0.85), mlp=(0.75, 1.1)),
 )

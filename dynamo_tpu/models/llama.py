@@ -58,6 +58,9 @@ def _embed_rows(cfg: ModelConfig, params: Params, tokens: jax.Array) -> jax.Arra
     if cfg.embed_scale:
         # Gemma normalizer: embeddings scale by sqrt(E) (fp32, cast back)
         x = (x.astype(jnp.float32) * (cfg.hidden_size ** 0.5)).astype(x.dtype)
+    if cfg.multipliers is not None:  # falcon_h1's embedding_multiplier
+        x = (x.astype(jnp.float32)
+             * cfg.multipliers.embedding).astype(x.dtype)
     return x
 
 
@@ -1015,14 +1018,17 @@ def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
 
 
 # ------------------------------------------------------------------ hybrid --
-# A HYBRID model (cfg.mixer_types, nemotron_h): every layer is
-# x + mixer(norm(x)) with ONE mixer of a static kind (Mamba-2 | experts |
-# attention). The layers run unrolled (the published pattern has no period),
-# each kind over its own parameter stack; only the attention layers own KV
-# pages, and every Mamba-2 layer owns a STATE SLOT a decode slot: a
-# sequence's state lives in the slot the engine reserved for it at admission
-# (a chunked prompt's state rides there from chunk to chunk) and decode row
-# b updates slot b where it lies.
+# A HYBRID model (cfg.mixer_types), in one of two forms. nemotron_h: every
+# layer is x + mixer(norm(x)) with ONE mixer of a static kind (Mamba-2 |
+# experts | attention); the layers run unrolled (the published pattern has
+# no period), each kind over its own parameter stack. falcon_h1
+# (cfg.parallel_mixers): every layer is attention AND Mamba-2 on one normed
+# input, summed, then a gated MLP; the layers are alike and run as ONE scan
+# (_parallel_layers). Either way a layer that attends owns KV pages, and a
+# layer with a Mamba-2 mixer owns a STATE SLOT a decode slot: a sequence's
+# state lives in the slot the engine reserved for it at admission (a chunked
+# prompt's state rides there from chunk to chunk) and decode row b updates
+# slot b where it lies.
 
 
 class StatePools(NamedTuple):
@@ -1031,7 +1037,11 @@ class StatePools(NamedTuple):
     attention layers' paged pool [attention layers, pages, page_size,
     KV*D], and one array a Mamba-2 layer over the decode slots: in
     `k_pages` the state S [slots, H, P, N] float32, in `v_pages` the conv's
-    last K-1 input rows [slots, K-1, C]."""
+    last K-1 input rows [slots, K-1, C]. Where the layers run as one scan
+    (cfg.parallel_mixers) `state` is ONE array over (layer, slot), [L,
+    slots, ...], addressed flat as the pages are (row l * slots + slot): a
+    tuple of arrays cannot ride a scan, and a scanned stack would be copied
+    whole (64 x 4.2 MB a layer a step)."""
     pages: Any
     state: Any
 
@@ -1087,8 +1097,14 @@ def _hybrid_param_specs(cfg: ModelConfig):
         "embed": w((cfg.vocab_size, e), 0.02),
         "final_norm": ((e,), "ones", 0.0),
         "lm_head": w((e, cfg.vocab_size), 0.02),
-        "mixer_norm": ((cfg.num_layers, e), "ones", 0.0),
     }
+    if cfg.parallel_mixers:
+        # every leaf over ALL layers: the norm before the two mixers, both
+        # mixers' leaves, the norm before the MLP, the gated MLP
+        lm = la = cfg.num_layers
+        p["attn_norm"] = ((lm, e), "ones", 0.0)
+    else:
+        p["mixer_norm"] = ((cfg.num_layers, e), "ones", 0.0)
     if lm:
         hm, d_in, c = (cfg.mamba_num_heads, cfg.mamba_d_inner,
                        cfg.mamba_conv_dim)
@@ -1119,6 +1135,12 @@ def _hybrid_param_specs(cfg: ModelConfig):
         if fs:
             p["w_up"] = w((le, e, fs), 1.0 / e ** 0.5)
             p["w_down"] = w((le, fs, e), 1.0 / fs ** 0.5)
+    if cfg.parallel_mixers:
+        l = cfg.num_layers
+        p["mlp_norm"] = ((l, e), "ones", 0.0)
+        p["w_gate"] = w((l, e, f), 1.0 / e ** 0.5)
+        p["w_up"] = w((l, e, f), 1.0 / e ** 0.5)
+        p["w_down"] = w((l, f, e), 1.0 / f ** 0.5)
     return p
 
 
@@ -1150,9 +1172,24 @@ def _zero_past(a, cuts):
     return cut(a)
 
 
+def _mup_vector(cfg: ModelConfig) -> jax.Array:
+    """falcon_h1's ssm_in_multiplier and ssm_multipliers as ONE vector over
+    the input projection's output lanes [z | x | B | C | dt] (the
+    projection is linear: scaling its input scales its output)."""
+    m = cfg.multipliers
+    gn = cfg.mamba_n_groups * cfg.ssm_state_size
+    widths = (cfg.mamba_d_inner, cfg.mamba_d_inner, gn, gn,
+              cfg.mamba_num_heads)
+    return jnp.concatenate([jnp.full((w,), m.ssm_in * v, jnp.float32)
+                            for w, v in zip(widths, m.ssm)])
+
+
 def _mamba_mixer(cfg: ModelConfig, lp: Params, u: jax.Array, ssm, conv,
-                 decode=None, chunk=None):
+                 decode=None, chunk=None, base=None):
     """The Mamba-2 mixer over u [T, E] (normed) -> (y [T, E], ssm, conv).
+
+    `base` (a traced scalar, or None): ssm and conv are pools over (layer,
+    slot) addressed flat, and this layer's slot i is row base + i.
 
     The rows are `decode` = (b, live [b] bool, its ssm_ops.LiveSlots): b
     rows, one token a decode slot, row i updating slot i where live; then
@@ -1168,6 +1205,9 @@ def _mamba_mixer(cfg: ModelConfig, lp: Params, u: jax.Array, ssm, conv,
     d_in, c_dim = cfg.mamba_d_inner, cfg.mamba_conv_dim
     with jax.named_scope("ssm_in_proj"):
         zxbcdt = qeinsum("te,ef->tf", u, lp["ssm_in"])
+        if cfg.multipliers is not None:
+            zxbcdt = (zxbcdt.astype(jnp.float32)
+                      * _mup_vector(cfg)).astype(zxbcdt.dtype)
     z, xbc = zxbcdt[:, :d_in], zxbcdt[:, d_in:d_in + c_dim]
     dt = jax.nn.softplus(zxbcdt[:, d_in + c_dim:].astype(jnp.float32)
                          + lp["ssm_dt_bias"].astype(jnp.float32))
@@ -1183,16 +1223,24 @@ def _mamba_mixer(cfg: ModelConfig, lp: Params, u: jax.Array, ssm, conv,
     if decode is not None:
         b, live, slots = decode
         with jax.named_scope("ssm_conv"):
-            xo, conv = ssm_ops.conv_step(
-                xbc[:b], conv, lp["ssm_conv_w"], lp["ssm_conv_b"], live)
+            if base is None:
+                xo, conv = ssm_ops.conv_step(
+                    xbc[:b], conv, lp["ssm_conv_w"], lp["ssm_conv_b"], live)
+            else:
+                xo, own = ssm_ops.conv_step(
+                    xbc[:b], jax.lax.dynamic_slice_in_dim(conv, base, b),
+                    lp["ssm_conv_w"], lp["ssm_conv_b"], live)
+                conv = jax.lax.dynamic_update_slice_in_dim(conv, own, base, 0)
         with jax.named_scope("ssm_scan"):
             x, bm, cm = parts(xo)
             y, ssm = ssm_ops.update(x, dt[:b], a, bm, cm, lp["ssm_d"], ssm,
-                                    live, slots)
+                                    live, slots, base=base)
         ys.append(y.reshape(b, d_in))
         off = b
     if chunk is not None:
         c, slot, n_valid, fresh = chunk
+        if base is not None:
+            slot = slot + base
         rows = slice(off, off + c)
         with jax.named_scope("ssm_conv"):
             prev = jnp.where(fresh, jnp.zeros_like(conv[slot]), conv[slot])
@@ -1216,14 +1264,75 @@ def _mamba_mixer(cfg: ModelConfig, lp: Params, u: jax.Array, ssm, conv,
         return qeinsum("tf,fe->te", y, lp["ssm_out"]), ssm, conv
 
 
+def _parallel_layers(cfg: ModelConfig, params: Params, x: jax.Array,
+                     k_pages: StatePools, v_pages: StatePools, attend,
+                     positions, decode=None, chunk=None):
+    """_hybrid_layers for a model whose every layer is attention AND
+    Mamba-2 on one normed input, summed, then a gated MLP (falcon_h1): ONE
+    scan over the layers. The pages and the states ride the carry FLAT
+    (_scan_layers_paged says why): layer l's page p is row l * P + p of the
+    pool and its slot b row l * B + b of the states, so an iteration
+    touches the rows it writes and the pages and live slots it reads, and
+    no layer's pool or states are sliced out or stacked back. The fixed
+    multipliers apply where the tensors are narrowest (a projection is
+    linear): tests/test_falcon_h1.py holds this placement to the
+    reference's, which applies each where the published description does."""
+    m = cfg.multipliers
+    pool, vpool = k_pages.pages.shape, v_pages.pages.shape
+    (ssm,), (conv,) = k_pages.state, v_pages.state
+    slots = ssm.shape[1]
+    carry = (x, k_pages.pages.reshape((-1,) + pool[2:]),
+             v_pages.pages.reshape((-1,) + vpool[2:]),
+             ssm.reshape((-1,) + ssm.shape[2:]),
+             conv.reshape((-1,) + conv.shape[2:]))
+
+    def layer(carry, xs):
+        x, kp, vp, sp, cp = carry
+        lp, idx = xs
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        with jax.named_scope("mixer_attn"):
+            q, k, v = _qkv(cfg, lp, h, positions)
+            # attention_in on q, k, v (their projections are linear);
+            # key_multiplier on k: a rotary is linear too
+            if m.attention_in != 1.0:
+                q, v = q * m.attention_in, v * m.attention_in
+            k = k * (m.attention_in * m.key)
+            with jax.named_scope("attn_full"):
+                o, kp, vp = attend(q, k, v, kp, vp, idx * pool[1])
+            ya = _attn_out(cfg, lp, o) * m.attention_out
+        with jax.named_scope("mixer_ssm"):
+            ys, sp, cp = _mamba_mixer(cfg, lp, h, sp, cp, decode, chunk,
+                                      base=idx * slots)
+            ys = ys * m.ssm_out
+        with jax.named_scope("mixer_sum"):
+            x = x + ya + ys
+        with jax.named_scope("mlp_dense"):
+            h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
+            g = qeinsum("te,ef->tf", h, lp["w_gate"])
+            u = qeinsum("te,ef->tf", h, lp["w_up"])
+            y = qeinsum("tf,fe->te", jax.nn.silu(g * m.mlp[0]) * u,
+                        lp["w_down"])
+            x = x + y * m.mlp[1]
+        return (x, kp, vp, sp, cp), None
+
+    (x, kp, vp, sp, cp), _ = jax.lax.scan(
+        layer, carry, (_layer_params(params), jnp.arange(cfg.num_layers)))
+    return (x, StatePools(kp.reshape(pool), (sp.reshape(ssm.shape),)),
+            StatePools(vp.reshape(vpool), (cp.reshape(conv.shape),)), None)
+
+
 def _hybrid_layers(cfg: ModelConfig, params: Params, x: jax.Array,
                    k_pages: StatePools, v_pages: StatePools, attend,
-                   token_mask, decode=None, chunk=None):
+                   token_mask, decode=None, chunk=None, positions=None):
     """Every layer of a hybrid model over x [T, E]: x + mixer(norm(x)).
     `attend(q, k, v, kp, vp, page_off)` -> (o, kp, vp) writes the rows'
     K / V into the flat pool and attends; `decode` / `chunk` are
     _mamba_mixer's. Returns (x, k_pages, v_pages, the expert layers'
-    counts or None)."""
+    counts or None). `positions` [T]: the rows' positions, for the
+    rotary of a model whose layers run both mixers (None elsewhere)."""
+    if cfg.parallel_mixers:
+        return _parallel_layers(cfg, params, x, k_pages, v_pages, attend,
+                                positions, decode, chunk)
     pool = k_pages.pages.shape
     kp = k_pages.pages.reshape((-1,) + pool[2:])
     vp = v_pages.pages.reshape((-1,) + pool[2:])
@@ -1286,9 +1395,13 @@ def _hybrid_prefill(cfg, params, tokens, n_valid, k_pages, v_pages,
         return o, kp, vp
 
     fresh = jnp.bool_(True) if start is None else start == 0
+    # the rows' positions, for the rotary of a layer that runs both mixers
+    positions = ((0 if start is None else start) + jnp.arange(c)
+                 if cfg.parallel_mixers else None)
     x, k_pages, v_pages, counts = _hybrid_layers(
         cfg, params, _embed_rows(cfg, params, tokens), k_pages, v_pages,
-        attend, token_mask, chunk=(c, pages.slot, n_valid, fresh))
+        attend, token_mask, chunk=(c, pages.slot, n_valid, fresh),
+        positions=positions)
     last = jnp.take(x, n_valid - 1, axis=0)[None]
     return PrefillOut(_logits(cfg, params, last)[0], k_pages, v_pages, counts)
 
@@ -1311,9 +1424,10 @@ def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
     rows [B decode | C chunk]. Returns (x [B(+C), E] after the last layer,
     k_pages, v_pages, counts)."""
     b = tokens.shape[0]
-    if b != k_pages.state[0].shape[0]:
+    n_slots = k_pages.state[0].shape[1 if cfg.parallel_mixers else 0]
+    if b != n_slots:
         raise ValueError(
-            f"{b} decode rows over {k_pages.state[0].shape[0]} state slots: "
+            f"{b} decode rows over {n_slots} state slots: "
             "decode row i updates state slot i")
     live = _live_slots(block_tables)
     if state_slots is None:
@@ -1321,9 +1435,13 @@ def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
     kernel_lens = jnp.where(live, context_lens, 0)
     tables = block_tables
     all_tokens, token_mask, mchunk = tokens, live, None
+    # every row's position, for the rotary of a layer that runs both mixers
+    rope_pos = positions if cfg.parallel_mixers else None
     if chunk is not None:
         c_tokens, start, n_valid, pages = chunk
         c = c_tokens.shape[0]
+        if rope_pos is not None:
+            rope_pos = jnp.concatenate([positions, start + jnp.arange(c)])
         all_tokens = jnp.concatenate([tokens, c_tokens])
         token_mask = jnp.concatenate([live, jnp.arange(c) < n_valid])
         mchunk = (c, pages.slot, n_valid, start == 0)
@@ -1348,16 +1466,18 @@ def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
 
     return _hybrid_layers(
         cfg, params, _embed_rows(cfg, params, all_tokens), k_pages, v_pages,
-        attend, token_mask, decode=(b, live, state_slots), chunk=mchunk)
+        attend, token_mask, decode=(b, live, state_slots), chunk=mchunk,
+        positions=rope_pos)
 
 
 def _no_hybrid(cfg: ModelConfig, what: str) -> None:
     if cfg.mixer_types:
         raise NotImplementedError(
             f"{what} is not implemented for a hybrid model "
-            "(cfg.mixer_types): a state slot holds ONE sequence's state at "
-            "ONE position, so lanes of one prompt batch and a verify "
-            "window that may roll back have nowhere to keep theirs")
+            "(cfg.mixer_types: a Mamba-2 mixer in some or in every layer): "
+            "a state slot holds ONE sequence's state at ONE position, so "
+            "lanes of one prompt batch and a verify window that may roll "
+            "back have nowhere to keep theirs")
 
 
 class PrefillOut(NamedTuple):
@@ -1369,6 +1489,10 @@ class PrefillOut(NamedTuple):
 
 def _logits(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
+    if cfg.multipliers is not None:
+        # falcon_h1's lm_head_multiplier, on the [T, E] rows and not on
+        # [T, V] logits (the head is linear; the published 2^-7 is exact)
+        x = x * cfg.multipliers.lm_head
     if cfg.tie_word_embeddings:
         out = quant.tied_head_einsum(x, params["embed"])
     else:

@@ -164,7 +164,13 @@ def live_slots(live: jax.Array) -> LiveSlots:
 # [64, 64, 128] state whole. Measured alone on a v5e at 27 of 64 slots live
 # (scripts/ssm_microbench.py, PR 43): 0.208 ms a layer in blocks of 64
 # heads, 0.225 of 32, 0.253 of 16 (XLA over every slot: 0.424); a grid step
-# costs 0.35 us, a skipped one 0.13.
+# costs 0.35 us, a skipped one 0.13. At a head's state of [128, 256] (128
+# KB: 32 heads, 4.2 MB a slot; PR 44, SSM_MICROBENCH_SHAPE=64,32,128,2,256)
+# the 2 MB block is 16 heads, two grid steps a live slot: 0.468 ms a layer
+# at 32 of 64 live (574 GB/s of live bytes), 0.871 at 64 (617); blocks of 8
+# heads 0.489 / 0.888; XLA over every slot 0.84-0.86 whatever is live; over
+# a pool of layers' states under a `base` the same within 1%. A block of 32
+# heads (4 MB, twice, in and out) does not fit the kernel's VMEM.
 _STATE_BLOCK_BYTES = 2 << 20
 _OP = "ssm state update"
 
@@ -185,6 +191,14 @@ def update_backend(state_shape) -> str:
     if backend == "pallas" and (state_shape[-1] % 128 or state_shape[-2] % 8):
         return "xla"  # a head's state is no multiple of the float32 tile
     return backend if backend in att._KERNEL_BACKENDS else "xla"
+
+
+def _update_kernel_based(ids_ref, n_ref, dt_ref, decay_ref, d_ref, base_ref,
+                         *refs, **kw):
+    """`_update_kernel` under a sixth prefetched scalar, the states' `base`
+    row: only the index maps read it."""
+    del base_ref
+    _update_kernel(ids_ref, n_ref, dt_ref, decay_ref, d_ref, *refs, **kw)
 
 
 def _update_kernel(ids_ref, n_ref, dt_ref, decay_ref, d_ref,  # SMEM
@@ -237,6 +251,7 @@ def _update_kernel(ids_ref, n_ref, dt_ref, decay_ref, d_ref,  # SMEM
 def update_live(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
                 cm: jax.Array, d: jax.Array, state: jax.Array,
                 live: jax.Array, slots: LiveSlots, *,
+                base: jax.Array | None = None,
                 interpret: bool = False,
                 head_block: int | None = None
                 ) -> tuple[jax.Array, jax.Array]:
@@ -246,9 +261,16 @@ def update_live(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
     on a row that is not live; the states, a live slot's after the token
     and any other untouched). The state operand is the result's memory
     (`input_output_aliases`): a slot the grid never names is neither read
-    nor written. The arithmetic is `step`'s, product for product."""
+    nor written. The arithmetic is `step`'s, product for product.
+
+    `base` (a scalar int32, or None): the states are a POOL [L * B, H, P,
+    N] of which slot i is row base + i (a model whose layers run as one
+    scan carries every layer's states as one array and hands the layer's
+    offset: models/llama.py); x, dt, bm, cm and y stay indexed by the slot
+    alone."""
     f32 = jnp.float32
-    b, h, p, n = state.shape
+    _, h, p, n = state.shape
+    b = x.shape[0]
     g = bm.shape[-2]
     hb = head_block or _head_block(h, p * n * 4)
     if h % hb or h % g:
@@ -271,18 +293,23 @@ def update_live(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
     def by_slot(i, j, *refs):
         return block(i, j, *refs)[0], 0, 0
 
+    def pooled(i, j, *refs):
+        slot, jb = block(i, j, *refs)
+        return slot + refs[5][0], jb, 0, 0
+
+    based = () if base is None else (jnp.asarray(base, jnp.int32)[None],)
     small = pl.BlockSpec((1, 1, p, hb), by_heads)
     rows = pl.BlockSpec((1, g, n), by_slot)
-    big = pl.BlockSpec((1, hb, p, n), by_heads)
+    big = pl.BlockSpec((1, hb, p, n), pooled if based else by_heads)
     y, new = pl.pallas_call(
-        functools.partial(_update_kernel, heads=h, head_block=hb,
-                          group_heads=h // g),
+        functools.partial(_update_kernel_based if based else _update_kernel,
+                          heads=h, head_block=hb, group_heads=h // g),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5, grid=(b, nj),
+            num_scalar_prefetch=5 + len(based), grid=(b, nj),
             in_specs=[small, rows, rows, big], out_specs=[small, big]),
         out_shape=[jax.ShapeDtypeStruct((b, nj, p, hb), f32),
                    jax.ShapeDtypeStruct(state.shape, f32)],
-        input_output_aliases={8: 1},
+        input_output_aliases={8 + len(based): 1},
         compiler_params=pltpu.CompilerParams(
             # in order: a step past the live count counts on the blocks of
             # the step before it
@@ -290,7 +317,7 @@ def update_live(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
         interpret=interpret,
         name="ssm_update_live",
     )(slots.ids, slots.count, dt.reshape(-1), decay.reshape(-1),
-      d.astype(f32), xt, bm.astype(f32), cm.astype(f32), state)
+      d.astype(f32), *based, xt, bm.astype(f32), cm.astype(f32), state)
     # a row the grid never wrote holds whatever the memory held
     y = jnp.where(live[:, None, None],
                   y.transpose(0, 1, 3, 2).reshape(b, h, p), 0.0)
@@ -299,18 +326,23 @@ def update_live(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
 
 def update(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
            cm: jax.Array, d: jax.Array, state: jax.Array, live: jax.Array,
-           slots: LiveSlots) -> tuple[jax.Array, jax.Array]:
+           slots: LiveSlots, base=None) -> tuple[jax.Array, jax.Array]:
     """The decode rows' one-token update, row i on slot i where `live`:
     `update_live` where the scoped backend is a kernel's, else `step` with
-    dt = 0 on the empty slots (which then rewrites every slot)."""
+    dt = 0 on the empty slots (which then rewrites every slot). `base`:
+    `update_live`'s, the rows' slots are state[base : base + B]."""
     backend = update_backend(state.shape)
     att._note_impl(_OP, backend)
     if backend == "xla":
         att._demote(att._resolve_backend(), _OP, "state_tiling",
                     f"a head's state {state.shape[-2:]} is no multiple of "
                     "the float32 tile (8, 128)")
-        return step_every_slot(x, dt, a, bm, cm, d, state, live)
-    return update_live(x, dt, a, bm, cm, d, state, live, slots,
+        if base is None:
+            return step_every_slot(x, dt, a, bm, cm, d, state, live)
+        own = jax.lax.dynamic_slice_in_dim(state, base, x.shape[0])
+        y, own = step_every_slot(x, dt, a, bm, cm, d, own, live)
+        return y, jax.lax.dynamic_update_slice_in_dim(state, own, base, 0)
+    return update_live(x, dt, a, bm, cm, d, state, live, slots, base=base,
                        interpret=backend == "pallas_interpret")
 
 

@@ -424,6 +424,31 @@ def test_prefill_kernel_compiles_for_v5e_at_nine_heads_a_kv_head(one_chip):
         assert "tpu_custom_call" in text
 
 
+def _hybrid_window(cfg):
+    """A hybrid model's 16-step decode window as the engine runs it: the
+    step in a `lax.scan`, the pools and states its carry, the live slots'
+    list built once for all its steps."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.models import llama
+
+    def window(params, tokens, positions, tables, lens, kp, vp):
+        slots = llama.live_state_slots(cfg, tables)
+
+        def body(carry, _):
+            toks, pos, ctx, kp, vp = carry
+            out = llama.decode_step(
+                cfg, params, toks, pos, tables, ctx, kp, vp,
+                page_size=PAGE, state_slots=slots)
+            nxt = jnp.argmax(out.logits, axis=-1).astype(jnp.int32)
+            return (nxt, pos + 1, ctx + 1, out.k_pages, out.v_pages), nxt
+
+        return jax.lax.scan(body, (tokens, positions, lens, kp, vp), None,
+                            length=16)
+    return window
+
+
 # NVIDIA-Nemotron-3-Nano's cut (PR 42): 64 slots = 64 state slots, a decode
 # table of 6,144 tokens, the one chunked-prompt table width the engine keeps
 NEMOTRON_SLOTS, NEMOTRON_TABLE, NEMOTRON_CHUNK_TABLE = 64, 384, 399
@@ -496,22 +521,8 @@ def test_nemotron_step_compiles_for_v5e_under_its_scope_names(one_chip,
                 params, i32(b), i32(b), i32(b, NEMOTRON_TABLE), i32(b), kp,
                 vp).compile()
         elif program == "window":
-            def window(params, tokens, positions, tables, lens, kp, vp):
-                slots = llama.live_state_slots(cfg, tables)
-
-                def body(carry, _):
-                    toks, pos, ctx, kp, vp = carry
-                    out = llama.decode_step(
-                        cfg, params, toks, pos, tables, ctx, kp, vp,
-                        page_size=PAGE, state_slots=slots)
-                    nxt = jnp.argmax(out.logits, axis=-1).astype(jnp.int32)
-                    return (nxt, pos + 1, ctx + 1, out.k_pages,
-                            out.v_pages), nxt
-
-                return jax.lax.scan(body, (tokens, positions, lens, kp, vp),
-                                    None, length=16)
-
-            compiled = jax.jit(window, donate_argnums=(5, 6)).lower(
+            compiled = jax.jit(_hybrid_window(cfg),
+                               donate_argnums=(5, 6)).lower(
                 params, i32(b), i32(b), i32(b, NEMOTRON_TABLE), i32(b), kp,
                 vp).compile()
         else:
@@ -541,3 +552,146 @@ def test_nemotron_step_compiles_for_v5e_under_its_scope_names(one_chip,
         assert "f32[64,64,64,128]" in result
         assert "f32[64,64,64,128]" in operands.split("metadata=")[0]
         assert "output_to_operand_aliasing={{1}: (8, {})}" in line
+
+
+# Falcon-H1-34B's cut (PR 44): 64 slots = 64 state slots in EVERY layer, a
+# decode table of 6,144 tokens, the one chunked-prompt table width
+FALCON_SLOTS, FALCON_TABLE, FALCON_CHUNK_TABLE = 64, 384, 399
+FALCON_PAGES, FALCON_LAYERS = 6144, 10
+FALCON_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
+                 "ssm_out_proj", "attn_full", "mixer_attn", "mixer_ssm",
+                 "mixer_sum", "mlp_dense")
+
+
+def _falcon_cut(one_chip):
+    """(cfg, abstract w8a8 params, k_pages, v_pages) of the cut at the
+    cell's sizes, for a described v5e."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.engine.kv_cache import KVCacheSpec
+    from dynamo_tpu.models import llama, quant
+    from dynamo_tpu.models.config import ModelConfig
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    cfg = ModelConfig.from_model_name(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks/chip/configs/falcon-h1-34b-w8a8-1chip"))
+    assert cfg.num_layers == FALCON_LAYERS
+    b = FALCON_SLOTS
+    spec = KVCacheSpec.from_model(cfg, FALCON_PAGES, PAGE, state_slots=b)
+    params = {}
+    for name, (shape, kind, _) in llama.param_specs(cfg).items():
+        axes = quant.quant_axes(name)
+        if axes and kind == "normal":
+            params[name] = quant.QTensorA8(
+                arg(shape, jnp.int8),
+                arg([1 if i in axes else s for i, s in enumerate(shape)],
+                    jnp.float32))
+        else:
+            params[name] = arg(shape, jnp.float32 if kind in llama.SSM_INITS
+                               else jnp.bfloat16)
+    lead = (spec.state_layers, b)
+    kp = llama.StatePools(arg(spec.shape, jnp.bfloat16),
+                          (arg(lead + spec.ssm_shape, jnp.float32),))
+    vp = llama.StatePools(arg(spec.v_shape, jnp.bfloat16),
+                          (arg(lead + spec.conv_shape, jnp.bfloat16),))
+    return cfg, params, kp, vp, arg
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed", "window", "prefill"])
+def test_falcon_h1_step_compiles_for_v5e_under_its_scope_names(one_chip,
+                                                               program):
+    """The cut model's decode step, mixed step, a 16-step window and a
+    whole-prompt prefill at the cell's sizes (w8a8, 64 slots, their states
+    in ALL ten layers as one pool f32[640,32,128,256], a 256-token chunk),
+    for a described v5e: the layers are ONE scan; no op leaves the kernels
+    (5 query heads a KV head, a rotary; the state update over the live
+    slots at a head's 128 x 256 state: no counted fallback, no
+    `state_tiling` demotion); every span the benchmark reads and the three
+    branch scopes are named in the HLO; the update is ONE custom call (the
+    scan's body) named `ssm_update_live` under `ssm_scan`, the whole pool
+    its operand and its result; the state pool is never copied, nor the
+    stacks of the MLP (3.3 GB), W_q, W_o and W_out. What IS copied, read
+    at PR 44: the conv rows' pool bf16[10,64,3,5120] (19.7 MB) into the
+    layer scan's layout and back, once a program; and in the 16-step
+    window alone (a scan in a scan), once a window ahead of its steps,
+    W_in's stack s8[10,5120,9248] (473 MB: the TPU lays an int8 array
+    whose minor extent is no multiple of 128 out transposed, and the
+    nested scan slices it row-major) and W_k / W_v (26 MB each): 1.2 ms a
+    window, 0.07 ms a token, and 0.5 GB beside the arguments (PERF.md
+    section 7). Beside its arguments (11.70 GB) a program holds under 0.05
+    GB (decode, mixed), 0.6 (window), 0.9 (a whole prompt's float
+    matmuls)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.ops import attention as att
+
+    cfg, params, kp, vp, arg = _falcon_cut(one_chip)
+    b = FALCON_SLOTS
+
+    def i32(*shape):
+        return arg(shape, jnp.int32)
+
+    before = dict(att.pallas_fallback_counts())
+    with att.attention_context("pallas", None, 1):
+        if program == "decode":
+            compiled = jax.jit(functools.partial(
+                llama.decode_step, cfg, page_size=PAGE),
+                donate_argnums=(5, 6)).lower(
+                params, i32(b), i32(b), i32(b, FALCON_TABLE), i32(b), kp,
+                vp).compile()
+        elif program == "window":
+            compiled = jax.jit(_hybrid_window(cfg),
+                               donate_argnums=(5, 6)).lower(
+                params, i32(b), i32(b), i32(b, FALCON_TABLE), i32(b), kp,
+                vp).compile()
+        elif program == "mixed":
+            compiled = jax.jit(functools.partial(
+                llama.mixed_step, cfg, page_size=PAGE),
+                donate_argnums=(9, 10)).lower(
+                params, i32(b), i32(b), i32(b, FALCON_TABLE), i32(b),
+                i32(CHUNK), i32(), i32(),
+                llama.SlotPages(i32(FALCON_CHUNK_TABLE), i32()), kp,
+                vp).compile()
+        else:
+            compiled = jax.jit(functools.partial(
+                llama.prefill, cfg, page_size=PAGE),
+                donate_argnums=(3, 4)).lower(
+                params, i32(128), i32(), kp, vp,
+                llama.SlotPages(i32(8), i32())).compile()
+    assert dict(att.pallas_fallback_counts()) == before
+    text = compiled.as_text()
+    for scope in FALCON_SCOPES:
+        assert scope in text, scope
+    pool = "f32[640,32,128,256]"
+    assert not re.search(r"f32\[(640|10,64),32,128,256\]\S* copy\(", text)
+    assert not re.search(
+        r"s8\[10,(5120,21504|21504,5120|5120,20,128|20,128,5120|4096,5120)"
+        r"\]\S* copy\(", text)
+    if program != "window":
+        assert not re.search(r"s8\[10,\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    assert 11.6e9 < mem.argument_size_in_bytes < 11.8e9
+    assert mem.temp_size_in_bytes < {
+        "window": 0.6e9, "prefill": 0.9e9}.get(program, 0.05e9)
+    if program == "prefill":
+        return  # a prompt alone runs the chunked scan, not the update
+    updates = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and "ssm_update_live" in line]
+    assert len(updates) == 1  # the layer scan's body
+    (line,) = updates
+    assert "ssm_scan" in line and "mixer_ssm" in line
+    result, operands = line.split(" custom-call(", 1)
+    assert pool in result
+    assert pool in operands.split("metadata=")[0]
+    assert "output_to_operand_aliasing={{1}: (9, {})}" in line
+    attn = [l for l in text.splitlines()
+            if "tpu_custom_call" in l and "attn_full" in l]
+    assert attn and all("mixer_attn" in l for l in attn)

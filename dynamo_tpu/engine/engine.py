@@ -3056,8 +3056,8 @@ class Engine:
             # 2,048). Each program holds a period's layers unrolled, so a
             # width costs more to compile than a one-kind model's; the
             # kernels' work follows the live KV whatever the width, and a
-            # sliding layer's ring has one width anyway. The price: the
-            # ragged kernel's grid is as wide as the table (ROADMAP S10)
+            # sliding layer's ring has one width anyway (the ragged
+            # kernel's grid follows the live KV blocks too since PR 45)
             w = _next_bucket(cfg.max_seq_len, cfg.page_size, cfg.max_seq_len)
             while w // 4 >= max(bucket, 2048):
                 w //= 4

@@ -1461,7 +1461,7 @@ def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
         o = att.ragged_mixed_attention(
             q, kp, vp, tables + off, context_lens, pages.pages + off, start,
             page_size=page_size, num_kv_heads=cfg.cache_kv_heads,
-            num_decode=b)
+            num_decode=b, kernel_lens=kernel_lens)
         return o, kp, vp
 
     return _hybrid_layers(
@@ -2013,6 +2013,8 @@ def mixed_step(
         cviews = _chunk_views(cfg, chunk_pages, chunk_start, c, page_size)
         block_tables = block_tables.full  # who is live
     live = _live_rows(cfg, block_tables)
+    # as decode_step: the ragged kernel does nothing for an empty slot
+    kernel_lens = jnp.where(_live_slots(block_tables), context_lens, 0)
     token_mask = jnp.concatenate(
         [jnp.ones((b,), bool) if live is None else live,
          jnp.arange(c) < chunk_len])
@@ -2044,7 +2046,8 @@ def mixed_step(
                 o = att.ragged_mixed_attention(
                     q, kp, vp, tb + page_off, ctx, table + page_off, wstart,
                     page_size=page_size, num_kv_heads=cfg.cache_kv_heads,
-                    num_decode=b, **akw)
+                    num_decode=b,
+                    kernel_lens=jnp.where(kernel_lens > 0, ctx, 0), **akw)
             x, counts = _kind_layer_tail(cfg, lp, x, h, o, token_mask)
             return x, kp, vp, counts
         q, k, v = _qkv(cfg, lp, h, all_pos,
@@ -2078,6 +2081,7 @@ def mixed_step(
                 qd, kp, vd, tables, context_lens, chunk_pages + page_off,
                 chunk_start, page_size=page_size,
                 num_kv_heads=cfg.cache_kv_heads, num_decode=b,
+                kernel_lens=kernel_lens,
                 **_attn_kwargs(cfg, page_off, k_pages.shape[1]),
             )
         x = x + _post(cfg, lp, "post_attn_norm",

@@ -559,6 +559,7 @@ def ragged_mixed_attention(
     num_decode: int,
     window=None,
     logit_cap: float = 0.0,
+    kernel_lens=None,  # [B] context_lens with 0 for a slot that holds nothing
 ) -> jax.Array:
     """Mixed ragged-batch attention: B decode rows AND one prefill chunk in
     a single program (the RPA unification — see ops/ragged_attention.py).
@@ -566,7 +567,10 @@ def ragged_mixed_attention(
     Decode rows attend their paged context through their block tables; the
     chunk's rows attend causally over its own page list. Inactive decode
     slots must carry context_lens >= 1 and zero tables (the engine's
-    existing inactive-slot contract).
+    existing inactive-slot contract). `kernel_lens` is what the Pallas
+    kernel is handed in place of `context_lens`, as in
+    `paged_attention_decode`: it does nothing for a row at context 0 (no
+    page copy, zeros out); the XLA composition keeps `context_lens`.
 
     Dispatch mirrors chunk_attention: DYNAMO_TPU_RAGGED_ATTENTION wins when
     set; otherwise the scoped backend selects (`auto` on a TPU: the Pallas
@@ -609,7 +613,8 @@ def ragged_mixed_attention(
             tabs = jnp.zeros((b + 1, w), jnp.int32)
             tabs = tabs.at[:b, :pmax].set(block_tables.astype(jnp.int32))
             tabs = tabs.at[b, :wp].set(p_pages.astype(jnp.int32))
-            cl = context_lens.astype(jnp.int32)
+            cl = (context_lens if kernel_lens is None
+                  else kernel_lens).astype(jnp.int32)
             st = jnp.asarray(p_start, jnp.int32)
             kv_lens = jnp.concatenate([cl, (st + c).reshape(1)])
             q_starts = jnp.concatenate(

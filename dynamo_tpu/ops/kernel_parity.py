@@ -6,7 +6,12 @@ head shapes — and the decode and ragged kernels at the benchmark cells' own
 steps (`decode_cell_*`, `ragged_cell_*`: 32 slots of which 6 live, tables 128
 wide, a 256-token chunk at positions 0 and 1536, 28/4 and 32/8 heads; the
 decode kernel is handed context 0 for an empty slot, as `llama.decode_step`
-hands it), and every paged kernel at MLA's latent geometry as the Kimi-K2
+hands it, and the ragged kernel `kernel_lens` as `llama.mixed_step` does;
+since PR 45 also the ragged kernel at Falcon-H1's mixed step,
+`ragged_cell_falcon_*`: 64 slots of which 13 live around 1,900 tokens, 20/4
+heads, tables 384 / 399 wide, and at the Kimi cell's as it runs now,
+`ragged_cell_kimi_*`: 2 live at 4,500 behind the 4,096-token prefix), and
+every paged kernel at MLA's latent geometry as the Kimi-K2
 cell runs it (`mla_*`: 64 heads on
 one 640-lane row stored once, 64 slots, contexts of 8-10k, a chunk behind
 an 8,192-token cached prefix) — and compares it with its XLA twin from
@@ -209,13 +214,16 @@ CELL_LIVE = {0: 517, 3: 300, 4: 16, 9: 129, 17: 1999, 31: 800}
 CELL_CHUNKS = ((0, 16 + 15), (1536, 128 + 15))
 
 
-def _cell_batch():
-    """(tables, contexts, first free page) of the chat cells' decode batch:
-    32 slots of which 6 live, tables 128 wide."""
-    tables = np.zeros((CELL_SLOTS, CELL_TABLE_WIDTH), np.int32)
-    ctx = np.ones((CELL_SLOTS,), np.int32)
+def _cell_batch(slots: int = CELL_SLOTS, width: int = CELL_TABLE_WIDTH,
+                live=None, shrink: int = 1):
+    """(tables, contexts, first free page) of a cell's decode batch: by
+    default the chat cells', 32 slots of which 6 live, tables 128 wide. An
+    interpret-mode rehearsal divides contexts and width by `shrink`."""
+    tables = np.zeros((slots, width // shrink), np.int32)
+    ctx = np.ones((slots,), np.int32)
     nxt = 1
-    for slot, c in CELL_LIVE.items():
+    for slot, c in (CELL_LIVE if live is None else live).items():
+        c = max(1, c // shrink)
         n = -(-c // PAGE_SIZE)
         tables[slot, :n] = np.arange(nxt, nxt + n)
         ctx[slot] = c
@@ -234,6 +242,27 @@ def _as_decode_step(ker, ref, args):
             lambda *a: jnp.where(live[:, None, None], ref(*a), 0), args)
 
 
+def _mixed_step_sides(n_kv: int, slots: int, interpret: bool, twin=None):
+    """(kernel, twin) of a cell's mixed step as `llama.mixed_step` hands it
+    over: the kernel gets `kernel_lens`, context 0 for a slot whose table is
+    all trash, and writes zeros there; the twin (the XLA composition unless
+    given) keeps the engine's pin (context 1), and its rows for those slots
+    are zeroed to match."""
+    def op(q, kp, vp, bt, cl, pg, st):
+        return att.ragged_mixed_attention(
+            q, kp, vp, bt, cl, pg, st, page_size=PAGE_SIZE,
+            num_kv_heads=n_kv, num_decode=slots,
+            kernel_lens=jnp.where(bt[:, 0] > 0, cl, 0))
+
+    ref = _forced(op, "xla") if twin is None else jax.jit(twin)
+
+    def zeroed(q, kp, vp, bt, *rest):
+        keep = jnp.concatenate(
+            [bt[:, 0] > 0, jnp.ones((q.shape[0] - slots,), bool)])
+        return jnp.where(keep[:, None, None], ref(q, kp, vp, bt, *rest), 0)
+    return _forced(op, "pallas_interpret" if interpret else "pallas"), zeroed
+
+
 def _case_decode_cell(h: int, n_kv: int, interpret: bool):
     """The decode window's kernel over the chat cells' batch, bf16 pool."""
     rng = np.random.default_rng(28)
@@ -249,27 +278,39 @@ def _case_decode_cell(h: int, n_kv: int, interpret: bool):
 
 
 def _case_ragged_cell(h: int, n_kv: int, p_start: int, width: int,
-                      interpret: bool):
-    """The cells' own mixed step: 32 decode rows of which 6 live, tables
-    128 wide, one 256-token chunk at `p_start`, bf16 pool."""
+                      interpret: bool, batch=None):
+    """A cell's own mixed step: by default the chat cells' (32 decode rows
+    of which 6 live, tables 128 wide), else `batch` = (slots, decode table
+    width, live slots, pool pages), shrunk 16-fold under the interpreter;
+    one 256-token chunk at `p_start` over a table `width` wide, bf16 pool."""
+    shrink = 16 if interpret and batch else 1
+    slots, table, live, pool = batch or (
+        CELL_SLOTS, CELL_TABLE_WIDTH, CELL_LIVE, CELL_POOL_PAGES)
     rng = np.random.default_rng(26 + p_start)
-    kp, vp = _pools(rng, n_kv, False, pages=CELL_POOL_PAGES)
-    tables, ctx, nxt = _cell_batch()
+    kp, vp = _pools(rng, n_kv, False, pages=pool // shrink + 32)
+    tables, ctx, nxt = _cell_batch(slots, table, live, shrink)
+    p_start = p_start // shrink // PAGE_SIZE * PAGE_SIZE
     used = (p_start + CELL_CHUNK) // PAGE_SIZE
-    assert nxt + used <= CELL_POOL_PAGES
-    pages = np.zeros((width,), np.int32)
+    assert nxt + used <= pool // shrink + 32
+    pages = np.zeros((max(width // shrink, used),), np.int32)
     pages[:used] = np.arange(nxt, nxt + used)
     q = jnp.asarray(
-        rng.normal(size=(CELL_SLOTS + CELL_CHUNK, h, HEAD_DIM)), jnp.bfloat16)
-
-    def op(*a):
-        return att.ragged_mixed_attention(
-            *a, page_size=PAGE_SIZE, num_kv_heads=n_kv,
-            num_decode=CELL_SLOTS)
+        rng.normal(size=(slots + CELL_CHUNK, h, HEAD_DIM)), jnp.bfloat16)
     args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(ctx),
             jnp.asarray(pages), jnp.asarray(p_start, jnp.int32))
-    return (_forced(op, "pallas_interpret" if interpret else "pallas"),
-            _forced(op, "xla"), args)
+    return (*_mixed_step_sides(n_kv, slots, interpret), args)
+
+
+# Falcon-H1's mixed step (benchmarks/chip/configs/falcon-h1-34b-w8a8-1chip:
+# 64 slots, --max-seq-len 6144 = decode tables 384 wide, ONE chunk table of
+# 384 + 15, 20 / 4 heads of 128): 13 of 64 slots live around 1,900 tokens
+# as the cell runs (PERF.md section 5), one context ending on a superblock
+# (1,920 = 15 x 128), one a token past it, one of a single token
+FALCON_SHAPE = ("20q4kv", 20, 4)
+FALCON_BATCH = (64, 384, {
+    0: 1900, 2: 1920, 7: 1921, 11: 128, 12: 1, 19: 2047, 23: 1664, 30: 777,
+    31: 1900, 40: 3000, 47: 1536, 55: 2200, 63: 900}, 2048)
+FALCON_CHUNK = (512, 384 + 15)
 
 
 # ---- MLA's latent geometry (Kimi-K2 / DeepSeek-V3 cell, benchmarks/chip/
@@ -288,6 +329,11 @@ MLA_LIVE = {0: 8600, 5: 8225, 6: 9984, 21: 8193, 40: 9000, 63: 16}
 # (chunk start, chunk table width): a first chunk in the 256 bucket, and a
 # tail's first chunk behind the cached prefix in the 10240 bucket
 MLA_CHUNKS = ((0, 16 + 15), (8192, 640 + 15))
+# the cell as it runs since ISSUE 27's fallback (PERF.md section 5): 2 of 64
+# slots live at ~4,500 behind the 4,096-token prefix, decode tables of 384
+# pages, the chunk's ONE table of 384 + 15, a tail's first chunk at 4,096
+KIMI_LIVE = {5: 4500, 40: 4353}
+KIMI_TABLE, KIMI_CHUNK = 384, (4096, 384 + 15)
 
 
 def _mla_decode_twin(q, kp, bt, cl):
@@ -322,19 +368,11 @@ def _mla_pool(rng, pages: int):
     return kp, jnp.zeros((pages, PAGE_SIZE, 0), jnp.bfloat16)
 
 
-def _mla_batch(shrink: int):
+def _mla_batch(shrink: int, live=None, width: int = MLA_TABLE_WIDTH):
     """(tables, contexts, first free page) of the cell's decode batch; an
     interpret-mode rehearsal divides the contexts by `shrink`."""
-    tables = np.zeros((MLA_SLOTS, MLA_TABLE_WIDTH // shrink), np.int32)
-    ctx = np.ones((MLA_SLOTS,), np.int32)
-    nxt = 1
-    for slot, c in MLA_LIVE.items():
-        c = max(1, c // shrink)
-        n = -(-c // PAGE_SIZE)
-        tables[slot, :n] = np.arange(nxt, nxt + n)
-        ctx[slot] = c
-        nxt += n
-    return tables, ctx, nxt
+    return _cell_batch(MLA_SLOTS, width, MLA_LIVE if live is None else live,
+                       shrink)
 
 
 def _case_mla_decode(interpret: bool):
@@ -375,21 +413,19 @@ def _case_mla_chunk(p_start: int, width: int, interpret: bool):
                       jnp.asarray(start, jnp.int32))
 
 
-def _case_mla_ragged(p_start: int, width: int, interpret: bool):
+def _case_mla_ragged(p_start: int, width: int, interpret: bool,
+                     live=None, table: int = MLA_TABLE_WIDTH):
     """The cell's mixed step through the dispatcher: 64 decode rows of
-    which 6 live plus one 256-token chunk at `p_start`."""
+    which 6 live (or `live`, over decode tables `table` wide) plus one
+    256-token chunk at `p_start`."""
     shrink = 16 if interpret else 1
     rng = np.random.default_rng(29 + p_start)
-    tables, ctx, nxt = _mla_batch(shrink)
+    tables, ctx, nxt = _mla_batch(shrink, live, table)
     kp, vp = _mla_pool(rng, MLA_POOL_PAGES // shrink + 32)
     pages, start = _mla_chunk_pages(nxt, p_start, width, shrink)
     q = jnp.asarray(
         rng.normal(size=(MLA_SLOTS + CELL_CHUNK, MLA_HEADS, MLA_LANES)),
         jnp.bfloat16)
-
-    def op(*a):
-        return att.ragged_mixed_attention(
-            *a, page_size=PAGE_SIZE, num_kv_heads=1, num_decode=MLA_SLOTS)
 
     def twin(q, kp, vp, bt, cl, pg, st):
         return jnp.concatenate([
@@ -397,8 +433,7 @@ def _case_mla_ragged(p_start: int, width: int, interpret: bool):
             _mla_chunk_twin(q[MLA_SLOTS:], kp, pg, st)], axis=0)
     args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(ctx),
             jnp.asarray(pages), jnp.asarray(start, jnp.int32))
-    return (_forced(op, "pallas_interpret" if interpret else "pallas"),
-            jax.jit(twin), args)
+    return (*_mixed_step_sides(1, MLA_SLOTS, interpret, twin), args)
 
 
 def _case_mla_prefill(s: int, interpret: bool):
@@ -468,7 +503,14 @@ def cases(interpret: bool) -> List[Tuple[str, Callable]]:
             out.append((f"ragged_cell_p{p_start}_bf16/{label}",
                         functools.partial(_case_ragged_cell, h, n_kv,
                                           p_start, width, interpret)))
+    label, h, n_kv = FALCON_SHAPE
+    out.append((f"ragged_cell_falcon_p{FALCON_CHUNK[0]}_bf16/{label}",
+                functools.partial(_case_ragged_cell, h, n_kv, *FALCON_CHUNK,
+                                  interpret, FALCON_BATCH)))
     label = f"{MLA_HEADS}q1kv{MLA_LANES}"
+    out.append((f"ragged_cell_kimi_p{KIMI_CHUNK[0]}_bf16/{label}",
+                functools.partial(_case_mla_ragged, *KIMI_CHUNK, interpret,
+                                  KIMI_LIVE, KIMI_TABLE)))
     out.append((f"mla_decode_cell_bf16/{label}",
                 functools.partial(_case_mla_decode, interpret)))
     for p_start, width in MLA_CHUNKS:

@@ -13,15 +13,26 @@ Kernel anatomy follows `_chunk_kernel` / `_decode_kernel` in
 `pallas_attention.py` (page-major fused-head KV, multi-page superblock DMA
 ring pipelined across a sequential grid via a persistent SMEM cursor,
 block-diagonal GQA matmuls, int8 packed-scale rows dequantized in-VMEM).
-Where it departs, it is to be small: the kernel is traced and lowered for
-every mixed program (16 at warmup, ~1 s each on a serving host before
-PR 26), so the page copies are a loop, `//` on traced integers is one
-`lax.div`, and the wrapper is jitted so that programs that share shapes
-share one trace:
+Where it departs, it is to be small: the kernel is lowered for every mixed
+program (16 at warmup, ~1 s each on a serving host before PR 26), so `//`
+on traced integers is one `lax.div` and the wrapper is jitted so that
+programs that share shapes share one trace (the page copies were a loop
+from PR 26 to PR 45, which measured the loop at 12% of the kernel):
 
-- Grid is `(num_q_blocks, nk_max)` where the first `num_decode` query blocks
-  are the decode slots (one real row each, padded to `block_q`) and the rest
-  tile the prefill chunk `block_q` tokens at a time.
+- Grid is `(num_q_blocks,)`: ONE grid step a query block, and inside it a
+  loop over that block's OWN KV blocks, as many as its causal horizon holds
+  (`n_blocks`, a dynamic trip count; under a static window from
+  `first_block`). The first `num_decode` query blocks are the decode slots
+  (one real row each, padded to `block_q`), the rest tile the prefill chunk
+  `block_q` tokens at a time. Until PR 45 the grid was `(num_q_blocks,
+  table width / block_pages)` whatever was live: at 64 slots and a
+  6,144-token table 4,800 steps a call of which ≈ 400 did work (alone at
+  that shape, 13 slots live: 0.78 -> 0.43 ms a call on a v5e; PERF.md
+  section 6, PR 45).
+- A decode row handed kv_len 0 (`ragged_mixed_attention`'s `kernel_lens`: a
+  slot that holds no sequence) owns no KV block: no page copy, no product,
+  zeros written. The DMA ring runs on across query blocks (issue order ==
+  consume order) and its issue cursor steps over such rows (`next_owner`).
 - Scalar-prefetched descriptor arrays drive everything ragged:
   `tables_ref [R, W]` (row r = sequence r's page table, trash-padded; the
   last row is the chunk's), `kvlen_ref [R]` (attention horizon per sequence,
@@ -37,9 +48,11 @@ share one trace:
   (whose outputs are discarded) from touching garbage pages past their
   context.
 
-NaN-safety mirrors the house kernels: token 0 is unmasked for every row of
-every sequence at its first KV block (`q_start >= 0`, `kv_len >= 1`), so the
-running max is finite from the first `_flash_update` on.
+NaN-safety mirrors the house kernels: token 0 is unmasked for every row of a
+block that owns KV blocks at the first one it visits (`q_start >= 0`,
+`kv_len >= 1`), so the running max is finite from the first `_flash_update`
+on (under a window a finite floor stands in: see the kernel's mask); a row
+that owns nothing runs no `_flash_update` at all and reads zero.
 
 Live KV only: a block's page copies never follow its table past the block's
 horizon (a superblock's tail re-reads the last live page), so the work, the
@@ -100,7 +113,8 @@ def _div(x, n: int):
 def _ragged_kernel(
     # scalar prefetch
     tables_ref,  # [R, W] int32 page tables (row R-1 = the prefill chunk's)
-    kvlen_ref,  # [R] int32 attention horizon per sequence (incl. this step)
+    kvlen_ref,  # [R] int32 attention horizon per sequence (incl. this
+    #             step); 0 = a decode slot that holds no sequence
     qstart_ref,  # [R] int32 absolute position of the first query token
     # inputs
     q_ref,  # [1, BQ, H, D] VMEM block (one ragged query block)
@@ -130,8 +144,13 @@ def _ragged_kernel(
     shared: bool = False,
     window: int = 0,
 ):
+    """One grid step a query block; inside it a loop over that block's OWN
+    KV blocks (`n_blocks`). A decode row whose sequence has kv_len 0 owns
+    none: no page copy, no product, zeros written. The DMA ring runs on
+    across query blocks (issue order == consume order), its issue cursor
+    stepping over the rows that own nothing (`_decode_kernel` is the
+    model)."""
     qb = pl.program_id(0)
-    kb = pl.program_id(1)
     nq = pl.num_programs(0)
     tokens_per_block = block_pages * page_size
     h, d = q_ref.shape[2], q_ref.shape[3]
@@ -152,7 +171,7 @@ def _ragged_kernel(
     def horizon(qq):
         # causal horizon of block qq clamped to its sequence's kv length
         # (a decode block stops at its context; a chunk block never reads
-        # past the chunk end), and >= 1: see n_blocks
+        # past the chunk end). Asked of owners only, and >= 1: see n_blocks
         r = seq_row(qq)
         hz = jnp.minimum(qstart_ref[r] + q_off(qq) + block_q, kvlen_ref[r])
         return jnp.maximum(hz, 1)
@@ -169,18 +188,21 @@ def _ragged_kernel(
 
     def block_dma(qq, kk, slot, wait):
         """Start (or wait for) the 2 * block_pages page copies of KV block
-        kk of query block qq into ring slot `slot`. A loop, not an unrolled
-        list: the kernel is traced and lowered once per mixed program (16
-        at warmup), and its size is set-up time."""
+        kk of query block qq into ring slot `slot`. Unrolled, as the decode
+        kernel has them: as a `fori_loop` the copies cost 0.16-0.18 us a block
+        more, 12% of the kernel at 13 of 64 slots live and 13% at 64 (PR 45,
+        v5e), and unrolled a mixed program lowers 0.02-0.035 s slower (the
+        kernel is traced once a shape: the wrapper is a jit of its own)."""
         r = seq_row(qq)
-        if window and not wait:
-            kk = kk + first_block(qq)
-        # live KV only: a superblock's tail past the horizon re-reads the
-        # last live page instead of following the table, so neither a
-        # table entry past the horizon nor the page it names is ever read
-        last = jnp.minimum(_div(horizon(qq) - 1, page_size), table_width - 1)
-
-        def page(j, carry):
+        if not wait:
+            # live KV only: a superblock's tail past the horizon re-reads
+            # the last live page instead of following the table, so neither
+            # a table entry past the horizon nor the page it names is read
+            last = jnp.minimum(_div(horizon(qq) - 1, page_size),
+                               table_width - 1)
+            if window:
+                kk = kk + first_block(qq)
+        for j in range(block_pages):
             # a wait needs the copy's shape and semaphore, not its source
             pg = 0 if wait else tables_ref[
                 r, jnp.minimum(kk * block_pages + j, last)]
@@ -190,91 +212,121 @@ def _ragged_kernel(
                 c = pltpu.make_async_copy(
                     hbm.at[pg], buf.at[slot, j], sem.at[slot, which, j])
                 c.wait() if wait else c.start()
-            return carry
-
-        jax.lax.fori_loop(0, block_pages, page, 0)
 
     def n_blocks(qq):
-        # >= 1 (horizon is): every block owns at least one pipeline step —
-        # breaking issue/consume pairing would corrupt the DMA slot parity
+        # of an owner, and >= 1 (horizon is): the issue side starts an
+        # owner's first block before it counts them, so an owner that
+        # consumed none would break the ring's issue/consume pairing
         n = _div(horizon(qq) + tokens_per_block - 1, tokens_per_block)
         return n - first_block(qq) if window else n
 
+    def owns(qq):
+        # a chunk block always does (its own tokens: kv_len >= 1)
+        return kvlen_ref[seq_row(qq)] > 0
+
+    def next_owner(qq):
+        """The first query block at or after qq that owns a KV block (any
+        chunk block does, so at most num_decode); nq if qq is past the
+        grid."""
+        if not num_decode:
+            return qq
+        return jax.lax.while_loop(
+            lambda x: (x < num_decode) & jnp.logical_not(owns(x)),
+            lambda x: x + 1, qq)
+
     def issue_one():
+        """Issue the block at the issue cursor (if any remain) into ring
+        slot `issued % num_bufs`, then advance the cursor to the next
+        block anyone owns; the consume side finds it at
+        `consumed % num_bufs`."""
         iq, ik = ptr_ref[1], ptr_ref[2]
 
         @pl.when(iq < nq)
         def _():
             block_dma(iq, ik, jax.lax.rem(ptr_ref[3], num_bufs), wait=False)
             ptr_ref[3] = ptr_ref[3] + 1
-            nxt = ik + 1
-            done = nxt >= n_blocks(iq)
-            ptr_ref[1] = jnp.where(done, iq + 1, iq)
-            ptr_ref[2] = jnp.where(done, 0, nxt)
+            more = ik + 1 < n_blocks(iq)
+            ptr_ref[2] = jnp.where(more, ik + 1, 0)
 
-    nb_q = n_blocks(qb)
+            @pl.when(jnp.logical_not(more))
+            def _():
+                ptr_ref[1] = next_owner(iq + 1)
 
-    @pl.when((qb == 0) & (kb == 0))
+    @pl.when(qb == 0)
     def _init():
         ptr_ref[0] = 0  # consumed-block count
-        ptr_ref[1] = 0  # issue cursor: query block
+        ptr_ref[1] = next_owner(0)  # issue cursor: query block
         ptr_ref[2] = 0  # issue cursor: kv block within it
         ptr_ref[3] = 0  # issued-block count
-        for _ in range(num_bufs - 1):
+
+        def prime(_, carry):
             issue_one()
+            return carry
 
-    @pl.when(kb < nb_q)
-    def _active():
-        cnt = ptr_ref[0]
-        cur = jax.lax.rem(cnt, num_bufs)
-        issue_one()
-        block_dma(qb, kb, cur, wait=True)
-        ptr_ref[0] = cnt + 1
+        # a loop: it runs once a call, and the kernel's size is set-up time
+        jax.lax.fori_loop(0, num_bufs - 1, prime, 0)
 
+    live = owns(qb)
+
+    @pl.when(jnp.logical_not(live))
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live)
+    def _live():
         def bd_mask():
-            # only the first and the last KV block of a query block need it
+            # needed before the loop (the queries) and after it (the fold)
             row = jax.lax.broadcasted_iota(jnp.int32, (rows, kvd), 0)
             lane = jax.lax.broadcasted_iota(jnp.int32, (rows, kvd), 1)
             return _div(jax.lax.rem(row, h), group) == _div(lane, d)
 
-        @pl.when(kb == 0)
-        def _reset():
-            _flash_reset(m_ref, l_ref, acc_ref)
-            q = q_ref[0].astype(jnp.float32).reshape(rows, d) * scale
-            qbd_ref[...] = jnp.where(bd_mask(), jnp.tile(q, (1, n_kv)), 0.0)
-
-        k, v = _kv_block(kbuf, vbuf, cur, tokens_per_block, n_kv, d,
-                         lane_width, quantized, shared)
-        s = jax.lax.dot_general(
-            qbd_ref[...].astype(k.dtype), k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [rows, T]
-        tok = ((kb + first_block(qb)) if window else kb
-               ) * tokens_per_block + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
+        _flash_reset(m_ref, l_ref, acc_ref)
+        q = q_ref[0].astype(jnp.float32).reshape(rows, d) * scale
+        qbd_ref[...] = jnp.where(bd_mask(), jnp.tile(q, (1, n_kv)), 0.0)
         r = seq_row(qb)
-        qpos = qstart_ref[r] + q_off(qb) + _div(
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, 0), h)
-        if window:
-            # a later row of the block may see nothing of the first block
-            # visited (it starts where the FIRST row's reach does): a
-            # finite floor keeps its running max finite, and the first
-            # block that holds a key in its reach wipes what it summed
-            s = jnp.where((tok <= qpos) & (tok < kvlen_ref[r])
-                          & (tok > qpos - window), s, _OUT_OF_REACH)
-        else:
-            s = jnp.where((tok <= qpos) & (tok < kvlen_ref[r]), s, NEG_INF)
-        _flash_update(m_ref, l_ref, acc_ref, s, v)
 
-        @pl.when(kb == nb_q - 1)
-        def _finalize():
-            out = _flash_normalize(l_ref, acc_ref)  # [rows, KVD]
-            out = jnp.where(bd_mask(), out, 0.0)
-            folded = out[:, 0:d]
-            for kv in range(1, n_kv):
-                folded = folded + out[:, kv * d:(kv + 1) * d]
-            o_ref[0] = folded.reshape(block_q, h, d).astype(o_ref.dtype)
+        def block(kb, carry):
+            cnt = ptr_ref[0]
+            cur = jax.lax.rem(cnt, num_bufs)
+            # keep the ring full: one block is issued `num_bufs - 1` ahead
+            # of the one consumed, into the slot consumed that long ago
+            issue_one()
+            block_dma(qb, kb, cur, wait=True)
+            ptr_ref[0] = cnt + 1
+            k, v = _kv_block(kbuf, vbuf, cur, tokens_per_block, n_kv, d,
+                             lane_width, quantized, shared)
+            s = jax.lax.dot_general(
+                qbd_ref[...].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [rows, T]
+            tok = ((kb + first_block(qb)) if window else kb
+                   ) * tokens_per_block + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1
+            )
+            qpos = qstart_ref[r] + q_off(qb) + _div(
+                jax.lax.broadcasted_iota(jnp.int32, s.shape, 0), h)
+            if window:
+                # a later row of the block may see nothing of the first
+                # block visited (it starts where the FIRST row's reach
+                # does): a finite floor keeps its running max finite, and
+                # the first block that holds a key in its reach wipes what
+                # it summed
+                s = jnp.where((tok <= qpos) & (tok < kvlen_ref[r])
+                              & (tok > qpos - window), s, _OUT_OF_REACH)
+            else:
+                s = jnp.where((tok <= qpos) & (tok < kvlen_ref[r]), s,
+                              NEG_INF)
+            _flash_update(m_ref, l_ref, acc_ref, s, v)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks(qb), block, 0)
+
+        out = _flash_normalize(l_ref, acc_ref)  # [rows, KVD]
+        out = jnp.where(bd_mask(), out, 0.0)
+        folded = out[:, 0:d]
+        for kv in range(1, n_kv):
+            folded = folded + out[:, kv * d:(kv + 1) * d]
+        o_ref[0] = folded.reshape(block_q, h, d).astype(o_ref.dtype)
 
 
 # jitted so that the kernel body is traced once per shape, not once per
@@ -341,7 +393,6 @@ def ragged_paged_attention(
     assert not window or window >= block_q, (window, block_q)
     n_chunk_blocks = c // block_q
     nbq = num_decode + n_chunk_blocks
-    nk_max = -(-width // block_pages)
     scale = 1.0 / (head_dim**0.5)
     rows = block_q * n_heads
 
@@ -360,16 +411,16 @@ def ragged_paged_attention(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(nbq, nk_max),
+        grid=(nbq,),
         in_specs=[
             pl.BlockSpec((1, block_q, n_heads, head_dim),
-                         lambda qb, kb, tb, kl, qs: (qb, 0, 0, 0)),
+                         lambda qb, tb, kl, qs: (qb, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
             (1, block_q, n_heads, head_dim),
-            lambda qb, kb, tb, kl, qs: (qb, 0, 0, 0),
+            lambda qb, tb, kl, qs: (qb, 0, 0, 0),
         ),
         scratch_shapes=[
             pltpu.VMEM((num_bufs, block_pages, page_size, lane_width),
@@ -407,7 +458,7 @@ def ragged_paged_attention(
         compiler_params=pltpu.CompilerParams(
             # sequential on purpose: the DMA pipeline carries state across
             # grid steps (see module docstring)
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
     )(tables.astype(jnp.int32), kv_lens.astype(jnp.int32),

@@ -248,6 +248,210 @@ def test_ragged_gate_demotion_is_counted(monkeypatch):
     assert ragged_keys, f"no ragged demotion counted: {after}"
 
 
+# ---------------------------------------- live work only (PR 45's kernel) --
+# One grid step a query block and a loop over that block's own KV blocks; a
+# decode row handed kv_len 0 (`kernel_lens`, as llama.mixed_step hands an
+# empty slot) owns none: no page copy, zeros written, and the ring's issue
+# cursor steps over it.
+
+_PS, _SLOTS, _WIDTH, _CHUNK = 4, 6, 24, 16  # 8 pages = 32 tokens a KV block
+# contexts: one ends exactly on a KV block (32), one a token past it, one
+# on the second block's end, a single token, two mid-block
+_CTX = (45, 32, 33, 64, 1, 70)
+# which slots hold a sequence
+_LIVE = {"empty_first": (2, 3, 4, 5), "empty_middle": (0, 1, 4, 5),
+         "empty_last": (0, 1, 2, 3), "first_and_last_live": (0, 5),
+         "all_empty": (), "all_live": (0, 1, 2, 3, 4, 5)}
+
+
+def _live_work_cell(rng, kind, live, start, k1=1):
+    """6 slots of which `live` hold a sequence, one 16-token chunk at
+    `start`. kind: plain (8 / 2 heads of 64, float32 pool) | shared (MLA: 4
+    heads on ONE 128-lane row, the V pool without lanes) | int8 (packed).
+    Returns the clean operands, a poisoned twin (NaN in the trash page, in
+    every free page and named by every table entry past a row's horizon;
+    None for int8) and the ops' keywords."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops import attention as att
+
+    h, n_kv, d = (4, 1, 128) if kind == "shared" else (8, 2, 64)
+    chunk_pages = (start + _CHUNK) // _PS
+    n_pool = 1 + sum(-(-(c + k1 - 1) // _PS) for c in _CTX) + chunk_pages + 2
+    kf = rng.normal(size=(n_pool, _PS, n_kv * d)).astype(np.float32)
+    vf = rng.normal(size=(n_pool, _PS, n_kv * d)).astype(np.float32)
+    tables = np.zeros((_SLOTS, _WIDTH), np.int32)
+    ctx = np.ones((_SLOTS,), np.int32)  # the engine's pin of an empty slot
+    dead = n_pool - 1
+    bad_tables = np.full((_SLOTS, _WIDTH), dead, np.int32)
+    bad_tables[:, 0] = 0  # an empty slot's first page is the trash page
+    nxt = 1
+    for slot in live:
+        n = -(-(_CTX[slot] + k1 - 1) // _PS)
+        tables[slot, :n] = bad_tables[slot, :n] = np.arange(nxt, nxt + n)
+        ctx[slot] = _CTX[slot]
+        nxt += n
+    pages = np.zeros((chunk_pages + 3,), np.int32)
+    pages[:chunk_pages] = np.arange(nxt, nxt + chunk_pages)
+    bad_pages = np.where(np.arange(pages.size) < chunk_pages, pages, dead)
+    nxt += chunk_pages
+    q = jnp.asarray(rng.normal(size=(_SLOTS * k1 + _CHUNK, h, d)),
+                    jnp.float32)
+
+    def pools(k, v):
+        if kind == "int8":
+            w = att.kv_lane_width(n_kv, d, True)
+            return tuple(att.pack_kv_rows(
+                jnp.asarray(x.reshape(-1, n_kv, d)), w).reshape(n_pool, _PS, w)
+                for x in (k, v))
+        if kind == "shared":
+            return jnp.asarray(k), jnp.zeros((n_pool, _PS, 0), jnp.float32)
+        return jnp.asarray(k), jnp.asarray(v)
+
+    clean = (q, *pools(kf, vf), jnp.asarray(tables), jnp.asarray(ctx),
+             jnp.asarray(pages), start)
+    bad = None
+    if kind != "int8":
+        bad_k, bad_v = kf.copy(), vf.copy()
+        bad_k[0], bad_v[0] = np.nan, np.nan
+        bad_k[nxt:], bad_v[nxt:] = np.nan, np.nan
+        bad = (q, *pools(bad_k, bad_v), jnp.asarray(bad_tables),
+               jnp.asarray(ctx), jnp.asarray(bad_pages), start)
+    return clean, bad, dict(page_size=_PS, num_kv_heads=n_kv)
+
+
+def _mixed(monkeypatch, backend, args, live, window=0, **kw):
+    """ragged_mixed_attention as llama.mixed_step hands it over
+    (`kernel_lens`: 0 where the slot is empty); numpy."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops import attention as att
+
+    monkeypatch.setenv("DYNAMO_TPU_RAGGED_ATTENTION", backend)
+    mask = np.zeros((_SLOTS,), bool)
+    mask[list(live)] = True
+    return np.asarray(att.ragged_mixed_attention(
+        *args, num_decode=_SLOTS, window=window or None,
+        kernel_lens=jnp.where(jnp.asarray(mask), args[4], 0), **kw))
+
+
+def _rows(live):
+    """The mask of the rows somebody owns: live decode rows, the chunk."""
+    rows = np.zeros((_SLOTS + _CHUNK,), bool)
+    rows[list(live)] = True
+    rows[_SLOTS:] = True
+    return rows
+
+
+@pytest.mark.parametrize("pattern", sorted(_LIVE))
+@pytest.mark.parametrize("window", [0, 12], ids=["full", "window12"])
+@pytest.mark.parametrize("kind", ["plain", "shared", "int8"])
+def test_ragged_kernel_skips_empty_rows(monkeypatch, kind, window, pattern):
+    """Live rows and the chunk's rows are the XLA composition's, an empty
+    row reads zero, wherever the empty rows lie in the batch: first, in
+    the middle, last, all of them, none."""
+    live = _LIVE[pattern]
+    # all_empty: the chunk at start 0 is the only owner; empty_first: it
+    # ends exactly on a KV block (16 + 16)
+    start = {"all_empty": 0, "empty_first": 16}.get(pattern, 40)
+    clean, _, kw = _live_work_cell(np.random.default_rng(45), kind, live,
+                                   start)
+    ref = _mixed(monkeypatch, "xla", clean, live, window, **kw)
+    out = _mixed(monkeypatch, "pallas_interpret", clean, live, window, **kw)
+    rows = _rows(live)
+    tol = 2e-2 if kind == "int8" else 2e-5
+    np.testing.assert_allclose(out[rows], ref[rows], atol=tol, rtol=tol)
+    assert not out[~rows].any()
+    assert np.isfinite(ref[~rows]).all()  # the twin keeps the engine's pin
+
+
+@pytest.mark.parametrize("window", [0, 12], ids=["full", "window12"])
+@pytest.mark.parametrize("kind", ["plain", "shared", "int8"])
+def test_ragged_kernel_with_no_decode_row(monkeypatch, kind, window):
+    """num_decode = 0 (a windowed chunk alone takes this form): the chunk
+    is the only sequence, at start 0 and mid-prompt."""
+    from dynamo_tpu.ops import attention as att
+
+    for start in (0, 40):
+        (q, kp, vp, _, _, pages, _), _, kw = _live_work_cell(
+            np.random.default_rng(46), kind, (), start)
+        args = (q[_SLOTS:], kp, vp, np.zeros((0, _WIDTH), np.int32),
+                np.zeros((0,), np.int32), pages, start)
+
+        def run(backend):
+            monkeypatch.setenv("DYNAMO_TPU_RAGGED_ATTENTION", backend)
+            return np.asarray(att.ragged_mixed_attention(
+                *args, num_decode=0, window=window or None, **kw))
+
+        tol = 2e-2 if kind == "int8" else 2e-5
+        np.testing.assert_allclose(run("pallas_interpret"), run("xla"),
+                                   atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("pattern", ["empty_middle", "all_empty"])
+@pytest.mark.parametrize("kind", ["plain", "shared", "int8"])
+def test_ragged_verify_windows_keep_their_blocks(monkeypatch, kind, pattern):
+    """decode_q = K + 1: an inactive window (zero table, position 0) has
+    kv_len K + 1 and keeps its block on the trash page, so every row is
+    the XLA composition's, the inactive windows' too."""
+    from dynamo_tpu.ops import attention as att
+
+    k1, live = 3, _LIVE[pattern]
+    (q, kp, vp, tabs, ctx, pages, start), _, kw = _live_work_cell(
+        np.random.default_rng(47), kind, live, 40, k1)
+
+    def run(backend):
+        monkeypatch.setenv("DYNAMO_TPU_RAGGED_ATTENTION", backend)
+        return np.asarray(att.ragged_verify_attention(
+            q, kp, vp, tabs, ctx - 1, pages, start, num_verify=_SLOTS,
+            verify_width=k1, **kw))
+
+    tol = 2e-2 if kind == "int8" else 2e-5
+    np.testing.assert_allclose(run("pallas_interpret"), run("xla"),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("pattern", ["empty_middle", "first_and_last_live",
+                                     "all_empty"])
+@pytest.mark.parametrize("window", [0, 12], ids=["full", "window12"])
+@pytest.mark.parametrize("kind", ["plain", "shared"])
+def test_ragged_kernel_reads_nothing_nobody_owns(monkeypatch, kind, window,
+                                                 pattern):
+    """NaN in the trash page, in every free page and behind every table
+    entry past a row's horizon: live rows and the chunk's rows bit for bit
+    what they are on the clean pool, empty rows zero."""
+    live = _LIVE[pattern]
+    clean, bad, kw = _live_work_cell(np.random.default_rng(48), kind, live,
+                                     40)
+    want = _mixed(monkeypatch, "pallas_interpret", clean, live, window, **kw)
+    got = _mixed(monkeypatch, "pallas_interpret", bad, live, window, **kw)
+    rows = _rows(live)
+    assert np.isfinite(want).all()
+    np.testing.assert_array_equal(got[rows], want[rows])
+    assert not got[~rows].any()
+    # the poison is in reach of anything that follows a table or the pin
+    assert np.isnan(_mixed(monkeypatch, "xla", bad, live, window, **kw)).any()
+
+
+@pytest.mark.parametrize("window", [0, 12], ids=["full", "window12"])
+@pytest.mark.parametrize("kind", ["plain", "shared", "int8"])
+def test_ragged_kernel_rows_do_not_talk_through_the_ring(monkeypatch, kind,
+                                                         window):
+    """A live row's output, and the chunk's, are bit for bit what the same
+    call gives with every other slot emptied: the ring that runs on across
+    query blocks hands each block its own pages in its own order, whoever
+    else owns blocks before or after it."""
+    live = _LIVE["all_live"]
+    clean, _, kw = _live_work_cell(np.random.default_rng(49), kind, live, 40)
+    full = _mixed(monkeypatch, "pallas_interpret", clean, live, window, **kw)
+    for slot in live:
+        alone = _mixed(monkeypatch, "pallas_interpret", clean, (slot,),
+                       window, **kw)
+        rows = _rows((slot,))
+        np.testing.assert_array_equal(alone[rows], full[rows])
+        assert not alone[~rows].any()
+
+
 # -------------------------------------------------- engine mixed (eager) --
 
 

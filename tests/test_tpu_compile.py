@@ -62,6 +62,98 @@ def test_ragged_kernel_compiles_for_v5e_at_cell_shapes(one_chip, heads,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _layer_metric_patterns(*names):
+    """The trace patterns of benchmarks/chip/layer_metrics/<name>.json, read
+    from the benchmark's own files."""
+    import json
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "chip", "layer_metrics")
+    out = []
+    for name in names:
+        with open(os.path.join(root, name + ".json")) as f:
+            out.append(json.load(f)["args"]["pattern"])
+    return out
+
+
+# the mixed step of every cell that runs the ragged kernel (the docqa
+# cell's selects its rows elsewhere): slots, query heads, KV heads, lanes a
+# head, table width handed (max(decode table, chunk table): --max-seq-len /
+# 16, + 15 where the chunk's table is the wider), static window, V lanes
+# (0: MLA's row stored once), and the layer metrics that find the kernel
+RAGGED_CELLS = {
+    "qwen7b-chat-r80": (32, 28, 4, 128, 128 + 15, 0, None,
+                        ("attn_kernel_busy_pct.chat",)),
+    "mixtral-chat-r80": (32, 32, 8, 128, 128 + 15, 0, None,
+                         ("attn_kernel_busy_pct.chat",)),
+    "kimi-k2-agent-r80": (64, 64, 1, 640, 384 + 15, 0, 0,
+                          ("mla_mixed_attn_roofline.agent",
+                           "mla_attn_busy_pct.agent")),
+    "laguna-s-longmix-r80.full": (64, 48, 8, 128, 2048 + 15, 0, None,
+                                  ("gqa_mixed_attn_roofline.longmix",
+                                   "full_attn_busy_pct.longmix")),
+    "laguna-s-longmix-r80.window": (64, 72, 8, 128, 49, 512, None,
+                                    ("gqa_mixed_attn_roofline.longmix",
+                                     "window_attn_busy_pct.longmix")),
+    "nemotron3-nano-reason-r80": (64, 32, 2, 128, 384 + 15, 0, None, ()),
+    "falcon-h1-rewrite-r80": (64, 20, 4, 128, 384 + 15, 0, None,
+                              ("gqa_mixed_attn_roofline.rewrite",
+                               "full_attn_busy_pct.rewrite")),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(RAGGED_CELLS))
+def test_ragged_kernel_is_found_by_the_benchmark_at_every_cell(one_chip,
+                                                                cell):
+    """The ragged kernel (PR 45: one grid step a query block, a loop with a
+    dynamic trip count over the block's own KV blocks) compiles for a
+    described v5e at every cell's mixed step, its grid has ONE dimension,
+    and its custom call's text, the name a trace gives it, is matched by
+    the patterns of the benchmark's own layer metrics: the result stays
+    `bf16[query blocks, 8, heads, lanes]`."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops import ragged_attention as ra
+
+    slots, heads, kv_heads, lanes, width, window, v_lanes, metrics = \
+        RAGGED_CELLS[cell]
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = jax.jit(functools.partial(
+        ra.ragged_paged_attention, page_size=PAGE, num_kv_heads=kv_heads,
+        num_decode=slots, window=window))
+    args = (arg((slots + CHUNK, heads, lanes), jnp.bfloat16),
+            arg((POOL_PAGES, PAGE, kv_heads * lanes), jnp.bfloat16),
+            arg((POOL_PAGES, PAGE,
+                 kv_heads * lanes if v_lanes is None else v_lanes),
+                jnp.bfloat16),
+            arg((slots + 1, width), jnp.int32), arg((slots + 1,), jnp.int32),
+            arg((slots + 1,), jnp.int32))
+
+    def pallas_calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from pallas_calls(sub)
+
+    calls = list(pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert len(calls) == 1
+    assert calls[0].params["grid_mapping"].grid == (slots + CHUNK // 8,)
+
+    lines = [ln.strip() for ln in fn.lower(*args).compile().as_text()
+             .splitlines() if "tpu_custom_call" in ln]
+    assert len(lines) == 1
+    assert f" = bf16[{slots + CHUNK // 8},8,{heads},{lanes}]{{" in lines[0]
+    for pattern in _layer_metric_patterns(*metrics):
+        assert re.search(pattern, lines[0]), (pattern, lines[0][:200])
+
+
 @pytest.mark.parametrize(
     "slots,heads,kv_heads,head_dim,width,pool_dtype,v_lanes",
     [(32, 28, 4, 128, 128, "bfloat16", None),

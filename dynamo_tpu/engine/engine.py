@@ -27,7 +27,7 @@ import inspect
 import logging
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -217,6 +217,11 @@ class EngineMetrics:
         self.mixed_buckets = [0] * (len(self._OCC_EDGES) + 1)
         self.mixed_sum = 0.0
         self.mixed_count = 0
+        # mixed steps dispatched while a program was still unread (behind a
+        # window or the chunk before them, Engine._mixed_step), counted at
+        # dispatch; over mixed_count: how often a prompt's chunk found the
+        # device busy instead of draining it first
+        self.mixed_behind = 0
         self.phases: Dict[str, PhaseTimer] = {p: PhaseTimer()
                                               for p in self._PHASES}
         # a first token by stage, cumulative seconds over `count` requests
@@ -546,6 +551,24 @@ class InflightPrefill:
         # import_kv taking the last slot mid-prefill would strand the finish)
         self.aslot = aslot  # LoRA device slot (pins it against eviction
         # for the chunks' duration; the registry reads it)
+
+
+class PendingProgram(NamedTuple):
+    """The program in flight under async scheduling: dispatched over the
+    decode batch and not read back yet, a fused window or a mixed step
+    alike. The next program is dispatched on its device outputs (the carry
+    in Engine._dev_state, the pools) before _materialize_window reads it."""
+
+    lag: int  # decode steps it advances every slot: what the host lags by
+    ys: tuple  # tokens (and logprobs) still on the device
+    want_lp: bool
+    dispatch_s: float  # HOST dispatch cost; the readback adds its own wait
+    slots: List[int]  # membership AT DISPATCH
+    ticket: int  # timeline.dispatch_seq after the dispatch
+    # a mixed step's alone: (start, take) of the prompt's chunk, and the
+    # decode rows' contexts as the kernels were handed them
+    chunk: Optional[Tuple[int, int]] = None
+    contexts: Optional[List[int]] = None
 
 
 class Engine:
@@ -966,9 +989,9 @@ class Engine:
         # (temp, top_p, top_k, pres, freq, min_p, bias_ids, bias_vals, keys)
         self._dev_sampling = None
         self._dev_adapters = None  # [B] int32 LoRA slots (lora mode only)
-        # async scheduling: the decode window whose tokens have been
-        # dispatched but not read back yet — (window, ys, want_lp, t0)
-        self._pending_win = None
+        # async scheduling: the program (a fused window or a mixed step)
+        # dispatched but not read back yet — a PendingProgram
+        self._pending_win: Optional[PendingProgram] = None
         # last warmup() result (programs compiled, seconds) — exposed on
         # worker /metrics by observability/engine_metrics.py
         self.warmup_info = None
@@ -2332,9 +2355,6 @@ class Engine:
                 break  # wait for running sequences to release pages
             with self._lock:
                 self._pending_remove(req)
-            # installing a slot invalidates the device carry: drain the
-            # in-flight async window before membership changes
-            events.extend(self._materialize_pending())
             if chunk > 0 and (n_cached > 0
                               or len(req.prompt_token_ids) > chunk
                               or self._warm_chunked
@@ -2346,9 +2366,14 @@ class Engine:
                 # Mixed mode routes EVERY prompt here while decode slots
                 # are live — the chunks then ride the unified ragged step
                 # instead of preempting it (an idle engine still takes the
-                # faster full/batched prefill below).
+                # faster full/batched prefill below). Starting one reserves
+                # a slot and pages on the host and changes no decode
+                # membership: the in-flight program keeps running
                 self._start_inflight(req, cached_pages, n_cached)
                 break
+            # installing a slot invalidates the device carry: drain the
+            # in-flight async program before membership changes
+            events.extend(self._materialize_pending())
             group = self._widen_group(req, chunk)
             if len(group) > 1:
                 got = self._prefill_group(group)
@@ -3145,37 +3170,81 @@ class Engine:
         token, or with `spec` runs a K+1-token verify window and emits
         1..K+1 (dispatched even when no slot drafted this step: n_acc = 0
         everywhere reduces it to plain mixed semantics, and the
-        compiled-program set stays bounded and warm)."""
+        compiled-program set stays bounded and warm).
+
+        Under async scheduling a plain mixed step rides the pipeline like
+        a window (_decode_async): it is dispatched on the device outputs
+        of the program in flight (a window, or the chunk before it) and
+        THAT program is read afterwards, so a prompt's chunks find the
+        device busy. The step itself stays in flight for the next step()
+        to read — unless it carries the prompt's final chunk (its logits
+        give the first token, the slot installs, the carry is rebuilt) or
+        the program read behind it held a finish, whose freed pages it
+        may still touch. Read at once, in the synchronous order: under
+        speculation (a verify's n-gram drafts need the newest tokens on
+        the host, and _decode_spec keeps nothing in flight for a demoted
+        step to ride behind), async_scheduling off, enforce_eager;
+        drained first: no headroom or no pages behind the in-flight
+        program, or a device carry that a side door (import_kv)
+        invalidated."""
         inf = self._inflight
-        # the mixed program extends the decode carry like a 1-step
-        # window: drain any in-flight async window first, then provision
-        # decode pages for the tokens this step may write
-        events = self._materialize_pending()
-        ahead = self.cfg.num_speculative_tokens + 1 if spec else 1
-        with self.timeline.phase("page_alloc"):
-            got = self._grow_pages(ahead, events)
-        if not self.seqs:
-            # page pressure killed the whole batch: the chunk still has
-            # its reserved pages — advance it on the classic path
-            events.extend(self._advance_chunk())
-            return events
+        cfg = self.cfg
+        events: List[TokenEvent] = []
+        ride = (cfg.async_scheduling and cfg.speculative_mode == "off"
+                and not cfg.enforce_eager)
+        prev = self._pending_win
+        got = 0
+        if prev is not None and ride and self._dev_state is not None \
+                and self._window_steps(extra=prev.lag) > 0:
+            # every live slot has prev.lag + 1 tokens of headroom: pages
+            # for the one token this step writes behind prev's
+            with self.timeline.phase("page_alloc"):
+                got = self._grow_pages(1, events, offset=prev.lag,
+                                       allow_kill=False)
+        if got <= 0:
+            # nothing in flight, or nothing may run behind it: drain the
+            # pipeline, then provision as a 1-step window (a verify: K+1)
+            events.extend(self._materialize_pending())
+            prev = None
+            ahead = cfg.num_speculative_tokens + 1 if spec else 1
+            with self.timeline.phase("page_alloc"):
+                got = self._grow_pages(ahead, events)
+            if not self.seqs:
+                # page pressure (or the drain's finishes) emptied the
+                # batch: the chunk still has its reserved pages — advance
+                # it on the classic path
+                events.extend(self._advance_chunk())
+                return events
         chunk_logits = self._ragged_step(
-            events, inf, self._spec_drafts(got) if spec else None)
-        if inf.done >= inf.prompt_len:
+            events, inf, self._spec_drafts(got) if spec else None,
+            lag=prev.lag if prev is not None else 0)
+        final = inf.done >= inf.prompt_len
+        if prev is not None:
+            self.metrics.mixed_behind += 1
+            events.extend(self._materialize_window(prev))
+        if not ride or final or any(ev.finished for ev in events):
+            # a finish frees pages the step just dispatched still touches
+            # (as in _decode_async); the final chunk is read for its token
+            events.extend(self._materialize_pending())
+        if final:
             self._finish_inflight(chunk_logits,
                                   "mixed_spec" if spec else "mixed", events)
         return events
 
     def _ragged_step(self, events: List[TokenEvent],
-                     inf: Optional[InflightPrefill], drafted):
-        """Dispatch ONE program over the decode batch, read it back,
-        account for it and emit its tokens. `drafted` = _spec_drafts'
-        (drafts, room, nreal) runs every slot's verify window, None
-        advances each slot one token; `inf` rides the same program with
-        its next chunk (a plain decode without a chunk is a window:
-        _dispatch_window). The verify and the mixed-verify programs share
-        their leading operands, the chunk's trail. Returns the chunk's
-        last-row logits (None without a chunk)."""
+                     inf: Optional[InflightPrefill], drafted, lag: int = 0):
+        """Dispatch ONE program over the decode batch, on the device carry
+        as it stands (the outputs of a program still in flight `lag`
+        unread steps ahead of the host, or a rebuild from the mirrors).
+        `drafted` = _spec_drafts' (drafts, room, nreal) runs every slot's
+        verify window: read back, accounted for and emitted here. None
+        advances each slot one token beside `inf`'s next chunk (a plain
+        decode without a chunk is a window: _dispatch_window): the mixed
+        step is left in flight as the pending program, for its caller to
+        materialize now or one program late; `inf.done` advances at
+        dispatch. The verify and the mixed-verify programs share their
+        leading operands, the chunk's trail. Returns the chunk's last-row
+        logits, on the device (None without a chunk)."""
         px, chunk = (), None
         if inf is not None:
             c = self.cfg.mixed_batch_tokens
@@ -3200,7 +3269,7 @@ class Engine:
         else:
             want_lp = any(s.logprobs is not None
                           for s in self.seqs.values())
-            fn, kind = self._mixed[want_lp], "mixed"
+            fn = self._mixed[want_lp]
             args = (self.params, cur, pos, ctx_lens, active_dev,
                     self._dev_tables, *self._dev_sampling,
                     self.token_counts)
@@ -3221,25 +3290,26 @@ class Engine:
             # _dispatch_window
         self._dev_state = (cur, pos, ctx_lens, active_dev)
         slots = list(self.seqs)
-        given = lps = None
-        with self.timeline.phase("device_wait"):
-            if drafted is not None:
-                toks = np.asarray(ys[0]).T  # [K+1, B]
-                nacc_np = np.asarray(ys[1])  # [B]
-                given = nacc_np + 1
-            else:
-                toks = np.asarray(ys[0])  # [1, B]
-                if want_lp:
-                    lps = tuple(np.asarray(y) for y in ys[1:])
-        dt = time.monotonic() - t0
         if inf is not None:
             inf.done += take
-        if drafted is not None:
-            self._spec_feedback(slots, room, nreal, nacc_np)
+        if drafted is None:
+            # the kernels' counters read the contexts the rows were handed:
+            # the host's, plus the steps of the program still unread
+            self._pending_win = PendingProgram(
+                1, ys, want_lp, time.monotonic() - t0, slots,
+                self.timeline.dispatch_seq, chunk,
+                [self.seqs[s].num_tokens + lag for s in slots])
+            return chunk_logits
+        with self.timeline.phase("device_wait"):
+            toks = np.asarray(ys[0]).T  # [K+1, B]
+            nacc_np = np.asarray(ys[1])  # [B]
+            given = nacc_np + 1
+        dt = time.monotonic() - t0
+        self._spec_feedback(slots, room, nreal, nacc_np)
         # the dispatch IS this iteration's decode step — it feeds the same
         # ITL histograms (that is exactly what the mixed A/B measures)
         self._account_step(kind, dt, 1, slots, chunk=chunk, given=given)
-        self._emit_tokens(events, slots, toks, given=given, lps=lps)
+        self._emit_tokens(events, slots, toks, given=given)
         return chunk_logits
 
     def _window_steps(self, extra: int = 0) -> int:
@@ -3579,9 +3649,11 @@ class Engine:
         return events
 
     def _decode_async(self) -> List[TokenEvent]:
-        """Pipelined decode: dispatch window k+1, THEN read window k back —
-        the host sync overlaps the new window's device compute. Any finish
-        discovered in window k drains the pipeline (window k+1's tokens for
+        """Pipelined decode: dispatch window k+1, THEN read program k back —
+        the host sync overlaps the new window's device compute. Program k
+        is the pending one: a window, or a mixed step a prompt's chunk
+        left in flight (_mixed_step runs the same pipeline). Any finish
+        discovered in k drains the pipeline (window k+1's tokens for
         surviving sequences are processed in the same step; the finished
         slot's are discarded by the normal membership iteration)."""
         events: List[TokenEvent] = []
@@ -3590,7 +3662,7 @@ class Engine:
             # the device carry since dispatch: materialize before rebuilding
             events.extend(self._materialize_pending())
         prev = self._pending_win
-        lag = prev[0] if prev is not None else 0
+        lag = prev.lag if prev is not None else 0
         window = self._window_steps(extra=lag)
         if window > 0:
             with self.timeline.phase("page_alloc"):
@@ -3697,45 +3769,53 @@ class Engine:
         # is the HOST dispatch cost; the materialize side adds its own wait
         # so interleaved work (chunk prefills, scheduling) between dispatch
         # and readback isn't double-counted into decode_window.
-        self._pending_win = (window, ys, want_lp,
-                             time.monotonic() - t0, list(self.seqs),
-                             self.timeline.dispatch_seq)
+        self._pending_win = PendingProgram(
+            window, ys, want_lp, time.monotonic() - t0, list(self.seqs),
+            self.timeline.dispatch_seq)
 
     def _materialize_pending(self) -> List[TokenEvent]:
         if self._pending_win is None:
             return []
         return self._materialize_window(self._pending_win)
 
-    def _materialize_window(self, pw) -> List[TokenEvent]:
+    def _materialize_window(self, pw: PendingProgram) -> List[TokenEvent]:
+        """Read one dispatched program back, a fused window or a mixed
+        step: account for it and emit its tokens."""
         if self._pending_win is pw:
             self._pending_win = None
-        window, ys, want_lp, dispatch_s, slots, ticket = pw
         events: List[TokenEvent] = []
         t_wait = time.monotonic()
         # the stepline's drained account needs to know WHICH program this
-        # waits for: under async scheduling a newer window is in flight
-        with self.timeline.phase("device_wait", upto=ticket):
+        # waits for: under async scheduling a newer one is in flight
+        with self.timeline.phase("device_wait", upto=pw.ticket):
             # chaos: slow-but-alive readback — must NOT trip the watchdog
             # when the delay stays under the deadline
             faults.sleep_point("engine.device_slow")
-            toks = np.asarray(ys[0])  # [window, B]
+            toks = np.asarray(pw.ys[0])  # [window, B]
             # chosen [window, B], top ids and values [window, B, K]
-            lps = (tuple(np.asarray(y) for y in ys[1:]) if want_lp
+            lps = (tuple(np.asarray(y) for y in pw.ys[1:]) if pw.want_lp
                    else None)
-        dt = dispatch_s + (time.monotonic() - t_wait)
-        self._account_step("decode", dt, window, slots)
-        self._emit_tokens(events, slots, toks, lps=lps)
+        dt = pw.dispatch_s + (time.monotonic() - t_wait)
+        # a mixed step IS its iteration's decode step — it feeds the same
+        # ITL histograms (that is exactly what the mixed A/B measures)
+        self._account_step("decode" if pw.chunk is None else "mixed", dt,
+                           pw.lag, pw.slots, chunk=pw.chunk,
+                           contexts=pw.contexts)
+        self._emit_tokens(events, pw.slots, toks, lps=lps)
         return events
 
     def _account_step(self, kind: str, dt: float, steps: int, slots,
-                      chunk=None, given=None) -> None:
+                      chunk=None, given=None, contexts=None) -> None:
         """Every observation one dispatch over the decode batch owes: it
         took `dt`, advanced the device `steps` decode steps over `slots`,
         carried `chunk` = (start, take) of the inflight prompt if any, and
         a verify gave slot s `given[s]` tokens. `decode_step` votes once
         per step advanced, so verifies, fused windows and mixed steps
         carry proportional votes in the shared histogram: a verify counts
-        the steps it advanced its slots on average."""
+        the steps it advanced its slots on average. `contexts` = what the
+        kernels' counters take the slots' contexts to be: a mixed step's
+        as taken at its dispatch, else the host's as they stand (a
+        program is accounted for before its tokens are emitted)."""
         m = self.metrics
         m.decode_steps += steps
         m.decode_time_s += dt
@@ -3756,8 +3836,9 @@ class Engine:
                           len(slots) if self._ssm_live_only
                           else self.cfg.max_num_seqs)
         if self.model_cfg.is_mla or kinds:  # read by the kernels' rooflines
-            contexts = [self.seqs[s].num_tokens for s in slots
-                        if s in self.seqs]
+            if contexts is None:
+                contexts = [self.seqs[s].num_tokens for s in slots
+                            if s in self.seqs]
             # a hybrid model's sliding_window is 0: its full layers alone
             w = self.model_cfg.sliding_window if kinds else None
             if chunk is not None:

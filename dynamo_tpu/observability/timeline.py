@@ -28,7 +28,8 @@ host time no instrumented phase claimed.
 Separately, each ``dispatch`` entry samples the **inter-dispatch host
 gap** — wall time between device program N returning control and
 program N+1 launching (clamped at 0: async scheduling legitimately
-dispatches window N+1 before materializing window N).  This is the
+dispatches program N+1, a decode window or a mixed step that carries a
+prompt's chunk, before materializing program N).  This is the
 number the zero-bubble PR must drive to ~0; it exports as
 ``dynamo_engine_host_gap_seconds`` and the per-phase digests ride the
 existing ``dynamo_engine_phase_seconds{phase}`` histogram as additional
@@ -68,8 +69,10 @@ otherwise the kind of the OLDEST unfinished program, declared at its
 window, a verify; also what an undeclared dispatch is) or ``prompt`` (it
 carries prompt tokens: a mixed step, a chunk, a prefill).  A ticket keeps
 its kind until a ``device_wait`` proves it finished, so this is the host's
-knowledge: under async scheduling the device may already run window k+1
-when the host learns that k is done; both are ``decode``.  A sequence
+knowledge: under async scheduling the device may already run program k+1
+when the host learns that k is done.  Two windows are both ``decode``; a
+mixed step dispatched behind window k waits as ``decode`` until k is read
+and as ``prompt`` from then on, with nothing drained between.  A sequence
 holds a `TokenWait` from its first token on (`token_start`); at every
 emission `token_gap` puts the causes' growth since its last one into
 ``token_time.row_s``, counts its tokens in ``token_time.gaps`` and keeps

@@ -17,6 +17,7 @@ from dynamo_tpu.models import llama
 from dynamo_tpu.models.reference import deepseek_v32 as ref
 
 from tests.deepseek_v32_common import PS, TOPK, ref_config, tapped, tiny
+from tests.pipelined_common import assert_pipelined_matches_sync
 
 
 def _engine(**kw):
@@ -86,6 +87,17 @@ def test_engine_matches_reference_and_counts():
     assert d["rows_selected"] == (q - TOPK) * TOPK + TOPK * (TOPK + 1) // 2
     assert d["decode_keys_scored"] >= 43 * d["decode_queries"]
     assert d["chunk_keys_scored"] > d["rows_selected"] - d["decode_queries"] * TOPK
+
+
+def test_mixed_steps_behind_the_pipeline_match_the_synchronous_order():
+    """A 43-token prompt's three chunks, each dispatched on the device
+    outputs of the program before it; the decode rows select over the live
+    slots' rung at contexts the host has not read yet: tokens and
+    `metrics.dsa` / `metrics.attn` are the synchronous order's."""
+    assert_pipelined_matches_sync(
+        _engine(async_scheduling=False, enable_prefix_caching=False),
+        _engine(enable_prefix_caching=False),
+        _req("live", [50, 51, 52], n=28), _req("late", [60, 61, 62], n=9))
 
 
 def test_metrics_dsa_arithmetic():

@@ -21,6 +21,7 @@ from dynamo_tpu.models.reference import falcon_h1 as ref
 from dynamo_tpu.observability.memory import MemoryAccountant
 
 from falcon_h1_common import hf_dict, tiny
+from pipelined_common import assert_pipelined_matches_sync
 
 CFG = dict(model="tiny-falcon-h1-debug", page_size=4, num_pages=128,
            max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
@@ -102,6 +103,24 @@ def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
     assert counters["admit_blocked"] == {"state_slots": 0, "pages": 0}
     assert eng.metrics.snapshot()["admit_blocked"] == counters[
         "admit_blocked"]
+
+
+def test_mixed_steps_behind_the_pipeline_match_the_synchronous_order(engine):
+    """A 30-token prompt's four chunks, each dispatched on the device
+    outputs of the program before it (pages AND every layer's state slots
+    are that program's results): tokens, `metrics.ssm` and
+    `metrics.attn_kinds` are the synchronous order's."""
+    sync = Engine(EngineConfig(**CFG, async_scheduling=False))
+    got = assert_pipelined_matches_sync(
+        sync, engine,
+        GenRequest("live", prompt(11, 13), max_tokens=28, temperature=0.0,
+                   ignore_eos=True),
+        GenRequest("late", prompt(12, 30), max_tokens=9, temperature=0.0,
+                   ignore_eos=True))
+    assert engine.metrics.ssm["chunk_calls"] == 4 + 2  # late's, live's
+    late = prompt(12, 30) + got["late"]
+    assert got["late"] == reference_greedy(engine, late, 9)
+    assert slots_held(engine) == 0
 
 
 def test_a_prefix_hit_is_counted_inexact_and_served_by_recompute(engine):

@@ -18,6 +18,7 @@ from dynamo_tpu.engine.request import GenRequest
 from dynamo_tpu.models.reference import laguna_s as ref
 from dynamo_tpu.observability.memory import MemoryAccountant
 
+from pipelined_common import assert_pipelined_matches_sync
 from test_laguna import hf_dict, tiny
 
 CFG = dict(model="tiny-laguna-debug", page_size=4, num_pages=128,
@@ -90,6 +91,23 @@ def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
             < kinds["full"]["decode_kv_rows"])
     assert 0 < kinds["window"]["mixed_chunk_kv_pairs"] <= kinds["full"][
         "mixed_chunk_kv_pairs"]
+
+
+def test_mixed_steps_behind_the_pipeline_match_the_synchronous_order(engine):
+    """A 30-token prompt's four chunks, each dispatched on the device
+    outputs of the program before it, while the decoding row's ring (6
+    pages, written over) keeps turning: tokens and `metrics.attn_kinds` of
+    both kinds are the synchronous order's."""
+    sync = Engine(EngineConfig(**CFG, async_scheduling=False))
+    got = assert_pipelined_matches_sync(
+        sync, engine,
+        GenRequest("live", prompt(11, 29), max_tokens=28, temperature=0.0,
+                   ignore_eos=True),
+        GenRequest("late", prompt(12, 30), max_tokens=9, temperature=0.0,
+                   ignore_eos=True))
+    late = prompt(12, 30) + got["late"]
+    assert got["late"] == reference_greedy(engine, late, 9)
+    assert engine.win_rings.pages_held() == 0
 
 
 def test_a_prefix_hit_is_served_only_where_exact(engine):

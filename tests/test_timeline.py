@@ -223,6 +223,50 @@ def test_engine_run_conserves_step_wall_time():
     assert summ["untracked_s"] >= 0.0
 
 
+def test_mixed_steps_behind_the_pipeline_open_no_drained_interval():
+    """window -> mixed -> mixed -> final on a real engine: every mixed step
+    is dispatched while a program is unfinished, so no drained interval
+    opens before any of the three; one opens at the final chunk's read."""
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import Engine
+    from dynamo_tpu.engine.request import GenRequest
+
+    eng = Engine(EngineConfig(**KW, num_scheduler_steps=4,
+                              prefill_chunk_tokens=8, mixed_batch_tokens=8))
+    tl = eng.timeline
+    eng.add_request(GenRequest("live", [1, 2, 3], max_tokens=40,
+                               temperature=0.0, ignore_eos=True))
+    for _ in range(3):
+        eng.step()
+    eng.add_request(GenRequest("long", list(range(1, 23)), max_tokens=4,
+                               temperature=0.0, ignore_eos=True))
+    seen = []
+    ragged = eng._ragged_step
+
+    def watched(events, inf, drafted, lag=0):
+        # (interval open?, closed so far, steps unread) at the dispatch
+        seen.append((tl._drained, tl.drained_count, lag))
+        return ragged(events, inf, drafted, lag=lag)
+
+    eng._ragged_step = watched
+    count0 = tl.drained_count
+    while eng._inflight is not None or eng.pending:
+        eng.step()
+    assert [d for d, _, _ in seen] == [False] * 3
+    assert [n for _, n, _ in seen] == [count0] * 3
+    assert [lag for _, _, lag in seen] == [4, 1, 1]  # a window, two chunks
+    assert (eng.metrics.mixed_behind, eng.metrics.mixed_count) == (3, 3)
+    assert tl._drained and tl.drained_count == count0  # open, not closed
+    eng.step()  # the next window's dispatch closes it
+    assert not tl._drained and tl.drained_count == count0 + 1
+    while eng.has_work:
+        eng.step()
+    summ = tl.summary()
+    # the summary rounds each cause to a microsecond
+    assert sum(summ["token_time"]["cause_s"].values()) == pytest.approx(
+        summ["loop_wall_s"], abs=1e-4)
+
+
 # ---------------------------------------------------------------------------
 # Perfetto export
 # ---------------------------------------------------------------------------
@@ -490,6 +534,31 @@ _DRAINED_CASES = {
          ("enter", "detok", 0.002), ("exit", None, 0.0),
          ("commit", None, 0.0)],
         {"detok": 0.005}, 0),
+    # mixed steps ride the pipeline: a window, then a prompt's three chunks
+    # each dispatched BEFORE the program ahead of it is waited for; only
+    # the final chunk's own readback (its logits give the first token)
+    # leaves the device drained, up to the next window's dispatch
+    "mixed_steps_behind_the_pipeline": (
+        [("begin", None, 0.0), ("enter", "dispatch", 0.002),
+         ("exit", None, 0.0), ("commit", None, 0.0005)]
+        + [op for ticket in (1, 2) for op in (
+            ("begin", None, 0.0), ("enter", "page_alloc", 0.001),
+            ("exit", None, 0.0), ("enter", "dispatch", 0.003),
+            ("exit", None, 0.0),
+            ("enter", ("device_wait", ticket), 0.010), ("exit", None, 0.0),
+            ("enter", "detok", 0.002), ("exit", None, 0.0),
+            ("commit", None, 0.0005))]
+        + [("begin", None, 0.0), ("enter", "dispatch", 0.003),
+           ("exit", None, 0.0),
+           ("enter", ("device_wait", 3), 0.010), ("exit", None, 0.0),
+           ("enter", "detok", 0.002), ("exit", None, 0.0),
+           ("enter", ("device_wait", 4), 0.012), ("exit", None, 0.0),
+           ("enter", "detok", 0.001), ("exit", None, 0.0),
+           ("enter", "device_wait", 0.0015), ("exit", None, 0.0),
+           ("enter", "admit", 0.0005), ("exit", None, 0.0),
+           ("enter", "dispatch", 0.002), ("exit", None, 0.0),
+           ("commit", None, 0.0)],
+        {"detok": 0.001, "admit": 0.0005, "dispatch": 0.002}, 1),
     # a device_wait inside a drained interval (first-token sampling is an
     # implicit program) is not drained time; the interval goes on after it
     "a_wait_while_drained_is_left_out": (
@@ -686,6 +755,24 @@ _CAUSE_CASES = {
         + [("enter", "detok", 0.0005), ("exit", None, 0.0),
            ("commit", None, 0.0)],
         {"drained": 0.002 + 0.001 + 0.0005, "prompt": 0.030 + 0.0015}),
+    # a mixed step dispatched behind the pending window, the window read
+    # afterwards (Engine._mixed_step under async scheduling): the window's
+    # wait is `decode`, what follows behind the chunk is `prompt`, and
+    # nothing is drained until the chunk's own readback
+    "a_mixed_step_behind_a_pending_window": (
+        [("loop", "between_steps", 0.0), ("begin", None, 0.0)]
+        + _dispatch("decode") + [("commit", None, 0.0005),
+                                 ("begin", None, 0.0),
+                                 ("enter", "page_alloc", 0.001),
+                                 ("exit", None, 0.0)]
+        + _dispatch("prompt", 0.004) + _wait(1, 0.004)
+        + [("enter", "detok", 0.002), ("exit", None, 0.0)]
+        + _wait(2, 0.024)
+        + [("enter", "detok", 0.002), ("exit", None, 0.0),
+           ("commit", None, 0.0)],
+        {"drained": 0.002 + 0.002,
+         "decode": 0.0005 + 0.001 + 0.004 + 0.004,
+         "prompt": 0.002 + 0.024}),
     # the OLDEST unfinished program decides: a chunk dispatched behind a
     # window waits as `decode` until the window is proved done, a window
     # behind a chunk as `prompt`; a wait on the newest ends both

@@ -49,9 +49,6 @@ KNOWN_ENV: Dict[str, str] = {
     "DRAIN_TIMEOUT_S":
         "worker SIGTERM drain budget: admission off, in-flight handoff, "
         "KV demote (operator aligns terminationGracePeriodSeconds)",
-    "DYNAMO_TPU_ATTN_BACKEND":
-        "attention backend: auto / xla / pallas / pallas_interpret "
-        "(auto = Pallas on TPU, XLA elsewhere)",
     "DYNAMO_TPU_BATCH_BURN_ADMIT":
         "preemptible batch tier: batch-class tenants admit only while "
         "every interactive fast-window SLO burn is below this "
@@ -68,9 +65,6 @@ KNOWN_ENV: Dict[str, str] = {
     "DYNAMO_TPU_CHIP":
         "TPU chip generation override (v4/v5e/v5p/v6e) for utilization "
         "denominators in engine metrics",
-    "DYNAMO_TPU_CHUNK_ATTENTION":
-        "chunked-prefill attention backend override (wins over "
-        "hardware-validation gating)",
     "DYNAMO_TPU_COORDINATOR":
         "multi-host: JAX coordinator address host:port",
     "DYNAMO_TPU_DEADLINE_S":
@@ -135,9 +129,6 @@ KNOWN_ENV: Dict[str, str] = {
     "DYNAMO_TPU_QUARANTINE_WINDOW_S":
         "watchdog: a second trip within this many seconds of the first "
         "quarantines the engine permanently (default 300)",
-    "DYNAMO_TPU_RAGGED_ATTENTION":
-        "mixed ragged prefill+decode attention backend override (wins "
-        "over hardware-validation gating)",
     "DYNAMO_TPU_RECLAIM_DEADLINE_S":
         "default hard drain deadline (seconds) for a /internal/reclaim "
         "notice that carries none (align with the spot pool's advertised "

@@ -43,9 +43,6 @@ class EngineConfig:
     # data_parallel == expert_parallel == 1; decode stays paged on the
     # (seq x model) mesh via GSPMD. Beyond reference parity (SURVEY §5).
     sequence_parallel: int = 1
-    # MoE prefill dispatch: 0 = exact dense-masked; > 0 enables the
-    # capacity-gather path with this capacity factor (ops/moe.py)
-    moe_capacity_factor: float = 0.0
 
     # disaggregation (NIXL-contract mirror)
     disaggregation_mode: str = "agg"  # agg | prefill | decode
@@ -246,7 +243,6 @@ class EngineConfig:
         p.add_argument("--ep", type=int, default=1)
         p.add_argument("--sp", "--sequence-parallel", type=int, default=1,
                        dest="sp")
-        p.add_argument("--moe-capacity-factor", type=float, default=0.0)
         p.add_argument("--num-scheduler-steps", type=int, default=1)
         import os as _os
 
@@ -385,7 +381,6 @@ class EngineConfig:
             data_parallel=args.dp,
             expert_parallel=args.ep,
             sequence_parallel=getattr(args, "sp", 1),
-            moe_capacity_factor=args.moe_capacity_factor,
             num_scheduler_steps=args.num_scheduler_steps,
             speculative_mode=getattr(args, "speculative_mode", "off"),
             num_speculative_tokens=getattr(args, "num_speculative_tokens", 4),

@@ -622,10 +622,6 @@ class Engine:
             model_cfg = ModelConfig.from_model_name(
                 cfg.model_path or cfg.model, dtype=cfg.dtype or default_dtype
             )
-        if cfg.moe_capacity_factor > 0:
-            model_cfg = dataclasses.replace(
-                model_cfg, moe_capacity_factor=cfg.moe_capacity_factor
-            )
         self.model_cfg = model_cfg
         if model_cfg.is_dsa and (cfg.speculative_mode != "off"
                                  or cfg.sequence_parallel > 1):
@@ -1399,7 +1395,7 @@ class Engine:
         # Bind this engine's attention backend + mesh around every call
         # (traces happen inside the first call, so the kernel selection and
         # shard_map mesh are baked per-engine — not via process globals).
-        backend = None if cfg.attention_backend == "auto" else cfg.attention_backend
+        backend = cfg.attention_backend
         mesh = self.mesh
         lane_blocks = self.kv_spec.lane_blocks
         # whether a hybrid model's state updates walk the live slots only

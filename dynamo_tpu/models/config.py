@@ -464,12 +464,6 @@ class ModelConfig:
     # MoE (mixtral/deepseek-style). num_experts == 0 -> dense MLP.
     num_experts: int = 0
     num_experts_per_tok: int = 2
-    # capacity factor for the prefill dispatch path (ops/moe.py). 0 (default)
-    # = exact dense-masked dispatch everywhere; > 0 enables the capacity-based
-    # gather for prefill-sized batches (~X/k fewer expert-MLP FLOPs), where
-    # tokens past an expert's capacity drop that expert — a throughput/
-    # fidelity trade the operator opts into per deployment
-    moe_capacity_factor: float = 0.0
     # DeepSeek-style SHARED experts: always-active dense experts added to
     # the routed top-k output (each of width intermediate_size)
     num_shared_experts: int = 0
@@ -746,7 +740,7 @@ class ModelConfig:
                 or self.post_norms or self.attn_logit_softcapping
                 or self.num_local_experts or self.n_group > 1
                 or self.rms_norm_unit_offset or self.embed_scale
-                or self.tie_word_embeddings or self.moe_capacity_factor):
+                or self.tie_word_embeddings):
             raise ValueError(
                 "mixer_types is served in two forms: every layer ONE mixer "
                 "(Mamba-2 | two-matrix experts, all held | plain GQA "

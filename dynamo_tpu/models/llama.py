@@ -878,14 +878,13 @@ def _chunk_views(cfg: ModelConfig, pages: ByKind, start, c: int,
     return out
 
 
-def _kind_layer_tail(cfg: ModelConfig, lp: Params, x, h, o, token_mask,
-                     allow_capacity: bool = False):
+def _kind_layer_tail(cfg: ModelConfig, lp: Params, x, h, o, token_mask):
     """What follows attention in a layer of a model of kinds: the gated
     output projection on the residual, then the MLP or expert layer.
     Returns (x, the expert layer's counts)."""
     x = x + _attn_out(cfg, lp, o, gate_in=h)
     y, counts = _mlp(cfg, lp, rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps),
-                     token_mask=token_mask, allow_capacity=allow_capacity)
+                     token_mask=token_mask)
     return x + y, counts
 
 
@@ -939,15 +938,11 @@ def _attn_out(cfg: ModelConfig, lp: Params, o: jax.Array,
 
 
 def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
-         token_mask: jax.Array | None = None,
-         allow_capacity: bool = False):
+         token_mask: jax.Array | None = None):
     """SwiGLU MLP or MoE block. x: [T, E]; token_mask: [T] bool, False for
-    padding rows (prefill pads to a page multiple). The capacity-gather MoE
-    path is prefill-only (allow_capacity): decode batches contain inactive
-    slots with no mask to exclude them, and are small enough that dense
-    dispatch wins anyway. Returns (y [T, E], counts): what the grouped
-    expert layer counted (moe_ops.MOE_STATS, int32), None on every other
-    path."""
+    padding rows (prefill pads to a page multiple). Returns (y [T, E],
+    counts): what the grouped expert layer counted (moe_ops.MOE_STATS,
+    int32), None on every other path."""
     def dense(x):
         if cfg.expert_act:  # two matrices, no gate (hybrid models)
             u = qeinsum("te,ef->tf", x, lp["w_up"])
@@ -967,14 +962,13 @@ def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
             shared = dense(x)
     else:
         shared = 0.0
-    # MoE: top-k routing, then one of three dispatch paths
-    # (dynamo_tpu.ops.moe). Grouped matmuls over each expert's own tokens
+    # MoE: top-k routing, then one of two exact dispatch paths
+    # (dynamo_tpu.ops.moe): grouped matmuls over each expert's own tokens
     # where a token picks few of many experts, or this chip holds a share
     # of a wider router (cfg.moe_grouped, decided from shapes); else
-    # exact dense-masked dispatch through a [T, X] combine matrix, or the
-    # capacity-based gather (T*k*cf expert-MLP rows instead of T*X) when
-    # the deployment opts in via moe_capacity_factor > 0. All partition
-    # over the `expert` mesh axis via the sharding rules on moe_w_*.
+    # dense-masked dispatch through a [T, X] combine matrix. Both
+    # partition over the `expert` mesh axis via the sharding rules on
+    # moe_w_*.
     with jax.named_scope("moe_router"):
         logits = jnp.einsum("te,ex->tx", x, lp["router"],
                             preferred_element_type=jnp.float32)
@@ -985,16 +979,7 @@ def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
             scoring=cfg.moe_scoring,
             select_bias=lp.get("router_bias"),
             n_group=cfg.n_group, topk_group=cfg.topk_group)
-    t = x.shape[0]
-    capacity = 0
-    if allow_capacity and cfg.moe_capacity_factor > 0:
-        capacity = moe_ops.expert_capacity(
-            t, cfg.num_experts, cfg.num_experts_per_tok,
-            cfg.moe_capacity_factor,
-        )
-        if capacity >= t or cfg.held_experts != cfg.num_experts:
-            capacity = 0  # gather only pays off when capacity cuts rows
-    if cfg.moe_grouped and not capacity:
+    if cfg.moe_grouped:
         y, stats = moe_ops.moe_mlp_grouped(
             x, topi, weights, lp.get("moe_w_gate"), lp["moe_w_up"],
             lp["moe_w_down"], expert_offset=cfg.local_expert_offset,
@@ -1004,13 +989,8 @@ def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
     combine = moe_ops.scatter_combine(topi, weights, cfg.num_experts,
                                       x.dtype)
     if token_mask is not None:
-        # padding rows must not claim expert capacity (nor compute)
+        # padding rows weigh nothing in any expert
         combine = combine * token_mask.astype(combine.dtype)[:, None]
-    if capacity:
-        return shared + moe_ops.moe_mlp_dropping(
-            x, combine, lp["moe_w_gate"], lp["moe_w_up"],
-            lp["moe_w_down"], capacity=capacity,
-        ), None
     return shared + moe_ops.moe_mlp_dense(
         x, combine, lp.get("moe_w_gate"), lp["moe_w_up"], lp["moe_w_down"],
         act=cfg.expert_act), None
@@ -1548,7 +1528,7 @@ def prefill(
                    else pages.window[:s // page_size])
             kp, vp = att.write_kv_prefill(
                 kp, vp, k, v, own + page_off, page_size=page_size)
-            x, counts = _kind_layer_tail(cfg, lp, x, h, o, token_mask, True)
+            x, counts = _kind_layer_tail(cfg, lp, x, h, o, token_mask)
             return x, kp, vp, counts
         q, k, v = _qkv(cfg, lp, h, positions,
                        rope=_layer_rope(cfg, page_off,
@@ -1575,8 +1555,7 @@ def prefill(
                 kp, vp, k, v, pages + page_off, page_size=page_size
             )
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-        y, counts = _mlp(cfg, lp, h,
-                         token_mask=token_mask, allow_capacity=True)
+        y, counts = _mlp(cfg, lp, h, token_mask=token_mask)
         x = x + _post(cfg, lp, "post_mlp_norm", y)
         return x, kp, vp, counts
 
@@ -1641,7 +1620,7 @@ def prefill_chunk(
                 o = att.chunk_attention(
                     q, kp, vp, table + page_off, wstart, page_size=page_size,
                     num_kv_heads=cfg.cache_kv_heads, **akw)
-            x, counts = _kind_layer_tail(cfg, lp, x, h, o, token_mask, True)
+            x, counts = _kind_layer_tail(cfg, lp, x, h, o, token_mask)
             return x, kp, vp, counts
         q, k, v = _qkv(cfg, lp, h, positions,
                        rope=_layer_rope(cfg, page_off,
@@ -1665,8 +1644,7 @@ def prefill_chunk(
         x = x + _post(cfg, lp, "post_attn_norm",
                       _attn_out(cfg, lp, o, lora_slots=slots))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-        y, counts = _mlp(cfg, lp, h,
-                         token_mask=token_mask, allow_capacity=True)
+        y, counts = _mlp(cfg, lp, h, token_mask=token_mask)
         x = x + _post(cfg, lp, "post_mlp_norm", y)
         return x, kp, vp, counts
 
@@ -1760,8 +1738,7 @@ def prefill_batch(
                 page_size=page_size
             )
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-        y, counts = _mlp(cfg, lp, h,
-                         token_mask=token_mask, allow_capacity=True)
+        y, counts = _mlp(cfg, lp, h, token_mask=token_mask)
         x = x + _post(cfg, lp, "post_mlp_norm", y)
         return x, kp, vp, counts
 
@@ -1992,9 +1969,8 @@ def mixed_step(
     own pages (prefix-cached pages are read-only full pages, and chunk
     starts are page-aligned, so a shared prefix is never rewritten).
 
-    MoE note: dispatch uses decode semantics (dense, no capacity gather)
-    for ALL rows — capacity dropping keys on batch composition, which
-    would break mixed-vs-separate token identity.
+    MoE note: both expert paths are exact a row, so a row's result does
+    not depend on the batch it rides in (mixed-vs-separate token identity).
     """
     b = tokens.shape[0]
     c = chunk_tokens.shape[0]

@@ -7,13 +7,14 @@ reference path (CPU tests, fallback) and the Pallas TPU kernels in
 defaults to 16 for parity with the reference's SGLang flag `--page-size 16`
 (/root/reference/examples/deploy/sglang/agg.yaml:38-39).
 
-Backend selection: `set_attention_backend()` or env `DYNAMO_TPU_ATTN_BACKEND`
-in {auto, xla, pallas, pallas_interpret}; `auto` uses Pallas on TPU and XLA
-elsewhere. The engine scopes backend + mesh per call via the
-`attention_context()` contextvar (set_attention_backend/set_attention_mesh
-only set the process-global fallback for code outside an engine). Under
-tensor parallelism the Pallas path runs inside `shard_map` over the
-(`data`, `model`) axes — attention is head-parallel, so no collectives.
+Backend selection: the engine scopes (backend, mesh, int8 lane blocking)
+around every jit call with `attention_context()`, from
+`EngineConfig.attention_backend` (`--attention-backend` in {auto, xla,
+pallas, pallas_interpret}; `auto` is Pallas on a TPU and XLA elsewhere):
+there is no other way to choose. What an op's trace then runs, and over
+which mesh, is decided once, by `_route` (the dispatch section): under
+tensor parallelism the kernels run inside `shard_map` over the (`data`,
+`model`) axes; attention is head-parallel, so no collectives.
 
 Layout (page-major, fused heads — one page is one contiguous DMA-able slab):
   k_pages, v_pages: [num_pages, page_size, num_kv_heads * head_dim]
@@ -30,7 +31,7 @@ import contextlib
 import contextvars
 import functools
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,9 +45,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 _ATTN_CTX: contextvars.ContextVar = contextvars.ContextVar(
     "dynamo_tpu_attn_ctx", default=(None, None, 1)
 )
-
-_BACKEND: Optional[str] = None  # process-wide override (tests, ad-hoc use)
-_MESH: Optional[Mesh] = None
 
 _VALID_BACKENDS = ("auto", "xla", "pallas", "pallas_interpret")
 _KERNEL_BACKENDS = ("pallas", "pallas_interpret")
@@ -66,58 +64,33 @@ def attention_context(backend: Optional[str], mesh: Optional[Mesh],
         _ATTN_CTX.reset(token)
 
 
-def set_attention_backend(name: Optional[str]) -> None:
-    """Process-wide backend override (None reverts to env/auto resolution)."""
-    global _BACKEND
-    if name is not None and name not in _VALID_BACKENDS:
-        raise ValueError(f"backend {name!r} not in {_VALID_BACKENDS}")
-    _BACKEND = name
-
-
-def set_attention_mesh(mesh: Optional[Mesh]) -> None:
-    """Process-wide mesh override so Pallas kernels run under shard_map."""
-    global _MESH
-    _MESH = mesh
-
-
 def _resolve_backend() -> str:
-    ctx_backend = _ATTN_CTX.get()[0]
-    b = ctx_backend or _BACKEND or os.environ.get("DYNAMO_TPU_ATTN_BACKEND", "auto")
-    if b not in _VALID_BACKENDS:
-        raise ValueError(f"DYNAMO_TPU_ATTN_BACKEND {b!r} not in {_VALID_BACKENDS}")
+    b = _ATTN_CTX.get()[0] or "auto"
     if b == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "xla"
     return b
 
 
-def _scoped_mesh() -> Optional[Mesh]:
-    ctx_mesh = _ATTN_CTX.get()[1]
-    return ctx_mesh if ctx_mesh is not None else _MESH
+def _axis_size(mesh: Optional[Mesh], axis: str) -> int:
+    return 1 if mesh is None else mesh.shape.get(axis, 1)
 
 
 def _seq_parallel_mesh() -> Optional[Mesh]:
     """The scoped mesh when it carries a real `seq` (context-parallel) axis."""
-    mesh = _scoped_mesh()
-    if mesh is None:
-        return None
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    return mesh if sizes.get("seq", 1) > 1 else None
+    mesh = _ATTN_CTX.get()[1]
+    return mesh if _axis_size(mesh, "seq") > 1 else None
 
 
 def _mesh_for_shard_map() -> Optional[Mesh]:
-    """The scoped (or global) mesh, when any axis actually needs sharding.
+    """The scoped mesh, when any axis actually needs sharding.
 
     Long-context ("seq") meshes are excluded — those route through
     dynamo_tpu.ops.ring_attention before backend dispatch, and the paged
     decode specs only know the (data, model) axes.
     """
-    if _seq_parallel_mesh() is not None:
-        return None
-    mesh = _scoped_mesh()
-    if mesh is None:
-        return None
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    if sizes.get("model", 1) == 1 and sizes.get("data", 1) == 1:
+    mesh = _ATTN_CTX.get()[1]
+    if _axis_size(mesh, "seq") > 1 or (
+            _axis_size(mesh, "model") == 1 and _axis_size(mesh, "data") == 1):
         return None
     return mesh
 
@@ -418,40 +391,21 @@ def chunk_attention(
     Two implementations:
     - XLA: the gather feeds a masked-softmax attention; simple, correct
       everywhere, but materializes [H, C, S] scores per layer.
-    - Pallas flash (default on TPU for bf16 pools since the round-5 on-chip
-      parity pass; DYNAMO_TPU_CHUNK_ATTENTION overrides): the decode
-      kernel's superblock DMA ring with a query BLOCK per grid row — no
-      score materialization, each KV byte fetched once per query block.
-      The int8-KV dequant-in-chunk path stays env-opt-in until its own
-      on-chip parity case passes (CHUNK_KERNEL_INT8_HW_VALIDATED).
+    - Pallas flash (bf16 pools): the decode kernel's superblock DMA ring
+      with a query BLOCK per grid row — no score materialization, each KV
+      byte fetched once per query block. The int8-KV dequant-in-chunk path
+      has never run on the chip: it stays behind
+      CHUNK_KERNEL_INT8_HW_VALIDATED, a counted demotion.
     """
-    # Selection: the DYNAMO_TPU_CHUNK_ATTENTION env var wins when set;
-    # otherwise it follows _resolve_backend() like the decode/prefill ops.
-    # Every route from a resolved kernel to the XLA path goes through
-    # _demote, so it is counted and logged.
-    op = "chunk attention"
-    backend = os.environ.get("DYNAMO_TPU_CHUNK_ATTENTION")
-    if not backend:
-        from dynamo_tpu.ops import pallas_attention as _pa
+    from dynamo_tpu.ops import pallas_attention as pa
 
-        backend = _resolve_backend()
-        if not _pa.CHUNK_KERNEL_HW_VALIDATED:
-            backend = _demote(backend, op, "not_validated",
-                              "CHUNK_KERNEL_HW_VALIDATED is False")
-        # the on-chip parity case that flipped the flag ran bf16 pages;
-        # int8 dequant-in-chunk has its own gate
-        if k_pages.dtype == jnp.int8 \
-                and not _pa.CHUNK_KERNEL_INT8_HW_VALIDATED:
-            backend = _demote(
-                backend, op, "int8_not_validated",
-                "int8 dequant-in-chunk awaits its on-chip parity verdict; "
-                "set DYNAMO_TPU_CHUNK_ATTENTION=pallas to force")
-    if _traced_window(window) or logit_cap:
-        backend = _demote(backend, op, "window_softcap")
-    if _seq_parallel_mesh() is not None:
-        # see the decode dispatch's seq-mesh note
-        backend = _demote(backend, op, "seq_mesh")
-    if backend in _KERNEL_BACKENDS and isinstance(window, int) and window:
+    route = _route(
+        "chunk attention", q.shape[1], q.shape[2],
+        _pool_kv_heads(k_pages, q.shape[2], num_kv_heads), k_pages,
+        window=window, logit_cap=logit_cap,
+        int8_validated=pa.CHUNK_KERNEL_INT8_HW_VALIDATED,
+        static_window_is_ragged=True)
+    if route is None:
         # a static window: the ragged kernel with no decode row is the
         # chunk kernel that masks below it (one windowed chunk kernel,
         # not two)
@@ -459,52 +413,20 @@ def chunk_attention(
             q, k_pages, v_pages, jnp.zeros((0, pages.shape[0]), jnp.int32),
             jnp.zeros((0,), jnp.int32), pages, start, page_size=page_size,
             num_kv_heads=num_kv_heads, num_decode=0, window=window)
-    if backend in _KERNEL_BACKENDS:
-        quantized = k_pages.dtype == jnp.int8
-        n_kv = _pool_kv_heads(k_pages, q.shape[2], num_kv_heads)
-        lb = _kv_lane_blocks() if quantized else 1
-        mesh = _mesh_for_shard_map()
-        tp = _mesh_tp(mesh)
-        span = n_kv * q.shape[2] if quantized else k_pages.shape[2]
-        aligned = (
-            _pallas_head_gate(q.shape[1], n_kv, tp, "chunk attention")
-            and _pallas_lane_gate(span, tp, "chunk attention")
-        )
-        if quantized and lb != max(tp, 1):
-            # the kernel reads single-block rows (see decode dispatch)
-            _note_fallback(
-                "chunk attention", "int8_lane_blocks",
-                f"mesh TP ({tp}) != pool lane blocking ({lb})")
-            aligned = False
-        if aligned:
-            from dynamo_tpu.ops import pallas_attention as pa
+    if not route.kernel:
+        return chunk_attention_xla(
+            q, k_pages, v_pages, pages, start, page_size=page_size,
+            num_kv_heads=num_kv_heads, window=window, logit_cap=logit_cap)
 
-            interp = backend == "pallas_interpret"
-            n_kv_call = n_kv // max(tp, 1)
-            _note_impl(op, backend)
+    def call(q, kp, vp, pg, st):
+        return pa.chunk_prefill_attention(
+            q, kp, vp, pg, st, page_size=page_size,
+            num_kv_heads=route.kv_heads, interpret=route.interpret)
 
-            def call(q, kp, vp, pg, st):
-                return pa.chunk_prefill_attention(
-                    q, kp, vp, pg, st, page_size=page_size,
-                    num_kv_heads=n_kv_call,
-                    interpret=interp,
-                )
-
-            st = jnp.asarray(start, jnp.int32)
-            if mesh is None:
-                return call(q, k_pages, v_pages, pages, st)
-            return jax.shard_map(
-                call,
-                mesh=mesh,
-                in_specs=(P(None, "model", None), P(None, None, "model"),
-                          P(None, None, "model"), P(None), P()),
-                out_specs=P(None, "model", None),
-                check_vma=False,
-            )(q, k_pages, v_pages, pages, st)
-    _note_impl(op, "xla")
-    return chunk_attention_xla(
-        q, k_pages, v_pages, pages, start, page_size=page_size,
-        num_kv_heads=num_kv_heads, window=window, logit_cap=logit_cap)
+    return _sharded(
+        route, call,
+        (q, k_pages, v_pages, pages, jnp.asarray(start, jnp.int32)),
+        (_HEADS, _POOL, _POOL, P(None), P()))
 
 
 def chunk_attention_xla(
@@ -572,76 +494,28 @@ def ragged_mixed_attention(
     `paged_attention_decode`: it does nothing for a row at context 0 (no
     page copy, zeros out); the XLA composition keeps `context_lens`.
 
-    Dispatch mirrors chunk_attention: DYNAMO_TPU_RAGGED_ATTENTION wins when
-    set; otherwise the scoped backend selects (`auto` on a TPU: the Pallas
-    kernel, whose work follows the live KV; elsewhere the XLA composition —
-    decode gather + chunk gather over the whole table). The same head/lane
-    gates guard the kernel, with demotions counted via _note_fallback.
+    On a kernel backend (`auto` on a TPU) the Pallas kernel runs, whose
+    work follows the live KV; else the XLA composition — decode gather +
+    chunk gather over the whole table.
     """
-    backend = _ragged_backend(window, logit_cap)
     n_kv = _pool_kv_heads(k_pages, q.shape[2], num_kv_heads)
+    route = _route("ragged attention", q.shape[1], q.shape[2], n_kv,
+                   k_pages, window=window, logit_cap=logit_cap)
     b = num_decode
-    c = q.shape[0] - b
-    if backend in _KERNEL_BACKENDS:
-        quantized = k_pages.dtype == jnp.int8
-        lb = _kv_lane_blocks() if quantized else 1
-        mesh = _mesh_for_shard_map()
-        tp = _mesh_tp(mesh)
-        span = n_kv * q.shape[2] if quantized else k_pages.shape[2]
-        aligned = (
-            _pallas_head_gate(q.shape[1], n_kv, tp, "ragged attention")
-            and _pallas_lane_gate(span, tp, "ragged attention")
-        )
-        if quantized and lb != max(tp, 1):
-            # the kernel reads single-block rows (see decode dispatch)
-            _note_fallback(
-                "ragged attention", "int8_lane_blocks",
-                f"mesh TP ({tp}) != pool lane blocking ({lb})")
-            aligned = False
-        if aligned:
-            from dynamo_tpu.ops import ragged_attention as ra
-
-            interp = backend == "pallas_interpret"
-            n_kv_call = n_kv // max(tp, 1)
-            _note_impl("ragged attention", backend)
-            # unified descriptor set: one page-table row per decode slot
-            # plus a final row for the chunk, all zero-(trash-)padded to a
-            # common width
-            pmax = block_tables.shape[1]
-            wp = p_pages.shape[0]
-            w = max(pmax, wp)
-            tabs = jnp.zeros((b + 1, w), jnp.int32)
-            tabs = tabs.at[:b, :pmax].set(block_tables.astype(jnp.int32))
-            tabs = tabs.at[b, :wp].set(p_pages.astype(jnp.int32))
-            cl = (context_lens if kernel_lens is None
-                  else kernel_lens).astype(jnp.int32)
-            st = jnp.asarray(p_start, jnp.int32)
-            kv_lens = jnp.concatenate([cl, (st + c).reshape(1)])
-            q_starts = jnp.concatenate(
-                [jnp.maximum(cl - 1, 0), st.reshape(1)])
-
-            def call(q, kp, vp, tb, kl, qs):
-                return ra.ragged_paged_attention(
-                    q, kp, vp, tb, kl, qs, page_size=page_size,
-                    num_kv_heads=n_kv_call, num_decode=b,
-                    interpret=interp, window=window or 0,
-                )
-
-            if mesh is None:
-                return call(q, k_pages, v_pages, tabs, kv_lens, q_starts)
-            return jax.shard_map(
-                call,
-                mesh=mesh,
-                in_specs=(P(None, "model", None), P(None, None, "model"),
-                          P(None, None, "model"), P(None, None), P(None),
-                          P(None)),
-                out_specs=P(None, "model", None),
-                check_vma=False,
-            )(q, k_pages, v_pages, tabs, kv_lens, q_starts)
+    if route.kernel:
+        # a decode row's one query sits at the end of its context
+        return _ragged_kernel(
+            route, q, k_pages, v_pages,
+            _ragged_tables(
+                block_tables,
+                context_lens if kernel_lens is None else kernel_lens,
+                p_pages, p_start, q.shape[0] - b,
+                lens_of=lambda cl: cl,
+                starts_of=lambda cl: jnp.maximum(cl - 1, 0)),
+            page_size=page_size, num_decode=b, window=window or 0)
     # XLA composition: the decode gather and chunk gather reference paths,
     # concatenated — token-identical to the separate-program paths by
     # construction, which is what the mixed-step parity tests pin.
-    _note_impl("ragged attention", "xla")
     if not b:  # a windowed chunk alone (chunk_attention)
         return chunk_attention_xla(
             q, k_pages, v_pages, p_pages, p_start, page_size=page_size,
@@ -678,73 +552,27 @@ def ragged_verify_attention(
     absolute position `positions[b] + j` and attends causally over the
     window's pages (drafts' K/V already written, like verify_attention).
 
-    Dispatch mirrors ragged_mixed_attention: DYNAMO_TPU_RAGGED_ATTENTION
-    wins when set; otherwise the scoped backend selects the Pallas kernel
-    (each window = one padded query block, via decode_q=K1) or the XLA
-    composition — verify gather + chunk gather. Inactive windows carry zero
-    tables + position 0 (trash-page rows, outputs discarded by the
-    engine)."""
-    backend = _ragged_backend(window, logit_cap)
+    Routed as ragged_mixed_attention: the Pallas kernel (each window = one
+    padded query block, via decode_q=K1) or the XLA composition — verify
+    gather + chunk gather. Inactive windows carry zero tables + position 0
+    (trash-page rows, outputs discarded by the engine)."""
     n_kv = _pool_kv_heads(k_pages, q.shape[2], num_kv_heads)
+    route = _route("ragged attention", q.shape[1], q.shape[2], n_kv,
+                   k_pages, window=window, logit_cap=logit_cap)
     b, k1 = num_verify, verify_width
-    c = q.shape[0] - b * k1
-    if backend in _KERNEL_BACKENDS:
-        quantized = k_pages.dtype == jnp.int8
-        lb = _kv_lane_blocks() if quantized else 1
-        mesh = _mesh_for_shard_map()
-        tp = _mesh_tp(mesh)
-        span = n_kv * q.shape[2] if quantized else k_pages.shape[2]
-        aligned = (
-            _pallas_head_gate(q.shape[1], n_kv, tp, "ragged attention")
-            and _pallas_lane_gate(span, tp, "ragged attention")
-        )
-        if quantized and lb != max(tp, 1):
-            # the kernel reads single-block rows (see decode dispatch)
-            _note_fallback(
-                "ragged attention", "int8_lane_blocks",
-                f"mesh TP ({tp}) != pool lane blocking ({lb})")
-            aligned = False
-        if aligned:
-            from dynamo_tpu.ops import ragged_attention as ra
-
-            interp = backend == "pallas_interpret"
-            n_kv_call = n_kv // max(tp, 1)
-            _note_impl("ragged attention", backend)
-            # unified descriptors: window rows span [pos, pos + K1) so the
-            # horizon includes every draft written this step
-            pmax = block_tables.shape[1]
-            wp = p_pages.shape[0]
-            w = max(pmax, wp)
-            tabs = jnp.zeros((b + 1, w), jnp.int32)
-            tabs = tabs.at[:b, :pmax].set(block_tables.astype(jnp.int32))
-            tabs = tabs.at[b, :wp].set(p_pages.astype(jnp.int32))
-            ps = positions.astype(jnp.int32)
-            st = jnp.asarray(p_start, jnp.int32)
-            kv_lens = jnp.concatenate([ps + k1, (st + c).reshape(1)])
-            q_starts = jnp.concatenate([ps, st.reshape(1)])
-
-            def call(q, kp, vp, tb, kl, qs):
-                return ra.ragged_paged_attention(
-                    q, kp, vp, tb, kl, qs, page_size=page_size,
-                    num_kv_heads=n_kv_call, num_decode=b, decode_q=k1,
-                    interpret=interp,
-                )
-
-            if mesh is None:
-                return call(q, k_pages, v_pages, tabs, kv_lens, q_starts)
-            return jax.shard_map(
-                call,
-                mesh=mesh,
-                in_specs=(P(None, "model", None), P(None, None, "model"),
-                          P(None, None, "model"), P(None, None), P(None),
-                          P(None)),
-                out_specs=P(None, "model", None),
-                check_vma=False,
-            )(q, k_pages, v_pages, tabs, kv_lens, q_starts)
+    if route.kernel:
+        # window rows span [pos, pos + K1) so the horizon includes every
+        # draft written this step
+        return _ragged_kernel(
+            route, q, k_pages, v_pages,
+            _ragged_tables(
+                block_tables, positions, p_pages, p_start,
+                q.shape[0] - b * k1,
+                lens_of=lambda ps: ps + k1, starts_of=lambda ps: ps),
+            page_size=page_size, num_decode=b, decode_q=k1)
     # XLA composition: the verify gather and chunk gather reference paths,
     # concatenated — token-identical to the separate-program paths by
     # construction (what the mixed-spec parity tests pin).
-    _note_impl("ragged attention", "xla")
     ver = verify_attention(
         q[:b * k1].reshape(b, k1, q.shape[1], q.shape[2]),
         k_pages, v_pages, block_tables, positions,
@@ -1021,15 +849,8 @@ def dsa_chunk_attention(
 # --------------------------------------------------------------- dispatch --
 
 
-def _mesh_tp(mesh) -> int:
-    if mesh is None:
-        return 1
-    return dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
-
-
-# Pallas -> XLA demotion visibility: the shape gates below used to demote
-# silently (or log per trace, unconditionally). _note_fallback gives every
-# demotion ONE log line per (op, reason) plus a process-wide counter that
+# Pallas -> XLA demotion visibility: every demotion gets ONE log line per
+# (op, reason) plus a process-wide counter that
 # observability/engine_metrics.py exports as dynamo_pallas_fallback_total.
 # Gates run at TRACE time, so counts are per compiled shape, not per step —
 # a nonzero count means some program is permanently off the kernel path.
@@ -1050,53 +871,13 @@ def _note_fallback(op: str, reason: str, detail: str = "") -> None:
             f": {detail}" if detail else "")
 
 
-# what the log line says for the reasons every op shares
-_DEMOTION_DETAIL = {
-    "window_softcap": "the kernel models neither sliding windows nor "
-                      "score capping",
-    "seq_mesh": "sequence-parallel mesh shards the pool under GSPMD",
-}
-
-
 def _demote(backend: str, op: str, reason: str, detail: str = "") -> str:
     """Send `op` to the XLA path. When it had resolved to a kernel
     (`auto` on a TPU, or an explicit pallas*), that is a demotion and is
     counted; when it was XLA already, nothing happened."""
     if backend in _KERNEL_BACKENDS:
-        _note_fallback(op, reason,
-                       detail or _DEMOTION_DETAIL.get(reason, ""))
+        _note_fallback(op, reason, detail)
     return "xla"
-
-
-def _traced_window(window) -> bool:
-    """A window the kernels cannot take: a traced per-layer scalar (the
-    Gemma / Mistral / Phi-3 path: window 0 = a global layer through the
-    same value). A plain int is STATIC: the layer's kind is known where
-    the program is traced (ModelConfig.layer_types), the kernels mask
-    below it and visit no KV block wholly out of reach."""
-    return window is not None and not isinstance(window, int)
-
-
-def _ragged_backend(window, logit_cap) -> str:
-    """Backend for the two ragged ops: DYNAMO_TPU_RAGGED_ATTENTION wins
-    when set; otherwise the scoped backend (RAGGED_KERNEL_HW_VALIDATED is
-    True since PR 26; pulled to False it demotes, visibly, to the XLA
-    composition). Windows, score caps and seq meshes demote either way."""
-    op = "ragged attention"
-    backend = os.environ.get("DYNAMO_TPU_RAGGED_ATTENTION")
-    if not backend:
-        from dynamo_tpu.ops import ragged_attention as _ra
-
-        backend = _resolve_backend()
-        if not _ra.RAGGED_KERNEL_HW_VALIDATED:
-            backend = _demote(backend, op, "not_validated",
-                              "RAGGED_KERNEL_HW_VALIDATED is False; set "
-                              "DYNAMO_TPU_RAGGED_ATTENTION=pallas to force")
-    if _traced_window(window) or logit_cap:
-        backend = _demote(backend, op, "window_softcap")
-    if _seq_parallel_mesh() is not None:
-        backend = _demote(backend, op, "seq_mesh")
-    return backend
 
 
 # Which implementation each op was TRACED with: {(op, impl): traces}, impl
@@ -1120,28 +901,167 @@ def pallas_fallback_counts() -> dict:
     return dict(_FALLBACK_COUNTS)
 
 
-def _pallas_head_gate(n_heads: int, n_kv: int, tp: int, op: str) -> bool:
-    """True when tp divides both query and KV heads, i.e. the explicit
-    head-parallel shard_map can split the kernel. Demotions name the
-    violated constraint (trace-time only)."""
-    if tp <= 1 or (n_kv % tp == 0 and n_heads % tp == 0):
-        return True
-    _note_fallback(
-        op, "head_gate",
-        f"tp={tp} does not divide query heads ({n_heads}) / "
-        f"KV heads ({n_kv})")
-    return False
+class _Route(NamedTuple):
+    """Where one trace of an attention op goes (`_route` decides)."""
+    backend: str  # "xla" | "pallas" | "pallas_interpret"
+    mesh: Optional[Mesh]  # shard_map over it; None: call directly
+    kv_heads: int  # KV heads one shard's call sees
+    lane_blocks: int  # int8 lane blocks in one shard's page rows
+
+    @property
+    def kernel(self) -> bool:
+        return self.backend in _KERNEL_BACKENDS
+
+    @property
+    def interpret(self) -> bool:
+        return self.backend == "pallas_interpret"
 
 
-def _pallas_lane_gate(kvd: int, tp: int, op: str) -> bool:
-    """True when the per-shard fused KV*D lane dim is 128-aligned — the TPU
-    DMA constraint all paged Pallas kernels share."""
-    if (kvd // max(tp, 1)) % 128 == 0:
-        return True
-    _note_fallback(
-        op, "lane_gate",
-        f"per-shard KV*D lane dim not 128-aligned (KV*D={kvd}, tp={tp})")
-    return False
+def _route(op: str, n_heads: int, head_dim: int, n_kv: int, pool=None, *,
+           window=None, logit_cap: float = 0.0, int8_validated: bool = True,
+           static_window_is_ragged: bool = False,
+           unsplit_keeps_backend: bool = False,
+           shards_xla: bool = False) -> Optional[_Route]:
+    """THE decision of which implementation one trace of `op` runs and over
+    which mesh: every gate between the scoped backend and a kernel, in one
+    order for all five ops, each demotion counted under the op's name and
+    the outcome noted. `pool` is the K pool of a paged op, None for the
+    whole-prompt prefill (whose flash kernel takes no window at all and has
+    a head-dim gate where the paged kernels have a lane gate).
+
+    Where the ops answer one gate differently, an argument says so:
+    - `unsplit_keeps_backend` (decode, prefill): a mesh that cannot split
+      the op (heads, or an int8 pool's lane blocks) is dropped and the
+      backend kept: the op is traced whole and GSPMD places it. Default
+      (chunk, ragged): such a mesh sends the op to the XLA composition.
+    - `shards_xla` (decode): the XLA twin runs under the same shard_map as
+      the kernel, so the mesh's gates are evaluated, and counted, on the
+      XLA backend too, and a shape gate that sends the op to XLA keeps it.
+    - `int8_validated` (chunk): False sends an int8 pool to XLA.
+    - `static_window_is_ragged` (chunk): a kernel backend under a STATIC
+      window returns None before the mesh and shape gates: the caller hands
+      the chunk to the ragged op, whose own route runs them."""
+    backend = _resolve_backend()
+    paged = pool is not None
+    quantized = paged and pool.dtype == jnp.int8
+    if quantized and not int8_validated:
+        backend = _demote(backend, op, "int8_not_validated",
+                          "int8 dequant-in-chunk awaits its on-chip "
+                          "parity verdict")
+    # A window the paged kernels cannot take is a traced per-layer scalar
+    # (the Gemma / Mistral / Phi-3 path: window 0 = a global layer through
+    # the same value). A plain int is STATIC: the layer's kind is known
+    # where the program is traced (ModelConfig.layer_types), the kernels
+    # mask below it and visit no KV block wholly out of reach.
+    windowed = bool(logit_cap) or (window is not None and not (
+        paged and isinstance(window, int)))
+    if windowed:
+        backend = _demote(backend, op, "window_softcap",
+                          "the kernel models neither sliding windows nor "
+                          "score capping")
+    if _seq_parallel_mesh() is not None:
+        # long-context (seq) mesh: the pool is GSPMD-sharded on `model`,
+        # and an unannotated pallas_call would force an all-gather of the
+        # whole pool per step — the XLA gather path partitions cleanly
+        backend = _demote(backend, op, "seq_mesh",
+                          "sequence-parallel mesh shards the pool under "
+                          "GSPMD")
+    if not paged and head_dim % 128 != 0 and head_dim not in (32, 64):
+        # e.g. MLA's latent width (kv_lora_rank + rope = 576): no Mosaic
+        # tiling for off-size trailing dims
+        backend = _demote(backend, op, "head_dim",
+                          f"no Mosaic tiling for head dim {head_dim}")
+    if (static_window_is_ragged and backend in _KERNEL_BACKENDS
+            and isinstance(window, int) and window):
+        return None
+    lb = _kv_lane_blocks() if quantized else 1
+    mesh, tp, fits = None, 1, True
+    if backend in _KERNEL_BACKENDS or shards_xla:
+        # a traced per-layer `window` scalar can't be closed over by an
+        # explicit shard_map body — GSPMD places the windowed op
+        mesh = None if windowed else _mesh_for_shard_map()
+        tp = _axis_size(mesh, "model")
+        fits = n_kv % tp == 0 and n_heads % tp == 0
+        if not fits:
+            _note_fallback(op, "head_gate",
+                           f"tp={tp} does not divide query heads "
+                           f"({n_heads}) / KV heads ({n_kv})")
+        if unsplit_keeps_backend and not (
+                fits and (not quantized or lb % tp == 0)):
+            # the lane split must hand each shard whole heads and whole
+            # int8 layout blocks (weights replicated by sharding._fit_spec)
+            mesh, tp, fits = None, 1, True
+    if backend in _KERNEL_BACKENDS:
+        # the TPU DMA constraint all paged kernels share: a 128-aligned
+        # per-shard lane span (tp=8 over 8 KV heads of dim 64 is below a
+        # lane tile). For int8 pools the VALUES span (the kernels slice
+        # rows[:, :kvd] in-VMEM): the padded packed width always aligns.
+        span = pool.shape[2] if paged and not quantized else n_kv * head_dim
+        if fits and paged and (span // tp) % 128 != 0:
+            _note_fallback(op, "lane_gate",
+                           "per-shard KV*D lane dim not 128-aligned "
+                           f"(KV*D={span}, tp={tp})")
+            fits = False
+        if quantized and lb != tp and (fits or not unsplit_keeps_backend):
+            # the kernels read SINGLE-block rows: the shard_map split count
+            # must equal the layout blocking (each shard then sees its own
+            # [values | scales | pad] block). Engine-built configs always
+            # match; mismatches (e.g. the head gate dropped the mesh) fall
+            # back.
+            _note_fallback(op, "int8_lane_blocks",
+                           f"mesh TP ({tp}) != pool lane blocking ({lb})")
+            fits = False
+        if not fits:
+            backend = "xla"
+            if not shards_xla:
+                mesh, tp = None, 1
+    _note_impl(op, backend)
+    return _Route(backend, mesh, n_kv // tp, lb // tp if quantized else 1)
+
+
+# Heads (the fused KV*D lane axis of a pool row) shard on `model`: attention
+# is embarrassingly parallel over them — no collectives inside.
+_HEADS = P(None, "model", None)  # [rows, H, D]
+_POOL = P(None, None, "model")  # [P, ps, KV*D]
+
+
+def _sharded(route: _Route, call, args, in_specs, out_specs=_HEADS):
+    """`call(*args)` directly, or under shard_map over the route's mesh."""
+    if route.mesh is None:
+        return call(*args)
+    return jax.shard_map(call, mesh=route.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
+
+
+def _ragged_tables(block_tables, rows, p_pages, p_start, chunk_len: int,
+                   *, lens_of, starts_of):
+    """The ragged kernel's unified descriptor set, (tabs, kv_lens,
+    q_starts): one page-table row per decode slot (or verify window) plus
+    a final row for the chunk, all zero-(trash-)padded to a common width.
+    `rows` [B] is what a slot's horizon `lens_of(rows)` and first query
+    position `starts_of(rows)` are read from."""
+    b, pmax = block_tables.shape
+    wp = p_pages.shape[0]
+    tabs = jnp.zeros((b + 1, max(pmax, wp)), jnp.int32)
+    tabs = tabs.at[:b, :pmax].set(block_tables.astype(jnp.int32))
+    tabs = tabs.at[b, :wp].set(p_pages.astype(jnp.int32))
+    rows = rows.astype(jnp.int32)
+    st = jnp.asarray(p_start, jnp.int32)
+    kv_lens = jnp.concatenate([lens_of(rows), (st + chunk_len).reshape(1)])
+    q_starts = jnp.concatenate([starts_of(rows), st.reshape(1)])
+    return tabs, kv_lens, q_starts
+
+
+def _ragged_kernel(route: _Route, q, k_pages, v_pages, tables, **kw):
+    from dynamo_tpu.ops import ragged_attention as ra
+
+    def call(q, kp, vp, tb, kl, qs):
+        return ra.ragged_paged_attention(
+            q, kp, vp, tb, kl, qs, num_kv_heads=route.kv_heads,
+            interpret=route.interpret, **kw)
+
+    return _sharded(route, call, (q, k_pages, v_pages, *tables),
+                    (_HEADS, _POOL, _POOL, P(None, None), P(None), P(None)))
 
 
 def paged_attention_decode(
@@ -1161,94 +1081,34 @@ def paged_attention_decode(
     `context_lens`: it does nothing for a slot at context 0 (no page copy,
     zeros out). The XLA twin keeps `context_lens`, where the engine pins an
     empty slot at context 1 so that no row is masked whole."""
-    backend = _resolve_backend()
-    windowed = _traced_window(window) or bool(logit_cap)
-    if windowed:
-        backend = _demote(backend, "decode", "window_softcap")
-    if _seq_parallel_mesh() is not None:
-        # long-context (seq) mesh: the pool is GSPMD-sharded on `model`,
-        # and an unannotated pallas_call would force an all-gather of the
-        # whole pool per step — the XLA gather path partitions cleanly
-        backend = _demote(backend, "decode", "seq_mesh")
-    mesh = _mesh_for_shard_map()
-    if windowed:
-        # the traced per-layer `window` scalar can't be closed over by an
-        # explicit shard_map body — let GSPMD place the windowed op
-        mesh = None
-    n_kv = _pool_kv_heads(k_pages, q.shape[2], num_kv_heads)
-    tp = _mesh_tp(mesh)
-    quantized = k_pages.dtype == jnp.int8
-    lb = _kv_lane_blocks() if quantized else 1
-    if not _pallas_head_gate(q.shape[1], n_kv, tp, "decode"):
-        # the explicit head-parallel shard_map can't split a head — let
-        # GSPMD place the op instead (weights replicated by
-        # sharding._fit_spec)
-        mesh = None
-    if quantized and mesh is not None and lb % _mesh_tp(mesh) != 0:
-        # a lane split must hand each shard whole layout blocks; otherwise
-        # run the full blocked layout under GSPMD
-        mesh = None
-    if backend != "xla":
-        # e.g. tp=8 over 8 KV heads of dim 64 drops the local fused-KV span
-        # below a lane tile. For int8 pools, gate on the VALUES span (the
-        # kernel slices rows[:, :kvd] in-VMEM) — the padded packed width is
-        # 128-aligned by construction and would always pass.
-        span = n_kv * q.shape[2] if quantized else k_pages.shape[2]
-        if not _pallas_lane_gate(span, _mesh_tp(mesh), "decode"):
-            backend = "xla"  # counted by the gate
-    if quantized and backend != "xla" and lb != max(_mesh_tp(mesh), 1):
-        # the Pallas kernel reads SINGLE-block rows: the shard_map split
-        # count must equal the layout blocking (each shard then sees its own
-        # [values | scales | pad] block). Engine-built configs always match;
-        # mismatches (e.g. head gate dropped the mesh) fall back.
-        _note_fallback(
-            "decode", "int8_lane_blocks",
-            f"mesh TP ({_mesh_tp(mesh)}) != pool lane blocking ({lb})")
-        backend = "xla"
-    tp_eff = _mesh_tp(mesh)
-    n_kv_call = n_kv // tp_eff  # per-shard KV heads seen by the inner call
-    lb_call = lb // tp_eff if quantized else 1
-    _note_impl("decode", backend)
-    if backend == "xla":
-        def call(q, kp, vp, bt, cl):
-            return paged_attention_decode_xla(
-                q, kp, vp, bt, cl, page_size=page_size,
-                num_kv_heads=n_kv_call, lane_blocks=lb_call,
-                window=window, logit_cap=logit_cap,
-            )
-    else:
+    route = _route(
+        "decode", q.shape[1], q.shape[2],
+        _pool_kv_heads(k_pages, q.shape[2], num_kv_heads), k_pages,
+        window=window, logit_cap=logit_cap, unsplit_keeps_backend=True,
+        shards_xla=True)
+    if route.kernel:
         from dynamo_tpu.ops import pallas_attention as pa
-
-        interpret = backend == "pallas_interpret"
 
         def call(q, kp, vp, bt, cl):
             return pa.paged_attention_decode(
-                q, kp, vp, bt, cl,
-                page_size=page_size,
-                num_kv_heads=n_kv_call,
-                interpret=interpret, window=window or 0,
-            )
+                q, kp, vp, bt, cl, page_size=page_size,
+                num_kv_heads=route.kv_heads, interpret=route.interpret,
+                window=window or 0)
 
         if kernel_lens is not None:
             context_lens = kernel_lens
+    else:
+        def call(q, kp, vp, bt, cl):
+            return paged_attention_decode_xla(
+                q, kp, vp, bt, cl, page_size=page_size,
+                num_kv_heads=route.kv_heads, lane_blocks=route.lane_blocks,
+                window=window, logit_cap=logit_cap)
 
-    if mesh is None:
-        return call(q, k_pages, v_pages, block_table, context_lens)
-    # Heads (the fused KV*D lane axis) shard on `model`, batch on `data`:
-    # attention is embarrassingly parallel over both — no collectives inside.
-    return jax.shard_map(
-        call,
-        mesh=mesh,
-        in_specs=(
-            P("data", "model", None),
-            P(None, None, "model"),
-            P(None, None, "model"),
-            P("data", None),
-            P("data"),
-        ),
-        out_specs=P("data", "model", None),
-        check_vma=False,
-    )(q, k_pages, v_pages, block_table, context_lens)
+    # the batch shards on `data` besides
+    return _sharded(
+        route, call, (q, k_pages, v_pages, block_table, context_lens),
+        (P("data", "model", None), _POOL, _POOL, P("data", None),
+         P("data")), P("data", "model", None))
 
 
 def prefill_attention(
@@ -1261,93 +1121,70 @@ def prefill_attention(
     logit_cap: float = 0.0,
 ) -> jax.Array:
     sp_mesh = _seq_parallel_mesh()
-    if (window is not None or logit_cap) and sp_mesh is not None:
-        # the ring/Ulysses paths don't model windows/caps; the Engine
-        # rejects --sp for sliding-window models before we ever get here
-        raise ValueError(
-            "sequence-parallel prefill does not support sliding-window/"
-            "softcap models")
-    if window is not None or logit_cap:
-        _demote(_resolve_backend(), "prefill", "window_softcap")
-        _note_impl("prefill", "xla")
+    if sp_mesh is not None:
+        if window is not None or logit_cap:
+            # the ring/Ulysses paths don't model windows/caps; the Engine
+            # rejects --sp for sliding-window models before we ever get here
+            raise ValueError(
+                "sequence-parallel prefill does not support sliding-window/"
+                "softcap models")
+        return _seq_parallel_prefill(q, k, v, seq_len, sp_mesh)
+    route = _route("prefill", q.shape[1], q.shape[2], k.shape[1],
+                   window=window, logit_cap=logit_cap,
+                   unsplit_keeps_backend=True)
+    if not route.kernel:
         return prefill_attention_xla(q, k, v, seq_len, window=window,
                                      logit_cap=logit_cap)
-    if sp_mesh is not None:
-        # Long-context path: sequence sharded over the `seq` axis (the
-        # reference has no analogue — SURVEY.md §5). Strategy via
-        # DYNAMO_TPU_SP_STRATEGY: `ring` (default; ppermute neighbour hops,
-        # one ICI step per hop) or `ulysses` (all_to_all head/sequence
-        # exchange — fewer collectives, favors meshes with all-to-all
-        # bandwidth). The engine pads prompts to page_size multiples, not
-        # sp multiples, so pad here to the divisibility requirement and
-        # slice back (the tail past seq_len is masked inside either way).
-        from dynamo_tpu.ops import ring_attention as ra
-
-        strategy = os.environ.get("DYNAMO_TPU_SP_STRATEGY", "ring")
-        if strategy not in ("ring", "ulysses"):
-            raise ValueError(
-                f"DYNAMO_TPU_SP_STRATEGY {strategy!r} not in "
-                f"('ring', 'ulysses')")
-        sizes = dict(zip(sp_mesh.axis_names, sp_mesh.devices.shape))
-        sp = sizes["seq"]
-        if strategy == "ulysses":
-            # Ulysses' all_to_all splits the LOCAL head axis across `seq`:
-            # per-model-shard query heads must divide by sp, else the
-            # ring (which has no head requirement) serves the prompt
-            local_h = q.shape[1] // max(sizes.get("model", 1), 1)
-            if local_h % sp != 0:
-                import logging
-
-                logging.getLogger("dynamo_tpu.ops").warning(
-                    "ulysses needs local query heads (%d) divisible by "
-                    "the seq axis (%d); using ring attention", local_h, sp)
-                strategy = "ring"
-        fn = (ra.ulysses_prefill_attention if strategy == "ulysses"
-              else ra.ring_prefill_attention)
-        s = q.shape[0]
-        pad = (-s) % sp
-        if pad:
-            q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
-            k = jnp.pad(k, ((0, pad), (0, 0), (0, 0)))
-            v = jnp.pad(v, ((0, pad), (0, 0), (0, 0)))
-        # ring/Ulysses are jnp collectives, not the flash kernel
-        _demote(_resolve_backend(), "prefill", "seq_mesh",
-                "sequence-parallel prefill runs ring/Ulysses attention")
-        _note_impl("prefill", strategy)
-        out = fn(q, k, v, seq_len, sp_mesh)
-        return out[:s] if pad else out
-    backend = _resolve_backend()
-    if q.shape[2] % 128 != 0 and q.shape[2] not in (32, 64):
-        # e.g. MLA's latent width (kv_lora_rank + rope = 576): no Mosaic
-        # tiling for off-size trailing dims — serve via XLA
-        backend = _demote(backend, "prefill", "head_dim",
-                          f"no Mosaic tiling for head dim {q.shape[2]}")
-    _note_impl("prefill", backend)
-    if backend == "xla":
-        return prefill_attention_xla(q, k, v, seq_len)
     from dynamo_tpu.ops import pallas_attention as pa
 
-    interpret = backend == "pallas_interpret"
-
     def call(q, k, v, sl):
-        return pa.prefill_attention(q, k, v, sl, interpret=interpret)
+        return pa.prefill_attention(q, k, v, sl, interpret=route.interpret)
 
-    mesh = _mesh_for_shard_map()
-    tp = _mesh_tp(mesh)
-    if not _pallas_head_gate(q.shape[1], k.shape[1], tp, "prefill"):
-        mesh = None  # heads not divisible: GSPMD auto-shards instead
-    if mesh is None:
-        return call(q, k, v, jnp.asarray(seq_len, jnp.int32))
     # Prefill is single-sequence: replicated over `data`, heads on `model`.
-    return jax.shard_map(
-        call,
-        mesh=mesh,
-        in_specs=(
-            P(None, "model", None),
-            P(None, "model", None),
-            P(None, "model", None),
-            P(),
-        ),
-        out_specs=P(None, "model", None),
-        check_vma=False,
-    )(q, k, v, jnp.asarray(seq_len, jnp.int32))
+    return _sharded(route, call, (q, k, v, jnp.asarray(seq_len, jnp.int32)),
+                    (_HEADS, _HEADS, _HEADS, P()))
+
+
+def _seq_parallel_prefill(q, k, v, seq_len, sp_mesh: Mesh) -> jax.Array:
+    """Long-context path: sequence sharded over the `seq` axis (the
+    reference has no analogue — SURVEY.md §5). Strategy via
+    DYNAMO_TPU_SP_STRATEGY: `ring` (default; ppermute neighbour hops, one
+    ICI step per hop) or `ulysses` (all_to_all head/sequence exchange —
+    fewer collectives, favors meshes with all-to-all bandwidth). Neither is
+    a paged kernel's route: jnp collectives, counted as the flash kernel's
+    `seq_mesh` demotion. The engine pads prompts to page_size multiples,
+    not sp multiples, so pad here to the divisibility requirement and slice
+    back (the tail past seq_len is masked inside either way)."""
+    from dynamo_tpu.ops import ring_attention as ra
+
+    strategy = os.environ.get("DYNAMO_TPU_SP_STRATEGY", "ring")
+    if strategy not in ("ring", "ulysses"):
+        raise ValueError(
+            f"DYNAMO_TPU_SP_STRATEGY {strategy!r} not in "
+            f"('ring', 'ulysses')")
+    sp = _axis_size(sp_mesh, "seq")
+    if strategy == "ulysses":
+        # Ulysses' all_to_all splits the LOCAL head axis across `seq`:
+        # per-model-shard query heads must divide by sp, else the
+        # ring (which has no head requirement) serves the prompt
+        local_h = q.shape[1] // _axis_size(sp_mesh, "model")
+        if local_h % sp != 0:
+            import logging
+
+            logging.getLogger("dynamo_tpu.ops").warning(
+                "ulysses needs local query heads (%d) divisible by "
+                "the seq axis (%d); using ring attention", local_h, sp)
+            strategy = "ring"
+    fn = (ra.ulysses_prefill_attention if strategy == "ulysses"
+          else ra.ring_prefill_attention)
+    s = q.shape[0]
+    pad = (-s) % sp
+    if pad:
+        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+        k = jnp.pad(k, ((0, pad), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, pad), (0, 0), (0, 0)))
+    _demote(_resolve_backend(), "prefill", "seq_mesh",
+            "sequence-parallel prefill runs ring/Ulysses attention")
+    _note_impl("prefill", strategy)
+    out = fn(q, k, v, seq_len, sp_mesh)
+    return out[:s] if pad else out

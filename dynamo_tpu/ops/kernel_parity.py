@@ -41,7 +41,6 @@ import json
 import os
 import sys
 from typing import Callable, Dict, List, Tuple
-from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -179,14 +178,13 @@ def _case_ragged(h: int, n_kv: int, quantized: bool, decode_q: int,
 
 
 def _forced(op: Callable, backend: str) -> Callable:
-    """`op` jitted with the ragged dispatch forced to `backend`. A fresh
+    """`op` jitted with the attention backend scoped to `backend`. A fresh
     function object per backend: jit's trace cache is keyed on the
-    function, and the env var is read at trace time."""
+    function, and the scope is read at trace time."""
     fn = jax.jit(lambda *a: op(*a))
 
     def run(*a):
-        with mock.patch.dict(
-                os.environ, {"DYNAMO_TPU_RAGGED_ATTENTION": backend}):
+        with att.attention_context(backend, None):
             return fn(*a)
     return run
 

@@ -2,38 +2,29 @@
 
 The reference serves MoE models through its consumed engines (BASELINE.json
 config #5: Mixtral/DeepSeek expert-parallel via the smart router); here the
-expert compute itself is TPU-native. Two paths, both jit-safe and
-GSPMD-partitionable over the `expert` mesh axis (sharding rules in
-dynamo_tpu.parallel.sharding map moe_w_* onto P('expert', ...)):
+expert compute itself is TPU-native. Two paths, both exact (no token is
+ever dropped), jit-safe and GSPMD-partitionable over the `expert` mesh axis
+(sharding rules in dynamo_tpu.parallel.sharding map moe_w_* onto
+P('expert', ...)); the choice between them is made from the model's shapes
+(ModelConfig.moe_grouped), there is no option:
 
 - `moe_mlp_dense`: every expert processes every token, the top-k combine
-  matrix zeroes the rest. No gathers, no token drops; the right choice for
-  small decode batches where dispatch overhead dominates.
-- `moe_mlp_dropping`: capacity-based dispatch for prefill-sized token counts.
-  Each expert gathers its top-C tokens by router weight (C = T*k/X * cf),
-  computes only those, and scatter-adds the weighted outputs. FLOPs drop from
-  T*X expert-MLPs to C*X ≈ T*k*cf — a 4x cut for Mixtral (X=8, k=2) — and
-  under expert-parallel sharding XLA partitions the leading X axis so each
-  device touches only its local experts. Tokens past an expert's capacity are
-  dropped (standard capacity-factor semantics); cf defaults to 1.25.
-
+  matrix zeroes the rest. No gathers; the right choice where a token picks
+  a large share of few experts (Mixtral: 2 of 8).
 - `moe_mlp_grouped`: each token is computed only in the experts it picked.
   The T*k assignments are sorted by expert and the three projections run as
   grouped matmuls (`jax.lax.ragged_dot`; int8 x int8 -> int32 for W8A8
-  weights) over the groups; nothing is dropped at any imbalance, an expert
-  no token picked is not read. It is told which experts it holds
-  (`expert_offset`, the weights' leading axis): the router keeps its full
-  width, assignments to experts held elsewhere are left out — that part
-  of the sum is another chip's — and no code stands in for their exchange.
-  The matmuls run over the smallest of a few static row counts that
-  holds the rows the held experts really received (`row_rungs`), and not
-  at all where no row picked a held expert.
-  The choice between it and `moe_mlp_dense` is made from the model's
-  shapes (ModelConfig.moe_grouped).
+  weights) over the groups; an expert no token picked is not read. It is
+  told which experts it holds (`expert_offset`, the weights' leading axis):
+  the router keeps its full width, assignments to experts held elsewhere
+  are left out — that part of the sum is another chip's — and no code
+  stands in for their exchange. The matmuls run over the smallest of a few
+  static row counts that holds the rows the held experts really received
+  (`row_rungs`), and not at all where no row picked a held expert.
 
 `route_topk` is the single router: (expert ids [T, k], weights [T, k]);
-`topk_combine` scatters them into the dense combine matrix [T, X] the first
-two paths contract with.
+`topk_combine` scatters them into the dense combine matrix [T, X] the dense
+path contracts with.
 """
 
 from __future__ import annotations
@@ -168,45 +159,6 @@ def moe_mlp_dense(
     u = qeinsum("te,xef->txf", x, w_up)
     y = qeinsum("txf,xfe->txe", jax.nn.silu(g) * u, w_down)
     return jnp.einsum("txe,tx->te", y, combine)
-
-
-def expert_capacity(num_tokens: int, num_experts: int, k: int,
-                    capacity_factor: float) -> int:
-    """Static per-expert token capacity (multiple of 8 for TPU lane tiling)."""
-    c = int(num_tokens * k / num_experts * capacity_factor)
-    c = max(8, -(-c // 8) * 8)  # round up to 8
-    return min(c, num_tokens)
-
-
-def moe_mlp_dropping(
-    x: jax.Array,        # [T, E]
-    combine: jax.Array,  # [T, X] dense combine matrix
-    w_gate: jax.Array,   # [X, E, F]
-    w_up: jax.Array,
-    w_down: jax.Array,   # [X, F, E]
-    *,
-    capacity: int,
-) -> jax.Array:
-    """Capacity-based dispatch: each expert computes only its top-C tokens.
-
-    Gather/scatter are batched on the leading X axis, so expert-parallel
-    sharding keeps every step local to the expert's device; the final
-    scatter-add contracts the X axis (XLA inserts the psum over `expert`).
-    """
-    t, e = x.shape
-    # per-expert token selection by routing weight: [X, C] indices into T
-    weights_xt = combine.T  # [X, T]
-    sel_w, sel_i = jax.lax.top_k(weights_xt, capacity)  # [X, C]
-    xg = jnp.take(x, sel_i, axis=0)  # [X, C, E]
-    g = qeinsum("xce,xef->xcf", xg, w_gate)
-    u = qeinsum("xce,xef->xcf", xg, w_up)
-    y = qeinsum("xcf,xfe->xce", jax.nn.silu(g) * u, w_down)  # [X, C, E]
-    # weight by routing prob; zero-weight slots (capacity padding for experts
-    # with fewer selected tokens) contribute nothing
-    y = y * sel_w[..., None].astype(y.dtype)
-    out = jnp.zeros((t, e), y.dtype)
-    out = out.at[sel_i.reshape(-1)].add(y.reshape(-1, e))
-    return out
 
 
 # what moe_mlp_grouped counts for a layer (int32 [6]); summed over layers

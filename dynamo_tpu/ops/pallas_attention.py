@@ -57,7 +57,6 @@ attends over its local KV-head lane span.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -69,43 +68,18 @@ from dynamo_tpu.ops.attention import shared_kv
 NEG_INF = float("-inf")
 
 
-def _env_int(name: str, default: int, lo: int) -> int:
-    """Defensive env knob parse: bad values warn and fall back."""
-    raw = os.environ.get(name)
-    if not raw:
-        return default
-    try:
-        return max(lo, int(raw))
-    except ValueError:
-        import logging
-
-        logging.getLogger("dynamo_tpu.ops").warning(
-            "ignoring %s=%r (not an integer)", name, raw)
-        return default
-
-
-# Chunk-prefill kernel hardware-validation flag: while False, chunked
-# prefill defaults to the XLA gather path unless DYNAMO_TPU_CHUNK_ATTENTION
-# explicitly selects the kernel (interpret mode cannot validate Mosaic
-# lowering). True: the kernel compiles under jaxlib 0.9.0's Mosaic on a v5e
-# and agrees with the XLA gather path within bf16 tolerance at 28/4 and 7/1
-# heads (ops/kernel_parity.py; outcomes in PERF.md). Selection follows the
-# engine's attention backend like the decode/prefill ops.
-CHUNK_KERNEL_HW_VALIDATED = True
-
-# The int8-KV dequant-in-chunk path compiles and passes the same on-chip
-# parity check, but its default does not flip on a parity run: a changed
-# default path is judged on a benchmark cell (ROADMAP S3/S4). Until then it
-# is env-opt-in (DYNAMO_TPU_CHUNK_ATTENTION=pallas) and the demotion is
-# counted in dynamo_pallas_fallback_total.
+# The int8-KV dequant-in-chunk path compiles and passes the on-chip parity
+# check (ops/kernel_parity.py), but a changed default path is judged on a
+# benchmark cell (ROADMAP S3/S4) and none has run it. Until one does,
+# ops/attention.chunk_attention sends an int8 pool to the XLA gather path,
+# counted in dynamo_pallas_fallback_total (`int8_not_validated`).
 CHUNK_KERNEL_INT8_HW_VALIDATED = False
 
-# pages per decode superblock (tokens per block = this * page_size);
-# DYNAMO_TPU_DECODE_BLOCK_PAGES / _NUM_BUFS override for hardware tuning
-DEFAULT_BLOCK_PAGES = _env_int("DYNAMO_TPU_DECODE_BLOCK_PAGES", 8, 1)
+# pages per decode superblock (tokens per block = this * page_size)
+DEFAULT_BLOCK_PAGES = 8
 # KV block buffers in the DMA ring: num_bufs - 1 blocks are in flight ahead
 # of the one being consumed (pipeline depth)
-DEFAULT_NUM_BUFS = _env_int("DYNAMO_TPU_DECODE_NUM_BUFS", 4, 2)
+DEFAULT_NUM_BUFS = 4
 
 
 def _kv_block(kbuf, vbuf, cur, tokens: int, n_kv: int, d: int,

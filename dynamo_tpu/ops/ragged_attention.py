@@ -60,14 +60,10 @@ bytes and the result depend on nothing a sequence does not own below its
 kv_len — the property the mixed step pays for (the XLA composition it
 replaced gathers max_num_seqs x max_seq_len whatever is live).
 
-Hardware-validation gating follows the CHUNK_KERNEL convention:
-`RAGGED_KERNEL_HW_VALIDATED` is True, so the dispatch in `attention.py`
-follows the scoped backend (`auto` -> this kernel on a TPU). It was flipped
-by PR 26 after on-chip parity at the benchmark cells' own shapes and after
-both cells judged it as the default step (PERF.md section 6). With the flag
-False the XLA composition serves every backend (counted in
-dynamo_pallas_fallback_total); `DYNAMO_TPU_RAGGED_ATTENTION` overrides
-either way.
+`ops/attention.py` routes the mixed step here on a kernel backend (`auto`
+on a TPU) since PR 26: on-chip parity at the benchmark cells' own shapes
+(ops/kernel_parity.py, `ragged_cell_*`), and both chat cells judged it as
+the default step (PERF.md section 6).
 """
 
 from __future__ import annotations
@@ -90,14 +86,6 @@ from dynamo_tpu.ops.pallas_attention import (
     _v_ring,
     shared_kv,
 )
-
-# True since PR 26: on-chip parity at the benchmark cells' own shapes
-# (ops/kernel_parity.py, `ragged_cell_*`) and both cells judged the kernel
-# as the default mixed step (PERF.md section 6). Kept, with the
-# `not_validated` route and DYNAMO_TPU_RAGGED_ATTENTION, for the parity
-# tool and the tests until ROADMAP D1 removes the duplicate kernels.
-RAGGED_KERNEL_HW_VALIDATED = True
-
 
 # what a masked score reads under a window (see the kernel's mask)
 _OUT_OF_REACH = -1e30

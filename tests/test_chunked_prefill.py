@@ -90,7 +90,7 @@ def test_chunked_final_chunk_past_bucket_cap():
     assert chunked == full
 
 
-def test_chunked_engine_with_pallas_chunk_kernel(monkeypatch):
+def test_chunked_engine_with_pallas_chunk_kernel():
     """End-to-end: engine chunked prefill through the Pallas flash kernel
     (interpret mode) produces the same tokens as the XLA chunk path.
 
@@ -109,17 +109,17 @@ def test_chunked_engine_with_pallas_chunk_kernel(monkeypatch):
     ref = Engine(EngineConfig(**kw), model_cfg=mcfg).generate(
         GenRequest("x", prompt, max_tokens=8, temperature=0.0,
                    ignore_eos=True))
-    monkeypatch.setenv("DYNAMO_TPU_CHUNK_ATTENTION", "pallas_interpret")
-    out = Engine(EngineConfig(**kw), model_cfg=mcfg).generate(
+    out = Engine(EngineConfig(**kw, attention_backend="pallas_interpret"),
+                 model_cfg=mcfg).generate(
         GenRequest("x", prompt, max_tokens=8, temperature=0.0,
                    ignore_eos=True))
     assert out == ref
 
 
-def test_chunk_backend_follows_engine_backend_once_validated(monkeypatch):
-    """With no env override, chunk attention stays XLA until the kernel is
-    hardware-validated; once CHUNK_KERNEL_HW_VALIDATED flips, selection
-    follows the engine's attention backend like the other ops."""
+def test_chunk_backend_follows_the_scoped_backend(monkeypatch):
+    """Chunk attention follows the engine's scoped attention backend like
+    the other ops, and nothing else: a kernel backend calls the kernel,
+    `xla` the gather path."""
     import numpy as np
     import jax.numpy as jnp
 
@@ -131,7 +131,6 @@ def test_chunk_backend_follows_engine_backend_once_validated(monkeypatch):
     kp = jnp.asarray(rng.normal(size=(16, ps, n_kv * d)), jnp.float32)
     pages = jnp.asarray([1, 2, 3, 4], jnp.int32)
     q = jnp.asarray(rng.normal(size=(16, h, d)), jnp.float32)
-    monkeypatch.delenv("DYNAMO_TPU_CHUNK_ATTENTION", raising=False)
 
     calls = []
     real = pa.chunk_prefill_attention
@@ -141,20 +140,18 @@ def test_chunk_backend_follows_engine_backend_once_validated(monkeypatch):
         return real(*a, **k)
 
     monkeypatch.setattr(pa, "chunk_prefill_attention", spy)
-    with att.attention_context("pallas_interpret", None):
-        monkeypatch.setattr(pa, "CHUNK_KERNEL_HW_VALIDATED", False)
-        att.chunk_attention(q, kp, kp, pages, 16, page_size=ps)
-        assert not calls  # not validated: XLA path even under pallas ctx
-        monkeypatch.setattr(pa, "CHUNK_KERNEL_HW_VALIDATED", True)
-        att.chunk_attention(q, kp, kp, pages, 16, page_size=ps)
-        assert calls  # validated: follows the engine backend
+    for backend, kernel in (("xla", False), ("pallas_interpret", True)):
+        del calls[:]
+        with att.attention_context(backend, None):
+            att.chunk_attention(q, kp, kp, pages, 16, page_size=ps)
+        assert bool(calls) is kernel
 
 
 def test_chunk_kernel_int8_pools_stay_gated_until_validated(monkeypatch):
-    """The bf16 on-chip parity pass flipped CHUNK_KERNEL_HW_VALIDATED, but
-    the int8 dequant-in-chunk path has its own gate: int8 pools keep the
-    XLA path under default selection until CHUNK_KERNEL_INT8_HW_VALIDATED
-    flips (ROADMAP S3/S4: judged on a cell)."""
+    """The int8 dequant-in-chunk path has a gate of its own: int8 pools
+    keep the XLA path on every backend until
+    CHUNK_KERNEL_INT8_HW_VALIDATED flips (ROADMAP S3/S4: judged on a
+    cell)."""
     import numpy as np
     import jax.numpy as jnp
 
@@ -168,8 +165,6 @@ def test_chunk_kernel_int8_pools_stay_gated_until_validated(monkeypatch):
     k8 = att.pack_kv_rows(kf, w).reshape(16, ps, w)
     pages = jnp.asarray([1, 2, 3, 4], jnp.int32)
     q = jnp.asarray(rng.normal(size=(16, h, d)), jnp.float32)
-    monkeypatch.delenv("DYNAMO_TPU_CHUNK_ATTENTION", raising=False)
-    monkeypatch.setattr(pa, "CHUNK_KERNEL_HW_VALIDATED", True)
 
     calls = []
     real = pa.chunk_prefill_attention

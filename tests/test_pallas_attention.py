@@ -68,15 +68,12 @@ def test_prefill_matches_xla(s, seq_len):
                                np.asarray(ref[:seq_len]), rtol=2e-5, atol=2e-5)
 
 
-def test_dispatch_backend_selection(monkeypatch):
+def test_dispatch_backend_selection():
     q, kp, vp, bt, cl = _decode_inputs(jax.random.PRNGKey(3))
-    att.set_attention_backend("pallas_interpret")
-    try:
+    with att.attention_context("pallas_interpret", None):
         out = att.paged_attention_decode(q, kp, vp, bt, cl, page_size=16)
-        att.set_attention_backend("xla")
+    with att.attention_context("xla", None):
         ref = att.paged_attention_decode(q, kp, vp, bt, cl, page_size=16)
-    finally:
-        att.set_attention_backend(None)
     np.testing.assert_allclose(np.asarray(out[:3]), np.asarray(ref[:3]),
                                rtol=2e-5, atol=2e-5)
 
@@ -90,13 +87,8 @@ def test_decode_shard_map_tp():
         jax.random.PRNGKey(4), bsz=4, n_heads=8, n_kv=4
     )
     ref = att.paged_attention_decode_xla(q, kp, vp, bt, cl, page_size=16)
-    att.set_attention_backend("pallas_interpret")
-    att.set_attention_mesh(mesh)
-    try:
+    with att.attention_context("pallas_interpret", mesh):
         out = att.paged_attention_decode(q, kp, vp, bt, cl, page_size=16)
-    finally:
-        att.set_attention_backend(None)
-        att.set_attention_mesh(None)
     np.testing.assert_allclose(np.asarray(out[:3]), np.asarray(ref[:3]),
                                rtol=2e-5, atol=2e-5)
 
@@ -112,14 +104,10 @@ def test_engine_generates_with_pallas_backend():
             model="tiny-debug", page_size=16, num_pages=64, max_num_seqs=2,
             max_seq_len=128, attention_backend=backend,
         ))
-        try:
-            return eng.generate(GenRequest(
-                "r1", [1, 2, 3, 4, 5], max_tokens=8, temperature=0.0,
-                ignore_eos=True,
-            ))
-        finally:
-            att.set_attention_backend(None)
-            att.set_attention_mesh(None)
+        return eng.generate(GenRequest(
+            "r1", [1, 2, 3, 4, 5], max_tokens=8, temperature=0.0,
+            ignore_eos=True,
+        ))
     toks_pallas = run("pallas_interpret")
     toks_xla = run("xla")
     assert toks_pallas == toks_xla
@@ -135,13 +123,8 @@ def test_prefill_shard_map_tp():
     k = jax.random.normal(ks[1], (s, n_kv, head_dim), jnp.float32)
     v = jax.random.normal(ks[2], (s, n_kv, head_dim), jnp.float32)
     ref = att.prefill_attention_xla(q, k, v, 50)
-    att.set_attention_backend("pallas_interpret")
-    att.set_attention_mesh(mesh)
-    try:
+    with att.attention_context("pallas_interpret", mesh):
         out = att.prefill_attention(q, k, v, 50)
-    finally:
-        att.set_attention_backend(None)
-        att.set_attention_mesh(None)
     np.testing.assert_allclose(np.asarray(out[:50]), np.asarray(ref[:50]),
                                rtol=2e-5, atol=2e-5)
 
@@ -170,7 +153,7 @@ def test_chunk_prefill_kernel_matches_xla():
                                    rtol=2e-5, atol=2e-5)
 
 
-def test_chunk_attention_env_dispatch(monkeypatch):
+def test_chunk_attention_scoped_dispatch():
     import numpy as np
 
     rng = np.random.default_rng(12)
@@ -180,8 +163,8 @@ def test_chunk_attention_env_dispatch(monkeypatch):
     pages = jnp.asarray([1, 2, 3, 4], jnp.int32)
     q = jnp.asarray(rng.normal(size=(16, h, d)), jnp.float32)
     ref = att.chunk_attention(q, kp, vp, pages, 16, page_size=ps)
-    monkeypatch.setenv("DYNAMO_TPU_CHUNK_ATTENTION", "pallas_interpret")
-    out = att.chunk_attention(q, kp, vp, pages, 16, page_size=ps)
+    with att.attention_context("pallas_interpret", None):
+        out = att.chunk_attention(q, kp, vp, pages, 16, page_size=ps)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 

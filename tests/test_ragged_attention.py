@@ -94,7 +94,7 @@ def _mixed_inputs(rng, quantized, ps=16, n_pool=64, b=3, h=8, n_kv=2, d=64,
 
 @pytest.mark.parametrize("quantized", [False, True],
                          ids=["f32_pool", "int8_pool"])
-def test_ragged_kernel_matches_xla_composition(monkeypatch, quantized):
+def test_ragged_kernel_matches_xla_composition(quantized):
     """The Pallas ragged kernel (interpret mode) is numerically equivalent
     to the per-path reference composition on a mixed batch with
     page-boundary-crossing mid-prefill rows — bf16 and int8-packed pools."""
@@ -104,10 +104,10 @@ def test_ragged_kernel_matches_xla_composition(monkeypatch, quantized):
     q, kp, vp, tabs, ctx, pp, start = _mixed_inputs(rng, quantized)
 
     def run(backend):
-        monkeypatch.setenv("DYNAMO_TPU_RAGGED_ATTENTION", backend)
-        return att.ragged_mixed_attention(
-            q, kp, vp, tabs, ctx, pp, start, page_size=16,
-            num_kv_heads=2, num_decode=3)
+        with att.attention_context(backend, None):
+            return att.ragged_mixed_attention(
+                q, kp, vp, tabs, ctx, pp, start, page_size=16,
+                num_kv_heads=2, num_decode=3)
 
     ref = run("xla")
     out = run("pallas_interpret")
@@ -116,17 +116,15 @@ def test_ragged_kernel_matches_xla_composition(monkeypatch, quantized):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_ragged_kernel_gated_until_hw_validated(monkeypatch):
-    """With no env override the ragged dispatch follows the engine's
-    scoped attention backend (the default since PR 26's cells judged the
-    kernel); with RAGGED_KERNEL_HW_VALIDATED pulled back to False it stays
-    on the XLA composition (CHUNK_KERNEL idiom)."""
+def test_ragged_kernel_follows_the_scoped_backend(monkeypatch):
+    """The ragged dispatch follows the engine's scoped attention backend
+    and nothing else: a kernel backend calls the kernel, `xla` the
+    composition."""
     from dynamo_tpu.ops import attention as att
     from dynamo_tpu.ops import ragged_attention as ra
 
     rng = np.random.default_rng(3)
     q, kp, vp, tabs, ctx, pp, start = _mixed_inputs(rng, False)
-    monkeypatch.delenv("DYNAMO_TPU_RAGGED_ATTENTION", raising=False)
 
     calls = []
     real = ra.ragged_paged_attention
@@ -136,18 +134,13 @@ def test_ragged_kernel_gated_until_hw_validated(monkeypatch):
         return real(*a, **k)
 
     monkeypatch.setattr(ra, "ragged_paged_attention", spy)
-    assert ra.RAGGED_KERNEL_HW_VALIDATED is True  # the shipped default
-    with att.attention_context("pallas_interpret", None):
-        att.ragged_mixed_attention(q, kp, vp, tabs, ctx, pp, start,
-                                   page_size=16, num_kv_heads=2,
-                                   num_decode=3)
-        assert calls  # validated: follows the engine backend
+    for backend, kernel in (("pallas_interpret", True), ("xla", False)):
         del calls[:]
-        monkeypatch.setattr(ra, "RAGGED_KERNEL_HW_VALIDATED", False)
-        att.ragged_mixed_attention(q, kp, vp, tabs, ctx, pp, start,
-                                   page_size=16, num_kv_heads=2,
-                                   num_decode=3)
-        assert not calls  # not validated: XLA path even under pallas ctx
+        with att.attention_context(backend, None):
+            att.ragged_mixed_attention(q, kp, vp, tabs, ctx, pp, start,
+                                       page_size=16, num_kv_heads=2,
+                                       num_decode=3)
+        assert bool(calls) is kernel
 
 
 def _poisoned_cell(rng, start, k1=1):
@@ -194,7 +187,7 @@ def _poisoned_cell(rng, start, k1=1):
 
 @pytest.mark.parametrize("start,k1", [(0, 1), (32, 1), (32, 3)],
                          ids=["first_chunk", "mid_prompt", "verify_k3"])
-def test_ragged_kernel_attends_live_kv_only(monkeypatch, start, k1):
+def test_ragged_kernel_attends_live_kv_only(start, k1):
     """What PR 26 made the default does work in proportion to the live KV:
     with every dead page and every table entry past a row's live pages
     poisoned, the kernel's output is finite and equal to the composition's
@@ -205,14 +198,14 @@ def test_ragged_kernel_attends_live_kv_only(monkeypatch, start, k1):
     clean, bad, kw = _poisoned_cell(np.random.default_rng(26), start, k1)
 
     def run(backend, args):
-        monkeypatch.setenv("DYNAMO_TPU_RAGGED_ATTENTION", backend)
-        if k1 == 1:
-            return np.asarray(att.ragged_mixed_attention(
-                *args, num_decode=32, **kw))
         q, kp, vp, tabs, ctx, pp, st = args
-        return np.asarray(att.ragged_verify_attention(
-            q, kp, vp, tabs, ctx - 1, pp, st, num_verify=32,
-            verify_width=k1, **kw))
+        with att.attention_context(backend, None):
+            if k1 == 1:
+                return np.asarray(att.ragged_mixed_attention(
+                    *args, num_decode=32, **kw))
+            return np.asarray(att.ragged_verify_attention(
+                q, kp, vp, tabs, ctx - 1, pp, st, num_verify=32,
+                verify_width=k1, **kw))
 
     ref = run("xla", clean)
     out = run("pallas_interpret", bad)
@@ -221,7 +214,7 @@ def test_ragged_kernel_attends_live_kv_only(monkeypatch, start, k1):
     assert np.isnan(run("xla", bad)).any()  # the poison is in reach of a gather
 
 
-def test_ragged_gate_demotion_is_counted(monkeypatch):
+def test_ragged_gate_demotion_is_counted():
     """A lane-gate demotion (64-lane KV span, below the 128-lane minimum)
     lands in pallas_fallback_counts under ("ragged attention", ...) — the
     series dynamo_pallas_fallback_total exposes (observability satellite)."""
@@ -236,11 +229,11 @@ def test_ragged_gate_demotion_is_counted(monkeypatch):
     tabs = jnp.asarray([[1, 0], [2, 3]], jnp.int32)
     ctx = jnp.asarray([2, 7], jnp.int32)
     pp = jnp.asarray([4, 5], jnp.int32)
-    monkeypatch.setenv("DYNAMO_TPU_RAGGED_ATTENTION", "pallas_interpret")
     before = dict(att.pallas_fallback_counts())
-    out = att.ragged_mixed_attention(q, kp, kp, tabs, ctx, pp, 0,
-                                     page_size=ps, num_kv_heads=n_kv,
-                                     num_decode=2)
+    with att.attention_context("pallas_interpret", None):
+        out = att.ragged_mixed_attention(q, kp, kp, tabs, ctx, pp, 0,
+                                         page_size=ps, num_kv_heads=n_kv,
+                                         num_decode=2)
     assert out.shape == q.shape
     after = att.pallas_fallback_counts()
     ragged_keys = [k for k in after if k[0] == "ragged attention"
@@ -320,19 +313,19 @@ def _live_work_cell(rng, kind, live, start, k1=1):
     return clean, bad, dict(page_size=_PS, num_kv_heads=n_kv)
 
 
-def _mixed(monkeypatch, backend, args, live, window=0, **kw):
+def _mixed(backend, args, live, window=0, **kw):
     """ragged_mixed_attention as llama.mixed_step hands it over
     (`kernel_lens`: 0 where the slot is empty); numpy."""
     import jax.numpy as jnp
 
     from dynamo_tpu.ops import attention as att
 
-    monkeypatch.setenv("DYNAMO_TPU_RAGGED_ATTENTION", backend)
     mask = np.zeros((_SLOTS,), bool)
     mask[list(live)] = True
-    return np.asarray(att.ragged_mixed_attention(
-        *args, num_decode=_SLOTS, window=window or None,
-        kernel_lens=jnp.where(jnp.asarray(mask), args[4], 0), **kw))
+    with att.attention_context(backend, None):
+        return np.asarray(att.ragged_mixed_attention(
+            *args, num_decode=_SLOTS, window=window or None,
+            kernel_lens=jnp.where(jnp.asarray(mask), args[4], 0), **kw))
 
 
 def _rows(live):
@@ -346,7 +339,7 @@ def _rows(live):
 @pytest.mark.parametrize("pattern", sorted(_LIVE))
 @pytest.mark.parametrize("window", [0, 12], ids=["full", "window12"])
 @pytest.mark.parametrize("kind", ["plain", "shared", "int8"])
-def test_ragged_kernel_skips_empty_rows(monkeypatch, kind, window, pattern):
+def test_ragged_kernel_skips_empty_rows(kind, window, pattern):
     """Live rows and the chunk's rows are the XLA composition's, an empty
     row reads zero, wherever the empty rows lie in the batch: first, in
     the middle, last, all of them, none."""
@@ -356,8 +349,8 @@ def test_ragged_kernel_skips_empty_rows(monkeypatch, kind, window, pattern):
     start = {"all_empty": 0, "empty_first": 16}.get(pattern, 40)
     clean, _, kw = _live_work_cell(np.random.default_rng(45), kind, live,
                                    start)
-    ref = _mixed(monkeypatch, "xla", clean, live, window, **kw)
-    out = _mixed(monkeypatch, "pallas_interpret", clean, live, window, **kw)
+    ref = _mixed("xla", clean, live, window, **kw)
+    out = _mixed("pallas_interpret", clean, live, window, **kw)
     rows = _rows(live)
     tol = 2e-2 if kind == "int8" else 2e-5
     np.testing.assert_allclose(out[rows], ref[rows], atol=tol, rtol=tol)
@@ -367,7 +360,7 @@ def test_ragged_kernel_skips_empty_rows(monkeypatch, kind, window, pattern):
 
 @pytest.mark.parametrize("window", [0, 12], ids=["full", "window12"])
 @pytest.mark.parametrize("kind", ["plain", "shared", "int8"])
-def test_ragged_kernel_with_no_decode_row(monkeypatch, kind, window):
+def test_ragged_kernel_with_no_decode_row(kind, window):
     """num_decode = 0 (a windowed chunk alone takes this form): the chunk
     is the only sequence, at start 0 and mid-prompt."""
     from dynamo_tpu.ops import attention as att
@@ -379,9 +372,9 @@ def test_ragged_kernel_with_no_decode_row(monkeypatch, kind, window):
                 np.zeros((0,), np.int32), pages, start)
 
         def run(backend):
-            monkeypatch.setenv("DYNAMO_TPU_RAGGED_ATTENTION", backend)
-            return np.asarray(att.ragged_mixed_attention(
-                *args, num_decode=0, window=window or None, **kw))
+            with att.attention_context(backend, None):
+                return np.asarray(att.ragged_mixed_attention(
+                    *args, num_decode=0, window=window or None, **kw))
 
         tol = 2e-2 if kind == "int8" else 2e-5
         np.testing.assert_allclose(run("pallas_interpret"), run("xla"),
@@ -390,7 +383,7 @@ def test_ragged_kernel_with_no_decode_row(monkeypatch, kind, window):
 
 @pytest.mark.parametrize("pattern", ["empty_middle", "all_empty"])
 @pytest.mark.parametrize("kind", ["plain", "shared", "int8"])
-def test_ragged_verify_windows_keep_their_blocks(monkeypatch, kind, pattern):
+def test_ragged_verify_windows_keep_their_blocks(kind, pattern):
     """decode_q = K + 1: an inactive window (zero table, position 0) has
     kv_len K + 1 and keeps its block on the trash page, so every row is
     the XLA composition's, the inactive windows' too."""
@@ -401,10 +394,10 @@ def test_ragged_verify_windows_keep_their_blocks(monkeypatch, kind, pattern):
         np.random.default_rng(47), kind, live, 40, k1)
 
     def run(backend):
-        monkeypatch.setenv("DYNAMO_TPU_RAGGED_ATTENTION", backend)
-        return np.asarray(att.ragged_verify_attention(
-            q, kp, vp, tabs, ctx - 1, pages, start, num_verify=_SLOTS,
-            verify_width=k1, **kw))
+        with att.attention_context(backend, None):
+            return np.asarray(att.ragged_verify_attention(
+                q, kp, vp, tabs, ctx - 1, pages, start, num_verify=_SLOTS,
+                verify_width=k1, **kw))
 
     tol = 2e-2 if kind == "int8" else 2e-5
     np.testing.assert_allclose(run("pallas_interpret"), run("xla"),
@@ -415,7 +408,7 @@ def test_ragged_verify_windows_keep_their_blocks(monkeypatch, kind, pattern):
                                      "all_empty"])
 @pytest.mark.parametrize("window", [0, 12], ids=["full", "window12"])
 @pytest.mark.parametrize("kind", ["plain", "shared"])
-def test_ragged_kernel_reads_nothing_nobody_owns(monkeypatch, kind, window,
+def test_ragged_kernel_reads_nothing_nobody_owns(kind, window,
                                                  pattern):
     """NaN in the trash page, in every free page and behind every table
     entry past a row's horizon: live rows and the chunk's rows bit for bit
@@ -423,19 +416,19 @@ def test_ragged_kernel_reads_nothing_nobody_owns(monkeypatch, kind, window,
     live = _LIVE[pattern]
     clean, bad, kw = _live_work_cell(np.random.default_rng(48), kind, live,
                                      40)
-    want = _mixed(monkeypatch, "pallas_interpret", clean, live, window, **kw)
-    got = _mixed(monkeypatch, "pallas_interpret", bad, live, window, **kw)
+    want = _mixed("pallas_interpret", clean, live, window, **kw)
+    got = _mixed("pallas_interpret", bad, live, window, **kw)
     rows = _rows(live)
     assert np.isfinite(want).all()
     np.testing.assert_array_equal(got[rows], want[rows])
     assert not got[~rows].any()
     # the poison is in reach of anything that follows a table or the pin
-    assert np.isnan(_mixed(monkeypatch, "xla", bad, live, window, **kw)).any()
+    assert np.isnan(_mixed("xla", bad, live, window, **kw)).any()
 
 
 @pytest.mark.parametrize("window", [0, 12], ids=["full", "window12"])
 @pytest.mark.parametrize("kind", ["plain", "shared", "int8"])
-def test_ragged_kernel_rows_do_not_talk_through_the_ring(monkeypatch, kind,
+def test_ragged_kernel_rows_do_not_talk_through_the_ring(kind,
                                                          window):
     """A live row's output, and the chunk's, are bit for bit what the same
     call gives with every other slot emptied: the ring that runs on across
@@ -443,9 +436,9 @@ def test_ragged_kernel_rows_do_not_talk_through_the_ring(monkeypatch, kind,
     else owns blocks before or after it."""
     live = _LIVE["all_live"]
     clean, _, kw = _live_work_cell(np.random.default_rng(49), kind, live, 40)
-    full = _mixed(monkeypatch, "pallas_interpret", clean, live, window, **kw)
+    full = _mixed("pallas_interpret", clean, live, window, **kw)
     for slot in live:
-        alone = _mixed(monkeypatch, "pallas_interpret", clean, (slot,),
+        alone = _mixed("pallas_interpret", clean, (slot,),
                        window, **kw)
         rows = _rows((slot,))
         np.testing.assert_array_equal(alone[rows], full[rows])

@@ -51,7 +51,7 @@ from dynamo_tpu.ops import attention as att_ops
 from dynamo_tpu.ops import json_guide
 from dynamo_tpu.ops import ssm as ssm_ops
 from dynamo_tpu.ops.moe import MOE_STATS
-from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.config import SLIDING, ModelConfig
 from dynamo_tpu.parallel.mesh import MeshConfig, build_mesh
 from dynamo_tpu.parallel import sharding as shd
 from dynamo_tpu.robustness import faults
@@ -255,6 +255,10 @@ class EngineMetrics:
         # KV rows are those within reach: min(context, sliding_window)
         self.attn_kinds: Dict[str, Dict[str, int]] = {
             kind: dict.fromkeys(self.attn, 0) for kind in ("full", "window")}
+        # query rows of a sliding layer whose softmax carried a learned
+        # sink (a layer's worth a step, like the rest; zero for a model
+        # whose sliding layers have none)
+        self.attn_kinds["window"]["sink_rows"] = 0
         # prefix-cache hits a model of kinds turned into misses (a hit is
         # exact only if the sliding layers' rows before it are still held;
         # a ring is its sequence's own, so none is), and those of a hybrid
@@ -296,13 +300,14 @@ class EngineMetrics:
         self._moe_lock = threading.Lock()
 
     def observe_decode_attention(self, contexts, steps: int,
-                                 window: Optional[int] = None) -> None:
+                                 window: Optional[int] = None,
+                                 sink: bool = False) -> None:
         """A fused window of `steps` decode steps over sequences whose
         contexts (tokens in the cache, the one being decoded included)
         are `contexts` at its first step. `window`: None = a model of one
         kind (`attn`); else both kinds of `attn_kinds`, a sliding layer's
         query reading min(context, window) rows (0: full layers alone, a
-        hybrid model's)."""
+        hybrid model's). `sink`: the sliding layers' softmax carries one."""
         if window is not None:
             ctx = (np.asarray(list(contexts), np.int64)[:, None]
                    + np.arange(steps, dtype=np.int64)[None, :])
@@ -311,6 +316,8 @@ class EngineMetrics:
                 a = self.attn_kinds[kind]
                 a["decode_q_rows"] += int(ctx.size)
                 a["decode_kv_rows"] += int(rows.sum())
+            if window and sink:
+                self.attn_kinds["window"]["sink_rows"] += int(ctx.size)
             return
         a = self.attn
         a["decode_q_rows"] += len(contexts) * steps
@@ -319,13 +326,16 @@ class EngineMetrics:
 
     def observe_mixed_attention(self, contexts, start: int, take: int,
                                 block_q: int = 8,
-                                window: Optional[int] = None) -> None:
+                                window: Optional[int] = None,
+                                sink: bool = False) -> None:
         """One ragged step: decode rows as above, and `take` tokens of a
         prompt from position `start` (`window` as above: a chunk token at
         position p reads min(p + 1, window) rows, a query block the rows
-        from its first token's reach to its last token)."""
+        from its first token's reach to its last token; `sink` as above)."""
         if window is not None:
             ctx = np.asarray(list(contexts), np.int64)
+            if window and sink:
+                self.attn_kinds["window"]["sink_rows"] += int(ctx.size) + take
             pos = start + np.arange(take, dtype=np.int64)
             first = pos[::block_q]  # each block's first token
             last = np.minimum(first + block_q, start + take)  # horizon
@@ -3123,7 +3133,8 @@ class Engine:
         if self.model_cfg.layer_types or self.model_cfg.mixer_types:
             # the same kernels' work as a mixed step's chunk, no decode row
             self.metrics.observe_mixed_attention(
-                [], start, take, window=self.model_cfg.sliding_window)
+                [], start, take, window=self.model_cfg.sliding_window,
+                sink=SLIDING in self.model_cfg.attn_sink_kinds)
         if self.model_cfg.mixer_types:
             self.metrics.observe_ssm(0, 1, take)
         # this dispatch ran the chunk alone — its tenant owns the segment
@@ -3837,10 +3848,13 @@ class Engine:
                             if s in self.seqs]
             # a hybrid model's sliding_window is 0: its full layers alone
             w = self.model_cfg.sliding_window if kinds else None
+            sink = SLIDING in self.model_cfg.attn_sink_kinds
             if chunk is not None:
-                m.observe_mixed_attention(contexts, start, take, window=w)
+                m.observe_mixed_attention(contexts, start, take, window=w,
+                                          sink=sink)
             elif given is None:  # a verify does not run the decode kernel
-                m.observe_decode_attention(contexts, steps, window=w)
+                m.observe_decode_attention(contexts, steps, window=w,
+                                           sink=sink)
             if self.model_cfg.is_dsa:
                 m.observe_dsa(self.model_cfg.index_topk,
                               self._dsa_selects(self.cfg.max_pages_per_seq),

@@ -1,9 +1,12 @@
 """Paged KV-cache: device-resident page pool + host-side page allocator.
 
-The device arrays are `[num_layers, num_pages, page_size, num_kv_heads *
-head_dim]` for K and V — page-major with the KV heads fused into the trailing
-lane axis, so one page is one contiguous slab the Pallas decode kernel moves
-with a single DMA. The fused axis is sharded over the `model` mesh axis
+The device arrays are `[num_layers, num_pages, page_size, lanes]`, one for K
+and one for V — page-major with the KV heads fused into the trailing lane
+axis, so one page is one contiguous slab the Pallas decode kernel moves with
+a single DMA. K's rows have `num_kv_heads * head_dim` lanes and V's
+`num_kv_heads * v_head_dim`: the same for most models, and narrower for V
+where a model's values are (MiMo-V2: keys 192 lanes a head, values 128; a
+head is never padded to the other's width). The fused axis is sharded over the `model` mesh axis
 (dynamo_tpu.parallel.sharding.KV_SPEC): head h occupies lanes [h*D, (h+1)*D),
 each tensor-parallel shard owns its local heads' lanes of every page, and the
 decode loop never crosses ICI for cache reads.
@@ -33,8 +36,11 @@ context, so its pool is sized for one RING of `KVCacheSpec.ring_pages`
 pages a decode slot (`window_ring_pages`) and a sequence's second table is
 that ring (`WindowRings`): logical page p lives in ring slot p % W, a page
 out of every query's reach is handed back by being written over, and a
-sequence never holds more than W pages a sliding layer. Both pools ride the
-engine's (k_pages, v_pages) plumbing as one pytree each.
+sequence never holds more than W pages a sliding layer. The kinds may differ
+in KV heads (`KVCacheSpec.window_kv_heads`), so a ring's rows and a full
+page's rows have lane counts of their own (MiMo-V2: 4 heads on the full
+layers, 768 | 512 lanes for K | V; 8 on the sliding ones, 1,536 | 1,024).
+Both pools ride the engine's (k_pages, v_pages) plumbing as one pytree each.
 
 A HYBRID model (`ModelConfig.mixer_types`: state-space, expert and attention
 layers, each layer one mixer; or every layer attention AND a state-space
@@ -104,6 +110,11 @@ class KVCacheSpec:
     window_layers: int = 0
     window_pages: int = 0
     ring_pages: int = 0
+    # KV heads of the sliding layers' rows where they differ from the full
+    # layers' (0: num_kv_heads), and the lanes a head of a V row where V is
+    # narrower than K (0: head_dim)
+    window_kv_heads: int = 0
+    v_head_dim: int = 0
     # a hybrid model (module docstring): num_layers above counts the
     # layers that attend; each of its state_layers layers with a Mamba-2
     # mixer keeps, a decode slot, one state of ssm_shape (float32) and
@@ -157,7 +168,11 @@ class KVCacheSpec:
             kinds = dict(
                 window_layers=cfg.kind_layers(SLIDING), ring_pages=ring,
                 # a ring a slot, and the trash page
-                window_pages=window_slots * ring + 1)
+                window_pages=window_slots * ring + 1,
+                window_kv_heads=(cfg.kind_kv_heads(SLIDING)
+                                 if cfg.kv_by_kind else 0),
+                v_head_dim=(cfg.value_head_dim
+                            if cfg.value_head_dim != head_dim else 0))
         if cfg.mixer_types:
             if quantized or tensor_parallel > 1:
                 raise ValueError(
@@ -221,15 +236,41 @@ class KVCacheSpec:
 
     @property
     def v_lane_width(self) -> int:
-        return self.index_lanes if self.v_from_k else self.lane_width
+        if self.v_from_k:
+            return self.index_lanes
+        if self.v_head_dim:  # bf16 (from_model): the heads' values alone
+            return self.num_kv_heads * self.v_head_dim
+        return self.lane_width
+
+    def kind_kv_heads(self) -> dict:
+        """{kind: KV heads a row of that kind's pool holds}."""
+        out = {"full": self.num_kv_heads}
+        if self.window_layers:
+            out["window"] = self.window_kv_heads or self.num_kv_heads
+        return out
+
+    def kind_lanes(self) -> dict:
+        """{kind: {"k": lanes of a K row, "v": of a V row}}: what a row
+        really holds, so a padded layout would show."""
+        per = self.num_kv_heads
+        return {kind: {"k": self.lane_width // per * n,
+                       "v": self.v_lane_width // per * n}
+                for kind, n in self.kind_kv_heads().items()}
 
     @property
     def window_shape(self):
-        """The sliding layers' pool (K and V alike); None with one pool."""
+        """The sliding layers' K pool; None with one pool."""
         if not self.window_layers:
             return None
         return (self.window_layers, self.window_pages, self.page_size,
-                self.lane_width)
+                self.kind_lanes()["window"]["k"])
+
+    @property
+    def window_v_shape(self):
+        """The sliding layers' V pool; None with one pool."""
+        if not self.window_layers:
+            return None
+        return self.window_shape[:3] + (self.kind_lanes()["window"]["v"],)
 
     def bytes_per_token(self) -> int:
         """Bytes a token of CONTEXT costs in the paged pool (with pools by
@@ -239,13 +280,13 @@ class KVCacheSpec:
     def bytes_per_token_by_kind(self) -> dict:
         """{kind: bytes a cached token costs on the layers of that kind}:
         a context token on the full layers, a token within a ring's reach
-        on the sliding ones. One entry where there is one pool."""
-        row = ((self.lane_width + self.v_lane_width)
-               * jnp.dtype(self.dtype).itemsize)
-        out = {"full": self.num_layers * row}
-        if self.window_layers:
-            out["window"] = self.window_layers * row
-        return out
+        on the sliding ones, each the kind's layers x its own row (K lanes
+        + V lanes; the kinds' KV heads, and so their rows, may differ). One
+        entry where there is one pool."""
+        size = jnp.dtype(self.dtype).itemsize
+        layers = {"full": self.num_layers, "window": self.window_layers}
+        return {kind: layers[kind] * (w["k"] + w["v"]) * size
+                for kind, w in self.kind_lanes().items()}
 
     def bytes_per_slot(self) -> int:
         """Bytes one state slot costs over the layers with a Mamba-2 mixer
@@ -325,7 +366,7 @@ def alloc_kv_pages(spec: KVCacheSpec, sharding=None):
         from dynamo_tpu.models.llama import ByKind
 
         return (ByKind(put(spec.shape), put(spec.window_shape)),
-                ByKind(put(spec.v_shape), put(spec.window_shape)))
+                ByKind(put(spec.v_shape), put(spec.window_v_shape)))
     return put(spec.shape), put(spec.v_shape)
 
 

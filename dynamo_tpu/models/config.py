@@ -110,7 +110,8 @@ ATTENTION_KINDS = (FULL, SLIDING)
 _MAPPED_MODEL_TYPES = frozenset((
     "llama", "mistral", "qwen2", "qwen3", "qwen3_moe", "mixtral", "phi3",
     "gemma", "gemma2", "gemma3", "gemma3_text", "deepseek_v2", "deepseek_v3",
-    "kimi_k2", "deepseek_v32", "nemotron_h", "falcon_h1"))
+    "kimi_k2", "deepseek_v32", "nemotron_h", "falcon_h1", "laguna",
+    "mimo_v2"))
 _MAPPED_ARCH_WORDS = ("Llama", "Mistral", "Qwen", "Mixtral", "Phi3", "Gemma",
                       "Deepseek", "Kimi")
 _KIND_KEYS = ("layer_types", "num_attention_heads_per_layer", "gating")
@@ -301,15 +302,107 @@ def _rope_of_kind(kind: str, rp: dict):
             float(rp.get("partial_rotary_factor", 1.0)), yarn)
 
 
+def _mimo_v2_from_hf(cfg: dict) -> dict:
+    """`model_type: mimo_v2`: sliding layers (a window, KV heads of their
+    own, a learned sink a query head in the softmax, a rotary base of their
+    own) and full layers mixed by `hybrid_layer_pattern` (0 full, 1
+    sliding), keys `head_dim` wide and values `v_head_dim`, a rotary over
+    the first int(head_dim x partial_rotary_factor) lanes, the heads'
+    outputs scaled by `attention_value_scale`, no output gate; leading
+    dense layers by `moe_layer_freq`, then sigmoid-scored experts under a
+    selection bias without groups or a shared expert. What the program
+    cannot serve refuses here, by the key's name."""
+    n = int(cfg["num_hidden_layers"])
+
+    def refuse(key, why):
+        raise ValueError(f"mimo_v2: {key}={cfg.get(key)!r} is not "
+                         f"implemented: {why}")
+
+    if cfg.get("add_full_attention_sink_bias"):
+        refuse("add_full_attention_sink_bias",
+               "a sink is served on the sliding layers' softmax only")
+    if cfg.get("attention_bias"):
+        refuse("attention_bias", "the projections are served without a bias")
+    for key in ("n_group", "topk_group"):
+        if int(cfg.get(key) or 1) != 1:
+            refuse(key, "the router is served without groups for this "
+                   "model type")
+    if int(cfg.get("n_shared_experts") or 0) > 0:
+        refuse("n_shared_experts", "no shared expert is served beside the "
+               "routed ones for this model type")
+    if cfg.get("scoring_func") != "sigmoid":
+        refuse("scoring_func", "the router's scores are sigmoid")
+    rs = cfg.get("rope_scaling") or {}
+    if (rs.get("rope_type") or rs.get("type") or "default") != "default":
+        refuse("rope_scaling", "both kinds take a plain rotary")
+    if cfg.get("hybrid_block_size") is not None:
+        refuse("hybrid_block_size", "the kinds follow hybrid_layer_pattern "
+               "layer by layer")
+    for swa, full in (("swa_head_dim", "head_dim"),
+                      ("swa_v_head_dim", "v_head_dim"),
+                      ("swa_num_attention_heads", "num_attention_heads")):
+        if cfg.get(swa) is not None and cfg[swa] != cfg.get(full):
+            refuse(swa, f"the sliding layers share {full}="
+                   f"{cfg.get(full)!r} with the full ones (KV heads alone "
+                   "differ by kind)")
+    pattern = cfg.get("hybrid_layer_pattern")
+    if pattern is None or len(pattern) != n or set(pattern) - {0, 1}:
+        refuse("hybrid_layer_pattern", f"it needs {n} entries of 0 (full) "
+               "or 1 (sliding)")
+    kinds = tuple(SLIDING if p else FULL for p in pattern)
+    listed = cfg.get("layer_types")
+    if listed is not None and tuple(listed) != kinds:
+        refuse("layer_types", "it disagrees with hybrid_layer_pattern")
+    window = int(cfg.get("sliding_window") or 0)
+    for key in ("attention_chunk_size", "sliding_window_size"):
+        if cfg.get(key) is not None and int(cfg[key]) != window:
+            refuse(key, f"it names the same span as sliding_window={window} "
+                   "and adds no mechanism")
+    freq = cfg.get("moe_layer_freq")
+    freq = [1] * n if freq is None else (
+        [int(f) for f in freq] if isinstance(freq, (list, tuple))
+        else [int(i % int(freq) == 0) for i in range(n)])
+    dense = n - sum(freq)
+    if len(freq) != n or set(freq) - {0, 1} or any(freq[:dense]):
+        refuse("moe_layer_freq", "dense layers are served as the LEADING "
+               "layers only (a zero behind a one is not)")
+    head_dim = int(cfg["head_dim"])
+    lanes = int(head_dim * float(cfg.get("partial_rotary_factor", 1.0)))
+    return dict(
+        layer_types=kinds,
+        heads_per_layer=(),
+        rope_by_kind=tuple(
+            (k, float(cfg.get(key, 10000.0)), lanes / head_dim, None)
+            for k, key in ((FULL, "rope_theta"), (SLIDING, "swa_rope_theta"))
+            if k in kinds),
+        rope_yarn_scaling=None, rope_llama3_scaling=None,
+        rope_longrope_scaling=None,
+        sliding_window=window if SLIDING in kinds else 0,
+        sliding_window_pattern=0,
+        first_k_dense=dense,
+        kv_heads_sliding=int(cfg.get("swa_num_key_value_heads")
+                             or cfg["num_key_value_heads"]),
+        v_head_dim=int(cfg.get("v_head_dim") or head_dim),
+        attn_sink_kinds=((SLIDING,) if cfg.get("add_swa_attention_sink_bias")
+                         and SLIDING in kinds else ()),
+        attn_value_scale=float(cfg.get("attention_value_scale") or 1.0),
+        rms_norm_eps=float(cfg.get("layernorm_epsilon")
+                           or cfg.get("rms_norm_eps", 1e-5)),
+    )
+
+
 def _layer_kinds_from_hf(cfg: dict, arch: str) -> dict:
     """The ModelConfig fields of a model whose layers are of more than one
     kind (`model_type: laguna`: window and full attention mixed, head
     counts and rotaries by kind, a per-head output gate, softmax-routed
     experts beside a shared expert of a width of its own, leading dense
-    layers named by mlp_only_layers); {} for every other model. Refuses,
-    loudly, what it would otherwise serve as another model."""
+    layers named by mlp_only_layers; `model_type: mimo_v2`:
+    _mimo_v2_from_hf); {} for every other model. Refuses, loudly, what it
+    would otherwise serve as another model."""
     mt = cfg.get("model_type")
     carried = [k for k in _KIND_KEYS if cfg.get(k) is not None]
+    if mt == "mimo_v2":
+        return _mimo_v2_from_hf(cfg)
     if mt != "laguna":
         if carried and mt not in _MAPPED_MODEL_TYPES and not any(
                 w in arch for w in _MAPPED_ARCH_WORDS):
@@ -317,7 +410,7 @@ def _layer_kinds_from_hf(cfg: dict, arch: str) -> dict:
                 f"model_type {mt!r} is not mapped and its config carries "
                 f"{carried}: serving it as a llama-family stack would "
                 "ignore them (per-layer kinds / head counts / an output "
-                "gate are mapped for model_type 'laguna')")
+                "gate are mapped for model_type 'laguna' and 'mimo_v2')")
         return {}
     n = int(cfg["num_hidden_layers"])
     gating = cfg.get("gating")
@@ -539,10 +632,20 @@ class ModelConfig:
     # None). attn_gate "per-head": o <- o * sigmoid(h W_g) a head, before
     # W_o. shared_expert_intermediate_size: the shared expert's own width
     # (0: num_shared_experts * intermediate_size).
+    # kv_heads_sliding: the sliding layers' KV heads where they differ
+    # from num_kv_heads, the full layers' (0: the same). A model of kinds
+    # may also store values narrower than keys: `v_head_dim` lanes a head
+    # beside K's head_dim (0: head_dim; MLA reads the field its own way).
+    # attn_sink_kinds: the kinds whose softmax carries a learned logit a
+    # query head in its denominator. attn_value_scale: what the heads'
+    # outputs are multiplied by before W_o.
     layer_types: Tuple[str, ...] = ()
     heads_per_layer: Tuple[int, ...] = ()
     rope_by_kind: Tuple[tuple, ...] = ()
     attn_gate: str = ""
+    kv_heads_sliding: int = 0
+    attn_sink_kinds: Tuple[str, ...] = ()
+    attn_value_scale: float = 1.0
     shared_expert_intermediate_size: int = 0
     # a HYBRID model: one entry a layer. nemotron_h: "mamba" | "moe" |
     # "attention", and every layer is x + mixer(norm(x)) with that ONE
@@ -629,10 +732,13 @@ class ModelConfig:
             raise ValueError(f"unknown attn_gate {self.attn_gate!r}")
         if self.layer_types:
             self._check_kinds()
-        elif (self.heads_per_layer or self.rope_by_kind or self.attn_gate):
+        elif (self.heads_per_layer or self.rope_by_kind or self.attn_gate
+              or self.kv_heads_sliding or self.attn_sink_kinds
+              or self.attn_value_scale != 1.0):
             raise ValueError(
-                "heads_per_layer / rope_by_kind / attn_gate without "
-                "layer_types: no layer would read them")
+                "heads_per_layer / rope_by_kind / attn_gate / "
+                "kv_heads_sliding / attn_sink_kinds / attn_value_scale "
+                "without layer_types: no layer would read them")
         if self.mixer_types:
             self._check_mixers()
         elif self.mamba_num_heads or self.expert_act or self.multipliers:
@@ -667,10 +773,19 @@ class ModelConfig:
                 or self.rope_local_theta or self.rope_llama3_scaling
                 or self.rope_longrope_scaling or self.rope_yarn_scaling):
             raise ValueError(
-                "layer_types is served for per-head K/V attention with a "
-                "rotary a kind (rope_by_kind) and none of: MLA, attention "
-                "bias, q/k norms, sandwich norms, score capping, the "
-                "model-wide rope scalings")
+                "layer_types is served for per-head K/V attention (query "
+                "and KV heads a kind, values as wide as keys or narrower, "
+                "a learned sink in a kind's softmax) with a rotary a kind "
+                "(rope_by_kind) and none of: MLA, attention bias, q/k "
+                "norms, sandwich norms, score capping, the model-wide "
+                "rope scalings")
+        if set(self.attn_sink_kinds) - set(kinds):
+            raise ValueError(f"attn_sink_kinds {self.attn_sink_kinds} names "
+                             "a kind no layer is of")
+        if not 0 <= self.v_head_dim <= self.head_dim:
+            raise ValueError(
+                f"v_head_dim {self.v_head_dim} over head_dim "
+                f"{self.head_dim}: values are as wide as keys or narrower")
         for kind in set(kinds):
             heads = {h for h, k in zip(self.heads_per_layer or
                                        (self.num_heads,) * n, kinds)
@@ -680,17 +795,20 @@ class ModelConfig:
                     f"{kind} layers have head counts {sorted(heads)}: one "
                     "parameter stack a kind needs one count a kind")
             (h,) = heads
-            if h % self.num_kv_heads:
-                raise ValueError(f"{h} query heads over {self.num_kv_heads}"
-                                 " KV heads")
+            if h % self.kind_kv_heads(kind):
+                raise ValueError(f"{h} query heads over "
+                                 f"{self.kind_kv_heads(kind)} KV heads")
         if set(k for k, *_ in self.rope_by_kind) - set(kinds) or (
                 self.rope_by_kind
                 and set(kinds) - set(k for k, *_ in self.rope_by_kind)):
             raise ValueError("rope_by_kind needs one entry for each kind of "
                              f"layer_types, got {self.rope_by_kind}")
         for _, _, share, _ in self.rope_by_kind:
+            # a share is lanes / head_dim: 64 / 192 is no exact binary
+            # fraction, so the product is held to its nearest whole
             lanes = self.head_dim * share
-            if lanes != int(lanes) or int(lanes) % 2 or not 0 < share <= 1:
+            if (abs(lanes - round(lanes)) > 1e-6 or round(lanes) % 2
+                    or not 0 < share <= 1):
                 raise ValueError(
                     f"partial_rotary_factor {share} of head_dim "
                     f"{self.head_dim} is not an even lane count")
@@ -823,6 +941,27 @@ class ModelConfig:
     def kind_layers(self, kind: str) -> int:
         return sum(1 for k in self.layer_types if k == kind)
 
+    def kind_kv_heads(self, kind: str) -> int:
+        """KV heads of the layers of `kind` (what a row of its pool holds)."""
+        if kind == SLIDING and self.kv_heads_sliding:
+            return self.kv_heads_sliding
+        return self.cache_kv_heads
+
+    @property
+    def kv_by_kind(self) -> bool:
+        """The kinds' K/V projections have shapes of their own (KV heads
+        differ by kind): wk / wv stack by kind, as wq / wo do."""
+        return bool(self.kv_heads_sliding
+                    and self.kv_heads_sliding != self.num_kv_heads)
+
+    @property
+    def value_head_dim(self) -> int:
+        """Lanes a head of a cached V row: head_dim, or a model of kinds'
+        narrower v_head_dim."""
+        if self.layer_types and self.v_head_dim and not self.is_mla:
+            return self.v_head_dim
+        return self.cache_head_dim
+
     @property
     def shared_expert_width(self) -> int:
         return (self.shared_expert_intermediate_size
@@ -903,7 +1042,10 @@ class ModelConfig:
         indexer's index_n_heads / index_head_dim / index_topk) and Laguna
         (`laguna`: full and sliding-window layers mixed by `layer_types`,
         head counts and rotaries by kind, a per-head output gate, leading
-        dense layers by `mlp_only_layers`: _layer_kinds_from_hf). Keys whose
+        dense layers by `mlp_only_layers`: _layer_kinds_from_hf) and
+        MiMo-V2 (`mimo_v2`: the kinds by `hybrid_layer_pattern`, KV heads a
+        kind, keys wider than values, a sink in the sliding softmax:
+        _mimo_v2_from_hf). Keys whose
         mechanism is not implemented refuse loudly (multi-token-prediction
         layers, interleaved dense layers, other scoring functions).
         """
@@ -944,7 +1086,8 @@ class ModelConfig:
                 "the module is a layer past num_hidden_layers that drafts "
                 "the next token; set the key to 0 to serve the model "
                 "without it")
-        if (cfg.get("moe_layer_freq") or 1) != 1:
+        if (cfg.get("moe_layer_freq") or 1) != 1 and cfg.get(
+                "model_type") != "mimo_v2":  # a list there: _mimo_v2_from_hf
             raise ValueError(
                 f"moe_layer_freq={cfg['moe_layer_freq']} (dense layers "
                 "interleaved after the leading ones) is not implemented")
@@ -1041,7 +1184,7 @@ class ModelConfig:
             num_shared_experts=cfg.get("n_shared_experts", 0) or 0,
             norm_topk_prob=bool(cfg.get("norm_topk_prob", True)),
             routed_scaling_factor=float(
-                cfg.get("routed_scaling_factor", 1.0)),
+                cfg.get("routed_scaling_factor", 1.0) or 1.0),  # null: 1
             moe_scoring=scoring if n_experts else "softmax",
             router_bias=bool(n_experts) and topk_method == "noaux_tc",
             n_group=n_group if n_experts else 1,
@@ -1553,6 +1696,32 @@ PRESETS["tiny-laguna-debug"] = ModelConfig(
                   (SLIDING, 10000.0, 1.0, None)),
     attn_gate="per-head",
 )
+
+# MiMo-V2's structure at a toy size: 1 dense layer of the full kind, then
+# one whole period [sliding x 5, full]; 8 query heads on both kinds over 2 KV
+# heads on the sliding layers and 1 on the full ones; keys 24 lanes a head
+# and values 16; a rotary over the first 8 of the 24 (a base a kind); window
+# 8; a sink a query head on the sliding layers; the heads' outputs x 0.707;
+# 16 sigmoid-routed experts top-2 under a selection bias, no shared one (8 *
+# 2 <= 16 keeps the grouped expert layer, the one the published 256 / top-8
+# takes)
+PRESETS["tiny-mimo-v2-debug"] = ModelConfig(
+    name="tiny-mimo-v2-debug",
+    hidden_size=64, intermediate_size=32, num_layers=7, num_heads=8,
+    num_kv_heads=1, kv_heads_sliding=2, head_dim=24, v_head_dim=16,
+    rms_norm_eps=1e-5, tie_word_embeddings=False, rope_theta=1e7,
+    num_experts=16, num_experts_per_tok=2, norm_topk_prob=True,
+    moe_scoring="sigmoid", router_bias=True,
+    first_k_dense=1, dense_intermediate_size=128,
+    sliding_window=8, sliding_window_pattern=0,
+    layer_types=(FULL,) + (SLIDING,) * 5 + (FULL,),
+    rope_by_kind=((FULL, 1e7, 8 / 24, None), (SLIDING, 1e4, 8 / 24, None)),
+    attn_sink_kinds=(SLIDING,), attn_value_scale=0.707,
+)
+# one chip's share of it: experts 4-7 of 16 held
+PRESETS["tiny-mimo-v2-ep4-debug"] = dataclasses.replace(
+    PRESETS["tiny-mimo-v2-debug"], name="tiny-mimo-v2-ep4-debug",
+    num_local_experts=4, local_expert_offset=4)
 
 # NVIDIA-Nemotron-3-Nano's structure at a toy size: the first nine letters
 # of its pattern (MEMEM*EME: every layer ONE mixer), Mamba-2 with 4 heads of

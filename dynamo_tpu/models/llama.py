@@ -155,11 +155,16 @@ def _post(cfg: ModelConfig, lp: Params, name: str, y: jax.Array) -> jax.Array:
 
 # leaf-name prefix of the leading dense layers' own parameter stack
 DENSE_PREFIX = "dense."
-# leaf-name prefix of the head-shaped attention leaves (wq, wo, wg) by kind,
-# where a model's layers are of more than one kind (cfg.layer_types): the
-# kinds' head counts may differ, so each kind stacks its own
+# leaf-name prefix of the head-shaped attention leaves (wq, wo, wg, a sink)
+# by kind, where a model's layers are of more than one kind
+# (cfg.layer_types): the kinds' head counts may differ, so each kind stacks
+# its own; wk and wv too where the kinds' KV heads differ (cfg.kv_by_kind)
 KIND_PREFIX = {FULL: "", SLIDING: "win."}
-_KIND_LEAVES = ("wq", "wo", "wg")
+
+
+def _kind_leaves(cfg: ModelConfig) -> Tuple[str, ...]:
+    return ("wq", "wo", "wg", "sink") + (
+        ("wk", "wv") if cfg.kv_by_kind else ())
 
 
 class ByKind(NamedTuple):
@@ -210,9 +215,18 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], str, float
         "attn_norm": ((l, e), nk, 0.0),
     }
     def attention(p, l, pre="", stacks=None):
-        # `stacks`: (leaf prefix, query heads, layers) for each stack of
-        # head-shaped leaves: one, or one a kind where cfg.layer_types
-        stacks = stacks or (("", h, l),)
+        # `stacks`: (leaf prefix, kind, layers) for each stack of
+        # head-shaped leaves: one (of no kind), or one a kind where
+        # cfg.layer_types
+        stacks = stacks or (("", None, l),)
+
+        def heads(k):
+            return h if k is None else cfg.kind_heads(k)
+
+        def kv_heads(k):
+            return kv if k is None else cfg.kind_kv_heads(k)
+
+        vd = cfg.value_head_dim  # head_dim, or a model of kinds' narrower
         if cfg.is_mla:
             # multi-head latent attention (DeepSeek-V2 family): queries
             # project per-head to [nope | rope] (through a low-rank latent
@@ -245,21 +259,28 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], str, float
                 p[pre + "idx_k_bias"] = ((l, di), "zeros", 0.0)
                 p[pre + "idx_w"] = w((l, e, hi))
         else:
-            for kp, hk, lk in stacks:
-                p[pre + kp + "wq"] = w((lk, e, hk, d))
-            p[pre + "wk"] = w((l, e, kv, d))
-            p[pre + "wv"] = w((l, e, kv, d))
-            for kp, hk, lk in stacks:
-                p[pre + kp + "wo"] = w((lk, hk, d, e))
+            for kp, k, lk in stacks:
+                p[pre + kp + "wq"] = w((lk, e, heads(k), d))
+            # one K/V stack for all layers, or one a kind where the kinds'
+            # KV heads differ
+            for kp, k, lk in (stacks if cfg.kv_by_kind
+                              else (("", None, l),)):
+                p[pre + kp + "wk"] = w((lk, e, kv_heads(k), d))
+                p[pre + kp + "wv"] = w((lk, e, kv_heads(k), vd))
+            for kp, k, lk in stacks:
+                p[pre + kp + "wo"] = w((lk, heads(k), vd, e))
             if cfg.attn_gate:
                 # the per-head output gate: one scalar a head from the
                 # layer's normed input (model dtype: 3072 x 72 at most)
-                for kp, hk, lk in stacks:
-                    p[pre + kp + "wg"] = w((lk, e, hk))
+                for kp, k, lk in stacks:
+                    p[pre + kp + "wg"] = w((lk, e, heads(k)))
+            for kp, k, lk in stacks:
+                if k in cfg.attn_sink_kinds:  # a logit a query head, f32
+                    p[pre + kp + "sink"] = ((lk, heads(k)), "sink", 0.0)
 
     scanned_kinds = cfg.layer_types[cfg.first_k_dense:]
     attention(p, l, stacks=tuple(
-        (KIND_PREFIX[k], cfg.kind_heads(k), scanned_kinds.count(k))
+        (KIND_PREFIX[k], k, scanned_kinds.count(k))
         for k in (FULL, SLIDING) if k in scanned_kinds) or None)
     p["mlp_norm"] = ((l, e), nk, 0.0)
     if cfg.post_norms:  # gemma-2 sandwich norms on branch outputs
@@ -305,8 +326,7 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, ...], str, float
         pre = DENSE_PREFIX
         p[pre + "attn_norm"] = ((ld, e), nk, 0.0)
         attention(p, ld, pre, stacks=(
-            (("", cfg.kind_heads(cfg.layer_types[0]), ld),)
-            if cfg.layer_types else None))
+            (("", cfg.layer_types[0], ld),) if cfg.layer_types else None))
         p[pre + "mlp_norm"] = ((ld, e), nk, 0.0)
         p[pre + "w_gate"] = w((ld, e, fd))
         p[pre + "w_up"] = w((ld, e, fd))
@@ -440,19 +460,39 @@ def _scan_layers_paged(cfg: ModelConfig, params: Params, body, x,
             carry[3] if extra else None)
 
 
+def _kind_runs(kinds) -> Tuple[Tuple[str, int, int], ...]:
+    """(kind, first position, length) of each run of equal neighbours."""
+    runs, j = [], 0
+    while j < len(kinds):
+        n = 1
+        while j + n < len(kinds) and kinds[j + n] == kinds[j]:
+            n += 1
+        runs.append((kinds[j], j, n))
+        j += n
+    return tuple(runs)
+
+
 def _scan_periods_paged(cfg: ModelConfig, params: Params, body, x,
                         k_pages: ByKind, v_pages: ByKind):
     """_scan_layers_paged for layers of more than one KIND: the leading
-    dense layers unrolled as there, then ONE lax.scan whose body is a whole
-    period of cfg.layer_types (its layers unrolled inside, so each layer's
-    kind is static: the kernels are specialised on the window, the head
-    count is a shape), then the layers of a last period cut short, unrolled.
+    dense layers unrolled as there, then ONE lax.scan over the periods of
+    cfg.layer_types, then the layers of a last period cut short. Inside a
+    period each RUN of layers of one kind in a row is a lax.scan of its own
+    (a run of one is called as it is), so a program holds one layer body a
+    run, not one a layer: a period of five sliding layers and a full one is
+    two bodies. A layer's kind stays static in its body: the kernels are
+    specialised on the window, the head counts are shapes.
+
+    The parameter stacks ride the scans' closure and a layer's leaves are
+    sliced out of them where the layer runs, by its index in the scanned
+    stack and in its kind's own (the head-shaped leaves, `_kind_leaves`,
+    stack by kind under KIND_PREFIX): a stack sliced by an outer scan and
+    again by an inner one would be copied a period at a time.
 
     Each kind has its own pools, carried flat as there; layer i of a kind
     (counted over the whole model, dense ones too) lives at i * P_kind.
     `body` is called as body(x, kp, vp, lp, page_off, kind) with the pools
-    of the layer's kind. The head-shaped leaves (wq, wo, wg) come from the
-    kind's own stack (KIND_PREFIX), every other leaf from the common one."""
+    of the layer's kind."""
     pools = [(k.reshape((-1,) + k.shape[2:]), v.reshape((-1,) + v.shape[2:]))
              for k, v in zip(k_pages, v_pages)]
     per_layer = [k.shape[1] for k in k_pages]
@@ -484,8 +524,8 @@ def _scan_periods_paged(cfg: ModelConfig, params: Params, body, x,
     scanned = _layer_params(params)
     whole = ({k: scanned.pop(k) for k in _EXPERT_STACKS}
              if cfg.moe_grouped else {})
-    by_kind = {kind: {leaf: scanned.pop(pre + leaf) for leaf in _KIND_LEAVES
-                      if pre + leaf in scanned}
+    by_kind = {kind: {leaf: scanned.pop(pre + leaf)
+                      for leaf in _kind_leaves(cfg) if pre + leaf in scanned}
                for kind, pre in KIND_PREFIX.items()}
     kinds = cfg.layer_types[nd:]
     period = cfg.kind_period
@@ -494,42 +534,37 @@ def _scan_periods_paged(cfg: ModelConfig, params: Params, body, x,
     # position j of a period -> its index among the period's layers of its kind
     within = [kinds[:j].count(kinds[j]) for j in range(period)]
 
-    def one(carry, common, own, j, layer_idx, t):
-        """Layer j of period t (layer_idx = t * period + j of the scanned
-        stack): `common` / `own` are the period's slices of the stacks."""
-        kind = kinds[j]
-        lp = dict(jax.tree.map(lambda a: a[j], common),
-                  **jax.tree.map(lambda a: a[within[j]], own[kind]))
+    def one(carry, kind, layer_idx, kind_idx):
+        """The layer at `layer_idx` of the scanned stack, `kind_idx` of
+        its kind's own stack (either may be traced)."""
+        lp = dict(jax.tree.map(lambda a: a[layer_idx], scanned),
+                  **jax.tree.map(lambda a: a[kind_idx], by_kind[kind]))
         if whole:
             lp.update(whole, moe_layer=layer_idx)
         which = _POOL_OF[kind]
         first = cfg.layer_types[:nd].count(kind)
-        off = (first + t * per_period[kind] + within[j]) * per_layer[which]
-        return layer(carry, lp, kind, off)
+        return layer(carry, lp, kind, (first + kind_idx) * per_layer[which])
 
-    def periods(a, per):
-        return a[:n_periods * per].reshape((n_periods, per) + a.shape[1:])
-
-    def wrapped(carry, xs):
-        common, own, t = xs
-        for j in range(period):
-            carry = one(carry, common, own, j, t * period + j, t)
-        return carry, None
+    def runs_of(carry, kinds, t):
+        """The runs of `kinds`, the leading layers of period t."""
+        for kind, j, n in _kind_runs(kinds):
+            layer0 = t * period + j
+            kind0 = t * per_period[kind] + within[j]
+            if n == 1:
+                carry = one(carry, kind, layer0, kind0)
+            else:
+                carry, _ = jax.lax.scan(
+                    lambda c, i, kind=kind, layer0=layer0, kind0=kind0: (
+                        one(c, kind, layer0 + i, kind0 + i), None),
+                    carry, jnp.arange(n))
+        return carry
 
     if n_periods:
-        carry, _ = jax.lax.scan(wrapped, carry, (
-            jax.tree.map(lambda a: periods(a, period), scanned),
-            {k: jax.tree.map(lambda a, k=k: periods(a, per_period[k]), v)
-             for k, v in by_kind.items()},
-            jnp.arange(n_periods)))
+        carry, _ = jax.lax.scan(
+            lambda c, t: (runs_of(c, kinds[:period], t), None),
+            carry, jnp.arange(n_periods))
     if tail:
-        rest = jax.tree.map(lambda a: a[n_periods * period:], scanned)
-        own = {k: jax.tree.map(
-            lambda a, k=k: a[n_periods * per_period[k]:], v)
-            for k, v in by_kind.items()}
-        for j in range(tail):
-            carry = one(carry, rest, own, j, n_periods * period + j,
-                        n_periods)
+        carry = runs_of(carry, kinds[:tail], n_periods)
     x, pools = carry[:2]
     return (x,
             ByKind(*(kf.reshape(k.shape) for (kf, _), k
@@ -609,11 +644,13 @@ def _rope_of_kind(cfg: ModelConfig, kind: str, a: jax.Array,
     YaRN attention_factor scales cos and sin (apply_rope), so it reaches
     the rotated lanes of q and k only."""
     (theta, share, yarn), = [r[1:] for r in cfg.rope_by_kind if r[0] == kind]
-    lanes = int(a.shape[-1] * share)
-    turned = apply_rope(a[..., :lanes], positions, theta, yarn_scaling=yarn)
+    lanes = round(a.shape[-1] * share)  # 64 / 192 is no binary fraction
     if lanes == a.shape[-1]:
-        return turned
-    return jnp.concatenate([turned, a[..., lanes:]], axis=-1)
+        return apply_rope(a, positions, theta, yarn_scaling=yarn)
+    with jax.named_scope("attn_qk_rope"):
+        turned = apply_rope(a[..., :lanes], positions, theta,
+                            yarn_scaling=yarn)
+        return jnp.concatenate([turned, a[..., lanes:]], axis=-1)
 
 
 def _qkv_mla(cfg: ModelConfig, lp: Params, x: jax.Array,
@@ -878,10 +915,23 @@ def _chunk_views(cfg: ModelConfig, pages: ByKind, start, c: int,
     return out
 
 
+def _kind_sink(cfg: ModelConfig, lp: Params, kind: str) -> dict:
+    """The attention ops' `sink` argument of a layer of `kind`: the layer's
+    learned logit a query head [H] float32 where the kind's softmax carries
+    one (an operand: read from the parameters, not baked into the
+    program), nothing where it does not."""
+    return {"sink": lp["sink"]} if kind in cfg.attn_sink_kinds else {}
+
+
 def _kind_layer_tail(cfg: ModelConfig, lp: Params, x, h, o, token_mask):
-    """What follows attention in a layer of a model of kinds: the gated
-    output projection on the residual, then the MLP or expert layer.
-    Returns (x, the expert layer's counts)."""
+    """What follows attention in a layer of a model of kinds: the heads'
+    outputs under the model's value scale, the gated output projection on
+    the residual, then the MLP or expert layer. Returns (x, the expert
+    layer's counts)."""
+    if cfg.attn_value_scale != 1.0:
+        # on the averaged values: the sum is linear, so the same number as
+        # on every V row, and the cache keeps V as projected
+        o = o * jnp.asarray(cfg.attn_value_scale, o.dtype)
     x = x + _attn_out(cfg, lp, o, gate_in=h)
     y, counts = _mlp(cfg, lp, rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps),
                      token_mask=token_mask)
@@ -1050,9 +1100,17 @@ def _init_dt_bias(u):
     return dt + jnp.log(-jnp.expm1(-dt))
 
 
-# param_specs kinds of the state-space vectors -> their initialiser over
-# uniform [0, 1) draws (init_params and the loader's random-int8 path)
-SSM_INITS = {"ssm_a_log": _init_a_log, "ssm_dt_bias": _init_dt_bias}
+def _init_sink(u):
+    """An attention sink's logit, uniform in [-2, 2): never the zero that
+    would hide a sink that is dropped or misplaced."""
+    return 4.0 * u - 2.0
+
+
+# param_specs kinds of the float32 vectors (the state-space ones, an
+# attention sink) -> their initialiser over uniform [0, 1) draws
+# (init_params and the loader's random-int8 path)
+SSM_INITS = {"ssm_a_log": _init_a_log, "ssm_dt_bias": _init_dt_bias,
+             "sink": _init_sink}
 # the leaves of each mixer kind's parameter stack
 _MIXER_LEAVES = {
     MAMBA: ("ssm_in", "ssm_conv_w", "ssm_conv_b", "ssm_dt_bias", "ssm_a_log",
@@ -1519,15 +1577,25 @@ def prefill(
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
         if kind is not None:
             q, k, v = _qkv(cfg, lp, h, positions, kind=kind)
-            # a bucket no longer than the window: the mask bites nowhere
-            akw = ({"window": cfg.sliding_window}
-                   if kind == SLIDING and s > cfg.sliding_window else {})
-            with jax.named_scope(_ATTN_SCOPE[kind]):
-                o = att.prefill_attention(q, k, v, seq_len, **akw)
+            sink = _kind_sink(cfg, lp, kind)
             own = (pages.full if kind == FULL
                    else pages.window[:s // page_size])
             kp, vp = att.write_kv_prefill(
                 kp, vp, k, v, own + page_off, page_size=page_size)
+            with jax.named_scope(_ATTN_SCOPE[kind]):
+                if kind == SLIDING and s > cfg.sliding_window:
+                    # the flash kernel takes no window: the rows just
+                    # written are attended as ONE chunk from position 0 by
+                    # the paged kernel that masks below a static window
+                    # (the ring holds the whole bucket: checked above)
+                    o = att.chunk_attention(
+                        q, kp, vp, own + page_off, 0, page_size=page_size,
+                        num_kv_heads=cfg.kind_kv_heads(kind),
+                        window=cfg.sliding_window, **sink)
+                else:
+                    # a bucket no longer than the window: the mask bites
+                    # nowhere
+                    o = att.prefill_attention(q, k, v, seq_len, **sink)
             x, counts = _kind_layer_tail(cfg, lp, x, h, o, token_mask)
             return x, kp, vp, counts
         q, k, v = _qkv(cfg, lp, h, positions,
@@ -1619,7 +1687,8 @@ def prefill_chunk(
             with jax.named_scope(_ATTN_SCOPE[kind]):
                 o = att.chunk_attention(
                     q, kp, vp, table + page_off, wstart, page_size=page_size,
-                    num_kv_heads=cfg.cache_kv_heads, **akw)
+                    num_kv_heads=cfg.kind_kv_heads(kind),
+                    **_kind_sink(cfg, lp, kind), **akw)
             x, counts = _kind_layer_tail(cfg, lp, x, h, o, token_mask)
             return x, kp, vp, counts
         q, k, v = _qkv(cfg, lp, h, positions,
@@ -1889,8 +1958,9 @@ def decode_step(
             with jax.named_scope(_ATTN_SCOPE[kind]):
                 o = att.paged_attention_decode(
                     q, kp, vp, tb + page_off, ctx, page_size=page_size,
-                    num_kv_heads=cfg.cache_kv_heads,
-                    kernel_lens=jnp.where(kernel_lens > 0, ctx, 0), **akw)
+                    num_kv_heads=cfg.kind_kv_heads(kind),
+                    kernel_lens=jnp.where(kernel_lens > 0, ctx, 0),
+                    **_kind_sink(cfg, lp, kind), **akw)
             x, counts = _kind_layer_tail(cfg, lp, x, h, o, live)
             return x, kp, vp, counts
         q, k, v = _qkv(cfg, lp, h, positions,
@@ -2021,9 +2091,10 @@ def mixed_step(
             with jax.named_scope(_ATTN_SCOPE[kind]):
                 o = att.ragged_mixed_attention(
                     q, kp, vp, tb + page_off, ctx, table + page_off, wstart,
-                    page_size=page_size, num_kv_heads=cfg.cache_kv_heads,
-                    num_decode=b,
-                    kernel_lens=jnp.where(kernel_lens > 0, ctx, 0), **akw)
+                    page_size=page_size,
+                    num_kv_heads=cfg.kind_kv_heads(kind), num_decode=b,
+                    kernel_lens=jnp.where(kernel_lens > 0, ctx, 0),
+                    **_kind_sink(cfg, lp, kind), **akw)
             x, counts = _kind_layer_tail(cfg, lp, x, h, o, token_mask)
             return x, kp, vp, counts
         q, k, v = _qkv(cfg, lp, h, all_pos,

@@ -152,6 +152,13 @@ def load_hf_safetensors(cfg: ModelConfig, files) -> Dict[str, jax.Array]:
     """Stream HF-layout tensors into the stacked [num_layers, ...] layout."""
     from safetensors import safe_open
 
+    if cfg.layer_types:
+        raise NotImplementedError(
+            "loading a checkpoint of a model whose layers are of more than "
+            "one kind (layer_types) is not implemented: the head-shaped "
+            "leaves stack by kind (models/llama.KIND_PREFIX) and no weight "
+            "file has been read against that yet; such a model is served "
+            "on seeded random weights")
     dt = jnp.dtype(cfg.dtype)
     e, h, kv, d, f = (
         cfg.hidden_size,

@@ -232,6 +232,12 @@ class MemoryAccountant:
             "state_slots": state_slots,
             "bytes_per_token_by_kind": eng.kv_spec.bytes_per_token_by_kind(),
             "rows_by_kind": rows_by_kind,
+            # what a row of each kind's pool really holds: KV heads, and
+            # lanes of its K and of its V row (the kinds may differ in KV
+            # heads and V may be narrower than K; a padded layout would
+            # show here as lanes over heads x the model's widths)
+            "kv_heads_by_kind": eng.kv_spec.kind_kv_heads(),
+            "kv_lanes_by_kind": eng.kv_spec.kind_lanes(),
             # the row kinds a page holds, lanes a token a layer: the K/V or
             # latent row, and the V pool's (a classic model's V row, an
             # indexed MLA model's indexer key, nothing for plain MLA)

@@ -192,6 +192,31 @@ def _pool_kv_heads(k_pages: jax.Array, head_dim: int,
     return k_pages.shape[-1] // head_dim
 
 
+def _v_head_dim(v_pages, n_kv: int, head_dim: int) -> int:
+    """Lanes a head of a V row. K and V rows need not be equally wide
+    (MiMo-V2: keys 192 lanes a head, values 128): a bf16 V pool's rows are
+    its heads' values and nothing else, so its own width says; an int8
+    row's width holds scales too and a shared row is K's: `head_dim`."""
+    if shared_kv(v_pages) or v_pages.dtype == jnp.int8:
+        return head_dim
+    return v_pages.shape[-1] // n_kv
+
+
+def _softmax(scores: jax.Array, sink=None) -> jax.Array:
+    """float32 softmax over the last axis. `sink` (broadcastable against
+    scores[..., :1]): a learned logit that joins the max and the
+    denominator and adds nothing to the numerator, so the probabilities of
+    a row sum to less than one. None traces plain softmax."""
+    s32 = scores.astype(jnp.float32)
+    if sink is None:
+        return jax.nn.softmax(s32, axis=-1)
+    with jax.named_scope("attn_sink"):
+        sink = sink.astype(jnp.float32)
+        m = jnp.maximum(jnp.max(s32, axis=-1, keepdims=True), sink)
+        p = jnp.exp(s32 - m)
+        return p / (jnp.sum(p, axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
 def _gather_kv(pages_pool: jax.Array, idx: jax.Array, n_kv: int,
                head_dim: int, dtype, lane_blocks=None) -> jax.Array:
     """Gather page rows by id and return [..., ps, KV, D] values
@@ -297,6 +322,7 @@ def paged_attention_decode_xla(
     lane_blocks=None,
     window=None,  # traced scalar: attend only the last `window` positions
     logit_cap: float = 0.0,
+    sink=None,  # [H] float32: a learned logit a head in the softmax
 ) -> jax.Array:
     """Reference paged decode attention (gather + masked softmax).
 
@@ -311,9 +337,10 @@ def paged_attention_decode_xla(
                    lane_blocks).reshape(
         bsz, pmax * page_size, n_kv, head_dim
     ).transpose(0, 2, 1, 3)
+    vd = _v_head_dim(v_pages, n_kv, head_dim)
     v = k if shared_kv(v_pages) else _gather_kv(
-        v_pages, block_table, n_kv, head_dim, q.dtype, lane_blocks).reshape(
-        bsz, pmax * page_size, n_kv, head_dim
+        v_pages, block_table, n_kv, vd, q.dtype, lane_blocks).reshape(
+        bsz, pmax * page_size, n_kv, vd
     ).transpose(0, 2, 1, 3)
     k = repeat_kv(k, n_heads // n_kv, axis=1)
     v = repeat_kv(v, n_heads // n_kv, axis=1)
@@ -328,7 +355,8 @@ def paged_attention_decode_xla(
         lower = jnp.where(window > 0, context_lens - window, 0)
         mask &= span >= lower[:, None, None]
     scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+    probs = _softmax(
+        scores, None if sink is None else sink[None, :, None]).astype(q.dtype)
     return jnp.einsum("bhs,bhsd->bhd", probs, v)
 
 
@@ -340,6 +368,7 @@ def prefill_attention_xla(
     *,
     window=None,
     logit_cap: float = 0.0,
+    sink=None,  # [H] float32: a learned logit a head in the softmax
 ) -> jax.Array:
     """Causal self-attention over a single padded prompt."""
     s, n_heads, head_dim = q.shape
@@ -355,7 +384,8 @@ def prefill_attention_xla(
     if window is not None:
         mask &= jnp.where(window > 0, ki > qi - window, True)
     scores = jnp.where(mask[None], scores, jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+    probs = _softmax(
+        scores, None if sink is None else sink[:, None, None]).astype(q.dtype)
     return jnp.einsum("hqk,khd->qhd", probs, v)
 
 
@@ -380,6 +410,7 @@ def chunk_attention(
     num_kv_heads=None,
     window=None,
     logit_cap: float = 0.0,
+    sink=None,  # [H] float32: a learned logit a head in the softmax
 ) -> jax.Array:
     """Chunked-prefill attention: C chunk queries over the sequence's cached
     pages (prefix + the chunk itself, already written) with a causal mask in
@@ -405,18 +436,20 @@ def chunk_attention(
         window=window, logit_cap=logit_cap,
         int8_validated=pa.CHUNK_KERNEL_INT8_HW_VALIDATED,
         static_window_is_ragged=True)
-    if route is None:
+    if route is None or (route.kernel and sink is not None):
         # a static window: the ragged kernel with no decode row is the
         # chunk kernel that masks below it (one windowed chunk kernel,
-        # not two)
+        # not two); and the one that carries a sink
         return ragged_mixed_attention(
             q, k_pages, v_pages, jnp.zeros((0, pages.shape[0]), jnp.int32),
             jnp.zeros((0,), jnp.int32), pages, start, page_size=page_size,
-            num_kv_heads=num_kv_heads, num_decode=0, window=window)
+            num_kv_heads=num_kv_heads, num_decode=0, window=window,
+            sink=sink)
     if not route.kernel:
         return chunk_attention_xla(
             q, k_pages, v_pages, pages, start, page_size=page_size,
-            num_kv_heads=num_kv_heads, window=window, logit_cap=logit_cap)
+            num_kv_heads=num_kv_heads, window=window, logit_cap=logit_cap,
+            sink=sink)
 
     def call(q, kp, vp, pg, st):
         return pa.chunk_prefill_attention(
@@ -440,6 +473,7 @@ def chunk_attention_xla(
     num_kv_heads=None,
     window=None,
     logit_cap: float = 0.0,
+    sink=None,  # [H] float32: a learned logit a head in the softmax
 ) -> jax.Array:
     """Reference chunk attention (gather + masked softmax): the CPU/tier-1
     fallback for chunk_attention, and one leg of the ragged mixed step's XLA
@@ -449,9 +483,9 @@ def chunk_attention_xla(
     s_ctx = pages.shape[0] * page_size
     k = _gather_kv(k_pages, pages, n_kv, head_dim, q.dtype).reshape(
         s_ctx, n_kv, head_dim)
+    vd = _v_head_dim(v_pages, n_kv, head_dim)
     v = k if shared_kv(v_pages) else _gather_kv(
-        v_pages, pages, n_kv, head_dim, q.dtype).reshape(
-        s_ctx, n_kv, head_dim)
+        v_pages, pages, n_kv, vd, q.dtype).reshape(s_ctx, n_kv, vd)
     k = repeat_kv(k, n_heads // n_kv, axis=1)
     v = repeat_kv(v, n_heads // n_kv, axis=1)
     scale = 1.0 / jnp.sqrt(head_dim).astype(q.dtype)
@@ -463,7 +497,8 @@ def chunk_attention_xla(
     if window is not None:
         mask &= jnp.where(window > 0, kpos > qpos - window, True)
     scores = jnp.where(mask, scores, jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+    probs = _softmax(
+        scores, None if sink is None else sink[:, None, None]).astype(q.dtype)
     return jnp.einsum("hcs,shd->chd", probs, v)
 
 
@@ -482,6 +517,7 @@ def ragged_mixed_attention(
     window=None,
     logit_cap: float = 0.0,
     kernel_lens=None,  # [B] context_lens with 0 for a slot that holds nothing
+    sink=None,  # [H] float32: a learned logit a head in the softmax
 ) -> jax.Array:
     """Mixed ragged-batch attention: B decode rows AND one prefill chunk in
     a single program (the RPA unification — see ops/ragged_attention.py).
@@ -512,21 +548,21 @@ def ragged_mixed_attention(
                 p_pages, p_start, q.shape[0] - b,
                 lens_of=lambda cl: cl,
                 starts_of=lambda cl: jnp.maximum(cl - 1, 0)),
-            page_size=page_size, num_decode=b, window=window or 0)
+            sink=sink, page_size=page_size, num_decode=b, window=window or 0)
     # XLA composition: the decode gather and chunk gather reference paths,
     # concatenated — token-identical to the separate-program paths by
     # construction, which is what the mixed-step parity tests pin.
     if not b:  # a windowed chunk alone (chunk_attention)
         return chunk_attention_xla(
             q, k_pages, v_pages, p_pages, p_start, page_size=page_size,
-            num_kv_heads=n_kv, window=window, logit_cap=logit_cap)
+            num_kv_heads=n_kv, window=window, logit_cap=logit_cap, sink=sink)
     dec = paged_attention_decode_xla(
         q[:b], k_pages, v_pages, block_tables, context_lens,
         page_size=page_size, num_kv_heads=n_kv,
-        window=window, logit_cap=logit_cap)
+        window=window, logit_cap=logit_cap, sink=sink)
     chk = chunk_attention_xla(
         q[b:], k_pages, v_pages, p_pages, p_start, page_size=page_size,
-        num_kv_heads=n_kv, window=window, logit_cap=logit_cap)
+        num_kv_heads=n_kv, window=window, logit_cap=logit_cap, sink=sink)
     return jnp.concatenate([dec, chk], axis=0)
 
 
@@ -966,9 +1002,10 @@ def _route(op: str, n_heads: int, head_dim: int, n_kv: int, pool=None, *,
         backend = _demote(backend, op, "seq_mesh",
                           "sequence-parallel mesh shards the pool under "
                           "GSPMD")
-    if not paged and head_dim % 128 != 0 and head_dim not in (32, 64):
+    if not paged and head_dim % 128 != 0 and head_dim not in (32, 64, 192):
         # e.g. MLA's latent width (kv_lora_rank + rope = 576): no Mosaic
-        # tiling for off-size trailing dims
+        # tiling for off-size trailing dims (192 over values of 128 lanes
+        # compiles for a v5e and is held to the XLA twin on the chip)
         backend = _demote(backend, op, "head_dim",
                           f"no Mosaic tiling for head dim {head_dim}")
     if (static_window_is_ragged and backend in _KERNEL_BACKENDS
@@ -1025,8 +1062,12 @@ _HEADS = P(None, "model", None)  # [rows, H, D]
 _POOL = P(None, None, "model")  # [P, ps, KV*D]
 
 
-def _sharded(route: _Route, call, args, in_specs, out_specs=_HEADS):
-    """`call(*args)` directly, or under shard_map over the route's mesh."""
+def _sharded(route: _Route, call, args, in_specs, out_specs=_HEADS,
+             sink=None):
+    """`call(*args)` directly, or under shard_map over the route's mesh.
+    `sink` [H], where given, rides as a last argument split by heads."""
+    if sink is not None:
+        args, in_specs = (*args, sink), (*in_specs, P("model"))
     if route.mesh is None:
         return call(*args)
     return jax.shard_map(call, mesh=route.mesh, in_specs=in_specs,
@@ -1052,16 +1093,18 @@ def _ragged_tables(block_tables, rows, p_pages, p_start, chunk_len: int,
     return tabs, kv_lens, q_starts
 
 
-def _ragged_kernel(route: _Route, q, k_pages, v_pages, tables, **kw):
+def _ragged_kernel(route: _Route, q, k_pages, v_pages, tables, *,
+                   sink=None, **kw):
     from dynamo_tpu.ops import ragged_attention as ra
 
-    def call(q, kp, vp, tb, kl, qs):
+    def call(q, kp, vp, tb, kl, qs, *sk):
         return ra.ragged_paged_attention(
             q, kp, vp, tb, kl, qs, num_kv_heads=route.kv_heads,
-            interpret=route.interpret, **kw)
+            interpret=route.interpret, sink=sk[0] if sk else None, **kw)
 
     return _sharded(route, call, (q, k_pages, v_pages, *tables),
-                    (_HEADS, _POOL, _POOL, P(None, None), P(None), P(None)))
+                    (_HEADS, _POOL, _POOL, P(None, None), P(None), P(None)),
+                    sink=sink)
 
 
 def paged_attention_decode(
@@ -1076,6 +1119,7 @@ def paged_attention_decode(
     window=None,
     logit_cap: float = 0.0,
     kernel_lens=None,  # [B] context_lens with 0 for a slot that holds nothing
+    sink=None,  # [H] float32: a learned logit a head in the softmax
 ) -> jax.Array:
     """`kernel_lens` is what the Pallas kernel is handed in place of
     `context_lens`: it does nothing for a slot at context 0 (no page copy,
@@ -1089,26 +1133,27 @@ def paged_attention_decode(
     if route.kernel:
         from dynamo_tpu.ops import pallas_attention as pa
 
-        def call(q, kp, vp, bt, cl):
+        def call(q, kp, vp, bt, cl, *sk):
             return pa.paged_attention_decode(
                 q, kp, vp, bt, cl, page_size=page_size,
                 num_kv_heads=route.kv_heads, interpret=route.interpret,
-                window=window or 0)
+                window=window or 0, sink=sk[0] if sk else None)
 
         if kernel_lens is not None:
             context_lens = kernel_lens
     else:
-        def call(q, kp, vp, bt, cl):
+        def call(q, kp, vp, bt, cl, *sk):
             return paged_attention_decode_xla(
                 q, kp, vp, bt, cl, page_size=page_size,
                 num_kv_heads=route.kv_heads, lane_blocks=route.lane_blocks,
-                window=window, logit_cap=logit_cap)
+                window=window, logit_cap=logit_cap,
+                sink=sk[0] if sk else None)
 
     # the batch shards on `data` besides
     return _sharded(
         route, call, (q, k_pages, v_pages, block_table, context_lens),
         (P("data", "model", None), _POOL, _POOL, P("data", None),
-         P("data")), P("data", "model", None))
+         P("data")), P("data", "model", None), sink=sink)
 
 
 def prefill_attention(
@@ -1119,10 +1164,11 @@ def prefill_attention(
     *,
     window=None,
     logit_cap: float = 0.0,
+    sink=None,  # [H] float32: a learned logit a head in the softmax
 ) -> jax.Array:
     sp_mesh = _seq_parallel_mesh()
     if sp_mesh is not None:
-        if window is not None or logit_cap:
+        if window is not None or logit_cap or sink is not None:
             # the ring/Ulysses paths don't model windows/caps; the Engine
             # rejects --sp for sliding-window models before we ever get here
             raise ValueError(
@@ -1134,15 +1180,17 @@ def prefill_attention(
                    unsplit_keeps_backend=True)
     if not route.kernel:
         return prefill_attention_xla(q, k, v, seq_len, window=window,
-                                     logit_cap=logit_cap)
+                                     logit_cap=logit_cap,
+                                     sink=sink)
     from dynamo_tpu.ops import pallas_attention as pa
 
-    def call(q, k, v, sl):
-        return pa.prefill_attention(q, k, v, sl, interpret=route.interpret)
+    def call(q, k, v, sl, *sk):
+        return pa.prefill_attention(q, k, v, sl, interpret=route.interpret,
+                                    sink=sk[0] if sk else None)
 
     # Prefill is single-sequence: replicated over `data`, heads on `model`.
     return _sharded(route, call, (q, k, v, jnp.asarray(seq_len, jnp.int32)),
-                    (_HEADS, _HEADS, _HEADS, P()))
+                    (_HEADS, _HEADS, _HEADS, P()), sink=sink)
 
 
 def _seq_parallel_prefill(q, k, v, seq_len, sp_mesh: Mesh) -> jax.Array:

@@ -63,7 +63,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dynamo_tpu.ops.attention import shared_kv
+from dynamo_tpu.ops.attention import _v_head_dim, shared_kv
 
 NEG_INF = float("-inf")
 
@@ -101,13 +101,29 @@ def _kv_block(kbuf, vbuf, cur, tokens: int, n_kv: int, d: int,
     k = kbuf[cur].reshape(tokens, n_kv * d)
     if shared:
         return k, k
+    # V's rows may be narrower than K's (`dv` lanes a head: the V ring's own)
     return (k.astype(jnp.float32),
-            vbuf[cur].reshape(tokens, n_kv * d).astype(jnp.float32))
+            vbuf[cur].reshape(tokens, vbuf.shape[-1]).astype(jnp.float32))
 
 
 def _v_ring(shared: bool, shape, dtype):
     """The V block ring's scratch; a token one when V is read from K."""
     return pltpu.VMEM((1, 1, 8, 128) if shared else shape, dtype)
+
+
+def _sink_specs(sink, rows: int, index_map) -> list:
+    """The sink operand's block ([rows, 1] float32, the same block at every
+    grid step); no operand without a sink."""
+    return [] if sink is None else [pl.BlockSpec((rows, 1), index_map)]
+
+
+def _sink_rows(sink, repeat: int = 1) -> tuple:
+    """sink [H] -> ([repeat * H, 1] float32,): row r is head r % H's, as
+    the kernels lay a query block's rows (query-major, head-minor)."""
+    if sink is None:
+        return ()
+    s = sink.astype(jnp.float32)
+    return ((jnp.tile(s, repeat) if repeat > 1 else s)[:, None],)
 
 
 # -------------------------------------------------------------- int8 dequant --
@@ -152,9 +168,17 @@ def _dequant_rows(rows, n_kv: int, d: int, lane_width: int):
 # ------------------------------------------------------ flash accumulation --
 
 
-def _flash_reset(m_ref, l_ref, acc_ref):
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
+def _flash_reset(m_ref, l_ref, acc_ref, sink=None):
+    """`sink` [R, 1] float32: a learned logit a row that joins the softmax's
+    running max and denominator and adds nothing to the numerator: the
+    online softmax simply STARTS from it (m = sink, l = exp(sink - m) = 1),
+    and every later `_flash_update` rescales that 1 with the rest."""
+    if sink is None:
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+    else:
+        m_ref[...] = jnp.broadcast_to(sink, m_ref.shape)
+        l_ref[...] = jnp.ones_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
@@ -205,19 +229,17 @@ def _decode_kernel(
     # inputs
     q_ref,  # [1, H, D] VMEM block (this slot's query)
     k_hbm,  # [P, ps, KVD] in ANY/HBM — manually DMA'd
-    v_hbm,  # [P, ps, KVD]
-    o_ref,  # [1, H, D]
-    # scratch (persistent across the sequential grid)
-    kbuf,  # [NBUF, SB, ps, KVD] KV-dtype ring of block buffers
-    vbuf,  # [NBUF, SB, ps, KVD]
-    qbd_ref,  # [H, KVD] block-diagonal queries as the product takes them
-    m_ref,  # [H, 128] f32 running max
-    l_ref,  # [H, 128] f32 running denominator
-    acc_ref,  # [H, KVD] f32 running numerator (off-head lanes carry garbage
-    #           that the finalize slice discards)
-    ptr_ref,  # SMEM [4] int32: consumed count, issue cursor (b, i), issued count
-    sem,  # DMA semaphores [NBUF, 2, SB]
-    *,
+    v_hbm,  # [P, ps, KV*Dv] (Dv = D unless V's rows are narrower)
+    *rest,  # [sink_ref [H, 1] f32 where `sink`,] o_ref [1, H, Dv], scratch
+    # (persistent across the sequential grid):
+    # kbuf [NBUF, SB, ps, KVD] KV-dtype ring of block buffers
+    # vbuf [NBUF, SB, ps, KV*Dv]
+    # qbd_ref [H, KVD] block-diagonal queries as the product takes them
+    # m_ref, l_ref [H, 128] f32 running max and denominator
+    # acc_ref [H, KV*Dv] f32 running numerator (off-head lanes carry garbage
+    #         that the finalize slice discards)
+    # ptr_ref SMEM [4] int32: consumed count, issue cursor (b, i), issued count
+    # sem DMA semaphores [NBUF, 2, SB]
     page_size: int,
     pages_per_seq: int,
     block_pages: int,
@@ -228,6 +250,7 @@ def _decode_kernel(
     quantized: bool,
     shared: bool = False,
     window: int = 0,
+    sink: bool = False,
 ):
     """One grid step a slot; inside it a loop over that slot's OWN
     superblocks, `ceil(ctx / tokens_per_block)` of them. A slot with context
@@ -236,13 +259,17 @@ def _decode_kernel(
     stepping over the slots that own no block. Under a static `window` the
     query sees the last `window` tokens of its context: the superblocks
     below them are not its own either (`first_block`), and the mask has a
-    lower edge. window = 0 traces none of it."""
+    lower edge. window = 0 traces none of it. `sink`: a learned logit a
+    query head in the softmax (`_flash_reset`); False traces none of it."""
+    sink_ref = rest[0] if sink else None
+    (o_ref, kbuf, vbuf, qbd_ref, m_ref, l_ref, acc_ref, ptr_ref,
+     sem) = rest[1:] if sink else rest
     b = pl.program_id(0)
     bsz = pl.num_programs(0)
     tokens_per_block = block_pages * page_size
     h, d = q_ref.shape[1], q_ref.shape[2]
+    dv = o_ref.shape[2]  # V's lanes a head: d, or narrower
     group = h // n_kv
-    kvd = n_kv * d
 
     def first_block(bb):
         if not window:
@@ -330,15 +357,16 @@ def _decode_kernel(
         # head (r // group) occupies lanes [(r//group)*D, (r//group+1)*D).
         # Built with iota + lane tiling — no lane-splitting reshapes, which
         # Mosaic cannot lower. Once a slot, with the tiled query.
-        def bd_mask():  # [H, KVD]; needed before the loop and after it
-            row = jax.lax.broadcasted_iota(jnp.int32, (h, kvd), 0)
-            lane = jax.lax.broadcasted_iota(jnp.int32, (h, kvd), 1)
+        def bd_mask(d):  # [H, KV*d]; before the loop (K's d), after (V's)
+            row = jax.lax.broadcasted_iota(jnp.int32, (h, n_kv * d), 0)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (h, n_kv * d), 1)
             return _div(row, group) == _div(lane, d)
 
         q = q_ref[0].astype(jnp.float32) * scale  # [H, D]
         qbd_ref[...] = jnp.where(
-            bd_mask(), jnp.tile(q, (1, n_kv)), 0.0).astype(qbd_ref.dtype)
-        _flash_reset(m_ref, l_ref, acc_ref)
+            bd_mask(d), jnp.tile(q, (1, n_kv)), 0.0).astype(qbd_ref.dtype)
+        _flash_reset(m_ref, l_ref, acc_ref,
+                     sink_ref[...] if sink else None)
 
         def block(i, carry):
             cnt = ptr_ref[0]
@@ -372,14 +400,14 @@ def _decode_kernel(
 
         jax.lax.fori_loop(0, n_blocks(b), block, 0)
 
-        out = _flash_normalize(l_ref, acc_ref)  # [H, KVD]
+        out = _flash_normalize(l_ref, acc_ref)  # [H, KV*Dv]
         # keep each row's own KV-head lane span (off-head lanes carry
-        # accumulated garbage), then fold the KV spans down to [H, D]
+        # accumulated garbage), then fold the KV spans down to [H, Dv]
         # with static lane slices — again avoiding lane-split reshapes.
-        out = jnp.where(bd_mask(), out, 0.0)
-        folded = out[:, 0:d]
+        out = jnp.where(bd_mask(dv), out, 0.0)
+        folded = out[:, 0:dv]
         for kv in range(1, n_kv):
-            folded = folded + out[:, kv * d:(kv + 1) * d]
+            folded = folded + out[:, kv * dv:(kv + 1) * dv]
         o_ref[0] = folded.astype(o_ref.dtype)
 
 
@@ -396,11 +424,13 @@ def paged_attention_decode(
     num_bufs: int = DEFAULT_NUM_BUFS,
     interpret: bool = False,
     window: int = 0,
+    sink=None,  # [H] float32: a learned logit a query head in the softmax
 ) -> jax.Array:
     bsz, n_heads, head_dim = q.shape
     lane_width = k_pages.shape[2]
     quantized = k_pages.dtype == jnp.int8
     shared = shared_kv(v_pages)
+    v_dim = _v_head_dim(v_pages, num_kv_heads, head_dim)
     if shared:
         v_pages = k_pages
     kvd = num_kv_heads * head_dim
@@ -408,6 +438,7 @@ def paged_attention_decode(
         assert lane_width >= kvd + 2 * num_kv_heads, (lane_width, kvd)
     else:
         assert lane_width == kvd, (lane_width, num_kv_heads, head_dim)
+    kvdv = num_kv_heads * v_dim
     pmax = block_table.shape[1]
     block_pages = max(1, min(block_pages, pmax))
     num_bufs = max(2, num_bufs)
@@ -422,19 +453,19 @@ def paged_attention_decode(
             pl.BlockSpec((1, n_heads, head_dim), lambda b, bt, cl: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
-        ],
+        ] + _sink_specs(sink, n_heads, lambda b, bt, cl: (0, 0)),
         out_specs=pl.BlockSpec(
-            (1, n_heads, head_dim), lambda b, bt, cl: (b, 0, 0)
+            (1, n_heads, v_dim), lambda b, bt, cl: (b, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((num_bufs, block_pages, page_size, lane_width),
                        k_pages.dtype),
-            _v_ring(shared, (num_bufs, block_pages, page_size, lane_width),
-                    v_pages.dtype),
+            _v_ring(shared, (num_bufs, block_pages, page_size,
+                             v_pages.shape[2]), v_pages.dtype),
             pltpu.VMEM((n_heads, kvd), q_dtype),
             pltpu.VMEM((n_heads, 128), jnp.float32),
             pltpu.VMEM((n_heads, 128), jnp.float32),
-            pltpu.VMEM((n_heads, kvd), jnp.float32),
+            pltpu.VMEM((n_heads, kvdv), jnp.float32),
             pltpu.SMEM((4,), jnp.int32),
             pltpu.SemaphoreType.DMA((num_bufs, 2, block_pages)),
         ],
@@ -451,11 +482,12 @@ def paged_attention_decode(
         quantized=quantized,
         shared=shared,
         window=window,
+        **({} if sink is None else {"sink": True}),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, n_heads, head_dim), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bsz, n_heads, v_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
             # sequential on purpose: the DMA pipeline carries state across
             # grid steps (see module docstring)
@@ -463,7 +495,7 @@ def paged_attention_decode(
         ),
         interpret=interpret,
     )(block_table.astype(jnp.int32), context_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
+      q, k_pages, v_pages, *_sink_rows(sink))
     return out
 
 
@@ -474,24 +506,24 @@ def _prefill_kernel(
     sl_ref,  # [1] int32 true sequence length
     q_ref,  # [1, G, Tq, D] — all `group` query heads of this KV head
     k_ref,  # [1, Tk, D]
-    v_ref,  # [1, Tk, D]
-    o_ref,  # [1, G, Tq, D]
-    m_ref,  # [G*Tq, 128] f32
-    l_ref,  # [G*Tq, 128] f32
-    acc_ref,  # [G*Tq, D] f32
-    *,
+    v_ref,  # [1, Tk, Dv]
+    *rest,  # [sink_ref [1, G*Tq, 1] f32 where `sink`,] o_ref [1, G, Tq, Dv],
+    # m_ref, l_ref [G*Tq, 128] f32, acc_ref [G*Tq, Dv] f32
     group: int,
     block_q: int,
     block_k: int,
     num_k_blocks: int,
     scale: float,
+    sink: bool = False,
 ):
+    sink_ref = rest[0] if sink else None
+    o_ref, m_ref, l_ref, acc_ref = rest[1:] if sink else rest
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
     def _reset():
-        _flash_reset(m_ref, l_ref, acc_ref)
+        _flash_reset(m_ref, l_ref, acc_ref, sink_ref[0] if sink else None)
 
     q_start = iq * block_q
     k_start = ik * block_k
@@ -522,9 +554,9 @@ def _prefill_kernel(
 
     @pl.when(ik == num_k_blocks - 1)
     def _finalize():
-        head_dim = q_ref.shape[-1]
         out = _flash_normalize(l_ref, acc_ref)
-        o_ref[0] = out.reshape(group, block_q, head_dim).astype(o_ref.dtype)
+        o_ref[0] = out.reshape(group, block_q,
+                               o_ref.shape[-1]).astype(o_ref.dtype)
 
 
 def prefill_attention(
@@ -536,9 +568,11 @@ def prefill_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    sink=None,  # [H] float32: a learned logit a query head in the softmax
 ) -> jax.Array:
     s, n_heads, head_dim = q.shape
     n_kv = k.shape[1]
+    v_dim = v.shape[2]  # head_dim, or narrower
     group = n_heads // n_kv
     scale = 1.0 / (head_dim**0.5)
 
@@ -576,17 +610,24 @@ def prefill_attention(
                 (1, group, block_q, head_dim), lambda h, iq, ik, sl: (h, 0, iq, 0)
             ),
             pl.BlockSpec((1, block_k, head_dim), lambda h, iq, ik, sl: (h, ik, 0)),
-            pl.BlockSpec((1, block_k, head_dim), lambda h, iq, ik, sl: (h, ik, 0)),
-        ],
+            pl.BlockSpec((1, block_k, v_dim), lambda h, iq, ik, sl: (h, ik, 0)),
+        ] + ([] if sink is None else [
+            pl.BlockSpec((1, group * block_q, 1),
+                         lambda h, iq, ik, sl: (h, 0, 0))]),
         out_specs=pl.BlockSpec(
-            (1, group, block_q, head_dim), lambda h, iq, ik, sl: (h, 0, iq, 0)
+            (1, group, block_q, v_dim), lambda h, iq, ik, sl: (h, 0, iq, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((group * block_q, 128), jnp.float32),
             pltpu.VMEM((group * block_q, 128), jnp.float32),
-            pltpu.VMEM((group * block_q, head_dim), jnp.float32),
+            pltpu.VMEM((group * block_q, v_dim), jnp.float32),
         ],
     )
+    # row r of a KV head's (group, Tq) block is query head
+    # kv * group + r // Tq: its sink, a row
+    sinks = () if sink is None else (jnp.repeat(
+        sink.astype(jnp.float32).reshape(n_kv, group), block_q,
+        axis=1)[..., None],)
     kernel = functools.partial(
         _prefill_kernel,
         group=group,
@@ -594,17 +635,18 @@ def prefill_attention(
         block_k=block_k,
         num_k_blocks=nk,
         scale=scale,
+        **({} if sink is None else {"sink": True}),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_kv, group, s_pad, head_dim), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_kv, group, s_pad, v_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(sl, qt, kt, vt)
-    out = out.reshape(n_heads, s_pad, head_dim)
+    )(sl, qt, kt, vt, *sinks)
+    out = out.reshape(n_heads, s_pad, v_dim)
     return jnp.moveaxis(out[:, :s], 0, 1)  # [S, H, D]
 
 
@@ -618,15 +660,15 @@ def _chunk_kernel(
     # inputs
     q_ref,  # [1, Cq, H, D] VMEM block (one query block of the chunk)
     k_hbm,  # [P, ps, KVD] in ANY/HBM — manually DMA'd
-    v_hbm,  # [P, ps, KVD]
-    o_ref,  # [1, Cq, H, D]
+    v_hbm,  # [P, ps, KV*Dv] (Dv = D unless V's rows are narrower)
+    o_ref,  # [1, Cq, H, Dv]
     # scratch (persistent across the sequential grid)
     kbuf,  # [NBUF, SB, ps, KVD]
-    vbuf,  # [NBUF, SB, ps, KVD]
+    vbuf,  # [NBUF, SB, ps, KV*Dv]
     qbd_ref,  # [Cq*H, KVD] f32 — block-diagonal queries, built once per qb
     m_ref,  # [Cq*H, 128] f32
     l_ref,  # [Cq*H, 128] f32
-    acc_ref,  # [Cq*H, KVD] f32
+    acc_ref,  # [Cq*H, KV*Dv] f32
     ptr_ref,  # SMEM [4]: consumed count, issue cursor (qb, kb), issued count
     sem,  # DMA semaphores [NBUF, 2, SB]
     *,
@@ -657,9 +699,9 @@ def _chunk_kernel(
     nq = pl.num_programs(0)
     tokens_per_block = block_pages * page_size
     h, d = q_ref.shape[2], q_ref.shape[3]
+    dv = o_ref.shape[3]
     group = h // n_kv
     rows = block_q * h
-    kvd = n_kv * d
     start = start_ref[0]
 
     def block_copies(qq, kk, slot):
@@ -712,10 +754,15 @@ def _chunk_kernel(
             c.wait()
         ptr_ref[0] = cnt + 1
 
-        row_kv = (jax.lax.broadcasted_iota(jnp.int32, (rows, kvd), 0)
-                  % h) // group
-        lane_kv = jax.lax.broadcasted_iota(jnp.int32, (rows, kvd), 1) // d
-        bd_mask = row_kv == lane_kv
+        def lanes_of_head(d):  # [rows, KV*d]: the row's own KV head's
+            row_kv = (jax.lax.broadcasted_iota(
+                jnp.int32, (rows, n_kv * d), 0) % h) // group
+            lane_kv = jax.lax.broadcasted_iota(
+                jnp.int32, (rows, n_kv * d), 1) // d
+            return row_kv == lane_kv
+
+        bd_mask = lanes_of_head(d)  # K's lanes: the queries'
+        out_mask = bd_mask if dv == d else lanes_of_head(dv)  # V's
 
         @pl.when(kb == 0)
         def _reset():
@@ -740,12 +787,12 @@ def _chunk_kernel(
 
         @pl.when(kb == nb_q - 1)
         def _finalize():
-            out = _flash_normalize(l_ref, acc_ref)  # [rows, KVD]
-            out = jnp.where(bd_mask, out, 0.0)
-            folded = out[:, 0:d]
+            out = _flash_normalize(l_ref, acc_ref)  # [rows, KV*Dv]
+            out = jnp.where(out_mask, out, 0.0)
+            folded = out[:, 0:dv]
             for kv in range(1, n_kv):
-                folded = folded + out[:, kv * d:(kv + 1) * d]
-            o_ref[0] = folded.reshape(block_q, h, d).astype(o_ref.dtype)
+                folded = folded + out[:, kv * dv:(kv + 1) * dv]
+            o_ref[0] = folded.reshape(block_q, h, dv).astype(o_ref.dtype)
 
 
 def chunk_prefill_attention(
@@ -766,6 +813,7 @@ def chunk_prefill_attention(
     lane_width = k_pages.shape[2]
     quantized = k_pages.dtype == jnp.int8
     shared = shared_kv(v_pages)
+    v_dim = _v_head_dim(v_pages, num_kv_heads, head_dim)
     if shared:
         v_pages = k_pages
     kvd = num_kv_heads * head_dim
@@ -797,18 +845,18 @@ def chunk_prefill_attention(
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec(
-            (1, block_q, n_heads, head_dim),
+            (1, block_q, n_heads, v_dim),
             lambda qb, kb, pg, st: (qb, 0, 0, 0),
         ),
         scratch_shapes=[
             pltpu.VMEM((num_bufs, block_pages, page_size, lane_width),
                        k_pages.dtype),
-            _v_ring(shared, (num_bufs, block_pages, page_size, lane_width),
-                    v_pages.dtype),
+            _v_ring(shared, (num_bufs, block_pages, page_size,
+                             v_pages.shape[2]), v_pages.dtype),
             pltpu.VMEM((rows, kvd), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, kvd), jnp.float32),
+            pltpu.VMEM((rows, num_kv_heads * v_dim), jnp.float32),
             pltpu.SMEM((4,), jnp.int32),
             pltpu.SemaphoreType.DMA((num_bufs, 2, block_pages)),
         ],
@@ -830,7 +878,7 @@ def chunk_prefill_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nq, block_q, n_heads, head_dim),
+        out_shape=jax.ShapeDtypeStruct((nq, block_q, n_heads, v_dim),
                                        q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
@@ -838,4 +886,4 @@ def chunk_prefill_attention(
         interpret=interpret,
     )(pages.astype(jnp.int32), jnp.asarray(start, jnp.int32).reshape(1),
       q4, k_pages, v_pages)
-    return out.reshape(c, n_heads, head_dim)
+    return out.reshape(c, n_heads, v_dim)

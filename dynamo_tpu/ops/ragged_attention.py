@@ -83,6 +83,9 @@ from dynamo_tpu.ops.pallas_attention import (
     _flash_reset,
     _flash_update,
     _kv_block,
+    _sink_rows,
+    _sink_specs,
+    _v_head_dim,
     _v_ring,
     shared_kv,
 )
@@ -107,18 +110,15 @@ def _ragged_kernel(
     # inputs
     q_ref,  # [1, BQ, H, D] VMEM block (one ragged query block)
     k_hbm,  # [P, ps, KVD] in ANY/HBM — manually DMA'd
-    v_hbm,  # [P, ps, KVD]
-    o_ref,  # [1, BQ, H, D]
-    # scratch (persistent across the sequential grid)
-    kbuf,  # [NBUF, SB, ps, KVD]
-    vbuf,  # [NBUF, SB, ps, KVD]
-    qbd_ref,  # [BQ*H, KVD] f32 — block-diagonal queries, built once per qb
-    m_ref,  # [BQ*H, 128] f32
-    l_ref,  # [BQ*H, 128] f32
-    acc_ref,  # [BQ*H, KVD] f32
-    ptr_ref,  # SMEM [4]: consumed count, issue cursor (qb, kb), issued count
-    sem,  # DMA semaphores [NBUF, 2, SB]
-    *,
+    v_hbm,  # [P, ps, KV*Dv] (Dv = D unless V's rows are narrower)
+    *rest,  # [sink_ref [BQ*H, 1] f32 where `sink`,] o_ref [1, BQ, H, Dv],
+    # scratch (persistent across the sequential grid):
+    # kbuf [NBUF, SB, ps, KVD], vbuf [NBUF, SB, ps, KV*Dv]
+    # qbd_ref [BQ*H, KVD] f32 — block-diagonal queries, built once per qb
+    # m_ref, l_ref [BQ*H, 128] f32
+    # acc_ref [BQ*H, KV*Dv] f32
+    # ptr_ref SMEM [4]: consumed count, issue cursor (qb, kb), issued count
+    # sem DMA semaphores [NBUF, 2, SB]
     page_size: int,
     table_width: int,
     block_pages: int,
@@ -131,20 +131,24 @@ def _ragged_kernel(
     quantized: bool,
     shared: bool = False,
     window: int = 0,
+    sink: bool = False,
 ):
     """One grid step a query block; inside it a loop over that block's OWN
     KV blocks (`n_blocks`). A decode row whose sequence has kv_len 0 owns
     none: no page copy, no product, zeros written. The DMA ring runs on
     across query blocks (issue order == consume order), its issue cursor
     stepping over the rows that own nothing (`_decode_kernel` is the
-    model)."""
+    model, for `sink` too)."""
+    sink_ref = rest[0] if sink else None
+    (o_ref, kbuf, vbuf, qbd_ref, m_ref, l_ref, acc_ref, ptr_ref,
+     sem) = rest[1:] if sink else rest
     qb = pl.program_id(0)
     nq = pl.num_programs(0)
     tokens_per_block = block_pages * page_size
     h, d = q_ref.shape[2], q_ref.shape[3]
+    dv = o_ref.shape[3]  # V's lanes a head: d, or narrower
     group = h // n_kv
     rows = block_q * h
-    kvd = n_kv * d
 
     def seq_row(qq):
         # query blocks 0..num_decode-1 are the decode slots; every later
@@ -262,15 +266,16 @@ def _ragged_kernel(
 
     @pl.when(live)
     def _live():
-        def bd_mask():
-            # needed before the loop (the queries) and after it (the fold)
-            row = jax.lax.broadcasted_iota(jnp.int32, (rows, kvd), 0)
-            lane = jax.lax.broadcasted_iota(jnp.int32, (rows, kvd), 1)
+        def bd_mask(d):
+            # before the loop (the queries: K's d), after it (the fold: V's)
+            row = jax.lax.broadcasted_iota(jnp.int32, (rows, n_kv * d), 0)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (rows, n_kv * d), 1)
             return _div(jax.lax.rem(row, h), group) == _div(lane, d)
 
-        _flash_reset(m_ref, l_ref, acc_ref)
+        _flash_reset(m_ref, l_ref, acc_ref,
+                     sink_ref[...] if sink else None)
         q = q_ref[0].astype(jnp.float32).reshape(rows, d) * scale
-        qbd_ref[...] = jnp.where(bd_mask(), jnp.tile(q, (1, n_kv)), 0.0)
+        qbd_ref[...] = jnp.where(bd_mask(d), jnp.tile(q, (1, n_kv)), 0.0)
         r = seq_row(qb)
 
         def block(kb, carry):
@@ -309,12 +314,12 @@ def _ragged_kernel(
 
         jax.lax.fori_loop(0, n_blocks(qb), block, 0)
 
-        out = _flash_normalize(l_ref, acc_ref)  # [rows, KVD]
-        out = jnp.where(bd_mask(), out, 0.0)
-        folded = out[:, 0:d]
+        out = _flash_normalize(l_ref, acc_ref)  # [rows, KV*Dv]
+        out = jnp.where(bd_mask(dv), out, 0.0)
+        folded = out[:, 0:dv]
         for kv in range(1, n_kv):
-            folded = folded + out[:, kv * d:(kv + 1) * d]
-        o_ref[0] = folded.reshape(block_q, h, d).astype(o_ref.dtype)
+            folded = folded + out[:, kv * dv:(kv + 1) * dv]
+        o_ref[0] = folded.reshape(block_q, h, dv).astype(o_ref.dtype)
 
 
 # jitted so that the kernel body is traced once per shape, not once per
@@ -339,6 +344,7 @@ def ragged_paged_attention(
     num_bufs: int = DEFAULT_NUM_BUFS,
     interpret: bool = False,
     window: int = 0,
+    sink=None,  # [H] float32: a learned logit a query head in the softmax
 ) -> jax.Array:
     """Mixed ragged batch: `num_decode` leading rows of `decode_q` query
     tokens each (one padded query block per row) plus ONE prefill chunk of C
@@ -350,13 +356,17 @@ def ragged_paged_attention(
     `window` > 0 (static): a query sees itself and the window - 1 keys
     before it; KV blocks wholly below a query block's reach are skipped.
     window = 0 traces none of it: the kernel is the one without.
-    Returns [num_decode * decode_q + C, H, D]."""
+    `sink`: every row's softmax carries its head's learned logit in the
+    denominator (`pallas_attention._flash_reset`); None traces none of it.
+    Returns [num_decode * decode_q + C, H, Dv] (Dv: V's lanes a head, the
+    V pool's own: D unless V's rows are narrower than K's)."""
     total, n_heads, head_dim = q.shape
     c = total - num_decode * decode_q
     assert c >= 1, "ragged batch needs a prefill chunk (use decode kernel)"
     lane_width = k_pages.shape[2]
     quantized = k_pages.dtype == jnp.int8
     shared = shared_kv(v_pages)
+    v_dim = _v_head_dim(v_pages, num_kv_heads, head_dim)
     if shared:
         v_pages = k_pages
     kvd = num_kv_heads * head_dim
@@ -405,20 +415,20 @@ def ragged_paged_attention(
                          lambda qb, tb, kl, qs: (qb, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
-        ],
+        ] + _sink_specs(sink, rows, lambda qb, tb, kl, qs: (0, 0)),
         out_specs=pl.BlockSpec(
-            (1, block_q, n_heads, head_dim),
+            (1, block_q, n_heads, v_dim),
             lambda qb, tb, kl, qs: (qb, 0, 0, 0),
         ),
         scratch_shapes=[
             pltpu.VMEM((num_bufs, block_pages, page_size, lane_width),
                        k_pages.dtype),
-            _v_ring(shared, (num_bufs, block_pages, page_size, lane_width),
-                    v_pages.dtype),
+            _v_ring(shared, (num_bufs, block_pages, page_size,
+                             v_pages.shape[2]), v_pages.dtype),
             pltpu.VMEM((rows, kvd), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, kvd), jnp.float32),
+            pltpu.VMEM((rows, num_kv_heads * v_dim), jnp.float32),
             pltpu.SMEM((4,), jnp.int32),
             pltpu.SemaphoreType.DMA((num_bufs, 2, block_pages)),
         ],
@@ -437,11 +447,12 @@ def ragged_paged_attention(
         quantized=quantized,
         shared=shared,
         window=window,
+        **({} if sink is None else {"sink": True}),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nbq, block_q, n_heads, head_dim),
+        out_shape=jax.ShapeDtypeStruct((nbq, block_q, n_heads, v_dim),
                                        q.dtype),
         compiler_params=pltpu.CompilerParams(
             # sequential on purpose: the DMA pipeline carries state across
@@ -450,7 +461,8 @@ def ragged_paged_attention(
         ),
         interpret=interpret,
     )(tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      q_starts.astype(jnp.int32), q4, k_pages, v_pages)
+      q_starts.astype(jnp.int32), q4, k_pages, v_pages,
+      *_sink_rows(sink, block_q))
     return jnp.concatenate(
-        [out[:num_decode, :decode_q].reshape(nd, n_heads, head_dim),
-         out[num_decode:].reshape(c, n_heads, head_dim)], axis=0)
+        [out[:num_decode, :decode_q].reshape(nd, n_heads, v_dim),
+         out[num_decode:].reshape(c, n_heads, v_dim)], axis=0)

@@ -486,8 +486,10 @@ def test_laguna_decode_step_compiles_for_v5e_under_its_scope_names(one_chip):
     kernels = re.findall(
         r"^\s*%(attn_\w+?)[\d.]* = bf16\[64,(\d+),128\]\S* custom-call\(",
         text, re.M)
+    # the dense layer's and the full layer's bodies, and ONE body for the
+    # period's run of three sliding layers (scanned since PR 48)
     assert sorted(kernels) == [("attn_full", "48")] * 2 + [
-        ("attn_window", "72")] * 3
+        ("attn_window", "72")]
     for scope in ("attn_gate", "moe_router", "moe_experts",
                   "moe_shared_expert"):
         assert scope in text, scope
@@ -787,3 +789,176 @@ def test_falcon_h1_step_compiles_for_v5e_under_its_scope_names(one_chip,
     attn = [l for l in text.splitlines()
             if "tpu_custom_call" in l and "attn_full" in l]
     assert attn and all("mixer_attn" in l for l in attn)
+
+
+# MiMo-V2.5's cut (PR 48): 64 slots, a 2,048-page table beside a ring of 25
+# pages, 24,576 full pages; keys 192 lanes a head, values 128; 4 KV heads on
+# the full layers (16 query heads each), 8 on the sliding ones
+MIMO_SLOTS, MIMO_RING, MIMO_TABLE, MIMO_PAGES = 64, 25, 2048, 24576
+MIMO_SCOPES = ("attn_full", "attn_window", "attn_qk_rope", "moe_router",
+               "moe_experts")
+
+
+def _mimo_cut(one_chip):
+    """(cfg, abstract w8a8 params, k_pages, v_pages, arg) of the cut at the
+    cell's sizes, for a described v5e."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.engine.kv_cache import KVCacheSpec
+    from dynamo_tpu.models import llama, quant
+    from dynamo_tpu.models.config import ModelConfig
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    cfg = ModelConfig.from_model_name(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks/chip/configs/mimo-v2.5-w8a8-ep16-1chip"))
+    spec = KVCacheSpec.from_model(cfg, MIMO_PAGES, PAGE,
+                                  window_slots=MIMO_SLOTS + 1,
+                                  window_ahead=CHUNK)
+    assert spec.ring_pages == MIMO_RING
+    params = {}
+    for name, (shape, kind, _) in llama.param_specs(cfg).items():
+        axes = quant.quant_axes(name)
+        if axes and kind == "normal":
+            params[name] = quant.QTensorA8(
+                arg(shape, jnp.int8),
+                arg([1 if i in axes else s for i, s in enumerate(shape)],
+                    jnp.float32))
+        else:
+            params[name] = arg(shape, jnp.float32 if kind == "sink"
+                               or name == "router_bias" else jnp.bfloat16)
+    kp = llama.ByKind(arg(spec.shape, jnp.bfloat16),
+                      arg(spec.window_shape, jnp.bfloat16))
+    vp = llama.ByKind(arg(spec.v_shape, jnp.bfloat16),
+                      arg(spec.window_v_shape, jnp.bfloat16))
+    return cfg, params, kp, vp, arg
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_mimo_step_compiles_for_v5e_under_its_scope_names(one_chip, program):
+    """The cut model's decode step and mixed step at the cell's sizes (w8a8,
+    64 slots, a 2,048-page table beside a ring of 25, a pool a kind whose K
+    rows are 768 / 1,536 lanes and V rows 512 / 1,024, a 256-token chunk),
+    for a described v5e: no op leaves the kernels (K 192 / V 128 lanes a
+    head, 16 and 8 query heads a KV head: no counted fallback); a program
+    holds THREE layer bodies (the dense layer, the scanned run of sliding
+    layers, the full layer) whatever the depth; the sliding kind's kernel
+    takes the sink as an f32[rows, 1] operand and the full kind's takes
+    none; the decode kernels carry their kind's scope as the instruction's
+    name (the ragged kernel, a jit of its own, is named after itself in
+    both kinds: the benchmark tells them apart by the page table's width,
+    and its patterns are held to that here); the partial rotary, the router
+    and the grouped matmuls are named in the HLO, and `attn_sink` is not
+    (inside a kernel the sink has no HLO of its own); no pool and no weight
+    is copied but W_v (the sliding layers' stack s8[10,4096,8,128], 42 MB,
+    and a full layer's 2 MB, into the layout their matmul takes: read at PR
+    48, PERF.md section 7); beside its arguments (10.79 GB) a program holds
+    under 0.15 GB."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.ops import attention as att
+
+    cfg, params, kp, vp, arg = _mimo_cut(one_chip)
+    b = MIMO_SLOTS
+
+    def i32(*shape):
+        return arg(shape, jnp.int32)
+
+    tables = llama.ByKind(i32(b, MIMO_TABLE), i32(b, MIMO_RING))
+    before = dict(att.pallas_fallback_counts())
+    with att.attention_context("pallas", None, 1):
+        if program == "decode":
+            compiled = jax.jit(functools.partial(
+                llama.decode_step, cfg, page_size=PAGE),
+                donate_argnums=(5, 6)).lower(
+                params, i32(b), i32(b), tables, i32(b), kp, vp).compile()
+        else:
+            compiled = jax.jit(functools.partial(
+                llama.mixed_step, cfg, page_size=PAGE),
+                donate_argnums=(9, 10)).lower(
+                params, i32(b), i32(b), tables, i32(b), i32(CHUNK), i32(),
+                i32(), llama.ByKind(i32(MIMO_TABLE + 15), i32(MIMO_RING)),
+                kp, vp).compile()
+    assert dict(att.pallas_fallback_counts()) == before
+    text = compiled.as_text()
+    for scope in MIMO_SCOPES:
+        assert scope in text, scope
+    assert "attn_sink" not in text
+    out = ("64,64,128" if program == "decode"
+           else f"{b + CHUNK // 8},8,64,128")
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and f" = bf16[{out}]" in ln]
+    assert len(calls) == 3  # dense layer, the sliding run's body, full layer
+    window = [ln for ln in calls if f",{MIMO_RING}]{{1,0}}" in ln]
+    full = [ln for ln in calls if ln not in window]
+    assert len(window) == 1 and len(full) == 2
+    rows = 64 if program == "decode" else 8 * 64
+    for ln in window:
+        assert "bf16[16260,16,1536]" in ln and "bf16[16260,16,1024]" in ln
+        assert f"f32[{rows},1]" in ln  # the sink, an operand
+    for ln in full:
+        assert "bf16[73728,16,768]" in ln and "bf16[73728,16,512]" in ln
+        assert "f32[" not in ln.split("frontend_attributes")[0]
+    if program == "decode":
+        assert window[0].startswith("%attn_window")
+        assert all(ln.startswith("%attn_full") for ln in full)
+    # the benchmark's patterns, on the trace's spelling of an operation (its
+    # first operand's shape follows `custom-call(`)
+    def as_traced(ln):
+        first = re.search(r"operand_layout_constraints=\{(s32\[[\d,]+\])",
+                          ln).group(1)
+        return ln.replace("custom-call(", f"custom-call({first}{{1,0}} ", 1)
+
+    win_pat, full_pat, roof = _layer_metric_patterns(
+        "window_attn_busy_pct.longreason", "full_attn_busy_pct.longreason",
+        f"gqa_{program}_attn_roofline.longreason")
+    for ln in window:
+        assert re.search(win_pat, as_traced(ln))
+        assert not re.search(full_pat, as_traced(ln))
+    for ln in full:
+        assert re.search(full_pat, as_traced(ln))
+        assert not re.search(win_pat, as_traced(ln))
+    assert all(re.search(roof, as_traced(ln)) for ln in calls)
+    # no pool is copied, and no weight but W_v (the sliding layers' stack,
+    # 42 MB, and a full layer's 2 MB)
+    assert not re.search(r"bf16\[(3,24576|73728|10,1626|16260),16,\d+\]\S* "
+                         r"copy\(", text)
+    copied = set(re.findall(r"= (s8\[[\d,]+\])\S* copy\(", text))
+    assert all(re.fullmatch(r"s8\[(\d+,)?4096,[48],128\]", c)
+               or eval(c[3:-1].replace(",", "*")) < 1 << 20  # activations
+               for c in copied), copied
+    assert text.count(" while(") == 2  # the periods' scan, the run's inside
+    mem = compiled.memory_analysis()
+    assert 10.7e9 < mem.argument_size_in_bytes < 10.9e9
+    assert mem.temp_size_in_bytes < 0.15e9
+
+
+@pytest.mark.parametrize("s,kv_heads,sink", [(128, 8, True), (256, 4, False)],
+                         ids=["sliding_in_window", "full_bucket_256"])
+def test_flash_kernel_compiles_for_v5e_at_wider_keys(one_chip, s, kv_heads,
+                                                     sink):
+    """The whole-prompt flash kernel at K 192 / V 128 lanes a head, with
+    the sink a query head (a sliding layer's bucket inside the window) and
+    without (a full layer's): Mosaic takes a 192-lane block."""
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.ops import pallas_attention as pa
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    kw = {"sink": arg((64,), jnp.float32)} if sink else {}
+    text = jax.jit(pa.prefill_attention).lower(
+        arg((s, 64, 192), jnp.bfloat16), arg((s, kv_heads, 192), jnp.bfloat16),
+        arg((s, kv_heads, 128), jnp.bfloat16), arg((), jnp.int32),
+        **kw).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert f"bf16[{kv_heads},{64 // kv_heads},{s},128]" in text

@@ -222,6 +222,14 @@ class EngineMetrics:
         # dispatch; over mixed_count: how often a prompt's chunk found the
         # device busy instead of draining it first
         self.mixed_behind = 0
+        # finishes applied while a program was in flight and WITHOUT reading
+        # it first (Engine._finish_slot: the slot was retired in the device
+        # carry, counted ahead or found at the read), over num_finished;
+        # and the high-water mark of the pages (a sequence's own and its
+        # ring's) that waited for a program to be read before they went
+        # back to their allocator (Engine._held)
+        self.finishes_behind = 0
+        self.held_pages_peak = 0
         self.phases: Dict[str, PhaseTimer] = {p: PhaseTimer()
                                               for p in self._PHASES}
         # a first token by stage, cumulative seconds over `count` requests
@@ -567,7 +575,12 @@ class PendingProgram(NamedTuple):
     """The program in flight under async scheduling: dispatched over the
     decode batch and not read back yet, a fused window or a mixed step
     alike. The next program is dispatched on its device outputs (the carry
-    in Engine._dev_state, the pools) before _materialize_window reads it."""
+    in Engine._dev_state, the pools) before _materialize_window reads it.
+    A sequence that ends in it, or is found ended when the program before
+    it is read, does not make the engine read it early: the slot is
+    retired in the carry (Engine._finish_slot), and what this program may
+    still touch of the leaver (pages, ring, decode slot) waits in
+    Engine._held under `ticket` until _materialize_window has read it."""
 
     lag: int  # decode steps it advances every slot: what the host lags by
     ys: tuple  # tokens (and logprobs) still on the device
@@ -998,6 +1011,15 @@ class Engine:
         # async scheduling: the program (a fused window or a mixed step)
         # dispatched but not read back yet — a PendingProgram
         self._pending_win: Optional[PendingProgram] = None
+        # slots retired in the device carry whose sequences are still in
+        # `seqs`: their last tokens are in the program in flight, and the
+        # program dispatched behind it runs without them (_retire)
+        self._leaving: set = set()
+        # what a program in flight may still touch of a sequence that was
+        # found finished when the program BEFORE it was read: (ticket,
+        # pages, request id, decode slot), given back to the allocators
+        # and to _free_slots once that ticket has been read (_release_held)
+        self._held: List[tuple] = []
         # last warmup() result (programs compiled, seconds) — exposed on
         # worker /metrics by observability/engine_metrics.py
         self.warmup_info = None
@@ -1022,9 +1044,21 @@ class Engine:
             # compilation as steady state (see _upload)
             (self.token_counts,) = self._upload(self.token_counts)
 
-    def _invalidate_dev(self, tables_only: bool = False):
+    def _invalidate_dev(self, tables_only: bool = False,
+                        keep_carry: bool = False):
+        """Mark device copies stale; _ensure_dev_state uploads them again
+        from the host's mirrors. `tables_only`: pages were added.
+        `keep_carry`: a slot left the batch (_retire, _finish_slot behind a
+        program in flight): everything the host owns goes up again (the
+        active mask, the tables, the sampling rows, the adapter slots),
+        while tokens / positions / context_lens stay the device's own,
+        which may be a program ahead of the mirrors."""
         self._dev_tables = None
-        if not tables_only:
+        if keep_carry:
+            self._dev_state = (*self._dev_state[:3], None)
+            self._dev_sampling = None
+            self._dev_adapters = None
+        elif not tables_only:
             self._dev_state = None
             self._dev_sampling = None
             self._dev_guide = None
@@ -1676,6 +1710,9 @@ class Engine:
                     one[..., :self.kv_spec.v_lane_width]
                     if self.kv_spec.index_lanes else one
                 )
+        # a slot retired in the carry uploads the active mask alone (warm
+        # traffic runs one sequence at a time and never retires one)
+        self._upload(np.zeros((cfg.max_num_seqs,), np.bool_))
         self.reset_metrics()  # don't surface warm traffic as load
         out = {
             "programs": self.compiled_program_count(),
@@ -1995,6 +2032,8 @@ class Engine:
             self._aborted.clear()
             self._rid_tenant.clear()
         self._pending_win = None  # unread tokens die with their sequences
+        self._leaving.clear()
+        self._release_held()
         inf, self._inflight = self._inflight, None
         if inf is not None:
             ids.append(inf.req.request_id)
@@ -3185,35 +3224,41 @@ class Engine:
         THAT program is read afterwards, so a prompt's chunks find the
         device busy. The step itself stays in flight for the next step()
         to read — unless it carries the prompt's final chunk (its logits
-        give the first token, the slot installs, the carry is rebuilt) or
-        the program read behind it held a finish, whose freed pages it
-        may still touch. Read at once, in the synchronous order: under
-        speculation (a verify's n-gram drafts need the newest tokens on
-        the host, and _decode_spec keeps nothing in flight for a demoted
-        step to ride behind), async_scheduling off, enforce_eager;
-        drained first: no headroom or no pages behind the in-flight
-        program, or a device carry that a side door (import_kv)
+        give the first token, the slot installs, the carry is rebuilt).
+        A sequence that ends in the program in flight is retired in the
+        carry first (_leavers, _retire) and the step runs over the others;
+        one found finished when that program is read leaves the step in
+        flight (_finish_slot holds its pages and its slot back). Read at
+        once, in the synchronous order: under speculation (a verify's
+        n-gram drafts need the newest tokens on the host, and _decode_spec
+        keeps nothing in flight for a demoted step to ride behind),
+        async_scheduling off, enforce_eager, and after an exit that
+        dropped the carry (kv_oom, an integrity fault); drained first: no
+        headroom or no pages behind the in-flight program, every decode
+        row ending in it, or a device carry that a side door (import_kv)
         invalidated."""
         inf = self._inflight
-        cfg = self.cfg
         events: List[TokenEvent] = []
-        ride = (cfg.async_scheduling and cfg.speculative_mode == "off"
-                and not cfg.enforce_eager)
+        ride = self._rides
         prev = self._pending_win
-        got = 0
-        if prev is not None and ride and self._dev_state is not None \
-                and self._window_steps(extra=prev.lag) > 0:
-            # every live slot has prev.lag + 1 tokens of headroom: pages
-            # for the one token this step writes behind prev's
-            with self.timeline.phase("page_alloc"):
-                got = self._grow_pages(1, events, offset=prev.lag,
-                                       allow_kill=False)
-        if got <= 0:
+        got, leaving = 0, ()
+        if prev is not None and ride and self._dev_state is not None:
+            leaving = self._leavers(prev)
+            if self._window_steps(extra=prev.lag, skip=leaving) > 0:
+                # every slot that stays has prev.lag + 1 tokens of
+                # headroom: pages for the one token this step writes
+                # behind prev's
+                with self.timeline.phase("page_alloc"):
+                    got = self._grow_pages(1, events, offset=prev.lag,
+                                           allow_kill=False, skip=leaving)
+        if got > 0:
+            self._retire(leaving)
+        else:
             # nothing in flight, or nothing may run behind it: drain the
             # pipeline, then provision as a 1-step window (a verify: K+1)
             events.extend(self._materialize_pending())
             prev = None
-            ahead = cfg.num_speculative_tokens + 1 if spec else 1
+            ahead = self.cfg.num_speculative_tokens + 1 if spec else 1
             with self.timeline.phase("page_alloc"):
                 got = self._grow_pages(ahead, events)
             if not self.seqs:
@@ -3229,9 +3274,10 @@ class Engine:
         if prev is not None:
             self.metrics.mixed_behind += 1
             events.extend(self._materialize_window(prev))
-        if not ride or final or any(ev.finished for ev in events):
-            # a finish frees pages the step just dispatched still touches
-            # (as in _decode_async); the final chunk is read for its token
+        if not ride or final or self._dev_state is None or not self.seqs:
+            # the final chunk is read for its token; an exit that dropped
+            # the carry freed pages the step just dispatched still touches
+            # (as in _decode_async); nobody is left to ride behind it
             events.extend(self._materialize_pending())
         if final:
             self._finish_inflight(chunk_logits,
@@ -3264,6 +3310,7 @@ class Engine:
         t0 = time.monotonic()
         self._ensure_dev_state()
         cur, pos, ctx_lens, active_dev = self._dev_state
+        batch = self._batch()
         lx = (self._dev_adapters,) if self.lora is not None else ()
         if drafted is not None:
             drafts, room, nreal = drafted
@@ -3274,8 +3321,7 @@ class Engine:
                     self._dev_tables, *self._dev_sampling,
                     self.token_counts, d_room)
         else:
-            want_lp = any(s.logprobs is not None
-                          for s in self.seqs.values())
+            want_lp = any(s.logprobs is not None for s in batch.values())
             fn = self._mixed[want_lp]
             args = (self.params, cur, pos, ctx_lens, active_dev,
                     self._dev_tables, *self._dev_sampling,
@@ -3296,7 +3342,7 @@ class Engine:
             del args  # the donated arrays die inside this span, as in
             # _dispatch_window
         self._dev_state = (cur, pos, ctx_lens, active_dev)
-        slots = list(self.seqs)
+        slots = list(batch)
         if inf is not None:
             inf.done += take
         if drafted is None:
@@ -3305,7 +3351,7 @@ class Engine:
             self._pending_win = PendingProgram(
                 1, ys, want_lp, time.monotonic() - t0, slots,
                 self.timeline.dispatch_seq, chunk,
-                [self.seqs[s].num_tokens + lag for s in slots])
+                [batch[s].num_tokens + lag for s in slots])
             return chunk_logits
         with self.timeline.phase("device_wait"):
             toks = np.asarray(ys[0]).T  # [K+1, B]
@@ -3319,30 +3365,48 @@ class Engine:
         self._emit_tokens(events, slots, toks, given=given)
         return chunk_logits
 
-    def _window_steps(self, extra: int = 0) -> int:
+    def _headroom(self, seq: SeqState) -> int:
+        """Tokens `seq` may still be given, by what the host has read:
+        max_tokens, max_seq_len, the block table's last column. The first
+        two are _check_stop's own terms, and the table is never narrower
+        than max_seq_len (EngineConfig.max_pages_per_seq), so a sequence
+        finishes exactly when its headroom is used up."""
+        n_out = len(seq.output_tokens)
+        return min(
+            seq.max_tokens - n_out,
+            self.cfg.max_seq_len - (seq.prompt_len + n_out),
+            self.cfg.max_pages_per_seq * self.cfg.page_size - seq.num_tokens,
+        )
+
+    def _window_steps(self, extra: int = 0, skip=()) -> int:
         """How many decode steps the next dispatch may fuse (1 = classic).
 
         The multi-step window requires every active sequence to have at least
         K tokens of headroom (max_tokens, max_seq_len, block-table columns) so
         no stop condition or table overflow can occur mid-window, and no
-        pending prefills waiting for a slot (admission latency beats batching
-        round-trips).
+        pending prefills waiting for a slot, nor a prompt just admitted whose
+        first chunk the next mixed step carries (admission latency beats
+        batching round-trips).
 
         `extra` = tokens already committed to an in-flight (unread) window
-        under async scheduling: headroom must cover BOTH windows. Returns 0
-        when not even a 1-step window fits on top of the in-flight one (the
-        caller drains the pipeline and retries synchronously)."""
+        under async scheduling: headroom must cover BOTH windows. `skip` =
+        the slots that END in that window (_leavers): the next program runs
+        without them and is priced over the others. Returns 0 when not
+        even a 1-step window fits on top of the in-flight one, or nobody
+        stays to run it (the caller drains the pipeline and retries
+        synchronously)."""
         k = self.cfg.num_scheduler_steps
-        small = k <= 1 or self.pending or not self.seqs
-        pmax_tokens = self.cfg.max_pages_per_seq * self.cfg.page_size
+        # a prompt whose chunk rides the NEXT step (a mixed step) waits
+        # like a pending one: no fused window in front of its first chunk
+        small = (k <= 1 or self.pending or not self.seqs
+                 or self._mixed_eligible())
         want = 1 if small else k
-        for seq in self.seqs.values():
-            n_out = len(seq.output_tokens)
-            headroom = min(
-                seq.max_tokens - n_out,
-                self.cfg.max_seq_len - (seq.prompt_len + n_out),
-                pmax_tokens - seq.num_tokens,
-            ) - extra
+        if skip and len(skip) == len(self.seqs):
+            return 0
+        for slot, seq in self.seqs.items():
+            if slot in skip:
+                continue
+            headroom = self._headroom(seq) - extra
             if headroom < want:
                 want = 1 if headroom >= 1 else 0
                 if want == 0:
@@ -3350,14 +3414,16 @@ class Engine:
         return want
 
     def _grow_pages(self, window: int, events: List[TokenEvent],
-                    offset: int = 0, allow_kill: bool = True) -> int:
+                    offset: int = 0, allow_kill: bool = True,
+                    skip=()) -> int:
         """Ensure every active sequence has KV pages for the next `window`
         tokens (positions num_tokens+offset .. +offset+window-1; `offset` =
         tokens of an in-flight async window). Falls back to a 1-token window
         if the pool can't cover the full window; sequences that can't even
         get one page finish with kv_oom — unless allow_kill is False (an
         async window is in flight over those pages), where 0 is returned so
-        the caller drains the pipeline first."""
+        the caller drains the pipeline first. `skip` = the slots that end
+        in that window (_leavers): they get no page."""
         cfg = self.cfg
         # never provision past the block-table width: positions beyond it
         # cannot be written (the spec path asks for K+1 ahead uniformly and
@@ -3365,7 +3431,9 @@ class Engine:
         pcap = cfg.max_pages_per_seq - 1
         if window > 1:
             need_total = 0
-            for seq in self.seqs.values():
+            for slot, seq in self.seqs.items():
+                if slot in skip:
+                    continue
                 last_page = min(
                     (seq.num_tokens + offset + window - 1) // cfg.page_size,
                     pcap)
@@ -3374,7 +3442,7 @@ class Engine:
                 window = 1
 
         for slot, seq in list(self.seqs.items()):
-            if self.seqs.get(slot) is not seq:
+            if self.seqs.get(slot) is not seq or slot in skip:
                 # preempted by an earlier iteration's _preempt_for: the
                 # snapshot entry is dead — allocating into it would leak
                 # pages into a detached SeqState forever
@@ -3659,10 +3727,23 @@ class Engine:
         """Pipelined decode: dispatch window k+1, THEN read program k back —
         the host sync overlaps the new window's device compute. Program k
         is the pending one: a window, or a mixed step a prompt's chunk
-        left in flight (_mixed_step runs the same pipeline). Any finish
-        discovered in k drains the pipeline (window k+1's tokens for
-        surviving sequences are processed in the same step; the finished
-        slot's are discarded by the normal membership iteration)."""
+        left in flight (_mixed_step runs the same pipeline).
+
+        A finish does not drain the pipeline. One the host can count
+        ahead (max_tokens, max_seq_len: k holds the sequence's last
+        token, _leavers): window k+1 is priced over the others, the
+        leaver is retired in the device carry (_retire) before k+1 is
+        dispatched, and k is read afterwards, where the leaver's last
+        tokens are emitted and its pages and slot go back at once: k+1
+        never knew them. One found at the read of k (a stop token), with
+        k+1 in flight over the leaver's row: k+1 stays in flight,
+        _finish_slot holds the leaver's pages, ring and slot back until
+        k+1 has been read (_held), retires the slot in the carry for
+        k+2, and k+1's rows for it are dropped by _emit_tokens'
+        membership check. What still reads k+1 at once: an exit that
+        drops the carry (an integrity fault), and the last sequence
+        leaving; abort and preemption drain BEFORE they tear down
+        (_apply_aborts, the QoS paths), kv_oom needs nothing in flight."""
         events: List[TokenEvent] = []
         if self._pending_win is not None and self._dev_state is None:
             # a side-door membership change (disagg import_kv) invalidated
@@ -3670,30 +3751,83 @@ class Engine:
             events.extend(self._materialize_pending())
         prev = self._pending_win
         lag = prev.lag if prev is not None else 0
-        window = self._window_steps(extra=lag)
+        leaving = self._leavers(prev)
+        window = self._window_steps(extra=lag, skip=leaving)
         if window > 0:
             with self.timeline.phase("page_alloc"):
                 window = self._grow_pages(window, events, offset=lag,
-                                          allow_kill=prev is None)
+                                          allow_kill=prev is None,
+                                          skip=leaving)
         if not self.seqs:
             events.extend(self._materialize_pending())
             return events
         if window <= 0:
             # not enough headroom/pages to run ahead of the in-flight
-            # window: drain it and fall back to a synchronous step
+            # window, or every sequence ends in it: drain it and fall back
+            # to a synchronous step
             events.extend(self._materialize_pending())
             if self.seqs:
                 events.extend(self._decode_once())
             return events
+        self._retire(leaving)
         self._dispatch_window(window)
         if prev is not None:
             events.extend(self._materialize_window(prev))
-            if any(ev.finished for ev in events):
-                # a finish frees pages the NEW in-flight window still
-                # touches; drain it now so next step's admissions can't
-                # reuse them mid-flight
+            if self._dev_state is None or not self.seqs:
+                # an exit dropped the carry and freed pages the NEW
+                # in-flight window still touches: drain it now so next
+                # step's admissions can't reuse them mid-flight. Or nobody
+                # is left whose tokens it holds
                 events.extend(self._materialize_pending())
         return events
+
+    @property
+    def _rides(self) -> bool:
+        """Whether a step rides the async pipeline at all: dispatched on
+        the carry of the program in flight, read one program late."""
+        cfg = self.cfg
+        return (cfg.async_scheduling and cfg.speculative_mode == "off"
+                and not cfg.enforce_eager)
+
+    def _batch(self) -> Dict[int, SeqState]:
+        """The sequences the NEXT program runs over: `seqs` less the slots
+        already retired in the carry, whose last tokens are still unread."""
+        if not self._leaving:
+            return self.seqs
+        return {slot: seq for slot, seq in self.seqs.items()
+                if slot not in self._leaving}
+
+    @property
+    def _carry_outlives_a_finish(self) -> bool:
+        """Whether a slot can be retired in the device carry as it stands:
+        the step rides the pipeline and nothing dropped the carry. A
+        guided batch's grammar carry needs no edit: a window masks it with
+        `gactive & active`, and the active mask is what a retirement
+        uploads."""
+        return self._rides and self._dev_state is not None
+
+    def _leavers(self, prev: Optional[PendingProgram]):
+        """The slots whose sequences END inside `prev`, the program in
+        flight, `prev.lag` unread steps ahead of the host: their headroom
+        is what it already holds, so the next program can run without them
+        (a window never crosses a sequence's end: _window_steps). None with
+        nothing in flight, or where the carry cannot be edited."""
+        if prev is None or not self._carry_outlives_a_finish:
+            return ()
+        return [slot for slot, seq in self.seqs.items()
+                if self._headroom(seq) <= prev.lag]
+
+    def _retire(self, slots) -> None:
+        """Take `slots` out of the device carry behind the program in
+        flight: their rows in the host's mirrors are reset as a finish
+        resets them and go up again before the next dispatch; their
+        sequences stay in `seqs` until that program is read and
+        _emit_tokens finishes them."""
+        for slot in slots:
+            self._reset_slot_mirrors(slot)
+            self._leaving.add(slot)
+        if slots:
+            self._invalidate_dev(keep_carry=True)
 
     def _ensure_dev_state(self) -> None:
         """Rebuild invalidated device batch state from the host mirrors.
@@ -3703,6 +3837,13 @@ class Engine:
         jnp.asarray (uncommitted) input would key a second compilation of
         every window variant for the rebuild-following call."""
         cfg = self.cfg
+        if self._dev_state is not None and self._dev_state[3] is None:
+            # a slot was retired in the carry (_invalidate_dev(keep_carry)):
+            # the mask alone; the tables and sampling rows follow below
+            active_mask = np.zeros((cfg.max_num_seqs,), np.bool_)
+            active_mask[list(self._batch())] = True
+            self._dev_state = (*self._dev_state[:3],
+                               *self._upload(active_mask))
         if self._dev_state is None:
             active = set(self.seqs)
             for slot in range(cfg.max_num_seqs):
@@ -3747,8 +3888,8 @@ class Engine:
             # real hang looks like to the watchdog monitor thread
             faults.sleep_point("engine.device_hang")
             self._ensure_dev_state()
-            want_lp = any(s.logprobs is not None
-                          for s in self.seqs.values())
+            batch = self._batch()
+            want_lp = any(s.logprobs is not None for s in batch.values())
             cur, pos, ctx_lens, active_dev = self._dev_state
             # lora mode: the per-slot adapter indices ride every window
             # (slot 0 keeps base sequences on the zero delta)
@@ -3756,7 +3897,7 @@ class Engine:
             args = (self.params, cur, pos, ctx_lens, active_dev,
                     self._dev_tables, *self._dev_sampling, self.token_counts,
                     self.k_pages, self.v_pages, *lx)
-            if any(s.guide is not None for s in self.seqs.values()):
+            if any(s.guide is not None for s in batch.values()):
                 self._ensure_dev_guide()
                 fn = self._get_guided_window(window > 1, want_lp)
                 (ys, cur, pos, ctx_lens, self.token_counts, *grammar,
@@ -3777,7 +3918,7 @@ class Engine:
         # so interleaved work (chunk prefills, scheduling) between dispatch
         # and readback isn't double-counted into decode_window.
         self._pending_win = PendingProgram(
-            window, ys, want_lp, time.monotonic() - t0, list(self.seqs),
+            window, ys, want_lp, time.monotonic() - t0, list(batch),
             self.timeline.dispatch_seq)
 
     def _materialize_pending(self) -> List[TokenEvent]:
@@ -3787,7 +3928,8 @@ class Engine:
 
     def _materialize_window(self, pw: PendingProgram) -> List[TokenEvent]:
         """Read one dispatched program back, a fused window or a mixed
-        step: account for it and emit its tokens."""
+        step: account for it, emit its tokens, and give back what was
+        held for it (_release_held)."""
         if self._pending_win is pw:
             self._pending_win = None
         events: List[TokenEvent] = []
@@ -3809,6 +3951,7 @@ class Engine:
                            pw.lag, pw.slots, chunk=pw.chunk,
                            contexts=pw.contexts)
         self._emit_tokens(events, pw.slots, toks, lps=lps)
+        self._release_held(pw.ticket)
         return events
 
     def _account_step(self, kind: str, dt: float, steps: int, slots,
@@ -3939,9 +4082,11 @@ class Engine:
                     if finished:
                         # mid-chain stop: the later tokens given to this
                         # slot are discarded (their KV lives in pages
-                        # freed right here); _finish_slot invalidates
-                        # device state, so a stale advanced position is
-                        # rebuilt from mirrors next step
+                        # _finish_slot frees, or holds back while a
+                        # program in flight still writes there); the
+                        # slot's stale advanced position dies with its
+                        # row: retired in the carry, or rebuilt from the
+                        # mirrors
                         self._finish_slot(slot, reason)
                         break
                 wait = seq.token_wait
@@ -3963,6 +4108,23 @@ class Engine:
         return False, None
 
     def _finish_slot(self, slot: int, reason: Optional[str]):
+        """Every route out of the running batch (finish, preempt, abort).
+
+        With a program in flight that was dispatched on this carry, a
+        stop or a length finish leaves it in flight and keeps the carry
+        (metrics.finishes_behind): either the slot was retired before
+        that program was dispatched (_retire: nothing on the device knows
+        the sequence any more, its pages and its slot go back here), or
+        the finish was found one program late and that program still
+        computes the slot's row, writes a token's KV through its table
+        and, in a hybrid model, updates its state slot: pages, ring and
+        decode slot then wait in _held under its ticket, and the slot is
+        retired in the carry for the program after it. Every other exit
+        drops the carry as it always did, and its caller reads the
+        program in flight before anything can reuse what was freed:
+        abort and preemption drain before they tear down, kv_oom happens
+        only with nothing in flight, an integrity fault is drained in the
+        same step (_decode_async, _mixed_step: `_dev_state is None`)."""
         seq = self.seqs.pop(slot, None)
         if seq is None:
             return
@@ -3971,7 +4133,45 @@ class Engine:
                              tenant=(self._tenant_of(seq.req)
                                      if seq.req is not None else "default"),
                              reason=reason, n_out=len(seq.output_tokens))
-        self._free_pages(seq.pages, seq.request_id)
+        pw = self._pending_win
+        retired = slot in self._leaving
+        held = (not retired and reason in ("stop", "length")
+                and pw is not None and slot in pw.slots
+                and self._carry_outlives_a_finish)
+        if retired:
+            self._leaving.discard(slot)
+        else:
+            self._reset_slot_mirrors(slot)
+        if held:
+            self._held.append((pw.ticket, seq.pages, seq.request_id, slot))
+            rings = self.win_rings
+            self.metrics.held_pages_peak = max(
+                self.metrics.held_pages_peak,
+                sum(len(pages) + (rings.held_by(rid) if rings else 0)
+                    for _, pages, rid, _ in self._held))
+        else:
+            self._free_pages(seq.pages, seq.request_id)
+            self._free_slots.append(slot)
+        # Speculation v3 teardown: the draft pool's pages for this slot and
+        # the adaptive controller's window both key on the DECODE SLOT, so
+        # every route out (finish / preempt / abort) must clear them before
+        # the slot's next tenant drafts
+        if self.draft is not None:
+            self.draft.release(slot)
+        if self._adaptive is not None:
+            self._adaptive.reset(slot)
+        self.metrics.num_finished += 1
+        if retired or held:
+            self.metrics.finishes_behind += 1
+        if held:
+            self._invalidate_dev(keep_carry=True)
+        elif not retired:
+            # the freed slot's device-side block-table row must stop
+            # pointing at the released pages before the next decode window
+            self._invalidate_dev()
+
+    def _reset_slot_mirrors(self, slot: int) -> None:
+        """The host's rows of a slot whose sequence leaves the batch."""
         self.block_tables[slot, :] = 0
         self.win_tables[slot, :] = 0
         self.context_lens[slot] = 0
@@ -3988,19 +4188,21 @@ class Engine:
         self.bias_ids[slot] = -1
         self.bias_vals[slot] = 0.0
         self.adapter_slots[slot] = 0  # unpin the LoRA slot
-        # Speculation v3 teardown: the draft pool's pages for this slot and
-        # the adaptive controller's window both key on the DECODE SLOT, so
-        # every route out (finish / preempt / abort) must clear them before
-        # the slot's next tenant drafts
-        if self.draft is not None:
-            self.draft.release(slot)
-        if self._adaptive is not None:
-            self._adaptive.reset(slot)
-        self._free_slots.append(slot)
-        self.metrics.num_finished += 1
-        # the freed slot's device-side block-table row must stop pointing at
-        # the released pages before the next decode window
-        self._invalidate_dev()
+
+    def _release_held(self, upto: Optional[int] = None) -> None:
+        """Give back what waited for the programs up to ticket `upto`
+        (None: everything, the engine is being torn down): pages to their
+        allocators, decode slots (a hybrid model's state slots) to
+        _free_slots."""
+        keep = []
+        for entry in self._held:
+            ticket, pages, rid, slot = entry
+            if upto is not None and ticket > upto:
+                keep.append(entry)
+                continue
+            self._free_pages(pages, rid)
+            self._free_slots.append(slot)
+        self._held = keep
 
     # --------------------------------------------------- disaggregation API --
 

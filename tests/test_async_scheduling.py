@@ -1,11 +1,16 @@
 """Async (pipelined) decode scheduling: output parity with synchronous mode
 across stops, sampling, aborts, chunked admissions, and disagg imports."""
 
+import itertools
+
+import numpy as np
 import pytest
 
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import Engine
 from dynamo_tpu.engine.request import GenRequest
+
+from pipelined_common import assert_finish_rides_pipeline
 
 
 def _mk(async_sched, **kw):
@@ -237,8 +242,8 @@ def test_mixed_steps_behind_the_pipeline_match_sync(pair, case, usable):
     refused = []
     grow = eng._grow_pages
 
-    def watched(window, events, offset=0, allow_kill=True):
-        got = grow(window, events, offset=offset, allow_kill=allow_kill)
+    def watched(window, events, offset=0, **kw):
+        got = grow(window, events, offset=offset, **kw)
         if got == 0 and eng._mixed_eligible():
             refused.append(offset)
         return got
@@ -259,17 +264,20 @@ def test_mixed_steps_behind_the_pipeline_match_sync(pair, case, usable):
         assert m.kv_oom == (usable == 13) and m.num_preempted > 0
 
 
-def test_an_eos_found_behind_a_mixed_step_drains_it(pair):
+def test_an_eos_found_behind_a_mixed_step_leaves_it_in_flight(pair):
     """`stopper` stops on a token that the window in flight at a mixed
-    step's dispatch holds: the step is read in the same step() (the freed
-    pages are its to touch), and every stream is the synchronous order's."""
+    step's dispatch holds. The finish is found when that window is read,
+    with the mixed step already on the device over the stopper's row: the
+    step STAYS in flight past the end of its step() (unless it carries
+    the prompt's final chunk), the stopper's pages and its slot wait for
+    it, and every stream is the synchronous order's."""
     ref_eng, eng = pair
 
-    def script(stop):
+    def script(stop, at=3):
         return {0: _add(_live(), GenRequest(
                     "stopper", [4, 5, 6], max_tokens=40, temperature=1.3,
                     seed=11, stop_token_ids=stop)),
-                3: _add(_long())}
+                at: _add(_long())}
 
     free_run = _drive(ref_eng, script([]))["stopper"]["tokens"]
     read = eng._materialize_window
@@ -277,27 +285,47 @@ def test_an_eos_found_behind_a_mixed_step_drains_it(pair):
 
     def watched(pw):
         behind = eng._pending_win
+        slot = next((s for s, q in eng.seqs.items()
+                     if q.request_id == "stopper"), None)
         evs = read(pw)
         if (behind is not pw and behind is not None
                 and behind.chunk is not None
-                and any(e.finished for e in evs)):
-            seen.append(behind)
+                and sum(behind.chunk) < len(LONG)
+                and any(e.finished and e.request_id == "stopper"
+                        for e in evs)):
+            seen.append((behind, slot))
         return evs
+
+    def probe(e):
+        if seen and not left:
+            left.append((e._pending_win, list(e._held),
+                         list(e._free_slots), e.allocator.free_pages))
 
     eng._materialize_window = watched
     try:
-        for k in range(8, 24):  # the stream's k-th token as the stop token
+        # the stream's k-th token as the stop token, the prompt arriving
+        # before step `at`: some pair puts the stop in the one-token
+        # program that a non-final mixed step is dispatched behind
+        for at, k in itertools.product((3, 4, 5, 6), range(8, 24)):
             stop = [free_run[k]]
             if seen or free_run.index(stop[0]) != k:
                 continue
-            out = _drive(eng, script(stop),
-                         probe=lambda e: seen and left.append(e._pending_win))
-            _same(out, _drive(ref_eng, script(stop)))
+            free = eng.allocator.free_pages
+            out = _drive(eng, script(stop, at), probe=probe)
+            _same(out, _drive(ref_eng, script(stop, at)))
             assert out["stopper"]["finish"] == "stop"
     finally:
         del eng._materialize_window
     assert seen, "no stop token fell in the program behind a mixed step"
-    assert left[0] is None  # the mixed step was read before step() returned
+    (step, slot), (pending, held, free_slots, free_then) = seen[0], left[0]
+    assert pending is step  # still unread when step() returned
+    (ticket, pages, rid, held_slot), = held
+    assert (ticket, rid, held_slot) == (step.ticket, "stopper", slot)
+    assert slot in step.slots and slot not in free_slots
+    # live's pages, the prompt's six and the stopper's are all still out
+    assert free_then <= free - len(pages) - 6
+    assert eng.metrics.held_pages_peak == len(pages) > 0
+    assert eng.metrics.finishes_behind >= 1
 
 
 @pytest.mark.parametrize("victim", ["long", "live"])
@@ -327,11 +355,257 @@ def _no_mixed_step_in_flight(eng):
 
 
 def test_what_keeps_the_drained_order_is_a_property_of_the_step(pair):
-    """async_scheduling off, and a verify (its drafts need the newest
-    tokens on the host), read every mixed step at once."""
+    """async_scheduling off, a verify (its drafts need the newest tokens
+    on the host) and enforce_eager read every mixed step at once and
+    apply every finish with nothing in flight."""
     spec = _mk(True, **MIXED, speculative_mode="ngram",
                num_speculative_tokens=2)
-    for eng in (pair[0], spec):
+    eager = _mk(True, **MIXED, enforce_eager=True)
+    for eng in (pair[0], spec, eager):
         _drive(eng, _three_chunks(), probe=_no_mixed_step_in_flight)
         assert eng.metrics.mixed_count == 3
         assert eng.metrics.mixed_behind == 0
+        assert (eng.metrics.num_finished, eng.metrics.finishes_behind,
+                eng.metrics.held_pages_peak) == (2, 0, 0)
+
+
+@pytest.mark.parametrize("exit_", ["abort", "preempt", "kv_oom",
+                                   "integrity_fault"])
+def test_an_exit_that_is_no_plain_finish_keeps_the_drained_order(pair, exit_):
+    """What the exit IS decides: an abort, a preemption, kv_oom and an
+    integrity fault are applied with nothing left in flight and nothing
+    held back, and count in no `finishes_behind`; the streams are the
+    synchronous order's."""
+    kw = dict(MIXED)
+    if exit_ in ("preempt", "kv_oom"):
+        kw["num_pages"] = {"preempt": 15, "kv_oom": 13}[exit_] + 1
+    ref_eng, eng = pair if exit_ not in ("preempt", "kv_oom") else (
+        _mk(a, **kw) for a in (False, True))
+    script = _two_live()
+    state = {}
+
+    def probe(e):
+        if exit_ == "abort" and not state and e._pending_win is not None \
+                and len(e.seqs) == 2:
+            e.abort_request("two")
+            state["at"] = e.metrics.finishes_behind
+        if exit_ == "integrity_fault" and not state and len(e.seqs) == 2 \
+                and e._pending_win is not None:
+            # the next readback carries a token id outside the vocabulary
+            pw = e._pending_win
+            bad = pw.ys[0].at[0, pw.slots[-1]].set(-7)
+            e._pending_win = pw._replace(ys=(bad, *pw.ys[1:]))
+            state["at"] = e.metrics.finishes_behind
+        assert not e._held and not e._leaving
+
+    was = eng.integrity
+    if exit_ == "integrity_fault":
+        eng.integrity = "on"
+    try:
+        out = _drive(eng, script, probe=probe)
+    finally:
+        eng.integrity = was
+    m = eng.metrics
+    if exit_ in ("abort", "integrity_fault"):
+        victim = next(r for r, rec in out.items() if rec["finish"] == exit_)
+        ref = _drive(ref_eng, script)
+        for rid in ref:
+            n = len(out[rid]["tokens"])
+            assert out[rid]["tokens"] == ref[rid]["tokens"][:n], rid
+            assert rid == victim or n == len(ref[rid]["tokens"])
+    else:
+        _same(out, _drive(ref_eng, script))
+    ref_m = ref_eng.metrics
+    if exit_ == "preempt":
+        assert m.num_preempted == ref_m.num_preempted > 0
+    if exit_ == "kv_oom":
+        assert m.kv_oom == ref_m.kv_oom == 1
+    # every plain finish beside the exit still rode; the exit itself never
+    plain = sum(rec["finish"] in ("stop", "length") for rec in out.values())
+    assert m.finishes_behind <= plain
+    assert m.held_pages_peak == 0
+
+
+# --- a finish rides the pipeline: retired in the device carry behind the
+# program in flight, pages and slot held back where that program still
+# touches them ---
+
+@pytest.mark.parametrize("model,kw", [
+    ("tiny-debug", {}),
+    ("tiny-moe-debug", {}),
+    ("tiny-mla-debug", {}),
+    ("tiny-kimi-ep4-debug", dict(dtype="float32")),
+    ("tiny-debug", dict(lora_slots=2)),
+], ids=["dense", "moe", "mla", "mla_grouped_experts", "lora_on"])
+def test_a_finish_rides_the_pipeline(pair, model, kw):
+    """Dense, every-expert MoE, MLA, and MLA under grouped expert matmuls
+    (whose layers mask the rows of an empty slot), also with the LoRA
+    operand riding: assert_finish_rides_pipeline's whole contract."""
+    sync, eng = pair if (model, kw) == ("tiny-debug", {}) else (
+        _mk(a, **MIXED, model=model, **kw) for a in (False, True))
+    assert_finish_rides_pipeline(
+        sync, eng, lambda i: [(7 * j + 11 * i) % 90 + 1 for j in range(5 + i)])
+
+
+def _stop_at(ref_eng, lo=8):
+    """`stopper`'s request, stopping on the first token from its `lo`-th
+    on that its free-running stream has not shown before."""
+    mk = lambda stop: GenRequest("stopper", [4, 5, 6], max_tokens=40,  # noqa: E731
+                                 temperature=1.3, seed=11, ignore_eos=True,
+                                 stop_token_ids=stop)
+    free_run = ref_eng.generate(mk([]))
+    k = next(k for k in range(lo, 40) if free_run.index(free_run[k]) == k)
+    return mk([free_run[k]]), free_run[:k + 1]
+
+
+@pytest.mark.parametrize("short", ["slots", "pages"])
+def test_what_is_held_is_not_handed_out_before_its_program_is_read(short):
+    """A stop token found at the read leaves the stopper's pages and its
+    decode slot held for the program in flight. A request that arrives at
+    that moment and needs exactly what is held (every slot taken; a pool
+    one page short of its prompt) waits out the step that reads that
+    program, is admitted by the next, and `PageAllocator.alloc` never
+    returns a held page."""
+    kw = dict(MIXED, num_pages=24, max_num_seqs=2 if short == "slots" else 4)
+    ref_eng, eng = _mk(False, **kw), _mk(True, **kw)
+    stopper, want = _stop_at(ref_eng)
+    alloc = eng.allocator.alloc
+    handed = []
+
+    def watched(n):
+        pages = alloc(n)
+        handed.append((set(pages), {p for _, ps, _, _ in eng._held
+                                    for p in ps}))
+        return pages
+
+    eng.allocator.alloc = watched
+    seen = {}
+
+    def probe(e):
+        if e._held and not seen:
+            (ticket, pages, rid, slot), = e._held
+            assert rid == "stopper" and e._pending_win.ticket == ticket
+            assert slot not in e._free_slots and slot not in e.seqs
+            free = e.allocator.free_pages
+            # the whole free list, handed out and given back: no held page
+            e.allocator.free(e.allocator.alloc(free))
+            if short == "slots":
+                assert not e._free_slots
+                n_prompt = 5
+            else:
+                assert e._free_slots
+                n_prompt = 4 * (free + 1)  # one page more than is free
+                assert len(pages) >= 1
+            late = GenRequest("late", [(3 * i) % 50 + 1
+                                       for i in range(n_prompt)],
+                              max_tokens=4, temperature=0.0, ignore_eos=True)
+            e.add_request(late)
+            seen.update(late=late, ticket=ticket, step=0)
+        elif seen and seen["step"] == 0:
+            # the step that READ the held ticket: it admitted nothing
+            seen["step"] = 1
+            assert [r.request_id for r in e.pending] == ["late"]
+            assert e._inflight is None and not e._held
+            assert e.timeline.dispatch_seq > seen["ticket"]
+        elif seen and seen["step"] == 1:
+            seen["step"] = 2
+            assert not e.pending  # given the slot / the pages now
+
+    out = _drive(eng, {0: _add(_live(n=30), stopper)}, probe=probe)
+    assert seen.get("step") == 2
+    assert out["stopper"]["tokens"] == want
+    assert out["stopper"]["finish"] == "stop"
+    assert out["live"]["tokens"] == ref_eng.generate(_live(n=30))
+    assert out["late"]["tokens"] == ref_eng.generate(seen["late"])
+    assert all(not got & held for got, held in handed)
+    assert any(held for _, held in handed)
+    assert eng.metrics.finishes_behind >= 1
+
+
+def test_six_finishes_counted_ahead_open_no_drained_interval():
+    """An anchor decodes 100 tokens while six sequences of 20, 29, ... 65
+    tokens end beside it. The parent of this change (PR 48's tree) counted
+    `timeline.drained.count` 17 over this script: every finish read the
+    program in flight early, then ran one synchronous step, so two
+    dispatches found the device empty; the anchor alone counts 1. Now the
+    six leave behind the program in flight: 17 - 2 x 6 = 5 (the first
+    dispatch, and those after a first token was installed)."""
+    eng = _mk(True, **MIXED, max_num_seqs=8)
+
+    def run(n):
+        eng.reset_metrics()
+        eng.add_request(_live("anchor", 100))
+        for i in range(n):
+            eng.add_request(GenRequest(f"s{i}", [4 + i, 5, 6],
+                                       max_tokens=20 + 9 * i,
+                                       temperature=0.0, ignore_eos=True))
+        while eng.has_work:
+            eng.step()
+        m = eng.metrics
+        return (eng.timeline.drained_count, m.num_finished,
+                m.finishes_behind)
+
+    assert run(0) == (1, 1, 0)
+    assert run(6) == (5, 7, 6)
+    assert eng.timeline.summary()["drained"]["count"] == 5
+
+
+def test_a_finished_top_k_and_logit_bias_row_closes_the_samplers_gates():
+    """The tiered sampler's gates read the whole [B] arrays
+    (engine/sampling.py: `needs_mask` = any top_k > 0 or top_p < 1,
+    `any_bias` = any bias id >= 0). A sampled request with top-k, top-p
+    and a logit bias ends beside a greedy one: the arrays the NEXT program
+    is handed, on the device, are all defaults again, and the active mask
+    holds the greedy row alone."""
+    eng = _mk(True, **MIXED)
+    eng.add_request(_live(n=40))
+    eng.add_request(GenRequest("picky", [9, 8, 7], max_tokens=9,
+                               temperature=0.8, top_k=5, top_p=0.9, seed=4,
+                               logit_bias={17: 4.0}, ignore_eos=True))
+    open_, closed = [], []
+    while eng.has_work:
+        eng.step()
+        if eng._dev_sampling is None or eng._dev_state is None:
+            continue
+        temp, top_p, top_k, *_, bias_ids, _, _ = (
+            np.asarray(a) for a in eng._dev_sampling)
+        gates = (bool(((top_k > 0) | (top_p < 1.0)).any()),
+                 bool((bias_ids >= 0).any()), bool((temp > 0).any()))
+        if len(eng.seqs) == 2:
+            open_.append(gates)
+        elif eng.metrics.finishes_behind == 1 and eng._pending_win:
+            mask = np.asarray(eng._dev_state[3]).tolist()
+            closed.append((gates, mask == [s in eng.seqs for s in range(4)]))
+    assert open_ and all(g == (True, True, True) for g in open_)
+    assert closed and all(g == (False, False, False) and mask_ok
+                          for g, mask_ok in closed)
+    assert eng.metrics.num_finished == 2 and eng.metrics.finishes_behind == 1
+
+
+def test_a_guided_batchs_finishes_ride_the_pipeline(pair):
+    """Guided sequences end beside a plain one, by `max_tokens` and on the
+    EOS their grammar allows once the object is closed (found at the
+    read): the grammar carry of those that stay lives on the device
+    through both, because a retirement uploads the active mask and the
+    guided window masks its automaton with `gactive & active`. Streams
+    and finish reasons are the synchronous order's."""
+    ref_eng, eng = pair
+
+    def script():
+        return {0: _add(
+            _live("plain", 60),
+            GenRequest("g1", [9, 8, 7], max_tokens=12, temperature=0.0,
+                       guided_json=True),
+            GenRequest("g2", [5, 8, 7, 1], max_tokens=40, temperature=1.1,
+                       seed=4, guided_json=True),
+            GenRequest("g3", [5, 8, 2, 1], max_tokens=25, temperature=1.3,
+                       seed=5, guided_json=True))}
+
+    held = []
+    out = _drive(eng, script(), probe=lambda e: held.append(len(e._held)))
+    _same(out, _drive(ref_eng, script()))
+    reasons = {rid: rec["finish"] for rid, rec in out.items()}
+    assert reasons["g1"] == "length" and "stop" in reasons.values()
+    m = eng.metrics
+    assert (m.num_finished, m.finishes_behind) == (4, 3)
+    assert any(held) and m.held_pages_peak > 0  # the EOS: one program late

@@ -17,7 +17,8 @@ from dynamo_tpu.models import llama
 from dynamo_tpu.models.reference import deepseek_v32 as ref
 
 from tests.deepseek_v32_common import PS, TOPK, ref_config, tapped, tiny
-from tests.pipelined_common import assert_pipelined_matches_sync
+from tests.pipelined_common import (assert_finish_rides_pipeline,
+                                    assert_pipelined_matches_sync)
 
 
 def _engine(**kw):
@@ -89,15 +90,31 @@ def test_engine_matches_reference_and_counts():
     assert d["chunk_keys_scored"] > d["rows_selected"] - d["decode_queries"] * TOPK
 
 
-def test_mixed_steps_behind_the_pipeline_match_the_synchronous_order():
+@pytest.fixture(scope="module")
+def pair():
+    """(synchronous, pipelined) engines; a test leaves both idle."""
+    return (_engine(async_scheduling=False, enable_prefix_caching=False),
+            _engine(enable_prefix_caching=False))
+
+
+def test_mixed_steps_behind_the_pipeline_match_the_synchronous_order(pair):
     """A 43-token prompt's three chunks, each dispatched on the device
     outputs of the program before it; the decode rows select over the live
     slots' rung at contexts the host has not read yet: tokens and
     `metrics.dsa` / `metrics.attn` are the synchronous order's."""
     assert_pipelined_matches_sync(
-        _engine(async_scheduling=False, enable_prefix_caching=False),
-        _engine(enable_prefix_caching=False),
-        _req("live", [50, 51, 52], n=28), _req("late", [60, 61, 62], n=9))
+        *pair, _req("live", [50, 51, 52], n=28),
+        _req("late", [60, 61, 62], n=9))
+
+
+def test_a_finish_rides_the_pipeline(pair):
+    """Sequences leave a running batch by `max_tokens` and on stop tokens
+    with no program read early: the rows that stay select over the live
+    slots' rung without the retired row (its table row is trash), at
+    contexts past `index_topk`: tokens, `metrics.dsa` and `metrics.attn`
+    are the synchronous order's."""
+    assert_finish_rides_pipeline(
+        *pair, lambda i: SHARED[:18 + i] + [70 + i, 71, 72])
 
 
 def test_metrics_dsa_arithmetic():

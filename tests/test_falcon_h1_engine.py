@@ -21,7 +21,8 @@ from dynamo_tpu.models.reference import falcon_h1 as ref
 from dynamo_tpu.observability.memory import MemoryAccountant
 
 from falcon_h1_common import hf_dict, tiny
-from pipelined_common import assert_pipelined_matches_sync
+from pipelined_common import (assert_finish_rides_pipeline,
+                              assert_pipelined_matches_sync)
 
 CFG = dict(model="tiny-falcon-h1-debug", page_size=4, num_pages=128,
            max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
@@ -58,6 +59,12 @@ def slots_held(eng: Engine) -> int:
 @pytest.fixture(scope="module")
 def engine():
     return Engine(EngineConfig(**CFG))
+
+
+@pytest.fixture(scope="module")
+def sync_engine():
+    """The oracle of the pipelined orders: async_scheduling off."""
+    return Engine(EngineConfig(**CFG, async_scheduling=False))
 
 
 def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
@@ -105,14 +112,14 @@ def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
         "admit_blocked"]
 
 
-def test_mixed_steps_behind_the_pipeline_match_the_synchronous_order(engine):
+def test_mixed_steps_behind_the_pipeline_match_the_synchronous_order(
+        sync_engine, engine):
     """A 30-token prompt's four chunks, each dispatched on the device
     outputs of the program before it (pages AND every layer's state slots
     are that program's results): tokens, `metrics.ssm` and
     `metrics.attn_kinds` are the synchronous order's."""
-    sync = Engine(EngineConfig(**CFG, async_scheduling=False))
     got = assert_pipelined_matches_sync(
-        sync, engine,
+        sync_engine, engine,
         GenRequest("live", prompt(11, 13), max_tokens=28, temperature=0.0,
                    ignore_eos=True),
         GenRequest("late", prompt(12, 30), max_tokens=9, temperature=0.0,
@@ -288,3 +295,15 @@ def test_warmup_compiles_what_the_window_runs(engine):
 def test_what_a_state_slot_does_not_serve_is_refused(change, word):
     with pytest.raises(ValueError, match=word):
         Engine(EngineConfig(**{**CFG, **change}), model_cfg=tiny())
+
+
+def test_a_finish_rides_the_pipeline(sync_engine, engine):
+    """Sequences leave a running batch by `max_tokens` and on stop tokens
+    with no program read early. A retired row's state slot in ALL layers
+    is neither advanced by the next program nor handed to a prompt while
+    the program in flight still updates it (the decode slot IS the state
+    slot): tokens, `metrics.ssm` and `metrics.attn_kinds` are the
+    synchronous order's."""
+    assert_finish_rides_pipeline(sync_engine, engine,
+                                 lambda i: prompt(40 + i, 5 + i))
+

@@ -279,6 +279,10 @@ def test_windowed_ragged_kernel_is_the_masked_composition(group, decode):
     p_pages = jnp.asarray(rng.permutation(np.arange(46, 96))[:20], jnp.int32)
     start = 13 * ps  # 208: the first query's reach starts at 169, mid-page
     outs = {}
+    # the counts are the process's own: another file's tests may have run
+    # in this worker first, so the assertion below is on their growth
+    demoted = att.pallas_fallback_counts().get(
+        ("ragged attention", "window_softcap"), 0)
     for backend in ("xla", "pallas_interpret"):
         with att.attention_context(backend, None, 1):
             if decode:
@@ -291,8 +295,8 @@ def test_windowed_ragged_kernel_is_the_masked_composition(group, decode):
                     num_kv_heads=n_kv, window=window)
     np.testing.assert_allclose(outs["pallas_interpret"], outs["xla"],
                                rtol=2e-5, atol=2e-5)
-    assert not att.pallas_fallback_counts().get(
-        ("ragged attention", "window_softcap"))
+    assert att.pallas_fallback_counts().get(
+        ("ragged attention", "window_softcap"), 0) == demoted
 
 
 # ------------------------------------------------------------ the two pools --

@@ -18,7 +18,8 @@ from dynamo_tpu.engine.request import GenRequest
 from dynamo_tpu.models.reference import laguna_s as ref
 from dynamo_tpu.observability.memory import MemoryAccountant
 
-from pipelined_common import assert_pipelined_matches_sync
+from pipelined_common import (assert_finish_rides_pipeline,
+                              assert_pipelined_matches_sync)
 from test_laguna import hf_dict, tiny
 
 CFG = dict(model="tiny-laguna-debug", page_size=4, num_pages=128,
@@ -52,6 +53,12 @@ def reference_greedy(eng: Engine, tokens, n_new: int):
 @pytest.fixture(scope="module")
 def engine():
     return Engine(EngineConfig(**CFG))
+
+
+@pytest.fixture(scope="module")
+def sync_engine():
+    """The oracle of the pipelined orders: async_scheduling off."""
+    return Engine(EngineConfig(**CFG, async_scheduling=False))
 
 
 def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
@@ -93,14 +100,14 @@ def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
         "mixed_chunk_kv_pairs"]
 
 
-def test_mixed_steps_behind_the_pipeline_match_the_synchronous_order(engine):
+def test_mixed_steps_behind_the_pipeline_match_the_synchronous_order(
+        sync_engine, engine):
     """A 30-token prompt's four chunks, each dispatched on the device
     outputs of the program before it, while the decoding row's ring (6
     pages, written over) keeps turning: tokens and `metrics.attn_kinds` of
     both kinds are the synchronous order's."""
-    sync = Engine(EngineConfig(**CFG, async_scheduling=False))
     got = assert_pipelined_matches_sync(
-        sync, engine,
+        sync_engine, engine,
         GenRequest("live", prompt(11, 29), max_tokens=28, temperature=0.0,
                    ignore_eos=True),
         GenRequest("late", prompt(12, 30), max_tokens=9, temperature=0.0,
@@ -198,3 +205,13 @@ def test_warmup_compiles_what_the_window_runs(engine):
 def test_what_pools_by_kind_do_not_serve_is_refused(change, word):
     with pytest.raises(ValueError, match=word):
         Engine(EngineConfig(**{**CFG, **change}), model_cfg=tiny())
+
+
+def test_a_finish_rides_the_pipeline(sync_engine, engine):
+    """Sequences leave a running batch by `max_tokens` and on stop tokens
+    with no program read early; a leaver's ring on the sliding layers is
+    held back with its pages where the program in flight still writes
+    there, and both pools end as they began."""
+    assert_finish_rides_pipeline(sync_engine, engine,
+                                 lambda i: prompt(40 + i, 5 + i))
+

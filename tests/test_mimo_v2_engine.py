@@ -21,7 +21,8 @@ from dynamo_tpu.models.reference import mimo_v2 as ref
 from dynamo_tpu.observability.memory import MemoryAccountant
 
 from mimo_v2_common import hf_dict, tiny
-from pipelined_common import assert_pipelined_matches_sync
+from pipelined_common import (assert_finish_rides_pipeline,
+                              assert_pipelined_matches_sync)
 
 CFG = dict(model="tiny-mimo-v2-debug", page_size=4, num_pages=128,
            max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
@@ -68,6 +69,15 @@ def engine():
     eng.params["router_bias"] = jnp.asarray(
         np.random.default_rng(1).normal(0.0, 0.3, bias.shape), bias.dtype)
     return eng
+
+
+@pytest.fixture(scope="module")
+def sync_engine(engine):
+    """The oracle of the pipelined orders: async_scheduling off, the
+    same router bias."""
+    sync = Engine(EngineConfig(**CFG, async_scheduling=False))
+    sync.params = engine.params
+    return sync
 
 
 def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
@@ -125,15 +135,14 @@ def test_a_share_of_the_experts_serves_the_reference_given_that_share():
     assert 0 < moe["assignments_held"] < moe["assignments"]
 
 
-def test_mixed_steps_behind_the_pipeline_match_the_synchronous_order(engine):
+def test_mixed_steps_behind_the_pipeline_match_the_synchronous_order(
+        sync_engine, engine):
     """A 30-token prompt's four chunks, each dispatched on the device
     outputs of the program before it, while the decoding row's ring keeps
     turning: tokens and `metrics.attn_kinds` (the sink's rows too) are the
     synchronous order's."""
-    sync = Engine(EngineConfig(**CFG, async_scheduling=False))
-    sync.params = engine.params
     got = assert_pipelined_matches_sync(
-        sync, engine,
+        sync_engine, engine,
         GenRequest("live", prompt(11, 29), max_tokens=28, temperature=0.0,
                    ignore_eos=True),
         GenRequest("late", prompt(12, 30), max_tokens=9, temperature=0.0,
@@ -240,3 +249,13 @@ def test_warmup_compiles_what_the_window_runs(engine):
 def test_what_pools_by_kind_do_not_serve_is_refused(change, word):
     with pytest.raises(ValueError, match=word):
         Engine(EngineConfig(**{**CFG, **change}), model_cfg=tiny())
+
+
+def test_a_finish_rides_the_pipeline(sync_engine, engine):
+    """Sequences leave a running batch by `max_tokens` and on stop tokens
+    with no program read early; a leaver's ring (rows of another width
+    than the full layers') is held back with its pages where the program
+    in flight still writes there, and both pools end as they began."""
+    assert_finish_rides_pipeline(sync_engine, engine,
+                                 lambda i: prompt(40 + i, 5 + i))
+

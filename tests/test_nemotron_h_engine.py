@@ -21,6 +21,8 @@ from dynamo_tpu.observability.memory import MemoryAccountant
 
 from nemotron_h_common import hf_dict, tiny
 
+from pipelined_common import assert_finish_rides_pipeline
+
 CFG = dict(model="tiny-nemotron-h-debug", page_size=4, num_pages=128,
            max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
            mixed_batch_tokens=8, num_scheduler_steps=4, dtype="float32")
@@ -56,6 +58,12 @@ def slots_held(eng: Engine) -> int:
 @pytest.fixture(scope="module")
 def engine():
     return Engine(EngineConfig(**CFG))
+
+
+@pytest.fixture(scope="module")
+def sync_engine():
+    """The oracle of the pipelined orders: async_scheduling off."""
+    return Engine(EngineConfig(**CFG, async_scheduling=False))
 
 
 # the state update's kernel (interpret mode: ops/ssm.update_live walks the
@@ -283,3 +291,21 @@ def test_warmup_compiles_what_the_window_runs(engine):
 def test_what_a_state_slot_does_not_serve_is_refused(change, word):
     with pytest.raises(ValueError, match=word):
         Engine(EngineConfig(**{**CFG, **change}), model_cfg=tiny())
+
+
+def test_a_finish_rides_the_pipeline(sync_engine, engine):
+    """Sequences leave a running batch by `max_tokens` and on stop tokens
+    with no program read early; the leaver's state slot waits for the
+    program in flight where that program still updates it: tokens and
+    `metrics.ssm` are the synchronous order's."""
+    assert_finish_rides_pipeline(sync_engine, engine,
+                                 lambda i: prompt(40 + i, 5 + i))
+
+def test_a_finish_rides_the_pipeline_over_the_live_slots_kernel(
+        kernel_engine):
+    """The same under the state update's kernel, which walks the live
+    slots' list: a retired slot is off that list in the next program, so
+    its state is neither read nor written while it waits."""
+    sync = Engine(EngineConfig(**KERNEL, async_scheduling=False))
+    assert_finish_rides_pipeline(sync, kernel_engine,
+                                 lambda i: prompt(50 + i, 5 + i))

@@ -230,6 +230,12 @@ class EngineMetrics:
         # back to their allocator (Engine._held)
         self.finishes_behind = 0
         self.held_pages_peak = 0
+        # first tokens given (Engine._finalize_admission, every path), and
+        # those of them that the final chunk's own program sampled and
+        # installed in the device carry while it stayed in flight
+        # (Engine._mixed_step: read one program late, nothing drained)
+        self.num_admitted = 0
+        self.first_tokens_behind = 0
         self.phases: Dict[str, PhaseTimer] = {p: PhaseTimer()
                                               for p in self._PHASES}
         # a first token by stage, cumulative seconds over `count` requests
@@ -555,11 +561,14 @@ class InflightPrefill:
     """A long prompt being prefilled chunk-by-chunk between decode windows."""
 
     __slots__ = ("req", "pages", "pages_arr", "prompt_len", "done", "slot",
-                 "t_start", "aslot")
+                 "t_start", "aslot", "key")
 
     def __init__(self, req: GenRequest, pages, pages_arr, prompt_len: int,
-                 slot: int, aslot: int = 0):
+                 slot: int, key, aslot: int = 0):
         self.req = req
+        # the request's PRNG chain root, drawn at admission: by the final
+        # chunk it is computed, and reading it waits on nothing in flight
+        self.key = key
         self.pages = pages  # real page ids (host list, allocator-owned)
         self.pages_arr = pages_arr  # bucket-padded np.int32 for the jit
         self.prompt_len = prompt_len
@@ -571,6 +580,22 @@ class InflightPrefill:
         # for the chunks' duration; the registry reads it)
 
 
+class FirstRow(NamedTuple):
+    """A mixed step's operand for its prompt's first token (mixed_fn): the
+    program samples it from the chunk's last-row logits as sample_first
+    does and, under `join`, writes the row into the device carry at
+    `slot`. Every chunk's program takes one; it means something under the
+    prompt's final chunk."""
+
+    slot: jax.Array  # int32: the decode slot reserved at _start_inflight
+    join: jax.Array  # bool: install the row (tokens, positions, context
+    # lengths, the count row); False: sample only
+    key: jax.Array  # uint32 [2]: the request's PRNG chain root
+    # (temperature, top_p, top_k, min_p, bias_ids, bias_vals), one lane
+    # each: Engine._first_sampling
+    sampling: tuple
+
+
 class PendingProgram(NamedTuple):
     """The program in flight under async scheduling: dispatched over the
     decode batch and not read back yet, a fused window or a mixed step
@@ -580,7 +605,11 @@ class PendingProgram(NamedTuple):
     it is read, does not make the engine read it early: the slot is
     retired in the carry (Engine._finish_slot), and what this program may
     still touch of the leaver (pages, ring, decode slot) waits in
-    Engine._held under `ticket` until _materialize_window has read it."""
+    Engine._held under `ticket` until _materialize_window has read it.
+    Nor does a prompt that ends in it: a mixed step that carries a
+    prompt's final chunk samples the first token and writes the
+    newcomer's row into the carry itself (Engine._join), and the host
+    learns the token when it reads the program, one program late."""
 
     lag: int  # decode steps it advances every slot: what the host lags by
     ys: tuple  # tokens (and logprobs) still on the device
@@ -592,6 +621,11 @@ class PendingProgram(NamedTuple):
     # decode rows' contexts as the kernels were handed them
     chunk: Optional[Tuple[int, int]] = None
     contexts: Optional[List[int]] = None
+    # of a mixed step that carries a prompt's final chunk and stays in
+    # flight: the prompt, and its first token as the program sampled it
+    # (token, logprob triple, the sentinel's isfinite), still on the device
+    joiner: Optional[InflightPrefill] = None
+    first: Optional[tuple] = None
 
 
 class Engine:
@@ -1252,6 +1286,22 @@ class Engine:
             n_multi if multi else 1, lp)
             for multi in (False, True) for lp in (False, True)}
 
+        def first_token(logits, temperature, top_p, top_k, min_p,
+                        bias_ids, bias_vals, req_key, pos):
+            """First-token sampling after prefill: logits [V] for one request.
+            Penalties don't apply (no output yet) but logit_bias and min_p
+            do; logprobs always computed (one [V] row — negligible). ONE
+            function for the host's program (sample_first) and for the
+            mixed step that samples its own final chunk: same key, same
+            position, same bits."""
+            state = smp.make_state(temperature, top_p, top_k, min_p=min_p,
+                                   bias_ids=bias_ids, bias_vals=bias_vals)
+            key = jax.random.fold_in(req_key, pos)
+            toks, chosen, tids, tvals = smp.sample_with_logprobs(
+                logits[None], state, key[None]
+            )
+            return toks[0], chosen[0], tids[0], tvals[0]
+
         def make_mixed_step(with_logprobs: bool):
             """One unified ragged step (RPA, PAPERS.md arxiv 2604.15464):
             every decode slot advances ONE token while up to
@@ -1268,13 +1318,13 @@ class Engine:
                 *extra,
             ):
                 # extra layout: [adapter_slots]? + (p_tokens, p_start,
-                # p_len, p_pages) + [p_adapter_slot]? — decode adapter
-                # slots ride first when lora is on, like the windows
+                # p_len, p_pages, p_first) + [p_adapter_slot]? — decode
+                # adapter slots ride first when lora is on, like the windows
                 aslots = None
                 if lora_on:
                     aslots, extra = extra[0], extra[1:]
-                p_tokens, p_start, p_len, p_pages = extra[:4]
-                p_aslot = extra[4] if lora_on else None
+                p_tokens, p_start, p_len, p_pages, p_first = extra[:5]
+                p_aslot = extra[5] if lora_on else None
                 state = smp.SamplingState(
                     temperature, top_p, top_k, presence, frequency,
                     min_p, bias_ids, bias_vals,
@@ -1302,10 +1352,31 @@ class Engine:
                 counts = counts.at[jnp.arange(b), nxt].add(
                     step.astype(counts.dtype)
                 )
-                # chunk_logits go back raw: the host samples the first
-                # token only on the FINAL chunk (same tail as chunk_fn)
-                return (rep(y), rep(out.chunk_logits), nxt,
-                        positions + step, context_lens + step, counts,
+                # the prompt's first token, sampled where its logits are
+                # (under the final chunk the last row IS the prompt's last
+                # position) with the host path's own function, and the
+                # integrity sentinel beside it. Under `join` the row goes
+                # into the carry as _install_slot's rebuild would upload
+                # it and reset_count_fn would count it, so the program
+                # dispatched behind this one decodes the newcomer
+                n_prompt = p_start + p_len
+                first = first_token(out.chunk_logits, *p_first.sampling,
+                                    p_first.key, n_prompt - 1)
+                finite = jnp.isfinite(out.chunk_logits).all()
+                slot, join = p_first.slot, p_first.join
+
+                def put(row, new):
+                    return row.at[slot].set(jnp.where(join, new, row[slot]))
+
+                counts = put(counts, jnp.zeros_like(counts[slot]).at[
+                    first[0]].add(1))
+                # chunk_logits go back raw too: the host samples from them
+                # where the request keeps the read-at-once order
+                # (Engine._joins; same tail as chunk_fn)
+                return (rep(y), rep(out.chunk_logits), rep((*first, finite)),
+                        put(nxt, first[0]),
+                        put(positions + step, n_prompt),
+                        put(context_lens + step, n_prompt + 1), counts,
                         out.k_pages, out.v_pages) + moe_tail(out.moe_stats)
 
             return mixed_fn
@@ -1409,18 +1480,10 @@ class Engine:
                     tokens_new, pos_new, ctx_new, counts,
                     out.k_pages, out.v_pages) + moe_tail(out.moe_stats)
 
-        def sample_first(logits, temperature, top_p, top_k, min_p,
-                         bias_ids, bias_vals, req_key, pos):
-            """First-token sampling after prefill: logits [V] for one request.
-            Penalties don't apply (no output yet) but logit_bias and min_p
-            do; logprobs always computed (one [V] row — negligible)."""
-            state = smp.make_state(temperature, top_p, top_k, min_p=min_p,
-                                   bias_ids=bias_ids, bias_vals=bias_vals)
-            key = jax.random.fold_in(req_key, pos)
-            toks, chosen, tids, tvals = smp.sample_with_logprobs(
-                logits[None], state, key[None]
-            )
-            return rep((toks[0], chosen[0], tids[0], tvals[0]))
+        def sample_first(*args):
+            """first_token as a program of its own, for the paths that
+            read a prompt's logits before they sample."""
+            return rep(first_token(*args))
 
         def reset_count_fn(counts, slot, token):
             """Zero a slot's penalty counts and count its first token."""
@@ -1524,6 +1587,11 @@ class Engine:
             return self._guided_windows[multi, lp]
 
         self._get_guided_window = guided_window
+        # what every chunk but a prompt's last hands its mixed step: no
+        # row to install, a greedy lane (the sampler's cheapest gate)
+        self._first_idle = FirstRow(
+            jnp.int32(0), jnp.bool_(False), jnp.zeros((2,), jnp.uint32),
+            self._first_sampling(GenRequest("", [], temperature=0.0)))
         if cfg.enforce_eager:
             self._upload = lambda *xs: tuple(jnp.asarray(x) for x in xs)
         else:
@@ -2651,7 +2719,7 @@ class Engine:
     def _first_token_or_abort(self, events: List[TokenEvent],
                               req: GenRequest, pages, prompt_len: int,
                               last_logits, where: str,
-                              slot: Optional[int] = None):
+                              slot: Optional[int] = None, req_key=None):
         """One prompt's first token from its last logits, for every path
         that samples a prompt on its own (full prefill, last chunk, a mixed
         step's ragged tail): (first, req_key, lp). Logits that are not
@@ -2663,7 +2731,8 @@ class Engine:
             # where the device is drained (a mixed step's own readback came
             # first) the sampling is an implicit program, and a prompt's
             with self.timeline.phase("device_wait", kind="prompt"):
-                return self._first_token(req, last_logits, prompt_len)
+                return self._first_token(req, last_logits, prompt_len,
+                                         req_key)
         except IntegrityFault:
             self._abort_poisoned(events, req, pages, where, slot)
             return None
@@ -2672,10 +2741,16 @@ class Engine:
                         pages, where: str, slot: Optional[int] = None):
         """End the one stream whose prompt gave logits that are not finite
         (see _first_token_or_abort; the grouped prefill checks each of its
-        lanes and calls this for a poisoned one)."""
-        self._free_pages(pages, req.request_id)
-        if slot is not None:
-            self._free_slots.append(slot)
+        lanes and calls this for a poisoned one). A prompt seated at its
+        final chunk's dispatch (_mixed_step) leaves as a sequence does:
+        the program behind that chunk may be decoding its row."""
+        seq = self.seqs.get(slot)
+        if seq is not None and seq.req is req:
+            self._finish_slot(slot, "integrity_fault")
+        else:
+            self._free_pages(pages, req.request_id)
+            if slot is not None:
+                self._free_slots.append(slot)
         self.watchdog.record_integrity_fault(
             "logits", [req.request_id], where=where)
         events.append(TokenEvent(req.request_id, -1, 0, True,
@@ -2683,10 +2758,13 @@ class Engine:
 
     def _finalize_admission(self, req: GenRequest, pages, prompt_len: int,
                             first: int, req_key, lp, t_prefill_start: float,
-                            slot: Optional[int] = None) -> TokenEvent:
+                            slot: Optional[int] = None,
+                            seq: Optional[SeqState] = None) -> TokenEvent:
         """What turns a finished prompt into a sequence, on every path:
         publish the prefix, install the slot (`slot` where _start_inflight
-        reserved one for a chunked prompt, a free one otherwise),
+        reserved one for a chunked prompt, a free one otherwise; `seq`
+        where the prompt was seated at its final chunk's dispatch and the
+        program installed its row: the token is all it lacks),
         stop-check the first token, decorate logprobs. The event's `phase`
         is the per-request bridge the serving layer turns into trace
         spans: how long the request queued before `t_prefill_start`, how
@@ -2695,10 +2773,17 @@ class Engine:
         if self.prefix_cache is not None:
             self.prefix_cache.insert(req.prompt_token_ids, pages,
                                      namespace=self._kv_namespace(req.adapter))
+        self.metrics.num_admitted += 1
         chunked = slot is not None
         if not chunked:
             slot = self._free_slots.pop()
-        seq = self._install_slot(req, slot, pages, prompt_len, first, req_key)
+        if seq is None:
+            seq = self._install_slot(req, slot, pages, prompt_len, first,
+                                     req_key)
+        else:
+            seq.num_tokens += 1  # it counted a token behind: _join
+            self._give_first(seq, first)
+            self.metrics.first_tokens_behind += 1
         finished, reason = self._check_stop(seq, first)
         ev = TokenEvent(req.request_id, first, 0, finished, reason)
         now = self.timeline.fold()
@@ -2736,7 +2821,7 @@ class Engine:
         self.metrics.prompt_tokens += inf.prompt_len
         got = self._first_token_or_abort(
             events, inf.req, inf.pages, inf.prompt_len, last_logits, where,
-            slot=inf.slot)
+            slot=inf.slot, req_key=inf.key)
         if got is not None:
             events.append(self._finalize_admission(
                 inf.req, inf.pages, inf.prompt_len, *got, inf.t_start,
@@ -2924,10 +3009,26 @@ class Engine:
         tvals, tids = jax.lax.top_k(logp, k)
         return (float(logp[tok]), np.asarray(tids), np.asarray(tvals))
 
-    def _first_token(self, req: GenRequest, last_logits, prompt_len: int):
+    @staticmethod
+    def _first_sampling(req: GenRequest) -> tuple:
+        """A request's sampling parameters as the first-token sampler takes
+        them, one lane each (temperature, top_p, top_k, min_p, bias ids,
+        bias values): sample_first's operands and FirstRow.sampling."""
+        bias_ids, bias_vals = _pack_logit_bias(req)
+        return (jnp.asarray([req.temperature], jnp.float32),
+                jnp.asarray([req.top_p], jnp.float32),
+                jnp.asarray([req.top_k], jnp.int32),
+                jnp.asarray([req.min_p], jnp.float32),
+                jnp.asarray(bias_ids[None]),
+                jnp.asarray(bias_vals[None]))
+
+    def _first_token(self, req: GenRequest, last_logits, prompt_len: int,
+                     req_key=None):
         """Sample the first token from prefill logits (shared by the full and
-        chunked prefill paths). Returns (first, req_key, lp)."""
-        req_key = self._request_key(req)
+        chunked prefill paths). `req_key`: the chain root where admission
+        drew it already (a chunked prompt's). Returns (first, req_key, lp)."""
+        if req_key is None:
+            req_key = self._request_key(req)
         if faults.check("engine.device_nan") is not None:
             # chaos drill: a corrupted forward — NaN logits straight off
             # the device (integrity sentinel catches, stream aborts)
@@ -2946,16 +3047,8 @@ class Engine:
             last_logits = last_logits - jnp.asarray(grow)
         # the prediction made FROM position prompt_len-1; decode windows fold
         # positions >= prompt_len, so the chains never collide
-        bias_ids, bias_vals = _pack_logit_bias(req)
         tok, chosen, tids, tvals = self._sample_first(
-            last_logits,
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_p], jnp.float32),
-            jnp.asarray([req.top_k], jnp.int32),
-            jnp.asarray([req.min_p], jnp.float32),
-            jnp.asarray(bias_ids[None]),
-            jnp.asarray(bias_vals[None]),
-            req_key,
+            last_logits, *self._first_sampling(req), req_key,
             jnp.int32(prompt_len - 1),
         )
         if finite is not None and not bool(finite):
@@ -2968,10 +3061,14 @@ class Engine:
         return int(tok), req_key, (float(chosen), np.asarray(tids),
                                    np.asarray(tvals))
 
-    def _install_slot(self, req: GenRequest, slot: int, pages, prompt_len: int,
-                      first: int, req_key) -> SeqState:
-        """Shared slot installation for the agg-prefill and KV-import paths:
-        SeqState + every host mirror + the device-side penalty-count reset."""
+    def _seat(self, req: GenRequest, slot: int, pages, prompt_len: int,
+              req_key) -> SeqState:
+        """The host's part of a slot installation, for every path: the
+        SeqState in `seqs` and the slot's row in every mirror the host
+        owns (tables, sampling, key chain, adapter slot). The first token
+        is not part of it (_give_first): a prompt whose final chunk rides
+        the pipeline is seated at that chunk's dispatch and learns its
+        token one program late."""
         seq = SeqState(
             req.request_id,
             slot,
@@ -2988,17 +3085,11 @@ class Engine:
         seq.req = req
         seq.adapter_slot = self._adapter_slot(req)  # resident: a dict hit
         self.adapter_slots[slot] = seq.adapter_slot
-        seq.output_tokens.append(first)
-        if req.guided_json:
-            seq.guide = json_guide.replay(
-                self._ensure_guide_table(),
-                [*req.prior_output_token_ids, first])
         self.seqs[slot] = seq
         self.block_tables[slot, :] = 0
         self.block_tables[slot, : len(pages)] = pages
         if self.win_rings is not None:
             self.win_tables[slot] = self.win_rings.row(req.request_id)
-        self.cur_tokens[slot] = first
         self.temperature[slot] = req.temperature
         self.top_p[slot] = req.top_p
         self.top_k[slot] = req.top_k
@@ -3007,6 +3098,30 @@ class Engine:
         self.min_p[slot] = req.min_p
         self.bias_ids[slot], self.bias_vals[slot] = _pack_logit_bias(req)
         self.slot_keys[slot] = np.asarray(req_key, dtype=np.uint32)
+        return seq
+
+    def _give_first(self, seq: SeqState, first: int) -> None:
+        """A seated sequence's first output token, as the host read it."""
+        seq.output_tokens.append(first)
+        self.cur_tokens[seq.slot] = first
+        self.metrics.output_tokens += 1
+        req = seq.req
+        self.flight.note("admit", rid=req.request_id, slot=seq.slot,
+                         tenant=self._tenant_of(req),
+                         adapter=req.adapter or "",
+                         prompt_len=seq.prompt_len, pages=len(seq.pages))
+
+    def _install_slot(self, req: GenRequest, slot: int, pages, prompt_len: int,
+                      first: int, req_key) -> SeqState:
+        """Slot installation with nothing in flight, for the agg-prefill
+        and KV-import paths: the seat, the first token, the device-side
+        penalty-count reset, and a device carry rebuilt from the mirrors."""
+        seq = self._seat(req, slot, pages, prompt_len, req_key)
+        self._give_first(seq, first)
+        if req.guided_json:
+            seq.guide = json_guide.replay(
+                self._ensure_guide_table(),
+                [*req.prior_output_token_ids, first])
         self.token_counts = self._reset_count(
             self.token_counts, jnp.int32(slot), jnp.int32(first)
         )
@@ -3020,12 +3135,7 @@ class Engine:
                                       np.int64), 1)
             self.token_counts = self.token_counts.at[slot].add(
                 jnp.asarray(row))
-        self.metrics.output_tokens += 1
         self._invalidate_dev()  # new membership -> rebuild device batch state
-        self.flight.note("admit", rid=req.request_id, slot=slot,
-                         tenant=self._tenant_of(req),
-                         adapter=req.adapter or "", prompt_len=prompt_len,
-                         pages=len(pages))
         return seq
 
     @staticmethod
@@ -3095,6 +3205,7 @@ class Engine:
         pages_arr[: len(pages)] = pages
         slot = self._free_slots.pop()
         inf = InflightPrefill(req, pages, pages_arr, prompt_len, slot,
+                              self._request_key(req),
                               aslot=self._adapter_slot(req))
         inf.done = n_cached  # cached prefix blocks skip straight to suffix
         self._inflight = inf
@@ -3223,8 +3334,13 @@ class Engine:
         of the program in flight (a window, or the chunk before it) and
         THAT program is read afterwards, so a prompt's chunks find the
         device busy. The step itself stays in flight for the next step()
-        to read — unless it carries the prompt's final chunk (its logits
-        give the first token, the slot installs, the carry is rebuilt).
+        to read, the one that carries the prompt's final chunk too: its
+        program samples the first token and installs the row in the
+        device carry (FirstRow), the prompt is seated on the host (_join)
+        and the token is emitted when the program is read, behind the
+        next one. What the REQUEST carries can keep the older order for
+        that last chunk (_joins): it is read at once, the host samples
+        from its logits, the slot installs and the carry is rebuilt.
         A sequence that ends in the program in flight is retired in the
         carry first (_leavers, _retire) and the step runs over the others;
         one found finished when that program is read leaves the step in
@@ -3267,25 +3383,78 @@ class Engine:
                 # it on the classic path
                 events.extend(self._advance_chunk())
                 return events
+        final = (inf.done + self.cfg.mixed_batch_tokens >= inf.prompt_len)
+        # decided before the dispatch: the program itself installs the row
+        joins = final and ride and self._joins(inf.req)
         chunk_logits = self._ragged_step(
             events, inf, self._spec_drafts(got) if spec else None,
-            lag=prev.lag if prev is not None else 0)
-        final = inf.done >= inf.prompt_len
+            lag=prev.lag if prev is not None else 0, joins=joins)
+        if joins:
+            self._join(inf)
         if prev is not None:
             self.metrics.mixed_behind += 1
             events.extend(self._materialize_window(prev))
-        if not ride or final or self._dev_state is None or not self.seqs:
-            # the final chunk is read for its token; an exit that dropped
-            # the carry freed pages the step just dispatched still touches
-            # (as in _decode_async); nobody is left to ride behind it
+        if (not ride or (final and not joins) or self._dev_state is None
+                or not self._batch()):
+            # a final chunk that does not join is read for its logits; an
+            # exit that dropped the carry freed pages the step just
+            # dispatched still touches (as in _decode_async); nobody is
+            # left to ride behind it
             events.extend(self._materialize_pending())
-        if final:
+        if final and not joins:
             self._finish_inflight(chunk_logits,
                                   "mixed_spec" if spec else "mixed", events)
         return events
 
+    def _joins(self, req: GenRequest) -> bool:
+        """Whether a prompt's first token can be sampled and installed by
+        its final chunk's own program (mixed_fn under FirstRow.join), by
+        what the REQUEST carries: not a guided one (its first token is
+        masked on the host, _guide_first_row), not a preempted
+        continuation whose penalties count its earlier output
+        (_penalty_row; _install_slot re-seeds its count row), and not
+        while the corrupted-forward drill is armed (it poisons the logits
+        the host reads). What the STEP carries is _mixed_step's part:
+        speculation, async_scheduling off and enforce_eager never ride."""
+        penalized = bool(req.prior_output_token_ids) and bool(
+            req.presence_penalty or req.frequency_penalty)
+        return not (req.guided_json or penalized
+                    or faults.armed("engine.device_nan"))
+
+    def _first_row(self, inf: InflightPrefill) -> FirstRow:
+        """The final chunk's operand for a prompt that joins behind it.
+        The row is installed unless the first token is the sequence's last
+        by what is known ahead (max_tokens 1, max_seq_len: _check_stop's
+        own terms); a stop token is found at the read."""
+        stays = min(inf.req.max_tokens,
+                    self.cfg.max_seq_len - inf.prompt_len) > 1
+        return FirstRow(jnp.int32(inf.slot), jnp.bool_(stays), inf.key,
+                        self._first_sampling(inf.req))
+
+    def _join(self, inf: InflightPrefill) -> None:
+        """The prompt whose final chunk was just dispatched joins the
+        batch behind it: seated now, so that the next program's batch, its
+        headroom and its pages count the newcomer (_batch, _window_steps,
+        _grow_pages), one token behind like every row of a program in
+        flight (its first token is that program's to give, and
+        _finalize_admission's to count when it is read). What the host
+        owns goes up again before the next dispatch (the mask with its
+        bit, its table row, its sampling row, its adapter slot); tokens,
+        positions and context lengths stay the device's, where the
+        program wrote the row. A newcomer whose first token is its last
+        (max_tokens 1, max_seq_len) is never activated: the program was
+        told not to install it, and the next dispatch finds it among
+        _leavers."""
+        self._inflight = None
+        seq = self._seat(inf.req, inf.slot, inf.pages, inf.prompt_len,
+                         inf.key)
+        seq.num_tokens -= 1
+        self._pending_win = self._pending_win._replace(joiner=inf)
+        self._invalidate_dev(keep_carry=True)
+
     def _ragged_step(self, events: List[TokenEvent],
-                     inf: Optional[InflightPrefill], drafted, lag: int = 0):
+                     inf: Optional[InflightPrefill], drafted, lag: int = 0,
+                     joins: bool = False):
         """Dispatch ONE program over the decode batch, on the device carry
         as it stands (the outputs of a program still in flight `lag`
         unread steps ahead of the host, or a rebuild from the mirrors).
@@ -3296,8 +3465,11 @@ class Engine:
         step is left in flight as the pending program, for its caller to
         materialize now or one program late; `inf.done` advances at
         dispatch. The verify and the mixed-verify programs share their
-        leading operands, the chunk's trail. Returns the chunk's last-row
-        logits, on the device (None without a chunk)."""
+        leading operands, the chunk's trail. `joins`: the chunk is the
+        prompt's last and its program gives the first token (FirstRow; the
+        row goes into the carry unless that token is the sequence's last).
+        Returns the chunk's last-row logits, on the device (None without a
+        chunk)."""
         px, chunk = (), None
         if inf is not None:
             c = self.cfg.mixed_batch_tokens
@@ -3333,10 +3505,14 @@ class Engine:
                       jnp.int32(take),
                       self._pages_operand(inf.pages_arr,
                                           inf.req.request_id, inf.slot))
+                if drafted is None:
+                    px += (self._first_row(inf) if joins
+                           else self._first_idle,)
                 if self.lora is not None:
                     px += (jnp.int32(inf.aslot),)
             ys, *out = fn(*args, self.k_pages, self.v_pages, *lx, *px)
             chunk_logits = out.pop(0) if inf is not None else None
+            first = out.pop(0) if drafted is None else None
             (cur, pos, ctx_lens, self.token_counts, self.k_pages,
              self.v_pages) = out
             del args  # the donated arrays die inside this span, as in
@@ -3351,7 +3527,7 @@ class Engine:
             self._pending_win = PendingProgram(
                 1, ys, want_lp, time.monotonic() - t0, slots,
                 self.timeline.dispatch_seq, chunk,
-                [batch[s].num_tokens + lag for s in slots])
+                [batch[s].num_tokens + lag for s in slots], first=first)
             return chunk_logits
         with self.timeline.phase("device_wait"):
             toks = np.asarray(ys[0]).T  # [K+1, B]
@@ -3740,7 +3916,10 @@ class Engine:
         _finish_slot holds the leaver's pages, ring and slot back until
         k+1 has been read (_held), retires the slot in the carry for
         k+2, and k+1's rows for it are dropped by _emit_tokens'
-        membership check. What still reads k+1 at once: an exit that
+        membership check. An admission does not drain it either: where k
+        carried a prompt's final chunk, the newcomer is in `seqs` since
+        k's dispatch (_join) and k+1 decodes its row; k's read gives its
+        first token. What still reads k+1 at once: an exit that
         drops the carry (an integrity fault), and the last sequence
         leaving; abort and preemption drain BEFORE they tear down
         (_apply_aborts, the QoS paths), kv_oom needs nothing in flight."""
@@ -3860,25 +4039,34 @@ class Engine:
                     self.win_tables[slot, :] = 0
             active_mask = np.zeros((cfg.max_num_seqs,), np.bool_)
             active_mask[list(active)] = True
-            self._dev_state = self._upload(
+            self._dev_state = self._upload_mirrors(
                 self.cur_tokens, self.positions, self.context_lens,
                 active_mask,
             )
             self._dev_tables = None  # block_tables zeroed above for inactive
         if self._dev_tables is None:
             if self.win_rings is None:
-                (self._dev_tables,) = self._upload(self.block_tables)
+                (self._dev_tables,) = self._upload_mirrors(self.block_tables)
             else:
-                self._dev_tables = llama.ByKind(
-                    *self._upload(self.block_tables, self.win_tables))
+                self._dev_tables = llama.ByKind(*self._upload_mirrors(
+                    self.block_tables, self.win_tables))
         if self._dev_sampling is None:
-            self._dev_sampling = self._upload(
+            self._dev_sampling = self._upload_mirrors(
                 self.temperature, self.top_p, self.top_k,
                 self.presence, self.frequency, self.min_p,
                 self.bias_ids, self.bias_vals, self.slot_keys,
             )
         if self.lora is not None and self._dev_adapters is None:
-            (self._dev_adapters,) = self._upload(self.adapter_slots)
+            (self._dev_adapters,) = self._upload_mirrors(self.adapter_slots)
+
+    def _upload_mirrors(self, *mirrors):
+        """_upload of the host's mirrors AS THEY STAND: copies go up. An
+        upload may alias the array it is handed and read it when its turn
+        on the device comes (the CPU backend does), and the host writes
+        its mirrors again while that upload and the program behind it are
+        still queued: _join seats a newcomer right after its final
+        chunk's dispatch, a finish found at a read resets its rows."""
+        return self._upload(*(m.copy() for m in mirrors))
 
     def _dispatch_window(self, window: int) -> None:
         t0 = time.monotonic()
@@ -3944,6 +4132,8 @@ class Engine:
             # chosen [window, B], top ids and values [window, B, K]
             lps = (tuple(np.asarray(y) for y in pw.ys[1:]) if pw.want_lp
                    else None)
+            first = (tuple(np.asarray(x) for x in pw.first)
+                     if pw.joiner is not None else None)
         dt = pw.dispatch_s + (time.monotonic() - t_wait)
         # a mixed step IS its iteration's decode step — it feeds the same
         # ITL histograms (that is exactly what the mixed A/B measures)
@@ -3951,8 +4141,30 @@ class Engine:
                            pw.lag, pw.slots, chunk=pw.chunk,
                            contexts=pw.contexts)
         self._emit_tokens(events, pw.slots, toks, lps=lps)
+        if first is not None:
+            with self.timeline.phase("detok"):
+                self._admit_joiner(events, pw.joiner, *first)
         self._release_held(pw.ticket)
         return events
+
+    def _admit_joiner(self, events: List[TokenEvent], inf: InflightPrefill,
+                      tok, chosen, tids, tvals, finite) -> None:
+        """The first token of the prompt whose final chunk stayed in
+        flight (_join), read with that program: _finish_inflight's tail,
+        one program late. A stop found here (an EOS as first token) and a
+        sentinel that reads false are finishes found behind the program
+        dispatched since, which decodes the newcomer's row: _finish_slot
+        retires the row in the carry and holds its pages, ring and slot
+        back for that program."""
+        self.metrics.prompt_tokens += inf.prompt_len
+        if self.integrity != "off" and not bool(finite):
+            self._abort_poisoned(events, inf.req, inf.pages, "mixed",
+                                 inf.slot)
+            return
+        events.append(self._finalize_admission(
+            inf.req, inf.pages, inf.prompt_len, int(tok), inf.key,
+            (float(chosen), tids, tvals), inf.t_start, slot=inf.slot,
+            seq=self.seqs[inf.slot]))
 
     def _account_step(self, kind: str, dt: float, steps: int, slots,
                       chunk=None, given=None, contexts=None) -> None:
@@ -4135,7 +4347,10 @@ class Engine:
                              reason=reason, n_out=len(seq.output_tokens))
         pw = self._pending_win
         retired = slot in self._leaving
-        held = (not retired and reason in ("stop", "length")
+        # a first token that read poisoned (no output yet: _admit_joiner)
+        # is found like a stop: nothing of the carry is in doubt
+        held = (not retired
+                and (reason in ("stop", "length") or not seq.output_tokens)
                 and pw is not None and slot in pw.slots
                 and self._carry_outlives_a_finish)
         if retired:

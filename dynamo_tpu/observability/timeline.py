@@ -53,15 +53,17 @@ program such as first-token sampling and is left out) is summed into
 ``drained.by``: a measurement of what the host did while the device had
 nothing to do, which ``bubble`` reports as shares of ``loop_wall_s``.
 What opens an interval under async scheduling (engine/engine.py): a
-prompt's final chunk (read at once for its first token; the slot installs
-and the carry is rebuilt), a program that finds nothing in flight (the
-first of a busy spell, an arrival on an idle engine), no headroom or no
-pages behind the program in flight, the last sequence of a batch leaving,
-and the exits that drop the device carry (abort, preemption, kv_oom, an
-integrity fault).  A prompt's other chunks and a
-sequence's end open none: each is dispatched, or retired in the carry,
-behind the program in flight (``metrics.mixed_behind``,
-``metrics.finishes_behind``).
+program that finds nothing in flight (the first of a busy spell, an
+arrival on an idle engine, a prompt that ends with nobody decoding), no
+headroom or no pages behind the program in flight, the last sequence of a
+batch leaving, the exits that drop the device carry (abort, preemption,
+kv_oom, an integrity fault), and a final chunk whose request keeps the
+read-at-once order (a guided request, a preempted continuation with
+penalties: ``Engine._joins``).  A prompt's chunks, its first token and a
+sequence's end open none: each is dispatched, sampled and installed in
+the carry, or retired in the carry, behind the program in flight
+(``metrics.mixed_behind``, ``metrics.first_tokens_behind`` over
+``metrics.num_admitted``, ``metrics.finishes_behind``).
 
 **One clock with the device.**  While a profiler capture is open
 (``start_annotations``, serving/api.py ``capture_trace``) every segment

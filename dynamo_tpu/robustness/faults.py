@@ -217,6 +217,12 @@ class FaultPlane:
         log.info("fault injected: %s (fire #%d)", name, self._fired[name])
         return spec
 
+    def armed(self, name: str) -> bool:
+        """Whether the point is configured at all: a question that counts
+        as no check (a site that must choose its path BEFORE the
+        instrumented call asks this)."""
+        return name in self._specs
+
     # ---------------------------------------------------------- introspection
     def snapshot(self) -> Dict:
         with self._lock:
@@ -254,6 +260,10 @@ def reset_plane(seed: Optional[int] = None) -> FaultPlane:
 # ------------------------- site helpers (the instrumented-path surface) ----
 def check(name: str) -> Optional[FaultSpec]:
     return get_plane().check(name)
+
+
+def armed(name: str) -> bool:
+    return get_plane().armed(name)
 
 
 def sleep_point(name: str) -> bool:

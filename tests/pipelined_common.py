@@ -1,10 +1,14 @@
 """The async pipeline against the synchronous order, for a model's own
 engine tests. Mixed steps: a prompt of several chunks arrives while a
 sequence decodes. Finishes: sequences leave a running batch one by one,
-by `max_tokens` and by stop tokens. Tokens and the kernels' counters must
-be the same."""
+by `max_tokens` and by stop tokens. First tokens: prompts end beside a
+sequence that keeps decoding, and their final chunk's program samples the
+token and installs the row. Tokens and the kernels' counters must be the
+same."""
 
 import dataclasses
+
+import pytest
 
 from dynamo_tpu.engine.request import GenRequest
 
@@ -142,3 +146,102 @@ def assert_finish_rides_pipeline(sync_eng, eng, prompt,
     assert sync_eng.metrics.held_pages_peak == 0
     assert _stores(eng) == before
     return got, got2
+
+
+def drive(eng, script, probe=None):
+    """Step `eng` until idle; `script` maps a step number to a callable run
+    before that step (arrivals: by the count of steps, so that both orders
+    run the same programs over the same contexts). Per request: tokens,
+    the finish reason and the chosen logprobs. `probe(eng)` runs after
+    every step."""
+    eng.reset_metrics()
+    out = {}
+    n = 0
+    while eng.has_work or any(k >= n for k in script):
+        if n in script:
+            script[n](eng)
+        for ev in eng.step():
+            rec = out.setdefault(ev.request_id,
+                                 {"tokens": [], "finish": None, "lp": []})
+            if ev.token_id >= 0:
+                rec["tokens"].append(ev.token_id)
+                if ev.logprob is not None:
+                    rec["lp"].append(ev.logprob)
+            if ev.finished:
+                rec["finish"] = ev.finish_reason
+        if probe is not None:
+            probe(eng)
+        n += 1
+    return out
+
+
+def same_streams(out, ref):
+    """Tokens and finish reasons equal, logprobs to 1e-4."""
+    assert out.keys() == ref.keys()
+    for rid in ref:
+        assert out[rid]["tokens"] == ref[rid]["tokens"], rid
+        assert out[rid]["finish"] == ref[rid]["finish"], rid
+        assert out[rid]["lp"] == pytest.approx(ref[rid]["lp"], abs=1e-4), rid
+
+
+def assert_first_token_rides_pipeline(sync_eng, eng, prompt):
+    """Three prompts end beside an anchor that keeps decoding: a greedy
+    one of three chunks with logprobs, a sampled one of one chunk under
+    top-k, min-p and a logit bias, and a sampled one of two chunks under
+    top-p with penalties (its later tokens read the count row that the
+    program reset and gave the first token). `prompt(i, n)` = the i-th
+    prompt, n tokens. `eng` (async scheduling) leaves every final chunk in
+    flight (`first_tokens_behind` 3 of the 3 that arrive beside the
+    anchor; the anchor itself finds an idle engine), opens no drained
+    interval for them, gives the tokens and logprobs `sync_eng`
+    (async_scheduling=False) gives, runs the same programs over the same
+    contexts (the kernels' counters) and leaves every store as it was."""
+    assert eng.cfg.async_scheduling and not sync_eng.cfg.async_scheduling
+    c = eng.cfg.mixed_batch_tokens
+    arrivals = {
+        0: GenRequest("anchor", prompt(0, 3), max_tokens=60,
+                      temperature=0.0, ignore_eos=True),
+        3: GenRequest("greedy", prompt(1, 2 * c + 6), max_tokens=6,
+                      temperature=0.0, ignore_eos=True, logprobs=2),
+        9: GenRequest("picky", prompt(2, c - 1), max_tokens=7,
+                      temperature=0.8, seed=7, top_k=8, min_p=0.05,
+                      logit_bias={17: 1.5}, ignore_eos=True, logprobs=1),
+        14: GenRequest("penalized", prompt(3, c + 3), max_tokens=9,
+                       temperature=1.1, seed=9, top_p=0.9,
+                       presence_penalty=0.5, frequency_penalty=0.3,
+                       ignore_eos=True),
+    }
+    script = {at: (lambda e, r=r: e.add_request(dataclasses.replace(r)))
+              for at, r in arrivals.items()}
+    before = _stores(eng)
+    seen = []
+
+    def probe(e):
+        pw = e._pending_win
+        if pw is not None and pw.joiner is not None:
+            # in flight past the end of its step(), the newcomer seated
+            # without a token, and the prompt no longer inflight
+            seq = e.seqs[pw.joiner.slot]
+            assert e._inflight is None and not seq.output_tokens
+            seen.append((pw.joiner.req.request_id, e.timeline.drained_count))
+
+    want = drive(sync_eng, script)
+    got = drive(eng, script, probe)
+    same_streams(got, want)
+    assert [len(got[rid]["lp"]) for rid in ("greedy", "picky")] == [6, 7]
+    assert [rid for rid, _ in seen] == ["greedy", "picky", "penalized"]
+    # the anchor's first dispatches found an idle engine; nothing since
+    assert len({n for _, n in seen}) == 1
+    assert eng.timeline.drained_count == seen[0][1]
+    m, ref = eng.metrics, sync_eng.metrics
+    assert (m.first_tokens_behind, m.num_admitted) == (3, 4)
+    assert (ref.first_tokens_behind, ref.num_admitted) == (0, 4)
+    assert m.mixed_count == ref.mixed_count == 6
+    assert m.mixed_behind == m.mixed_count
+    assert m.output_tokens == ref.output_tokens
+    assert m.prompt_tokens == ref.prompt_tokens
+    counters, ref_counters = m.kernel_counters(), ref.kernel_counters()
+    for name in ("attn", "attn_kinds", "dsa", "ssm"):
+        assert counters[name] == ref_counters[name], name
+    assert _stores(eng) == before
+    return got

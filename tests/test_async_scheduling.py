@@ -10,7 +10,11 @@ from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import Engine
 from dynamo_tpu.engine.request import GenRequest
 
-from pipelined_common import assert_finish_rides_pipeline
+from dynamo_tpu.robustness import faults
+
+from pipelined_common import (assert_finish_rides_pipeline,
+                              assert_first_token_rides_pipeline, drive,
+                              same_streams)
 
 
 def _mk(async_sched, **kw):
@@ -159,27 +163,9 @@ def pair():
 
 
 def _drive(eng, script, probe=None):
-    """Step `eng` until idle; `script` maps a step number to a callable run
-    before that step (arrivals). Per request: tokens, the finish reason and
-    the chosen logprobs. `probe(eng)` runs after every step."""
-    eng.reset_metrics()
-    out = {}
-    n = 0
-    while eng.has_work or any(k >= n for k in script):
-        if n in script:
-            script[n](eng)
-        for ev in eng.step():
-            rec = out.setdefault(ev.request_id,
-                                 {"tokens": [], "finish": None, "lp": []})
-            if ev.token_id >= 0:
-                rec["tokens"].append(ev.token_id)
-                if ev.logprob is not None:
-                    rec["lp"].append(ev.logprob)
-            if ev.finished:
-                rec["finish"] = ev.finish_reason
-        if probe is not None:
-            probe(eng)
-        n += 1
+    """pipelined_common.drive on an engine without a prefix cache: every
+    page and every slot is free again when it is idle."""
+    out = drive(eng, script, probe)
     assert eng.allocator.free_pages == eng.cfg.num_pages - 1
     assert len(eng._free_slots) == eng.cfg.max_num_seqs
     return out
@@ -199,12 +185,7 @@ def _long(n=6, **kw):
     return GenRequest("long", LONG, max_tokens=n, ignore_eos=True, **kw)
 
 
-def _same(out, ref):
-    assert out.keys() == ref.keys()
-    for rid in ref:
-        assert out[rid]["tokens"] == ref[rid]["tokens"], rid
-        assert out[rid]["finish"] == ref[rid]["finish"], rid
-        assert out[rid]["lp"] == pytest.approx(ref[rid]["lp"], abs=1e-4), rid
+_same = same_streams
 
 
 def _three_chunks():
@@ -524,12 +505,15 @@ def test_what_is_held_is_not_handed_out_before_its_program_is_read(short):
 
 def test_six_finishes_counted_ahead_open_no_drained_interval():
     """An anchor decodes 100 tokens while six sequences of 20, 29, ... 65
-    tokens end beside it. The parent of this change (PR 48's tree) counted
-    `timeline.drained.count` 17 over this script: every finish read the
-    program in flight early, then ran one synchronous step, so two
-    dispatches found the device empty; the anchor alone counts 1. Now the
-    six leave behind the program in flight: 17 - 2 x 6 = 5 (the first
-    dispatch, and those after a first token was installed)."""
+    tokens end beside it. PR 48's tree counted `timeline.drained.count`
+    17 over this script: every finish read the program in flight early,
+    then ran one synchronous step, so two dispatches found the device
+    empty; the anchor alone counts 1. With the six leaving behind the
+    program in flight it was 17 - 2 x 6 = 5 (the first dispatch, and
+    those after a first token was installed); now the three prompts that
+    end beside decoding rows join behind their final chunk's program
+    too, and what is left is the busy spell's first dispatch and the one
+    after the grouped prefill of the first four."""
     eng = _mk(True, **MIXED, max_num_seqs=8)
 
     def run(n):
@@ -543,11 +527,11 @@ def test_six_finishes_counted_ahead_open_no_drained_interval():
             eng.step()
         m = eng.metrics
         return (eng.timeline.drained_count, m.num_finished,
-                m.finishes_behind)
+                m.finishes_behind, m.first_tokens_behind)
 
-    assert run(0) == (1, 1, 0)
-    assert run(6) == (5, 7, 6)
-    assert eng.timeline.summary()["drained"]["count"] == 5
+    assert run(0) == (1, 1, 0, 0)
+    assert run(6) == (2, 7, 6, 3)
+    assert eng.timeline.summary()["drained"]["count"] == 2
 
 
 def test_a_finished_top_k_and_logit_bias_row_closes_the_samplers_gates():
@@ -609,3 +593,241 @@ def test_a_guided_batchs_finishes_ride_the_pipeline(pair):
     m = eng.metrics
     assert (m.num_finished, m.finishes_behind) == (4, 3)
     assert any(held) and m.held_pages_peak > 0  # the EOS: one program late
+
+
+# --- a first token rides the pipeline: the final chunk's program samples
+# it and installs the row in the device carry; the host reads it one
+# program late ---
+
+def _prompt(i, n):
+    return [(7 * j + 11 * i) % 90 + 1 for j in range(n)]
+
+
+def test_a_first_token_rides_the_pipeline(pair):
+    """Greedy and sampled prompts of one, two and three chunks, with
+    logprobs, top-k / min-p / logit-bias rows and penalties, on the dense
+    tiny model (tests/test_nemotron_h_engine.py and test_falcon_h1_engine
+    .py hold the state-slot models to the same contract)."""
+    assert_first_token_rides_pipeline(*pair, _prompt)
+
+
+def test_eight_admissions_beside_an_anchor_open_no_drained_interval():
+    """An anchor decodes while eight prompts arrive one after another and
+    end beside it: every one of them is sampled by its final chunk's own
+    program (`first_tokens_behind` 8 of 8), and from the anchor's second
+    program on no dispatch finds the device empty."""
+    eng = _mk(True, **MIXED, max_num_seqs=4)
+    eng.add_request(_live("anchor", 120))
+    for _ in range(3):
+        eng.step()
+    assert eng._pending_win is not None
+    drained = eng.timeline.drained_count
+    base = eng.metrics.num_admitted
+    n_steps, late, ended = 0, 0, set()
+    while len(ended) < 8:  # then the anchor is alone again, still decoding
+        if late < 8 and n_steps % 3 == 0:
+            eng.add_request(GenRequest(
+                f"n{late}", _prompt(late, 5 + 3 * late), max_tokens=5,
+                temperature=0.7, seed=late, ignore_eos=True))
+            late += 1
+        ended.update(ev.request_id for ev in eng.step() if ev.finished)
+        n_steps += 1
+    assert "anchor" not in ended and eng._pending_win is not None
+    m = eng.metrics
+    assert (m.first_tokens_behind, m.num_admitted - base) == (8, 8)
+    assert eng.timeline.drained_count == drained
+    assert eng.timeline.summary()["drained"]["count"] == drained
+    eng.abort_request("anchor")
+    while eng.has_work:
+        eng.step()
+
+
+def _first_of(ref_eng, req):
+    """The first token the synchronous order gives `req` beside `live`."""
+    out = _drive(ref_eng, {0: _add(_live()), 3: _add(req)})
+    return out[req.request_id]["tokens"][0]
+
+
+def test_an_eos_as_first_token_leaves_the_program_behind_it_in_flight(pair):
+    """The prompt's first token is its stop token. It is found when the
+    final chunk's program is read, with the next program already on the
+    device over the newcomer's row: that program STAYS in flight, the
+    newcomer's pages and its slot wait in `_held` under its ticket, and
+    `PageAllocator.alloc` hands none of them out before it is read."""
+    ref_eng, eng = pair
+    mk = lambda stop: GenRequest(  # noqa: E731
+        "long", LONG, max_tokens=9, temperature=0.9, seed=3,
+        stop_token_ids=stop, ignore_eos=True)
+    stop = [_first_of(ref_eng, mk([]))]
+    script = lambda: {0: _add(_live()), 3: _add(mk(stop))}  # noqa: E731
+    ref = _drive(ref_eng, script())
+    assert ref["long"]["tokens"] == stop and ref["long"]["finish"] == "stop"
+    alloc = eng.allocator.alloc
+    handed, seen = [], []
+
+    def watched(n):
+        pages = alloc(n)
+        handed.append((set(pages), {p for _, ps, _, _ in eng._held
+                                    for p in ps}))
+        return pages
+
+    def probe(e):
+        if e._held and not seen:
+            (ticket, pages, rid, slot), = e._held
+            pw = e._pending_win
+            assert pw is not None and pw.ticket == ticket  # still unread
+            assert rid == "long" and slot in pw.slots
+            assert slot not in e._free_slots and slot not in e.seqs
+            assert len(pages) >= 6
+            # the whole free list, handed out and given back: none held
+            e.allocator.free(e.allocator.alloc(e.allocator.free_pages))
+            seen.append(ticket)
+
+    eng.allocator.alloc = watched
+    try:
+        out = _drive(eng, script(), probe=probe)
+    finally:
+        del eng.allocator.alloc
+    _same(out, ref)
+    assert seen and any(held for _, held in handed)
+    assert all(not got & held for got, held in handed)
+    m = eng.metrics
+    assert (m.first_tokens_behind, m.finishes_behind) == (1, 1)
+    assert m.held_pages_peak >= 6
+
+
+def test_a_first_token_that_is_the_last_is_never_activated(pair):
+    """`max_tokens` 1 is known ahead: the final chunk's program samples
+    the token and installs no row, the next program's mask never holds
+    the newcomer's bit, and its pages and its slot go back when the token
+    is read."""
+    ref_eng, eng = pair
+    script = lambda: {0: _add(_live()), 3: _add(_long(1, logprobs=2))}  # noqa: E731
+    masks = []
+
+    def probe(e):
+        pw = e._pending_win
+        if pw is not None and pw.joiner is not None:
+            masks.append((pw.joiner.slot, None))
+        elif masks and masks[-1][1] is None and e._dev_state[3] is not None:
+            # the program dispatched behind the final chunk's
+            masks[-1] = (masks[-1][0], np.asarray(e._dev_state[3]).tolist())
+
+    out = _drive(eng, script(), probe=probe)
+    _same(out, _drive(ref_eng, script()))
+    assert out["long"]["finish"] == "length" and len(out["long"]["lp"]) == 1
+    (slot, mask), = masks
+    assert mask is not None and not mask[slot] and sum(mask) == 1
+    m = eng.metrics
+    assert (m.first_tokens_behind, m.finishes_behind) == (1, 1)
+    assert m.held_pages_peak == 0
+
+
+def test_a_poisoned_final_chunk_ends_that_stream_alone(pair):
+    """The integrity sentinel beside the first token reads false (the
+    program's result, poisoned before it is read): the newcomer's stream
+    ends `integrity_fault`, the row is retired behind the program that
+    decodes it, and the anchor's stream is the synchronous order's."""
+    ref_eng, eng = pair
+    script = lambda: {0: _add(_live()), 3: _add(_long(9))}  # noqa: E731
+    ref = _drive(ref_eng, script())
+    done = []
+
+    def probe(e):
+        pw = e._pending_win
+        if not done and pw is not None and pw.joiner is not None:
+            e._pending_win = pw._replace(
+                first=(*pw.first[:4], np.bool_(False)))
+            done.append(pw.joiner.slot)
+
+    was, eng.integrity = eng.integrity, "on"
+    try:
+        out = _drive(eng, script(), probe=probe)
+    finally:
+        eng.integrity = was
+    assert done
+    assert out["long"] == {"tokens": [], "finish": "integrity_fault",
+                           "lp": []}
+    assert out["live"] == ref["live"]
+    assert eng.watchdog.integrity_faults_total.get("logits", 0) >= 1
+    assert eng.metrics.first_tokens_behind == 0  # it was given none
+
+
+def test_abort_while_a_final_chunks_program_is_in_flight(pair):
+    """The newcomer is aborted after its final chunk's dispatch and
+    before its first token is read: the abort drains the pipeline (the
+    token is emitted), then ends the stream; the anchor's is whole."""
+    ref_eng, eng = pair
+    ref = _drive(ref_eng, _three_chunks())
+    aborted = []
+
+    def probe(e):
+        pw = e._pending_win
+        if not aborted and pw is not None and pw.joiner is not None:
+            e.abort_request("long")
+            aborted.append(pw)
+
+    out = _drive(eng, _three_chunks(), probe=probe)
+    assert aborted and out["long"]["finish"] == "abort"
+    assert out["long"]["tokens"] == ref["long"]["tokens"][:1]
+    assert out["live"]["tokens"] == ref["live"]["tokens"]
+
+
+@pytest.mark.parametrize("what", ["guided", "penalized_continuation",
+                                  "nan_drill_armed"])
+def test_what_keeps_the_read_at_once_order_is_a_property_of_the_request(
+        pair, what):
+    """A guided request (its first token is masked on the host), a
+    preempted continuation whose penalties count its earlier output (the
+    host re-seeds its count row), and any request while the corrupted-
+    forward drill is armed (it poisons the logits the host reads): their
+    final chunk's program is read at once and no first token rides;
+    streams are the synchronous order's. The plain request beside them
+    in the same run rides."""
+    ref_eng, eng = pair
+    kw = {"guided": dict(guided_json=True, temperature=1.1, seed=4),
+          "penalized_continuation": dict(
+              prior_output_token_ids=[5, 9, 9, 14], presence_penalty=0.6,
+              frequency_penalty=0.4, temperature=0.9, seed=8, logprobs=1),
+          "nan_drill_armed": dict(temperature=0.9, seed=8)}[what]
+    odd = GenRequest("long", LONG, max_tokens=9, **kw)
+    plain = GenRequest("plain", LONG[:11], max_tokens=5, temperature=0.0,
+                       ignore_eos=True)
+    script = lambda: {0: _add(_live()), 3: _add(odd),  # noqa: E731
+                      12: _add(plain)}
+    rode = []
+
+    def probe(e):
+        pw = e._pending_win
+        if pw is not None and pw.joiner is not None:
+            rode.append(pw.joiner.req.request_id)
+
+    if what == "nan_drill_armed":
+        # armed, and never firing: the path is chosen by what is armed
+        faults.get_plane().arm("engine.device_nan", after=1 << 30)
+        script = lambda: {0: _add(_live()), 3: _add(odd)}  # noqa: E731
+    try:
+        ref = _drive(ref_eng, script())
+        out = _drive(eng, script(), probe=probe)
+    finally:
+        faults.reset_plane()
+    _same(out, ref)
+    if what == "nan_drill_armed":
+        assert not rode and eng.metrics.first_tokens_behind == 0
+    else:
+        assert set(rode) == {"plain"}
+        assert eng.metrics.first_tokens_behind == 1
+    assert eng.metrics.num_admitted == len(out)
+
+
+def test_warmup_compiles_what_a_first_token_behind_the_pipeline_runs():
+    """The mixed program is warmed with its first-token operands: the
+    count of compiled programs is the parent's for this configuration
+    (30, PR 50's tree), and a run whose prompts join behind their final
+    chunks compiles nothing more."""
+    eng = _mk(True, **MIXED, max_seq_len=64)
+    assert eng.warmup()["programs"] == 30
+    n = eng.compiled_program_count()
+    out = _drive(eng, _seeded_sampling())
+    assert len(out) == 3 and eng.metrics.first_tokens_behind == 1
+    assert eng.compiled_program_count() == n

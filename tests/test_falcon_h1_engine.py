@@ -22,6 +22,7 @@ from dynamo_tpu.observability.memory import MemoryAccountant
 
 from falcon_h1_common import hf_dict, tiny
 from pipelined_common import (assert_finish_rides_pipeline,
+                              assert_first_token_rides_pipeline,
                               assert_pipelined_matches_sync)
 
 CFG = dict(model="tiny-falcon-h1-debug", page_size=4, num_pages=128,
@@ -306,4 +307,14 @@ def test_a_finish_rides_the_pipeline(sync_engine, engine):
     synchronous order's."""
     assert_finish_rides_pipeline(sync_engine, engine,
                                  lambda i: prompt(40 + i, 5 + i))
+
+
+def test_a_first_token_rides_the_pipeline(sync_engine, engine):
+    """Prompts end beside a sequence that keeps decoding: the final
+    chunk's program samples the first token and installs the row; the
+    newcomer's state in ALL layers is where its chunks left it when the
+    next program decodes its row. Tokens, logprobs, `metrics.ssm` and
+    `metrics.attn_kinds` are the synchronous order's."""
+    assert_first_token_rides_pipeline(sync_engine, engine,
+                                      lambda i, n: prompt(60 + i, n))
 

@@ -21,7 +21,8 @@ from dynamo_tpu.observability.memory import MemoryAccountant
 
 from nemotron_h_common import hf_dict, tiny
 
-from pipelined_common import assert_finish_rides_pipeline
+from pipelined_common import (assert_finish_rides_pipeline,
+                              assert_first_token_rides_pipeline)
 
 CFG = dict(model="tiny-nemotron-h-debug", page_size=4, num_pages=128,
            max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
@@ -75,6 +76,11 @@ KERNEL = dict(CFG, attention_backend="pallas_interpret")
 @pytest.fixture(scope="module")
 def kernel_engine():
     return Engine(EngineConfig(**KERNEL))
+
+
+@pytest.fixture(scope="module")
+def kernel_sync_engine():
+    return Engine(EngineConfig(**KERNEL, async_scheduling=False))
 
 
 def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
@@ -302,10 +308,25 @@ def test_a_finish_rides_the_pipeline(sync_engine, engine):
                                  lambda i: prompt(40 + i, 5 + i))
 
 def test_a_finish_rides_the_pipeline_over_the_live_slots_kernel(
-        kernel_engine):
+        kernel_sync_engine, kernel_engine):
     """The same under the state update's kernel, which walks the live
     slots' list: a retired slot is off that list in the next program, so
     its state is neither read nor written while it waits."""
-    sync = Engine(EngineConfig(**KERNEL, async_scheduling=False))
-    assert_finish_rides_pipeline(sync, kernel_engine,
+    assert_finish_rides_pipeline(kernel_sync_engine, kernel_engine,
                                  lambda i: prompt(50 + i, 5 + i))
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["xla_twin", "live_slots_kernel"])
+def test_a_first_token_rides_the_pipeline(request, kernel):
+    """Prompts end beside a sequence that keeps decoding: the final
+    chunk's program samples the first token and installs the row, the
+    chunks have written the prompt's state into its reserved slot already
+    and the join only flips the slot's table row and mask bit (under the
+    kernel: puts it on the live slots' list of the NEXT program). Tokens,
+    logprobs and `metrics.ssm` are the synchronous order's."""
+    sync, eng = (request.getfixturevalue(name) for name in (
+        ("kernel_sync_engine", "kernel_engine") if kernel
+        else ("sync_engine", "engine")))
+    assert_first_token_rides_pipeline(sync, eng,
+                                      lambda i, n: prompt(60 + i, n))

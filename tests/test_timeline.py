@@ -226,7 +226,9 @@ def test_engine_run_conserves_step_wall_time():
 def test_mixed_steps_behind_the_pipeline_open_no_drained_interval():
     """window -> mixed -> mixed -> final on a real engine: every mixed step
     is dispatched while a program is unfinished, so no drained interval
-    opens before any of the three; one opens at the final chunk's read."""
+    opens before any of the three, and none at the final chunk either: its
+    program stays in flight with the prompt's first token in it, and the
+    next window is dispatched behind it."""
     from dynamo_tpu.engine.config import EngineConfig
     from dynamo_tpu.engine.engine import Engine
     from dynamo_tpu.engine.request import GenRequest
@@ -243,10 +245,10 @@ def test_mixed_steps_behind_the_pipeline_open_no_drained_interval():
     seen = []
     ragged = eng._ragged_step
 
-    def watched(events, inf, drafted, lag=0):
+    def watched(events, inf, drafted, lag=0, **kw):
         # (interval open?, closed so far, steps unread) at the dispatch
         seen.append((tl._drained, tl.drained_count, lag))
-        return ragged(events, inf, drafted, lag=lag)
+        return ragged(events, inf, drafted, lag=lag, **kw)
 
     eng._ragged_step = watched
     count0 = tl.drained_count
@@ -257,10 +259,16 @@ def test_mixed_steps_behind_the_pipeline_open_no_drained_interval():
     # behind a window of ONE step (a prompt just admitted keeps fused
     # windows out of its first chunk's way), then behind two chunks
     assert [lag for _, _, lag in seen] == [1, 1, 1]
-    assert (eng.metrics.mixed_behind, eng.metrics.mixed_count) == (3, 3)
-    assert tl._drained and tl.drained_count == count0  # open, not closed
-    eng.step()  # the next window's dispatch closes it
-    assert not tl._drained and tl.drained_count == count0 + 1
+    # counted at the dispatch, and at the read: the final chunk is unread
+    assert (eng.metrics.mixed_behind, eng.metrics.mixed_count) == (3, 2)
+    assert eng._pending_win.joiner.req.request_id == "long"
+    assert not tl._drained and tl.drained_count == count0
+    eng.step()  # the next window, dispatched behind it; then its read
+    assert (eng.metrics.mixed_count, eng.metrics.first_tokens_behind) == (3, 1)
+    assert not tl._drained and tl.drained_count == count0
+    while len(eng.seqs) == 2:
+        eng.step()
+    assert tl.drained_count == count0  # "long" left behind a program too
     while eng.has_work:
         eng.step()
     summ = tl.summary()

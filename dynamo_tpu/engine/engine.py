@@ -290,6 +290,14 @@ class EngineMetrics:
         self.ssm: Dict[str, int] = {
             "decode_rows": 0, "chunk_tokens": 0, "chunk_calls": 0,
             "layer_steps": 0, "slots_touched": 0}
+        # the same five for the gated short-convolution layers of an
+        # operator-then-FFN model (lfm2_moe; zero for any other, and `ssm`
+        # stays zero for it): decode_rows / slots_touched are rows of
+        # [B | C | u] gated and tapped and slots whose K-1 rows a layer-step
+        # read and wrote (every slot's: ops/short_conv.gated_step takes the
+        # layer's slots as one block), chunk_tokens / chunk_calls the
+        # prompt rows convolved from a slot's rows to a slot's rows
+        self.conv: Dict[str, int] = dict.fromkeys(self.ssm, 0)
         # admission passes (Engine._admit) that left the queue's head
         # waiting, by the store of a hybrid model that lacked room for it:
         # no free state slot, too few free pages for its prompt, or both
@@ -402,11 +410,14 @@ class EngineMetrics:
                 d["rows_selected"] += int(np.minimum(c, topk).sum())
 
     def observe_ssm(self, decode_rows: int, steps: int,
-                    chunk_tokens: int = 0, slots_touched: int = 0) -> None:
+                    chunk_tokens: int = 0, slots_touched: int = 0,
+                    conv: bool = False) -> None:
         """One dispatch of a hybrid model: `decode_rows` live rows over
         `steps` steps, each step's update touching `slots_touched` state
-        slots, and `chunk_tokens` of a prompt (0: no chunk)."""
-        s = self.ssm
+        slots, and `chunk_tokens` of a prompt (0: no chunk); counted under
+        `ssm`, or under `conv` where the state layers are short
+        convolutions (ModelConfig.operator_ffn)."""
+        s = self.conv if conv else self.ssm
         s["decode_rows"] += decode_rows * steps
         s["layer_steps"] += steps
         s["slots_touched"] += slots_touched * steps
@@ -517,7 +528,7 @@ class EngineMetrics:
                 "attn_kinds": {k: dict(v)
                                for k, v in self.attn_kinds.items()},
                 "dsa": dict(self.dsa), "moe": dict(self.moe),
-                "ssm": dict(self.ssm),
+                "ssm": dict(self.ssm), "conv": dict(self.conv),
                 "admit_blocked": dict(self.admit_blocked)}
 
     def snapshot(self) -> Dict[str, float]:
@@ -527,7 +538,8 @@ class EngineMetrics:
                             "spec_accepted_by", "spec_hist_by",
                             "spec_sum_by", "spec_count_by",
                             "first_token", "_first_token_lock", "moe", "attn",
-                            "attn_kinds", "dsa", "ssm", "admit_blocked",
+                            "attn_kinds", "dsa", "ssm", "conv",
+                            "admit_blocked",
                             "_moe_pending", "_moe_lock")}
         out.update(self.kernel_counters())
         with self._first_token_lock:
@@ -1508,7 +1520,7 @@ class Engine:
         # whether a hybrid model's state updates walk the live slots only
         # (the kernel) or every slot (its XLA twin): metrics.ssm
         self._ssm_live_only = False
-        if mcfg.mixer_types:
+        if mcfg.mixer_types and not mcfg.operator_ffn:
             with att_ops.attention_context(backend, mesh, lane_blocks):
                 self._ssm_live_only = ssm_ops.update_backend(
                     self.kv_spec.ssm_shape) != "xla"
@@ -2893,7 +2905,8 @@ class Engine:
                 *lx,
             )
         if self.model_cfg.mixer_types:
-            self.metrics.observe_ssm(0, 1, prompt_len)
+            self.metrics.observe_ssm(0, 1, prompt_len,
+                                     conv=self.model_cfg.operator_ffn)
         got = self._first_token_or_abort(events, req, pages, prompt_len,
                                          last_logits, "prefill")
         if got is None:
@@ -3286,7 +3299,8 @@ class Engine:
                 [], start, take, window=self.model_cfg.sliding_window,
                 sink=SLIDING in self.model_cfg.attn_sink_kinds)
         if self.model_cfg.mixer_types:
-            self.metrics.observe_ssm(0, 1, take)
+            self.metrics.observe_ssm(0, 1, take,
+                                     conv=self.model_cfg.operator_ffn)
         # this dispatch ran the chunk alone — its tenant owns the segment
         self._step_obs("prefill_chunk", dt, take=take,
                        shares={self._tenant_of(inf.req): float(take)})
@@ -4196,7 +4210,8 @@ class Engine:
         if hybrid:
             m.observe_ssm(len(slots), steps, take,
                           len(slots) if self._ssm_live_only
-                          else self.cfg.max_num_seqs)
+                          else self.cfg.max_num_seqs,
+                          conv=self.model_cfg.operator_ffn)
         if self.model_cfg.is_mla or kinds:  # read by the kernels' rooflines
             if contexts is None:
                 contexts = [self.seqs[s].num_tokens for s in slots

@@ -51,7 +51,11 @@ second's); every layer with a Mamba-2 mixer keeps, for each decode slot, the
 state S [H, P, N] float32 and the conv's last K-1 input rows
 (`KVCacheSpec.ssm_shape` / `conv_shape`, `models/llama.StatePools`; one array
 a layer, or where the layers run as one scan ONE array over (layer, slot):
-`state_stacked`). In the second form a sequence holds both in every layer, a
+`state_stacked`). A third form (`lfm2_moe`: every layer a gated short
+convolution OR attention, then an FFN) keeps a slot WITHOUT a recurrence: the
+convolution's last K-1 rows alone (`ssm_shape` empty; two rows of 2,048 lanes
+a layer at the published kernel 3: 147 KB a slot over 18 layers, less than
+one 16-token page of its KV). In the second form a sequence holds both in every layer, a
 slot flat and pages by the token, and either store can be the one that
 fills. The slot IS the decode slot: the engine
 reserves it at admission before the first chunk (a chunked prompt's state
@@ -118,9 +122,11 @@ class KVCacheSpec:
     # a hybrid model (module docstring): num_layers above counts the
     # layers that attend; each of its state_layers layers with a Mamba-2
     # mixer keeps, a decode slot, one state of ssm_shape (float32) and
-    # conv_shape rows (the model's dtype). 0: no state. state_stacked: the
-    # states are ONE array [state_layers, state_slots, ...] (the layers run
-    # as one scan), not an array a layer.
+    # conv_shape rows (the model's dtype). ssm_shape may be EMPTY: a gated
+    # short convolution has no recurrence, and its slot holds conv_shape
+    # rows alone. 0: no state. state_stacked: the states are ONE array
+    # [state_layers, state_slots, ...] (the layers run as scans), not an
+    # array a layer.
     state_layers: int = 0
     state_slots: int = 0
     ssm_shape: tuple = ()
@@ -184,10 +190,13 @@ class KVCacheSpec:
                 raise ValueError("a hybrid model needs state_slots")
             kinds = dict(  # the spec's fields of a hybrid model
                 state_layers=cfg.state_layers, state_slots=state_slots,
-                state_stacked=cfg.parallel_mixers,
-                ssm_shape=(cfg.mamba_num_heads, cfg.mamba_head_dim,
-                           cfg.ssm_state_size),
-                conv_shape=(cfg.conv_kernel - 1, cfg.mamba_conv_dim))
+                state_stacked=cfg.state_stacked,
+                ssm_shape=(() if cfg.operator_ffn else (
+                    cfg.mamba_num_heads, cfg.mamba_head_dim,
+                    cfg.ssm_state_size)),
+                conv_shape=(cfg.conv_kernel - 1,
+                            cfg.hidden_size if cfg.operator_ffn
+                            else cfg.mamba_conv_dim))
         blocks = 1 if cfg.is_mla else tensor_parallel
         if quantized and kv_heads % blocks != 0:
             raise ValueError(
@@ -289,13 +298,14 @@ class KVCacheSpec:
                 for kind, w in self.kind_lanes().items()}
 
     def bytes_per_slot(self) -> int:
-        """Bytes one state slot costs over the layers with a Mamba-2 mixer
-        (0 without): what a hybrid model's sequence owns beside its pages,
-        whatever its length."""
+        """Bytes one state slot costs over the layers that keep one (0
+        without): what a hybrid model's sequence owns beside its pages,
+        whatever its length. An empty ssm_shape costs nothing."""
         if not self.state_layers:
             return 0
+        ssm = int(np.prod(self.ssm_shape)) * 4 if self.ssm_shape else 0
         return self.state_layers * (
-            int(np.prod(self.ssm_shape)) * 4
+            ssm
             + int(np.prod(self.conv_shape)) * jnp.dtype(self.dtype).itemsize)
 
     def page_table_width(self, bucket_tokens: int,
@@ -359,7 +369,8 @@ def alloc_kv_pages(spec: KVCacheSpec, sharding=None):
                          for _ in range(spec.state_layers))
 
         return (StatePools(put(spec.shape),
-                           states(spec.ssm_shape, jnp.float32)),
+                           states(spec.ssm_shape, jnp.float32)
+                           if spec.ssm_shape else ()),
                 StatePools(put(spec.v_shape),
                            states(spec.conv_shape, jnp.dtype(spec.dtype))))
     if spec.window_layers:

@@ -111,7 +111,7 @@ _MAPPED_MODEL_TYPES = frozenset((
     "llama", "mistral", "qwen2", "qwen3", "qwen3_moe", "mixtral", "phi3",
     "gemma", "gemma2", "gemma3", "gemma3_text", "deepseek_v2", "deepseek_v3",
     "kimi_k2", "deepseek_v32", "nemotron_h", "falcon_h1", "laguna",
-    "mimo_v2"))
+    "mimo_v2", "lfm2_moe"))
 _MAPPED_ARCH_WORDS = ("Llama", "Mistral", "Qwen", "Mixtral", "Phi3", "Gemma",
                       "Deepseek", "Kimi")
 _KIND_KEYS = ("layer_types", "num_attention_heads_per_layer", "gating")
@@ -123,9 +123,14 @@ MIXER_LETTERS = {"M": MAMBA, "E": EXPERTS, "*": ATTENTION}
 # falcon_h1's layer: attention AND a Mamba-2 mixer side by side on one normed
 # input, summed, then a gated MLP. Every layer of such a model is this one.
 PARALLEL = "attention+mamba"
-MIXER_KINDS = (MAMBA, EXPERTS, ATTENTION, PARALLEL)
+# lfm2_moe's operator kinds: a layer is an OPERATOR (a gated short
+# convolution, `conv`, or GQA attention under q/k norms and a rotary) and
+# then an FFN (dense in the leading layers, experts behind them). The conv
+# keeps a state slot WITHOUT a recurrence: its last conv_kernel - 1 rows.
+CONV = "conv"
+MIXER_KINDS = (MAMBA, EXPERTS, ATTENTION, PARALLEL, CONV)
 # the kinds whose layers page KV, and those that keep a state slot
-PAGED_MIXERS, STATE_MIXERS = (ATTENTION, PARALLEL), (MAMBA, PARALLEL)
+PAGED_MIXERS, STATE_MIXERS = (ATTENTION, PARALLEL), (MAMBA, PARALLEL, CONV)
 # two-matrix experts act(u W_up) W_down: the activations written down
 TWO_MATRIX_ACTS = ("relu2", "silu")
 
@@ -218,11 +223,14 @@ def _falcon_h1_from_hf(cfg: dict) -> dict:
 def _hybrid_from_hf(cfg: dict) -> dict:
     """The ModelConfig fields of a hybrid model: `model_type: nemotron_h`
     (every layer ONE mixer by `hybrid_override_pattern`: Mamba-2, experts of
-    two matrices beside a shared one, or GQA attention without a rotary) or
-    `falcon_h1` (_falcon_h1_from_hf); {} for every other model. Refuses,
+    two matrices beside a shared one, or GQA attention without a rotary),
+    `falcon_h1` (_falcon_h1_from_hf) or `lfm2_moe` (_lfm2_from_hf); {} for
+    every other model. Refuses,
     loudly, what it would otherwise serve as another model."""
     if cfg.get("model_type") == "falcon_h1":
         return _falcon_h1_from_hf(cfg)
+    if cfg.get("model_type") in ("lfm2_moe", "lfm2"):
+        return _lfm2_from_hf(cfg)
     if cfg.get("model_type") != "nemotron_h":
         return {}
     n = int(cfg["num_hidden_layers"])
@@ -285,6 +293,72 @@ def _hybrid_from_hf(cfg: dict) -> dict:
         sliding_window=0,
         rope_llama3_scaling=None, rope_yarn_scaling=None,
         rope_longrope_scaling=None,
+    )
+
+
+def _lfm2_from_hf(cfg: dict) -> dict:
+    """The ModelConfig fields of `model_type: lfm2_moe`: every layer an
+    OPERATOR by `layer_types` (`conv`: C * conv_K(B * u) between two
+    projections, depthwise, causal, no bias, no activation, its state the
+    last K - 1 rows of B * u; `full_attention`: GQA under per-head q/k RMS
+    norms and a rotary) and then an FFN (gated dense in the first
+    `num_dense_layers`, sigmoid-routed gated experts picked under a
+    selection bias behind them), a head tied to the embedding. Refuses, by
+    the key's name, what the program would otherwise serve as another
+    model."""
+    def refuse(key, why):
+        raise ValueError(f"{key}={cfg.get(key)!r} is not implemented for "
+                         f"model_type {cfg.get('model_type')!r}: {why}")
+
+    if cfg.get("model_type") == "lfm2":
+        refuse("model_type", "the dense sibling (no experts) has not been "
+               "run against a reference; 'lfm2_moe' is served")
+    n = int(cfg["num_hidden_layers"])
+    kinds = tuple(cfg.get("layer_types") or ())
+    if len(kinds) != n:
+        raise ValueError(f"layer_types has {len(kinds)} entries for "
+                         f"num_hidden_layers={n}")
+    names = {"conv": CONV, FULL: ATTENTION}
+    if set(kinds) - set(names):
+        refuse("layer_types", f"entries other than {sorted(names)}")
+    if cfg.get("conv_bias"):
+        refuse("conv_bias", "the projections and the conv carry no bias")
+    if int(cfg.get("conv_L_cache") or 0) < 2:
+        refuse("conv_L_cache", "a conv of fewer than 2 taps keeps no state")
+    dense = int(cfg.get("num_dense_layers") or 0)
+    if dense >= n:
+        refuse("num_dense_layers", "at least one expert layer must follow "
+               f"the dense ones (num_hidden_layers={n})")
+    if not cfg.get("use_expert_bias", False):
+        refuse("use_expert_bias", "the pick is served under the selection "
+               "bias only")
+    if not cfg.get("norm_topk_prob", False):
+        refuse("norm_topk_prob", "the picked scores are served "
+               "renormalised only")
+    if int(cfg["num_experts_per_tok"]) > int(cfg["num_experts"]):
+        refuse("num_experts_per_tok", f"over num_experts="
+               f"{cfg['num_experts']}")
+    if cfg.get("sliding_window") is not None:
+        refuse("sliding_window", "the attention layers attend in full")
+    for key in ("rope_scaling", "rope_parameters"):
+        rp = cfg.get(key)
+        if rp and (rp.get("rope_type") or rp.get("type")
+                   or "default") != "default":
+            refuse(key, "the rotary is served unscaled")
+    return dict(
+        mixer_types=tuple(names[k] for k in kinds),
+        conv_kernel=int(cfg["conv_L_cache"]),
+        qk_norm=True,
+        rms_norm_eps=float(cfg.get("norm_eps") or 1e-5),
+        rope_theta=float(cfg.get("rope_theta", 1000000.0)),
+        rope_llama3_scaling=None, rope_yarn_scaling=None,
+        rope_longrope_scaling=None,
+        moe_scoring="sigmoid", router_bias=True, n_group=1, topk_group=1,
+        norm_topk_prob=True,
+        first_k_dense=dense,
+        dense_intermediate_size=int(cfg["intermediate_size"]) if dense else 0,
+        # ASSUMED (ISSUE 52): the family ties its head to the embedding
+        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", True)),
     )
 
 
@@ -410,7 +484,8 @@ def _layer_kinds_from_hf(cfg: dict, arch: str) -> dict:
                 f"model_type {mt!r} is not mapped and its config carries "
                 f"{carried}: serving it as a llama-family stack would "
                 "ignore them (per-layer kinds / head counts / an output "
-                "gate are mapped for model_type 'laguna' and 'mimo_v2')")
+                "gate are mapped for model_type 'laguna' and 'mimo_v2', "
+                "operator kinds for 'lfm2_moe')")
         return {}
     n = int(cfg["num_hidden_layers"])
     gating = cfg.get("gating")
@@ -478,6 +553,19 @@ def _layer_kinds_from_hf(cfg: dict, arch: str) -> dict:
         moe_scoring="softmax",
         router_bias=False,
     )
+
+
+# the shapes ops/grouped_matmul's kernel takes (it was measured at these
+# and no others: PERF.md section 6, PR 52): a layer of at most 32 experts
+# held here, of at most 4 MiB an int8 matrix, both extents lane multiples
+GROUPED_KERNEL_MAX_EXPERTS, GROUPED_KERNEL_MAX_MATRIX_BYTES = 32, 4 << 20
+
+
+def grouped_kernel_shapes(experts: int, k: int, n: int) -> bool:
+    """Whether a layer of `experts` int8 matrices [k, n] (or [n, k]) has the
+    shapes ops/grouped_matmul's kernel takes."""
+    return (0 < experts <= GROUPED_KERNEL_MAX_EXPERTS and k % 128 == 0
+            and n % 128 == 0 and k * n <= GROUPED_KERNEL_MAX_MATRIX_BYTES)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -694,8 +782,8 @@ class ModelConfig:
             raise ValueError(
                 "first_k_dense needs an MoE model with at least one expert "
                 "layer after the dense ones and a dense_intermediate_size")
-        if self.first_k_dense and (self.attention_bias or self.qk_norm
-                                   or self.post_norms):
+        if self.first_k_dense and not self.operator_ffn and (
+                self.attention_bias or self.qk_norm or self.post_norms):
             # the dense stack (llama.param_specs) carries the attention
             # and FFN matrices and the two pre-norms, nothing else yet
             raise ValueError(
@@ -826,7 +914,7 @@ class ModelConfig:
         bad = set(kinds) - set(MIXER_KINDS)
         if bad:
             raise ValueError(f"unknown mixer_types {sorted(bad)}")
-        if self.state_layers and not (
+        if self.state_layers and not self.operator_ffn and not (
                 self.mamba_num_heads > 0 and self.mamba_head_dim > 0
                 and self.mamba_n_groups > 0 and self.ssm_state_size > 0
                 and self.conv_kernel > 1 and self.ssm_chunk_size > 0
@@ -853,20 +941,54 @@ class ModelConfig:
         elif self.multipliers is not None:
             raise ValueError(f"multipliers without {PARALLEL!r} layers: no "
                              "layer would read them")
-        if (self.layer_types or self.is_mla or self.first_k_dense
-                or self.sliding_window or self.attention_bias or self.qk_norm
-                or self.post_norms or self.attn_logit_softcapping
-                or self.num_local_experts or self.n_group > 1
+        if self.operator_ffn:
+            self._check_operators()
+        if (self.layer_types or self.is_mla or self.sliding_window
+                or self.attention_bias or self.post_norms
+                or self.attn_logit_softcapping or self.n_group > 1
                 or self.rms_norm_unit_offset or self.embed_scale
-                or self.tie_word_embeddings):
+                or (not self.operator_ffn and (
+                    self.first_k_dense or self.qk_norm
+                    or self.num_local_experts
+                    or self.tie_word_embeddings))):
             raise ValueError(
-                "mixer_types is served in two forms: every layer ONE mixer "
+                "mixer_types is served in three forms: every layer ONE mixer "
                 "(Mamba-2 | two-matrix experts, all held | plain GQA "
-                "attention without a rotary), or every layer attention "
-                "under a rotary AND Mamba-2 side by side, then a gated MLP; "
-                "both with an untied head and none of: layer_types, MLA, "
-                "leading dense layers, a window, biases, q/k or sandwich "
-                "norms, score capping, router groups, a capacity factor")
+                "attention without a rotary); every layer attention under a "
+                "rotary AND Mamba-2 side by side, then a gated MLP; or every "
+                "layer an operator (a gated short convolution | GQA "
+                "attention under q/k norms and a rotary) and then an FFN "
+                "(leading dense layers, then gated experts, all held or a "
+                "share), its head tied or not. The first two with an untied "
+                "head, every expert held and no leading dense layers or q/k "
+                "norms; all three with none of: layer_types, MLA, a window, "
+                "biases, sandwich norms, score capping, router groups, a "
+                "capacity factor")
+
+    def _check_operators(self) -> None:
+        """The operator-then-FFN form (lfm2_moe) and what hangs on it."""
+        kinds = set(self.mixer_types)
+        if kinds - {CONV, ATTENTION}:
+            raise ValueError(
+                f"a model with a {CONV!r} layer has layers of {CONV!r} and "
+                f"{ATTENTION!r} only, got {sorted(kinds)}: its layers are an "
+                "operator and then an FFN")
+        if self.conv_kernel < 2:
+            raise ValueError(f"conv_kernel {self.conv_kernel}: a short conv "
+                             "of fewer than 2 taps keeps no state")
+        if not (self.qk_norm and self.hidden_act == "silu"
+                and not self.expert_act and self.multipliers is None
+                and not self.mamba_num_heads):
+            raise ValueError(
+                f"{CONV!r} layers are served beside attention under q/k "
+                "norms, with gated silu FFNs (expert_act '') and without "
+                "mamba_* / multipliers")
+        if self.is_moe and not (
+                self.moe_scoring == "sigmoid" and self.num_shared_experts == 0
+                and self.moe_grouped):
+            raise ValueError(
+                f"{CONV!r} layers are served with sigmoid-routed experts "
+                "through the grouped matmuls and no shared expert")
 
     def mixer_layers(self, kind: str) -> int:
         return sum(1 for k in self.mixer_types if k == kind)
@@ -875,6 +997,18 @@ class ModelConfig:
     def parallel_mixers(self) -> bool:
         """Every layer attention AND Mamba-2 side by side (falcon_h1)."""
         return PARALLEL in self.mixer_types
+
+    @property
+    def operator_ffn(self) -> bool:
+        """Every layer an operator (a gated short conv | attention) and
+        then an FFN (lfm2_moe)."""
+        return CONV in self.mixer_types
+
+    @property
+    def state_stacked(self) -> bool:
+        """A hybrid model whose layers run as scans: every state layer's
+        slots ride ONE array over (layer, slot), not an array a layer."""
+        return self.parallel_mixers or self.operator_ffn
 
     @property
     def paged_layers(self) -> int:
@@ -903,11 +1037,19 @@ class ModelConfig:
         12-18 ms for 384 rows over 128 experts; at 3,072 x 2,048 it takes
         1.9 ms, and W_down at 2,048 x 3,072 likewise (15.5 -> 1.9 ms). The
         configurations the benchmark had before are 1,024-multiples
-        already. The price: 26% more expert bytes than the model has."""
+        already. The price: 26% more expert bytes than the model has.
+
+        NOT padded: a layer whose shapes ops/grouped_matmul's kernel takes
+        (`grouped_kernel_shapes`: LFM2-8B-A1B's 32 experts of 2,048 x
+        1,792): it streams whole matrices at whatever lane multiple they
+        have, so the model's own extents are the cheapest (PR 52)."""
         def up(n: int) -> int:
             if n >= 1024:
                 return -(-n // 1024) * 1024
             return -(-n // 128) * 128 if n >= 128 else n
+        if grouped_kernel_shapes(self.held_experts, self.hidden_size,
+                                 self.intermediate_size):
+            return self.hidden_size, self.intermediate_size
         return up(self.hidden_size), up(self.intermediate_size)
 
     @property
@@ -979,7 +1121,7 @@ class ModelConfig:
 
     @property
     def num_moe_layers(self) -> int:
-        if self.mixer_types:
+        if self.mixer_types and not self.operator_ffn:
             return self.mixer_layers(EXPERTS)
         return self.num_layers - self.first_k_dense if self.is_moe else 0
 
@@ -1760,3 +1902,26 @@ PRESETS["tiny-falcon-h1-debug"] = ModelConfig(
         key=0.45, ssm_in=0.8, ssm_out=0.9,
         ssm=(0.55, 1.2, 0.65, 1.4, 0.85), mlp=(0.75, 1.1)),
 )
+
+# LFM2-MoE's structure at a toy size: nine layers, each an operator and then
+# an FFN. The operators `c c a c c c a c c`: a gated short convolution
+# (kernel 3, a 2-row state a sequence) or GQA attention (8 query heads over
+# 2 KV heads of 16 lanes, four a KV head as published, q/k norms, a rotary);
+# the FFNs: two leading dense layers of width 128, then 16 sigmoid-routed
+# gated experts of width 32 taking 2 a token (8 x 2 <= 16: the grouped
+# matmuls, as the published 4 of 32 take them) under a selection bias; a
+# tied head. The second preset is one chip's share of four: experts 4-7 held.
+PRESETS["tiny-lfm2-moe-debug"] = ModelConfig(
+    name="tiny-lfm2-moe-debug",
+    hidden_size=64, intermediate_size=32, num_layers=9, num_heads=8,
+    num_kv_heads=2, head_dim=16, tie_word_embeddings=True, qk_norm=True,
+    rope_theta=1e6,
+    num_experts=16, num_experts_per_tok=2, norm_topk_prob=True,
+    moe_scoring="sigmoid", router_bias=True,
+    first_k_dense=2, dense_intermediate_size=128,
+    mixer_types=tuple({"c": CONV, "a": ATTENTION}[c] for c in "ccacccacc"),
+    conv_kernel=3,
+)
+PRESETS["tiny-lfm2-moe-ep4-debug"] = dataclasses.replace(
+    PRESETS["tiny-lfm2-moe-debug"], name="tiny-lfm2-moe-ep4-debug",
+    num_local_experts=4, local_expert_offset=4)

@@ -26,11 +26,12 @@ from typing import Any, Dict, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
-from dynamo_tpu.models.config import (ATTENTION, EXPERTS, FULL, MAMBA,
+from dynamo_tpu.models.config import (ATTENTION, CONV, EXPERTS, FULL, MAMBA,
                                       SLIDING, ModelConfig)
 from dynamo_tpu.models import quant
 from dynamo_tpu.ops import attention as att
 from dynamo_tpu.ops import moe as moe_ops
+from dynamo_tpu.ops import short_conv
 from dynamo_tpu.ops import ssm as ssm_ops
 from dynamo_tpu.ops.rope import apply_rope
 
@@ -610,8 +611,11 @@ def _qkv(cfg: ModelConfig, lp: Params, x: jax.Array, positions: jax.Array,
         k = k + lp["bk"]
         v = v + lp["bv"]
     if cfg.qk_norm:
-        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
-        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps, cfg.rms_norm_unit_offset)
+        with jax.named_scope("attn_qk_norm"):
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps,
+                         cfg.rms_norm_unit_offset)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps,
+                         cfg.rms_norm_unit_offset)
     if kind is not None:
         return _rope_of_kind(cfg, kind, q, positions), _rope_of_kind(
             cfg, kind, k, positions), v
@@ -621,10 +625,11 @@ def _qkv(cfg: ModelConfig, lp: Params, x: jax.Array, positions: jax.Array,
         pos = positions.astype(jnp.float32) / scale
     l3, yarn = cfg.rope_llama3_scaling, cfg.rope_yarn_scaling
     lr = _longrope_args(cfg)
-    q = apply_rope(q, pos, theta, llama3_scaling=l3, yarn_scaling=yarn,
-                   longrope_scaling=lr)
-    k = apply_rope(k, pos, theta, llama3_scaling=l3, yarn_scaling=yarn,
-                   longrope_scaling=lr)
+    with jax.named_scope("attn_qk_rope"):
+        q = apply_rope(q, pos, theta, llama3_scaling=l3, yarn_scaling=yarn,
+                       longrope_scaling=lr)
+        k = apply_rope(k, pos, theta, llama3_scaling=l3, yarn_scaling=yarn,
+                       longrope_scaling=lr)
     q = _yarn_softmax_scale(cfg, q)
     if cfg.query_pre_attn_scalar > 0:
         # the attention ops scale scores by head_dim^-0.5; gemma-2 wants
@@ -1048,14 +1053,19 @@ def _mlp(cfg: ModelConfig, lp: Params, x: jax.Array,
 
 
 # ------------------------------------------------------------------ hybrid --
-# A HYBRID model (cfg.mixer_types), in one of two forms. nemotron_h: every
+# A HYBRID model (cfg.mixer_types), in one of three forms. nemotron_h: every
 # layer is x + mixer(norm(x)) with ONE mixer of a static kind (Mamba-2 |
 # experts | attention); the layers run unrolled (the published pattern has
 # no period), each kind over its own parameter stack. falcon_h1
 # (cfg.parallel_mixers): every layer is attention AND Mamba-2 on one normed
 # input, summed, then a gated MLP; the layers are alike and run as ONE scan
-# (_parallel_layers). Either way a layer that attends owns KV pages, and a
-# layer with a Mamba-2 mixer owns a STATE SLOT a decode slot: a sequence's
+# (_parallel_layers). lfm2_moe (cfg.operator_ffn): every layer an OPERATOR
+# (a gated short convolution | GQA attention under q/k norms and a rotary)
+# and then an FFN (dense | experts), as two scans whose operator is taken by
+# the layer's kind (_operator_layers). Either way a layer that attends owns
+# KV pages, and a layer with a Mamba-2 mixer or a short convolution owns a
+# STATE SLOT a decode slot (the convolution's is its last K-1 rows alone: no
+# recurrence, `k_pages.state` is empty): a sequence's
 # state lives in the slot the engine reserved for it at admission (a chunked
 # prompt's state rides there from chunk to chunk) and decode row b updates
 # slot b where it lies.
@@ -1071,7 +1081,10 @@ class StatePools(NamedTuple):
     (cfg.parallel_mixers) `state` is ONE array over (layer, slot), [L,
     slots, ...], addressed flat as the pages are (row l * slots + slot): a
     tuple of arrays cannot ride a scan, and a scanned stack would be copied
-    whole (64 x 4.2 MB a layer a step)."""
+    whole (64 x 4.2 MB a layer a step). A model whose state layers are
+    short convolutions (cfg.operator_ffn) keeps NO recurrent state:
+    `k_pages.state` is () and `v_pages.state` the one array of conv rows
+    [conv layers, slots, K-1, E]."""
     pages: Any
     state: Any
 
@@ -1118,12 +1131,68 @@ _MIXER_LEAVES = {
     EXPERTS: ("router", "router_bias", "w_up", "w_down"),
     ATTENTION: ("wq", "wk", "wv", "wo"),
 }
+# the leaves of each operator kind's stack of an operator-then-FFN model,
+# and of its two FFN kinds' (the dense one under DENSE_PREFIX)
+_OPERATOR_LEAVES = {
+    CONV: ("conv_in", "conv_w", "conv_out"),
+    ATTENTION: ("wq", "wk", "wv", "wo", "q_norm", "k_norm"),
+}
+_DENSE_FFN = ("w_gate", "w_up", "w_down")
+_EXPERT_FFN = ("router", "router_bias")
+
+
+def _operator_param_specs(cfg: ModelConfig):
+    """param_specs of an operator-then-FFN model (lfm2_moe): a stack an
+    operator kind over that kind's layers, the leading dense FFNs' stack
+    under DENSE_PREFIX, the expert layers' router and experts, and the two
+    norms of every layer in `operator_norm` / `ffn_norm` [L, E]. The conv's
+    taps [L_c, K, E] stay in the model's dtype (6 K numbers a layer)."""
+    e, l, kd = cfg.hidden_size, cfg.num_layers, cfg.first_k_dense
+    lc, la = cfg.mixer_layers(CONV), cfg.mixer_layers(ATTENTION)
+
+    def w(shape, sigma=None):
+        return (shape, "normal",
+                sigma if sigma is not None else 1.0 / shape[-1] ** 0.5)
+
+    p = {"embed": w((cfg.vocab_size, e), 0.02),
+         "final_norm": ((e,), "ones", 0.0)}
+    if not cfg.tie_word_embeddings:
+        p["lm_head"] = w((e, cfg.vocab_size), 0.02)
+    p["operator_norm"] = ((l, e), "ones", 0.0)
+    p["ffn_norm"] = ((l, e), "ones", 0.0)
+    p["conv_in"] = w((lc, e, 3 * e), 1.0 / e ** 0.5)  # [B | C | u]
+    p["conv_w"] = w((lc, cfg.conv_kernel, e), 1.0 / cfg.conv_kernel ** 0.5)
+    p["conv_out"] = w((lc, e, e), 1.0 / e ** 0.5)
+    if la:
+        h, kv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        p["wq"] = w((la, e, h, d), 1.0 / e ** 0.5)
+        p["wk"] = w((la, e, kv, d), 1.0 / e ** 0.5)
+        p["wv"] = w((la, e, kv, d), 1.0 / e ** 0.5)
+        p["wo"] = w((la, h, d, e), 1.0 / (h * d) ** 0.5)
+        p["q_norm"] = ((la, d), "ones", 0.0)
+        p["k_norm"] = ((la, d), "ones", 0.0)
+    if kd:
+        fd = cfg.dense_intermediate_size
+        p[DENSE_PREFIX + "w_gate"] = w((kd, e, fd), 1.0 / e ** 0.5)
+        p[DENSE_PREFIX + "w_up"] = w((kd, e, fd), 1.0 / e ** 0.5)
+        p[DENSE_PREFIX + "w_down"] = w((kd, fd, e), 1.0 / fd ** 0.5)
+    if cfg.is_moe:
+        le, x, f = l - kd, cfg.held_experts, cfg.intermediate_size
+        fp = cfg.expert_dims_stored[1]  # f and zero lanes (zero_lanes)
+        p["router"] = w((le, e, cfg.num_experts), 0.02)
+        p["router_bias"] = ((le, cfg.num_experts), "zeros", 0.0)
+        p["moe_w_gate"] = w((le, x, e, fp), 1.0 / e ** 0.5)
+        p["moe_w_up"] = w((le, x, e, fp), 1.0 / e ** 0.5)
+        p["moe_w_down"] = w((le, x, fp, e), 1.0 / f ** 0.5)
+    return p
 
 
 def _hybrid_param_specs(cfg: ModelConfig):
     """param_specs of a hybrid model: one stack a mixer kind on a leading
     axis of that kind's layers, the norm before every layer's mixer in
-    `mixer_norm` [L, E]."""
+    `mixer_norm` [L, E]. An operator-then-FFN model's: _operator_param_specs."""
+    if cfg.operator_ffn:
+        return _operator_param_specs(cfg)
     e, f = cfg.hidden_size, cfg.intermediate_size
     lm, le, la = (cfg.mixer_layers(k) for k in (MAMBA, EXPERTS, ATTENTION))
 
@@ -1190,6 +1259,12 @@ def zero_lanes(cfg: ModelConfig) -> Dict[str, Tuple[Tuple[int, int], ...]]:
     if not cfg.mixer_types:
         return {}
     e, f = cfg.hidden_size, cfg.intermediate_size
+    if cfg.operator_ffn:
+        # the width alone is stored wider (gated experts: silu(0) * 0 = 0)
+        if not cfg.is_moe or cfg.expert_dims_stored[1] == f:
+            return {}
+        return {"moe_w_gate": ((3, f),), "moe_w_up": ((3, f),),
+                "moe_w_down": ((2, f),)}
     if cfg.expert_dims_stored == (e, f):
         return {}
     return {"moe_w_up": ((2, e), (3, f)), "moe_w_down": ((2, f), (3, e))}
@@ -1359,6 +1434,140 @@ def _parallel_layers(cfg: ModelConfig, params: Params, x: jax.Array,
             StatePools(vp.reshape(vpool), (cp.reshape(conv.shape),)), None)
 
 
+def _conv_operator(cfg: ModelConfig, lp: Params, h: jax.Array, conv,
+                   decode, chunk, base):
+    """The gated short convolution over h [T, E] (normed) -> (y [T, E],
+    conv). `conv` is the pool of conv rows over (layer, slot) addressed
+    flat, this layer's slot i at row base + i; the rows are `decode` /
+    `chunk` as _mamba_mixer's. The decode rows read and write the layer's
+    slots' rows as ONE block (slots x (K-1) x E: 512 KB at 64 slots, once a
+    layer-step), an empty slot's coming back bit for bit; a chunk reads and
+    writes its own slot's rows."""
+    with jax.named_scope("conv_in_proj"):
+        bcu = qeinsum("te,ef->tf", h, lp["conv_in"])  # [B | C | u]
+    ys, off = [], 0
+    with jax.named_scope("conv_gate_taps"):
+        if decode is not None:
+            b, live, _ = decode
+            y, own = short_conv.gated_step(
+                bcu[:b], jax.lax.dynamic_slice_in_dim(conv, base, b),
+                lp["conv_w"], live)
+            conv = jax.lax.dynamic_update_slice_in_dim(conv, own, base, 0)
+            ys.append(y)
+            off = b
+        if chunk is not None:
+            c, slot, n_valid, fresh = chunk
+            row = base + slot
+            prev = jax.lax.dynamic_index_in_dim(conv, row, keepdims=False)
+            y, kept = short_conv.gated_rows(
+                bcu[off:off + c], jnp.where(fresh, jnp.zeros_like(prev), prev),
+                lp["conv_w"], n_valid)
+            conv = jax.lax.dynamic_update_index_in_dim(conv, kept, row, 0)
+            ys.append(y)
+        y = ys[0] if len(ys) == 1 else jnp.concatenate(ys)
+    with jax.named_scope("conv_out_proj"):
+        return qeinsum("te,ef->tf", y, lp["conv_out"]), conv
+
+
+def _operator_layers(cfg: ModelConfig, params: Params, x: jax.Array,
+                     k_pages: StatePools, v_pages: StatePools, attend,
+                     token_mask, positions, decode=None, chunk=None):
+    """_hybrid_layers for an operator-then-FFN model (lfm2_moe): layer l is
+    x += operator_l(norm(x)); x += ffn_l(norm(x)), the operator a gated
+    short convolution or attention by cfg.mixer_types, the FFN dense in the
+    first cfg.first_k_dense layers and the experts behind them.
+
+    TWO scans, whatever the depth: the dense layers and the expert layers.
+    A scan whose layers are of both operator kinds takes the operator by a
+    `lax.cond` on the layer's kind (one body a kind, the kind's parameter
+    stack indexed by the layer's index within its kind); the pages and the
+    conv rows ride the carry FLAT as _parallel_layers carries them (layer
+    l's page p at row l * P + p, its slot b at row l * B + b), both through
+    either branch, so no pool and no stack is sliced out or copied. The
+    experts' whole stack and the layer's index go to the grouped matmul as
+    _scan_layers_paged hands them."""
+    pool, vpool = k_pages.pages.shape, v_pages.pages.shape
+    (conv,) = v_pages.state
+    slots = conv.shape[1]
+    kinds, kd = cfg.mixer_types, cfg.first_k_dense
+    # a layer's index among the layers of its kind
+    within = [kinds[:i].count(k) for i, k in enumerate(kinds)]
+    stacks = {kind: {k: params[k] for k in leaves if k in params}
+              for kind, leaves in _OPERATOR_LEAVES.items()}
+
+    def at(kind, j):
+        return {k: jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, j, keepdims=False), v)
+            for k, v in stacks[kind].items()}
+
+    def conv_op(h, kp, vp, cp, j):
+        y, cp = _conv_operator(cfg, at(CONV, j), h, cp, decode, chunk,
+                               j * slots)
+        return y, kp, vp, cp
+
+    def attn_op(h, kp, vp, cp, j):
+        lp = at(ATTENTION, j)
+        q, k, v = _qkv(cfg, lp, h, positions)
+        with jax.named_scope("attn_full"):
+            o, kp, vp = attend(q, k, v, kp, vp, j * pool[1])
+        return _attn_out(cfg, lp, o), kp, vp, cp
+
+    def segment(carry, first, last, ffn, scanned):
+        """Layers [first, last) as one scan; ffn(xs, h) -> (y, counts) with
+        `scanned`, the FFN kind's leaves over these layers, sliced in xs."""
+        of = set(kinds[first:last])
+
+        def layer(carry, xs):
+            x, kp, vp, cp, counts = carry
+            h = rms_norm(x, xs["operator_norm"], cfg.rms_norm_eps)
+            if len(of) == 1:
+                op = conv_op if of == {CONV} else attn_op
+                y, kp, vp, cp = op(h, kp, vp, cp, xs["within"])
+            else:
+                y, kp, vp, cp = jax.lax.cond(
+                    xs["is_conv"], conv_op, attn_op, h, kp, vp, cp,
+                    xs["within"])
+            x = x + y
+            y, c = ffn(xs, rms_norm(x, xs["ffn_norm"], cfg.rms_norm_eps))
+            if c is not None:
+                counts = counts + c
+            return (x + y, kp, vp, cp, counts), None
+
+        xs = {"operator_norm": params["operator_norm"][first:last],
+              "ffn_norm": params["ffn_norm"][first:last],
+              "is_conv": jnp.asarray(
+                  [k == CONV for k in kinds[first:last]]),
+              "within": jnp.asarray(within[first:last], jnp.int32),
+              "ffn_layer": jnp.arange(last - first, dtype=jnp.int32),
+              **scanned}
+        return jax.lax.scan(layer, carry, xs)[0]
+
+    def dense_ffn(xs, h):
+        with jax.named_scope("mlp_dense"):
+            return _mlp(cfg, {k: xs[DENSE_PREFIX + k] for k in _DENSE_FFN}, h)
+
+    def expert_ffn(xs, h):
+        lp = {k: xs[k] for k in _EXPERT_FFN}
+        lp.update({k: params[k] for k in _EXPERT_STACKS},
+                  moe_layer=xs["ffn_layer"])
+        return _mlp(cfg, lp, h, token_mask=token_mask)
+
+    carry = (x, k_pages.pages.reshape((-1,) + pool[2:]),
+             v_pages.pages.reshape((-1,) + vpool[2:]),
+             conv.reshape((-1,) + conv.shape[2:]),
+             jnp.zeros((len(moe_ops.MOE_STATS),), jnp.int32))
+    if kd:
+        carry = segment(carry, 0, kd, dense_ffn, {
+            DENSE_PREFIX + k: params[DENSE_PREFIX + k] for k in _DENSE_FFN})
+    if cfg.is_moe:
+        carry = segment(carry, kd, cfg.num_layers, expert_ffn,
+                        {k: params[k] for k in _EXPERT_FFN})
+    x, kp, vp, cp, counts = carry
+    return (x, StatePools(kp.reshape(pool), ()),
+            StatePools(vp.reshape(vpool), (cp.reshape(conv.shape),)),
+            counts if cfg.moe_grouped else None)
+
+
 def _hybrid_layers(cfg: ModelConfig, params: Params, x: jax.Array,
                    k_pages: StatePools, v_pages: StatePools, attend,
                    token_mask, decode=None, chunk=None, positions=None):
@@ -1367,10 +1576,13 @@ def _hybrid_layers(cfg: ModelConfig, params: Params, x: jax.Array,
     K / V into the flat pool and attends; `decode` / `chunk` are
     _mamba_mixer's. Returns (x, k_pages, v_pages, the expert layers'
     counts or None). `positions` [T]: the rows' positions, for the
-    rotary of a model whose layers run both mixers (None elsewhere)."""
+    rotary of a model whose attention takes one (None elsewhere)."""
     if cfg.parallel_mixers:
         return _parallel_layers(cfg, params, x, k_pages, v_pages, attend,
                                 positions, decode, chunk)
+    if cfg.operator_ffn:
+        return _operator_layers(cfg, params, x, k_pages, v_pages, attend,
+                                token_mask, positions, decode, chunk)
     pool = k_pages.pages.shape
     kp = k_pages.pages.reshape((-1,) + pool[2:])
     vp = v_pages.pages.reshape((-1,) + pool[2:])
@@ -1408,6 +1620,12 @@ def _hybrid_layers(cfg: ModelConfig, params: Params, x: jax.Array,
             StatePools(vp.reshape(v_pages.pages.shape), tuple(conv)), counts)
 
 
+def _hybrid_rotary(cfg: ModelConfig) -> bool:
+    """Whether a hybrid model's attention takes a rotary (nemotron_h's
+    takes none: position reaches it through the states)."""
+    return cfg.parallel_mixers or cfg.operator_ffn
+
+
 def _hybrid_prefill(cfg, params, tokens, n_valid, k_pages, v_pages,
                     pages: SlotPages, start, page_size: int):
     """A whole prompt (start None: from position 0, attention over the
@@ -1416,6 +1634,13 @@ def _hybrid_prefill(cfg, params, tokens, n_valid, k_pages, v_pages,
     c = tokens.shape[0]
     token_mask = jnp.arange(c) < n_valid
     table = pages.pages
+    if start is None and cfg.operator_ffn:
+        # the whole prompt as ONE chunk from position 0: behind the
+        # operator's conditional the whole-prompt form (attention over q, k,
+        # v themselves, the pools written and not read) had both pools
+        # copied through the convolution's branch, 1.6 GB a program
+        # (compiled for a described v5e, PR 52); the chunk's form is not
+        start = 0
 
     def attend(q, k, v, kp, vp, off):
         if start is None:
@@ -1433,9 +1658,9 @@ def _hybrid_prefill(cfg, params, tokens, n_valid, k_pages, v_pages,
         return o, kp, vp
 
     fresh = jnp.bool_(True) if start is None else start == 0
-    # the rows' positions, for the rotary of a layer that runs both mixers
+    # the rows' positions, for the rotary of a model whose attention has one
     positions = ((0 if start is None else start) + jnp.arange(c)
-                 if cfg.parallel_mixers else None)
+                 if _hybrid_rotary(cfg) else None)
     x, k_pages, v_pages, counts = _hybrid_layers(
         cfg, params, _embed_rows(cfg, params, tokens), k_pages, v_pages,
         attend, token_mask, chunk=(c, pages.slot, n_valid, fresh),
@@ -1462,7 +1687,7 @@ def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
     rows [B decode | C chunk]. Returns (x [B(+C), E] after the last layer,
     k_pages, v_pages, counts)."""
     b = tokens.shape[0]
-    n_slots = k_pages.state[0].shape[1 if cfg.parallel_mixers else 0]
+    n_slots = v_pages.state[0].shape[1 if cfg.state_stacked else 0]
     if b != n_slots:
         raise ValueError(
             f"{b} decode rows over {n_slots} state slots: "
@@ -1473,8 +1698,8 @@ def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
     kernel_lens = jnp.where(live, context_lens, 0)
     tables = block_tables
     all_tokens, token_mask, mchunk = tokens, live, None
-    # every row's position, for the rotary of a layer that runs both mixers
-    rope_pos = positions if cfg.parallel_mixers else None
+    # every row's position, for the rotary of a model whose attention has one
+    rope_pos = positions if _hybrid_rotary(cfg) else None
     if chunk is not None:
         c_tokens, start, n_valid, pages = chunk
         c = c_tokens.shape[0]

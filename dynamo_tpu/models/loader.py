@@ -159,6 +159,18 @@ def load_hf_safetensors(cfg: ModelConfig, files) -> Dict[str, jax.Array]:
             "leaves stack by kind (models/llama.KIND_PREFIX) and no weight "
             "file has been read against that yet; such a model is served "
             "on seeded random weights")
+    if cfg.operator_ffn:
+        raise NotImplementedError(
+            "loading a checkpoint of model_type lfm2_moe (an operator-then-"
+            "FFN model: models/llama._operator_param_specs) is not "
+            "implemented: no weight file has been read against the "
+            "published key names (model.layers.N.conv.{in_proj,conv,"
+            "out_proj}, self_attn.{q,k,v,out}_proj with q_layernorm / "
+            "k_layernorm, operator_norm, ffn_norm, feed_forward.{w1,w2,w3} "
+            "| gate | experts.M.{w1,w2,w3} | expert_bias, model."
+            "embedding_norm: ASSUMED, benchmarks/chip/configs/lfm2-8b-a1b-"
+            "w8a8-1chip.json); such a model is served on seeded random "
+            "weights")
     dt = jnp.dtype(cfg.dtype)
     e, h, kv, d, f = (
         cfg.hidden_size,

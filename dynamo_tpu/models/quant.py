@@ -83,6 +83,10 @@ QUANT_AXES: Dict[str, Tuple[int, ...]] = {
     # vectors stay in their own dtypes)
     "ssm_in": (1,),   # [L_M, E, z | x B C | dt]
     "ssm_out": (1,),  # [L_M, d_in, E]
+    # a gated short convolution's two projections (its taps stay in the
+    # model's dtype)
+    "conv_in": (1,),   # [L_c, E, B | C | u]
+    "conv_out": (1,),  # [L_c, E, E]
     "moe_w_gate": (2,),  # [L, X, E, F]
     "moe_w_up": (2,),
     "moe_w_down": (2,),  # [L, X, F, E]

@@ -20,7 +20,9 @@ P('expert', ...)); the choice between them is made from the model's shapes
   are left out — that part of the sum is another chip's — and no code
   stands in for their exchange. The matmuls run over the smallest of a few
   static row counts that holds the rows the held experts really received
-  (`row_rungs`), and not at all where no row picked a held expert.
+  (`row_rungs`), and not at all where no row picked a held expert. A layer
+  of few, small experts under a kernel backend takes ops/grouped_matmul's
+  kernel instead of `ragged_dot` (`_kernel_for`, `_expert_rows_kernel`).
 
 `route_topk` is the single router: (expert ids [T, k], weights [T, k]);
 `topk_combine` scatters them into the dense combine matrix [T, X] the dense
@@ -36,6 +38,8 @@ import jax.numpy as jnp
 
 from dynamo_tpu.models import quant
 from dynamo_tpu.models.quant import einsum as qeinsum
+from dynamo_tpu.ops import attention as att
+from dynamo_tpu.ops import grouped_matmul as gmm
 
 
 def route_topk(logits: jax.Array, k: int,
@@ -254,6 +258,62 @@ def _expert_rows(rows: int, act: str, x, tok, row_expert, wr, n_held,
         return jnp.zeros(x.shape, jnp.float32).at[tok].add(y).astype(x.dtype)
 
 
+def _kernel_for(rows: int, w_gate, w_up, w_down):
+    """The backend under which ops/grouped_matmul takes this layer's three
+    matmuls over `rows` sorted rows (its `serves`: W8A8, gated, few small
+    lane-aligned experts, a kernel backend scoped), else None."""
+    if not rows or w_gate is None or not all(
+            isinstance(w, quant.QTensorA8) for w in (w_gate, w_up, w_down)):
+        return None
+    took = {gmm.serves(rows, *w.q.shape[-3:]) for w in (w_gate, w_up, w_down)}
+    return took.pop() if len(took) == 1 else None
+
+
+def _quantise_rows(x: jax.Array):
+    """x [R, K] -> (int8 rows, float32 scales [R, 1]), a row's largest
+    value at 127: what `_grouped_dot` does to its rows under W8A8."""
+    x32 = x.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(x32), axis=-1, keepdims=True)
+    xs = jnp.where(amax > 0, amax / 127.0, 1.0)
+    return jnp.clip(jnp.round(x32 / xs), -127, 127).astype(jnp.int8), xs
+
+
+def _expert_rows_kernel(rows: int, backend: str, order, sizes, layer,
+                        x, tok, row_expert, wr, n_held, group_sizes,
+                        w_gate, w_up, w_down) -> jax.Array:
+    """`_expert_rows` for a gated W8A8 layer ops/grouped_matmul takes
+    (`_kernel_for`): the three projections are its kernel's over the
+    layer's own `sizes` [Xh] and index `layer` (row_expert and the whole
+    stack's group_sizes are `ragged_dot`'s and unread), the scales ride the
+    kernel, the down projection's rows come out weighted (a row's gate
+    weight folded into its activation scale), and a token's k results are
+    GATHERED back by rank (`order`: the sort's permutation) and summed: no
+    scatter-add over the rows."""
+    del row_expert, group_sizes
+    t, a = x.shape[0], order.shape[0]
+    interpret = backend == "pallas_interpret"
+    live = (jnp.arange(rows) < n_held)[:, None]
+    work = gmm.pairs(sizes, rows)
+    with jax.named_scope("moe_experts"):
+        xq, xs = _quantise_rows(jnp.take(x, tok[:rows], axis=0))
+        g = gmm.grouped_matmul(xq, xs, w_gate.q, w_gate.scale, work, layer,
+                               out_dtype=x.dtype, interpret=interpret)
+        u = gmm.grouped_matmul(xq, xs, w_up.q, w_up.scale, work, layer,
+                               out_dtype=x.dtype, interpret=interpret)
+        # rows behind the last group were never written: select
+        hq, hs = _quantise_rows(jnp.where(live, jax.nn.silu(g) * u, 0))
+        y = gmm.grouped_matmul(
+            hq, hs * wr[:rows, None], w_down.q, w_down.scale, work, layer,
+            out_dtype=jnp.float32, interpret=interpret)
+        # assignment (token, j) is sorted row rank[token, j]; one behind
+        # the held rows (another chip's expert, a masked token) adds nothing
+        rank = jnp.zeros((a,), jnp.int32).at[order].set(
+            jnp.arange(a, dtype=jnp.int32))
+        got = jnp.take(y, jnp.minimum(rank, rows - 1), axis=0)
+        got = jnp.where((rank < n_held)[:, None], got, 0)
+        return got.reshape(t, a // t, -1).sum(axis=1).astype(x.dtype)
+
+
 def moe_mlp_grouped(
     x: jax.Array,        # [T, E]
     topi: jax.Array,     # [T, K] expert ids over the router's whole width
@@ -319,8 +379,16 @@ def moe_mlp_grouped(
     rungs = row_rungs(t * k, xh / (num_experts or xh))
     ladder = jnp.asarray(rungs, jnp.int32)
     rung = jnp.sum(ladder[:-1] < n_held)  # the first that holds n_held
+    # a rung ops/grouped_matmul takes runs `_expert_rows_kernel` over the
+    # layer's own sizes and index; every other branch is traced as it was
+    took = [_kernel_for(r, w_gate, w_up, w_down) for r in rungs]
+    for backend in filter(None, took):
+        att._note_impl(gmm._OP, backend)
     out = jax.lax.switch(
-        rung, [functools.partial(_expert_rows, r, act) for r in rungs],
+        rung, [functools.partial(_expert_rows_kernel, r, backend, order,
+                                 layer_sizes, layer) if backend else
+               functools.partial(_expert_rows, r, act)
+               for r, backend in zip(rungs, took)],
         x, tok, row_expert, wr, n_held, group_sizes, w_gate, w_up, w_down)
     n_all = (jnp.sum(token_mask) * k if token_mask is not None
              else jnp.int32(t * k))

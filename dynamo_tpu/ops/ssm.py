@@ -38,6 +38,18 @@ from dynamo_tpu.ops import attention as att
 _HI = jax.lax.Precision.HIGHEST
 
 
+def tap_sum(acc, cat: jax.Array, w: jax.Array, t: int) -> jax.Array:
+    """acc + sum_k w_k * cat[k : k + t] in float32: the taps of a depthwise
+    causal conv over `cat` [K-1+T, C] (the K-1 rows before the first, then
+    the T rows), w [K, C]. What comes before (a bias) and after (an
+    activation, a gate) is the caller's: Mamba-2's conv_rows here, the gated
+    short convolution in ops/short_conv.py."""
+    for j in range(w.shape[0]):
+        acc = acc + w[j].astype(jnp.float32) * cat[j:j + t].astype(
+            jnp.float32)
+    return acc
+
+
 def conv_rows(xbc: jax.Array, prev: jax.Array, w: jax.Array, b: jax.Array,
               n_valid) -> tuple[jax.Array, jax.Array]:
     """Depthwise causal conv over time with bias, then silu.
@@ -49,10 +61,7 @@ def conv_rows(xbc: jax.Array, prev: jax.Array, w: jax.Array, b: jax.Array,
     k = w.shape[0]
     t = xbc.shape[0]
     cat = jnp.concatenate([prev.astype(xbc.dtype), xbc])  # [K-1+T, C]
-    acc = b.astype(jnp.float32)
-    for j in range(k):
-        acc = acc + w[j].astype(jnp.float32) * cat[j:j + t].astype(
-            jnp.float32)
+    acc = tap_sum(b.astype(jnp.float32), cat, w, t)
     # real rows are cat[K-1 : K-1+n_valid]; the K-1 before the next token
     kept = jax.lax.dynamic_slice_in_dim(cat, n_valid, k - 1)
     return jax.nn.silu(acc).astype(xbc.dtype), kept.astype(prev.dtype)
@@ -61,7 +70,10 @@ def conv_rows(xbc: jax.Array, prev: jax.Array, w: jax.Array, b: jax.Array,
 def conv_step(xbc: jax.Array, prev: jax.Array, w: jax.Array, b: jax.Array,
               live: jax.Array) -> tuple[jax.Array, jax.Array]:
     """conv_rows for one token a slot: xbc [B, C], prev [B, K-1, C], live
-    [B] bool -> (out [B, C], prev shifted by the token where live)."""
+    [B] bool -> (out [B, C], prev shifted by the token where live). One
+    row a slot: the same taps as one contraction over the K rows (an
+    einsum, not tap_sum's loop, which only conv_rows and
+    ops/short_conv.gated_rows share)."""
     cat = jnp.concatenate([prev.astype(xbc.dtype), xbc[:, None]], axis=1)
     acc = b.astype(jnp.float32) + jnp.einsum(
         "bkc,kc->bc", cat.astype(jnp.float32), w.astype(jnp.float32))

@@ -545,7 +545,7 @@ def test_the_tiny_preset_is_what_from_hf_config_makes_of_its_spelling():
     (dict(mixer_types=(PARALLEL, "mamba", PARALLEL)), "no layer of another"),
     (dict(multipliers=None), "multipliers"),
     (dict(num_experts=4), "without experts"),
-    (dict(tie_word_embeddings=True), "two forms"),
+    (dict(tie_word_embeddings=True), "three forms"),
     (dict(mamba_n_groups=3), "multiple of"),
 ], ids=["mixed_kinds", "no_multipliers", "experts", "tied_head", "groups"])
 def test_a_model_config_refuses_what_the_block_is_not(change, word):

@@ -962,3 +962,139 @@ def test_flash_kernel_compiles_for_v5e_at_wider_keys(one_chip, s, kv_heads,
         **kw).compile().as_text()
     assert "tpu_custom_call" in text
     assert f"bf16[{kv_heads},{64 // kv_heads},{s},128]" in text
+
+
+# LFM2-8B-A1B as published (PR 52): 64 slots = 64 state slots of conv rows
+# alone, a decode table of 4,096 tokens, the one chunked-prompt table width
+LFM2_SLOTS, LFM2_TABLE, LFM2_CHUNK_TABLE = 64, 256, 271
+LFM2_SCOPES = ("conv_in_proj", "conv_gate_taps", "conv_out_proj", "attn_full",
+               "attn_qk_norm", "attn_qk_rope", "mlp_dense", "moe_router",
+               "moe_experts")
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed", "window", "prefill",
+                                     "chunk"])
+def test_lfm2_step_compiles_for_v5e_under_its_scope_names(one_chip, program):
+    """The WHOLE published model's decode step, mixed step, 16-step window,
+    whole-prompt prefill and prompt chunk at the cell's sizes (w8a8, all 24 layers, 32
+    experts a layer, 64 slots and their conv rows, a 256-token chunk), for a
+    described v5e: no op leaves the kernels at 64-lane heads (4 query heads
+    a KV head, a 512-lane row: no counted fallback), every span the
+    benchmark and the docs name is in the HLO, the attention kernels are
+    found by the patterns of the cell's roofline metrics, the program holds
+    TWO layer scans and ONE conditional over the operator's kind whatever
+    the depth, the experts' matmuls are the grouped-matmul kernel of our
+    own, no pool, no conv-row array and no weight stack is copied
+    (everything beside the arguments stays under 0.06 GB: the pool is 0.8
+    GB, the smallest stack 25 MB), and the arguments are the memory the
+    configuration file reckons."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.engine.kv_cache import KVCacheSpec
+    from dynamo_tpu.models import llama, quant
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.ops import attention as att
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return arg(shape, jnp.int32)
+
+    cfg = ModelConfig.from_model_name(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks/chip/configs/lfm2-8b-a1b-w8a8-1chip"))
+    b = LFM2_SLOTS
+    spec = KVCacheSpec.from_model(cfg, 8192, PAGE, state_slots=b)
+    assert spec.shape == (6, 8192, PAGE, 512) and spec.ssm_shape == ()
+    assert spec.bytes_per_slot() == 147456
+    params = {}
+    for name, (shape, kind, _) in llama.param_specs(cfg).items():
+        axes = quant.quant_axes(name)
+        if axes and kind == "normal":
+            params[name] = quant.QTensorA8(
+                arg(shape, jnp.int8),
+                arg([1 if i in axes else s for i, s in enumerate(shape)],
+                    jnp.float32))
+        else:
+            params[name] = arg(shape, jnp.float32 if name == "router_bias"
+                               else jnp.bfloat16)
+    kp = llama.StatePools(arg(spec.shape, jnp.bfloat16), ())
+    vp = llama.StatePools(arg(spec.v_shape, jnp.bfloat16),
+                          (arg((18, b) + spec.conv_shape, jnp.bfloat16),))
+    before = dict(att.pallas_fallback_counts())
+    with att.attention_context("pallas", None, 1):
+        if program == "decode":
+            compiled = jax.jit(functools.partial(
+                llama.decode_step, cfg, page_size=PAGE),
+                donate_argnums=(5, 6)).lower(
+                params, i32(b), i32(b), i32(b, LFM2_TABLE), i32(b), kp,
+                vp).compile()
+        elif program == "window":
+            compiled = jax.jit(_hybrid_window(cfg),
+                               donate_argnums=(5, 6)).lower(
+                params, i32(b), i32(b), i32(b, LFM2_TABLE), i32(b), kp,
+                vp).compile()
+        elif program == "mixed":
+            compiled = jax.jit(functools.partial(
+                llama.mixed_step, cfg, page_size=PAGE),
+                donate_argnums=(9, 10)).lower(
+                params, i32(b), i32(b), i32(b, LFM2_TABLE), i32(b),
+                i32(CHUNK), i32(), i32(),
+                llama.SlotPages(i32(LFM2_CHUNK_TABLE), i32()), kp,
+                vp).compile()
+        elif program == "chunk":
+            compiled = jax.jit(functools.partial(
+                llama.prefill_chunk, cfg, page_size=PAGE),
+                donate_argnums=(4, 5)).lower(
+                params, i32(CHUNK), i32(), i32(), kp, vp,
+                llama.SlotPages(i32(LFM2_CHUNK_TABLE), i32())).compile()
+        else:
+            compiled = jax.jit(functools.partial(
+                llama.prefill, cfg, page_size=PAGE),
+                donate_argnums=(3, 4)).lower(
+                params, i32(128), i32(), kp, vp,
+                llama.SlotPages(i32(8), i32())).compile()
+    assert dict(att.pallas_fallback_counts()) == before
+    text = compiled.as_text()
+    for scope in LFM2_SCOPES:
+        assert scope in text, scope
+    calls = [ln.strip() for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "attn_full" in ln]
+    assert len(calls) == 1  # ONE attention body for the six layers
+    if program in ("decode", "window", "mixed"):
+        (pattern,) = _layer_metric_patterns(
+            "gqa_%s_attn_roofline.extract" % (
+                "mixed" if program == "mixed" else "decode"))
+        assert re.search(pattern, calls[0]), calls[0][:200]
+        assert "bf16[49152,16,512]" in calls[0]  # the pool flat, in place
+    # two layer scans (the window's own scan around them) and the
+    # operator's conditional in the expert layers' scan alone
+    assert text.count(" while(") == (3 if program == "window" else 2)
+    assert len(re.findall(r" conditional\(", text)) >= 1
+    assert "true_computation" in text or "branch_computations" in text
+    # no program copies a pool: the whole-prompt prefill runs as ONE chunk
+    # from position 0 (models/llama._hybrid_prefill says what its own form
+    # cost behind the operator's conditional)
+    assert not re.findall(r"bf16\[(?:6,8192|49152),16,512\]\S* copy\(", text)
+    # the experts' three matmuls are ops/grouped_matmul's kernel in every
+    # rung that holds rows, found by the name the cell's roofline metric
+    # matches, and no ragged-dot is left beside it
+    gmm_calls = [ln for ln in text.splitlines()
+                 if "tpu_custom_call" in ln and "moe_experts" in ln]
+    assert len(gmm_calls) == 3 and "ragged-dot" not in text
+    (pattern,) = _layer_metric_patterns("moe_grouped_matmul_roofline.extract")
+    assert all(re.search(pattern, ln.strip()) for ln in gmm_calls), (
+        gmm_calls[0][:200])
+    assert not re.search(r"bf16\[(18,64|1152),2,2048\]\S* copy\(", text)
+    assert not re.search(r"= s8\[(18|6|22|2|704),\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.06e9
+    stored = cfg.expert_dims_stored[1]
+    want = (22 * 32 * 3 * 2048 * stored + 18 * 16.78e6 + 6 * 10.49e6
+            + 2 * 44.04e6 + 134.2e6 + 2 * 6 * 8192 * 16 * 512 * 2
+            + 18 * 64 * 2 * 2048 * 2)
+    assert want < mem.argument_size_in_bytes < want + 0.05e9

@@ -2711,11 +2711,12 @@ class Engine:
             for i, r in enumerate(reqs):
                 aslots[i] = self._adapter_slot(r)
             lx = (jnp.asarray(aslots),)
-        with self.timeline.phase("dispatch", kind="prompt"):
+        with self.timeline.phase("dispatch", kind="prompt") as ph:
             logits, self.k_pages, self.v_pages = self._prefill_batch(
                 self.params, jnp.asarray(tokens), jnp.asarray(seq_lens),
                 self.k_pages, self.v_pages, jnp.asarray(pages_arr), *lx,
             )
+            ph.done_when(logits, rows=len(reqs))
         if faults.check("engine.device_nan") is not None:
             # chaos drill: poison ONE lane (the lead request) — the
             # sentinel must abort exactly that stream while co-batched
@@ -2753,13 +2754,14 @@ class Engine:
         raw_logits = logits
         if pen_rows is not None:
             logits = logits - jnp.asarray(pen_rows)
-        with self.timeline.phase("dispatch", kind="prompt"):
+        with self.timeline.phase("dispatch", kind="prompt") as ph:
             toks, chosen, tids, tvals = self._sample_first_batch(
                 logits, jnp.asarray(temp), jnp.asarray(top_p),
                 jnp.asarray(top_k), jnp.asarray(min_p),
                 jnp.asarray(bias_ids), jnp.asarray(bias_vals),
                 jnp.asarray(keys), jnp.asarray(seq_lens - 1),
             )
+            ph.done_when(toks, rows=len(reqs))
         with self.timeline.phase("device_wait"):
             toks_np, chosen_np = np.asarray(toks), np.asarray(chosen)
             tids_np, tvals_np = np.asarray(tids), np.asarray(tvals)
@@ -2965,7 +2967,7 @@ class Engine:
 
         lx = ((jnp.int32(self._adapter_slot(req)),)
               if self.lora is not None else ())
-        with self.timeline.phase("dispatch", kind="prompt"):
+        with self.timeline.phase("dispatch", kind="prompt") as ph:
             last_logits, self.k_pages, self.v_pages = self._prefill(
                 self.params,
                 jnp.asarray(tokens),
@@ -2978,6 +2980,7 @@ class Engine:
                     self._free_slots[-1] if self._free_slots else 0),
                 *lx,
             )
+            ph.done_when(last_logits, rows=1)
         if self.model_cfg.mixer_types:
             self.metrics.observe_ssm(0, 1, prompt_len,
                                      conv=self.model_cfg.operator_ffn)
@@ -3393,7 +3396,7 @@ class Engine:
         tokens[:take] = inf.req.prompt_token_ids[start:start + take]
 
         lx = (jnp.int32(inf.aslot),) if self.lora is not None else ()
-        with self.timeline.phase("dispatch", kind="prompt"):
+        with self.timeline.phase("dispatch", kind="prompt") as ph:
             last_logits, self.k_pages, self.v_pages = self._prefill_chunk(
                 self.params,
                 jnp.asarray(tokens),
@@ -3405,6 +3408,7 @@ class Engine:
                                     inf.slot),
                 *lx,
             )
+            ph.done_when(last_logits, rows=1)
             inf.done += take
             self._save_state(inf)
         dt = time.monotonic() - t0
@@ -3631,7 +3635,8 @@ class Engine:
                     self._dev_tables, *self._dev_sampling,
                     self.token_counts)
         with self.timeline.phase(
-                "dispatch", kind="decode" if inf is None else "prompt"):
+                "dispatch",
+                kind="decode" if inf is None else "prompt") as ph:
             if inf is not None:  # fresh uploads each call, never donated
                 px = (jnp.asarray(p_tokens), jnp.int32(start),
                       jnp.int32(take),
@@ -3643,6 +3648,8 @@ class Engine:
                 if self.lora is not None:
                     px += (jnp.int32(inf.aslot),)
             ys, *out = fn(*args, self.k_pages, self.v_pages, *lx, *px)
+            # the tokens the host reads later anyway: never donated
+            ph.done_when(ys[0], rows=len(batch) + (inf is not None))
             chunk_logits = out.pop(0) if inf is not None else None
             first = out.pop(0) if drafted is None else None
             (cur, pos, ctx_lens, self.token_counts, self.k_pages,
@@ -4203,7 +4210,7 @@ class Engine:
 
     def _dispatch_window(self, window: int) -> None:
         t0 = time.monotonic()
-        with self.timeline.phase("dispatch", kind="decode"):
+        with self.timeline.phase("dispatch", kind="decode") as ph:
             # chaos: a wedged device program — the sleep runs INSIDE the
             # armed dispatch seam with _exec_lock held, exactly what a
             # real hang looks like to the watchdog monitor thread
@@ -4229,6 +4236,7 @@ class Engine:
                 (ys, cur, pos, ctx_lens, self.token_counts, self.k_pages,
                  self.v_pages) = fn(*args)
             self._dev_state = (cur, pos, ctx_lens, active_dev)
+            ph.done_when(ys[0], steps=window, rows=len(batch))
             # the last references to the donated arrays die INSIDE this
             # span: on a TPU releasing them takes ~0.5 ms a window, which
             # `host_share_pct` would otherwise read as the host's

@@ -12,9 +12,11 @@ second observation path:
   plus the step-timeline self-time phases (admit / page_alloc / dispatch /
   device_wait / detok / bank) from observability/timeline.py riding the
   same series as additional label values;
-- `dynamo_engine_host_gap_seconds` — inter-dispatch host gap sampled by
-  the step timeline at every device-program launch (the zero-bubble
-  roadmap item's acceptance number);
+- `dynamo_engine_device_idle_seconds` — how long the device idled
+  before each program, from the programs' own completion stamps (the
+  step timeline's device account: the zero-bubble roadmap item's
+  acceptance number), and `dynamo_engine_device_idle_seconds_total
+  {segment}` — the same idle time by what the engine thread was doing;
 - `dynamo_engine_batch_occupancy` — decode-window batch occupancy
   (active slots / max_num_seqs) histogram;
 - `dynamo_engine_mixed_prefill_fraction` — unified ragged step
@@ -115,15 +117,16 @@ def _phase_series(engine):
     return out
 
 
-def _host_gap_series(engine):
-    """Inter-dispatch host-gap distribution from the step timeline — the
-    zero-bubble roadmap item's acceptance number."""
+def _device_idle_series(engine):
+    """The device's idle time before each program (timeline.device:
+    idle_before_s, one sample a program) — the zero-bubble roadmap item's
+    acceptance number."""
     from dynamo_tpu.observability.timeline import PhaseDigest
 
     edges_ms = PhaseDigest._EDGES_MS
     idxs = list(range(0, len(edges_ms), _OCTAVE_STRIDE))
     edges_s = [round(edges_ms[i] / 1e3, 8) for i in idxs]
-    gd = engine.timeline.gap_digest
+    gd = engine.timeline.idle_digest
     cum, count = _downsample_cum(gd.buckets, gd.count, idxs)
     return [({}, edges_s, cum, round(gd.sum_s, 6), count)]
 
@@ -264,11 +267,19 @@ class EngineMetricsBridge:
             "Engine phase step-time distribution (PhaseTimer bridge)",
             registry, lambda: _phase_series(self.engine))
         CallbackHistogram(
-            "dynamo_engine_host_gap_seconds",
-            "Inter-dispatch host gap: wall time between a device program "
-            "returning control and the next program launching (step "
-            "timeline; the zero-bubble target)",
-            registry, lambda: _host_gap_series(self.engine))
+            "dynamo_engine_device_idle_seconds",
+            "Device idle time before each program: from the completion "
+            "stamp of the program before it to its own launch (step "
+            "timeline's device account; the zero-bubble target)",
+            registry, lambda: _device_idle_series(self.engine))
+        CallbackCounterVec(
+            "dynamo_engine_device_idle_seconds_total",
+            "Device idle time by the engine thread's segment that "
+            "overlapped it (admit, page_alloc, dispatch, device_wait, "
+            "detok, bank, untracked, between_steps, no_work)",
+            registry, lambda: {(("segment", k),): v for k, v in
+                               self.engine.timeline.device_idle_by().items()},
+            labelnames=("segment",))
         CallbackHistogram(
             "dynamo_engine_batch_occupancy",
             "Decode-window batch occupancy (active slots / max_num_seqs)",

@@ -1,10 +1,10 @@
 """Stepline: unified per-step timeline, host-bubble accounting, Perfetto
 export.
 
-The ROADMAP's zero-bubble engine-loop item is gated on measurement:
-"acceptance = phase accounting shows inter-dispatch host gap near zero".
-This module is that measurement substrate — an always-on, low-overhead
-per-step timeline the engine's `step()` feeds with precise monotonic
+The ROADMAP's zero-bubble engine-loop item is gated on measurement: the
+device must not idle between one program and the next.  This module is
+that measurement substrate — an always-on, low-overhead per-step
+timeline the engine's `step()` feeds with precise monotonic
 phase intervals:
 
 - ``admit``       — scheduling/admission host work (aborts, queue picks,
@@ -25,15 +25,9 @@ by construction.  Conservation therefore holds exactly:
 ``sum(phase self-times) + gap = step wall time``, where ``gap`` is the
 host time no instrumented phase claimed.
 
-Separately, each ``dispatch`` entry samples the **inter-dispatch host
-gap** — wall time between device program N returning control and
-program N+1 launching (clamped at 0: async scheduling legitimately
-dispatches program N+1, a decode window or a mixed step that carries a
-prompt's chunk, before materializing program N).  This is the
-number the zero-bubble PR must drive to ~0; it exports as
-``dynamo_engine_host_gap_seconds`` and the per-phase digests ride the
-existing ``dynamo_engine_phase_seconds{phase}`` histogram as additional
-label values (observability/engine_metrics.py).
+The per-phase digests ride the existing
+``dynamo_engine_phase_seconds{phase}`` histogram as additional label
+values (observability/engine_metrics.py).
 
 The record also spans the engine thread's time OUTSIDE ``step()`` when a
 loop driver (serving/engine_service.py ``_run``) declares it with
@@ -65,11 +59,76 @@ the carry, or retired in the carry, behind the program in flight
 (``metrics.mixed_behind``, ``metrics.first_tokens_behind`` over
 ``metrics.num_admitted``, ``metrics.finishes_behind``).
 
+**The device's own end** (``device``).  ``drained`` and ``token_time``
+are the host's knowledge: a program counts as unfinished until a
+``device_wait`` has read it, so behind a program in flight the chip may
+long be idle while the host stages the next one, and neither account
+sees it.  The device account takes each program's end from the device.
+A dispatch hands over an output of its program that the next program
+does not take as donated (``phase("dispatch").done_when(array)``: the
+tokens the host reads later anyway, or the logits); one daemon thread a
+timeline, the watcher, takes the handles in ticket order, blocks until
+the array is ready (the GIL is released meanwhile), stamps
+``time.monotonic()``, drops the handle and posts ``(ticket, t_done)`` to
+a deque that the engine thread empties at its next segment boundary
+(``_mark``).  The engine thread pays one queue put a dispatch.  Where
+its own ``device_wait(upto=k)`` returns, that exit is a second stamp of
+the same event; the account keeps whichever it sees first, which is the
+earlier one but for a stamp taken before the exit and posted after it
+(one GIL hand-off).  The thread starts at the first handle, ends with
+``close()`` (serving/engine_service.py) or when the timeline is
+collected, and a disabled timeline starts none; ``reset()`` abandons it
+with its stamps in flight and the next handle starts another, and so
+does a dispatch that finds ``WATCH_BACKLOG`` handles behind it: a thread
+that waits on a hung program holds nothing back for long.  A handle that
+raises (a deleted buffer, a backend that died) is stamped as of then.
+
+With ``enq_k`` the exit of program k's ``dispatch`` (the lower reading
+of busy: the runtime is handed the program a little before its dispatch
+returns; ``busy_enter_s`` keeps the upper reading, from the dispatch's
+enter, and docs/observability.md says what the device trace made of
+both) and ``done_k`` its stamp: ``start_k = max(enq_k, done_{k-1})``,
+``busy_k = done_k - start_k``, ``idle_before_k = max(0, enq_k -
+done_{k-1})``.  ``busy_s + idle_s`` is ``loop_wall_s`` (under a loop
+driver; a caller of ``step()`` without one owns the time between its
+steps, and a program in flight through it is busy all the same), ``busy_by`` splits busy by the
+program's declared kind and ``idle_by`` cuts every idle interval by the
+thread's segments that overlap it, ``device_wait`` included (an implicit
+program such as first-token sampling carries no ticket, see below).
+While every dispatched program is stamped, a segment goes to ``idle_by``
+as it closes; while one is unstamped the time counts as busy and the
+closed segments are kept in a tail, so that a stamp, which arrives after
+the segments it falls in, can cut ``[done_k, enq_{k+1})`` out of them.
+The tail is dropped up to the oldest unstamped program's start at every
+stamp and holds at most ``TAIL_SEGMENTS`` segments (some ten seconds of
+a busy engine); what a stamp finds older than the tail is charged whole
+to ``untracked`` and counted in ``device.late_stamps``.  A summary read
+between stamps counts the time since the last settled instant as busy of
+the oldest unstamped program's kind.  ``row_idle_s`` charges each live
+sequence, at every emission, the growth of ``idle_s`` since its last one
+(beside ``token_time.row_s``, not inside it); over ``token_time.gaps``
+it is the milliseconds of a token's time in which the chip ran nothing.
+``idle_worst`` keeps the 8 longest idle intervals, ranked by what is not
+``no_work`` (the part host work can shrink); ``programs`` counts the
+tickets stamped since the reset and ``unstamped`` those dispatched and
+not yet stamped, 0 whenever the engine is idle; ``stamp_skew_ms`` is the
+account's own error bar: watcher's stamp less the wait's exit, over the
+tickets whose ``device_wait`` blocked for more than 1 ms.  Programs that
+carry no ticket (the ``save_state`` / ``restore_state`` copies, first-
+token sampling, a disaggregated prefill, ``import_kv``, the capture's
+marks) run in order between two ticketed ones: where the next ticketed
+program is already enqueued they fall inside its busy interval, where
+the device is otherwise empty they read as idle under the segment the
+thread was in (``device_wait`` for a sampling it waits on).
+
 **One clock with the device.**  While a profiler capture is open
 (``start_annotations``, serving/api.py ``capture_trace``) every segment
 is also a ``jax.profiler.TraceAnnotation`` named ``stepline/<segment>``
 and each step a ``StepTraceAnnotation``, so the trace holds the host's
-phases on the profiler's clock beside the device's operations.
+phases on the profiler's clock beside the device's operations; a
+``dispatch`` carries ``ticket=`` (the one its program gets) and ``kind=``
+as annotation arguments and a ``device_wait`` the ticket it waits for,
+so a run of device operations can be joined to the program's record.
 
 **Token time by cause.**  Every second of the thread's time also goes
 to exactly one of three causes (``token_time.cause_s``; their sum is
@@ -82,7 +141,8 @@ window, a verify; also what an undeclared dispatch is) or ``prompt`` (it
 carries prompt tokens: a mixed step, a chunk, a prefill).  A ticket keeps
 its kind until a ``device_wait`` proves it finished, so this is the host's
 knowledge: under async scheduling the device may already run program k+1
-when the host learns that k is done.  Two windows are both ``decode``; a
+when the host learns that k is done (``device`` above has the device's
+own end).  Two windows are both ``decode``; a
 mixed step dispatched behind window k waits as ``decode`` until k is read
 and as ``prompt`` from then on, with nothing drained between.  A sequence
 holds a `TokenWait` from its first token on (`token_start`); at every
@@ -123,8 +183,10 @@ from __future__ import annotations
 import collections
 import logging
 import os
+import queue
 import threading
 import time
+import weakref
 from typing import Any, Dict, List, Optional
 
 log = logging.getLogger("dynamo_tpu.timeline")
@@ -150,7 +212,15 @@ ANNOTATION_PREFIX = "stepline/"
 # down to (token_time): the kind of the oldest unfinished program, or none
 CAUSES = ("decode", "prompt", "drained")
 _DRAINED_CAUSE = CAUSES.index("drained")
-WORST_KEPT = 8  # single waits kept in token_time.worst
+WORST_KEPT = 8  # single waits kept in token_time.worst, gaps in idle_worst
+# the device account (`device`): what an idle interval is cut over (every
+# segment of the thread: a `device_wait` on an implicit program too) ...
+IDLE_KEYS = PHASES + (UNTRACKED,) + LOOP_STATES
+KINDS = CAUSES[:2]  # ... and what a program's busy time goes to
+TAIL_SEGMENTS = 4096  # closed segments kept for a stamp to cut against
+SKEW_WAIT_S = 1e-3  # a device_wait that blocked longer measures the skew
+SKEWS_KEPT = 1024  # newest skews the quantiles are taken over
+WATCH_BACKLOG = 64  # handles behind a watcher that has stopped answering
 
 
 def _env_capacity() -> int:
@@ -218,7 +288,7 @@ class _Phase:
     """Reusable-shape context manager for one instrumented phase; kept
     allocation-light because several open per engine step."""
 
-    __slots__ = ("_tl", "_name", "_upto", "_kind", "_watched")
+    __slots__ = ("_tl", "_name", "_upto", "_kind", "_watched", "_done")
 
     def __init__(self, tl: "StepTimeline", name: str,
                  upto: Optional[int] = None, kind: str = "decode"):
@@ -227,6 +297,15 @@ class _Phase:
         self._upto = upto
         self._kind = kind
         self._watched = False
+        self._done = ()
+
+    def done_when(self, handle: Any, steps: int = 1, rows: int = 0) -> None:
+        """Inside a `dispatch`: `handle` is an output of the program just
+        launched that no later program takes as donated; the program is
+        finished when it is ready (the watcher thread blocks on it, the
+        engine thread never).  `steps` and `rows` go to the program's
+        record: the decode steps it fuses, the batch rows it carries."""
+        self._done = (handle, steps, rows)
 
     def __enter__(self) -> "_Phase":
         # device seams feed the engine watchdog even when the timeline
@@ -240,7 +319,8 @@ class _Phase:
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._tl._exit()
+        done, self._done = self._done, ()
+        self._tl._exit(*done)
         watch, self._watched = self._watched, False
         if watch:
             watch.device_exit(self._name)
@@ -253,10 +333,13 @@ class TokenWait:
     since the first came to.  The engine hands the same object to a
     preempted sequence's continuation: its client keeps waiting."""
 
-    __slots__ = ("mark", "done_seq", "sums", "gap_max_s", "tokens", "t_last")
+    __slots__ = ("mark", "idle_mark", "done_seq", "sums", "gap_max_s",
+                 "tokens", "t_last")
 
-    def __init__(self, mark: List[float], done_seq: int, t: float):
+    def __init__(self, mark: List[float], done_seq: int, t: float,
+                 idle_mark: float = 0.0):
         self.mark = mark
+        self.idle_mark = idle_mark  # the device's lifetime idle, likewise
         self.done_seq = done_seq
         self.sums = [0.0] * len(CAUSES)
         self.gap_max_s = 0.0
@@ -272,9 +355,307 @@ class TokenWait:
         return out
 
 
+def _watch(inbox: "queue.SimpleQueue", stamps: "collections.deque") -> None:
+    """The watcher thread: handles in ticket order, each waited for,
+    stamped and dropped.  Holds neither the timeline nor, beyond the wait,
+    a handle; None ends it."""
+    while True:
+        item = inbox.get()
+        if item is None:
+            return
+        ticket, handle = item
+        item = None
+        try:
+            if len(handle.devices()) > 1:  # a mesh: one local shard will do
+                handle = handle.addressable_shards[0].data
+            handle.block_until_ready()
+        except Exception:  # deleted under us, or the backend died: as of now
+            pass
+        handle = None
+        stamps.append((ticket, time.monotonic()))
+
+
+class _Program:
+    """A dispatched program until its stamp arrives."""
+
+    __slots__ = ("ticket", "kind", "t_enter", "t_enq", "unix_ns", "steps",
+                 "rows", "idle_before_s")
+
+    def __init__(self, ticket: int, kind: int, t_enter: float, t_enq: float,
+                 unix_ns: int, steps: int, rows: int):
+        self.ticket = ticket
+        self.kind = kind  # index into KINDS
+        self.t_enter = t_enter  # its dispatch's enter and exit
+        self.t_enq = t_enq
+        self.unix_ns = unix_ns  # t_enq on the step record's wall anchor
+        self.steps = steps
+        self.rows = rows
+        self.idle_before_s = 0.0
+
+
+class _DeviceAccount:
+    """The stepline's `device` account (module docstring, "The device's own
+    end"): written on the engine thread only, but for `stamps`, which the
+    watcher appends to.  `t` is the instant up to which the device's time
+    is settled into `busy_by` / `idle_by`: the newest stamped program's
+    end, or the start of the oldest unstamped one, or (nothing unstamped)
+    the thread's last segment boundary."""
+
+    def __init__(self, now: float, keep_records: bool):
+        self.keep_records = keep_records
+        self.finished: List[Dict[str, Any]] = []  # records the owner takes
+        self.idle_life = 0.0  # never zeroed: TokenWait marks point into it
+        self.unix0_ns = 0  # wall clock less monotonic, as the newest step had it
+        self._inbox: Optional["queue.SimpleQueue"] = None
+        self._stop: Optional[Any] = None  # weakref.finalize: ends the watcher
+        self.thread: Optional[threading.Thread] = None
+        self.zero(now)
+
+    def zero(self, now: float) -> None:
+        self.stop_watcher()
+        self.finished = []
+        self.stamps: "collections.deque[tuple]" = collections.deque()
+        self.flying: "collections.deque[_Program]" = collections.deque()
+        self.tail: "collections.deque[tuple]" = collections.deque(
+            maxlen=TAIL_SEGMENTS)
+        self.t = now
+        self.done = now  # the newest stamp (busy_enter_s counts from it)
+        self.busy_by = [0.0] * len(KINDS)
+        self.busy_enter_s = 0.0
+        self.idle_by: Dict[str, float] = {k: 0.0 for k in IDLE_KEYS}
+        self.idle_s = 0.0
+        self.programs = 0
+        self.late_stamps = 0
+        self.gap_by: Dict[str, float] = {}  # the open idle interval
+        self.gap_t0 = now
+        self.worst: List[Dict[str, Any]] = []  # longest first
+        self.worst_floor = 0.0
+        self.idle_digest = PhaseDigest()  # idle_before_s of every program
+        self.skews: "collections.deque[float]" = collections.deque(
+            maxlen=SKEWS_KEPT)
+        self.skew_count = 0
+        self.seen = (0, 0.0)  # newest watcher stamp taken: (ticket, t)
+        self.waited = (0, 0.0)  # newest wait that blocked: (ticket, exit)
+        # (busy, busy from enter, idle, t, anything unstamped): swapped
+        # in whole at every settled instant, for totals() on any thread
+        self.pub = (0.0, 0.0, 0.0, now, False)
+
+    # ------------------------------------------------------- the watcher --
+    def post(self, owner: Any, ticket: int, handle: Any) -> None:
+        if self._inbox is not None and self._inbox.qsize() > WATCH_BACKLOG:
+            # it sits on a program that does not end (a hung device): the
+            # handles behind it are its own to drop, the next get another
+            self.stop_watcher()
+        if self._inbox is None:
+            self._inbox = queue.SimpleQueue()
+            self.thread = threading.Thread(
+                target=_watch, args=(self._inbox, self.stamps), daemon=True,
+                name="stepline-device-watch")
+            self._stop = weakref.finalize(owner, self._inbox.put, None)
+            self.thread.start()
+        self._inbox.put((ticket, handle))
+
+    def stop_watcher(self) -> Optional[threading.Thread]:
+        """Tell the watcher to end behind what it holds, and forget it: its
+        stamps go to a deque nobody reads any more."""
+        thread, self.thread, self._inbox = self.thread, None, None
+        if self._stop is not None:
+            self._stop()
+            self._stop = None
+        return thread
+
+    # ----------------------------------------------------- engine thread --
+    def fold(self, t0: float, t1: float, name: Optional[str]) -> None:
+        """The thread's segment [t0, t1) has closed."""
+        if self.flying:
+            self.tail.append((t0, t1, name))  # busy, until a stamp says not
+            return
+        if name is not None:
+            self._idle(name, t1 - t0)
+        else:  # a caller without a loop, between its steps: no account's
+            self.pub = (self.pub[0], self.pub[1], self.idle_s, t1, False)
+        self.t = t1
+
+    def _idle(self, name: str, dur: float) -> None:
+        self.idle_by[name] += dur
+        self.idle_s += dur
+        self.idle_life += dur
+        self.gap_by[name] = self.gap_by.get(name, 0.0) + dur
+
+    def _cut(self, a: float, b: float) -> None:
+        """The device idled through [a, b): charge it to the segments of
+        the tail that overlap it."""
+        tail = self.tail
+        head = tail[0][0] if tail else b
+        if head > a:  # older than the tail: nothing to cut against
+            self._idle(UNTRACKED, min(head, b) - a)
+            self.late_stamps += 1
+        for s0, s1, name in tail:
+            if s0 >= b:
+                break
+            lo, hi = max(s0, a), min(s1, b)
+            if hi > lo and name is not None:
+                self._idle(name, hi - lo)
+
+    def _close_gap(self, prog: _Program, t0: float) -> None:
+        """The idle interval that began at t0 ends with `prog`'s start."""
+        by, self.gap_by = self.gap_by, {}
+        total = sum(by.values(), 0.0)
+        prog.idle_before_s = total
+        self.idle_digest.observe(total)
+        if total - by.get("no_work", 0.0) > self.worst_floor:
+            rec = {"idle_s": total, "by": by, "before_ticket": prog.ticket,
+                   "kind": KINDS[prog.kind],
+                   "t_unix_ns": self.unix0_ns + int(t0 * 1e9)}
+            # a new list, swapped in whole: summary() reads from any thread
+            worst = sorted(self.worst + [rec], key=_idle_rank)
+            self.worst = worst[:WORST_KEPT]
+            if len(worst) >= WORST_KEPT:
+                self.worst_floor = -_idle_rank(self.worst[-1])
+
+    def enq(self, prog: _Program) -> None:
+        """`prog` was handed to the runtime at prog.t_enq, the boundary
+        the thread has just folded up to."""
+        if not self.flying:
+            self._close_gap(prog, self.gap_t0)
+        self.flying.append(prog)
+        self.pub = (sum(self.busy_by), self.busy_enter_s, self.idle_s,
+                    self.t, True)
+
+    def settle(self, ticket: int, t_done: float, now: float) -> None:
+        """Every program up to `ticket` was finished at t_done; the thread
+        has folded up to `now`.  Their records go to `finished`."""
+        flying = self.flying
+        while flying and flying[0].ticket <= ticket:
+            p = flying.popleft()
+            t = max(t_done, self.t)
+            busy = t - self.t
+            self.busy_by[p.kind] += busy
+            self.busy_enter_s += t - max(min(p.t_enter, t), self.done)
+            self.programs += 1
+            if self.keep_records:
+                self.finished.append({
+                    "ticket": p.ticket, "kind": KINDS[p.kind],
+                    "steps": p.steps, "rows": p.rows,
+                    "t_enter": round(p.t_enter, 9),
+                    "t_enq": round(p.t_enq, 9), "t_done": round(t, 9),
+                    "t_enq_unix_ns": p.unix_ns, "busy_s": round(busy, 9),
+                    "idle_before_s": round(p.idle_before_s, 9)})
+            self.t = self.done = t
+            if flying:
+                nxt = flying[0]
+                if nxt.t_enq > t:
+                    self._cut(t, nxt.t_enq)
+                    self.t = nxt.t_enq
+                self._close_gap(nxt, t)
+                tail = self.tail
+                while tail and tail[0][1] <= self.t:
+                    tail.popleft()
+            else:
+                self._cut(t, now)
+                self.gap_t0 = t
+                self.t = now
+                self.tail.clear()
+        self.pub = (sum(self.busy_by), self.busy_enter_s, self.idle_s,
+                    self.t, bool(flying))
+
+    def take_stamps(self, now: float) -> None:
+        stamps = self.stamps
+        while stamps:
+            ticket, t = self.seen = stamps.popleft()
+            if self.waited[0] == ticket:
+                self._skew(t - self.waited[1])
+            self.settle(ticket, t, now)
+
+    def waited_for(self, ticket: int, blocked_s: float,
+                   now: float) -> None:
+        """The thread's own device_wait on `ticket` has returned at `now`:
+        a second stamp of the same event."""
+        if blocked_s > SKEW_WAIT_S:
+            seen, t_seen = self.seen
+            if seen < ticket:  # the watcher's stamp is still to come
+                self.waited = (ticket, now)
+            elif seen == ticket and t_seen >= now - blocked_s:
+                self._skew(t_seen - now)
+            # else the program was finished before this wait began: it
+            # blocked on an implicit program, which measures nothing here
+        self.settle(ticket, now, now)
+
+    def _skew(self, s: float) -> None:
+        self.skews.append(s)
+        self.skew_count += 1
+        self.waited = (0, 0.0)
+
+    # ----------------------------------------------------------- readers --
+    def totals(self, now: float) -> Dict[str, float]:
+        """busy_s, busy_enter_s and idle_s as of `now`, from any thread:
+        the time since the settled instant is busy while a program is
+        unstamped."""
+        busy, enter, idle, t, flying = self.pub
+        rest = max(0.0, now - t)
+        busy_rest = rest if flying else 0.0
+        return {"busy_s": round(busy + busy_rest, 6),
+                "busy_enter_s": round(enter + busy_rest, 6),
+                "idle_s": round(idle + rest - busy_rest, 6)}
+
+    def summary(self, upto: float, row_idle_s: float) -> Dict[str, Any]:
+        busy_by = list(self.busy_by)
+        rest = 0.0
+        try:
+            head = self.flying[0]
+        except IndexError:
+            pass
+        else:
+            rest = max(0.0, upto - self.t)
+            busy_by[head.kind] += rest
+        skews = sorted(self.skews)
+
+        def skew_ms(q: float) -> float:
+            if not skews:
+                return 0.0
+            return round(1e3 * skews[min(len(skews) - 1,
+                                         int(q * len(skews)))], 4)
+
+        return _device_of(
+            busy_by, dict(self.idle_by),
+            {"busy_enter_s": self.busy_enter_s + rest,
+             "programs": self.programs, "unstamped": len(self.flying),
+             "late_stamps": self.late_stamps, "row_idle_s": row_idle_s},
+            [dict(rec, idle_s=round(rec["idle_s"], 6),
+                  by={k: round(v, 6) for k, v in rec["by"].items()})
+             for rec in self.worst],
+            {"p50": skew_ms(0.5), "p95": skew_ms(0.95),
+             "count": self.skew_count})
+
+
+def _idle_rank(rec: Dict[str, Any]) -> float:
+    """idle_worst's order: longest first by what is not `no_work`."""
+    return rec["by"].get("no_work", 0.0) - rec["idle_s"]
+
+
+def _device_of(busy_by, idle_by: Dict[str, float], sums: Dict[str, Any],
+               worst: List[Dict[str, Any]],
+               skew: Dict[str, Any]) -> Dict[str, Any]:
+    """`device` as summary() and merge_summaries give it; `sums` holds what
+    merges by addition beside the two splits."""
+    return {
+        "busy_s": round(sum(busy_by), 6),
+        "idle_s": round(sum(idle_by.values()), 6),
+        "programs": sums["programs"],
+        "unstamped": sums["unstamped"],
+        "busy_by": {k: round(v, 6) for k, v in zip(KINDS, busy_by)},
+        "busy_enter_s": round(sums["busy_enter_s"], 6),
+        "idle_by": {k: round(v, 6) for k, v in idle_by.items()},
+        "row_idle_s": round(sums["row_idle_s"], 6),
+        "idle_worst": worst,
+        "late_stamps": sums["late_stamps"],
+        "stamp_skew_ms": skew,
+    }
+
+
 class StepTimeline:
     """Bounded ring of exact per-step phase intervals + streaming
-    per-phase digests + inter-dispatch host-gap accounting."""
+    per-phase digests + the drained, token-time and device accounts."""
 
     def __init__(self, capacity: Optional[int] = None,
                  enabled: Optional[bool] = None):
@@ -294,14 +675,12 @@ class StepTimeline:
         # reads are monotonic-safe the same way PhaseTimer's are)
         self.digests: Dict[str, PhaseDigest] = {p: PhaseDigest()
                                                 for p in PHASES}
-        self.gap_digest = PhaseDigest()  # inter-dispatch host-gap samples
         self.phase_totals: Dict[str, float] = {p: 0.0 for p in PHASES}
-        self.host_gap_total_s = 0.0
         self.wall_total_s = 0.0
         # open per-step draft + phase stack; engine scheduler thread only
         self._draft: Optional[Dict[str, Any]] = None
-        self._stack: List[List[Any]] = []  # [name, segment_open_monotonic]
-        self._last_return: Optional[float] = None  # device ctrl-return mark
+        # [name, segment_open_monotonic, upto, kind, entered_monotonic]
+        self._stack: List[List[Any]] = []
         # the thread's time outside step(), by loop state; None until a
         # loop driver calls loop_state() (a library caller of step() has
         # no loop, and its own time between steps is not the engine's)
@@ -332,6 +711,12 @@ class StepTimeline:
         self._worst: List[Dict[str, Any]] = []  # longest first
         self._worst_last: Optional[Dict[str, Any]] = None  # newest record
         self._worst_floor = 0.0  # a wait must pass it to be kept
+        # the device account, and a record a finished program beside the
+        # steps' ring
+        self._dev = _DeviceAccount(time.monotonic(), self.capacity > 0)
+        self.row_idle_s = 0.0
+        self._programs: "collections.deque[Dict[str, Any]]" = collections.deque(  # guarded_by: _lock
+            maxlen=max(1, self.capacity))
         # profiler annotations, only while a capture is open
         self._tracing = False  # the one test a segment pays outside one
         self._annotate: Optional[Any] = None
@@ -350,16 +735,14 @@ class StepTimeline:
         discarded; `seq` keeps counting so record ids stay unique."""
         with self._lock:
             self._ring.clear()
+            self._programs.clear()
         self.steps_total = 0
         self.dropped_total = 0
         self.digests = {p: PhaseDigest() for p in PHASES}
-        self.gap_digest = PhaseDigest()
         self.phase_totals = {p: 0.0 for p in PHASES}
-        self.host_gap_total_s = 0.0
         self.wall_total_s = 0.0
         self._draft = None
         self._stack = []
-        self._last_return = None
         self.loop_totals = {s: 0.0 for s in LOOP_STATES}
         self._loop_t = self._cur_t = time.monotonic()
         self.drained_by = {k: 0.0 for k in DRAINED_KEYS}
@@ -372,6 +755,18 @@ class StepTimeline:
         self._worst = []
         self._worst_last = None
         self._worst_floor = 0.0
+        # the device account starts over, its watcher with it: stamps in
+        # flight are forgotten, programs in flight read as idle time
+        self._dev.zero(self._cur_t)
+        self.row_idle_s = 0.0
+
+    def close(self) -> None:
+        """End the watcher thread (the loop driver's close): behind the
+        handles it holds, or never if the device hangs under one, which a
+        daemon thread may."""
+        thread = self._dev.stop_watcher()
+        if thread is not None:
+            thread.join(timeout=2.0)
 
     def loop_state(self, name: str) -> None:
         """The loop driver's declaration, on the engine thread, of what it
@@ -421,14 +816,28 @@ class StepTimeline:
             if self._drained and cur != "device_wait":
                 self.drained_by[cur] += dur
                 self.drained_total_s += dur
+        if dur > 0:
+            self._dev.fold(self._cur_t, now, cur)
         self._cur_t = now
 
     def _mark(self, now: float, name: Optional[str]) -> None:
         """Segment boundary: [_cur_t, now) was `_cur_name`, `name` opens."""
         self._fold(now)
+        dev = self._dev
+        if dev.stamps:
+            dev.take_stamps(now)
+            self._keep_programs()
         self._cur_name = name
         if self._tracing:
             self._reannotate(name)
+
+    def _keep_programs(self) -> None:
+        """The records of the programs the account has just settled."""
+        dev = self._dev
+        if dev.finished:
+            recs, dev.finished = dev.finished, []
+            with self._lock:
+                self._programs.extend(recs)
 
     def _reannotate(self, name: Optional[str]) -> None:
         ann, self._ann = self._ann, None
@@ -439,7 +848,18 @@ class StepTimeline:
             self._close_step_annotation()
             self._tracing = False
         elif name is not None:
-            self._ann = make(ANNOTATION_PREFIX + name)
+            if name in DEVICE_PHASES and self._stack:
+                # the ticket this dispatch's program gets, or the one this
+                # wait is on: what joins the device's operations to it
+                _, _, upto, kind, _ = self._stack[-1]
+                if name == "dispatch":
+                    upto = self.dispatch_seq + 1
+                elif upto is None:
+                    upto = self.dispatch_seq
+                self._ann = make(ANNOTATION_PREFIX + name, ticket=upto,
+                                 kind=CAUSES[kind])
+            else:
+                self._ann = make(ANNOTATION_PREFIX + name)
             self._ann.__enter__()
 
     def _close_step_annotation(self) -> None:
@@ -456,8 +876,8 @@ class StepTimeline:
         if self._draft is not None:
             self._finalize(aborted=True)
         now = time.monotonic()
-        self._draft = {"t0": now, "t0_unix_ns": time.time_ns(),
-                       "segs": [], "gaps": []}
+        self._draft = {"t0": now, "t0_unix_ns": time.time_ns(), "segs": []}
+        self._dev.unix0_ns = self._draft["t0_unix_ns"] - int(now * 1e9)
         self._stack = []
         if self._tracing:
             self._mark(now, None)  # the step's annotation holds its segments
@@ -496,17 +916,13 @@ class StepTimeline:
             if now > outer[1]:
                 d["segs"].append((outer[0], outer[1] - d["t0"],
                                   now - d["t0"]))
-        if name == "dispatch" and self._last_return is not None:
-            # inter-dispatch host gap: device program N returned control
-            # at _last_return; program N+1 launches now. Clamped — async
-            # scheduling dispatches N+1 before materializing N.
-            d["gaps"].append(max(0.0, now - self._last_return))
-        stack.append([name, now, upto, CAUSES.index(kind)])
+        stack.append([name, now, upto, CAUSES.index(kind), now])
         self._mark(now, name)
         if name == "device_wait":
             self._wait_cause = stack[-1][3]
 
-    def _exit(self) -> None:
+    def _exit(self, handle: Any = None, steps: int = 1,
+              rows: int = 0) -> None:
         d = self._draft
         stack = self._stack
         if d is None or not stack:
@@ -519,7 +935,7 @@ class StepTimeline:
             stack[-1][1] = now  # resume the paused outer phase
         self._mark(now, stack[-1][0] if stack else UNTRACKED)
         if top[0] in DEVICE_PHASES:
-            self._last_return = now
+            dev = self._dev
             if top[0] == "dispatch":
                 # the device has work again: a drained interval ends here
                 self.dispatch_seq += 1
@@ -527,6 +943,12 @@ class StepTimeline:
                 if self._drained:
                     self._drained = False
                     self.drained_count += 1
+                dev.enq(_Program(
+                    self.dispatch_seq, top[3], top[4], now,
+                    d["t0_unix_ns"] + int((now - d["t0"]) * 1e9),
+                    steps, rows))
+                if handle is not None:
+                    dev.post(self, self.dispatch_seq, handle)
             else:
                 done = self.dispatch_seq if top[2] is None else top[2]
                 if done > self._done_seq:
@@ -535,6 +957,8 @@ class StepTimeline:
                 flying = self._flying
                 while flying and flying[0][0] <= self._done_seq:
                     flying.popleft()
+                dev.waited_for(done, now - top[1], now)
+                self._keep_programs()
 
     # ------------------------------------------------ token time by cause --
     def fold(self) -> float:
@@ -550,7 +974,8 @@ class StepTimeline:
         account as it stands (call `fold` first).  None when disabled."""
         if not self.enabled:
             return None
-        return TokenWait(list(self._cause), self._done_seq, self._cur_t)
+        return TokenWait(list(self._cause), self._done_seq, self._cur_t,
+                         self._dev.idle_life)
 
     def token_gap(self, wait: TokenWait, tokens: int,
                   request_id: str) -> None:
@@ -565,6 +990,9 @@ class StepTimeline:
             mark[i] = cause[i]
             sums[i] += part
             row[i] += part
+        idle = self._dev.idle_life
+        self.row_idle_s += idle - wait.idle_mark
+        wait.idle_mark = idle
         programs = self._done_seq - wait.done_seq
         wait.done_seq = self._done_seq
         wait.tokens += tokens
@@ -633,9 +1061,6 @@ class StepTimeline:
             if dg is not None:
                 dg.observe(tot)
                 self.phase_totals[name] += tot
-        for g in d["gaps"]:
-            self.gap_digest.observe(g)
-            self.host_gap_total_s += g
         self.wall_total_s += wall
         self.steps_total += 1
         rec: Dict[str, Any] = {
@@ -645,7 +1070,6 @@ class StepTimeline:
             "segs": [(n, round(s0, 9), round(s1, 9))
                      for n, s0, s1 in d["segs"]],
             "gap_s": gap,
-            "host_gap": [round(g, 9) for g in d["gaps"]],
         }
         if aborted:
             rec["aborted"] = True
@@ -669,6 +1093,30 @@ class StepTimeline:
             out = out[-n:]
         return out
 
+    def programs(self, n: Optional[int] = None) -> List[Dict[str, Any]]:
+        """The newest finished programs' records, oldest first: {ticket,
+        kind, steps, rows, t_enter, t_enq, t_done (monotonic seconds),
+        t_enq_unix_ns, busy_s, idle_before_s}."""
+        with self._lock:
+            out = list(self._programs)
+        if n is not None and n > 0:
+            out = out[-n:]
+        return out
+
+    @property
+    def idle_digest(self) -> PhaseDigest:
+        """idle_before_s of every program since the reset
+        (dynamo_engine_device_idle_seconds)."""
+        return self._dev.idle_digest
+
+    def device_idle_by(self) -> Dict[str, float]:
+        return dict(self._dev.idle_by)
+
+    def device_totals(self) -> Dict[str, float]:
+        """{busy_s, busy_enter_s, idle_s} as of now, from any thread (a
+        capture's samples: serving/api.py _sleep_sampling)."""
+        return self._dev.totals(time.monotonic())
+
     # ----------------------------------------------------------- summary ---
     def _token_time(self) -> Dict[str, Any]:
         worst = [{k: round(v, 6) if isinstance(v, float) else v
@@ -680,8 +1128,8 @@ class StepTimeline:
 
     def summary(self) -> Dict[str, Any]:
         """Bubble-attribution rollup: per-phase p50/p95 + share of step
-        wall time, the inter-dispatch host-gap distribution, and which
-        host phase eats the gap.  Rides /worker/stats and the heartbeat
+        wall time, what the host did while the device was drained, token
+        time by cause, and the device's own busy and idle time.  Rides /worker/stats and the heartbeat
         (fleet rollup via merge_summaries)."""
         wall = self.wall_total_s
         phases: Dict[str, Any] = {}
@@ -698,20 +1146,11 @@ class StepTimeline:
                 if wall else 0.0,
             }
         tracked = sum(self.phase_totals.values())
-        gd = self.gap_digest
         out: Dict[str, Any] = {
             "enabled": self.enabled,
             "steps": self.steps_total,
             "wall_s": round(wall, 6),
             "phases": phases,
-            "host_gap": {
-                "count": gd.count,
-                "total_s": round(self.host_gap_total_s, 6),
-                "p50_ms": round(gd.quantile_ms(0.5), 3),
-                "p95_ms": round(gd.quantile_ms(0.95), 3),
-                "share": round(self.host_gap_total_s / wall, 4)
-                if wall else 0.0,
-            },
             "untracked_s": round(max(0.0, wall - tracked), 6),
             # the thread's whole time: step wall + the loop's two states
             "loop_wall_s": round(wall + sum(self.loop_totals.values()), 6),
@@ -722,6 +1161,7 @@ class StepTimeline:
                 "by": {k: round(t, 6) for k, t in self.drained_by.items()},
             },
             "token_time": self._token_time(),
+            "device": self._dev.summary(self._cur_t, self.row_idle_s),
         }
         bubble = _bubble_attribution(out["drained"]["by"],
                                      out["loop_wall_s"])
@@ -765,14 +1205,31 @@ def merge_summaries(summaries: List[Dict[str, Any]]) -> Dict[str, Any]:
     agg: Dict[str, Any] = {
         "steps": 0, "wall_s": 0.0, "untracked_s": 0.0, "loop_wall_s": 0.0,
         "phases": {},
-        "host_gap": {"count": 0, "total_s": 0.0, "p95_ms_max": 0.0},
         "drained": {"total_s": 0.0, "count": 0, "by": {}},
     }
     cause_s, row_s = [0.0] * len(CAUSES), [0.0] * len(CAUSES)
     gaps, worst = 0, []
+    busy_by = [0.0] * len(KINDS)
+    idle_by = {k: 0.0 for k in IDLE_KEYS}
+    dev = {"busy_enter_s": 0.0, "programs": 0, "unstamped": 0,
+           "late_stamps": 0, "row_idle_s": 0.0}
+    idle_worst: List[Dict[str, Any]] = []
+    skew = {"p50": 0.0, "p95": 0.0, "count": 0}
     for s in summaries:
         if not s:
             continue
+        dv = s.get("device") or {}  # absent: a worker from before it
+        for i, k in enumerate(KINDS):
+            busy_by[i] += (dv.get("busy_by") or {}).get(k, 0.0)
+        for k, t in (dv.get("idle_by") or {}).items():
+            idle_by[k] = idle_by.get(k, 0.0) + t
+        for k in dev:
+            dev[k] += dv.get(k, 0)
+        idle_worst += dv.get("idle_worst") or []
+        sk = dv.get("stamp_skew_ms") or {}
+        skew["count"] += sk.get("count", 0)
+        for q in ("p50", "p95"):  # the worst worker's, by size
+            skew[q] = max(skew[q], sk.get(q, 0.0), key=abs)
         tt = s.get("token_time") or {}  # absent: a worker from before it
         for i, c in enumerate(CAUSES):
             cause_s[i] += (tt.get("cause_s") or {}).get(c, 0.0)
@@ -790,11 +1247,6 @@ def merge_summaries(summaries: List[Dict[str, Any]]) -> Dict[str, Any]:
         for name, t in (dr.get("by") or {}).items():
             agg["drained"]["by"][name] = (
                 agg["drained"]["by"].get(name, 0.0) + t)
-        hg = s.get("host_gap") or {}
-        agg["host_gap"]["count"] += hg.get("count", 0)
-        agg["host_gap"]["total_s"] += hg.get("total_s", 0.0)
-        agg["host_gap"]["p95_ms_max"] = max(
-            agg["host_gap"]["p95_ms_max"], hg.get("p95_ms", 0.0))
         for name, ph in (s.get("phases") or {}).items():
             t = agg["phases"].setdefault(
                 name, {"count": 0, "total_s": 0.0, "p95_ms_max": 0.0})
@@ -805,20 +1257,20 @@ def merge_summaries(summaries: List[Dict[str, Any]]) -> Dict[str, Any]:
     if wall > 0:
         for ph in agg["phases"].values():
             ph["share"] = round(ph["total_s"] / wall, 4)
-        agg["host_gap"]["share"] = round(
-            agg["host_gap"]["total_s"] / wall, 4)
     agg["wall_s"] = round(agg["wall_s"], 6)
     agg["untracked_s"] = round(agg["untracked_s"], 6)
     agg["loop_wall_s"] = round(agg["loop_wall_s"], 6)
     agg["drained"]["total_s"] = round(agg["drained"]["total_s"], 6)
     agg["drained"]["by"] = {n: round(t, 6)
                             for n, t in agg["drained"]["by"].items()}
-    agg["host_gap"]["total_s"] = round(agg["host_gap"]["total_s"], 6)
     for ph in agg["phases"].values():
         ph["total_s"] = round(ph["total_s"], 6)
     agg["token_time"] = _token_time_of(
         cause_s, row_s, gaps,
         sorted(worst, key=lambda r: -r["gap_s"])[:WORST_KEPT])
+    agg["device"] = _device_of(
+        busy_by, idle_by, dev,
+        sorted(idle_worst, key=_idle_rank)[:WORST_KEPT], skew)
     bubble = _bubble_attribution(agg["drained"]["by"], agg["loop_wall_s"])
     if bubble is not None:
         agg["bubble"] = bubble
@@ -829,6 +1281,8 @@ def merge_summaries(summaries: List[Dict[str, Any]]) -> Dict[str, Any]:
 
 _ENGINE_PID = 1
 _SPAN_PID = 2
+_STEP_TID = 1
+_DEVICE_TID = 2
 
 
 def _arg_value(v: Any) -> Any:
@@ -841,33 +1295,64 @@ def perfetto_trace(timeline: "StepTimeline", collector=None,
                    trace_id: Optional[str] = None) -> Dict[str, Any]:
     """Chrome Trace Event JSON (the array format Perfetto/chrome://tracing
     ingest): engine step phases + step-boundary markers on one track,
+    the device's programs on a `device` track under the same process,
     request spans on per-service tracks, all on the unix-epoch clock in
     microseconds — step records anchor ``time.time_ns`` at begin, and
     tracing spans are ``time_ns`` natively, so a request's spans line up
-    with the engine steps that served it."""
+    with the engine steps that served it.  A program's bar runs from its
+    start (``t_done - busy_s``) to its stamp, and a flow with the ticket
+    as id joins the ``dispatch`` segment that launched it to the bar:
+    request -> host phase -> device program in one file, with no profiler
+    capture."""
     events: List[Dict[str, Any]] = [
         {"name": "process_name", "ph": "M", "pid": _ENGINE_PID,
          "args": {"name": "engine"}},
-        {"name": "thread_name", "ph": "M", "pid": _ENGINE_PID, "tid": 1,
-         "args": {"name": "engine.step"}},
+        {"name": "thread_name", "ph": "M", "pid": _ENGINE_PID,
+         "tid": _STEP_TID, "args": {"name": "engine.step"}},
+        {"name": "thread_name", "ph": "M", "pid": _ENGINE_PID,
+         "tid": _DEVICE_TID, "args": {"name": "device"}},
     ]
+    programs = timeline.programs(steps)
+    # one anchor for every bar (the newest program's), so that rounding
+    # and the wall clock's drift between steps never make two overlap
+    anchor_us = (programs[-1]["t_enq_unix_ns"] / 1e3
+                 - programs[-1]["t_enq"] * 1e6) if programs else 0.0
+    for p in programs:
+        start_us = round(anchor_us + (p["t_done"] - p["busy_s"]) * 1e6, 3)
+        end_us = round(anchor_us + p["t_done"] * 1e6, 3)
+        events.append({
+            "name": p["kind"], "ph": "X", "cat": "device",
+            "ts": start_us, "dur": round(end_us - start_us, 3),
+            "pid": _ENGINE_PID, "tid": _DEVICE_TID,
+            "args": {"ticket": p["ticket"], "steps": p["steps"],
+                     "rows": p["rows"],
+                     "idle_before_ms": round(p["idle_before_s"] * 1e3, 3)},
+        })
+        # the flow leaves the middle of the dispatch segment, on the
+        # anchor of the step that drew it
+        launch_us = round(p["t_enq_unix_ns"] / 1e3
+                          - (p["t_enq"] - p["t_enter"]) * 5e5, 3)
+        flow = {"name": "launch", "cat": "device", "id": p["ticket"],
+                "pid": _ENGINE_PID}
+        events.append({**flow, "ph": "s", "ts": launch_us,
+                       "tid": _STEP_TID})
+        events.append({**flow, "ph": "f", "bp": "e", "ts": start_us,
+                       "tid": _DEVICE_TID})
     for rec in timeline.records(steps):
         base_us = rec["t0_unix_ns"] / 1e3
         events.append({
             "name": "step", "ph": "i", "s": "t", "cat": "engine",
-            "ts": round(base_us, 3), "pid": _ENGINE_PID, "tid": 1,
+            "ts": round(base_us, 3), "pid": _ENGINE_PID, "tid": _STEP_TID,
             "args": {"seq": rec.get("seq"),
                      "wall_ms": round(rec["wall_s"] * 1e3, 3),
-                     "gap_ms": round(rec["gap_s"] * 1e3, 3),
-                     "host_gap_ms": [round(g * 1e3, 3)
-                                     for g in rec.get("host_gap", [])]},
+                     "gap_ms": round(rec["gap_s"] * 1e3, 3)},
         })
         for name, s0, s1 in rec["segs"]:
             events.append({
                 "name": name, "ph": "X", "cat": "engine",
                 "ts": round(base_us + s0 * 1e6, 3),
                 "dur": round((s1 - s0) * 1e6, 3),
-                "pid": _ENGINE_PID, "tid": 1,
+                "pid": _ENGINE_PID, "tid": _STEP_TID,
                 "args": {"step": rec.get("seq")},
             })
     if collector is not None:
@@ -925,5 +1410,6 @@ def timeline_debug_payload(timeline: "StepTimeline",
         "steps_total": timeline.steps_total,
         "dropped_total": timeline.dropped_total,
         "records": timeline.records(n),
+        "programs": timeline.programs(n),
         "summary": timeline.summary(),
     }

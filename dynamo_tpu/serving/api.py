@@ -886,8 +886,11 @@ class ServingContext:
     def _sleep_sampling(self, duration_s: float) -> dict:
         """Sleep through a capture of `duration_s`, reading every
         CAPTURE_SAMPLE_S, and at its end, what the kernels were asked for
-        (EngineMetrics.kernel_counters): {"period_s", "samples": [{"t_s":
-        seconds since the capture's start mark, "metrics": {...}}]}. A
+        (EngineMetrics.kernel_counters) and what the stepline's device
+        account read (timeline.device_totals: busy and idle seconds as of
+        the sample): {"period_s", "samples": [{"t_s": seconds since the
+        capture's start mark, "metrics": {...}, "device": {"busy_s",
+        "busy_enter_s", "idle_s"}}]}. A
         reader that holds a kernel's device time in a slice of the capture
         against what it was asked takes both over the same seconds so; the
         1 Hz polls of /worker/stats bracket a slice a second or so wider,
@@ -903,7 +906,8 @@ class ServingContext:
             time.sleep(max(0.0, t0 + t - time.monotonic()))
             samples.append({
                 "t_s": round(time.monotonic() - t0, 4),
-                "metrics": self.engine.metrics.kernel_counters()})
+                "metrics": self.engine.metrics.kernel_counters(),
+                "device": self.engine.timeline.device_totals()})
         return {"period_s": period, "samples": samples}
 
     def begin_drain(self) -> None:

@@ -44,6 +44,10 @@ class EngineService:
         self._stop = True
         self._wake.set()
         self._thread.join(timeout=10)
+        # the stepline's watcher thread lives as long as the loop it times
+        timeline = getattr(self.engine, "timeline", None)
+        if timeline is not None:
+            timeline.close()
 
     # --------------------------------------------------------------- intake
     def submit(self, req: GenRequest) -> "queue.Queue[TokenEvent]":

@@ -7,8 +7,9 @@ timeline sections) and `/debug/flight?n=` — so it needs no cluster
 credentials beyond reach of the frontend. One screen answers: who is
 serving what, how full is every KV tier, which tenant is spending the
 chips, where each engine's step time goes (per-phase p50/p95 and the
-inter-dispatch host-gap share — the bubble the zero-bubble work must
-close), and what each engine did in its last few steps.
+device's idle share by its own end of every program — the bubble the
+zero-bubble work must close), and what each engine did in its last few
+steps.
 
 Usage:
     python scripts/dynamo_top.py --frontend http://localhost:8000
@@ -129,13 +130,21 @@ def render(frame: Dict[str, Any], flight_n: int) -> List[str]:
             out(f"   costs  {tens}")
         tl = st.get("timeline")
         if tl and tl.get("steps"):
-            hg = tl.get("host_gap") or {}
+            dev = tl.get("device") or {}
             bub = tl.get("bubble") or {}
             eater = bub.get("gap_eater")
+            # the device's idle share of the thread's time, by its own
+            # end of every program; what the host did through most of it;
+            # the longest gap a host change could shrink
+            idle_by = dev.get("idle_by") or {}
+            top = max(idle_by, key=idle_by.get) if idle_by else "-"
+            gap = (dev.get("idle_worst") or [{}])[0]
             out(f"   stepln steps={tl.get('steps')}"
-                f"  host_gap p50={hg.get('p50_ms', 0):.2f}ms"
-                f" p95={hg.get('p95_ms', 0):.2f}ms"
-                f" share={hg.get('share', 0) * 100:.1f}%"
+                f"  device idle="
+                f"{100 * dev.get('idle_s', 0) / (tl.get('loop_wall_s') or 1):.1f}%"
+                f" (most in {top})"
+                f"  longest gap={gap.get('idle_s', 0) * 1e3:.1f}ms"
+                f" before #{gap.get('before_ticket', '-')}"
                 f"{('  eater=' + eater) if eater else ''}")
             phases = tl.get("phases") or {}
             if phases:
@@ -155,6 +164,7 @@ def render(frame: Dict[str, Any], flight_n: int) -> List[str]:
                     for c in ("decode", "prompt", "drained"))
                 worst = (tt.get("worst") or [{}])[0]
                 out(f"          token    {per}"
+                    f"  idle={1e3 * dev.get('row_idle_s', 0) / tt['gaps']:.2f}ms"
                     f"  longest gap={tt.get('gap_max_s', 0) * 1e3:.0f}ms"
                     f" ({worst.get('request_id', '-')})")
         fl = w.get("flight")

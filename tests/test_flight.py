@@ -414,7 +414,7 @@ def test_debug_timeline_route_live(server):
     assert "/debug/timeline" in idx
     summ = _get_json(url, "/debug/timeline?format=summary")
     assert summ["enabled"] and summ["steps"] > 0
-    assert "bubble" in summ and "host_gap" in summ
+    assert "bubble" in summ and "device" in summ
     trace = _get_json(url, "/debug/timeline?format=perfetto")
     evs = trace["traceEvents"]
     assert any(e["ph"] == "X" and e["pid"] == 1 for e in evs)

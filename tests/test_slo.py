@@ -321,6 +321,26 @@ def test_worker_exposes_engine_phase_and_utilization(stack):
     assert "dynamo_spans_dropped_total" in text
 
 
+def test_worker_exposes_the_devices_idle_time(stack):
+    """The step timeline's device account on /metrics: idle time before
+    each program as a histogram, the same time by the engine thread's
+    segment as a counter; the host-gap histogram they replace is gone."""
+    _chat(stack["worker"]).read()
+    text = _get(stack["worker"], "/metrics")
+    assert_valid_scrape(text)
+    m = re.search(r"dynamo_engine_device_idle_seconds_count (\d+)", text)
+    assert m and int(m.group(1)) > 0
+    assert "dynamo_engine_device_idle_seconds_bucket{" in text
+    by = dict(re.findall(
+        r'dynamo_engine_device_idle_seconds_total\{segment="(\w+)"\} (\S+)',
+        text))
+    assert set(by) == {"admit", "page_alloc", "dispatch", "device_wait",
+                       "detok", "bank", "untracked", "between_steps",
+                       "no_work"}
+    assert float(by["dispatch"]) > 0.0  # the first program's own launch
+    assert "host_gap" not in text
+
+
 def test_live_mfu_mbu_nonzero_with_forced_chip(stack, monkeypatch):
     """With a chip identity forced (CPU box), the scrape-window utilization
     math must produce a nonzero MFU/MBU after decode activity."""

@@ -7,8 +7,12 @@ Covers observability/timeline.py and its engine + HTTP wiring:
 - conservation: on a REAL tiny-engine run, every record's phase
   self-times are disjoint, live inside [0, wall], and sum + gap equals
   the step wall time — the invariant the zero-bubble acceptance reads;
-- host-gap sampling: every inter-dispatch gap sample is >= 0 (clamped:
-  async scheduling dispatches N+1 before materializing N);
+- the device account: busy and idle time from the programs' own
+  completion stamps (posted by hand here, on a clock the test moves),
+  conserved against `loop_wall_s`, idle cut by the thread's segments,
+  `row_idle_s`, `idle_worst`, the stamp's skew, reset() and
+  merge_summaries; on a real engine the watcher stamps every ticket and
+  ends with the loop, and the streams do not change with it;
 - Perfetto export: deterministic golden over stub records + a fixed
   tracing span — schema-valid Chrome Trace Event JSON whose engine
   steps and request spans share the unix-epoch microsecond clock;
@@ -36,6 +40,7 @@ from dynamo_tpu.observability import timeline as timeline_mod
 from dynamo_tpu.observability.timeline import (
     CAUSES,
     DRAINED_KEYS,
+    IDLE_KEYS,
     PHASES,
     PhaseDigest,
     StepTimeline,
@@ -67,8 +72,6 @@ def _assert_record_conserves(rec, tol=1e-6):
     total = sum(rec["phases"].values())
     assert rec["gap_s"] >= 0.0
     assert abs(total + rec["gap_s"] - wall) < tol
-    for g in rec["host_gap"]:
-        assert g >= 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -116,25 +119,6 @@ def test_idle_steps_are_elided_and_unwind_is_flagged():
     (rec,) = tl.records()
     assert rec.get("aborted") is True
     _assert_record_conserves(rec)
-
-
-def test_host_gap_sampled_between_dispatches():
-    tl = StepTimeline(capacity=8, enabled=True)
-    for _ in range(3):
-        tl.begin_step()
-        with tl.phase("dispatch"):
-            pass
-        with tl.phase("device_wait"):
-            pass
-        tl.commit_step()
-    recs = tl.records()
-    # first dispatch has no prior device return: no sample; later ones do
-    assert recs[0]["host_gap"] == []
-    assert len(recs[1]["host_gap"]) == 1
-    assert len(recs[2]["host_gap"]) == 1
-    assert all(g >= 0.0 for r in recs for g in r["host_gap"])
-    assert tl.gap_digest.count == 2
-    assert tl.summary()["host_gap"]["count"] == 2
 
 
 def test_ring_bounded_and_capacity_zero_keeps_digests():
@@ -281,11 +265,15 @@ def test_mixed_steps_behind_the_pipeline_open_no_drained_interval():
 # Perfetto export
 # ---------------------------------------------------------------------------
 class _StubTimeline:
-    def __init__(self, recs):
+    def __init__(self, recs, programs=()):
         self._recs = recs
+        self._programs = list(programs)
 
     def records(self, n=None):
         return self._recs[-n:] if n else list(self._recs)
+
+    def programs(self, n=None):
+        return self._programs[-n:] if n else list(self._programs)
 
 
 _BASE_NS = 1_754_000_000_000_000_000  # fixed epoch anchor
@@ -302,7 +290,6 @@ def _stub_records():
             "segs": [("admit", 0.0, 0.002), ("dispatch", 0.002, 0.007),
                      ("device_wait", 0.007, 0.009)],
             "gap_s": 0.001,
-            "host_gap": [],
         },
         {
             "seq": 1,
@@ -311,8 +298,23 @@ def _stub_records():
             "phases": {"dispatch": 0.006, "detok": 0.001},
             "segs": [("dispatch", 0.0, 0.006), ("detok", 0.006, 0.007)],
             "gap_s": 0.001,
-            "host_gap": [0.0005],
         },
+    ]
+
+
+def _stub_programs():
+    """The two dispatch segments' programs, on a monotonic clock that read
+    50.0 when step 0 began: the first ran 6 ms from its dispatch's exit,
+    the second was launched 2 ms before that and so started at its end."""
+    return [
+        {"ticket": 1, "kind": "prompt", "steps": 1, "rows": 1,
+         "t_enter": 50.002, "t_enq": 50.007, "t_done": 50.013,
+         "t_enq_unix_ns": _BASE_NS + 7_000_000, "busy_s": 0.006,
+         "idle_before_s": 0.0},
+        {"ticket": 2, "kind": "decode", "steps": 16, "rows": 3,
+         "t_enter": 50.010, "t_enq": 50.016, "t_done": 50.030,
+         "t_enq_unix_ns": _BASE_NS + 16_000_000, "busy_s": 0.014,
+         "idle_before_s": 0.003},
     ]
 
 
@@ -332,7 +334,7 @@ def _stub_collector():
 
 
 def test_perfetto_trace_schema_and_shared_clock_domain():
-    trace = perfetto_trace(_StubTimeline(_stub_records()),
+    trace = perfetto_trace(_StubTimeline(_stub_records(), _stub_programs()),
                            collector=_stub_collector(), steps=128)
     # deterministic, JSON-round-trippable
     blob = json.dumps(trace, sort_keys=True)
@@ -340,7 +342,7 @@ def test_perfetto_trace_schema_and_shared_clock_domain():
     assert trace["displayTimeUnit"] == "ms"
     events = trace["traceEvents"]
     for ev in events:
-        assert ev["ph"] in ("M", "i", "X")
+        assert ev["ph"] in ("M", "i", "X", "s", "f")
         assert isinstance(ev["name"], str)
         if ev["ph"] != "M":
             assert isinstance(ev["ts"], float)
@@ -349,9 +351,32 @@ def test_perfetto_trace_schema_and_shared_clock_domain():
         if ev["ph"] == "i":
             assert ev["s"] == "t"
     # every phase segment exports as a complete event on the engine track
-    engine_x = [e for e in events if e["ph"] == "X" and e["pid"] == 1]
+    engine_x = [e for e in events if e["ph"] == "X" and e["pid"] == 1
+                and e["tid"] == 1]
     assert [e["name"] for e in engine_x] == [
         "admit", "dispatch", "device_wait", "dispatch", "detok"]
+    # the device's programs: a `device` track under the engine's process,
+    # bars that do not overlap, each joined by a flow (id = ticket) that
+    # leaves inside the dispatch segment that launched it
+    assert [e["args"]["name"] for e in events if e["ph"] == "M"
+            and e["name"] == "thread_name" and e["pid"] == 1] == [
+        "engine.step", "device"]
+    bars = [e for e in events if e["ph"] == "X" and e.get("tid") == 2
+            and e["pid"] == 1]
+    assert [(b["name"], b["args"]["ticket"]) for b in bars] == [
+        ("prompt", 1), ("decode", 2)]
+    assert bars[0]["ts"] + bars[0]["dur"] <= bars[1]["ts"]
+    assert bars[0]["dur"] == pytest.approx(6000.0, abs=0.01)
+    assert bars[1]["args"] == {"ticket": 2, "steps": 16, "rows": 3,
+                               "idle_before_ms": 3.0}
+    dispatches = [e for e in engine_x if e["name"] == "dispatch"]
+    for bar, seg in zip(bars, dispatches):
+        start, end = [e for e in events if e["ph"] in "sf"
+                      and e["id"] == bar["args"]["ticket"]]
+        assert (start["ph"], start["tid"]) == ("s", 1)
+        assert seg["ts"] < start["ts"] < seg["ts"] + seg["dur"]
+        assert (end["ph"], end["bp"], end["tid"]) == ("f", "e", 2)
+        assert end["ts"] == bar["ts"]
     # step-boundary instants, one per record
     assert len([e for e in events if e["ph"] == "i"]) == 2
     # request span rides pid 2 with its service-named thread
@@ -387,7 +412,7 @@ def test_debug_payload_formats():
     assert "records" in timeline_debug_payload(tl, {"steps": ["bogus"]})
     # summary format
     s = timeline_debug_payload(tl, {"format": ["summary"]})
-    assert s["steps"] == 1 and "phases" in s and "host_gap" in s
+    assert s["steps"] == 1 and "phases" in s and "device" in s
     # perfetto format (no collector wired: engine track only)
     t = timeline_debug_payload(tl, {"format": ["perfetto"]})
     assert "traceEvents" in t
@@ -405,8 +430,6 @@ def test_merge_summaries_totals_and_bubble():
             "phases": {"admit": {"count": 10, "total_s": admit_s,
                                  "p50_ms": p95 / 2, "p95_ms": p95,
                                  "share": admit_s / wall}},
-            "host_gap": {"count": 5, "total_s": gap_s, "p50_ms": 1.0,
-                         "p95_ms": p95, "share": gap_s / wall},
         }
 
     a, b = mk(1.0, 0.2, 0.05, 4.0), mk(2.0, 0.4, 0.10, 9.0)
@@ -424,9 +447,7 @@ def test_merge_summaries_totals_and_bubble():
     assert abs(adm["total_s"] - 0.6) < 1e-9
     assert adm["p95_ms_max"] == 9.0  # worst worker, quantiles don't merge
     assert abs(adm["share"] - 0.2) < 1e-6
-    hg = merged["host_gap"]
-    assert hg["count"] == 10 and hg["p95_ms_max"] == 9.0
-    assert abs(hg["total_s"] - 0.15) < 1e-9
+    assert "device" in merged
     assert abs(merged["loop_wall_s"] - 4.0) < 1e-9
     dr = merged["drained"]
     assert dr["count"] == 7 and abs(dr["total_s"] - 1.7) < 1e-9
@@ -443,11 +464,14 @@ def test_merge_summaries_totals_and_bubble():
 # ---------------------------------------------------------------------------
 # the loop account and drained time, on a clock the test moves
 # ---------------------------------------------------------------------------
+T0 = 100.0  # where the moved clock starts
+
+
 class _Clock:
     """Stands in for the `time` module inside observability/timeline.py."""
 
     def __init__(self):
-        self.t = 100.0
+        self.t = T0
 
     def monotonic(self):
         return self.t
@@ -468,7 +492,8 @@ def _play(tl, clock, script):
     a loop state, `begin`/`commit` bracket a step, `enter`/`exit` a phase
     (`enter` takes a name, (name, upto) or (name, upto, kind)), `idle` is
     time inside a step
-    that no phase claims, `first` makes a sequence's TokenWait under the
+    that no phase claims, `stamp` = (ticket, seconds since T0) posts the
+    watcher thread's completion stamp as of now, `first` makes a sequence's TokenWait under the
     name `arg` in `tl.waits` and `emit` = (name, tokens) charges it an
     emission. The clock moves by `seconds` AFTER each op, so that is how
     long the segment the op opened lasts."""
@@ -489,6 +514,8 @@ def _play(tl, clock, script):
             tl.__dict__.setdefault("waits", {})[arg] = tl.token_start()
         elif op == "emit":
             tl.token_gap(tl.waits[arg[0]], arg[1], arg[0])
+        elif op == "stamp":  # the watcher's: (ticket, seconds since T0)
+            tl._dev.stamps.append((arg[0], T0 + arg[1]))
         clock.t += seconds
 
 
@@ -955,17 +982,428 @@ def test_dynamo_top_prints_token_time_by_cause(clock):
         frame = {"ts": "00:00:00", "workers": [{
             "url": "http://w", "flight": None,
             "stats": {"model": "m", "timeline": tl.summary()}}]}
-        return [ln for ln in top.render(frame, 0) if " token " in ln]
+        return [ln for ln in top.render(frame, 0)
+                if " token " in ln or " stepln " in ln]
 
-    assert lines() == []
+    (line,) = lines()  # the bubble panel; no token ended a wait yet
+    assert "device idle=6.2% (most in dispatch)" in line
+    assert "longest gap=2.0ms before #1" in line
     _play(tl, clock, [("begin", None, 0.0)] + _dispatch("decode", 0.004)
           + _wait(None, 0.196) + [("enter", "detok", 0.0),
                                   ("emit", ("req-7", 16), 0.0),
                                   ("exit", None, 0.0), ("commit", None, 0.0)])
-    (line,) = lines()
+    panel, line = lines()
+    assert "device idle=2.6% (most in dispatch)" in panel
+    assert "longest gap=4.0ms before #2" in panel
     assert "decode=12.25ms" in line and "prompt=0.00ms" in line
+    assert "idle=0.25ms" in line
     assert "drained=0.25ms" in line
     assert "longest gap=200ms (req-7)" in line
+
+
+# ---------------------------------------------------------------------------
+# the device account: busy and idle from the programs' own ends
+# ---------------------------------------------------------------------------
+def _device_conserves(summ):
+    dev = summ["device"]
+    assert dev["busy_s"] + dev["idle_s"] == pytest.approx(
+        summ["loop_wall_s"], abs=2e-6)
+    assert sum(dev["idle_by"].values()) == pytest.approx(dev["idle_s"],
+                                                         abs=2e-6)
+    assert sum(dev["busy_by"].values()) == pytest.approx(dev["busy_s"],
+                                                         abs=2e-6)
+    assert set(dev["idle_by"]) == set(IDLE_KEYS)
+    assert dev["busy_enter_s"] >= dev["busy_s"] - 2e-6
+    return dev
+
+
+def _pipelined(stamp_at):
+    """Two windows in the pipeline, then a third launched 3 ms after the
+    second's end. Program 1 runs 0.002-0.010 (the host's wait on it
+    returns then: the only stamp it gets), program 2 from 0.010 to 0.011,
+    in the middle of the host's `detok`; the host reads it only after it
+    has launched program 3 at 0.014, so no second of this is drained. The
+    watcher's stamp for 2 is taken up either at the next boundary
+    (`early`: the account finds nothing enqueued and charges segments as
+    they close) or after program 3 is enqueued (`late`: the 3 ms are cut
+    out of the tail)."""
+    stamp = [("stamp", (2, 0.011), 0.0)]
+    return ([("loop", "between_steps", 0.0), ("begin", None, 0.0)]
+            + _dispatch("decode") + _dispatch("prompt")
+            + _wait(1, 0.006)
+            + [("enter", "detok", 0.002), ("exit", None, 0.0)]
+            + (stamp if stamp_at == "early" else [])
+            + [("enter", "bank", 0.0005), ("exit", None, 0.0),
+               ("idle", None, 0.0005)]
+            + _dispatch("decode", 0.001)
+            + (stamp if stamp_at == "late" else [])
+            + _wait(2, 0.0001) + _wait(3, 0.005)
+            + [("commit", None, 0.0), ("loop", "no_work", 0.020),
+               ("loop", "no_work", 0.0)])
+
+
+@pytest.mark.parametrize("stamp_at", ["early", "late"])
+def test_device_idle_behind_the_hosts_back(clock, stamp_at):
+    tl = StepTimeline(capacity=8, enabled=True)
+    _play(tl, clock, _pipelined(stamp_at))
+    summ = tl.summary()
+    dev = _device_conserves(summ)
+    # the host's account saw none of it: a program was unread until the
+    # last wait, and what is drained is the waiting for a request after it
+    assert {k: v for k, v in summ["drained"]["by"].items() if v} == {
+        "no_work": pytest.approx(0.020)}
+    assert summ["drained"]["count"] == 0
+    # the device's: the 2 ms of the first dispatch, the 3 ms between
+    # program 2's end and program 3's launch cut by the four segments that
+    # cover them, and the waiting once program 3 was read
+    assert dev["idle_by"] == pytest.approx(
+        {"admit": 0.0, "page_alloc": 0.0, "dispatch": 0.002 + 0.001,
+         "device_wait": 0.0, "detok": 0.001, "bank": 0.0005,
+         "untracked": 0.0005, "between_steps": 0.0, "no_work": 0.020},
+        abs=1e-6)
+    assert dev["programs"] == tl.dispatch_seq == 3 and dev["unstamped"] == 0
+    assert dev["late_stamps"] == 0
+    # 1: 0.002-0.010 (decode), 2: 0.010-0.011 (prompt), 3: 0.014-0.0191
+    assert dev["busy_by"] == pytest.approx(
+        {"decode": 0.008 + 0.0051, "prompt": 0.001}, abs=1e-6)
+    # counted from each dispatch's ENTER: 2 ms more for program 1, 1 ms
+    # for program 3; program 2 started at its predecessor's end either way
+    assert dev["busy_enter_s"] == pytest.approx(dev["busy_s"] + 0.003,
+                                                abs=1e-6)
+    recs = tl.programs()
+    assert [r["ticket"] for r in recs] == [1, 2, 3]
+    # a successor launched before the stamp waited for the device, not
+    # the device for it
+    assert [r["idle_before_s"] for r in recs] == pytest.approx(
+        [0.002, 0.0, 0.003], abs=1e-9)
+    assert [r["busy_s"] for r in recs] == pytest.approx(
+        [0.008, 0.001, 0.0051], abs=1e-9)
+    assert [(r["kind"], r["steps"], r["rows"]) for r in recs] == [
+        ("decode", 1, 0), ("prompt", 1, 0), ("decode", 1, 0)]
+    # the longest gaps, ranked by what is not `no_work`
+    worst = dev["idle_worst"]
+    assert [w["before_ticket"] for w in worst] == [3, 1]
+    assert worst[0]["idle_s"] == pytest.approx(0.003)
+    assert worst[0]["by"] == pytest.approx(
+        {"detok": 0.001, "bank": 0.0005, "untracked": 0.0005,
+         "dispatch": 0.001})
+    assert worst[0]["kind"] == "decode"
+    assert worst[0]["t_unix_ns"] == int((T0 + 0.011) * 1e9)
+    # the histogram behind dynamo_engine_device_idle_seconds: one sample a
+    # program
+    assert tl.idle_digest.count == 3
+    assert tl.idle_digest.sum_s == pytest.approx(0.005)
+    # a reading between stamps counts the oldest unstamped program busy
+    _play(tl, clock, [("loop", "between_steps", 0.0), ("begin", None, 0.0)]
+          + _dispatch("prompt") + [("enter", "detok", 0.004)])
+    clock.t += 0.001
+    tl.fold()
+    mid = tl.summary()["device"]
+    assert (mid["programs"], mid["unstamped"]) == (3, 1)
+    assert mid["busy_by"]["prompt"] == pytest.approx(0.001 + 0.005, abs=1e-6)
+    assert tl.device_totals() == {
+        "busy_s": mid["busy_s"], "busy_enter_s": mid["busy_enter_s"],
+        "idle_s": mid["idle_s"]}
+
+
+def test_the_earlier_of_two_stamps_wins_and_the_skew_is_kept(clock):
+    tl = StepTimeline(capacity=8, enabled=True)
+    # 1: the watcher's stamp (0.0070) is there before the host's wait on it
+    # returns (0.0072); 2: the wait returns at 0.0152 and the watcher's
+    # stamp, taken at 0.0153, is posted behind it; 3 was long finished
+    # when the host's wait on it began, and the wait then sat 2 ms on an
+    # implicit program: no measure of the stamp
+    _play(tl, clock, [("loop", "between_steps", 0.0), ("begin", None, 0.0)]
+          + _dispatch("decode")
+          + [("enter", ("device_wait", 1), 0.005), ("stamp", (1, 0.0070), 0.0002),
+             ("exit", None, 0.0)]
+          + _dispatch("decode", 0.001) + _wait(2, 0.007)
+          + [("stamp", (2, 0.0153), 0.0)]
+          + _dispatch("decode", 0.001)
+          + [("stamp", (3, 0.0170), 0.003), ("enter", "detok", 0.002),
+             ("exit", None, 0.0)]
+          + _wait(3, 0.002) + [("commit", None, 0.0)])
+    dev = _device_conserves(tl.summary())
+    assert [r["t_done"] - T0 for r in tl.programs()] == pytest.approx(
+        [0.0070, 0.0152, 0.0170], abs=1e-9)
+    assert dev["stamp_skew_ms"] == {"p50": 0.1, "p95": 0.1, "count": 2}
+    assert sorted(tl._dev.skews) == pytest.approx([-0.0002, 0.0001])
+
+
+def test_a_stamp_older_than_the_tail(clock, monkeypatch):
+    monkeypatch.setattr(timeline_mod, "TAIL_SEGMENTS", 3)
+    tl = StepTimeline(capacity=8, enabled=True)
+    # program 1 ends at 0.003, in `admit`; five segments close before its
+    # stamp is seen, and the tail keeps three: [0.003, 0.005) is older
+    _play(tl, clock, [("loop", "between_steps", 0.0), ("begin", None, 0.0)]
+          + _dispatch("decode")
+          + [("enter", "admit", 0.002), ("exit", None, 0.0),
+             ("enter", "page_alloc", 0.001), ("exit", None, 0.0),
+             ("enter", "detok", 0.001), ("exit", None, 0.0),
+             ("enter", "bank", 0.001), ("exit", None, 0.0),
+             ("stamp", (1, 0.003), 0.001), ("commit", None, 0.0)])
+    dev = _device_conserves(tl.summary())
+    assert dev["late_stamps"] == 1
+    assert dev["busy_s"] == pytest.approx(0.001)
+    assert dev["idle_by"] == pytest.approx(
+        {"admit": 0.0, "page_alloc": 0.0, "dispatch": 0.002,
+         "device_wait": 0.0, "detok": 0.001, "bank": 0.001,
+         "untracked": 0.002 + 0.001, "between_steps": 0.0, "no_work": 0.0},
+        abs=1e-9)
+
+
+def test_device_reset_forgets_stamps_in_flight(clock):
+    tl = StepTimeline(capacity=8, enabled=True)
+    _play(tl, clock, [("loop", "between_steps", 0.0), ("begin", None, 0.0)]
+          + _dispatch("decode") + [("commit", None, 0.001),
+                                   ("stamp", (1, 0.0025), 0.0)])
+    old = tl._dev.stamps
+    tl.reset()
+    assert old and not tl._dev.stamps and not tl._dev.flying
+    # the program dispatched before the reset belongs to no account: its
+    # wait settles nothing, and the next program starts the count at one
+    _play(tl, clock, [("loop", "between_steps", 0.002), ("begin", None, 0.0)]
+          + _wait(1, 0.001) + _dispatch("decode") + _wait(2, 0.004)
+          + [("commit", None, 0.0), ("loop", "no_work", 0.0)])
+    summ = tl.summary()
+    dev = _device_conserves(summ)
+    assert summ["loop_wall_s"] == pytest.approx(0.009)
+    assert dev["programs"] == 1 and tl.programs()[0]["ticket"] == 2
+    assert dev["busy_s"] == pytest.approx(0.004)
+    assert tl.idle_digest.count == 1
+
+
+def test_row_idle_goes_to_the_slots_live_across_the_gap(clock):
+    tl = StepTimeline(capacity=8, enabled=True)
+    # "a" has its first token before the 3 ms gap of _pipelined, "b" after
+    script = _pipelined("early")
+    at = script.index(("enter", "detok", 0.002))
+    script[at:at] = [("first", "a", 0.0)]
+    end = script.index(("commit", None, 0.0))
+    script[end:end] = [("first", "b", 0.0), ("enter", "detok", 0.001),
+                       ("emit", ("a", 1), 0.0), ("emit", ("b", 1), 0.0),
+                       ("exit", None, 0.0)]
+    _play(tl, clock, script)
+    summ = tl.summary()
+    dev = _device_conserves(summ)
+    assert dev["row_idle_s"] == pytest.approx(0.003, abs=1e-9)
+    # beside the causes, not among them: they still sum to the token time
+    assert summ["token_time"]["gaps"] == 2
+    assert sum(tl.waits["a"].sums) == pytest.approx(0.0091, abs=1e-9)
+    # idle once the last program is read is the emitting `detok` here:
+    # charged at the NEXT emission of whoever is live then
+    _play(tl, clock, [("loop", "between_steps", 0.0), ("begin", None, 0.0),
+                      ("enter", "detok", 0.0), ("emit", ("b", 1), 0.0),
+                      ("exit", None, 0.0), ("commit", None, 0.0)])
+    assert tl.summary()["device"]["row_idle_s"] == pytest.approx(
+        0.003 + 0.001 + 0.020, abs=1e-9)
+    tl.reset()
+    assert tl.summary()["device"]["row_idle_s"] == 0.0
+
+
+def _gaps(tl, clock, gaps_ms):
+    """One synchronous program a gap: the host sits `g` ms in `admit` with
+    the device empty, then dispatches and waits."""
+    for g in gaps_ms:
+        _play(tl, clock, [("begin", None, 0.0),
+                          ("enter", "admit", g / 1e3), ("exit", None, 0.0)]
+              + _dispatch("decode", 0.0005) + _wait(None, 0.002)
+              + [("commit", None, 0.0)])
+
+
+def test_the_eight_longest_idle_intervals_are_kept(clock):
+    tl = StepTimeline(capacity=8, enabled=True)
+    tl.loop_state("between_steps")
+    _gaps(tl, clock, [3, 9, 1, 7, 5, 11, 2, 8, 6, 10, 4])
+    worst = tl.summary()["device"]["idle_worst"]
+    assert [round(w["idle_s"] * 1e3, 1) for w in worst] == [
+        11.5, 10.5, 9.5, 8.5, 7.5, 6.5, 5.5, 4.5]
+    assert worst[0]["by"] == pytest.approx({"admit": 0.011,
+                                            "dispatch": 0.0005})
+    assert worst[0]["before_ticket"] == 6
+    # half a second without a request is long, and nothing a host change
+    # could shrink: it is ranked by the rest of its interval
+    _play(tl, clock, [("loop", "no_work", 0.5),
+                      ("loop", "between_steps", 0.0)])
+    _gaps(tl, clock, [5.2])
+    worst = tl.summary()["device"]["idle_worst"]
+    assert len(worst) == 8
+    assert [w["before_ticket"] for w in worst][6:] == [12, 5]
+    assert worst[6]["idle_s"] == pytest.approx(0.5057)
+    assert worst[6]["by"]["no_work"] == pytest.approx(0.5)
+    tl.reset()
+    assert tl.summary()["device"]["idle_worst"] == []
+
+
+def test_merge_summaries_folds_the_device_account(clock):
+    a, b = (StepTimeline(capacity=8, enabled=True) for _ in range(2))
+    _play(a, clock, _pipelined("early"))
+    b.loop_state("between_steps")
+    _gaps(b, clock, [4, 2])
+    sa, sb = a.summary(), b.summary()
+    sb["device"]["stamp_skew_ms"] = {"p50": -0.3, "p95": 0.2, "count": 4}
+    old = {"steps": 1, "wall_s": 0.5}  # a worker from before the account
+    merged = merge_summaries([sa, sb, old, {}])
+    dev = merged["device"]
+    for key in ("busy_s", "idle_s", "programs", "unstamped", "row_idle_s",
+                "busy_enter_s", "late_stamps"):
+        assert dev[key] == pytest.approx(
+            sa["device"][key] + sb["device"][key], abs=2e-6), key
+    assert dev["programs"] == 5
+    for key in IDLE_KEYS:
+        assert dev["idle_by"][key] == pytest.approx(
+            sa["device"]["idle_by"][key] + sb["device"]["idle_by"][key],
+            abs=2e-6)
+    assert dev["busy_by"]["prompt"] == pytest.approx(0.001)
+    assert [w["idle_s"] for w in dev["idle_worst"]] == pytest.approx(
+        [0.0045, 0.003, 0.0025, 0.002])
+    assert dev["stamp_skew_ms"] == {"p50": -0.3, "p95": 0.2, "count": 4}
+
+
+def test_perfetto_draws_the_programs_the_timeline_kept(clock):
+    tl = StepTimeline(capacity=8, enabled=True)
+    _play(tl, clock, _pipelined("late"))
+    events = perfetto_trace(tl)["traceEvents"]
+    bars = [e for e in events if e["ph"] == "X" and e["cat"] == "device"]
+    assert [b["args"]["ticket"] for b in bars] == [1, 2, 3]
+    for before, after in zip(bars, bars[1:]):
+        assert before["ts"] + before["dur"] <= after["ts"]
+    assert [round(b["dur"]) for b in bars] == [8000, 1000, 5100]
+    segs = [e for e in events if e["ph"] == "X" and e["name"] == "dispatch"]
+    assert len(segs) == 3
+    for bar, seg in zip(bars, segs):
+        start, end = [e for e in events if e["ph"] in "sf"
+                      and e["id"] == bar["args"]["ticket"]]
+        assert seg["ts"] < start["ts"] < seg["ts"] + seg["dur"]
+        assert (end["ts"], end["tid"]) == (bar["ts"], bar["tid"])
+    payload = timeline_debug_payload(tl, {})
+    assert [p["ticket"] for p in payload["programs"]] == [1, 2, 3]
+
+
+def test_a_handle_that_never_ends_does_not_hold_the_watcher_for_good():
+    """A hung program: the watcher sits on its handle. The dispatches
+    behind it queue up to WATCH_BACKLOG handles, then get a watcher of
+    their own; a handle that raises (a deleted buffer) is stamped as of
+    then; close() does not wait for the one that hangs."""
+    import threading
+    import time
+
+    release = threading.Event()
+
+    class _Handle:
+        def __init__(self, how):
+            self.how = how
+
+        def devices(self):
+            return {0}
+
+        def block_until_ready(self):
+            if self.how == "hangs":
+                release.wait(30.0)
+            elif self.how == "deleted":
+                raise RuntimeError("Array has been deleted.")
+
+    tl = StepTimeline(capacity=8, enabled=True)
+    tl.begin_step()
+
+    def dispatch(how):
+        with tl.phase("dispatch") as ph:
+            ph.done_when(_Handle(how))
+
+    def stamped(n, within=5.0):
+        deadline = time.monotonic() + within
+        while time.monotonic() < deadline:
+            tl.fold()
+            tl._mark(tl._cur_t, tl._cur_name)
+            if tl.summary()["device"]["programs"] >= n:
+                return True
+            time.sleep(0.01)
+        return False
+
+    try:
+        dispatch("ready")
+        dispatch("deleted")
+        assert stamped(2)
+        first = tl._dev.thread
+        dispatch("hangs")
+        while tl._dev._inbox.qsize():  # until the watcher has taken it
+            time.sleep(0.001)
+        for _ in range(timeline_mod.WATCH_BACKLOG + 1):
+            dispatch("ready")
+        assert tl._dev.thread is first and first.is_alive()
+        assert not stamped(3, within=0.2)
+        dispatch("ready")  # one too many behind it: a watcher of its own
+        second = tl._dev.thread
+        assert second is not first
+        # programs run in order: the newest one's stamp settles them all
+        assert stamped(tl.dispatch_seq)
+        t0 = time.monotonic()
+        tl.close()
+        assert time.monotonic() - t0 < 3.0 and not second.is_alive()
+        assert first.is_alive()
+    finally:
+        release.set()
+    first.join(5.0)
+    assert not first.is_alive()
+
+
+def _service_streams(monkeypatch, enabled):
+    """Three greedy requests through an EngineService on the tiny model:
+    (streams by request, the engine's timeline, the watcher thread)."""
+    import threading
+
+    from dynamo_tpu.engine.config import EngineConfig
+    from dynamo_tpu.engine.engine import Engine
+    from dynamo_tpu.engine.request import GenRequest
+    from dynamo_tpu.serving.engine_service import EngineService
+
+    monkeypatch.setenv("DYNAMO_TPU_TIMELINE", "1" if enabled else "0")
+    eng = Engine(EngineConfig(**KW, num_scheduler_steps=4,
+                              prefill_chunk_tokens=8, mixed_batch_tokens=8))
+    assert eng.timeline.enabled is enabled
+    svc = EngineService(eng)
+    watcher = None
+    try:
+        reqs = [GenRequest(f"d{i}", list(range(1 + i, 4 + 9 * i)),
+                           max_tokens=12, temperature=0.0, ignore_eos=True)
+                for i in range(3)]
+        queues = [svc.submit(r) for r in reqs]
+        streams = {r.request_id: [ev.token_id for ev in svc.drain(r, q, 60.0)]
+                   for r, q in zip(reqs, queues)}
+        watcher = eng.timeline._dev.thread
+        if enabled:
+            assert watcher is not None and watcher.is_alive()
+            assert watcher in threading.enumerate()
+    finally:
+        svc.close()
+    return streams, eng.timeline, watcher
+
+
+def test_engine_watcher_stamps_every_ticket_and_ends_with_the_loop(
+        monkeypatch):
+    on, tl, watcher = _service_streams(monkeypatch, True)
+    # the loop is closed: the watcher has ended behind its last handle,
+    # which it had stamped (close() joined it)
+    assert not watcher.is_alive() and tl._dev.thread is None
+    tl.loop_state("no_work")  # a boundary: the last stamps are taken up
+    summ = tl.summary()
+    dev = summ["device"]
+    assert tl.dispatch_seq > 6
+    assert dev["programs"] == tl.dispatch_seq and dev["unstamped"] == 0
+    assert dev["late_stamps"] == 0
+    assert dev["busy_s"] > 0.0 and dev["idle_by"]["no_work"] >= 0.0
+    assert dev["busy_s"] + dev["idle_s"] == pytest.approx(
+        summ["loop_wall_s"], abs=1e-4)
+    assert sum(dev["idle_by"].values()) == pytest.approx(dev["idle_s"],
+                                                         abs=1e-4)
+    assert 0.0 < dev["row_idle_s"] <= 3 * dev["idle_s"]
+    kinds = {p["kind"] for p in tl.programs()}
+    assert kinds == {"decode", "prompt"}
+    assert any(p["steps"] == 4 for p in tl.programs())
+    # a disabled timeline starts no thread, and the streams are the same
+    off, tl_off, none = _service_streams(monkeypatch, False)
+    assert none is None and tl_off.summary()["device"]["programs"] == 0
+    assert on == off and all(len(v) == 12 for v in on.values())
 
 
 # ---------------------------------------------------------------------------
@@ -996,6 +1434,13 @@ def test_annotations_are_made_only_during_a_capture(clock):
                      "stepline/untracked", "stepline/device_wait",
                      "stepline/untracked", "stepline/between_steps"]
     assert ("enter", "stepline/step", {"step_num": 1}) in log
+    # a dispatch names the ticket its program gets, a wait the one it is
+    # on: what joins the device's operations in the profile to the program
+    assert ("enter", "stepline/dispatch",
+            {"ticket": 2, "kind": "decode"}) in log
+    assert ("enter", "stepline/device_wait",
+            {"ticket": 2, "kind": "decode"}) in log
+    assert ("enter", "stepline/untracked", {}) in log
     # segments nest inside the step's annotation and never overlap
     depth = 0
     for e in log:

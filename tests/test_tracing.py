@@ -180,7 +180,7 @@ def test_worker_stats_carry_what_the_layer_metrics_read(server):
                      and not p.startswith("timeline.phases."))
     assert not missing, f"/worker/stats lacks {missing}"
     tl = stats["timeline"]
-    assert {"wall_s", "steps", "phases", "untracked_s", "host_gap", "bubble",
+    assert {"wall_s", "steps", "phases", "untracked_s", "device", "bubble",
             "loop_wall_s", "loop", "drained", "token_time"} <= set(tl)
     assert set(tl["token_time"]) == {"cause_s", "row_s", "gaps",
                                      "gap_max_s", "worst"}

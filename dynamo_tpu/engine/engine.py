@@ -241,6 +241,12 @@ class EngineMetrics:
         # (Engine._mixed_step: read one program late, nothing drained)
         self.num_admitted = 0
         self.first_tokens_behind = 0
+        # decode windows dispatched (Engine._dispatch_window; a mixed step
+        # or a verify is no window): the programs, the decode steps they
+        # fused, and the programs of 2 .. num_scheduler_steps - 1 steps,
+        # cut to the shortest headroom of their batch (_window_steps)
+        self.windows: Dict[str, int] = {"programs": 0, "steps": 0,
+                                        "short": 0}
         self.phases: Dict[str, PhaseTimer] = {p: PhaseTimer()
                                               for p in self._PHASES}
         # a first token by stage, cumulative seconds over `count` requests
@@ -1225,10 +1231,16 @@ class Engine:
 
         def make_decode_window(n_steps: int, with_logprobs: bool,
                                guide_tables=None):
-            """n_steps fused decode iterations in one dispatch: lax.scan over
-            the step body with on-device sampling AND the batch state carried
-            on device, so a steady-state window costs one dispatch + one
-            token download instead of ~9 host round-trips. The logprobs
+            """Up to n_steps fused decode iterations in one dispatch: a loop
+            over the step body with on-device sampling AND the batch state
+            carried on device, so a steady-state window costs one dispatch +
+            one token download instead of ~9 host round-trips. A program of
+            n_steps > 1 takes its trip count as its LAST operand, a traced
+            int32 `steps` in 1..n_steps (Engine._window_steps: a full window,
+            or a short one under the batch's shortest headroom), so one
+            program text serves every length;
+            rows `steps`.. of its [n_steps, B] results are never written and
+            never read. The logprobs
             variant additionally streams back the chosen-token logprob and
             top-5 alternatives per step (compiled lazily — costs nothing
             unless a request asks for logprobs).
@@ -1252,7 +1264,11 @@ class Engine:
                 *extra,
             ):
                 # extra layout: [adapter_slots]? + [gmode, gdepth, gbits,
-                # gactive]? — adapter slots ride first when lora is on
+                # gactive]? + [steps]? — adapter slots ride first when lora
+                # is on, a fused window's trip count rides last
+                steps = 1
+                if n_steps > 1:
+                    *extra, steps = extra
                 gs = extra
                 aslots = None
                 if lora_on:
@@ -1268,6 +1284,14 @@ class Engine:
                 if guided:
                     gmode0, gdepth0, gbits0, gactive = gs
                     gact = gactive & active
+
+                def draw(logits, keys, cnts):
+                    """A step's rows of the results: the sampled tokens [B],
+                    and under logprobs the chosen one's and the top-5."""
+                    if with_logprobs:
+                        return smp.sample_with_logprobs(logits, state, keys,
+                                                        cnts)
+                    return (smp.sample(logits, state, keys, cnts),)
 
                 def body(carry, _):
                     if guided:
@@ -1286,15 +1310,8 @@ class Engine:
                         logits = jnp.where(
                             gact[:, None] & ~allow,
                             jnp.asarray(-1e9, logits.dtype), logits)
-                    keys = smp.fold_positions(slot_keys, pos)
-                    if with_logprobs:
-                        nxt, chosen, tids, tvals = smp.sample_with_logprobs(
-                            logits, state, keys, cnts
-                        )
-                        y = (nxt, chosen, tids, tvals)
-                    else:
-                        nxt = smp.sample(logits, state, keys, cnts)
-                        y = (nxt,)
+                    y = draw(logits, smp.fold_positions(slot_keys, pos), cnts)
+                    nxt = y[0]
                     # count only active slots' emissions; inactive rows are
                     # zeroed at (re)admission anyway
                     cnts = cnts.at[jnp.arange(b), nxt].add(
@@ -1320,9 +1337,32 @@ class Engine:
                         if guided else
                         (tokens, positions, context_lens, counts,
                          k_pages, v_pages))
-                carry, (ys, st) = jax.lax.scan(body, init, None,
-                                               length=n_steps)
-                tail = moe_tail(st.sum(axis=0) if counts_moe else None)
+                # every step writes its row of the preallocated results and
+                # adds to the expert layers' counts; the classic program's
+                # one trip is a constant. The rows are shaped from the
+                # sampler alone, under stand-in logits (the model is traced
+                # once, inside the loop): step_at holds every real row to it
+                rows_like = jax.eval_shape(
+                    draw, jax.ShapeDtypeStruct(counts.shape, jnp.float32),
+                    slot_keys, counts)
+                ys0 = jax.tree.map(
+                    lambda a: jnp.zeros((n_steps,) + a.shape, a.dtype),
+                    rows_like)
+                st0 = (jnp.zeros((len(MOE_STATS),), jnp.int32)
+                       if counts_moe else None)
+
+                def step_at(i, loop):
+                    carry, ys, st = loop
+                    carry, (y, st_i) = body(carry, None)
+                    assert [(a.shape, a.dtype) for a in y] == [
+                        (a.shape, a.dtype) for a in rows_like], y
+                    ys = jax.tree.map(
+                        lambda rows, row: rows.at[i].set(row), ys, y)
+                    return carry, ys, (st + st_i if counts_moe else None)
+
+                carry, ys, st = jax.lax.fori_loop(
+                    0, steps, step_at, (init, ys0, st0))
+                tail = moe_tail(st)
                 if guided:
                     (tokens, positions, context_lens, counts,
                      gm, gd, gb, k_pages, v_pages) = carry
@@ -3694,15 +3734,27 @@ class Engine:
             self.cfg.max_pages_per_seq * self.cfg.page_size - seq.num_tokens,
         )
 
+    # The steps a window may fuse under the shortest headroom, where a full
+    # one no longer fits. One step there left the device idle a third of
+    # the time where the host is the slower side (the engine thread comes
+    # back 11 ms late at the median, 27 at p90, to a step of 8: PERF.md
+    # section 6, PR 55); the whole headroom as one window made an arrival
+    # wait out two windows of up to 15 steps where it had waited two
+    # steps. Four steps in flight cover 85-95% of that idle, and an
+    # arrival waits out eight.
+    SHORT_WINDOW_STEPS = 4
+
     def _window_steps(self, extra: int = 0, skip=()) -> int:
         """How many decode steps the next dispatch may fuse (1 = classic).
 
-        The multi-step window requires every active sequence to have at least
-        K tokens of headroom (max_tokens, max_seq_len, block-table columns) so
-        no stop condition or table overflow can occur mid-window, and no
-        pending prefills waiting for a slot, nor a prompt just admitted whose
-        first chunk the next mixed step carries (admission latency beats
-        batching round-trips).
+        A full window (num_scheduler_steps) requires that much headroom
+        (max_tokens, max_seq_len, block-table columns) of every sequence
+        it runs over; under it a window is as long as the shortest
+        headroom, SHORT_WINDOW_STEPS at most. So a window never crosses a
+        sequence's end (it may end ON it), and no length finish or table
+        overflow can occur mid-window. It is one step while prefills wait
+        for a slot or a prompt just admitted has its first chunk on the
+        next mixed step (admission latency beats batching round-trips).
 
         `extra` = tokens already committed to an in-flight (unread) window
         under async scheduling: headroom must cover BOTH windows. `skip` =
@@ -3722,12 +3774,10 @@ class Engine:
         for slot, seq in self.seqs.items():
             if slot in skip:
                 continue
-            headroom = self._headroom(seq) - extra
-            if headroom < want:
-                want = 1 if headroom >= 1 else 0
-                if want == 0:
-                    return 0
-        return want
+            want = min(want, self._headroom(seq) - extra)
+            if want < 1:
+                return 0
+        return want if want == k else min(want, self.SHORT_WINDOW_STEPS)
 
     def _grow_pages(self, window: int, events: List[TokenEvent],
                     offset: int = 0, allow_kill: bool = True,
@@ -4225,18 +4275,27 @@ class Engine:
             args = (self.params, cur, pos, ctx_lens, active_dev,
                     self._dev_tables, *self._dev_sampling, self.token_counts,
                     self.k_pages, self.v_pages, *lx)
+            # a window of one step is the classic program; every other
+            # length is the fused program's, its trip count the last operand
+            fused = window > 1
+            nx = (jnp.int32(window),) if fused else ()
             if any(s.guide is not None for s in batch.values()):
                 self._ensure_dev_guide()
-                fn = self._get_guided_window(window > 1, want_lp)
+                fn = self._get_guided_window(fused, want_lp)
                 (ys, cur, pos, ctx_lens, self.token_counts, *grammar,
-                 self.k_pages, self.v_pages) = fn(*args, *self._dev_guide)
+                 self.k_pages, self.v_pages) = fn(*args, *self._dev_guide,
+                                                  *nx)
                 self._dev_guide = (*grammar, self._dev_guide[3])
             else:
-                fn = self._windows[(window > 1, want_lp)]
+                fn = self._windows[(fused, want_lp)]
                 (ys, cur, pos, ctx_lens, self.token_counts, self.k_pages,
-                 self.v_pages) = fn(*args)
+                 self.v_pages) = fn(*args, *nx)
             self._dev_state = (cur, pos, ctx_lens, active_dev)
             ph.done_when(ys[0], steps=window, rows=len(batch))
+            w = self.metrics.windows
+            w["programs"] += 1
+            w["steps"] += window
+            w["short"] += 1 < window < self.cfg.num_scheduler_steps
             # the last references to the donated arrays die INSIDE this
             # span: on a TPU releasing them takes ~0.5 ms a window, which
             # `host_share_pct` would otherwise read as the host's
@@ -4269,7 +4328,9 @@ class Engine:
             # chaos: slow-but-alive readback — must NOT trip the watchdog
             # when the delay stays under the deadline
             faults.sleep_point("engine.device_slow")
-            toks = np.asarray(pw.ys[0])  # [window, B]
+            # the rows the program wrote: a fused window's results are
+            # num_scheduler_steps rows whatever its trip count
+            toks = np.asarray(pw.ys[0])[:pw.lag]  # [window, B]
             # chosen [window, B], top ids and values [window, B, K]
             lps = (tuple(np.asarray(y) for y in pw.ys[1:]) if pw.want_lp
                    else None)

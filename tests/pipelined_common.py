@@ -245,3 +245,58 @@ def assert_first_token_rides_pipeline(sync_eng, eng, prompt):
         assert counters[name] == ref_counters[name], name
     assert _stores(eng) == before
     return got
+
+
+def assert_windows_as_long_as_the_shortest_headroom(single, engines, prompt):
+    """A greedy and a seeded sampled sequence end beside an anchor (greedy,
+    logprobs) at every offset 1 .. k + 1 of a k-step window: the greedy one
+    after n decode steps, the sampled one after 2k + 2 - n (an odd number
+    of steps behind it). Every engine of `engines` (num_scheduler_steps =
+    k, either order) gives the tokens, logprobs and finish reasons that
+    `single` (num_scheduler_steps=1: a classic program a step) gives, runs
+    the same decode steps over the same contexts (the kernels' counters),
+    and the pipelined ones dispatched windows of every length the
+    scheduler hands out: k, and 1 .. SHORT_WINDOW_STEPS under the
+    shortest headroom (`metrics.windows`).
+    `prompt(i)` = the i-th prompt, every round its own three."""
+    assert single.cfg.num_scheduler_steps == 1
+    k = engines[0].cfg.num_scheduler_steps
+    lags = [set() for _ in engines]  # window lengths seen in flight
+    for n in range(1, k + 2):
+        reqs = [
+            GenRequest("anchor", prompt(3 * n), max_tokens=3 * k + 3,
+                       temperature=0.0, ignore_eos=True, logprobs=2),
+            GenRequest("greedy", prompt(3 * n + 1), max_tokens=n + 1,
+                       temperature=0.0, ignore_eos=True),
+            GenRequest("sampled", prompt(3 * n + 2), max_tokens=2 * k + 3 - n,
+                       temperature=0.9, seed=40 + n, top_k=8,
+                       ignore_eos=True, logprobs=1)]
+        script = {0: lambda e: [e.add_request(dataclasses.replace(r))
+                                for r in reqs]}
+        want = drive(single, script)
+        assert [len(want[r.request_id]["tokens"]) for r in reqs] == [
+            3 * k + 3, n + 1, 2 * k + 3 - n]
+        for eng, seen in zip(engines, lags):
+            assert eng.cfg.num_scheduler_steps == k > 1
+
+            def probe(e, seen=seen):
+                pw = e._pending_win
+                if pw is not None and pw.chunk is None:
+                    # a window ends ON a sequence's end or before it
+                    assert 1 <= pw.lag <= min(
+                        e._headroom(e.seqs[s]) for s in pw.slots)
+                    assert pw.lag == k or pw.lag <= e.SHORT_WINDOW_STEPS
+                    seen.add(pw.lag)
+
+            same_streams(drive(eng, script, probe), want)
+            m, ref = eng.metrics, single.metrics
+            assert m.decode_steps == ref.decode_steps
+            assert m.windows["steps"] == ref.windows["steps"]
+            assert m.windows["programs"] < ref.windows["programs"]
+            counters, ref_counters = m.kernel_counters(), ref.kernel_counters()
+            for name in ("attn", "attn_kinds", "dsa", "ssm", "conv"):
+                assert counters[name] == ref_counters[name], name
+    for eng, seen in zip(engines, lags):
+        if eng.cfg.async_scheduling:
+            short = min(k, eng.SHORT_WINDOW_STEPS)
+            assert seen == set(range(1, short + 1)) | {k}, sorted(seen)

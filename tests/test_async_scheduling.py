@@ -8,13 +8,14 @@ import pytest
 
 from dynamo_tpu.engine.config import EngineConfig
 from dynamo_tpu.engine.engine import Engine
+from dynamo_tpu.engine.kv_cache import SeqState
 from dynamo_tpu.engine.request import GenRequest
 
 from dynamo_tpu.robustness import faults
 
-from pipelined_common import (assert_finish_rides_pipeline,
-                              assert_first_token_rides_pipeline, drive,
-                              same_streams)
+from pipelined_common import (
+    assert_finish_rides_pipeline, assert_first_token_rides_pipeline,
+    assert_windows_as_long_as_the_shortest_headroom, drive, same_streams)
 
 
 def _mk(async_sched, **kw):
@@ -593,6 +594,189 @@ def test_a_guided_batchs_finishes_ride_the_pipeline(pair):
     m = eng.metrics
     assert (m.num_finished, m.finishes_behind) == (4, 3)
     assert any(held) and m.held_pages_peak > 0  # the EOS: one program late
+
+
+# --- a window as long as the shortest headroom: the fused program takes
+# its trip count as an operand, so a sequence's last tokens cost one short
+# window; num_scheduler_steps=1 (a classic program a step) is the oracle ---
+
+@pytest.mark.parametrize("model,kw,short", [
+    ("tiny-debug", dict(num_scheduler_steps=16), None),
+    ("tiny-debug", dict(num_scheduler_steps=16), 16),
+    ("tiny-kimi-ep4-debug", dict(dtype="float32"), None),
+    ("tiny-debug", dict(lora_slots=2), None),
+], ids=["dense_16_steps", "dense_16_steps_every_trip_count",
+        "mla_grouped_experts", "lora_on"])
+def test_windows_of_every_length_give_the_single_steps_tokens(model, kw,
+                                                              short):
+    """Dense at the cells' 16 steps (rows end at every offset 1 .. 17 of a
+    window: windows of 1 .. 4 and 16 steps as the scheduler hands them
+    out, and once with the short window's bound lifted, so that the
+    program runs at EVERY trip count 1 .. 16), MLA under grouped expert
+    matmuls (whose counts the loop sums in its carry), and the LoRA
+    operand riding beside the trip count; the state-slot, ring and DSA
+    models in their own engine tests."""
+    kw = dict(MIXED, model=model, **kw)
+    single = _mk(False, **dict(kw, num_scheduler_steps=1))
+    engines = [_mk(False, **kw), _mk(True, **kw)]
+    for eng in engines:
+        if short is not None:
+            eng.SHORT_WINDOW_STEPS = short
+    assert_windows_as_long_as_the_shortest_headroom(
+        single, engines, lambda i: _prompt(i, 5 + i % 3))
+
+
+def _rows(eng, monkeypatch, headrooms, prompt_len=6):
+    """`eng.seqs` set to one sequence a slot with `headrooms[slot]` tokens
+    left by max_tokens (far from max_seq_len and the table's end)."""
+    seqs = {}
+    for slot, room in enumerate(headrooms):
+        seq = SeqState(f"r{slot}", slot, [], prompt_len, max_tokens=room + 1)
+        seq.output_tokens = [1]
+        seq.num_tokens = prompt_len
+        seqs[slot] = seq
+    monkeypatch.setattr(eng, "seqs", seqs)
+
+
+@pytest.mark.parametrize("headrooms,extra,skip,want", [
+    ((40, 30, 9), 0, (), 4),  # everybody has a full window left
+    ((40, 3, 9), 0, (), 3),  # the shortest headroom
+    ((40, 3, 2), 0, (), 2),
+    ((40, 1, 9), 0, (), 1),  # one token left: the classic program
+    ((40, 7, 9), 4, (), 3),  # behind a program in flight: headroom - lag
+    ((40, 5, 9), 4, (), 1),
+    ((40, 4, 9), 4, (), 0),  # no step fits on top of the program in flight
+    ((40, 4, 9), 4, (1,), 4),  # a leaver is priced out: min over the rest
+    ((40, 4, 6), 4, (1,), 2),
+    ((3, 4, 2), 4, (0, 1, 2), 0),  # nobody stays to run it
+    ((), 0, (), 1),  # nobody live
+], ids=["full", "shortest_3", "shortest_2", "one_left", "behind_lag",
+        "behind_lag_one", "behind_lag_none", "skip_leaver",
+        "skip_leaver_short", "all_leave", "nobody_live"])
+def test_window_steps_is_the_shortest_headroom_of_the_rows_that_stay(
+        pair, monkeypatch, headrooms, extra, skip, want):
+    """`_window_steps` = min(num_scheduler_steps, shortest headroom - extra)
+    over the rows that stay, 0 where not even one step fits."""
+    eng = pair[1]
+    assert eng.cfg.num_scheduler_steps == 4 and not eng.has_work
+    _rows(eng, monkeypatch, headrooms)
+    assert eng._window_steps(extra=extra, skip=skip) == want
+
+
+@pytest.mark.parametrize("headrooms,extra,skip,want", [
+    ((40, 30, 16), 0, (), 16),  # a full window fits
+    ((40, 30, 15), 0, (), 4),  # it no longer does: the short window
+    ((40, 5, 15), 0, (), 4),
+    ((40, 3, 15), 0, (), 3),  # the shortest headroom under it
+    ((40, 36, 32), 16, (), 16),  # behind a full window in flight
+    ((40, 36, 31), 16, (), 4),
+    ((40, 18, 31), 16, (), 2),
+    ((40, 16, 31), 16, (1,), 4),  # the leaver priced out
+    ((40, 16, 32), 16, (1,), 16),
+], ids=["full", "just_under", "under", "shortest_3", "behind_full",
+        "behind_just_under", "behind_shortest_2", "skip_leaver_short",
+        "skip_leaver_full"])
+def test_a_window_under_a_full_ones_headroom_is_a_short_one(
+        pair, monkeypatch, headrooms, extra, skip, want):
+    """At the cells' 16 steps: a full window where every row that stays
+    has 16 tokens of headroom on top of the program in flight, else the
+    shortest headroom, SHORT_WINDOW_STEPS (4) at most: an arrival waits
+    out two programs, and they are 8 steps where the headroom's own
+    length would make them up to 30."""
+    eng = pair[1]
+    assert eng.SHORT_WINDOW_STEPS == 4 and not eng.has_work
+    monkeypatch.setattr(eng.cfg, "num_scheduler_steps", 16)
+    _rows(eng, monkeypatch, headrooms)
+    assert eng._window_steps(extra=extra, skip=skip) == want
+
+
+@pytest.mark.parametrize("small", ["pending", "chunk_rides_next_step",
+                                   "one_scheduler_step"])
+def test_window_steps_stays_one_step_in_every_small_case(pair, monkeypatch,
+                                                         small):
+    """Something pending, a prompt whose chunk rides the next mixed step,
+    num_scheduler_steps <= 1: one step exactly as before, whatever the
+    headrooms; 0 still where no step fits."""
+    eng = pair[1]
+    _rows(eng, monkeypatch, (40, 3, 9))
+    assert eng._window_steps() == 3
+    if small == "pending":
+        monkeypatch.setattr(eng, "pending", [_live()])
+    elif small == "chunk_rides_next_step":
+        monkeypatch.setattr(eng, "_inflight", object())
+        assert eng._mixed_eligible()
+    else:
+        monkeypatch.setattr(eng.cfg, "num_scheduler_steps", 1)
+    assert eng._window_steps() == 1
+    assert eng._window_steps(extra=2) == 1
+    assert eng._window_steps(extra=3) == 0
+
+
+def test_a_row_that_ends_on_a_short_windows_last_step_is_a_leaver(pair):
+    """`short` has 3 decode steps left when the batch is whole: the window
+    is 3 steps and ends ON its end. While that window is in flight the row
+    is a leaver (retired in the carry, no page grown for it), the next
+    window is priced over `live` alone (a full 4 steps) and dispatched
+    before the short one is read: a finish behind the program in flight,
+    as PR 50's full windows had it."""
+    sync, eng = pair
+    script = {0: _add(_live(n=30),
+                      GenRequest("short", [4, 5, 6, 7], max_tokens=4,
+                                 temperature=0.8, seed=3, ignore_eos=True))}
+    seen = []
+
+    def probe(e):
+        pw = e._pending_win
+        if pw is not None:
+            seen.append((pw.lag, len(pw.slots), len(e.seqs),
+                         sorted(e._leaving)))
+
+    ref = _drive(sync, script)
+    out = _drive(eng, script, probe)
+    _same(out, ref)
+    # the 3-step window over both rows, then 4 steps over `live` alone,
+    # dispatched before `short` was read (finishes_behind)
+    assert seen[:2] == [(3, 2, 2, []), (4, 1, 1, [])]
+    m = eng.metrics
+    assert m.finishes_behind == 1 and m.held_pages_peak == 0
+    assert m.windows["short"] >= 1
+    assert m.windows["steps"] == m.decode_steps - m.mixed_count
+    assert not eng._leaving
+
+
+def test_a_stop_token_inside_a_short_window_holds_its_pages(pair):
+    """A stop token found where a SHORT window is read: the program behind
+    it computes the stopper's row, so its pages and slot wait in `_held`
+    until that program is read, as behind a full window."""
+    sync, eng = pair
+    stopper, want = _stop_at(sync, lo=9)
+    k = len(want)  # tokens the stopper gives, the stop included
+    # `ender` ends two steps behind the stop: the window that holds the
+    # stop token is cut to the ender's headroom
+    script = {0: _add(_live(n=40), stopper,
+                      GenRequest("ender", [9, 8, 7], max_tokens=k + 2,
+                                 temperature=0.0, ignore_eos=True))}
+    read = []
+    materialize = eng._materialize_window
+
+    def watched(pw):
+        events = materialize(pw)
+        if any(ev.request_id == "stopper" and ev.finished for ev in events):
+            read.append((pw.lag, pw.chunk, len(eng._held)))
+        return events
+
+    eng._materialize_window = watched
+    try:
+        out = _drive(eng, script)
+    finally:
+        del eng._materialize_window
+    _same(out, _drive(sync, script))
+    assert out["stopper"]["tokens"] == want
+    assert out["stopper"]["finish"] == "stop"
+    (lag, chunk, held), = read
+    assert chunk is None and 1 < lag < eng.cfg.num_scheduler_steps
+    assert held == 1 and eng.metrics.held_pages_peak > 0
+    assert eng.metrics.windows["short"] >= 1
 
 
 # --- a first token rides the pipeline: the final chunk's program samples

@@ -17,8 +17,9 @@ from dynamo_tpu.models import llama
 from dynamo_tpu.models.reference import deepseek_v32 as ref
 
 from tests.deepseek_v32_common import PS, TOPK, ref_config, tapped, tiny
-from tests.pipelined_common import (assert_finish_rides_pipeline,
-                                    assert_pipelined_matches_sync)
+from tests.pipelined_common import (
+    assert_finish_rides_pipeline, assert_pipelined_matches_sync,
+    assert_windows_as_long_as_the_shortest_headroom)
 
 
 def _engine(**kw):
@@ -115,6 +116,19 @@ def test_a_finish_rides_the_pipeline(pair):
     are the synchronous order's."""
     assert_finish_rides_pipeline(
         *pair, lambda i: SHARED[:18 + i] + [70 + i, 71, 72])
+
+
+def test_windows_of_every_length_give_the_single_steps_tokens(pair):
+    """Rows end at every offset of a window, so the fused program runs at
+    every trip count 1 .. 4, its rows selecting at contexts past
+    `index_topk`: tokens, logprobs, `metrics.dsa` and `metrics.attn` are
+    those of a classic program a step (num_scheduler_steps=1), in both
+    orders."""
+    single = _engine(async_scheduling=False, enable_prefix_caching=False,
+                     num_scheduler_steps=1)
+    assert_windows_as_long_as_the_shortest_headroom(
+        single, list(pair),
+        lambda i: SHARED[:18 + i % 3] + [70 + i, 71, 72])
 
 
 def test_metrics_dsa_arithmetic():

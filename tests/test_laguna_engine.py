@@ -18,8 +18,9 @@ from dynamo_tpu.engine.request import GenRequest
 from dynamo_tpu.models.reference import laguna_s as ref
 from dynamo_tpu.observability.memory import MemoryAccountant
 
-from pipelined_common import (assert_finish_rides_pipeline,
-                              assert_pipelined_matches_sync)
+from pipelined_common import (
+    assert_finish_rides_pipeline, assert_pipelined_matches_sync,
+    assert_windows_as_long_as_the_shortest_headroom)
 from test_laguna import hf_dict, tiny
 
 CFG = dict(model="tiny-laguna-debug", page_size=4, num_pages=128,
@@ -208,6 +209,20 @@ def test_warmup_compiles_what_the_window_runs(engine):
 def test_what_pools_by_kind_do_not_serve_is_refused(change, word):
     with pytest.raises(ValueError, match=word):
         Engine(EngineConfig(**{**CFG, **change}), model_cfg=tiny())
+
+
+def test_windows_of_every_length_give_the_single_steps_tokens(
+        sync_engine, engine):
+    """Rows end at every offset of a window, so the fused program runs at
+    every trip count 1 .. 4: the sliding layers' rings are grown for the
+    window's own length and written as far.
+    Tokens, logprobs and the counters are those of a classic program a
+    step (num_scheduler_steps=1), in both orders."""
+    single = Engine(EngineConfig(**{**CFG, "num_scheduler_steps": 1,
+                                    "async_scheduling": False}))
+    assert_windows_as_long_as_the_shortest_headroom(
+        single, [sync_engine, engine],
+        lambda i: prompt(100 + i, 5 + i % 3))
 
 
 def test_a_finish_rides_the_pipeline(sync_engine, engine):

@@ -23,8 +23,9 @@ from dynamo_tpu.observability.memory import MemoryAccountant
 
 from lfm2_moe_common import hf_dict, tiny
 
-from pipelined_common import (assert_finish_rides_pipeline,
-                              assert_first_token_rides_pipeline)
+from pipelined_common import (
+    assert_finish_rides_pipeline, assert_first_token_rides_pipeline,
+    assert_windows_as_long_as_the_shortest_headroom)
 
 CFG = dict(model="tiny-lfm2-moe-debug", page_size=4, num_pages=128,
            max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
@@ -281,6 +282,20 @@ def test_warmup_compiles_what_the_window_runs(engine):
 def test_what_a_state_slot_does_not_serve_is_refused(change, word):
     with pytest.raises(ValueError, match=word):
         Engine(EngineConfig(**{**CFG, **change}), model_cfg=tiny())
+
+
+def test_windows_of_every_length_give_the_single_steps_tokens(
+        sync_engine, engine):
+    """Rows end at every offset of a window, so the fused program runs at
+    every trip count 1 .. 4: a slot's two conv rows are those of the
+    last step run, and the experts' counts are summed in the loop's carry.
+    Tokens, logprobs and the counters are those of a classic program a
+    step (num_scheduler_steps=1), in both orders."""
+    single = Engine(EngineConfig(**{**CFG, "num_scheduler_steps": 1,
+                                    "async_scheduling": False}))
+    assert_windows_as_long_as_the_shortest_headroom(
+        single, [sync_engine, engine],
+        lambda i: prompt(100 + i, 5 + i % 3))
 
 
 def test_a_finish_rides_the_pipeline(sync_engine, engine):

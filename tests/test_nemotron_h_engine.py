@@ -21,8 +21,9 @@ from dynamo_tpu.observability.memory import MemoryAccountant
 
 from nemotron_h_common import hf_dict, tiny
 
-from pipelined_common import (assert_finish_rides_pipeline,
-                              assert_first_token_rides_pipeline)
+from pipelined_common import (
+    assert_finish_rides_pipeline, assert_first_token_rides_pipeline,
+    assert_windows_as_long_as_the_shortest_headroom)
 
 CFG = dict(model="tiny-nemotron-h-debug", page_size=4, num_pages=128,
            max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
@@ -309,6 +310,20 @@ def test_a_finish_rides_the_pipeline(sync_engine, engine):
     `metrics.ssm` are the synchronous order's."""
     assert_finish_rides_pipeline(sync_engine, engine,
                                  lambda i: prompt(40 + i, 5 + i))
+
+def test_windows_of_every_length_give_the_single_steps_tokens(
+        sync_engine, engine):
+    """Rows end at every offset of a window, so the fused program runs at
+    every trip count 1 .. 4: the Mamba-2 state slots' recurrence
+    stops where the loop stops, the live slots' list is built once a window.
+    Tokens, logprobs and the counters are those of a classic program a
+    step (num_scheduler_steps=1), in both orders."""
+    single = Engine(EngineConfig(**{**CFG, "num_scheduler_steps": 1,
+                                    "async_scheduling": False}))
+    assert_windows_as_long_as_the_shortest_headroom(
+        single, [sync_engine, engine],
+        lambda i: prompt(100 + i, 5 + i % 3))
+
 
 def test_a_finish_rides_the_pipeline_over_the_live_slots_kernel(
         kernel_sync_engine, kernel_engine):

@@ -519,28 +519,49 @@ def test_prefill_kernel_compiles_for_v5e_at_nine_heads_a_kv_head(one_chip):
 
 
 def _hybrid_window(cfg):
-    """A hybrid model's 16-step decode window as the engine runs it: the
-    step in a `lax.scan`, the pools and states its carry, the live slots'
-    list built once for all its steps."""
+    """A hybrid model's fused decode window as the engine runs it
+    (engine.make_decode_window): the step in a loop whose trip count is
+    the last operand (1 .. 16), each step's tokens written into their row
+    of a preallocated [16, B] result, the pools and states its carry, the
+    live slots' list built once for all its steps."""
     import jax
     import jax.numpy as jnp
 
     from dynamo_tpu.models import llama
 
-    def window(params, tokens, positions, tables, lens, kp, vp):
+    def window(params, tokens, positions, tables, lens, kp, vp, steps):
         slots = llama.live_state_slots(cfg, tables)
 
-        def body(carry, _):
-            toks, pos, ctx, kp, vp = carry
+        def body(i, loop):
+            (toks, pos, ctx, kp, vp), ys = loop
             out = llama.decode_step(
                 cfg, params, toks, pos, tables, ctx, kp, vp,
                 page_size=PAGE, state_slots=slots)
             nxt = jnp.argmax(out.logits, axis=-1).astype(jnp.int32)
-            return (nxt, pos + 1, ctx + 1, out.k_pages, out.v_pages), nxt
+            return ((nxt, pos + 1, ctx + 1, out.k_pages, out.v_pages),
+                    ys.at[i].set(nxt))
 
-        return jax.lax.scan(body, (tokens, positions, lens, kp, vp), None,
-                            length=16)
+        return jax.lax.fori_loop(
+            0, steps, body,
+            ((tokens, positions, lens, kp, vp),
+             jnp.zeros((16,) + tokens.shape, jnp.int32)))
     return window
+
+
+def _loops_bounded_by_an_operand(text):
+    """The `while` operations of a compiled program whose condition holds
+    no constant, so that the counter is compared with a value of the
+    loop's own tuple: a fused window's loop, whose trip count is an
+    operand, and no other loop of a step program (a layer scan's condition
+    compares with `constant(<layers>)`)."""
+    import re
+
+    found = []
+    for name in re.findall(r" while\(.*?condition=%([\w.\-]+)", text):
+        cond = text.split("\n%" + name + " (", 1)[1].split("\n}", 1)[0]
+        if "constant(" not in cond:
+            found.append(name)
+    return found
 
 
 # NVIDIA-Nemotron-3-Nano's cut (PR 42): 64 slots = 64 state slots, a decode
@@ -554,8 +575,9 @@ NEMOTRON_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
 @pytest.mark.parametrize("program", ["decode", "mixed", "window"])
 def test_nemotron_step_compiles_for_v5e_under_its_scope_names(one_chip,
                                                               program):
-    """The cut model's whole decode step, mixed step and a 16-step window
-    (the step in a `lax.scan`, the pools and states its donated carry) at
+    """The cut model's whole decode step, mixed step and a fused window of
+    up to 16 steps (the step in a loop whose trip count is an operand, the
+    pools and states its donated carry) at
     the cell's sizes (w8a8, 64 slots and their states, a 256-token chunk),
     for a described v5e: no op leaves the kernels (16 query heads over each
     of 2 KV heads, 256 lanes a row, no rotary; the state update over the
@@ -618,7 +640,7 @@ def test_nemotron_step_compiles_for_v5e_under_its_scope_names(one_chip,
             compiled = jax.jit(_hybrid_window(cfg),
                                donate_argnums=(5, 6)).lower(
                 params, i32(b), i32(b), i32(b, NEMOTRON_TABLE), i32(b), kp,
-                vp).compile()
+                vp, i32()).compile()
         else:
             compiled = jax.jit(functools.partial(
                 llama.mixed_step, cfg, page_size=PAGE),
@@ -637,6 +659,8 @@ def test_nemotron_step_compiles_for_v5e_under_its_scope_names(one_chip,
     # rows (buffer assignment read at PR 43): no state among them
     assert compiled.memory_analysis().temp_size_in_bytes < (
         0.1e9 if program == "mixed" else 0.06e9)
+    # the window's loop alone is bounded by an operand (its trip count)
+    assert len(_loops_bounded_by_an_operand(text)) == (program == "window")
     updates = [line for line in text.splitlines()
                if "tpu_custom_call" in line and "ssm_update_live" in line]
     assert len(updates) == 4  # one a Mamba-2 layer, in a window's body too
@@ -745,7 +769,7 @@ def test_falcon_h1_step_compiles_for_v5e_under_its_scope_names(one_chip,
             compiled = jax.jit(_hybrid_window(cfg),
                                donate_argnums=(5, 6)).lower(
                 params, i32(b), i32(b), i32(b, FALCON_TABLE), i32(b), kp,
-                vp).compile()
+                vp, i32()).compile()
         elif program == "mixed":
             compiled = jax.jit(functools.partial(
                 llama.mixed_step, cfg, page_size=PAGE),
@@ -775,6 +799,15 @@ def test_falcon_h1_step_compiles_for_v5e_under_its_scope_names(one_chip,
     assert 11.6e9 < mem.argument_size_in_bytes < 11.8e9
     assert mem.temp_size_in_bytes < {
         "window": 0.6e9, "prefill": 0.9e9}.get(program, 0.05e9)
+    # the window's loop is bounded by its trip count, an operand (PR 55):
+    # what it copies is the list above, all of it in the entry computation
+    # (once a window whatever its length), and it holds 65,536 bytes more
+    # than the 16-step scan it replaced (558,939,136), the [16, B] result
+    assert len(_loops_bounded_by_an_operand(text)) == (program == "window")
+    if program == "window":
+        assert mem.temp_size_in_bytes < 0.56e9
+        body = text.split("\nENTRY ", 1)[0]
+        assert not re.search(r"s8\[10,\S* copy\(", body)
     if program == "prefill":
         return  # a prompt alone runs the chunked scan, not the update
     updates = [line for line in text.splitlines()
@@ -1037,7 +1070,7 @@ def test_lfm2_step_compiles_for_v5e_under_its_scope_names(one_chip, program):
             compiled = jax.jit(_hybrid_window(cfg),
                                donate_argnums=(5, 6)).lower(
                 params, i32(b), i32(b), i32(b, LFM2_TABLE), i32(b), kp,
-                vp).compile()
+                vp, i32()).compile()
         elif program == "mixed":
             compiled = jax.jit(functools.partial(
                 llama.mixed_step, cfg, page_size=PAGE),
@@ -1074,6 +1107,8 @@ def test_lfm2_step_compiles_for_v5e_under_its_scope_names(one_chip, program):
     # two layer scans (the window's own scan around them) and the
     # operator's conditional in the expert layers' scan alone
     assert text.count(" while(") == (3 if program == "window" else 2)
+    # of them the window's own is bounded by an operand, its trip count
+    assert len(_loops_bounded_by_an_operand(text)) == (program == "window")
     assert len(re.findall(r" conditional\(", text)) >= 1
     assert "true_computation" in text or "branch_computations" in text
     # no program copies a pool: the whole-prompt prefill runs as ONE chunk

@@ -56,6 +56,8 @@ from dynamo_tpu.ops import attention as att_ops
 from dynamo_tpu.ops import json_guide
 from dynamo_tpu.ops import ssm as ssm_ops
 from dynamo_tpu.ops.moe import MOE_STATS
+from dynamo_tpu.ops import sparse_blocks
+from dynamo_tpu.ops.sparse_blocks import CHUNK_STATS
 from dynamo_tpu.models.config import SLIDING, ModelConfig
 from dynamo_tpu.parallel.mesh import MeshConfig, build_mesh
 from dynamo_tpu.parallel import sharding as shd
@@ -330,6 +332,26 @@ class EngineMetrics:
             "decode_queries": 0, "decode_keys_scored": 0,
             "chunk_queries": 0, "chunk_keys_scored": 0,
             "rows_selected": 0, "queries_unselected": 0}
+        # what the block-sparse attention layers of a minicpm_sala model were
+        # asked for (all zero for any other model), counted like `attn`: on
+        # the host at dispatch, a layer's worth. decode_rows: live rows x
+        # steps; dense_rows: those at or under sparse_dense_len (they attend
+        # their whole context); a row past it scores keys_scored pooled
+        # keys, attends blocks_selected blocks (blocks_forced of them the
+        # initial ones and the local window) holding rows_attended rows of
+        # the rows_in_context it has (a dense row: all of them);
+        # chunk_calls: chunks whose last query stood past sparse_dense_len
+        # (their queries select each their own blocks); chunk_queries,
+        # chunk_keys_scored, chunk_rows_attended: the same three of the
+        # chunks' real queries, whatever their program. The last two come
+        # from the device (ops/sparse_blocks.CHUNK_STATS, summed over the
+        # sparse layers): tiles of the chunks' masked attention that were
+        # attended, and those no query of the chunk had selected
+        self.sparse: Dict[str, int] = dict.fromkeys(
+            ("decode_rows", "dense_rows", "keys_scored", "blocks_selected",
+             "blocks_forced", "rows_attended", "rows_in_context",
+             "chunk_calls", "chunk_queries", "chunk_keys_scored",
+             "chunk_rows_attended") + CHUNK_STATS, 0)
         # all zero for a model whose expert layers do not count
         self.moe: Dict[str, int] = dict.fromkeys(MOE_STATS, 0)
         self._moe_pending: list = []
@@ -423,6 +445,41 @@ class EngineMetrics:
             if selects:
                 d["rows_selected"] += int(np.minimum(c, topk).sum())
 
+    def observe_sparse(self, sz, contexts=(), steps: int = 1,
+                       chunk=None) -> None:
+        """One dispatch of a model with block-sparse attention layers whose
+        decode table can hold a context past `sz.dense_len` (`sz`:
+        ops/sparse_blocks.Sizes): decode rows whose contexts (the token
+        being decoded included) are `contexts` at the first of `steps`
+        steps, and `chunk` = (start, take) prompt tokens."""
+        d = self.sparse
+
+        def attended(ctx):
+            """(rows past dense_len, KV rows all of `ctx` attend a head)."""
+            far = ctx[ctx > sz.dense_len]
+            return far, int(ctx.sum() - far.sum() + (
+                (sz.picked - 1) * sz.block + far
+                - (far - 1) // sz.block * sz.block).sum())
+
+        ctx = (np.asarray(list(contexts), np.int64)[:, None]
+               + np.arange(steps, dtype=np.int64)[None, :]).reshape(-1)
+        far, rows = attended(ctx)
+        d["decode_rows"] += int(ctx.size)
+        d["dense_rows"] += int(ctx.size - far.size)
+        d["rows_in_context"] += int(ctx.sum())
+        d["keys_scored"] += int((far // sz.stride - 1).sum())
+        d["blocks_selected"] += int(far.size) * sz.picked
+        d["blocks_forced"] += int(far.size) * (sz.init_blocks
+                                               + sz.window_blocks)
+        d["rows_attended"] += rows
+        if chunk is not None:
+            start, take = chunk
+            far, rows = attended(start + 1 + np.arange(take, dtype=np.int64))
+            d["chunk_calls"] += int(start + take > sz.dense_len)
+            d["chunk_queries"] += int(take)
+            d["chunk_keys_scored"] += int((far // sz.stride - 1).sum())
+            d["chunk_rows_attended"] += rows
+
     def observe_ssm(self, decode_rows: int, steps: int,
                     chunk_tokens: int = 0, slots_touched: int = 0,
                     conv: bool = False) -> None:
@@ -430,7 +487,7 @@ class EngineMetrics:
         `steps` steps, each step's update touching `slots_touched` state
         slots, and `chunk_tokens` of a prompt (0: no chunk); counted under
         `ssm`, or under `conv` where the state layers are short
-        convolutions (ModelConfig.operator_ffn)."""
+        convolutions (ModelConfig.conv_state)."""
         s = self.conv if conv else self.ssm
         s["decode_rows"] += decode_rows * steps
         s["layer_steps"] += steps
@@ -439,11 +496,11 @@ class EngineMetrics:
             s["chunk_tokens"] += chunk_tokens
             s["chunk_calls"] += 1
 
-    def observe_moe(self, stats) -> None:
+    def observe_moe(self, stats, into: str = "moe") -> None:
         """One program's expert-layer counts (a device array, maybe still
-        being computed)."""
+        being computed); `into` "sparse": its sparse layers' CHUNK_STATS."""
         with self._moe_lock:
-            self._moe_pending.append(stats)
+            self._moe_pending.append((into, stats))
             if len(self._moe_pending) < 256:
                 return
             # the oldest finished long ago: reading them cannot wait
@@ -454,11 +511,16 @@ class EngineMetrics:
     def _fold_moe(self, done: list) -> None:
         """Read the counts back (outside the lock: the newest may still be
         computed, and the step loop must not queue behind a reader)."""
-        sums = np.sum([np.asarray(st) for st in done], axis=0,
-                      dtype=np.int64) if done else ()
+        by: Dict[str, list] = {}
+        for into, st in done:
+            by.setdefault(into, []).append(np.asarray(st))
         with self._moe_lock:
-            for key, v in zip(self.moe, sums):
-                self.moe[key] += int(v)
+            for into, counted in by.items():
+                target = getattr(self, into)
+                names = MOE_STATS if into == "moe" else CHUNK_STATS
+                for key, v in zip(names, np.sum(counted, axis=0,
+                                                dtype=np.int64)):
+                    target[key] += int(v)
 
     def observe_first_token(self, submit_s: float, queue_s: float,
                             prefill_s: float, emit_s: float) -> None:
@@ -542,6 +604,7 @@ class EngineMetrics:
                 "attn_kinds": {k: dict(v)
                                for k, v in self.attn_kinds.items()},
                 "dsa": dict(self.dsa), "moe": dict(self.moe),
+                "sparse": dict(self.sparse),
                 "ssm": dict(self.ssm), "conv": dict(self.conv),
                 "admit_blocked": dict(self.admit_blocked)}
 
@@ -552,7 +615,7 @@ class EngineMetrics:
                             "spec_accepted_by", "spec_hist_by",
                             "spec_sum_by", "spec_count_by",
                             "first_token", "_first_token_lock", "moe", "attn",
-                            "attn_kinds", "dsa", "ssm", "conv",
+                            "attn_kinds", "dsa", "sparse", "ssm", "conv",
                             "admit_blocked",
                             "_moe_pending", "_moe_lock")}
         out.update(self.kernel_counters())
@@ -755,6 +818,8 @@ class Engine:
                 raise ValueError(
                     "a hybrid model (mixer_types: state-space layers) is "
                     f"not served with: {'; '.join(unserved)}")
+            if model_cfg.is_sala:
+                sparse_blocks.check_page_size(model_cfg, cfg.page_size)
         if cfg.sequence_parallel > 1:
             # long-context serving: prefill shards the sequence over the
             # `seq` axis (ring/Ulysses over ICI); params/KV shard on
@@ -839,6 +904,11 @@ class Engine:
                 2 * max(1, cfg.num_scheduler_steps) + ps),
             # a hybrid model: a state slot a decode slot
             state_slots=cfg.max_num_seqs,
+            # block-sparse layers: a row of key sums a page of the widest
+            # table a sequence's chunks are handed
+            pooled_key_pages=cfg.max_pages_per_seq + att_ops.chunk_table_tail(
+                max(cfg.prefill_chunk_tokens, cfg.mixed_batch_tokens),
+                cfg.page_size) + 1,
         )
         # MLA pools replicate across the model axis (every TP shard scores
         # its local heads against the FULL shared latent row); classic
@@ -1184,7 +1254,10 @@ class Engine:
         # has every step program return the counts last; `program` takes
         # them off again at each call, so call sites see the same tuples
         # for every model
-        counts_moe = mcfg.moe_grouped
+        # (a minicpm_sala model's sparse layers' CHUNK_STATS ride the same
+        # place into metrics.sparse)
+        counts_moe = mcfg.moe_grouped or mcfg.is_sala
+        n_counts = len(CHUNK_STATS if mcfg.is_sala else MOE_STATS)
 
         def moe_tail(stats):
             return (rep(stats),) if counts_moe else ()
@@ -1348,7 +1421,7 @@ class Engine:
                 ys0 = jax.tree.map(
                     lambda a: jnp.zeros((n_steps,) + a.shape, a.dtype),
                     rows_like)
-                st0 = (jnp.zeros((len(MOE_STATS),), jnp.int32)
+                st0 = (jnp.zeros((n_counts,), jnp.int32)
                        if counts_moe else None)
 
                 def step_at(i, loop):
@@ -1602,7 +1675,7 @@ class Engine:
         # whether a hybrid model's state updates walk the live slots only
         # (the kernel) or every slot (its XLA twin): metrics.ssm
         self._ssm_live_only = False
-        if mcfg.mixer_types and not mcfg.operator_ffn:
+        if mcfg.mixer_types and not mcfg.conv_state:
             with att_ops.attention_context(backend, mesh, lane_blocks):
                 self._ssm_live_only = ssm_ops.update_backend(
                     self.kv_spec.ssm_shape) != "xla"
@@ -1627,7 +1700,8 @@ class Engine:
                 with att_ops.attention_context(backend, mesh, lane_blocks):
                     out = fn(*args)
                 if steps and counts_moe:
-                    self.metrics.observe_moe(out[-1])
+                    self.metrics.observe_moe(
+                        out[-1], "sparse" if mcfg.is_sala else "moe")
                     out = out[:-1]
                 return out
 
@@ -3023,7 +3097,7 @@ class Engine:
             ph.done_when(last_logits, rows=1)
         if self.model_cfg.mixer_types:
             self.metrics.observe_ssm(0, 1, prompt_len,
-                                     conv=self.model_cfg.operator_ffn)
+                                     conv=self.model_cfg.conv_state)
         got = self._first_token_or_abort(events, req, pages, prompt_len,
                                          last_logits, "prefill")
         if got is None:
@@ -3462,7 +3536,9 @@ class Engine:
                 sink=SLIDING in self.model_cfg.attn_sink_kinds)
         if self.model_cfg.mixer_types:
             self.metrics.observe_ssm(0, 1, take,
-                                     conv=self.model_cfg.operator_ffn)
+                                     conv=self.model_cfg.conv_state)
+        if self.model_cfg.is_sala:
+            self._observe_sparse(chunk=(start, take))
         # this dispatch ran the chunk alone — its tenant owns the segment
         self._step_obs("prefill_chunk", dt, take=take,
                        shares={self._tenant_of(inf.req): float(take)})
@@ -4399,7 +4475,7 @@ class Engine:
             m.observe_ssm(len(slots), steps, take,
                           len(slots) if self._ssm_live_only
                           else self.cfg.max_num_seqs,
-                          conv=self.model_cfg.operator_ffn)
+                          conv=self.model_cfg.conv_state)
         if self.model_cfg.is_mla or kinds:  # read by the kernels' rooflines
             if contexts is None:
                 contexts = [self.seqs[s].num_tokens for s in slots
@@ -4417,7 +4493,20 @@ class Engine:
                 m.observe_dsa(self.model_cfg.index_topk,
                               self._dsa_selects(self.cfg.max_pages_per_seq),
                               contexts, steps, chunk)
+            if self.model_cfg.is_sala:
+                self._observe_sparse(contexts, steps, chunk)
         self._step_obs(kind, dt, take=take)
+
+    def _observe_sparse(self, contexts=(), steps: int = 1,
+                        chunk=None) -> None:
+        """metrics.sparse for one dispatch of a minicpm_sala model. Its
+        decode rows select where their table can hold a context past
+        sparse_dense_len (llama._sparse_selects, from the same shapes);
+        under a narrower one every row attends densely."""
+        sz = sparse_blocks.sizes_of(self.model_cfg)
+        if self.cfg.max_pages_per_seq * self.cfg.page_size <= sz.dense_len:
+            sz = sz._replace(dense_len=1 << 62)
+        self.metrics.observe_sparse(sz, contexts, steps, chunk)
 
     def _dsa_selects(self, table_pages: int) -> bool:
         """Whether a program over a page table of `table_pages` pages runs

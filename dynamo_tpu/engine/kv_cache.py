@@ -76,6 +76,16 @@ full-batch decode step stays shape-static without masking scatter writes.
 
 Page size defaults to 16 — parity with the reference's SGLang flag
 (/root/reference/examples/deploy/sglang/agg.yaml:38-39).
+
+A fourth form (`minicpm_sala`: every layer block-sparse attention OR
+Lightning linear attention, then a dense FFN) keeps the Lightning layers'
+states as the slot (`ssm_shape` [H, D, D] float32, `conv_shape` empty: 50 MB a
+slot over 24 layers at the published sizes, kept at no block boundary) and a
+THIRD kind of cache for the sparse layers' pooled keys: one float32 row for
+every page a slot's sequence can hold, the sum of the keys that page holds
+(`pooled_key_layers` x `state_slots` x `pooled_key_pages`,
+`StatePools.pooled`), which follows the slot's life as the state does and is
+read with no gather (ops/sparse_blocks.py).
 """
 
 from __future__ import annotations
@@ -138,19 +148,29 @@ class KVCacheSpec:
     ssm_shape: tuple = ()
     conv_shape: tuple = ()
     state_stacked: bool = False
+    # block-sparse attention over mean-pooled keys (minicpm_sala): each of
+    # these layers keeps, beside its K pool, ONE float32 row for every page
+    # a decode slot's sequence can hold, the sum of the keys that page holds
+    # [pooled_key_layers, state_slots, pooled_key_pages, KV*D]: indexed by
+    # the slot and the page's place in the sequence, so that a row's pooled
+    # keys are read with no gather. It is written where the page's keys are
+    # written, needs no allocator and dies with the slot. 0: none.
+    pooled_key_layers: int = 0
+    pooled_key_pages: int = 0
 
     @staticmethod
     def from_model(
         cfg: ModelConfig, num_pages: int, page_size: int,
         kv_dtype: str = "auto", tensor_parallel: int = 1,
         window_slots: int = 0, window_ahead: int = 0,
-        state_slots: int = 0,
+        state_slots: int = 0, pooled_key_pages: int = 0,
     ) -> "KVCacheSpec":
         """`window_slots` / `window_ahead` (a model of kinds only): the
         sequences that may hold a ring at once and the tokens a step may
         write ahead of the oldest query in flight; they size the sliding
         layers' pool. `state_slots` (a hybrid model only): the decode
-        slots, a state slot each."""
+        slots, a state slot each. `pooled_key_pages` (block-sparse layers
+        only): the widest page table of a sequence."""
         if kv_dtype not in ("auto", "", "int8"):
             # only exactly "int8" takes the packed-scale quantized path;
             # any other narrow dtype would silently value-cast KV garbage
@@ -197,12 +217,18 @@ class KVCacheSpec:
             kinds = dict(  # the spec's fields of a hybrid model
                 state_layers=cfg.state_layers, state_slots=state_slots,
                 state_stacked=cfg.state_stacked,
-                ssm_shape=(() if cfg.operator_ffn else (
+                ssm_shape=(() if cfg.conv_state else (
                     cfg.mamba_num_heads, cfg.mamba_head_dim,
                     cfg.ssm_state_size)),
-                conv_shape=(cfg.conv_kernel - 1,
-                            cfg.hidden_size if cfg.operator_ffn
-                            else cfg.mamba_conv_dim))
+                # a Lightning layer keeps no conv rows
+                conv_shape=(() if cfg.is_sala else (
+                    cfg.conv_kernel - 1,
+                    cfg.hidden_size if cfg.conv_state
+                    else cfg.mamba_conv_dim)),
+                pooled_key_layers=(cfg.paged_layers if cfg.is_sala else 0),
+                pooled_key_pages=(pooled_key_pages if cfg.is_sala else 0))
+            if cfg.is_sala and pooled_key_pages <= 0:
+                raise ValueError("block-sparse layers need pooled_key_pages")
         blocks = 1 if cfg.is_mla else tensor_parallel
         if quantized and kv_heads % blocks != 0:
             raise ValueError(
@@ -310,9 +336,22 @@ class KVCacheSpec:
         if not self.state_layers:
             return 0
         ssm = int(np.prod(self.ssm_shape)) * 4 if self.ssm_shape else 0
-        return self.state_layers * (
-            ssm
-            + int(np.prod(self.conv_shape)) * jnp.dtype(self.dtype).itemsize)
+        conv = (int(np.prod(self.conv_shape))
+                * jnp.dtype(self.dtype).itemsize if self.conv_shape else 0)
+        return self.state_layers * (ssm + conv)
+
+    @property
+    def pooled_key_shape(self):
+        """The sparse layers' pooled-key sums (float32); None without."""
+        if not self.pooled_key_layers:
+            return None
+        return (self.pooled_key_layers, self.state_slots,
+                self.pooled_key_pages, self.lane_width)
+
+    def pooled_key_bytes(self) -> int:
+        """Bytes of the pooled-key sums over all slots (0 without)."""
+        shape = self.pooled_key_shape
+        return int(np.prod(shape)) * 4 if shape else 0
 
     @property
     def state_kept_at_blocks(self) -> bool:
@@ -392,11 +431,18 @@ def alloc_kv_pages(spec: KVCacheSpec, sharding=None):
             return tuple(one((spec.state_slots,))
                          for _ in range(spec.state_layers))
 
+        pooled = ()
+        if spec.pooled_key_layers:  # replicated, as the states are
+            sums = jnp.zeros(spec.pooled_key_shape, jnp.float32)
+            pooled = (sums if sharding is None else jax.device_put(
+                sums, jax.sharding.NamedSharding(
+                    sharding.mesh, jax.sharding.PartitionSpec())),)
         return (StatePools(put(spec.shape),
                            states(spec.ssm_shape, jnp.float32)
-                           if spec.ssm_shape else ()),
+                           if spec.ssm_shape else (), pooled),
                 StatePools(put(spec.v_shape),
-                           states(spec.conv_shape, jnp.dtype(spec.dtype))))
+                           states(spec.conv_shape, jnp.dtype(spec.dtype))
+                           if spec.conv_shape else ()))
     if spec.window_layers:
         from dynamo_tpu.models.llama import ByKind
 

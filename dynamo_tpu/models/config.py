@@ -111,7 +111,7 @@ _MAPPED_MODEL_TYPES = frozenset((
     "llama", "mistral", "qwen2", "qwen3", "qwen3_moe", "mixtral", "phi3",
     "gemma", "gemma2", "gemma3", "gemma3_text", "deepseek_v2", "deepseek_v3",
     "kimi_k2", "deepseek_v32", "nemotron_h", "falcon_h1", "laguna",
-    "mimo_v2", "lfm2_moe"))
+    "mimo_v2", "lfm2_moe", "minicpm_sala"))
 _MAPPED_ARCH_WORDS = ("Llama", "Mistral", "Qwen", "Mixtral", "Phi3", "Gemma",
                       "Deepseek", "Kimi")
 _KIND_KEYS = ("layer_types", "num_attention_heads_per_layer", "gating")
@@ -128,9 +128,20 @@ PARALLEL = "attention+mamba"
 # then an FFN (dense in the leading layers, experts behind them). The conv
 # keeps a state slot WITHOUT a recurrence: its last conv_kernel - 1 rows.
 CONV = "conv"
-MIXER_KINDS = (MAMBA, EXPERTS, ATTENTION, PARALLEL, CONV)
+# minicpm_sala's operator kinds (the same operator-then-FFN layer, every FFN
+# dense): `sparse`, MiniCPM4's InfLLM-v2 attention (GQA under q/k norms, no
+# rotary, an output gate; past `sparse_dense_len` tokens of context a query
+# attends the blocks it selects over mean-pooled keys and nothing else), and
+# `lightning`, Lightning linear attention (a decayed outer-product state
+# [heads, head_dim, head_dim] float32 a sequence: ops/ssm.py's recurrence
+# with x = v, B = k, C = q, dt = 1).
+SPARSE, LIGHTNING = "sparse", "lightning"
+MIXER_KINDS = (MAMBA, EXPERTS, ATTENTION, PARALLEL, CONV, SPARSE, LIGHTNING)
 # the kinds whose layers page KV, and those that keep a state slot
-PAGED_MIXERS, STATE_MIXERS = (ATTENTION, PARALLEL), (MAMBA, PARALLEL, CONV)
+PAGED_MIXERS = (ATTENTION, PARALLEL, SPARSE)
+STATE_MIXERS = (MAMBA, PARALLEL, CONV, LIGHTNING)
+# the operator kinds of an operator-then-FFN model, by family
+_LFM2_OPERATORS, _SALA_OPERATORS = (CONV, ATTENTION), (SPARSE, LIGHTNING)
 # two-matrix experts act(u W_up) W_down: the activations written down
 TWO_MATRIX_ACTS = ("relu2", "silu")
 
@@ -231,6 +242,8 @@ def _hybrid_from_hf(cfg: dict) -> dict:
         return _falcon_h1_from_hf(cfg)
     if cfg.get("model_type") in ("lfm2_moe", "lfm2"):
         return _lfm2_from_hf(cfg)
+    if cfg.get("model_type") == "minicpm_sala":
+        return _minicpm_sala_from_hf(cfg)
     if cfg.get("model_type") != "nemotron_h":
         return {}
     n = int(cfg["num_hidden_layers"])
@@ -359,6 +372,95 @@ def _lfm2_from_hf(cfg: dict) -> dict:
         dense_intermediate_size=int(cfg["intermediate_size"]) if dense else 0,
         # ASSUMED (ISSUE 52): the family ties its head to the embedding
         tie_word_embeddings=bool(cfg.get("tie_word_embeddings", True)),
+    )
+
+
+# MiniCPM4-8B's published `sparse_config` (the InfLLM-v2 sizes the
+# MiniCPM-SALA config does not repeat; ASSUMED, ISSUE 56): a 32-token mean
+# pool every 16 tokens, blocks of 64, the 64 best beside 1 initial block and
+# a 2,048-token local window, dense up to 8,192 tokens of context
+SALA_SPARSE_DEFAULTS = dict(
+    kernel_size=32, kernel_stride=16, block_size=64, topk=64, init_blocks=1,
+    window_size=2048, dense_len=8192)
+
+
+def _minicpm_sala_from_hf(cfg: dict) -> dict:
+    """The ModelConfig fields of `model_type: minicpm_sala`: every layer an
+    OPERATOR by `mixer_types` (`minicpm4`: InfLLM-v2 block-sparse GQA under
+    per-head q/k norms, no rotary, a sigmoid output gate; `lightning-attn`:
+    Lightning linear attention under q/k norms and a rotary, an output norm
+    and gate) and then a dense gated silu FFN; the MiniCPM family's three
+    muP scalars. Refuses, by the key's name, what the program would
+    otherwise serve as another model."""
+    def refuse(key, why):
+        raise ValueError(f"{key}={cfg.get(key)!r} is not implemented for "
+                         f"model_type 'minicpm_sala': {why}")
+
+    n = int(cfg["num_hidden_layers"])
+    kinds = tuple(cfg.get("mixer_types") or ())
+    if len(kinds) != n:
+        raise ValueError(f"mixer_types has {len(kinds)} entries for "
+                         f"num_hidden_layers={n}")
+    names = {"minicpm4": SPARSE, "lightning-attn": LIGHTNING}
+    if set(kinds) - set(names):
+        refuse("mixer_types", f"entries other than {sorted(names)}")
+    if cfg.get("attn_use_rope", False):
+        refuse("attn_use_rope", "the sparse layers are served without a "
+               "rotary (a selected block has no position of its own in the "
+               "page table a row attends)")
+    if not cfg.get("lightning_use_rope", True):
+        refuse("lightning_use_rope", "the Lightning layers are served "
+               "under a rotary only")
+    if not cfg.get("qk_norm", False):
+        refuse("qk_norm", "both operators are served under per-head q/k "
+               "norms only")
+    for key in ("use_output_gate", "use_output_norm", "attn_use_output_gate"):
+        if not cfg.get(key, False):
+            refuse(key, "the operators are served with their output gate "
+                   "and norm only")
+    if cfg.get("attention_bias"):
+        refuse("attention_bias", "the projections carry no bias")
+    if cfg.get("lightning_scale", "1/sqrt(d)") != "1/sqrt(d)":
+        refuse("lightning_scale", "q is scaled by 1 / sqrt(head_dim) only")
+    heads, hd = int(cfg["num_attention_heads"]), int(cfg["head_dim"])
+    lh = int(cfg.get("lightning_nh") or heads)
+    lkv = int(cfg.get("lightning_nkv") or lh)
+    lhd = int(cfg.get("lightning_head_dim") or hd)
+    if (lh, lhd) != (heads, hd):
+        refuse("lightning_nh", "the Lightning layers are served at the "
+               "attention's heads and head_dim (their output gate and W_o "
+               "are [hidden, heads * head_dim] alike)")
+    if lkv != lh:
+        refuse("lightning_nkv", "grouped Lightning keys are not served: a "
+               "head's state is its own k (x) v")
+    if cfg.get("rope_scaling"):
+        refuse("rope_scaling", "the rotary is served unscaled")
+    sparse = dict(SALA_SPARSE_DEFAULTS, **(cfg.get("sparse_config") or {}))
+    unknown = sorted(set(sparse) - set(SALA_SPARSE_DEFAULTS) - {
+        "use_nope", "dense_len_scale"})
+    if unknown:
+        refuse("sparse_config", f"keys {unknown} are not implemented")
+    return dict(
+        mixer_types=tuple(names[k] for k in kinds),
+        qk_norm=True,
+        rms_norm_eps=float(cfg.get("rms_norm_eps") or 1e-6),
+        rope_theta=float(cfg.get("rope_theta", 10000.0)),
+        rope_llama3_scaling=None, rope_yarn_scaling=None,
+        rope_longrope_scaling=None,
+        mamba_num_heads=lh, mamba_head_dim=lhd, mamba_n_groups=lkv,
+        ssm_state_size=lhd,
+        sparse_kernel_size=int(sparse["kernel_size"]),
+        sparse_kernel_stride=int(sparse["kernel_stride"]),
+        sparse_block_size=int(sparse["block_size"]),
+        sparse_topk=int(sparse["topk"]),
+        sparse_init_blocks=int(sparse["init_blocks"]),
+        sparse_window_size=int(sparse["window_size"]),
+        sparse_dense_len=int(sparse["dense_len"]),
+        scale_emb=float(cfg.get("scale_emb") or 1.0),
+        scale_depth=float(cfg.get("scale_depth") or 0.0),
+        dim_model_base=int(cfg.get("dim_model_base") or 0),
+        first_k_dense=0, dense_intermediate_size=0,
+        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)),
     )
 
 
@@ -758,6 +860,28 @@ class ModelConfig:
     ssm_chunk_size: int = 128
     expert_act: str = ""
     multipliers: Optional[Multipliers] = None
+    # minicpm_sala (SPARSE / LIGHTNING operators). The Lightning layers'
+    # heads ride mamba_num_heads / mamba_head_dim / ssm_state_size (the
+    # state is [heads, head_dim, head_dim] float32, a B / C row a head:
+    # mamba_n_groups == mamba_num_heads). sparse_*: InfLLM-v2's sizes (a
+    # mean pool of sparse_kernel_size tokens every sparse_kernel_stride;
+    # blocks of sparse_block_size; the sparse_topk best beside
+    # sparse_init_blocks leading blocks and the sparse_window_size tokens'
+    # blocks that end with the query's; dense up to sparse_dense_len).
+    # scale_emb / scale_depth / dim_model_base: the MiniCPM family's muP
+    # scalars (embedding x scale_emb, a residual branch x scale_depth /
+    # sqrt(layers), the final hidden / (hidden_size / dim_model_base));
+    # 1 / 0 / 0 = none of them.
+    sparse_kernel_size: int = 0
+    sparse_kernel_stride: int = 0
+    sparse_block_size: int = 0
+    sparse_topk: int = 0
+    sparse_init_blocks: int = 0
+    sparse_window_size: int = 0
+    sparse_dense_len: int = 0
+    scale_emb: float = 1.0
+    scale_depth: float = 0.0
+    dim_model_base: int = 0
     # dtype for params/compute (bfloat16 on TPU; float32 for CPU tests)
     dtype: str = "bfloat16"
     eos_token_id: int = 2
@@ -829,9 +953,12 @@ class ModelConfig:
                 "without layer_types: no layer would read them")
         if self.mixer_types:
             self._check_mixers()
-        elif self.mamba_num_heads or self.expert_act or self.multipliers:
-            raise ValueError("mamba_* / expert_act / multipliers without "
-                             "mixer_types: no layer would read them")
+        elif (self.mamba_num_heads or self.expert_act or self.multipliers
+              or self.sparse_block_size or self.scale_depth
+              or self.dim_model_base or self.scale_emb != 1.0):
+            raise ValueError("mamba_* / expert_act / multipliers / sparse_* "
+                             "/ scale_* without mixer_types: no layer would "
+                             "read them")
         held = self.num_local_experts
         if held and not (
                 self.is_moe and 0 <= self.local_expert_offset
@@ -965,9 +1092,54 @@ class ModelConfig:
                 "biases, sandwich norms, score capping, router groups, a "
                 "capacity factor")
 
+    def _check_sala(self) -> None:
+        """The operator-then-FFN form of minicpm_sala and what hangs on
+        it."""
+        kinds = set(self.mixer_types)
+        if kinds - set(_SALA_OPERATORS):
+            raise ValueError(
+                f"a model with a {SPARSE!r} or {LIGHTNING!r} layer has "
+                f"layers of these two kinds only, got {sorted(kinds)}")
+        if not (self.qk_norm and self.hidden_act == "silu"
+                and not self.is_moe and not self.expert_act
+                and self.multipliers is None and not self.conv_kernel):
+            raise ValueError(
+                f"{SPARSE!r} / {LIGHTNING!r} layers are served under q/k "
+                "norms with dense gated silu FFNs, without experts, "
+                "multipliers or a conv")
+        if LIGHTNING in kinds and not (
+                self.mamba_num_heads == self.mamba_n_groups == self.num_heads
+                and self.mamba_head_dim == self.ssm_state_size
+                == self.head_dim):
+            raise ValueError(
+                f"{LIGHTNING!r} layers are served at the attention's heads "
+                "and head_dim, a key row a head (mamba_num_heads == "
+                "mamba_n_groups == num_heads, mamba_head_dim == "
+                "ssm_state_size == head_dim)")
+        if SPARSE in kinds:
+            k, s, b = (self.sparse_kernel_size, self.sparse_kernel_stride,
+                       self.sparse_block_size)
+            picked = (self.sparse_init_blocks + self.sparse_topk
+                      + -(-self.sparse_window_size // max(b, 1)))
+            if not (s > 0 and k == 2 * s and b > 0 and b % s == 0
+                    and self.sparse_topk > 0 and self.sparse_init_blocks >= 0
+                    and self.sparse_window_size > 0
+                    and self.sparse_window_size % b == 0
+                    and self.sparse_dense_len % b == 0
+                    and self.sparse_dense_len >= picked * b - b):
+                raise ValueError(
+                    f"{SPARSE!r} layers are served with sparse_kernel_size "
+                    "== 2 x sparse_kernel_stride (a pooled key is two "
+                    "pages' sums), blocks and a window that are multiples "
+                    "of the stride and of a block, and a sparse_dense_len "
+                    "(a multiple of a block) past which every query has "
+                    "its init + window + top-k blocks to select")
+
     def _check_operators(self) -> None:
         """The operator-then-FFN form (lfm2_moe) and what hangs on it."""
         kinds = set(self.mixer_types)
+        if kinds & set(_SALA_OPERATORS):
+            return self._check_sala()
         if kinds - {CONV, ATTENTION}:
             raise ValueError(
                 f"a model with a {CONV!r} layer has layers of {CONV!r} and "
@@ -1000,9 +1172,30 @@ class ModelConfig:
 
     @property
     def operator_ffn(self) -> bool:
-        """Every layer an operator (a gated short conv | attention) and
-        then an FFN (lfm2_moe)."""
+        """Every layer an operator and then an FFN: a gated short conv |
+        attention (lfm2_moe), or block-sparse attention | Lightning linear
+        attention (minicpm_sala)."""
+        return self.conv_state or self.is_sala
+
+    @property
+    def conv_state(self) -> bool:
+        """The state layers are gated short convolutions (lfm2_moe): a
+        slot holds the conv's last rows and no recurrent state."""
         return CONV in self.mixer_types
+
+    @property
+    def is_sala(self) -> bool:
+        """minicpm_sala's operators: block-sparse attention | Lightning
+        linear attention (a recurrent state and no conv rows a slot; the
+        sparse layers keep a row of pooled-key sums a page)."""
+        return bool(set(self.mixer_types) & set(_SALA_OPERATORS))
+
+    @property
+    def sparse_picked_blocks(self) -> int:
+        """Blocks a query past sparse_dense_len attends: the initial ones,
+        the window's (the query's own last) and the top-k."""
+        return (self.sparse_init_blocks + self.sparse_topk
+                + self.sparse_window_size // self.sparse_block_size)
 
     @property
     def state_stacked(self) -> bool:
@@ -1925,3 +2118,25 @@ PRESETS["tiny-lfm2-moe-debug"] = ModelConfig(
 PRESETS["tiny-lfm2-moe-ep4-debug"] = dataclasses.replace(
     PRESETS["tiny-lfm2-moe-debug"], name="tiny-lfm2-moe-ep4-debug",
     num_local_experts=4, local_expert_offset=4)
+
+# MiniCPM-SALA's structure at a toy size: eight layers `S L L L S L L S`,
+# each an operator and then a dense gated FFN. S: InfLLM-v2 block-sparse GQA
+# (8 query heads over 2 KV heads of 32 lanes under q/k norms, no
+# rotary, an output gate; a mean pool of 8 tokens every 4 (4-token pages),
+# blocks of 16, top-2 beside 1 initial block and a 32-token window, dense up
+# to 64 tokens), so that every branch runs in under 300 tokens. L: Lightning
+# linear attention, 8 heads of 32 lanes under q/k norms and a rotary, a state
+# of [8, 32, 32] float32 a sequence. The three muP scalars set and distinct.
+PRESETS["tiny-minicpm-sala-debug"] = ModelConfig(
+    name="tiny-minicpm-sala-debug",
+    hidden_size=128, intermediate_size=256, num_layers=8, num_heads=8,
+    num_kv_heads=2, head_dim=32, qk_norm=True, rope_theta=10000.0,
+    rms_norm_eps=1e-6, tie_word_embeddings=False,
+    mixer_types=tuple({"S": SPARSE, "L": LIGHTNING}[c] for c in "SLLLSLLS"),
+    mamba_num_heads=8, mamba_head_dim=32, mamba_n_groups=8,
+    ssm_state_size=32, ssm_chunk_size=16,
+    sparse_kernel_size=8, sparse_kernel_stride=4, sparse_block_size=16,
+    sparse_topk=2, sparse_init_blocks=1, sparse_window_size=32,
+    sparse_dense_len=64,
+    scale_emb=12.0, scale_depth=1.4, dim_model_base=32,
+)

@@ -24,14 +24,17 @@ import math
 from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
+import numpy as np
 import jax.numpy as jnp
 
-from dynamo_tpu.models.config import (ATTENTION, CONV, EXPERTS, FULL, MAMBA,
-                                      SLIDING, ModelConfig)
+from dynamo_tpu.models.config import (ATTENTION, CONV, EXPERTS, FULL,
+                                      LIGHTNING, MAMBA, SLIDING, SPARSE,
+                                      ModelConfig)
 from dynamo_tpu.models import quant
 from dynamo_tpu.ops import attention as att
 from dynamo_tpu.ops import moe as moe_ops
 from dynamo_tpu.ops import short_conv
+from dynamo_tpu.ops import sparse_blocks as sparse_ops
 from dynamo_tpu.ops import ssm as ssm_ops
 from dynamo_tpu.ops.rope import apply_rope
 
@@ -62,6 +65,8 @@ def _embed_rows(cfg: ModelConfig, params: Params, tokens: jax.Array) -> jax.Arra
     if cfg.multipliers is not None:  # falcon_h1's embedding_multiplier
         x = (x.astype(jnp.float32)
              * cfg.multipliers.embedding).astype(x.dtype)
+    if cfg.scale_emb != 1.0:  # the MiniCPM family's scale_emb
+        x = (x.astype(jnp.float32) * cfg.scale_emb).astype(x.dtype)
     return x
 
 
@@ -156,6 +161,9 @@ def _post(cfg: ModelConfig, lp: Params, name: str, y: jax.Array) -> jax.Array:
 
 # leaf-name prefix of the leading dense layers' own parameter stack
 DENSE_PREFIX = "dense."
+# the Lightning layers' stack of a minicpm_sala model (quant.quant_axes reads
+# the name behind the last dot, as for DENSE_PREFIX)
+LIGHTNING_PREFIX = "lightning."
 # leaf-name prefix of the head-shaped attention leaves (wq, wo, wg, a sink)
 # by kind, where a model's layers are of more than one kind
 # (cfg.layer_types): the kinds' head counts may differ, so each kind stacks
@@ -1084,9 +1092,15 @@ class StatePools(NamedTuple):
     whole (64 x 4.2 MB a layer a step). A model whose state layers are
     short convolutions (cfg.operator_ffn) keeps NO recurrent state:
     `k_pages.state` is () and `v_pages.state` the one array of conv rows
-    [conv layers, slots, K-1, E]."""
+    [conv layers, slots, K-1, E]. A model of block-sparse and Lightning
+    layers (minicpm_sala) keeps the Lightning states as the one array of
+    `k_pages.state` [L_l, slots, H, D, D] float32, no conv rows, and in
+    `k_pages.pooled` ONE array of the sparse layers' pooled-key sums
+    [L_s, slots, pages a sequence, KV*D] float32 (a row a slot's page:
+    ops/sparse_blocks.py)."""
     pages: Any
     state: Any
+    pooled: Any = ()
 
 
 class SlotPages(NamedTuple):
@@ -1136,6 +1150,15 @@ _MIXER_LEAVES = {
 _OPERATOR_LEAVES = {
     CONV: ("conv_in", "conv_w", "conv_out"),
     ATTENTION: ("wq", "wk", "wv", "wo", "q_norm", "k_norm"),
+    # minicpm_sala: the sparse layers' stack under the plain names, the
+    # Lightning layers' under LIGHTNING_PREFIX (the same leaves and out_norm)
+    # (w_q / w_k / w_v: the projections with their heads side by side,
+    # [L, E, heads * D]. As [L, E, H, D] stacks of 128-lane heads the TPU
+    # compiler relaid every one out head-major in every step program, 0.4 GB
+    # a Lightning stack: compiled for a described v5e, PR 56)
+    SPARSE: ("w_q", "w_k", "w_v", "wo", "q_norm", "k_norm", "w_og"),
+    LIGHTNING: tuple(LIGHTNING_PREFIX + k for k in (
+        "w_q", "w_k", "w_v", "wo", "q_norm", "k_norm", "w_og", "out_norm")),
 }
 _DENSE_FFN = ("w_gate", "w_up", "w_down")
 _EXPERT_FFN = ("router", "router_bias")
@@ -1160,6 +1183,29 @@ def _operator_param_specs(cfg: ModelConfig):
         p["lm_head"] = w((e, cfg.vocab_size), 0.02)
     p["operator_norm"] = ((l, e), "ones", 0.0)
     p["ffn_norm"] = ((l, e), "ones", 0.0)
+    if cfg.is_sala:
+        # minicpm_sala: a stack a kind (the Lightning layers' k and v have
+        # as many heads as q), the output gates [E, H * D], every FFN dense
+        h, kv, d, f = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                       cfg.intermediate_size)
+        for pre, n, nk in (("", cfg.mixer_layers(SPARSE), kv),
+                           (LIGHTNING_PREFIX, cfg.mixer_layers(LIGHTNING),
+                            h)):
+            if not n:
+                continue
+            p[pre + "w_q"] = w((n, e, h * d), 1.0 / e ** 0.5)
+            p[pre + "w_k"] = w((n, e, nk * d), 1.0 / e ** 0.5)
+            p[pre + "w_v"] = w((n, e, nk * d), 1.0 / e ** 0.5)
+            p[pre + "wo"] = w((n, h, d, e), 1.0 / (h * d) ** 0.5)
+            p[pre + "q_norm"] = ((n, d), "ones", 0.0)
+            p[pre + "k_norm"] = ((n, d), "ones", 0.0)
+            p[pre + "w_og"] = w((n, e, h * d), 1.0 / e ** 0.5)
+            if pre:
+                p[pre + "out_norm"] = ((n, h * d), "ones", 0.0)
+        p["w_gate"] = w((l, e, f), 1.0 / e ** 0.5)
+        p["w_up"] = w((l, e, f), 1.0 / e ** 0.5)
+        p["w_down"] = w((l, f, e), 1.0 / f ** 0.5)
+        return p
     p["conv_in"] = w((lc, e, 3 * e), 1.0 / e ** 0.5)  # [B | C | u]
     p["conv_w"] = w((lc, cfg.conv_kernel, e), 1.0 / cfg.conv_kernel ** 0.5)
     p["conv_out"] = w((lc, e, e), 1.0 / e ** 0.5)
@@ -1469,48 +1515,154 @@ def _conv_operator(cfg: ModelConfig, lp: Params, h: jax.Array, conv,
         return qeinsum("te,ef->tf", y, lp["conv_out"]), conv
 
 
+def lightning_slopes(cfg: ModelConfig) -> jax.Array:
+    """[L, H] float32: the decay rate of head h (1 .. H) of layer l (0 .. L
+    - 1, counted over ALL layers) of a Lightning layer, 2^(-8 h / H) (1 - l
+    / (L - 1 + 1e-5) + 1e-5), as MiniMax-01's Lightning attention sets it
+    (transformers' MiniMaxLightningAttention.get_slope_rate)."""
+    h, l = cfg.mamba_num_heads, cfg.num_layers
+    # in float64 on the host (the config is static): the last layer's
+    # factor is 2e-5, what float32 leaves of 1 - (l - 1) / (l - 1 + 1e-5)
+    base = 2.0 ** (-8.0 * np.arange(1, h + 1) / h)
+    factor = 1.0 - np.arange(l) / (l - 1 + 1e-5) + 1e-5
+    return jnp.asarray(factor[:, None] * base[None, :], jnp.float32)
+
+
+def _gated(o: jax.Array, h: jax.Array, w_og) -> jax.Array:
+    """o [T, H * D] (float32) under minicpm_sala's output gate: o *
+    sigmoid(h W_g), lane by lane, in the model's dtype."""
+    with jax.named_scope("attn_gate"):
+        g = jax.nn.sigmoid(qeinsum("te,ef->tf", h, w_og).astype(jnp.float32))
+        return (o.astype(jnp.float32) * g).astype(h.dtype)
+
+
+def _lightning_operator(cfg: ModelConfig, lp: Params, h: jax.Array, state,
+                        decode, chunk, base, positions, slope):
+    """Lightning linear attention over h [T, E] (normed) -> (y [T, E],
+    state): S_t = e^(-s) S_(t-1) + k_t (x) v_t, o_t = q_t S_t a head, which
+    IS ops/ssm.py's recurrence with x = v, B = k, C = q, a = -s, D = 0 and
+    dt = 1 on a real row, 0 on padding. `state` is the pool of states over
+    (layer, slot) addressed flat, this layer's slot i at row base + i; the
+    rows are `decode` / `chunk` as _mamba_mixer's; `slope` [H] float32."""
+    hm, d = cfg.mamba_num_heads, cfg.mamba_head_dim
+    t = h.shape[0]
+    with jax.named_scope("lightning_in_proj"):
+        q, k, v = (qeinsum("te,ef->tf", h, lp[name]).reshape(t, hm, d)
+                   for name in ("w_q", "w_k", "w_v"))
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+        q = q * jnp.asarray(d ** -0.5, q.dtype)
+    a, zero = -slope, jnp.zeros((hm,), jnp.float32)
+    ys, off = [], 0
+    if decode is not None:
+        b, live, slots = decode
+        with jax.named_scope("lightning_update"):
+            y, state = ssm_ops.update(
+                v[:b], jnp.ones((b, hm), jnp.float32), a, k[:b], q[:b], zero,
+                state, live, slots, base=base)
+        ys.append(y)
+        off = b
+    if chunk is not None:
+        c, slot, n_valid, fresh = chunk
+        row = base + slot
+        with jax.named_scope("lightning_scan"):
+            prev = jax.lax.dynamic_index_in_dim(state, row, keepdims=False)
+            real = (jnp.arange(c) < n_valid).astype(jnp.float32)
+            y, final = ssm_ops.scan_chunked(
+                v[off:off + c], jnp.broadcast_to(real[:, None], (c, hm)), a,
+                k[off:off + c], q[off:off + c], zero,
+                jnp.where(fresh, jnp.zeros_like(prev), prev),
+                cfg.ssm_chunk_size)
+            state = jax.lax.dynamic_update_index_in_dim(state, final, row, 0)
+        ys.append(y)
+    y = (ys[0] if len(ys) == 1 else jnp.concatenate(ys)).reshape(t, hm * d)
+    with jax.named_scope("lightning_gate_norm"):
+        y = rms_norm(y, lp["out_norm"].astype(jnp.float32), cfg.rms_norm_eps)
+        y = _gated(y, h, lp["w_og"])
+    with jax.named_scope("lightning_out_proj"):
+        return qeinsum("thd,hde->te", y.reshape(t, hm, d), lp["wo"]), state
+
+
 def _operator_layers(cfg: ModelConfig, params: Params, x: jax.Array,
                      k_pages: StatePools, v_pages: StatePools, attend,
                      token_mask, positions, decode=None, chunk=None):
-    """_hybrid_layers for an operator-then-FFN model (lfm2_moe): layer l is
-    x += operator_l(norm(x)); x += ffn_l(norm(x)), the operator a gated
-    short convolution or attention by cfg.mixer_types, the FFN dense in the
-    first cfg.first_k_dense layers and the experts behind them.
+    """_hybrid_layers for an operator-then-FFN model: layer l is
+    x += operator_l(norm(x)); x += ffn_l(norm(x)). lfm2_moe: the operator a
+    gated short convolution or attention by cfg.mixer_types, the FFN dense
+    in the first cfg.first_k_dense layers and the experts behind them.
+    minicpm_sala: the operator block-sparse attention or Lightning linear
+    attention, every FFN dense, each branch under the family's residual
+    scale.
 
-    TWO scans, whatever the depth: the dense layers and the expert layers.
-    A scan whose layers are of both operator kinds takes the operator by a
-    `lax.cond` on the layer's kind (one body a kind, the kind's parameter
-    stack indexed by the layer's index within its kind); the pages and the
-    conv rows ride the carry FLAT as _parallel_layers carries them (layer
-    l's page p at row l * P + p, its slot b at row l * B + b), both through
-    either branch, so no pool and no stack is sliced out or copied. The
-    experts' whole stack and the layer's index go to the grouped matmul as
-    _scan_layers_paged hands them."""
+    A scan a run of layers with one FFN kind (lfm2_moe: the dense layers
+    and the expert layers; minicpm_sala: ONE). A scan whose layers are of
+    both operator kinds takes the operator by a `lax.cond` on the layer's
+    kind (one body a kind, the kind's parameter stack indexed by the
+    layer's index within its kind); the pages and the states (lfm2_moe: the
+    conv rows; minicpm_sala: the Lightning states and the sparse layers'
+    page sums, a row a slot) ride the carry FLAT as _parallel_layers carries
+    them (layer l's page p at row l * P + p, its slot b at row l * B + b), all
+    through either branch, so no pool and no stack is sliced out or copied.
+    The experts' whole stack and the layer's index go to the grouped matmul
+    as _scan_layers_paged hands them."""
     pool, vpool = k_pages.pages.shape, v_pages.pages.shape
-    (conv,) = v_pages.state
-    slots = conv.shape[1]
+    sala = cfg.is_sala
+    # the state arrays the operators carry, each over (layer, slot | page)
+    held = ((k_pages.state[0], k_pages.pooled[0]) if sala
+            else (v_pages.state[0],))
+    slots = held[0].shape[1]
     kinds, kd = cfg.mixer_types, cfg.first_k_dense
     # a layer's index among the layers of its kind
     within = [kinds[:i].count(k) for i, k in enumerate(kinds)]
-    stacks = {kind: {k: params[k] for k in leaves if k in params}
-              for kind, leaves in _OPERATOR_LEAVES.items()}
+    stacks = {kind: {k.rsplit(".", 1)[-1]: params[k] for k in leaves
+                     if k in params}
+              for kind, leaves in _OPERATOR_LEAVES.items() if kind in kinds}
+    # the cond's first branch, then its second
+    pair = (SPARSE, LIGHTNING) if sala else (CONV, ATTENTION)
 
     def at(kind, j):
         return {k: jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, j, keepdims=False), v)
             for k, v in stacks[kind].items()}
 
-    def conv_op(h, kp, vp, cp, j):
-        y, cp = _conv_operator(cfg, at(CONV, j), h, cp, decode, chunk,
+    def conv_op(h, kp, vp, st, j):
+        y, cp = _conv_operator(cfg, at(CONV, j), h, st[0], decode, chunk,
                                j * slots)
-        return y, kp, vp, cp
+        return y, kp, vp, (cp,)
 
-    def attn_op(h, kp, vp, cp, j):
+    def attn_op(h, kp, vp, st, j):
         lp = at(ATTENTION, j)
         q, k, v = _qkv(cfg, lp, h, positions)
         with jax.named_scope("attn_full"):
             o, kp, vp = attend(q, k, v, kp, vp, j * pool[1])
-        return _attn_out(cfg, lp, o), kp, vp, cp
+        return _attn_out(cfg, lp, o), kp, vp, st
+
+    def sparse_op(h, kp, vp, st, j, slope):
+        lp = at(SPARSE, j)
+        q, k, v = (qeinsum("te,ef->tf", h, lp[name]).reshape(
+            h.shape[0], -1, cfg.head_dim) for name in ("w_q", "w_k", "w_v"))
+        with jax.named_scope("attn_qk_norm"):  # and no rotary
+            q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
+        with jax.named_scope("attn_sparse"):
+            o, kp, vp, sums, seen = attend(q, k, v, kp, vp, j * pool[1],
+                                           st[1], j * slots)
+        o = _gated(o.reshape(o.shape[0], -1), h, lp["w_og"])
+        y = qeinsum("thd,hde->te", o.reshape(q.shape), lp["wo"])
+        return y, kp, vp, (st[0], sums, st[2] + seen)
+
+    def lightning_op(h, kp, vp, st, j, slope):
+        y, sp = _lightning_operator(cfg, at(LIGHTNING, j), h, st[0], decode,
+                                    chunk, j * slots, positions, slope)
+        return y, kp, vp, (sp,) + st[1:]
+
+    ops = {CONV: conv_op, ATTENTION: attn_op, SPARSE: sparse_op,
+           LIGHTNING: lightning_op}
+    # minicpm_sala's residual scale (scale_depth / sqrt(layers))
+    branch = (jnp.asarray(cfg.scale_depth / cfg.num_layers ** 0.5,
+                          x.dtype) if cfg.scale_depth else None)
 
     def segment(carry, first, last, ffn, scanned):
         """Layers [first, last) as one scan; ffn(xs, h) -> (y, counts) with
@@ -1518,28 +1670,32 @@ def _operator_layers(cfg: ModelConfig, params: Params, x: jax.Array,
         of = set(kinds[first:last])
 
         def layer(carry, xs):
-            x, kp, vp, cp, counts = carry
+            x, kp, vp, st, counts = carry
             h = rms_norm(x, xs["operator_norm"], cfg.rms_norm_eps)
+            extra = (xs["slope"],) if sala else ()
             if len(of) == 1:
-                op = conv_op if of == {CONV} else attn_op
-                y, kp, vp, cp = op(h, kp, vp, cp, xs["within"])
+                y, kp, vp, st = ops[min(of, key=pair.index)](
+                    h, kp, vp, st, xs["within"], *extra)
             else:
-                y, kp, vp, cp = jax.lax.cond(
-                    xs["is_conv"], conv_op, attn_op, h, kp, vp, cp,
-                    xs["within"])
-            x = x + y
+                y, kp, vp, st = jax.lax.cond(
+                    xs["is_first"], ops[pair[0]], ops[pair[1]], h, kp, vp,
+                    st, xs["within"], *extra)
+            x = x + (y if branch is None else y * branch)
             y, c = ffn(xs, rms_norm(x, xs["ffn_norm"], cfg.rms_norm_eps))
             if c is not None:
                 counts = counts + c
-            return (x + y, kp, vp, cp, counts), None
+            return (x + (y if branch is None else y * branch), kp, vp, st,
+                    counts), None
 
         xs = {"operator_norm": params["operator_norm"][first:last],
               "ffn_norm": params["ffn_norm"][first:last],
-              "is_conv": jnp.asarray(
-                  [k == CONV for k in kinds[first:last]]),
+              "is_first": jnp.asarray(
+                  [k == pair[0] for k in kinds[first:last]]),
               "within": jnp.asarray(within[first:last], jnp.int32),
               "ffn_layer": jnp.arange(last - first, dtype=jnp.int32),
               **scanned}
+        if sala:
+            xs["slope"] = lightning_slopes(cfg)[first:last]
         return jax.lax.scan(layer, carry, xs)[0]
 
     def dense_ffn(xs, h):
@@ -1552,19 +1708,33 @@ def _operator_layers(cfg: ModelConfig, params: Params, x: jax.Array,
                   moe_layer=xs["ffn_layer"])
         return _mlp(cfg, lp, h, token_mask=token_mask)
 
-    carry = (x, k_pages.pages.reshape((-1,) + pool[2:]),
-             v_pages.pages.reshape((-1,) + vpool[2:]),
-             conv.reshape((-1,) + conv.shape[2:]),
+    def every_ffn(xs, h):
+        with jax.named_scope("mlp_dense"):
+            return _mlp(cfg, {k: xs[k] for k in _DENSE_FFN}, h)
+
+    kp = k_pages.pages.reshape((-1,) + pool[2:])
+    vp = v_pages.pages.reshape((-1,) + vpool[2:])
+    flat = tuple(a.reshape((-1,) + a.shape[2:]) for a in held)
+    if sala:  # and what the chunks' masked attention visited and skipped
+        flat += (jnp.zeros((len(sparse_ops.CHUNK_STATS),), jnp.int32),)
+    carry = (x, kp, vp, flat,
              jnp.zeros((len(moe_ops.MOE_STATS),), jnp.int32))
+    if sala:
+        carry = segment(carry, 0, cfg.num_layers, every_ffn,
+                        {k: params[k] for k in _DENSE_FFN})
+        x, kp, vp, (sp, sums, seen), _ = carry
+        return (x, StatePools(kp.reshape(pool), (sp.reshape(held[0].shape),),
+                              (sums.reshape(held[1].shape),)),
+                StatePools(vp.reshape(vpool), ()), seen)
     if kd:
         carry = segment(carry, 0, kd, dense_ffn, {
             DENSE_PREFIX + k: params[DENSE_PREFIX + k] for k in _DENSE_FFN})
     if cfg.is_moe:
         carry = segment(carry, kd, cfg.num_layers, expert_ffn,
                         {k: params[k] for k in _EXPERT_FFN})
-    x, kp, vp, cp, counts = carry
+    x, kp, vp, (cp,), counts = carry
     return (x, StatePools(kp.reshape(pool), ()),
-            StatePools(vp.reshape(vpool), (cp.reshape(conv.shape),)),
+            StatePools(vp.reshape(vpool), (cp.reshape(held[0].shape),)),
             counts if cfg.moe_grouped else None)
 
 
@@ -1626,6 +1796,37 @@ def _hybrid_rotary(cfg: ModelConfig) -> bool:
     return cfg.parallel_mixers or cfg.operator_ffn
 
 
+def _sparse_selects(cfg: ModelConfig, table_pages: int, chunk_tokens: int,
+                    page_size: int) -> bool:
+    """Whether a program whose page table has `table_pages` entries (a
+    chunk's: its trash tail taken off again; 0 chunk tokens: a decode
+    table) can hold a context past sparse_dense_len: any other keeps the
+    dense kernels and traces no selection at all."""
+    tail = att.chunk_table_tail(chunk_tokens, page_size) if chunk_tokens else 0
+    return (table_pages - tail) * page_size > cfg.sparse_dense_len
+
+
+def _sparse_chunk(cfg: ModelConfig, q, kp, vp, sums, row, table, start,
+                  page_size: int, dense):
+    """A sparse layer's chunk attention -> (o, what the masked attention
+    counted: sparse_ops.CHUNK_STATS); `row`: the sequence's row of `sums`.
+    Under a table that cannot hold a
+    context past sparse_dense_len: `dense()`, the chunk's attention over
+    its whole context through the kernels as they are. Under any other the
+    masked attention, every query under its own membership mask (a query at
+    or under sparse_dense_len: every block up to its own), with NO
+    conditional between the two: a prompt crosses the threshold between two
+    chunks of one program, and a `lax.cond` around a read of the pools had
+    both pools copied, 1 GB each (compiled for a described v5e, PR 56)."""
+    if not _sparse_selects(cfg, table.shape[0], q.shape[0], page_size):
+        return dense(), jnp.zeros((len(sparse_ops.CHUNK_STATS),), jnp.int32)
+    o, seen, skipped = sparse_ops.chunk_attention(
+        q, kp, vp, jax.lax.dynamic_slice_in_dim(sums, row, 1), table, start,
+        sparse_ops.sizes_of(cfg), page_size=page_size,
+        num_kv_heads=cfg.cache_kv_heads)
+    return o, jnp.stack([seen, skipped])
+
+
 def _hybrid_prefill(cfg, params, tokens, n_valid, k_pages, v_pages,
                     pages: SlotPages, start, page_size: int):
     """A whole prompt (start None: from position 0, attention over the
@@ -1657,6 +1858,22 @@ def _hybrid_prefill(cfg, params, tokens, n_valid, k_pages, v_pages,
                                 num_kv_heads=cfg.cache_kv_heads)
         return o, kp, vp
 
+    if cfg.is_sala:
+        def attend(q, k, v, kp, vp, off, sums, base):  # a sparse layer's
+            write = jax.lax.dynamic_slice(table, (start // page_size,),
+                                          (c // page_size,)) + off
+            kp, vp = att.write_kv_prefill(kp, vp, k, v, write,
+                                          page_size=page_size)
+            sums = sparse_ops.page_sums_prefill(
+                sums, k, base + pages.slot, start, n_valid,
+                page_size=page_size, dtype=kp.dtype)
+            o, seen = _sparse_chunk(
+                cfg, q, kp, vp, sums, base + pages.slot, table + off, start,
+                page_size, lambda: att.chunk_attention(
+                    q, kp, vp, table + off, start, page_size=page_size,
+                    num_kv_heads=cfg.cache_kv_heads))
+            return o, kp, vp, sums, seen
+
     fresh = jnp.bool_(True) if start is None else start == 0
     # the rows' positions, for the rotary of a model whose attention has one
     positions = ((0 if start is None else start) + jnp.arange(c)
@@ -1687,7 +1904,8 @@ def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
     rows [B decode | C chunk]. Returns (x [B(+C), E] after the last layer,
     k_pages, v_pages, counts)."""
     b = tokens.shape[0]
-    n_slots = v_pages.state[0].shape[1 if cfg.state_stacked else 0]
+    n_slots = (k_pages if cfg.is_sala else v_pages).state[0].shape[
+        1 if cfg.state_stacked else 0]
     if b != n_slots:
         raise ValueError(
             f"{b} decode rows over {n_slots} state slots: "
@@ -1727,6 +1945,51 @@ def _hybrid_step(cfg, params, tokens, positions, block_tables, context_lens,
             num_decode=b, kernel_lens=kernel_lens)
         return o, kp, vp
 
+    if cfg.is_sala:
+        dense_attend = attend
+        sz = sparse_ops.sizes_of(cfg)
+        none = jnp.zeros((len(sparse_ops.CHUNK_STATS),), jnp.int32)
+        # a decode table that can hold a context past sparse_dense_len: the
+        # decode rows select (each by its own context), the chunk apart;
+        # under a narrower one the kernels as they are, and the page sums
+        selects = _sparse_selects(cfg, tables.shape[1], 0, page_size)
+
+        def page_sums_at(sums, kp, k, off, base):
+            """The sums of the rows' pages just written, and the chunk's."""
+            sums = sparse_ops.page_sums_token(
+                sums, kp, tables + off, positions, live, base,
+                page_size=page_size)
+            if chunk is None:
+                return sums
+            return sparse_ops.page_sums_prefill(
+                sums, k[b:], base + pages.slot, start, n_valid,
+                page_size=page_size, dtype=kp.dtype)
+
+        def attend(q, k, v, kp, vp, off, sums, base):  # a sparse layer's
+            if not selects:
+                o, kp, vp = dense_attend(q, k, v, kp, vp, off)
+                return o, kp, vp, page_sums_at(sums, kp, k, off, base), none
+            kp, vp = att.write_kv_token(kp, vp, k[:b], v[:b], tables + off,
+                                        positions, page_size=page_size)
+            if chunk is not None:
+                kp, vp = att.write_kv_prefill(kp, vp, k[b:], v[b:],
+                                              write + off,
+                                              page_size=page_size)
+            sums = page_sums_at(sums, kp, k, off, base)
+            o = sparse_ops.decode_attention(
+                q[:b], kp, vp, jax.lax.dynamic_slice_in_dim(sums, base, b),
+                tables + off, context_lens, kernel_lens, sz,
+                page_size=page_size, num_kv_heads=cfg.cache_kv_heads)
+            if chunk is None:
+                return o, kp, vp, sums, none
+            oc, seen = _sparse_chunk(
+                cfg, q[b:], kp, vp, sums, base + pages.slot,
+                pages.pages + off, start, page_size,
+                lambda: att.chunk_attention(
+                    q[b:], kp, vp, pages.pages + off, start,
+                    page_size=page_size, num_kv_heads=cfg.cache_kv_heads))
+            return jnp.concatenate([o, oc]), kp, vp, sums, seen
+
     return _hybrid_layers(
         cfg, params, _embed_rows(cfg, params, all_tokens), k_pages, v_pages,
         attend, token_mask, decode=(b, live, state_slots), chunk=mchunk,
@@ -1756,6 +2019,10 @@ def _logits(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
         # falcon_h1's lm_head_multiplier, on the [T, E] rows and not on
         # [T, V] logits (the head is linear; the published 2^-7 is exact)
         x = x * cfg.multipliers.lm_head
+    if cfg.dim_model_base:
+        # the MiniCPM family's muP width: the final hidden over hidden_size
+        # / dim_model_base, on the [T, E] rows (the head is linear)
+        x = x * jnp.asarray(cfg.dim_model_base / cfg.hidden_size, x.dtype)
     if cfg.tie_word_embeddings:
         out = quant.tied_head_einsum(x, params["embed"])
     else:

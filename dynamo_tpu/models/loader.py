@@ -159,6 +159,14 @@ def load_hf_safetensors(cfg: ModelConfig, files) -> Dict[str, jax.Array]:
             "leaves stack by kind (models/llama.KIND_PREFIX) and no weight "
             "file has been read against that yet; such a model is served "
             "on seeded random weights")
+    if cfg.is_sala:
+        raise NotImplementedError(
+            "loading a checkpoint of model_type minicpm_sala (an operator-"
+            "then-FFN model: models/llama._operator_param_specs) is not "
+            "implemented: no weight file has been read against the "
+            "published key names (ASSUMED, benchmarks/chip/configs/minicpm-"
+            "sala-w8a8-1chip.json); such a model is served on seeded random "
+            "weights")
     if cfg.operator_ffn:
         raise NotImplementedError(
             "loading a checkpoint of model_type lfm2_moe (an operator-then-"

@@ -87,6 +87,12 @@ QUANT_AXES: Dict[str, Tuple[int, ...]] = {
     # model's dtype)
     "conv_in": (1,),   # [L_c, E, B | C | u]
     "conv_out": (1,),  # [L_c, E, E]
+    # minicpm_sala's projections with their heads side by side, and its
+    # output gates (sparse and Lightning operators alike)
+    "w_q": (1,),  # [L, E, H * D]
+    "w_k": (1,),  # [L, E, KV * D] (a Lightning layer: H * D)
+    "w_v": (1,),
+    "w_og": (1,),  # [L, E, H * D]
     "moe_w_gate": (2,),  # [L, X, E, F]
     "moe_w_up": (2,),
     "moe_w_down": (2,),  # [L, X, F, E]

@@ -230,6 +230,9 @@ class MemoryAccountant:
             "bytes_per_token": eng.kv_spec.bytes_per_token(),
             "bytes_per_slot": eng.kv_spec.bytes_per_slot(),
             "state_slots": state_slots,
+            # a row of key sums a page in every block-sparse layer (0
+            # without such layers: engine/kv_cache.KVCacheSpec)
+            "pooled_key_bytes": eng.kv_spec.pooled_key_bytes(),
             "bytes_per_token_by_kind": eng.kv_spec.bytes_per_token_by_kind(),
             "rows_by_kind": rows_by_kind,
             # what a row of each kind's pool really holds: KV heads, and

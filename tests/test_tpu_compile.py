@@ -1168,3 +1168,145 @@ def test_lfm2_state_snapshot_copies_stay_small_and_apart(one_chip):
         assert text.count("dynamic-update-slice_fusion = ") == 1
         assert not [line for line in ops if re.search(
             r"= bf16\[(512,18,1|18,64),2,2048\]\S* copy\(", line)]
+
+
+# MiniCPM-SALA as published (PR 56): 16 slots = 16 state slots of 24 Lightning
+# states, a decode table of 49,152 tokens, the widest chunked-prompt table
+SALA_SLOTS, SALA_TABLE, SALA_CHUNK_TABLE = 16, 3072, 3087
+SALA_PAGES = 16384
+SALA_SCOPES = ("sparse_pool_keys", "attn_sparse", "attn_qk_norm", "attn_gate",
+               "lightning_in_proj", "lightning_gate_norm",
+               "lightning_out_proj", "mlp_dense")
+SALA_SCOPES_BY = {
+    "decode": ("sparse_block_scores", "sparse_block_select",
+               "attn_sparse_blocks", "lightning_update"),
+    "window": ("sparse_block_scores", "sparse_block_select",
+               "attn_sparse_blocks", "lightning_update"),
+    "mixed": ("sparse_block_scores", "sparse_block_select",
+              "attn_sparse_blocks", "attn_sparse_mask", "lightning_update",
+              "lightning_scan"),
+    "chunk": ("sparse_block_scores", "sparse_block_select",
+              "attn_sparse_mask", "lightning_scan"),
+    "prefill": ("lightning_scan",),
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed", "window", "prefill",
+                                     "chunk"])
+def test_sala_step_compiles_for_v5e_under_its_scope_names(one_chip, program):
+    """The WHOLE published model's decode step, mixed step, 16-step window,
+    whole-prompt prefill and prompt chunk at the cell's sizes (w8a8, all 32
+    layers, 16 slots of 24 Lightning states and of 3,088 page sums in 8
+    layers, 16,384 pages, a 256-token chunk, the 49,152-token tables) for a described
+    v5e: no op leaves the kernels (no counted fallback), every span the
+    docs name is in the HLO, the state update is the kernel
+    `ssm_update_live` over the pool of states, the layers are ONE scan with
+    ONE conditional over the operator's kind, no pool, no pooled-key array,
+    no state array and no weight stack is copied, and the arguments are the
+    memory the configuration file reckons, under 13 GB."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.engine.kv_cache import KVCacheSpec
+    from dynamo_tpu.models import llama, quant
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.ops import attention as att
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    def i32(*shape):
+        return arg(shape, jnp.int32)
+
+    cfg = ModelConfig.from_model_name(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks/chip/configs/minicpm-sala-w8a8-1chip"))
+    b = SALA_SLOTS
+    spec = KVCacheSpec.from_model(cfg, SALA_PAGES, PAGE, state_slots=b,
+                                  pooled_key_pages=SALA_CHUNK_TABLE + 1)
+    assert spec.shape == (8, SALA_PAGES, PAGE, 256)
+    assert spec.ssm_shape == (32, 128, 128) and spec.conv_shape == ()
+    assert spec.bytes_per_slot() == 24 * 32 * 128 * 128 * 4  # 50.3 MB
+    assert spec.pooled_key_shape == (8, b, SALA_CHUNK_TABLE + 1, 256)
+    assert not spec.state_kept_at_blocks
+    params = {}
+    for name, (shape, kind, _) in llama.param_specs(cfg).items():
+        axes = quant.quant_axes(name)
+        if axes and kind == "normal":
+            params[name] = quant.QTensorA8(
+                arg(shape, jnp.int8),
+                arg([1 if i in axes else s for i, s in enumerate(shape)],
+                    jnp.float32))
+        else:
+            params[name] = arg(shape, jnp.bfloat16)
+    kp = llama.StatePools(
+        arg(spec.shape, jnp.bfloat16),
+        (arg((24, b) + spec.ssm_shape, jnp.float32),),
+        (arg(spec.pooled_key_shape, jnp.float32),))
+    vp = llama.StatePools(arg(spec.v_shape, jnp.bfloat16), ())
+    before = dict(att.pallas_fallback_counts())
+    with att.attention_context("pallas", None, 1):
+        if program == "decode":
+            compiled = jax.jit(functools.partial(
+                llama.decode_step, cfg, page_size=PAGE),
+                donate_argnums=(5, 6)).lower(
+                params, i32(b), i32(b), i32(b, SALA_TABLE), i32(b), kp,
+                vp).compile()
+        elif program == "window":
+            compiled = jax.jit(_hybrid_window(cfg),
+                               donate_argnums=(5, 6)).lower(
+                params, i32(b), i32(b), i32(b, SALA_TABLE), i32(b), kp,
+                vp, i32()).compile()
+        elif program == "mixed":
+            compiled = jax.jit(functools.partial(
+                llama.mixed_step, cfg, page_size=PAGE),
+                donate_argnums=(9, 10)).lower(
+                params, i32(b), i32(b), i32(b, SALA_TABLE), i32(b),
+                i32(CHUNK), i32(), i32(),
+                llama.SlotPages(i32(SALA_CHUNK_TABLE), i32()), kp,
+                vp).compile()
+        elif program == "chunk":
+            compiled = jax.jit(functools.partial(
+                llama.prefill_chunk, cfg, page_size=PAGE),
+                donate_argnums=(4, 5)).lower(
+                params, i32(CHUNK), i32(), i32(), kp, vp,
+                llama.SlotPages(i32(SALA_CHUNK_TABLE), i32())).compile()
+        else:
+            compiled = jax.jit(functools.partial(
+                llama.prefill, cfg, page_size=PAGE),
+                donate_argnums=(3, 4)).lower(
+                params, i32(128), i32(), kp, vp,
+                llama.SlotPages(i32(8), i32())).compile()
+    assert dict(att.pallas_fallback_counts()) == before
+    text = compiled.as_text()
+    for scope in SALA_SCOPES + SALA_SCOPES_BY[program]:
+        assert scope in text, scope
+    if program in ("decode", "window", "mixed"):
+        calls = [ln.strip() for ln in text.splitlines()
+                 if "tpu_custom_call" in ln and "lightning_update" in ln]
+        assert len(calls) == 1 and "ssm_update_live" in calls[0]
+        assert "f32[384,32,128,128]" in calls[0]  # the states flat, in place
+        calls = [ln.strip() for ln in text.splitlines()
+                 if "tpu_custom_call" in ln and "attn_sparse_blocks" in ln]
+        assert len(calls) == 1  # ONE decode kernel for the eight layers
+        assert "bf16[131072,16,256]" in calls[0]  # the pool flat, in place
+    # no pool, pooled-key array, state array or weight stack is copied
+    assert not re.findall(r"bf16\[(?:8,16384|131072),16,256\]\S* copy\(", text)
+    # (the chunk-ALONE program, what an idle engine runs a prompt's chunks
+    # through, relays the page sums out pages-minor at its entry and back at
+    # its end, 0.4 GB each way, 2 ms of its 29: PERF.md section 7. The
+    # programs that carry decode rows, the cell's, do not)
+    sums_copies = re.findall(r"f32\[(?:8,16|128),3088,256\]\S* copy\(", text)
+    assert len(sums_copies) == (2 if program == "chunk" else 0)
+    assert not re.findall(r"f32\[(?:24,16|384),32,128,128\]\S* copy\(", text)
+    assert not re.search(r"= s8\[(8|24|32),\S* copy\(", text)
+    assert len(re.findall(r" conditional\(", text)) >= 1
+    mem = compiled.memory_analysis()
+    want = (8 * 52.4e6 + 24 * 83.9e6 + 32 * 201.3e6 + 601.7e6
+            + 2 * 8 * SALA_PAGES * 16 * 256 * 2 + 24 * b * 32 * 128 * 128 * 4
+            + 8 * b * (SALA_CHUNK_TABLE + 1) * 256 * 4)
+    assert want < mem.argument_size_in_bytes < want + 0.08e9
+    assert mem.argument_size_in_bytes < 13e9
+    assert mem.temp_size_in_bytes < (0.5e9 if program == "chunk" else 0.15e9)

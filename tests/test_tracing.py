@@ -368,7 +368,8 @@ def test_a_capture_samples_the_kernels_counters(server):
         assert due <= got < due + 0.2
     for s in cap["samples"]:
         assert set(s["metrics"]) == {"attn", "attn_kinds", "dsa", "moe",
-                                      "ssm", "conv", "admit_blocked"}
+                                      "sparse", "ssm", "conv",
+                                      "admit_blocked"}
         assert s["metrics"]["attn"] == stats["metrics"]["attn"]  # idle
 
 

@@ -8,9 +8,73 @@ same."""
 
 import dataclasses
 
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from dynamo_tpu.engine.config import EngineConfig
+from dynamo_tpu.engine.engine import Engine
 from dynamo_tpu.engine.request import GenRequest
+from dynamo_tpu.observability.memory import MemoryAccountant
+
+
+def prompt(seed: int, n: int):
+    return [int(t) for t in np.random.default_rng(seed).integers(3, 500, n)]
+
+
+def drain(eng) -> dict:
+    out = {}
+    while eng.has_work:
+        for ev in eng.step():
+            if ev.token_id >= 0:
+                out.setdefault(ev.request_id, []).append(ev.token_id)
+    return out
+
+
+def slots_held(eng) -> int:
+    return MemoryAccountant(eng).snapshot()["state_slots"]["held"]
+
+
+def greedy_of(ref, hf_dict, eng, tokens, n_new: int):
+    """The argmax of `ref` (a family's reference module, configured through
+    its test file's `hf_dict`) at every generated position, teacher forced
+    on `tokens` (prompt + what the engine gave)."""
+    cfg = dataclasses.replace(eng.model_cfg, dtype="float32")
+    logits = ref.forward(ref.Config.from_hf(hf_dict(cfg)),
+                         ref.dequantize(eng.params), jnp.asarray(tokens))
+    first = len(tokens) - n_new
+    return [int(t) for t in np.argmax(logits[first - 1:-1], axis=-1)]
+
+
+def engine_pair(cfg: dict):
+    """Module-scoped fixtures of one configuration: the engine, and the
+    oracle of the pipelined orders (async_scheduling off)."""
+    @pytest.fixture(scope="module")
+    def engine():
+        return Engine(EngineConfig(**cfg))
+
+    @pytest.fixture(scope="module")
+    def sync_engine():
+        return Engine(EngineConfig(**cfg, async_scheduling=False))
+
+    return engine, sync_engine
+
+
+def warm_then_serve(eng, short: int = 20, long: int = 60):
+    """After warmup() no request compiles a program: not a short prompt
+    whose decoders leave before it is done, nor a long one. Returns the
+    long prompt and its six greedy tokens."""
+    eng.warmup()
+    before = eng.compiled_program_count()
+    eng.add_request(GenRequest("a", prompt(5, short), max_tokens=2,
+                               temperature=0.0, ignore_eos=True))
+    eng.step()
+    p = prompt(6, long)
+    eng.add_request(GenRequest("b", p, max_tokens=6, temperature=0.0,
+                               ignore_eos=True))
+    toks = drain(eng)["b"]
+    assert eng.compiled_program_count() == before
+    return p, toks
 
 
 def serve(eng, live, late, at=3):

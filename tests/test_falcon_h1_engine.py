@@ -8,10 +8,9 @@ unbroken run's; a prefix hit counted inexact and served by recompute;
 `metrics.ssm`, `metrics.attn_kinds.full`, `metrics.admit_blocked` by the
 store that lacked room, and the memory snapshot; what is refused."""
 
-import dataclasses
+import functools
 
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from dynamo_tpu.engine.config import EngineConfig
@@ -21,51 +20,17 @@ from dynamo_tpu.models.reference import falcon_h1 as ref
 from dynamo_tpu.observability.memory import MemoryAccountant
 
 from falcon_h1_common import hf_dict, tiny
-from pipelined_common import (assert_finish_rides_pipeline,
-                              assert_first_token_rides_pipeline,
-                              assert_pipelined_matches_sync)
+from pipelined_common import (
+    assert_finish_rides_pipeline, assert_first_token_rides_pipeline,
+    assert_pipelined_matches_sync, drain, engine_pair, greedy_of, prompt,
+    slots_held, warm_then_serve)
 
 CFG = dict(model="tiny-falcon-h1-debug", page_size=4, num_pages=128,
            max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
            mixed_batch_tokens=8, num_scheduler_steps=4, dtype="float32")
 
-
-def prompt(seed: int, n: int):
-    return [int(t) for t in np.random.default_rng(seed).integers(3, 500, n)]
-
-
-def drain(eng: Engine) -> dict:
-    out = {}
-    while eng.has_work:
-        for ev in eng.step():
-            if ev.token_id >= 0:
-                out.setdefault(ev.request_id, []).append(ev.token_id)
-    return out
-
-
-def reference_greedy(eng: Engine, tokens, n_new: int):
-    """The reference's argmax at every generated position, teacher forced
-    on `tokens` (prompt + what the engine gave)."""
-    cfg = dataclasses.replace(eng.model_cfg, dtype="float32")
-    logits = ref.forward(ref.Config.from_hf(hf_dict(cfg)),
-                         ref.dequantize(eng.params), jnp.asarray(tokens))
-    first = len(tokens) - n_new
-    return [int(t) for t in np.argmax(logits[first - 1:-1], axis=-1)]
-
-
-def slots_held(eng: Engine) -> int:
-    return MemoryAccountant(eng).snapshot()["state_slots"]["held"]
-
-
-@pytest.fixture(scope="module")
-def engine():
-    return Engine(EngineConfig(**CFG))
-
-
-@pytest.fixture(scope="module")
-def sync_engine():
-    """The oracle of the pipelined orders: async_scheduling off."""
-    return Engine(EngineConfig(**CFG, async_scheduling=False))
+reference_greedy = functools.partial(greedy_of, ref, hf_dict)
+engine, sync_engine = engine_pair(CFG)
 
 
 def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
@@ -274,18 +239,8 @@ def test_a_model_without_state_slots_counts_no_blocked_admission():
 
 
 def test_warmup_compiles_what_the_window_runs(engine):
-    eng = engine
-    eng.warmup()
-    before = eng.compiled_program_count()
-    eng.add_request(GenRequest("a", prompt(5, 20), max_tokens=2,
-                               temperature=0.0, ignore_eos=True))
-    eng.step()
-    p = prompt(6, 60)
-    eng.add_request(GenRequest("b", p, max_tokens=6, temperature=0.0,
-                               ignore_eos=True))
-    toks = drain(eng)["b"]
-    assert eng.compiled_program_count() == before
-    assert toks == reference_greedy(eng, p + toks, 6)
+    p, toks = warm_then_serve(engine)
+    assert toks == reference_greedy(engine, p + toks, 6)
 
 
 @pytest.mark.parametrize("change,word", [

@@ -6,10 +6,8 @@ and sequences of very different lengths; a prefix hit served only where it
 is exact (never, with rings of a sequence's own: counted, served a miss);
 `metrics.attn_kinds` and the memory snapshot by kind; what is refused."""
 
-import dataclasses
+import functools
 
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from dynamo_tpu.engine.config import EngineConfig
@@ -20,46 +18,16 @@ from dynamo_tpu.observability.memory import MemoryAccountant
 
 from pipelined_common import (
     assert_finish_rides_pipeline, assert_pipelined_matches_sync,
-    assert_windows_as_long_as_the_shortest_headroom)
+    assert_windows_as_long_as_the_shortest_headroom, drain, engine_pair,
+    greedy_of, prompt, warm_then_serve)
 from test_laguna import hf_dict, tiny
 
 CFG = dict(model="tiny-laguna-debug", page_size=4, num_pages=128,
            max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
            mixed_batch_tokens=8, num_scheduler_steps=4, dtype="float32")
 
-
-def prompt(seed: int, n: int):
-    return [int(t) for t in np.random.default_rng(seed).integers(3, 500, n)]
-
-
-def drain(eng: Engine) -> dict:
-    out = {}
-    while eng.has_work:
-        for ev in eng.step():
-            if ev.token_id >= 0:
-                out.setdefault(ev.request_id, []).append(ev.token_id)
-    return out
-
-
-def reference_greedy(eng: Engine, tokens, n_new: int):
-    """The reference's argmax at every generated position, teacher forced
-    on `tokens` (prompt + what the engine gave)."""
-    cfg = dataclasses.replace(eng.model_cfg, dtype="float32")
-    logits = ref.forward(ref.Config.from_hf(hf_dict(cfg)),
-                         ref.dequantize(eng.params), jnp.asarray(tokens))
-    first = len(tokens) - n_new
-    return [int(t) for t in np.argmax(logits[first - 1:-1], axis=-1)]
-
-
-@pytest.fixture(scope="module")
-def engine():
-    return Engine(EngineConfig(**CFG))
-
-
-@pytest.fixture(scope="module")
-def sync_engine():
-    """The oracle of the pipelined orders: async_scheduling off."""
-    return Engine(EngineConfig(**CFG, async_scheduling=False))
+reference_greedy = functools.partial(greedy_of, ref, hf_dict)
+engine, sync_engine = engine_pair(CFG)
 
 
 def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
@@ -186,16 +154,7 @@ def test_warmup_compiles_what_the_window_runs(engine):
     whose decoders leave before it is done (the chunk program at a table
     width the prefix-cached second pass would have compiled for a one-kind
     model), nor a long one."""
-    eng = engine
-    eng.warmup()
-    before = eng.compiled_program_count()
-    eng.add_request(GenRequest("a", prompt(5, 20), max_tokens=2,
-                               temperature=0.0, ignore_eos=True))
-    eng.step()
-    eng.add_request(GenRequest("b", prompt(6, 60), max_tokens=6,
-                               temperature=0.0, ignore_eos=True))
-    drain(eng)
-    assert eng.compiled_program_count() == before
+    warm_then_serve(engine)
 
 
 @pytest.mark.parametrize("change,word", [

@@ -21,25 +21,13 @@ from dynamo_tpu.models.reference import mimo_v2 as ref
 from dynamo_tpu.observability.memory import MemoryAccountant
 
 from mimo_v2_common import hf_dict, tiny
-from pipelined_common import (assert_finish_rides_pipeline,
-                              assert_pipelined_matches_sync)
+from pipelined_common import (
+    assert_finish_rides_pipeline, assert_pipelined_matches_sync, drain,
+    prompt, warm_then_serve)
 
 CFG = dict(model="tiny-mimo-v2-debug", page_size=4, num_pages=128,
            max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
            mixed_batch_tokens=8, num_scheduler_steps=4, dtype="float32")
-
-
-def prompt(seed: int, n: int):
-    return [int(t) for t in np.random.default_rng(seed).integers(3, 500, n)]
-
-
-def drain(eng: Engine) -> dict:
-    out = {}
-    while eng.has_work:
-        for ev in eng.step():
-            if ev.token_id >= 0:
-                out.setdefault(ev.request_id, []).append(ev.token_id)
-    return out
 
 
 def pools_free(eng: Engine):
@@ -227,16 +215,7 @@ def test_preemption_and_resume_conserve_both_pools():
 def test_warmup_compiles_what_the_window_runs(engine):
     """After warmup() no request compiles a program: not a prompt inside
     the window, nor one past it whose chunks ride mixed steps."""
-    eng = engine
-    eng.warmup()
-    before = eng.compiled_program_count()
-    eng.add_request(GenRequest("a", prompt(5, 7), max_tokens=2,
-                               temperature=0.0, ignore_eos=True))
-    eng.step()
-    eng.add_request(GenRequest("b", prompt(6, 60), max_tokens=6,
-                               temperature=0.0, ignore_eos=True))
-    drain(eng)
-    assert eng.compiled_program_count() == before
+    warm_then_serve(engine, short=7)
 
 
 @pytest.mark.parametrize("change,word", [

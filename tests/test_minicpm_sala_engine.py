@@ -6,7 +6,7 @@ sequences of which one stays under `dense_len` in one batch; a reused slot
 starts from zero; preemption and resume; a prefix hit counted inexact;
 `metrics.sparse`, `metrics.ssm` and the memory snapshot; what is refused."""
 
-import dataclasses
+import functools
 
 import jax.numpy as jnp
 import numpy as np
@@ -20,50 +20,16 @@ from dynamo_tpu.observability.memory import MemoryAccountant
 
 from minicpm_sala_common import hf_dict, tiny
 
-from pipelined_common import (assert_finish_rides_pipeline,
-                              assert_first_token_rides_pipeline)
+from pipelined_common import (
+    assert_finish_rides_pipeline, assert_first_token_rides_pipeline, drain,
+    engine_pair, greedy_of, prompt, slots_held, warm_then_serve)
 
 CFG = dict(model="tiny-minicpm-sala-debug", page_size=4, num_pages=256,
            max_num_seqs=4, max_seq_len=256, prefill_chunk_tokens=16,
            mixed_batch_tokens=16, num_scheduler_steps=4, dtype="float32")
 
-
-def prompt(seed: int, n: int):
-    return [int(t) for t in np.random.default_rng(seed).integers(3, 500, n)]
-
-
-def drain(eng: Engine) -> dict:
-    out = {}
-    while eng.has_work:
-        for ev in eng.step():
-            if ev.token_id >= 0:
-                out.setdefault(ev.request_id, []).append(ev.token_id)
-    return out
-
-
-def reference_greedy(eng: Engine, tokens, n_new: int):
-    """The reference's argmax at every generated position, teacher forced
-    on `tokens` (prompt + what the engine gave)."""
-    cfg = dataclasses.replace(eng.model_cfg, dtype="float32")
-    logits = ref.forward(ref.Config.from_hf(hf_dict(cfg)),
-                         ref.dequantize(eng.params), jnp.asarray(tokens))
-    first = len(tokens) - n_new
-    return [int(t) for t in np.argmax(logits[first - 1:-1], axis=-1)]
-
-
-def slots_held(eng: Engine) -> int:
-    return MemoryAccountant(eng).snapshot()["state_slots"]["held"]
-
-
-@pytest.fixture(scope="module")
-def engine():
-    return Engine(EngineConfig(**CFG))
-
-
-@pytest.fixture(scope="module")
-def sync_engine():
-    """The oracle of the pipelined orders: async_scheduling off."""
-    return Engine(EngineConfig(**CFG, async_scheduling=False))
+reference_greedy = functools.partial(greedy_of, ref, hf_dict)
+engine, sync_engine = engine_pair(CFG)
 
 
 def test_a_long_and_a_short_sequence_in_one_batch_match_the_reference(engine):
@@ -222,18 +188,8 @@ def test_preemption_and_resume_reproduce_the_tokens():
 
 
 def test_warmup_compiles_what_the_window_runs(engine):
-    eng = engine
-    eng.warmup()
-    before = eng.compiled_program_count()
-    eng.add_request(GenRequest("a", prompt(5, 20), max_tokens=2,
-                               temperature=0.0, ignore_eos=True))
-    eng.step()
-    p = prompt(6, 120)
-    eng.add_request(GenRequest("b", p, max_tokens=6, temperature=0.0,
-                               ignore_eos=True))
-    toks = drain(eng)["b"]
-    assert eng.compiled_program_count() == before
-    assert toks == reference_greedy(eng, p + toks, 6)
+    p, toks = warm_then_serve(engine, long=120)
+    assert toks == reference_greedy(engine, p + toks, 6)
 
 
 @pytest.mark.parametrize("change,word", [

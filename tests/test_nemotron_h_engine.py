@@ -7,10 +7,9 @@ finish, abort, preemption and resume; a reused slot starts from zero; a
 prefix hit counted inexact and served by recompute; `metrics.ssm`,
 `metrics.attn_kinds.full` and the memory snapshot; what is refused."""
 
-import dataclasses
+import functools
 
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from dynamo_tpu.engine.config import EngineConfig
@@ -23,65 +22,22 @@ from nemotron_h_common import hf_dict, tiny
 
 from pipelined_common import (
     assert_finish_rides_pipeline, assert_first_token_rides_pipeline,
-    assert_windows_as_long_as_the_shortest_headroom)
+    assert_windows_as_long_as_the_shortest_headroom, drain, engine_pair,
+    greedy_of, prompt, slots_held, warm_then_serve)
 
 CFG = dict(model="tiny-nemotron-h-debug", page_size=4, num_pages=128,
            max_num_seqs=4, max_seq_len=128, prefill_chunk_tokens=8,
            mixed_batch_tokens=8, num_scheduler_steps=4, dtype="float32")
 
-
-def prompt(seed: int, n: int):
-    return [int(t) for t in np.random.default_rng(seed).integers(3, 500, n)]
-
-
-def drain(eng: Engine) -> dict:
-    out = {}
-    while eng.has_work:
-        for ev in eng.step():
-            if ev.token_id >= 0:
-                out.setdefault(ev.request_id, []).append(ev.token_id)
-    return out
-
-
-def reference_greedy(eng: Engine, tokens, n_new: int):
-    """The reference's argmax at every generated position, teacher forced
-    on `tokens` (prompt + what the engine gave)."""
-    cfg = dataclasses.replace(eng.model_cfg, dtype="float32")
-    logits = ref.forward(ref.Config.from_hf(hf_dict(cfg)),
-                         ref.dequantize(eng.params), jnp.asarray(tokens))
-    first = len(tokens) - n_new
-    return [int(t) for t in np.argmax(logits[first - 1:-1], axis=-1)]
-
-
-def slots_held(eng: Engine) -> int:
-    return MemoryAccountant(eng).snapshot()["state_slots"]["held"]
-
-
-@pytest.fixture(scope="module")
-def engine():
-    return Engine(EngineConfig(**CFG))
-
-
-@pytest.fixture(scope="module")
-def sync_engine():
-    """The oracle of the pipelined orders: async_scheduling off."""
-    return Engine(EngineConfig(**CFG, async_scheduling=False))
+reference_greedy = functools.partial(greedy_of, ref, hf_dict)
+engine, sync_engine = engine_pair(CFG)
 
 
 # the state update's kernel (interpret mode: ops/ssm.update_live walks the
 # live slots only and leaves a dead slot's state where it lies); attention
 # takes its XLA path at these head sizes, which is counted and no matter here
 KERNEL = dict(CFG, attention_backend="pallas_interpret")
-
-
-@pytest.fixture(scope="module")
-def kernel_engine():
-    return Engine(EngineConfig(**KERNEL))
-
-
-@pytest.fixture(scope="module")
-def kernel_sync_engine():
-    return Engine(EngineConfig(**KERNEL, async_scheduling=False))
+kernel_engine, kernel_sync_engine = engine_pair(KERNEL)
 
 
 def test_two_sequences_of_very_different_lengths_match_the_reference(engine):
@@ -274,20 +230,10 @@ def test_warmup_compiles_what_the_window_runs(engine):
     """After warmup() no request compiles a program: not a short prompt
     whose decoders leave before it is done, nor a long one; the state
     arrays ride every step program as donated buffers and come back."""
-    eng = engine
-    eng.warmup()
-    before = eng.compiled_program_count()
-    eng.add_request(GenRequest("a", prompt(5, 20), max_tokens=2,
-                               temperature=0.0, ignore_eos=True))
-    eng.step()
-    p = prompt(6, 60)
-    eng.add_request(GenRequest("b", p, max_tokens=6, temperature=0.0,
-                               ignore_eos=True))
-    toks = drain(eng)["b"]
-    assert eng.compiled_program_count() == before
-    assert toks == reference_greedy(eng, p + toks, 6)
-    assert len(eng.k_pages.state) == len(eng.v_pages.state) == 4
-    assert eng.k_pages.state[0].dtype == jnp.float32
+    p, toks = warm_then_serve(engine)
+    assert toks == reference_greedy(engine, p + toks, 6)
+    assert len(engine.k_pages.state) == len(engine.v_pages.state) == 4
+    assert engine.k_pages.state[0].dtype == jnp.float32
 
 
 @pytest.mark.parametrize("change,word", [

@@ -111,3 +111,23 @@ def test_native_build_dir_shares_the_build_home(monkeypatch):
 
     monkeypatch.delenv("DYNAMO_TPU_BUILD_DIR", raising=False)
     assert native._build_dir() == os.path.join(plat.build_home(), "native")
+
+
+def test_a_phase_past_its_limit_fails_and_does_not_hang(request, monkeypatch):
+    """tests/conftest.py arms an alarm around every test's setup and call:
+    a sleep inside the armed hook ends at the limit with pytest's failure,
+    and the alarm this test itself runs under is armed again afterwards."""
+    import signal
+    import time
+
+    import conftest
+
+    monkeypatch.setattr(conftest, "PHASE_LIMIT_S", 0.2)
+    armed = conftest.pytest_runtest_call(request.node)
+    next(armed)
+    try:
+        with pytest.raises(pytest.fail.Exception, match="call ran past 0.2 s"):
+            time.sleep(30)
+    finally:
+        armed.close()
+    assert signal.getitimer(signal.ITIMER_REAL)[0] > 0.2

@@ -389,8 +389,12 @@ def test_drain_demotes_prefix_kv_to_host_tier():
     try:
         from dynamo_tpu.engine.request import GenRequest
 
-        eng.generate(GenRequest("warm", list(range(1, 20)), max_tokens=2,
-                                temperature=0.0, ignore_eos=True))
+        # through the service, whose thread alone may step the engine: a
+        # second consumer (eng.generate here) can see `has_work` fall
+        # before that thread has given the finished sequence's pages back
+        list(ctx.service.stream(GenRequest(
+            "warm", list(range(1, 20)), max_tokens=2, temperature=0.0,
+            ignore_eos=True)))
         assert eng.prefix_cache.evictable() > 0
         demoted = ctx.drain_demote()
         assert demoted > 0

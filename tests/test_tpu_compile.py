@@ -21,13 +21,22 @@ PAGE, HEAD_DIM, SLOTS, CHUNK, POOL_PAGES = 16, 128, 32, 256, 4096
 @pytest.fixture(scope="module")
 def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
     from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
 
     try:
-        return topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no libtpu here, or it is held elsewhere
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # the session's compile cache (conftest.py) could write what libtpu
+    # compiles and never read it (DeserializeLoadedExecutable: unimplemented)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
 
 
 @pytest.fixture(scope="module")

@@ -250,7 +250,8 @@ def test_nats_plane_roundtrip_preserves_trace():
         trace_id = resp.headers.get("X-Request-Id")
         assert trace_id and len(trace_id) == 32
 
-        _, spans = _spans_for(frontend, trace_id, min_spans=4)
+        _, spans = _spans_for(frontend, trace_id, min_spans=4,
+                              require=("frontend.request", "worker.request"))
         by_name = {sp["name"]: (svc, sp) for svc, sp in spans}
         assert "frontend.request" in by_name
         svc, fr = by_name["frontend.request"]
